@@ -19,9 +19,9 @@ from predictionio_tpu import __version__
 @click.group()
 def cli():
     """predictionio_tpu — TPU-native ML server framework."""
-    from predictionio_tpu.utils.config import honor_jax_platforms
+    from predictionio_tpu.utils.device import enable_compile_cache
 
-    honor_jax_platforms()
+    enable_compile_cache()
 
 
 @cli.command()
@@ -402,7 +402,10 @@ def train(variant, batch, skip_sanity_check, stop_after_read,
           stop_after_prepare, mesh_shape, mesh_axes, checkpoint_dir,
           checkpoint_interval):
     """Train an engine instance (Console.scala:179, CoreWorkflow.runTrain)."""
+    from predictionio_tpu.utils import configure_logging
     from predictionio_tpu.workflow import WorkflowParams, run_train
+
+    configure_logging()
 
     engine, engine_params, factory_path, variant_id, _ = \
         _load_engine_variant(variant)
@@ -476,7 +479,10 @@ def deploy(variant, ip, port, engine_instance_id, release_selector, feedback,
     from predictionio_tpu.deploy.releases import resolve_release
     from predictionio_tpu.server.query_server import run_query_server
     from predictionio_tpu.storage import Storage
+    from predictionio_tpu.utils import configure_logging
     from predictionio_tpu.workflow.train import load_for_deploy
+
+    configure_logging()
 
     engine, _, factory_path, variant_id, _vj = _load_engine_variant(variant)
     instances = Storage.get_meta_data_engine_instances()
@@ -542,6 +548,14 @@ def deploy(variant, ip, port, engine_instance_id, release_selector, feedback,
         click.echo("[INFO] Online fold-in disabled (enable via engine.json "
                    '{"foldin": {"enabled": true}} or PIO_FOLDIN=1)')
     result, ctx = load_for_deploy(engine, instance)
+    # claim the device NOW: a server that cannot get its chip (one chip
+    # serves one process) must die here with JAX's reason, not at its
+    # first device-lane query
+    import jax
+
+    from predictionio_tpu.utils.device import describe_devices
+
+    click.echo(f"[INFO] Serving on {describe_devices(jax.devices())}")
     run_query_server(engine, result, instance, ctx, ip=ip, port=port,
                      feedback=feedback, feedback_app_name=event_server_app,
                      access_key=accesskey, log_url=log_url,

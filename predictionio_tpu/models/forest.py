@@ -170,7 +170,7 @@ def _sharded_fit_fn(mesh, c: int, depth: int, b: int, impurity: str):
     axis = mesh.axis_names[0]
 
     def build():
-        from predictionio_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         return jax.jit(shard_map(

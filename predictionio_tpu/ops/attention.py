@@ -33,9 +33,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from predictionio_tpu.parallel.compat import HAS_VMA, pcast_varying, shard_map
 
 NEG_INF = -1e30    # large-negative instead of -inf: avoids NaN in exp(m - m)
 
@@ -172,7 +171,7 @@ def _ring_attention_local(q, k, v, key_mask, *, axis: str, causal: bool,
     vary_axes = (axis,) if batch_axis is None else (axis, batch_axis)
 
     def _vary(x):
-        return pcast_varying(x, vary_axes)
+        return jax.lax.pcast(x, vary_axes, to="varying")
 
     o0 = _vary(jnp.zeros((b, h, lq, d), jnp.float32))
     m0 = _vary(jnp.full((b, h, lq), NEG_INF, jnp.float32))
@@ -256,11 +255,7 @@ def _sharded_fn(local_fn, mesh: Mesh, axis: str, causal: bool,
             functools.partial(local_fn, axis=axis, causal=causal,
                               batch_axis=batch_axis),
             mesh=mesh, in_specs=(spec, spec, spec, mask_spec),
-            out_specs=spec,
-            # the vma marking (pcast_varying on the scan carries)
-            # satisfies the new checker; the old replication checker has
-            # no equivalent
-            check_vma=HAS_VMA))
+            out_specs=spec))
 
     return mesh_cached_fn(f"attention_{local_fn.__name__.strip('_')}",
                           mesh, (axis, causal, batch_axis), build)

@@ -853,82 +853,3 @@ def unit_scorer_status(result) -> list:
         if cached is not None:
             out.append(cached[2].status())
     return out
-
-
-# ---------------------------------------------------------------------------
-# Pallas variant (TPU): fused dequantize -> matmul -> local top-c
-# ---------------------------------------------------------------------------
-
-def pallas_available() -> bool:
-    """The Pallas shortlist kernel runs only on a real TPU backend; the
-    lax.scan kernels above are the portable lowering everywhere else
-    (and the numerics oracle the interpret-mode test checks against)."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
-def build_pallas_shortlist(tile: int, cand: int, interpret: bool = False):
-    """Build the Pallas stage-1 kernel: grid over item tiles, each
-    program dequantizing its [T, R] int8 tile in VMEM, scoring it on the
-    MXU with f32 accumulation, and emitting the tile's local top-c by
-    iterated masked argmax (top_k is not a Pallas primitive; c is small,
-    so c passes over the [B, T] tile stay cheap VPU work).
-
-    Returns ``fn(u [B,R] f32, tiles [nt,T,R] int8, scales [nt,T] f32,
-    n_items) -> (vals [nt,B,c], ids [nt,B,c])`` or raises ImportError
-    where Pallas is unavailable. ``interpret=True`` runs the kernel on
-    the CPU interpreter (the parity test path)."""
-    from jax.experimental import pallas as pl
-
-    def kernel(n_ref, u_ref, v_ref, s_ref, vals_ref, ids_ref):
-        t = pl.program_id(0)
-        u = u_ref[...]                                   # [B, R] f32
-        v = v_ref[0].astype(jnp.float32)                 # [T, R]
-        sc = jax.lax.dot_general(
-            u, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [B, T]
-        sc = sc * s_ref[0][None, :]
-        base = t * tile
-        ids = base + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where(ids >= n_ref[0], -jnp.inf, sc)
-
-        def body(j, carry):
-            sc_c = carry
-            m = jnp.max(sc_c, axis=1)                    # [B]
-            am = jnp.argmax(sc_c, axis=1).astype(jnp.int32)
-            vals_ref[0, :, j] = m
-            ids_ref[0, :, j] = base + am
-            # knock the winner out for the next pass
-            hit = (jax.lax.broadcasted_iota(jnp.int32, sc_c.shape, 1)
-                   == am[:, None])
-            return jnp.where(hit, -jnp.inf, sc_c)
-
-        jax.lax.fori_loop(0, cand, body, sc)
-
-    def fn(u, tiles, scales, n_items):
-        nt, t, r = tiles.shape
-        b = u.shape[0]
-        n_arr = jnp.full((1,), n_items, jnp.int32)
-        return pl.pallas_call(
-            kernel,
-            grid=(nt,),
-            in_specs=[
-                pl.BlockSpec((1,), lambda i: (0,)),
-                pl.BlockSpec((b, r), lambda i: (0, 0)),
-                pl.BlockSpec((1, t, r), lambda i: (i, 0, 0)),
-                pl.BlockSpec((1, t), lambda i: (i, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, b, cand), lambda i: (i, 0, 0)),
-                pl.BlockSpec((1, b, cand), lambda i: (i, 0, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((nt, b, cand), jnp.float32),
-                jax.ShapeDtypeStruct((nt, b, cand), jnp.int32),
-            ],
-            interpret=interpret,
-        )(n_arr, u, tiles, scales)
-
-    return fn

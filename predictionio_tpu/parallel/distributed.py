@@ -65,21 +65,13 @@ def _enable_cpu_collectives(jax) -> None:
     """Multi-process runs on the CPU backend need a cross-process
     collectives transport: without one, the first computation over a
     cross-process mesh dies with XLA's "Multiprocess computations aren't
-    implemented on the CPU backend". Newer jaxlibs ship a Gloo transport
-    behind ``jax_cpu_collectives_implementation``; select it BEFORE the
-    backend initializes (a no-op on TPU — the flag only affects the CPU
-    client). Best-effort: older jax versions without the flag keep their
-    previous behavior."""
+    implemented on the CPU backend". Select jaxlib's Gloo transport
+    BEFORE the backend initializes (a no-op on TPU — the flag only
+    affects the CPU client)."""
     if os.environ.get("JAX_PLATFORMS", "").lower() not in ("", "cpu"):
         return
-    for flag, value in (("jax_cpu_collectives_implementation", "gloo"),
-                        ("jax_cpu_enable_gloo_collectives", True)):
-        try:
-            jax.config.update(flag, value)
-            logger.info("CPU collectives transport: %s=%r", flag, value)
-            return
-        except (AttributeError, ValueError):
-            continue
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
+    logger.info("CPU collectives transport: gloo")
 
 
 def resolve_worker(rank: Optional[int] = None,

@@ -125,7 +125,7 @@ def _all_to_all(send: np.ndarray) -> np.ndarray:
     nproc, m, kk = send.shape
 
     def build():
-        from predictionio_tpu.parallel.compat import shard_map
+        from jax import shard_map
 
         def step(x):        # local block [1, nproc, m, kk]
             return jax.lax.all_to_all(

@@ -46,6 +46,7 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import logging
+import subprocess
 import time
 from typing import Callable, Dict, Optional
 
@@ -197,6 +198,10 @@ class Router:
         self._session: Optional[aiohttp.ClientSession] = None
         self._health_task: Optional[asyncio.Task] = None
         self._fleet_task: Optional[asyncio.Task] = None
+        self._startup_task: Optional[asyncio.Task] = None
+        #: why the router gave up at start-up (`run_router` exits
+        #: non-zero with it); None while the fleet is fine
+        self.fatal: Optional[str] = None
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._qps_sample = (time.monotonic(), 0.0)
 
@@ -301,12 +306,15 @@ class Router:
         if self._spawn is not None and not self.replicas:
             for rank in range(self.cfg.replicas):
                 await self.grow(wait_healthy=False)
+            self._startup_task = self._loop.create_task(
+                self._startup_watch())
         self._health_task = self._loop.create_task(self._health_loop())
         if self.fleet is not None:
             self._fleet_task = self._loop.create_task(self._fleet_loop())
 
     async def _on_cleanup(self, app) -> None:
-        for task in (self._health_task, self._fleet_task):
+        for task in (self._health_task, self._fleet_task,
+                     self._startup_task):
             if task is not None:
                 task.cancel()
                 try:
@@ -368,6 +376,37 @@ class Router:
                     f"within {SPAWN_HEALTHY_TIMEOUT_S:g}s")
         return rank
 
+    async def _startup_watch(self) -> None:
+        """The initial fleet must come up or the router gives up loudly:
+        a spawned replica that EXITS before its first healthy probe (it
+        could not load its model, or could not claim its device — one
+        chip serves one process), or that is still not healthy after
+        `SPAWN_HEALTHY_TIMEOUT_S`, is a start-up failure, not something
+        to probe with back-off forever. Replicas that die after having
+        been healthy stay the health loop's business (eject, re-admit)."""
+        deadline = time.monotonic() + SPAWN_HEALTHY_TIMEOUT_S
+        waiting = [h for h in self.replicas.values() if h.proc is not None]
+        while waiting:
+            waiting = [h for h in waiting if not h.healthy]
+            late = time.monotonic() > deadline
+            for handle in waiting:
+                rc = handle.proc.poll()
+                if rc is not None:
+                    self.fatal = (
+                        f"replica {handle.rank} ({handle.url}) exited "
+                        f"with code {rc} before it ever became healthy "
+                        "(its own output above says why)")
+                elif late:
+                    self.fatal = (
+                        f"replica {handle.rank} ({handle.url}) is not "
+                        f"healthy after {SPAWN_HEALTHY_TIMEOUT_S:g}s")
+                if self.fatal is not None:
+                    logger.error("router start-up failed: %s; stopping "
+                                 "the fleet", self.fatal)
+                    self._loop.call_soon(_raise_shutdown)
+                    return
+            await asyncio.sleep(0.2)
+
     async def wait_replica_healthy(
             self, rank: int,
             timeout_s: float = SPAWN_HEALTHY_TIMEOUT_S) -> bool:
@@ -419,10 +458,18 @@ class Router:
                 logger.exception("replica %d stop hook failed",
                                  handle.rank)
         elif handle.proc is not None:
+            wait = asyncio.get_running_loop().run_in_executor
             try:
                 handle.proc.terminate()
-                await asyncio.get_running_loop().run_in_executor(
-                    None, handle.proc.wait, 10)
+                try:
+                    await wait(None, handle.proc.wait, 10)
+                except subprocess.TimeoutExpired:
+                    # a replica holding a TPU can take longer than this
+                    # to tear its runtime down; it must not outlive us
+                    logger.warning("replica %d ignored SIGTERM for 10s; "
+                                   "killing it", handle.rank)
+                    handle.proc.kill()
+                    await wait(None, handle.proc.wait, 10)
             except Exception:
                 logger.exception("replica %d terminate failed",
                                  handle.rank)
@@ -761,6 +808,10 @@ class FleetRouterActuator:
             timeout=self._router.cfg.drain_timeout_s + 30)
 
 
+def _raise_shutdown():
+    raise web.GracefulExit()
+
+
 def run_router(config: Optional[RouterConfig] = None,
                ip: str = "localhost",
                spawn: Optional[Callable] = None,
@@ -797,3 +848,5 @@ def run_router(config: Optional[RouterConfig] = None,
     logger.info("Router listening on %s:%s over %d replica(s)", ip,
                 cfg.port, max(len(router.replicas), cfg.replicas))
     web.run_app(router.app, host=ip, port=cfg.port, print=None)
+    if router.fatal is not None:
+        raise SystemExit(f"[ERROR] Router start-up failed: {router.fatal}")

@@ -89,8 +89,11 @@ class WorkflowContext:
         n = 1
         for s in shape:
             n *= s
+        from predictionio_tpu.utils.device import describe_devices
+
         arr = np.asarray(devices[:n]).reshape(shape)
-        logger.info("mesh: shape=%s axes=%s over %d device(s)", shape, axes, n)
+        logger.info("mesh: shape=%s axes=%s %s", shape, axes,
+                    describe_devices(devices[:n]))
         return Mesh(arr, axis_names=axes)
 
     @property

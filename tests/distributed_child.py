@@ -209,10 +209,6 @@ def main() -> None:
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
 
-    from predictionio_tpu.utils.config import honor_jax_platforms
-
-    honor_jax_platforms()
-
     from predictionio_tpu.parallel.distributed import (
         initialize_distributed, process_count, process_index)
 

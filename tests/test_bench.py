@@ -598,13 +598,32 @@ def test_every_bench_config_has_smoke():
 def test_bench_survives_wedged_worker_and_reports_partial(tmp_path):
     """A config that hangs its worker (the hidden _sleep_forever wedge
     simulator, budget 15s) must not take down the suite: the next config
-    still runs on a fresh worker and the final line still prints."""
+    still runs on a fresh worker of the SAME platform and the final line
+    still prints — but a suite with a hole exits non-zero."""
     p = _run("_sleep_forever,naive_bayes_spam", "300", timeout=280,
              tmp_path=tmp_path)
-    assert p.returncode == 0, p.stderr[-2000:]
+    assert p.returncode != 0, p.stderr[-2000:]
     lines = [ln for ln in p.stdout.strip().splitlines() if ln.strip()]
     assert len(lines) == 1
     out = json.loads(lines[0])
     assert "naive_bayes_spam" in out["unit"]      # measured despite wedge
     assert "1/2" in out["unit"]                   # and the hole is visible
     assert "TIMEOUT" in p.stderr
+    doc = json.load(open(tmp_path / "details.json"))
+    # both attempts (first worker + the one retry) are on record
+    assert {f["name"] for f in doc["failures"]} == {"_sleep_forever"}
+    # the platform never changed within the run
+    assert {d["platform"] for d in doc["details"]} == {"cpu"}
+
+
+def test_bench_without_the_requested_platform_fails_fast(tmp_path):
+    """No device of the requested platform -> non-zero exit, nothing
+    measured anywhere else (the old ladder carried on on the CPU and
+    wrote the result under the same keys)."""
+    p = _run("naive_bayes_spam", "300", timeout=120, tmp_path=tmp_path,
+             extra_env={"BENCH_PLATFORM": "tpu"})
+    assert p.returncode != 0
+    doc = json.load(open(tmp_path / "details.json"))
+    assert doc["details"] == []
+    assert doc["failures"] and all(
+        f["name"] == "_worker_init_tpu" for f in doc["failures"])

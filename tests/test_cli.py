@@ -257,6 +257,13 @@ def test_cli_deploy_serves_and_stops(clienv, tmp_path, monkeypatch):
         port = s.getsockname()[1]
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
+    # the child runs from tmp_path: put the checkout on its path so the
+    # test does not depend on the package being pip-installed
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = repo + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    # the server's durable telemetry lands under PIO_HOME: keep it here
+    env["PIO_HOME"] = str(tmp_path / "pio_home")
     # serve through the quantized kernel: the deploy must echo the
     # resolved scorer mode and /deploy/status.json must mirror it
     env["PIO_SCORER_MODE"] = "fused_int8"

@@ -88,6 +88,11 @@ def test_two_process_sharded_als_matches_single_process(tmp_path):
            if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
     env["PIO_DIST_STORE"] = store_dir
     env["PIO_DIST_DB"] = db_path
+    # the child runs as a script (sys.path[0] = tests/): put the checkout
+    # on its path so the test does not depend on a pip install
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = repo + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     procs = [
         subprocess.Popen(
             [sys.executable, CHILD, str(pid), "2", str(port)],

@@ -773,10 +773,22 @@ async def test_freshness_e2e_and_rollback(foldin_store):
         assert reflected2_at is not None
         assert reflected2_at - t1 <= 0.2 + 3.0
 
+        async def eventually(check, timeout_s=5.0):
+            """Registry status writes ride the server's background
+            lineage lane and the applied-row counters trail the swap, so
+            neither is settled the instant the HTTP answer is."""
+            deadline = time.monotonic() + timeout_s
+            while not (ok := await check()) \
+                    and time.monotonic() < deadline:
+                await asyncio.sleep(0.02)
+            return ok
+
         # status surfaces the loop
-        st = await (await qc.get("/deploy/status.json")).json()
-        assert st["foldin"]["enabled"] is True
-        assert st["foldin"]["appliedUserRows"] >= 2
+        async def applied_both():
+            st = await (await qc.get("/deploy/status.json")).json()
+            assert st["foldin"]["enabled"] is True
+            return st["foldin"]["appliedUserRows"] >= 2
+        assert await eventually(applied_both)
 
         # the drift is a registry revision over the base
         rels = Storage.get_meta_data_releases().get_for_variant(
@@ -794,10 +806,12 @@ async def test_freshness_e2e_and_rollback(foldin_store):
         assert await reflected("fresh1") == []
         assert await reflected("fresh2") == []
         assert qs._unit.result.models[0] is base_model
-        assert Storage.get_meta_data_releases().get(
-            drift.id).status == "ROLLED_BACK"
-        assert Storage.get_meta_data_releases().get(
-            base_release.id).status == "LIVE"
+
+        async def lineage_settled():
+            releases = Storage.get_meta_data_releases()
+            return (releases.get(drift.id).status == "ROLLED_BACK"
+                    and releases.get(base_release.id).status == "LIVE")
+        assert await eventually(lineage_settled)
     finally:
         await qc.close()
         await ec.close()

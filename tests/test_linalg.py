@@ -65,3 +65,7 @@ def test_dispatch_env_override(monkeypatch):
         monkeypatch.setenv("PIO_TPU_SOLVE", method)
         np.testing.assert_allclose(batched_spd_solve(A, b), x_ref,
                                    rtol=2e-4, atol=2e-4)
+    # the Pallas override never quietly runs the interpreter off-TPU
+    monkeypatch.setenv("PIO_TPU_SOLVE", "pallas")
+    with pytest.raises(RuntimeError, match="needs a TPU backend"):
+        batched_spd_solve(A, b)

@@ -188,7 +188,7 @@ def test_collectives_ring(mesh8):
     from jax.sharding import PartitionSpec as P
 
     from predictionio_tpu.parallel.collectives import psum, ring_pass, ring_reduce
-    from predictionio_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     def f(x):
         local = x.reshape(-1)

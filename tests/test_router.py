@@ -381,6 +381,52 @@ async def test_router_backoff_readmission_bounded_by_cap():
         await _close(client, stubs)
 
 
+async def test_router_startup_fails_loudly_when_spawned_replica_exits(
+        monkeypatch):
+    """A spawned replica that exits before its first healthy probe (on
+    one chip: the second `pio deploy` cannot claim the device) must stop
+    the router with a reason — not sit in the probe back-off forever."""
+    from predictionio_tpu.server import router as router_mod
+    from predictionio_tpu.server.router import ReplicaHandle
+
+    stubs, urls = await _stubs(1)
+
+    class Proc:
+        def __init__(self, rc):
+            self.rc = rc
+
+        def poll(self):
+            return self.rc
+
+        def terminate(self):
+            pass
+
+        def wait(self, timeout=None):
+            return self.rc
+
+    stopped = []
+    monkeypatch.setattr(router_mod, "_raise_shutdown",
+                        lambda: stopped.append(True))
+
+    def spawn(rank):
+        # replica 0 serves (its process is alive); replica 1 died at
+        # start-up with exit code 1
+        if rank == 0:
+            return ReplicaHandle(rank=0, url=urls[0], proc=Proc(None))
+        return ReplicaHandle(rank=1, url="http://127.0.0.1:9",
+                             proc=Proc(1))
+
+    router = Router(_rcfg(replicas=2), spawn=spawn)
+    client = TestClient(TestServer(router.app))
+    await client.start_server()
+    try:
+        assert await _wait_for(lambda: bool(stopped))
+        assert "replica 1" in router.fatal
+        assert "exited with code 1" in router.fatal
+    finally:
+        await _close(client, stubs)
+
+
 async def test_router_drain_is_zero_drop():
     """Scale-down discipline: weight to zero FIRST, the in-flight query
     runs to completion, THEN the replica detaches."""

@@ -647,30 +647,3 @@ def test_batchpredict_lane_parity_exact_vs_fused(tmp_path):
         outs[mode] = open(out, "rb").read()
     # byte-identical output: the fused f32 kernel IS the exact scorer
     assert outs["fused"] == outs["exact"]
-
-
-# ---------------------------------------------------------------------------
-# Pallas variant (interpret-mode parity against the lax.scan oracle)
-# ---------------------------------------------------------------------------
-
-def test_pallas_shortlist_interpret_parity():
-    pl = pytest.importorskip("jax.experimental.pallas")
-    assert pl is not None
-    tile, cand, rank = 128, 4, 8
-    V = _factors(256, k=rank, seed=60)
-    q, s = scoring._quantize_int8(V)
-    tiles = q.reshape(2, tile, rank)
-    scales = s.reshape(2, tile)
-    U = _factors(4, k=rank, seed=61)
-    try:
-        fn = scoring.build_pallas_shortlist(tile, cand, interpret=True)
-        vals, ids = fn(U, tiles, scales, 256)
-    except Exception as e:       # pragma: no cover - backend-dependent
-        pytest.skip(f"pallas interpret unavailable here: {e!r}")
-    vals, ids = np.asarray(vals), np.asarray(ids)
-    # oracle: per-tile local top-c on dequantized scores
-    for t in range(2):
-        sc = (U @ tiles[t].T.astype(np.float32)) * scales[t][None, :]
-        ref_v, ref_local = host_topk(sc, cand)
-        assert np.allclose(np.asarray(vals)[t], ref_v, rtol=1e-5)
-        assert (np.asarray(ids)[t] == ref_local + t * tile).all()

@@ -22,6 +22,7 @@ from predictionio_tpu.core.base import (
     SanityCheck, Serving, instantiate, load_class, params_class_of,
 )
 from predictionio_tpu.core.params import EngineParams, engine_params_from_json
+from predictionio_tpu.obs.tracing import span
 
 logger = logging.getLogger("pio.engine")
 
@@ -131,13 +132,15 @@ class Engine:
               stop_after_read: bool = False,
               stop_after_prepare: bool = False) -> TrainResult:
         data_source = self._data_source(engine_params)
-        td = data_source.read_training(ctx)
+        with span("train_read"):
+            td = data_source.read_training(ctx)
         _sanity(td, "training data", skip_sanity_check)
         if stop_after_read:
             raise StopAfterReadInterruption(td)
 
         preparator = self._preparator(engine_params)
-        pd = preparator.prepare(ctx, td)
+        with span("train_prepare"):
+            pd = preparator.prepare(ctx, td)
         _sanity(pd, "prepared data", skip_sanity_check)
         if stop_after_prepare:
             raise StopAfterPrepareInterruption(pd)
@@ -154,7 +157,8 @@ class Engine:
                 ctx.checkpointer = shared_ckpt.scoped(
                     f"algo_{i}_{name or type(algo).__name__}")
             try:
-                model = algo.train(ctx, pd)
+                with span("train_algorithm"):
+                    model = algo.train(ctx, pd)
             finally:
                 if shared_ckpt is not None:
                     ctx.checkpointer = shared_ckpt

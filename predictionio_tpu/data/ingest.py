@@ -20,14 +20,17 @@ Three concerns live here so the six engines share one implementation:
   repeated folds of `pio eval` (k-fold re-reads) and back-to-back
   `pio train` runs skip the rescan when the store hasn't changed.
   Disable with ``PIO_INGEST_CACHE=0``.
-* **`pio_ingest_*` metrics** — rows scanned, rows/s, cache hit/miss
-  counters on the process registry, plus ``ingest_scan`` /
-  ``ingest_intern`` / ``ingest_assemble`` spans through the obs span
-  histogram (OBSERVABILITY.md inventory).
+* **`pio_ingest_*` metrics** — rows scanned and decoded, rows/s, cache
+  hit/miss counters on the process registry, plus ``ingest_digest`` /
+  ``ingest_scan`` / ``ingest_decode`` / ``ingest_intern`` /
+  ``ingest_assemble`` spans,
+  which reach the span histogram of the job they run under
+  (OBSERVABILITY.md inventory).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import threading
@@ -95,6 +98,17 @@ def _cache_put(key, value) -> None:
         if len(_scan_cache) >= _CACHE_MAX and key not in _scan_cache:
             _scan_cache.pop(next(iter(_scan_cache)))
         _scan_cache[key] = value
+
+
+def _snapshot_digest(app_name: str, channel_name: Optional[str]):
+    """The scan-cache key's content fingerprint, under an
+    ``ingest_digest`` span: on sqlite it is a COUNT and a MAX over the
+    whole event table, which a read pays whether or not the cache then
+    hits."""
+    from predictionio_tpu.data.eventstore import EventStoreClient
+
+    with span("ingest_digest"):
+        return EventStoreClient.snapshot_digest(app_name, channel_name)
 
 
 @dataclasses.dataclass
@@ -176,7 +190,7 @@ def training_scan(app_name: str, channel_name: Optional[str] = None, *,
 
     key = None
     if cache and _cache_enabled():
-        digest = EventStoreClient.snapshot_digest(app_name, channel_name)
+        digest = _snapshot_digest(app_name, channel_name)
         if digest is not None:
             key = (app_name, channel_name, digest,
                    shard[:2] if shard else None,
@@ -189,7 +203,7 @@ def training_scan(app_name: str, channel_name: Optional[str] = None, *,
                                     replicated=replicated)
 
     t0 = time.perf_counter()
-    with span("ingest_scan", registry=_registry()):
+    with span("ingest_scan"):
         table = EventStoreClient.find_columnar(
             app_name=app_name, channel_name=channel_name, shard=shard,
             **filters)
@@ -211,14 +225,14 @@ def aggregate_scan(app_name: str, entity_type: str,
 
     key = None
     if cache and _cache_enabled():
-        digest = EventStoreClient.snapshot_digest(app_name, channel_name)
+        digest = _snapshot_digest(app_name, channel_name)
         if digest is not None:
             key = ("aggregate", app_name, channel_name, entity_type,
                    tuple(required) if required else None, digest)
             hit = _cache_get(app_name, key)
             if hit is not None:
                 return dict(hit)
-    with span("ingest_aggregate", registry=_registry()):
+    with span("ingest_aggregate"):
         out = EventStoreClient.aggregate_properties(
             app_name, entity_type, channel_name=channel_name,
             required=required)
@@ -226,6 +240,21 @@ def aggregate_scan(app_name: str, entity_type: str,
         _cache_put(key, out)
         return dict(out)
     return out
+
+
+@contextlib.contextmanager
+def decoding(app_name: str, table):
+    """The ``ingest_decode`` span of a training read: wrap the block that
+    turns a scanned table into NumPy columns (`event_columns`,
+    `columnar.property_column`, `TrainingScan.local_slice`); counts the
+    table's rows into ``pio_ingest_decoded_rows_total``."""
+    with span("ingest_decode"):
+        yield
+    _registry().counter(
+        "pio_ingest_decoded_rows_total",
+        "Event rows a training read decoded from a scanned table into "
+        "NumPy columns (a scan-cache hit decodes without scanning)",
+        labelnames=("app",)).inc(table.num_rows, app=app_name)
 
 
 def event_columns(table, *names) -> Tuple[np.ndarray, ...]:
@@ -255,7 +284,7 @@ def intern_pairs(users: np.ndarray, items: np.ndarray):
     build without per-row dict hits, under an ``ingest_intern`` span."""
     from predictionio_tpu.data.bimap import assign_indices
 
-    with span("ingest_intern", registry=_registry()):
+    with span("ingest_intern"):
         user_vocab, user_codes = assign_indices(users)
         item_vocab, item_codes = assign_indices(items)
     return user_vocab, user_codes, item_vocab, item_codes
@@ -272,7 +301,7 @@ def pair_counts(users: np.ndarray, items: np.ndarray,
     if len(users) == 0:
         return (np.empty(0, object), np.empty(0, object),
                 np.empty(0, np.float32))
-    with span("ingest_assemble", registry=_registry()):
+    with span("ingest_assemble"):
         user_vocab, ucodes, item_vocab, icodes = (
             intern_pairs(users, items))
         combined = ucodes.astype(np.int64) * len(item_vocab) + icodes
@@ -295,7 +324,7 @@ def latest_per_pair(users: np.ndarray, items: np.ndarray,
     Returns (users', items', values') for the distinct pairs."""
     if len(users) == 0:
         return users, items, values
-    with span("ingest_assemble", registry=_registry()):
+    with span("ingest_assemble"):
         user_vocab, ucodes, item_vocab, icodes = (
             intern_pairs(users, items))
         combined = ucodes.astype(np.int64) * len(item_vocab) + icodes
@@ -314,7 +343,7 @@ def sessions_by_entity(users: np.ndarray, items: np.ndarray,
     sorted-user order (the row path's ``sorted(by_user)`` contract)."""
     if len(users) == 0:
         return []
-    with span("ingest_assemble", registry=_registry()):
+    with span("ingest_assemble"):
         from predictionio_tpu.data.bimap import assign_indices
 
         _, codes = assign_indices(users)

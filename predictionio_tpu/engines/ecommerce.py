@@ -89,7 +89,7 @@ class ECommerceDataSource(DataSource):
 
     def read_training(self, ctx) -> TrainingData:
         from predictionio_tpu.data.ingest import (
-            aggregate_scan, event_columns, training_scan,
+            aggregate_scan, decoding, event_columns, training_scan,
         )
 
         app = self.params.app_name
@@ -101,8 +101,9 @@ class ECommerceDataSource(DataSource):
             app, entity_type="user", event_names=["view", "buy"],
             target_entity_type="item",
             columns=("event", "entity_id", "target_entity_id"))
-        events, u, i = event_columns(
-            scan.table, "event", "entity_id", "target_entity_id")
+        with decoding(app, scan.table):
+            events, u, i = event_columns(
+                scan.table, "event", "entity_id", "target_entity_id")
         is_view = events == "view"
         return TrainingData(
             users=users, items=items,

@@ -34,6 +34,8 @@ from predictionio_tpu.data.bimap import assign_indices
 from predictionio_tpu.data.eventstore import EventStoreClient
 from predictionio_tpu.engines.common import resolved_als_solver
 from predictionio_tpu.models.als import ALSData, ALSModel, ALSParams, train_als
+from predictionio_tpu.obs.tracing import span
+from predictionio_tpu.obs.train_stats import als_entities
 
 logger = logging.getLogger("pio.engine.recommendation")
 
@@ -174,7 +176,9 @@ class RecommendationDataSource(DataSource):
         owners over the interconnect (models/als.build_distributed) — no
         process materializes the full event set."""
         from predictionio_tpu.data.columnar import property_column
-        from predictionio_tpu.data.ingest import event_columns, training_scan
+        from predictionio_tpu.data.ingest import (
+            decoding, event_columns, training_scan,
+        )
 
         names = self.params.event_names or ["rate", "buy"]
         weights = {**self.DEFAULT_WEIGHTS, **(self.params.event_weights or {})}
@@ -190,37 +194,38 @@ class RecommendationDataSource(DataSource):
             columns=("event", "entity_id", "target_entity_id",
                      "properties"))
         table = scan.table
-        events, users, items = event_columns(
-            table, "event", "entity_id", "target_entity_id")
-        is_rate = events == "rate"
-        values = np.empty(len(events), np.float32)
-        for name in set(events.tolist()):
-            if name != "rate":
-                values[events == name] = float(weights.get(name, 1.0))
-        if is_rate.any():
-            import pyarrow as pa
+        with decoding(self.params.app_name, table):
+            events, users, items = event_columns(
+                table, "event", "entity_id", "target_entity_id")
+            is_rate = events == "rate"
+            values = np.empty(len(events), np.float32)
+            for name in set(events.tolist()):
+                if name != "rate":
+                    values[events == name] = float(weights.get(name, 1.0))
+            if is_rate.any():
+                import pyarrow as pa
 
-            # parse ONLY the rate rows' properties (a mostly-implicit
-            # event log would otherwise json-parse millions of rows whose
-            # value the mask immediately discards)
-            values[is_rate] = property_column(
-                table.filter(pa.array(is_rate)), "rating")
-        bad = bool(np.isnan(values[is_rate]).any())
-        if jax.process_count() > 1:
-            # data errors live in ONE process's shard; the raise must be
-            # COLLECTIVE or the erroring process dies while its peers
-            # block forever in the training collectives downstream
-            from predictionio_tpu.parallel.shuffle import allgather_object
+                # parse ONLY the rate rows' properties (a mostly-implicit
+                # event log would otherwise json-parse millions of rows whose
+                # value the mask immediately discards)
+                values[is_rate] = property_column(
+                    table.filter(pa.array(is_rate)), "rating")
+            bad = bool(np.isnan(values[is_rate]).any())
+            if jax.process_count() > 1:
+                # data errors live in ONE process's shard; the raise must be
+                # COLLECTIVE or the erroring process dies while its peers
+                # block forever in the training collectives downstream
+                from predictionio_tpu.parallel.shuffle import allgather_object
 
-            bad = any(allgather_object(bad))
-        if bad:
-            raise ValueError(
-                "rate event without a rating property "
-                "(DataSource.scala:66 MatchError parity)")
-        # replicated fallback (backend couldn't partition): keep a
-        # disjoint strided slice so the distributed build's
-        # exchange-by-owner sees each rating exactly once
-        users, items, values = scan.local_slice((users, items, values))
+                bad = any(allgather_object(bad))
+            if bad:
+                raise ValueError(
+                    "rate event without a rating property "
+                    "(DataSource.scala:66 MatchError parity)")
+            # replicated fallback (backend couldn't partition): keep a
+            # disjoint strided slice so the distributed build's
+            # exchange-by-owner sees each rating exactly once
+            users, items, values = scan.local_slice((users, items, values))
         return RatingColumns(users=users, items=items, values=values)
 
     def read_training(self, ctx) -> TrainingData:
@@ -316,18 +321,25 @@ class ALSAlgorithm(Algorithm):
             from predictionio_tpu.models.als import build_distributed
             from predictionio_tpu.parallel.shuffle import global_vocab
 
-            user_vocab = global_vocab(np.asarray(users))
-            item_vocab = global_vocab(np.asarray(items))
-            user_codes = np.searchsorted(user_vocab, users).astype(np.int32)
-            item_codes = np.searchsorted(item_vocab, items).astype(np.int32)
+            with span("train_id_assign"):
+                user_vocab = global_vocab(np.asarray(users))
+                item_vocab = global_vocab(np.asarray(items))
+                user_codes = np.searchsorted(
+                    user_vocab, users).astype(np.int32)
+                item_codes = np.searchsorted(
+                    item_vocab, items).astype(np.int32)
             data = build_distributed(mesh, user_codes, item_codes, values,
                                      len(user_vocab), len(item_vocab))
         else:
-            user_vocab, user_codes = assign_indices(users)
-            item_vocab, item_codes = assign_indices(items)
+            with span("train_id_assign"):
+                user_vocab, user_codes = assign_indices(users)
+                item_vocab, item_codes = assign_indices(items)
             n_shards = int(np.prod(mesh.devices.shape))
             data = ALSData.build(user_codes, item_codes, values,
                                  len(user_vocab), len(item_vocab), n_shards)
+        entities = als_entities()
+        entities.set(len(user_vocab), side="user")
+        entities.set(len(item_vocab), side="item")
         solver, block_size = resolved_als_solver(self.params, logger)
         als_params = ALSParams(
             rank=self.params.rank,
@@ -458,7 +470,6 @@ class ALSAlgorithm(Algorithm):
         from predictionio_tpu.models.als_sweep import (
             build_sweep_data, run_sweep,
         )
-        from predictionio_tpu.obs.tracing import span
 
         cols = grid.data
         fold_of = fold_assignments(grid.k_fold, len(cols))

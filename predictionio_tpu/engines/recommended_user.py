@@ -123,7 +123,7 @@ class RecommendedUserDataSource(DataSource):
 
     def read_training(self, ctx) -> TrainingData:
         from predictionio_tpu.data.ingest import (
-            aggregate_scan, event_columns, training_scan,
+            aggregate_scan, decoding, event_columns, training_scan,
         )
 
         app = self.params.app_name
@@ -133,8 +133,10 @@ class RecommendedUserDataSource(DataSource):
             app, entity_type="user", event_names=["follow"],
             target_entity_type="user",
             columns=("entity_id", "target_entity_id", "event_time_ms"))
-        u, f, t = event_columns(
-            scan.table, "entity_id", "target_entity_id", "event_time_ms")
+        with decoding(app, scan.table):
+            u, f, t = event_columns(
+                scan.table, "entity_id", "target_entity_id",
+                "event_time_ms")
         return TrainingData(users=users,
                             follows=FollowColumns(u, f, t))
 

@@ -94,7 +94,7 @@ class SessionDataSource(DataSource):
 
     def _read_sessions(self) -> List[List[str]]:
         from predictionio_tpu.data.ingest import (
-            event_columns, sessions_by_entity, training_scan,
+            decoding, event_columns, sessions_by_entity, training_scan,
         )
 
         scan = training_scan(
@@ -103,8 +103,10 @@ class SessionDataSource(DataSource):
             event_names=list(self.params.event_names),
             target_entity_type="item",
             columns=("entity_id", "target_entity_id", "event_time_ms"))
-        users, items, times = event_columns(
-            scan.table, "entity_id", "target_entity_id", "event_time_ms")
+        with decoding(self.params.app_name, scan.table):
+            users, items, times = event_columns(
+                scan.table, "entity_id", "target_entity_id",
+                "event_time_ms")
         return sessions_by_entity(users, items, times)
 
     def read_training(self, ctx) -> TrainingData:

@@ -112,7 +112,7 @@ class SimilarProductDataSource(DataSource):
 
     def read_training(self, ctx) -> TrainingData:
         from predictionio_tpu.data.ingest import (
-            aggregate_scan, event_columns, training_scan,
+            aggregate_scan, decoding, event_columns, training_scan,
         )
 
         app = self.params.app_name
@@ -128,9 +128,10 @@ class SimilarProductDataSource(DataSource):
             target_entity_type="item",
             columns=("event", "entity_id", "target_entity_id",
                      "event_time_ms"))
-        events, u, i, t = event_columns(
-            scan.table, "event", "entity_id", "target_entity_id",
-            "event_time_ms")
+        with decoding(app, scan.table):
+            events, u, i, t = event_columns(
+                scan.table, "event", "entity_id", "target_entity_id",
+                "event_time_ms")
         is_view = events == "view"
         return TrainingData(
             users=users, items=items,

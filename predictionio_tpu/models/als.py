@@ -34,6 +34,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.core.params import Params
 from predictionio_tpu.data.bimap import vocab_index
+from predictionio_tpu.obs import jax_stats, train_stats
+from predictionio_tpu.obs.tracing import span
 from predictionio_tpu.ops.bucketing import bucket_size, pad_rows as _pad_rows
 from predictionio_tpu.ops.fn_cache import shape_cached_fn
 from predictionio_tpu.ops.linalg import batched_spd_solve
@@ -264,16 +266,19 @@ class ALSData:
     def build(cls, user_idx: np.ndarray, item_idx: np.ndarray,
               ratings: np.ndarray, n_users: int, n_items: int,
               n_shards: int, row_len: Optional[int] = None) -> "ALSData":
-        by_user = shard_rows(user_idx, item_idx, ratings, n_users, n_shards,
-                             row_len=row_len)
-        by_item = shard_rows(item_idx, user_idx, ratings, n_items, n_shards,
-                             row_len=row_len)
-        return cls(by_user=by_user, by_item=by_item,
-                   n_users=n_users, n_items=n_items,
-                   n_users_pad=by_user.n_segments,
-                   n_items_pad=by_item.n_segments,
-                   nnz=int(len(ratings)),
-                   digest=coo_digest(user_idx, item_idx, ratings))
+        with span("als_pack"):
+            by_user = shard_rows(user_idx, item_idx, ratings, n_users,
+                                 n_shards, row_len=row_len)
+            by_item = shard_rows(item_idx, user_idx, ratings, n_items,
+                                 n_shards, row_len=row_len)
+            data = cls(by_user=by_user, by_item=by_item,
+                       n_users=n_users, n_items=n_items,
+                       n_users_pad=by_user.n_segments,
+                       n_items_pad=by_item.n_segments,
+                       nnz=int(len(ratings)),
+                       digest=coo_digest(user_idx, item_idx, ratings))
+        train_stats.observe_row_fill(data)
+        return data
 
     def put(self, mesh: Mesh) -> "ALSData":
         """Commit the row arrays to the mesh ONCE (sharded over "data",
@@ -286,6 +291,21 @@ class ALSData:
         from each process's local shard rows without gathering anywhere
         (SURVEY §2.9 P2 sharded input loading; the JdbcRDD-partition
         analog)."""
+        row_sh = NamedSharding(mesh, P("data", None, None))
+        seg_sh = NamedSharding(mesh, P("data", None))
+        moving = [a for rows in (self.by_user, self.by_item) for a, sh in (
+            (rows.tgt, row_sh), (rows.val, row_sh), (rows.w, row_sh),
+            (rows.seg, seg_sh))
+            if not (isinstance(a, jax.Array) and a.sharding == sh)]
+        if not moving:
+            return self             # already resident HERE (idempotent)
+        with span("als_put"):
+            out = self._put(mesh, row_sh, seg_sh)
+        train_stats.als_put_bytes().inc(sum(a.nbytes for a in moving))
+        return out
+
+    def _put(self, mesh: Mesh, row_sh, seg_sh) -> "ALSData":
+        """The transfer itself, through its block_until_ready."""
         multiproc = jax.process_count() > 1
         if multiproc:
             # the local-slice math below requires the standard layouts:
@@ -316,8 +336,6 @@ class ALSData:
                 sharding, np.ascontiguousarray(arr[lo:hi]), arr.shape)
 
         def commit(rows: ShardedRows) -> ShardedRows:
-            row_sh = NamedSharding(mesh, P("data", None, None))
-            seg_sh = NamedSharding(mesh, P("data", None))
             return dataclasses.replace(
                 rows,
                 tgt=commit_one(rows.tgt, row_sh),
@@ -356,32 +374,41 @@ def _half_sweep_dyn(opposite: jax.Array, row_tgt, row_seg, row_val, row_w,
         # value * weight = c * p exactly. alpha == 0 degenerates to c = 1
         # (unweighted implicit), where the gram correction vanishes and the
         # rhs is a plain preference sum — use a direct pass for that case.
-        gram_all = opposite.T @ opposite                 # [K, K] MXU
-        p = jnp.where(row_val > 0, 1.0, 0.0)
-        if alpha_is_zero:
-            gram, rhs, cnt = rows_gram_rhs(
-                opposite, row_tgt, row_seg, row_val=p, row_w=row_w,
-                num_segments=seg_per_shard, chunk_rows=chunk_rows)
-            gram = jnp.zeros_like(gram)  # (c-1) = 0; keep only the rhs
-        else:
-            cm1 = alpha * jnp.abs(row_val)               # c - 1
-            vals = jnp.where(cm1 > 0,
-                             (1.0 + cm1) * p / jnp.maximum(cm1, 1e-12), 0.0)
-            gram, rhs, _ = rows_gram_rhs(
-                opposite, row_tgt, row_seg,
-                row_val=vals, row_w=row_w * cm1,
-                num_segments=seg_per_shard, chunk_rows=chunk_rows)
-            cnt = segment_count(row_seg, row_w.sum(axis=1), seg_per_shard)
-        A = gram_all[None, :, :] + gram
+        with jax.named_scope("als_gram"):
+            gram_all = opposite.T @ opposite             # [K, K] MXU
+            p = jnp.where(row_val > 0, 1.0, 0.0)
+            if alpha_is_zero:
+                gram, rhs, cnt = rows_gram_rhs(
+                    opposite, row_tgt, row_seg, row_val=p, row_w=row_w,
+                    num_segments=seg_per_shard, chunk_rows=chunk_rows)
+                gram = jnp.zeros_like(gram)  # (c-1) = 0; keep only the rhs
+            else:
+                cm1 = alpha * jnp.abs(row_val)           # c - 1
+                vals = jnp.where(
+                    cm1 > 0, (1.0 + cm1) * p / jnp.maximum(cm1, 1e-12), 0.0)
+                gram, rhs, _ = rows_gram_rhs(
+                    opposite, row_tgt, row_seg,
+                    row_val=vals, row_w=row_w * cm1,
+                    num_segments=seg_per_shard, chunk_rows=chunk_rows)
+                cnt = segment_count(row_seg, row_w.sum(axis=1),
+                                    seg_per_shard)
+        with jax.named_scope("als_reg"):
+            A = gram_all[None, :, :] + gram
+            lam = reg * jnp.where(weighted_reg, jnp.maximum(cnt, 1.0), 1.0)
+            A = A + lam[:, None, None] * jnp.eye(opposite.shape[1],
+                                                 dtype=A.dtype)
+        with jax.named_scope("als_solve"):
+            return batched_spd_solve(A, rhs)
+    with jax.named_scope("als_gram"):
+        gram, rhs, cnt = rows_gram_rhs(
+            opposite, row_tgt, row_seg, row_val=row_val, row_w=row_w,
+            num_segments=seg_per_shard, chunk_rows=chunk_rows)
+    with jax.named_scope("als_reg"):
         lam = reg * jnp.where(weighted_reg, jnp.maximum(cnt, 1.0), 1.0)
-        A = A + lam[:, None, None] * jnp.eye(opposite.shape[1], dtype=A.dtype)
+        A = gram + lam[:, None, None] * jnp.eye(opposite.shape[1],
+                                                dtype=gram.dtype)
+    with jax.named_scope("als_solve"):
         return batched_spd_solve(A, rhs)
-    gram, rhs, cnt = rows_gram_rhs(
-        opposite, row_tgt, row_seg, row_val=row_val, row_w=row_w,
-        num_segments=seg_per_shard, chunk_rows=chunk_rows)
-    lam = reg * jnp.where(weighted_reg, jnp.maximum(cnt, 1.0), 1.0)
-    A = gram + lam[:, None, None] * jnp.eye(opposite.shape[1], dtype=gram.dtype)
-    return batched_spd_solve(A, rhs)
 
 
 def _half_sweep(opposite: jax.Array, row_tgt, row_seg, row_val, row_w,
@@ -447,10 +474,12 @@ def _half_sweep_subspace_dyn(x_prev: jax.Array, opposite: jax.Array,
     chunk_b = chunk_rows * max(1, k // b)
 
     # ---- per-half-sweep cache: built once, reused by every block solve
-    cnt = segment_count(row_seg, row_w.sum(axis=1), seg_per_shard)
-    lam = reg * jnp.where(weighted_reg, jnp.maximum(cnt, 1.0), 1.0)
+    with jax.named_scope("als_reg"):
+        cnt = segment_count(row_seg, row_w.sum(axis=1), seg_per_shard)
+        lam = reg * jnp.where(weighted_reg, jnp.maximum(cnt, 1.0), 1.0)
     if implicit_prefs:
-        gram_all = _global_gram(opposite, axis, mesh_shards)   # [K, K]
+        with jax.named_scope("als_gram"):
+            gram_all = _global_gram(opposite, axis, mesh_shards)  # [K, K]
         p = jnp.where(row_val > 0, 1.0, 0.0)
         if alpha_is_zero:
             # c = 1 everywhere: the per-rating Gramian term vanishes
@@ -474,18 +503,21 @@ def _half_sweep_subspace_dyn(x_prev: jax.Array, opposite: jax.Array,
     for j, s in enumerate(starts):
         f_b = jax.lax.slice_in_dim(opposite, s, s + b, axis=1)
         x_b = jax.lax.slice_in_dim(x, s, s + b, axis=1)
-        gram, rhs = block_gram_rhs(
-            f_b, x_b, row_tgt, row_seg, pred, rhs_val, gram_w,
-            num_segments=seg_per_shard, chunk_rows=chunk_b)
-        if implicit_prefs:
-            # dense all-items term from the CACHED global Gramian:
-            # A += G[B,B]; rhs -= (x G)[:,B] - x_B G[B,B]
-            g_col = jax.lax.slice_in_dim(gram_all, s, s + b, axis=1)
-            g_bb = jax.lax.slice_in_dim(g_col, s, s + b, axis=0)
-            gram = gram + g_bb[None, :, :]
-            rhs = rhs - (x @ g_col - x_b @ g_bb)
-        A = gram + lam[:, None, None] * eye_b
-        y = batched_spd_solve(A, rhs)
+        with jax.named_scope("als_gram"):
+            gram, rhs = block_gram_rhs(
+                f_b, x_b, row_tgt, row_seg, pred, rhs_val, gram_w,
+                num_segments=seg_per_shard, chunk_rows=chunk_b)
+            if implicit_prefs:
+                # dense all-items term from the CACHED global Gramian:
+                # A += G[B,B]; rhs -= (x G)[:,B] - x_B G[B,B]
+                g_col = jax.lax.slice_in_dim(gram_all, s, s + b, axis=1)
+                g_bb = jax.lax.slice_in_dim(g_col, s, s + b, axis=0)
+                gram = gram + g_bb[None, :, :]
+                rhs = rhs - (x @ g_col - x_b @ g_bb)
+        with jax.named_scope("als_reg"):
+            A = gram + lam[:, None, None] * eye_b
+        with jax.named_scope("als_solve"):
+            y = batched_spd_solve(A, rhs)
         if j + 1 < len(starts):
             # fold this block's delta into the running predictions (the
             # LAST block's update feeds nothing, so skip its pass)
@@ -659,10 +691,10 @@ def _cached_train_fn(mesh: Mesh, data_dims, params: ALSParams,
     compiled program). Registered in the shared `ops/fn_cache` ledger so
     training compiles surface as ``pio_jax_compile_total{family=
     als_train}``, with the same bounded-LRU protection for long-running
-    servers retraining on growing data. Returns (fn, fresh) — fresh
-    meaning this fetch BUILT the fn, so its first dispatch will
-    trace+compile."""
-    from predictionio_tpu.ops.fn_cache import family_keys, mesh_cached_fn
+    servers retraining on growing data. The key leaves out the padded
+    row count, so it says nothing about whether a dispatch compiles:
+    `train_als` asks the compiler's own count for that."""
+    from predictionio_tpu.ops.fn_cache import mesh_cached_fn
 
     def build():
         if chunk_iters is None:
@@ -677,10 +709,7 @@ def _cached_train_fn(mesh: Mesh, data_dims, params: ALSParams,
     key_params = (dataclasses.replace(params, block_size=0)
                   if params.solver == "full" else params)
     key = (data_dims, dataclasses.astuple(key_params), chunk_iters)
-    # a fn fetched fresh has never been dispatched: its first call pays
-    # trace+compile, which the half-sweep timing metric must not count
-    fresh = (mesh, key) not in family_keys(TRAIN_FAMILY)
-    return mesh_cached_fn(TRAIN_FAMILY, mesh, key, build), fresh
+    return mesh_cached_fn(TRAIN_FAMILY, mesh, key, build)
 
 
 def _process_shard_range(mesh: Mesh) -> Tuple[int, int]:
@@ -717,11 +746,6 @@ def build_distributed(mesh: Mesh, user_idx: np.ndarray,
 
     Single-process meshes degrade to `ALSData.build(...).put(mesh)`.
     """
-    import jax
-
-    from predictionio_tpu.parallel.shuffle import allgather_object, \
-        exchange_rows
-
     user_idx = np.ascontiguousarray(user_idx, np.int32)
     item_idx = np.ascontiguousarray(item_idx, np.int32)
     ratings = np.ascontiguousarray(ratings, np.float32)
@@ -730,6 +754,22 @@ def build_distributed(mesh: Mesh, user_idx: np.ndarray,
         return ALSData.build(user_idx, item_idx, ratings, n_users,
                              n_items, n_shards, row_len=row_len).put(mesh)
 
+    with span("als_pack"):
+        data = _build_distributed(mesh, user_idx, item_idx, ratings,
+                                  n_users, n_items, row_len)
+    train_stats.observe_row_fill(data)
+    return data
+
+
+def _build_distributed(mesh: Mesh, user_idx, item_idx, ratings,
+                       n_users: int, n_items: int,
+                       row_len: Optional[int]) -> ALSData:
+    """The multi-process body of `build_distributed`: exchange, pack and
+    commit this process's rows."""
+    from predictionio_tpu.parallel.shuffle import allgather_object, \
+        exchange_rows
+
+    n_shards = int(mesh.devices.size)
     lo, hi = _process_shard_range(mesh)
     shards_per_proc = hi - lo
     # global sizes ride one tiny metadata all-gather
@@ -886,7 +926,6 @@ def train_als(mesh: Mesh, data: ALSData, params: ALSParams,
     a different mesh shape (snapshots hold unpadded host arrays)."""
     import time
 
-    from predictionio_tpu.obs.tracing import span
     from predictionio_tpu.obs.train_stats import (
         als_block_sweeps, als_gramian_cache_hits, als_half_sweep_seconds,
     )
@@ -923,16 +962,28 @@ def train_als(mesh: Mesh, data: ALSData, params: ALSParams,
     bi = (data.by_item.tgt, data.by_item.seg, data.by_item.val, data.by_item.w)
 
     solve_s = 0.0    # device-dispatch wall only, excluding snapshot I/O
-    compiled = False  # any timed dispatch paid trace+compile
-    iters_run = params.num_iterations
-    if checkpointer is None:
-        train, fresh = _cached_train_fn(mesh, dims, params)
-        compiled |= fresh
+    # whether a timed dispatch paid trace+compile is the compiler's word
+    # (its own events, counted before and after), not the fn_cache
+    # ledger's: jit retraces under an unchanged ledger key when a shape
+    # the key leaves out changes
+    jax_stats.listen_to_compiler()
+    compiled = False
+
+    def dispatch(fn, *args):
+        """One blocked, timed dispatch of a train program."""
+        nonlocal solve_s, compiled
         with span("als_solve"):
+            compiles_before = jax_stats.backend_compile_count()
             t0 = time.perf_counter()
-            U, V = train(bu, bi, key)
+            U, V = fn(bu, bi, *args)
             jax.block_until_ready(V)
             solve_s += time.perf_counter() - t0
+            compiled |= jax_stats.backend_compile_count() > compiles_before
+        return U, V
+
+    iters_run = params.num_iterations
+    if checkpointer is None:
+        U, V = dispatch(_cached_train_fn(mesh, dims, params), key)
     else:
         k = params.rank
         fp = als_fingerprint(data, params)
@@ -1003,14 +1054,8 @@ def train_als(mesh: Mesh, data: ALSData, params: ALSParams,
         snap_u = params.solver == "subspace"
         while it < params.num_iterations:
             n = min(checkpointer.interval, params.num_iterations - it)
-            chunk, fresh = _cached_train_fn(mesh, dims, params,
-                                            chunk_iters=n)
-            compiled |= fresh
-            with span("als_solve"):
-                t0 = time.perf_counter()
-                U, V = chunk(bu, bi, U, V)
-                jax.block_until_ready(V)
-                solve_s += time.perf_counter() - t0
+            U, V = dispatch(
+                _cached_train_fn(mesh, dims, params, chunk_iters=n), U, V)
             it += n
             if it < params.num_iterations:
                 if multihost:
@@ -1048,7 +1093,11 @@ def train_als(mesh: Mesh, data: ALSData, params: ALSParams,
         # the per-half-sweep Gramian/count cache serves every block solve
         # after the first without a rebuild
         als_gramian_cache_hits().inc(half_sweeps * max(0, n_blocks - 1))
-    return gather_host(U, data.n_users), gather_host(V, data.n_items)
+    with span("als_fetch"):
+        U_host = gather_host(U, data.n_users)
+        V_host = gather_host(V, data.n_items)
+    train_stats.als_fetch_bytes().inc(U_host.nbytes + V_host.nbytes)
+    return U_host, V_host
 
 
 # ---------------------------------------------------------------------------

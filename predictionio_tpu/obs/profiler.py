@@ -13,8 +13,10 @@ production without redeploying instrumented code. Two mechanisms:
   disables the wrap entirely.
 
 * **bounded trace capture** — :func:`capture` runs ``jax.profiler``
-  for a capped duration and returns the trace directory, exposed as
-  ``POST /debug/profile`` on the query server and ``pio profile``.
+  (Python tracer off: the host plane holds the program's ``pio:<span>``
+  annotations beside the runtime's events, on the device lines' time
+  base) for a capped duration and returns the trace directory, exposed
+  as ``POST /debug/profile`` on the query server and ``pio profile``.
   One capture at a time (a second request gets a busy error), duration
   clamped to :data:`MAX_CAPTURE_S` — an operator can never wedge a
   serving box with an unbounded profile.
@@ -84,7 +86,12 @@ def capture(seconds: float, outdir: Optional[str] = None) -> dict:
 
         trace_dir = outdir or tempfile.mkdtemp(prefix="pio-profile-")
         t0 = time.perf_counter()
-        jax.profiler.start_trace(trace_dir)
+        # the Python tracer would write an event per Python call of the
+        # host loop and bury the program's own `pio:<span>` annotations
+        # (obs/tracing.span), which are what names the host plane
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
         try:
             time.sleep(seconds)
         finally:

@@ -185,7 +185,12 @@ class FlightRecorder:
                     parent_span_id: Optional[str], name: str,
                     duration_s: float, spans: Optional[Dict] = None,
                     status: str = "ok", process: Optional[str] = None,
-                    attrs: Optional[dict] = None) -> dict:
+                    attrs: Optional[dict] = None,
+                    timeline: Optional[List[dict]] = None) -> dict:
+        """One completed hop. ``spans`` is its seconds by span name;
+        ``timeline`` its timed records (``tracing.Trace.timeline``:
+        name, start and end in seconds from the hop's start, parent as
+        an index), kept only when the hop recorded any."""
         record = {
             "traceId": trace_id,
             "spanId": span_id,
@@ -199,6 +204,8 @@ class FlightRecorder:
         }
         if attrs:
             record["attrs"] = attrs
+        if timeline:
+            record["timeline"] = timeline
         self.record_trace(record)
         return record
 

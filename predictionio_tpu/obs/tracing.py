@@ -18,9 +18,15 @@ recorder. Whole processes adopt a parent's context from the
 ``PIO_TRACE_CONTEXT`` env var with :func:`adopt` (batchpredict/train
 shards), so one trace id stitches a fleet run end to end.
 
-Span timings feed two places: the active trace (surfaced in structured
-slow-request log lines) and the owning registry's
-``pio_span_duration_seconds`` histogram (surfaced at ``/metrics``).
+A span is a timed record: name, start and end on one monotonic clock
+(``time.perf_counter_ns``) and the span that was open when it started,
+kept in memory on the active :class:`Trace` and handed to the flight
+recorder when the job or hop ends. Span timings feed three places: the
+active trace (slow-request log lines, the flight recorder), the owning
+registry's ``pio_span_duration_seconds`` histogram (``/metrics``), and --
+once jax is imported -- the profiler's own trace, as a
+``jax.profiler.TraceAnnotation`` named ``pio:<span>``, so that a capture
+shows the program's spans on the time base of the device lines.
 """
 
 from __future__ import annotations
@@ -30,11 +36,12 @@ import contextvars
 import json
 import logging
 import os
+import sys
 import time
 import uuid
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
-from predictionio_tpu.obs.registry import MetricsRegistry
+from predictionio_tpu.obs.registry import MetricsRegistry, default_registry
 from predictionio_tpu.obs.trace_context import (
     TraceContext, new_span_id, recorder,
 )
@@ -57,6 +64,33 @@ _request_id_var: contextvars.ContextVar[Optional[str]] = \
     contextvars.ContextVar("pio_request_id", default=None)
 _trace_var: contextvars.ContextVar[Optional["Trace"]] = \
     contextvars.ContextVar("pio_trace", default=None)
+#: the span open in this context (the parent of the next one); a context
+#: variable, not a field of the trace: tasks of one request share its
+#: trace, and each has its own innermost span
+_open_var: contextvars.ContextVar[Optional["Span"]] = \
+    contextvars.ContextVar("pio_open_span", default=None)
+
+#: prefix of the profiler annotations span() writes; a capture's host
+#: plane holds ``pio:<span>`` beside the runtime's own events
+ANNOTATION_PREFIX = "pio:"
+#: timed records one hop hands to the flight recorder (the ring is
+#: bounded by records, so each record is bounded too)
+MAX_TIMELINE = 256
+
+_annotation_cls = None
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation("pio:<name>")`` iff jax is already
+    imported (a bare event server must not import it from here); costs a
+    fraction of a microsecond while no profiler session runs."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return None
+        _annotation_cls = profiler.TraceAnnotation
+    return _annotation_cls(ANNOTATION_PREFIX + name)
 
 
 def new_request_id() -> str:
@@ -78,6 +112,24 @@ def span_histogram(registry: MetricsRegistry):
         "Per-stage wall time recorded by span()", labelnames=("span",))
 
 
+class Span:
+    """One timed record of a trace: start and end in
+    ``time.perf_counter_ns`` ticks, and the span that enclosed it."""
+
+    __slots__ = ("name", "start_ns", "end_ns", "parent")
+
+    def __init__(self, name: str, start_ns: int, end_ns: Optional[int],
+                 parent: Optional["Span"]):
+        self.name = name
+        self.start_ns = start_ns
+        self.end_ns = end_ns          # None while the span is open
+        self.parent = parent
+
+    @property
+    def seconds(self) -> float:
+        return ((self.end_ns or self.start_ns) - self.start_ns) * 1e-9
+
+
 class Trace:
     """Per-request (or per-job/per-hop) span accumulator with identity."""
 
@@ -93,7 +145,8 @@ class Trace:
         #: pre-resolved pio_span_duration_seconds handle — span() exits on
         #: the query hot path must not take the registry lock per call
         self.span_hist = span_hist
-        self.spans: List[Tuple[str, float]] = []
+        #: in start order, so a span's parent always precedes it
+        self.spans: List[Span] = []
         # identity: adopt the carried context (this hop is a child of the
         # carrier), else the request id IS the trace id (root)
         if context is not None:
@@ -105,13 +158,62 @@ class Trace:
         self.span_id = new_span_id()
 
     def add(self, name: str, seconds: float) -> None:
-        self.spans.append((name, seconds))
+        """A stage timed by the caller's own clock: it ended now and
+        lasted ``seconds``, under whatever span is open here."""
+        end = time.perf_counter_ns()
+        self.spans.append(Span(name, end - int(seconds * 1e9), end,
+                               _open_var.get()))
 
     def spans_by_name(self) -> Dict[str, float]:
+        """Seconds per span name. A span nested in one of its own name
+        is inside that one's seconds already and is not added again."""
         out: Dict[str, float] = {}
-        for name, seconds in self.spans:
-            out[name] = out.get(name, 0.0) + seconds
+        for s in self.spans:
+            up = s.parent
+            while up is not None and up.name != s.name:
+                up = up.parent
+            if up is None:
+                out[s.name] = out.get(s.name, 0.0) + s.seconds
         return out
+
+    def self_seconds(self) -> Dict[str, float]:
+        """Seconds per span name that no child span covers: a span's
+        duration minus the union of its children's intervals."""
+        children: Dict[int, List[Span]] = {}
+        for s in self.spans:
+            if s.parent is not None and s.end_ns is not None:
+                children.setdefault(id(s.parent), []).append(s)
+        out: Dict[str, float] = {}
+        for s in self.spans:
+            if s.end_ns is None:
+                continue
+            covered, reach = 0, s.start_ns
+            for c in sorted(children.get(id(s), ()),
+                            key=lambda c: c.start_ns):
+                lo, hi = max(c.start_ns, reach), min(c.end_ns, s.end_ns)
+                if hi > lo:
+                    covered += hi - lo
+                    reach = hi
+            out[s.name] = out.get(s.name, 0.0) \
+                + (s.end_ns - s.start_ns - covered) * 1e-9
+        return out
+
+    def timeline(self, origin_ns: int) -> List[dict]:
+        """The closed spans as JSON-ready rows: seconds from
+        ``origin_ns``, and the parent as an index into the same list."""
+        rows, index = [], {}
+        for s in self.spans:
+            if len(rows) >= MAX_TIMELINE:
+                break
+            if s.end_ns is None:
+                continue
+            index[id(s)] = len(rows)
+            rows.append({
+                "name": s.name,
+                "start": round((s.start_ns - origin_ns) * 1e-9, 6),
+                "end": round((s.end_ns - origin_ns) * 1e-9, 6),
+                "parent": index.get(id(s.parent))})
+        return rows
 
     def context(self) -> TraceContext:
         """This trace's position as a carryable context (the hop a child
@@ -126,13 +228,15 @@ def start_trace(request_id: str,
     """Install a fresh trace + request id; returns tokens for
     :func:`reset_trace`."""
     trace = Trace(request_id, registry, span_hist, context=context)
-    return (_request_id_var.set(request_id), _trace_var.set(trace)), trace
+    return (_request_id_var.set(request_id), _trace_var.set(trace),
+            _open_var.set(None)), trace
 
 
 def reset_trace(tokens) -> None:
-    rid_token, trace_token = tokens
+    rid_token, trace_token, open_token = tokens
     _request_id_var.reset(rid_token)
     _trace_var.reset(trace_token)
+    _open_var.reset(open_token)
 
 
 def capture_context() -> Optional[TraceContext]:
@@ -157,7 +261,7 @@ def carried(context: Optional[TraceContext], name: str,
     that would flood the ring under load record selectively)."""
     rid = context.trace_id if context is not None else new_request_id()
     tokens, trace = start_trace(rid, registry, span_hist, context=context)
-    t0 = time.perf_counter()
+    t0 = time.perf_counter_ns()
     status = "ok"
     try:
         yield trace
@@ -170,8 +274,9 @@ def carried(context: Optional[TraceContext], name: str,
             recorder().record_span(
                 trace_id=trace.trace_id, span_id=trace.span_id,
                 parent_span_id=trace.parent_span_id, name=name,
-                duration_s=time.perf_counter() - t0,
-                spans=trace.spans_by_name(), status=status, attrs=attrs)
+                duration_s=(time.perf_counter_ns() - t0) * 1e-9,
+                spans=trace.spans_by_name(), status=status, attrs=attrs,
+                timeline=trace.timeline(t0))
 
 
 @contextlib.contextmanager
@@ -186,30 +291,59 @@ def adopt(name: str, context: Optional[TraceContext] = None,
     in-process by a traced parent (an orchestrator cycle running
     run_train/run_evaluation as phases) joins the parent's trace id
     instead of starting a fresh root. A standalone run becomes a root.
-    The job is recorded in the flight recorder on exit either way."""
+    The job is recorded in the flight recorder on exit either way.
+
+    A job's spans reach ``pio_span_duration_seconds`` of ``registry``
+    (default: the process registry) without a ``registry=`` at each call
+    site: the histogram is resolved here, once."""
+    if registry is None:
+        registry = default_registry()
     if context is None:
         from predictionio_tpu.obs.trace_context import from_env
 
         context = from_env()
         if context is None:
             context = capture_context()
-    with carried(context, name, registry=registry, attrs=attrs) as trace:
+    with carried(context, name, registry=registry,
+                 span_hist=span_histogram(registry), attrs=attrs) as trace:
         yield trace
 
 
-@contextlib.contextmanager
-def span(name: str, registry: Optional[MetricsRegistry] = None):
-    """Record this block's wall time as a named stage of the current
-    request (no-op-cheap when no trace/registry is active)."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        trace = _trace_var.get()
+class span:
+    """Record this block as a named, timed stage of the current request
+    or job: a :class:`Span` on the active trace (parent: the span open
+    around it), a sample of ``pio_span_duration_seconds``, and a
+    ``pio:<name>`` annotation in the profiler's trace. Without a trace,
+    a registry or jax it does nothing but read the clock."""
+
+    __slots__ = ("name", "registry", "_trace", "_record", "_annotation",
+                 "_t0")
+
+    def __init__(self, name: str,
+                 registry: Optional[MetricsRegistry] = None):
+        self.name = name
+        self.registry = registry
+
+    def __enter__(self) -> "span":
+        self._annotation = _annotation(self.name)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._record = None
+        self._t0 = time.perf_counter_ns()
+        self._trace = trace = _trace_var.get()
+        if trace is not None:
+            self._record = Span(self.name, self._t0, None, _open_var.get())
+            trace.spans.append(self._record)
+            _open_var.set(self._record)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        end = time.perf_counter_ns()
+        trace, registry = self._trace, self.registry
         hist = None
         if trace is not None:
-            trace.add(name, dt)
+            self._record.end_ns = end
+            _open_var.set(self._record.parent)
             if registry is None:
                 hist = trace.span_hist
                 if hist is None and trace.registry is not None:
@@ -217,7 +351,10 @@ def span(name: str, registry: Optional[MetricsRegistry] = None):
         if hist is None and registry is not None:
             hist = span_histogram(registry)
         if hist is not None:
-            hist.observe(dt, span=name)
+            hist.observe((end - self._t0) * 1e-9, span=self.name)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        return False
 
 
 def log_slow_request(service: str, method: str, path: str, status: int,

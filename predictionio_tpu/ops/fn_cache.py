@@ -76,8 +76,10 @@ def _cached(family: str, key: Hashable, build: Callable[[], Callable],
     with _LOCK:
         cache = _CACHES.setdefault(family, OrderedDict())
         if key not in cache:
-            # a climbing pio_jax_compile_total on a serving box flags a
-            # retrace leak — exactly what this cache exists to prevent
+            # a first sighting of this key, not a compilation: a climbing
+            # pio_jax_compile_total on a serving box flags a leak of keys,
+            # what this cache exists to prevent (what the compiler built
+            # is pio_jax_backend_compile_total, obs/jax_stats.py)
             compile_counter().inc(family=family)
             cache[key] = fn
             while len(cache) > max_entries:

@@ -1,19 +1,14 @@
-"""Profiling hooks.
+"""Host-phase timing sinks (`phase` / `collect_phases`).
 
-The reference has no tracing beyond Spark's UI (SURVEY.md section 5); the
-rebuild adds jax.profiler integration: wrap train steps in profile_trace to
-capture a TensorBoard-compatible device trace, and trace_annotation to name
-regions inside it.
+Device traces come from `obs/profiler.capture` (`pio profile`), and the
+named regions inside them from `obs/tracing.span`.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
-import logging
 import time
-
-logger = logging.getLogger("pio.profiling")
 
 #: ContextVar, not a module global: concurrent requests/trainings each see
 #: their own sink instead of clobbering whichever was installed last.
@@ -50,23 +45,3 @@ def phase(name: str):
         yield
     finally:
         sink[name] = sink.get(name, 0.0) + time.perf_counter() - t0
-
-
-@contextlib.contextmanager
-def profile_trace(log_dir: str):
-    """Capture a jax.profiler trace around a block (train step, sweep)."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-        logger.info("profiler trace written to %s", log_dir)
-
-
-def trace_annotation(name: str):
-    """Named region inside a device trace (jax.profiler.TraceAnnotation)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)

@@ -33,6 +33,12 @@ def publish_phase_timings(sink: dict, workflow: str) -> None:
         hist.observe(seconds, workflow=workflow, phase=name)
 
 
+def persist_bytes():
+    return default_registry().counter(
+        "pio_train_persist_bytes_total",
+        "Bytes of serialised models `pio train` wrote to the model store")
+
+
 @contextlib.contextmanager
 def workflow_run_metrics(workflow: str, metric_prefix: str):
     """Instrument one workflow run; yields the phase sink.

@@ -21,7 +21,9 @@ from predictionio_tpu.data.event import UTC
 from predictionio_tpu.storage.base import EngineInstance, Model
 from predictionio_tpu.storage.registry import Storage
 from predictionio_tpu.workflow.context import WorkflowContext, WorkflowParams
-from predictionio_tpu.workflow.instrument import workflow_run_metrics
+from predictionio_tpu.workflow.instrument import (
+    persist_bytes, workflow_run_metrics,
+)
 from predictionio_tpu.workflow.serialization import serialize_models
 
 logger = logging.getLogger("pio.workflow")
@@ -67,8 +69,9 @@ def run_train(engine: Engine,
     # the whole run is one trace: a parent pipeline (or a multi-process
     # launcher) hands its context via PIO_TRACE_CONTEXT so this train's
     # record joins the parent's trace id in the flight recorder
+    from predictionio_tpu.deploy.releases import record_release
     from predictionio_tpu.obs.trace_context import record_event
-    from predictionio_tpu.obs.tracing import adopt
+    from predictionio_tpu.obs.tracing import adopt, span
 
     with adopt("train", attrs={"instance": instance_id,
                                "variant": engine_variant}):
@@ -80,30 +83,33 @@ def run_train(engine: Engine,
                 stop_after_read=wp.stop_after_read,
                 stop_after_prepare=wp.stop_after_prepare)
 
-            if wp.save_model:
-                persisted = engine.persist_models(ctx, instance_id, result)
-                blob = serialize_models(persisted)
-                Storage.get_model_data_models().insert(
-                    Model(id=instance_id, models=blob))
-                logger.info("models saved (%d bytes) for instance %s",
-                            len(blob), instance_id)
+            with span("train_persist"):
+                if wp.save_model:
+                    persisted = engine.persist_models(ctx, instance_id,
+                                                      result)
+                    blob = serialize_models(persisted)
+                    Storage.get_model_data_models().insert(
+                        Model(id=instance_id, models=blob))
+                    persist_bytes().inc(len(blob))
+                    logger.info("models saved (%d bytes) for instance %s",
+                                len(blob), instance_id)
 
-            instance.status = "COMPLETED"
-            instance.end_time = _dt.datetime.now(tz=UTC)
-            instances.update(instance)
+                instance.status = "COMPLETED"
+                instance.end_time = _dt.datetime.now(tz=UTC)
+                instances.update(instance)
         record_event("train_completed", {
             "instance": instance_id, "variant": engine_variant})
 
-    # register the completed instance as the variant's next release
-    # (deploy/ subsystem: `pio releases` listing, warm deploys, rollback
-    # lineage). Best-effort by contract — the train already succeeded.
-    from predictionio_tpu.deploy.releases import record_release
-
-    record_release(
-        instance,
-        train_seconds=(instance.end_time - instance.start_time
-                       ).total_seconds(),
-        blob=blob)
+        # register the completed instance as the variant's next release
+        # (deploy/ subsystem: `pio releases` listing, warm deploys,
+        # rollback lineage). Best-effort by contract — the train already
+        # succeeded.
+        with span("train_release"):
+            record_release(
+                instance,
+                train_seconds=(instance.end_time - instance.start_time
+                               ).total_seconds(),
+                blob=blob)
     if getattr(ctx, "checkpointer", None) is not None:
         # resume is for crashed/preempted runs only: a completed run clears
         # its snapshots so the next train never resumes from stale factors

@@ -1,0 +1,322 @@
+"""Timestamped, parented spans (obs/tracing.py), the spans at the layer
+boundaries of `pio train`, their mirror in the profiler's trace, and the
+compiler's own compile events (obs/jax_stats.py)."""
+
+import time
+
+import numpy as np
+import pytest
+
+from predictionio_tpu.obs import jax_stats, trace_context, tracing
+from predictionio_tpu.obs.registry import MetricsRegistry, default_registry
+
+#: table B of ISSUE 24: every span of a recommendation train, with the
+#: span that encloses it (None = the job itself)
+TRAIN_SPANS = {
+    "train_read": None,
+    "ingest_digest": "train_read",
+    "ingest_scan": "train_read",
+    "ingest_decode": "train_read",
+    "train_prepare": None,
+    "train_algorithm": None,
+    "train_id_assign": "train_algorithm",
+    "als_pack": "train_algorithm",
+    "als_put": "train_algorithm",
+    "als_solve": "train_algorithm",
+    "als_fetch": "train_algorithm",
+    "train_persist": None,
+    "train_release": None,
+}
+
+
+def span_count(name, registry=None):
+    hist = (registry or default_registry()).get("pio_span_duration_seconds")
+    return hist.count(span=name) if hist is not None else 0
+
+
+# -- the span record ----------------------------------------------------------
+
+def test_span_records_times_and_the_enclosing_span():
+    tokens, trace = tracing.start_trace("rid")
+    try:
+        with tracing.span("outer"):
+            with tracing.span("first"):
+                time.sleep(0.002)
+            with tracing.span("second"):
+                pass
+    finally:
+        tracing.reset_trace(tokens)
+    outer, first, second = trace.spans
+    assert [s.name for s in trace.spans] == ["outer", "first", "second"]
+    for s in trace.spans:
+        assert s.start_ns <= s.end_ns
+    assert outer.parent is None
+    assert first.parent is outer and second.parent is outer
+    assert outer.start_ns <= first.start_ns <= first.end_ns \
+        <= second.start_ns <= second.end_ns <= outer.end_ns
+    assert first.seconds >= 0.002
+
+
+def test_self_seconds_of_a_parent_with_two_children():
+    trace = tracing.Trace("rid")
+    ms = 1_000_000
+    parent = tracing.Span("parent", 0, 100 * ms, None)
+    trace.spans += [parent,
+                    tracing.Span("child", 10 * ms, 30 * ms, parent),
+                    tracing.Span("child", 50 * ms, 90 * ms, parent)]
+    own = trace.self_seconds()
+    assert own["parent"] == pytest.approx(0.040)
+    assert own["child"] == pytest.approx(0.060)
+    assert trace.spans_by_name() == {"parent": pytest.approx(0.100),
+                                     "child": pytest.approx(0.060)}
+
+
+def test_same_name_nesting_is_counted_once():
+    tokens, trace = tracing.start_trace("rid")
+    try:
+        with tracing.span("work"):
+            with tracing.span("work"):
+                time.sleep(0.002)
+    finally:
+        tracing.reset_trace(tokens)
+    outer, inner = trace.spans
+    assert inner.parent is outer
+    assert trace.spans_by_name() == {"work": pytest.approx(outer.seconds)}
+    assert trace.self_seconds()["work"] == pytest.approx(outer.seconds)
+
+
+def test_a_hop_hands_its_timeline_to_the_flight_recorder():
+    trace_context.recorder().clear()
+    with tracing.carried(None, "hop"):
+        with tracing.span("a"):
+            with tracing.span("b"):
+                pass
+    record = trace_context.recorder().traces()[-1]
+    assert record["name"] == "hop" and set(record["spans"]) == {"a", "b"}
+    a, b = record["timeline"]
+    assert (a["name"], a["parent"]) == ("a", None)
+    assert (b["name"], b["parent"]) == ("b", 0)
+    assert 0 <= a["start"] <= b["start"] <= b["end"] <= a["end"] \
+        <= record["durationSec"] + 1e-3
+
+
+def test_adopt_without_a_registry_reaches_the_default_registry():
+    before = span_count("adopted_probe")
+    with tracing.adopt("job"):
+        with tracing.span("adopted_probe"):
+            pass
+    assert span_count("adopted_probe") == before + 1
+    # a registry that is given still wins
+    own = MetricsRegistry()
+    with tracing.adopt("job", registry=own):
+        with tracing.span("adopted_probe"):
+            pass
+    assert span_count("adopted_probe", own) == 1
+    assert span_count("adopted_probe") == before + 1
+
+
+# -- the train path -----------------------------------------------------------
+
+@pytest.fixture()
+def rated_app(tmp_path):
+    from predictionio_tpu.data import DataMap, Event
+    from predictionio_tpu.data.eventstore import clear_cache
+    from predictionio_tpu.data.ingest import clear_scan_cache
+    from predictionio_tpu.storage import App, Storage
+
+    Storage.configure({
+        "sources": {"DB": {"TYPE": "sqlite",
+                           "PATH": str(tmp_path / "spans.db")}},
+        "repositories": {
+            "METADATA": {"NAME": "pio", "SOURCE": "DB"},
+            "EVENTDATA": {"NAME": "pio", "SOURCE": "DB"},
+            "MODELDATA": {"NAME": "pio", "SOURCE": "DB"},
+        },
+    })
+    clear_cache()
+    clear_scan_cache()
+    app_id = Storage.get_meta_data_apps().insert(App(id=0, name="SpanApp"))
+    store = Storage.get_events()
+    store.init_channel(app_id)
+    rng = np.random.default_rng(11)
+    store.insert_batch([
+        Event(event="rate", entity_type="user", entity_id=f"u{u}",
+              target_entity_type="item", target_entity_id=f"i{i}",
+              properties=DataMap({"rating": float(rng.integers(1, 6))}))
+        for u in range(24) for i in range(16) if rng.random() < 0.5],
+        app_id)
+    yield "SpanApp"
+    Storage.reset()
+    clear_cache()
+    clear_scan_cache()
+
+
+def test_a_train_leaves_every_boundary_span_once(rated_app):
+    from predictionio_tpu.engines.recommendation import (
+        default_engine_params, engine,
+    )
+    from predictionio_tpu.workflow import run_train
+
+    trace_context.recorder().clear()
+    counts0 = {name: span_count(name) for name in TRAIN_SPANS}
+    reg = default_registry()
+    instance = run_train(
+        engine(), default_engine_params(rated_app, rank=4, num_iterations=2),
+        engine_factory="predictionio_tpu.engines.recommendation:engine")
+    assert instance.status == "COMPLETED"
+
+    # every span reached the scrape without a registry= at its call site
+    for name in TRAIN_SPANS:
+        assert span_count(name) == counts0[name] + 1, name
+    record = [t for t in trace_context.recorder().traces()
+              if t["name"] == "train"][-1]
+    rows = record["timeline"]
+    assert sorted(r["name"] for r in rows) == sorted(TRAIN_SPANS)
+    by_name = {r["name"]: r for r in rows}
+    for name, parent in TRAIN_SPANS.items():
+        got = by_name[name]["parent"]
+        assert (rows[got]["name"] if got is not None else None) == parent
+    for parent in ("train_read", "train_algorithm"):
+        children = [r for r in rows if r["parent"] is not None
+                    and rows[r["parent"]]["name"] == parent]
+        assert sum(c["end"] - c["start"] for c in children) \
+            <= by_name[parent]["end"] - by_name[parent]["start"] + 1e-5
+    # the counts taken at the same boundaries
+    assert reg.get("pio_train_als_entities").value(side="user") == 24
+    assert reg.get("pio_train_als_entities").value(side="item") == 16
+    fill = reg.get("pio_train_als_row_fill_ratio")
+    assert 0 < fill.sum_(side="user") / fill.count(side="user") <= 1
+    assert reg.get("pio_train_als_put_bytes_total").value() > 0
+    assert reg.get("pio_train_als_fetch_bytes_total").value() \
+        >= (24 + 16) * 4 * 4
+    assert reg.get("pio_train_persist_bytes_total").value() > 0
+    assert reg.get("pio_ingest_decoded_rows_total").value(
+        app=rated_app) > 0
+
+
+# -- the profiler's trace, and the compiler's own events ---------------------
+
+def single_mesh():
+    import jax
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(jax.devices()[:1]), axis_names=("data",))
+
+
+def rating_set(nnz, n_users=48, n_items=24, seed=5):
+    """`nnz` distinct (user, item) pairs with ratings, packed two to a
+    row: the padded row count follows nnz, the other dims do not."""
+    from predictionio_tpu.models.als import ALSData
+
+    rng = np.random.default_rng(seed)
+    cells = rng.choice(n_users * n_items, size=nnz, replace=False)
+    users = np.concatenate([np.arange(n_users), cells // n_items])
+    items = np.concatenate([np.arange(n_users) % n_items, cells % n_items])
+    ratings = rng.integers(1, 6, len(users)).astype(np.float32)
+    return ALSData.build(users.astype(np.int32), items.astype(np.int32),
+                         ratings, n_users, n_items, 1, row_len=2)
+
+
+def test_spans_are_annotations_in_a_profiler_capture(tmp_path):
+    import glob
+    import threading
+
+    from jax.profiler import ProfileData
+
+    from predictionio_tpu.models.als import ALSParams, train_als
+    from predictionio_tpu.obs import profiler
+
+    mesh, data = single_mesh(), rating_set(100)
+    params = ALSParams(rank=4, num_iterations=2)
+    train_als(mesh, data, params)                  # compile outside
+    out: dict = {}
+    capture = threading.Thread(target=lambda: out.update(
+        profiler.capture(0.5, str(tmp_path / "prof"))))
+    capture.start()
+    deadline = time.monotonic() + 30
+    while capture.is_alive() and time.monotonic() < deadline:
+        train_als(mesh, data, params)
+    capture.join(timeout=30)
+    assert not capture.is_alive() and out["traceDir"]
+    files = glob.glob(f"{out['traceDir']}/plugins/profile/*/*.xplane.pb")
+    assert files
+    host_events = {
+        e.name for plane in ProfileData.from_file(files[-1]).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events}
+    assert "pio:als_solve" in host_events
+    assert "pio:als_fetch" in host_events
+    # the Python tracer is off: no event per Python call of the host loop
+    assert not any(name.startswith("$") for name in host_events)
+
+
+def test_a_silent_retrace_is_counted_and_kept_out_of_the_half_sweeps():
+    from predictionio_tpu.models.als import (
+        TRAIN_FAMILY, ALSParams, train_als,
+    )
+
+    reg = default_registry()
+    mesh = single_mesh()
+    params = ALSParams(rank=4, num_iterations=3, seed=24)
+    small, large = rating_set(100), rating_set(900)
+    assert small.by_user.tgt.shape[1] != large.by_user.tgt.shape[1]
+
+    def readings():
+        sweeps = reg.get("pio_train_als_half_sweep_seconds")
+        compiles = reg.get(jax_stats.BACKEND_COMPILE_COUNTER)
+        return (jax_stats.compile_counter().value(family=TRAIN_FAMILY),
+                sum(v for labels, v in compiles.samples()
+                    if labels["fun"] == "jit(train)") if compiles else 0,
+                sweeps.count(solver="full") if sweeps else 0)
+
+    train_als(mesh, small, params)                 # first sighting: compiles
+    ledger0, compiled0, sweeps0 = readings()
+    train_als(mesh, small, params)                 # warm: one sample
+    assert readings() == (ledger0, compiled0, sweeps0 + 1)
+    # the same ledger key (the padded row count is not in it), another
+    # input shape: jit retraces, only the compiler's own count sees it
+    train_als(mesh, large, params)
+    assert readings() == (ledger0, compiled0 + 1, sweeps0 + 1)
+    train_als(mesh, large, params)
+    assert readings() == (ledger0, compiled0 + 1, sweeps0 + 2)
+
+
+def test_compile_fun_labels_are_bounded():
+    events = jax_stats._CompilerEvents(MetricsRegistry())
+    for i in range(jax_stats.MAX_COMPILE_FUNS + 5):
+        events.on_duration("/jax/core/compile/backend_compile_duration",
+                           0.5, fun_name=f"jit(f{i})")
+    events.on_duration("/jax/core/compile/jaxpr_trace_duration", 0.25,
+                       fun_name="f")
+    events.on_event("/jax/compilation_cache/cache_hits")
+    funs = {labels["fun"]: v for labels, v in events.compiles.samples()}
+    assert len(funs) == jax_stats.MAX_COMPILE_FUNS + 1
+    assert funs["other"] == 5 and events.count == len(funs) + 4
+    assert events.compile_seconds.value(fun="other") == 2.5
+    assert events.durations[
+        "/jax/core/compile/jaxpr_trace_duration"].value() == 0.25
+    assert events.events["/jax/compilation_cache/cache_hits"].value() == 1
+
+
+def test_a_trace_inside_a_trace_is_not_counted_again():
+    """jax reports a function traced inside another's trace on its own
+    and inside the outer one's duration; only the outer one counts."""
+    import jax
+    import jax.numpy as jnp
+
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    events = jax_stats._CompilerEvents(MetricsRegistry())
+    during = []
+
+    @jax.jit
+    def outer(x):
+        # this body runs while `outer` is traced, which is where jax
+        # reports the jitted functions `outer` calls
+        events.on_duration(trace, 5.0, fun_name="inner")
+        during.append(events.durations[trace].value())
+        return x + 1
+
+    outer(jnp.ones(3))
+    assert during == [0.0]
+    events.on_duration(trace, 2.0, fun_name="outer")
+    assert events.durations[trace].value() == 2.0

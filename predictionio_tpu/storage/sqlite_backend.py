@@ -21,7 +21,7 @@ from typing import Iterator, List, Optional, Sequence, Tuple
 
 from predictionio_tpu.data.datamap import DataMap
 from predictionio_tpu.data.event import UTC, Event, millis as _to_ms
-from predictionio_tpu.storage import base
+from predictionio_tpu.storage import base, sqlite_scan
 from predictionio_tpu.storage.base import (
     AccessKey, App, Channel, EngineInstance, EvaluationInstance, Model,
     Release, StorageError, UNFILTERED, generate_id,
@@ -383,23 +383,68 @@ class SqliteEvents(base.EventStore):
         EVENT_SCHEMA subset a training read actually consumes (fetching
         9 columns to use 4 dominates the scan otherwise).
         ``reversed_order``/``limit`` semantics require the sort, so they
-        force it back on."""
+        force it back on. An unordered read of a whole large table is
+        cut into rowid windows read by as many short-lived processes
+        (`_fanned_out`, storage/sqlite_scan.py): same rows, same order."""
         from predictionio_tpu.data.columnar import (
             SQL_COLUMN_OF, projected_schema, rows_to_event_table,
         )
 
         if filters.get("reversed_order") or filters.get("limit") is not None:
             ordered = True
-        names = projected_schema(columns).names
-        cols = ", ".join(SQL_COLUMN_OF[n] for n in names)
-        sql, params = self._find_sql(cols, app_id, channel_id,
-                                     ordered=ordered, **filters)
+        schema = projected_schema(columns)
+        cols = ", ".join(SQL_COLUMN_OF[n] for n in schema.names)
         try:
+            if not ordered:
+                table = self._fanned_out(cols, schema, app_id, channel_id,
+                                         filters)
+                if table is not None:
+                    return table
+            sql, params = self._find_sql(cols, app_id, channel_id,
+                                         ordered=ordered, **filters)
             rows = self.client.conn().execute(sql, params).fetchall()
         except sqlite3.OperationalError as ex:
             raise StorageError(
                 f"cannot read app {app_id} channel {channel_id}: {ex}") from ex
-        return rows_to_event_table(rows, names)
+        sqlite_scan.count_readers(1)
+        return rows_to_event_table(rows, schema.names)
+
+    def _fanned_out(self, cols: str, schema, app_id: int,
+                    channel_id: Optional[int], filters: dict):
+        """The unordered, unlimited read through `sqlite_scan.fan_out`, or
+        None where the serial scan answers: a store in memory, a filter
+        by value that makes the read a handful of rows (the serving-time
+        reads by entity, fold-in's by time, which may also take the
+        eventTime index that a rowid range would fight), this
+        connection inside a transaction of its own (its uncommitted rows
+        are not in any reader's snapshot), a window too small to be
+        worth a process. A ``shard=`` read splits its shard's window."""
+        if (self.client.path == ":memory:"
+                or filters.get("entity_id") is not None
+                or filters.get("target_entity_id", UNFILTERED)
+                is not UNFILTERED
+                or filters.get("start_time") is not None
+                or filters.get("until_time") is not None):
+            return None
+        conn = self.client.conn()
+        if conn.in_transaction:
+            return None
+        rest = {k: v for k, v in filters.items() if k != "shard"}
+        shard = filters.get("shard")
+        agreed = shard[2] if shard is not None and len(shard) > 2 else None
+        name = event_table_name(app_id, channel_id)
+
+        def window():
+            whole = agreed or sqlite_scan.rowid_window(conn, name)
+            if shard is None:
+                return whole
+            return base.shard_window(*whole, shard)
+
+        return sqlite_scan.fan_out(
+            conn, self.client.path, window,
+            lambda sub: self._find_sql(cols, app_id, channel_id,
+                                       ordered=False, shard=sub, **rest),
+            schema)
 
 
 def _row_to_event(row) -> Event:

@@ -3,30 +3,39 @@
 
 Started by run.py with a JSON spec on the command line. It drives the
 program's normal entry points -- `workflow.train.run_train`,
-`workflow.batch_predict.run_batch_predict` -- and talks to the parent in lines on stdout that start with "BENCH ".
-Everything it learns (job walls, metric deltas, memory, the reduced
-trace, the comparison with the reference) goes back as one "evidence"
-document; the parent turns that into the result line.
+`workflow.batch_predict.run_batch_predict` -- and talks to the parent in
+lines on stdout that start with "BENCH ". Everything it learns (job
+walls, metric deltas, memory, the reduced trace, the rows of the output
+check) goes back as one "evidence" document; the parent turns that into
+the result line.
+
+The `train` kind knows no template, algorithm or model. The window, its
+clock and what a job is are here, for every training configuration
+alike; the configuration's file names what differs and the harness finds
+each by name: its events (events/<name>.py), its algorithm parameters,
+its output check with its reference (checks/<name>.py).
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import gc
+import importlib
 import json
 import os
+import pickle
 import shutil
+import subprocess
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
-
-from benchmarks.lib import datagen, reference  # noqa: E402
 
 APP = "bench"
 
@@ -186,69 +195,23 @@ def pio(*args) -> None:
     cli.main(args=list(args), standalone_mode=False)
 
 
-def write_variant(work: str, cfg: dict, num_iterations: Optional[int] = None,
+def write_variant(work: str, cfg: dict, overlay: Optional[dict] = None,
                   name: str = "engine") -> str:
+    """The template's engine.json with the app's name, and the
+    configuration's `algorithm_params` (then `overlay`) laid over the
+    first algorithm's parameters; every other parameter stays at the
+    program's default."""
     engine_dir = os.path.join(work, name)
     pio("template", "get", cfg["template"], engine_dir)
     path = os.path.join(engine_dir, "engine.json")
     with open(path) as f:
         variant = json.load(f)
     variant["datasource"]["params"]["app_name"] = APP
-    # solver and scorer stay at the program's defaults (full, exact)
     variant["algorithms"][0]["params"].update(
-        {"rank": cfg["rank"],
-         "num_iterations": num_iterations or cfg["num_iterations"]})
+        {**cfg["algorithm_params"], **(overlay or {})})
     with open(path, "w") as f:
         json.dump(variant, f, indent=2)
     return path
-
-
-def fill_event_store(cfg: dict, seed: int):
-    """The configuration's rating events, from the seed, into the sqlite
-    event store in bulk: the rows `insert_batch` would write (its columns,
-    its encodings, taken from one real Event per rating value), handed
-    to the store's own connection in a few `executemany` calls. Building
-    2M Event objects for `insert_batch` takes a minute of host Python
-    that every run of every check would pay (tests/test_datagen.py holds
-    the two paths to the same rows). Returns the generated columns for
-    the reference."""
-    import datetime as dt
-
-    from predictionio_tpu.data.datamap import DataMap
-    from predictionio_tpu.data.event import UTC, Event, millis
-    from predictionio_tpu.storage import Storage
-    from predictionio_tpu.storage.sqlite_backend import (
-        _tz_offset_min, event_table_name,
-    )
-
-    users, items, ratings = datagen.rating_events(
-        cfg["n_users"], cfg["n_items"], cfg["n_events"], seed,
-        cfg.get("structure_seed", 0))
-    app = Storage.get_meta_data_apps().get_by_name(APP)
-    when = dt.datetime(2015, 3, 31, tzinfo=UTC)
-    proto = Event(event="rate", entity_type="user", entity_id="0",
-                  target_entity_type="item", target_entity_id="0",
-                  event_time=when, creation_time=when)
-    ms, tz = millis(when), _tz_offset_min(when)
-    props = {r: DataMap({"rating": r}).to_json()
-             for r in set(ratings.tolist())}
-    store = Storage.get_events()
-    table = event_table_name(app.id, None)
-    block = 250_000
-    for lo in range(0, len(users), block):
-        rows = [(f"{seed & 0xFFFFFFFF:08x}{n:024x}", proto.event,
-                 proto.entity_type, str(u + 1), proto.target_entity_type,
-                 str(i + 1), props[r], ms, tz, None, None, ms, tz)
-                for n, (u, i, r) in enumerate(
-                    zip(users[lo:lo + block].tolist(),
-                        items[lo:lo + block].tolist(),
-                        ratings[lo:lo + block].tolist()), start=lo)]
-        with store.client.write_lock():
-            store.client.conn().executemany(
-                f"INSERT INTO {table} VALUES (?,?,?,?,?,?,?,?,?,?,?,?,?)",
-                rows)
-            store.client.conn().commit()
-    return users, items, ratings
 
 
 def register_release(variant_path: str, model) -> Any:
@@ -296,9 +259,11 @@ def synthetic_model(cfg: dict, seed: int):
     """The configuration's factors from the seed as the program's own
     ALSModel. Returns (model, generated factors); the program only ever
     sees a serialised copy, the reference keeps the generated arrays."""
+    from benchmarks.lib import datagen
     from predictionio_tpu.models.als import ALSModel
 
-    gen = datagen.factors(cfg["n_users"], cfg["n_items"], cfg["rank"], seed)
+    gen = datagen.factors(cfg["n_users"], cfg["n_items"],
+                          cfg["algorithm_params"]["rank"], seed)
     model = ALSModel(
         user_vocab=datagen.entity_ids(cfg["n_users"], "u"),
         item_vocab=datagen.entity_ids(cfg["n_items"], "i"),
@@ -317,30 +282,54 @@ def served_rows(item_scores_rows):
 # cell kind: train
 # ---------------------------------------------------------------------------
 
+@dataclasses.dataclass
+class TrainRun:
+    """What a configuration's check (checks/<name>.py) is given."""
+
+    #: the configuration as run, with its `limits`
+    config: dict
+    seed: int
+    #: what the event generator returned beside the columns
+    truth: Any
+    #: the window's last train, as registered
+    instance: Any
+    #: an instance -> its model, out of the model store
+    load_model: Callable[[Any], Any]
+    #: algorithm parameters -> the instance of one more train of the same
+    #: events with them laid over, outside every clock
+    train_again: Callable[[dict], Any]
+
+
 def run_train_cell(spec: dict) -> dict:
     cfg, traffic, work = spec["config"], spec["traffic"], spec["work"]
+    checker = importlib.import_module(f"benchmarks.checks.{cfg['check']}")
     pio("app", "new", APP)
-    # the store fills (sqlite, host only) while JAX reaches the chip
-    filled: list = []
-    filler = threading.Thread(
-        target=lambda: filled.append(fill_event_store(cfg, spec["seed"])))
-    filler.start()
-    device = claim_device(spec)
-    log(f"device claimed: {device['kind']} x{device['count']}")
-    from predictionio_tpu.cli.main import _load_engine_variant
-    from predictionio_tpu.data.ingest import clear_scan_cache
-    from predictionio_tpu.storage import Storage
-    from predictionio_tpu.workflow import run_train
-    from predictionio_tpu.workflow.serialization import deserialize_models
+    # the store fills (fill.py: sqlite, host only, a process of its own)
+    # while JAX reaches the chip
+    truth_path = os.path.join(work, "truth.pickle")
+    filler = subprocess.Popen(
+        [sys.executable, os.path.join(HERE, "fill.py"),
+         json.dumps({"config": cfg, "seed": spec["seed"]}), APP, truth_path],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, stdin=subprocess.DEVNULL)
+    try:
+        device = claim_device(spec)
+        log(f"device claimed: {device['kind']} x{device['count']}")
+        from predictionio_tpu.cli.main import _load_engine_variant
+        from predictionio_tpu.data.ingest import clear_scan_cache
+        from predictionio_tpu.storage import Storage
+        from predictionio_tpu.workflow import run_train
+        from predictionio_tpu.workflow.serialization import deserialize_models
 
-    filler.join()
-    if not filled:
-        raise SystemExit("the event store could not be filled")
-    users, items, ratings = filled[0]
-    log(f"event store filled: {len(users)} events")
-    variant_path = write_variant(work, cfg)
+        if filler.wait(timeout=900) != 0:
+            raise SystemExit("the event store could not be filled (fill.py "
+                             f"exited with code {filler.returncode})")
+    finally:
+        if filler.poll() is None:
+            filler.kill()
+            filler.wait()
+    log("event store filled")
     engine, engine_params, factory_path, variant_id, _ = \
-        _load_engine_variant(variant_path)
+        _load_engine_variant(write_variant(work, cfg))
 
     def one_train(params=engine_params):
         # every train starts as a fresh `pio train` does: nothing of the
@@ -350,12 +339,18 @@ def run_train_cell(spec: dict) -> dict:
         return run_train(engine, params, engine_factory=factory_path,
                          engine_variant=variant_id)
 
-    def release_of(instance):
-        """(U, V) of a registered release, rows in numeric id order."""
+    def load_model(instance):
         blob = Storage.get_model_data_models().get(instance.id).models
-        model = deserialize_models(blob)[0]
-        return (model.U[np_argsort_ids(model.user_vocab)],
-                model.V[np_argsort_ids(model.item_vocab)])
+        return deserialize_models(blob)[0]
+
+    def train_again(overlay: dict):
+        t0 = time.perf_counter()
+        params = _load_engine_variant(
+            write_variant(work, cfg, overlay, "engine_again"))[1]
+        instance = one_train(params)
+        log(f"train with {overlay} for the check: "
+            f"{time.perf_counter() - t0:.2f} s")
+        return instance
 
     for _ in range(int(traffic.get("warm_jobs", 1))):
         s0, t0 = span_totals(), time.perf_counter()
@@ -364,7 +359,7 @@ def run_train_cell(spec: dict) -> dict:
             + span_note(spans_between(s0, span_totals())))
     tracer = Tracer(work, "train_host") if spec["trace"] else None
     say("ready")
-    before, spans0 = registry_snapshot(), span_totals()
+    before = registry_snapshot()
     jobs: List[dict] = []
     t_open = time.perf_counter()
     instance = None
@@ -389,37 +384,21 @@ def run_train_cell(spec: dict) -> dict:
     after = registry_snapshot()
     mem = memory_peaks()
 
-    # the last train's release, as the registry holds it, and the item
-    # factors its last user half-sweep read: the same train (same events,
-    # same seed, deterministic) run for one iteration fewer, after the
-    # window and outside every clock
-    U, V = release_of(instance)
+    # the configuration's check of the last train's release, as the
+    # registry holds it: after the window and outside every clock
+    with open(truth_path, "rb") as f:     # written by this run's fill.py
+        truth = pickle.load(f)
+    run = TrainRun(config=cfg, seed=spec["seed"], truth=truth,
+                   instance=instance, load_model=load_model,
+                   train_again=train_again)
     t0 = time.perf_counter()
-    shorter = _load_engine_variant(write_variant(
-        work, cfg, cfg["num_iterations"] - 1, "engine_prev"))[1]
-    _, V_prev = release_of(one_train(shorter))
-    log(f"train of {cfg['num_iterations'] - 1} iterations for the check: "
-        f"{time.perf_counter() - t0:.2f} s")
-    t0 = time.perf_counter()
-    rows = reference.compare_train(U, V, V_prev, users, items, ratings,
-                                   cfg["reg"], spec["seed"], cfg["limits"])
-    log(f"reference: {time.perf_counter() - t0:.2f} s")
+    rows = [list(r) for r in checker.check(run)]
+    log(f"check: {time.perf_counter() - t0:.2f} s")
     return {"device": device, "memory": mem, "jobs": jobs,
             "window_s": window_s, "attempted": len(jobs),
             "failed": 0, "registry_before": before, "registry_after": after,
             "trace": tracer.reduced if tracer else None,
-            "correct_rows": rows, "shapes": {
-                "n_users": int(U.shape[0]), "n_items": int(V.shape[0]),
-                "rank": int(U.shape[1]),
-                "num_iterations": cfg["num_iterations"]}}
-
-
-def np_argsort_ids(vocab):
-    """Row order that puts a vocabulary of "1".."n" id strings in
-    numeric order (the program sorts them as strings)."""
-    import numpy as np
-
-    return np.argsort(np.asarray(vocab).astype(np.int64), kind="stable")
+            "correct_rows": rows, "shapes": checker.shapes(run)}
 
 
 # ---------------------------------------------------------------------------
@@ -427,6 +406,8 @@ def np_argsort_ids(vocab):
 # ---------------------------------------------------------------------------
 
 def write_queries(path: str, cfg: dict, traffic: dict, seed: int):
+    from benchmarks.lib import datagen
+
     users = datagen.query_users(cfg["n_users"], traffic["zipf_exponent"],
                                 traffic["rows_per_job"], seed)
     ids = datagen.entity_ids(cfg["n_users"], "u")
@@ -439,6 +420,8 @@ def write_queries(path: str, cfg: dict, traffic: dict, seed: int):
 
 def run_batchpredict_cell(spec: dict) -> dict:
     import numpy as np
+
+    from benchmarks.lib import reference
 
     cfg, traffic, work = spec["config"], spec["traffic"], spec["work"]
     pio("app", "new", APP)
@@ -525,7 +508,8 @@ def run_batchpredict_cell(spec: dict) -> dict:
             "registry_after": after,
             "trace": tracer.reduced if tracer else None,
             "correct_rows": rows, "shapes": {
-                "n_items": cfg["n_items"], "rank": cfg["rank"],
+                "n_items": cfg["n_items"],
+                "rank": cfg["algorithm_params"]["rank"],
                 "num": traffic["num"], "chunk_size": traffic["chunk_size"]}}
 
 

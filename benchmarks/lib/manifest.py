@@ -4,7 +4,11 @@ Everything that belongs to one configuration, one traffic mix, one layer
 metric or one cell is a file the harness finds by the name in
 BENCHMARK.json, so a later PR adds cells by adding files:
 
-    configs/<config>.json            sizes, source, reduced, assumed
+    configs/<config>.json            sizes, source, reduced, assumed; for a
+                                     cell that trains: template,
+                                     algorithm_params, events, check
+    events/<events>.py               the configuration's events from --seed
+    checks/<check>.py                its output check, with its reference
     traffic/<traffic>.json           the mix's parameters
     traffic/<cell name>.json         optional per-cell numbers (the frozen
                                      offered rate), laid over the mix
@@ -24,6 +28,10 @@ ROOT = os.path.dirname(BENCH_DIR)
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+#: the cell kinds child.py drives (a traffic file's `kind`)
+KINDS = ("train", "batchpredict")
+#: what a configuration of a `train` cell names, and the directory of each
+TRAIN_PARTS = {"events": "events", "check": "checks"}
 
 
 class ManifestError(ValueError):
@@ -72,6 +80,27 @@ def load_traffic(cell: dict, root: str = ROOT) -> dict:
     if os.path.exists(own):
         mix = {**mix, **_read_json(own)}
     return mix
+
+
+def train_parts(config: dict, root: str = ROOT) -> List[str]:
+    """What a configuration lacks of what a `train` cell needs of it: a
+    template, algorithm parameters, and an event generator and an output
+    check that are files. An empty list means nothing."""
+    who = f"config {config.get('name')}"
+    bad = []
+    if not isinstance(config.get("template"), str):
+        bad.append(f"{who} names no template")
+    if not isinstance(config.get("algorithm_params"), dict):
+        bad.append(f"{who} has no algorithm_params object")
+    for key, folder in TRAIN_PARTS.items():
+        part = config.get(key)
+        if not isinstance(part, str) or not part.isidentifier():
+            bad.append(f"{who} names no {key} (a module name under "
+                       f"{folder}/): {part!r}")
+        elif not os.path.exists(os.path.join(bench_dir(root), folder,
+                                             part + ".py")):
+            bad.append(f"{who}: no file {folder}/{part}.py for its {key}")
+    return bad
 
 
 def metrics_of_cell(bench: dict, cell_name: str, group: str) -> List[dict]:
@@ -157,6 +186,17 @@ def check(bench: dict, root: str = ROOT) -> List[str]:
                              f"{cell['traffic']}.json")
         if not os.path.exists(tfile):
             bad.append(f"workload {cell['name']}: no traffic file {tfile}")
+            continue
+        try:
+            kind = load_traffic(cell, root).get("kind")
+            if kind not in KINDS:
+                bad.append(f"workload {cell['name']}: traffic kind {kind!r} "
+                           f"is none of {KINDS}")
+            elif kind == "train" and cell["config"] in config_names:
+                bad.extend(train_parts(
+                    load_config(bench, cell["config"], root), root))
+        except ManifestError as e:     # a file named above that is not there
+            bad.append(str(e))
     for unused in config_names - used:
         bad.append(f"config {unused} is used by no cell")
     four = sum(1 for c in bench["workloads"] if c.get("chips") == 4)

@@ -23,8 +23,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-#: host annotations the benchmark writes start with this
-ANNOTATION_PREFIX = "bench_"
+#: the host annotations kept: the benchmark's own start with `bench_`,
+#: the program's spans (obs/tracing.span) with `pio:`
+ANNOTATION_PREFIXES = ("bench_", "pio:")
 
 #: control-flow operations span the operations inside them; they count
 #: towards busy time (a union) but are no entry of the top list
@@ -78,7 +79,7 @@ def load(path: str) -> dict:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith(ANNOTATION_PREFIX):
+                    if e.name.startswith(ANNOTATION_PREFIXES):
                         out["annotations"].append(
                             (e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9))
     out["annotations"].sort(key=lambda ev: ev[1])
@@ -106,6 +107,31 @@ def _innermost(annotations: Sequence[Event], t: float) -> Optional[str]:
     return best[0] if best else None
 
 
+def _span_name(annotation: str) -> str:
+    """`pio:ingest_scan` and `bench_host:prepare` -> the span's own name."""
+    if ":" in annotation:
+        return annotation.split(":", 1)[1]
+    return annotation[len("bench_"):]
+
+
+def gap_pieces(annotations: Sequence[Event], lo: float, hi: float,
+               position: str) -> List[Tuple[str, float]]:
+    """An idle gap cut where the innermost host annotation changes: each
+    piece is named by the span the host was in, a piece under no
+    annotation by the gap's `position` among the device operations."""
+    cuts = sorted({lo, hi} | {t for _, s, d in annotations
+                              for t in (s, s + d) if lo < t < hi})
+    pieces: List[List] = []
+    for a, b in zip(cuts, cuts[1:]):
+        note = _innermost(annotations, (a + b) / 2)
+        name = _span_name(note) if note else position
+        if pieces and pieces[-1][0] == name:
+            pieces[-1][1] += b - a
+        else:
+            pieces.append([name, b - a])
+    return [(n, t) for n, t in pieces]
+
+
 def reduce(trace: dict, window: Optional[Tuple[float, float]] = None,
            host_label: str = "host", top: int = 10) -> dict:
     """The numbers every cell's traced run reports.
@@ -114,7 +140,8 @@ def reduce(trace: dict, window: Optional[Tuple[float, float]] = None,
     by default it is the outermost `bench_job` annotation, else the span
     from the first to the last device event. Busy time is the union of
     "XLA Ops" intervals inside the window, averaged over the device
-    planes; gaps are named `<host_label>:<annotation or position>`.
+    planes; an idle gap is cut where the host's innermost span changes
+    and each piece named `<host_label>:<span or position>`.
     """
     jobs = [a for a in trace["annotations"] if a[0] == "bench_job"]
     devices = trace["devices"]
@@ -155,11 +182,9 @@ def reduce(trace: dict, window: Optional[Tuple[float, float]] = None,
             where = ("before_the_first_device_op" if i == 0 else
                      "after_the_last_device_op" if i == len(edges) - 2
                      else "between_device_ops")
-            note = _innermost(inner, (lo + hi) / 2)
-            if note:
-                where = note.split(":", 1)[-1] if ":" in note \
-                    else note[len(ANNOTATION_PREFIX):]
-            gaps.append((f"{host_label}:{where}", hi - lo))
+            gaps.extend((f"{host_label}:{name}", t)
+                        for name, t in gap_pieces(inner, lo, hi, where)
+                        if t >= MIN_GAP_S)
     window_s = w1 - w0
     busy_s = sum(busy_per_device) / len(busy_per_device)
     gaps.sort(key=lambda g: -g[1])

@@ -12,14 +12,17 @@ that comes from a device trace.
 
 The last line of stdout is the result: correct, attempted, failed,
 metrics (the cell's end_to_end metrics with --trace 0, its per_layer
-metrics with --trace 1) and device. Earlier lines carry each job's wall
-and each number compared with the reference beside its limit.
+metrics with --trace 1), device and, last, `checks`: each number the
+output check compared, with its limit. Earlier lines carry each job's
+wall and each compared number on a `CHECK` line, which are also the last
+lines on standard error.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import queue
 import shutil
@@ -185,13 +188,17 @@ def end_to_end(evidence: dict, kind: str) -> Dict[str, float]:
     return out
 
 
+def check_lines(rows) -> List[str]:
+    return [f"CHECK {name} value={value:.6g} limit={limit:.6g} "
+            f"{'ok' if ok else 'NOT OK'}" for name, value, limit, ok in rows]
+
+
 def result_line(bench: dict, cell: dict, evidence: dict, trace: bool,
                 tiny: bool, root: str = ROOT) -> dict:
     kind = evidence["kind"]
     rows = evidence.get("correct_rows") or []
-    for name, value, limit, ok in rows:
-        print(f"CHECK {name} value={value:.6g} limit={limit:.6g} "
-              f"{'ok' if ok else 'NOT OK'}", flush=True)
+    for text in check_lines(rows):
+        print(text, flush=True)
     correct = bool(rows) and all(r[3] for r in rows) \
         and not evidence.get("failed")
     metrics: Dict[str, dict] = {}
@@ -224,6 +231,12 @@ def result_line(bench: dict, cell: dict, evidence: dict, trace: bool,
         device["window_s"] = t["window_s"]
         line["breakdown"] = {"device_ops": t["device_ops_top"],
                              "idle_gaps": t["idle_gaps_top"]}
+    # a value that is not finite has no JSON number: null (its CHECK
+    # line says which it was)
+    line["checks"] = {
+        name: {"value": value if math.isfinite(value) else None,
+               "limit": limit, "ok": ok}
+        for name, value, limit, ok in rows}
     return line
 
 
@@ -236,6 +249,14 @@ def build_spec(bench: dict, cell: dict, args, work: str,
         traffic = {**traffic, **traffic.get("tiny", {})}
     config.pop("tiny", None)
     traffic.pop("tiny", None)
+    if traffic.get("kind") not in manifest.KINDS:
+        raise manifest.ManifestError(
+            f"traffic {cell['traffic']}: kind {traffic.get('kind')!r} is "
+            f"none of {manifest.KINDS}")
+    missing = manifest.train_parts(config, root) \
+        if traffic["kind"] == "train" else []
+    if missing:
+        raise manifest.ManifestError("; ".join(missing))
     return {"workload": cell["name"], "config": config, "traffic": traffic,
             "seed": args.seed, "seconds": args.seconds,
             "trace": bool(args.trace) and not args.tiny,
@@ -280,6 +301,9 @@ def main(argv=None) -> int:
         if child is not None:
             child.kill()
         shutil.rmtree(work, ignore_errors=True)
+    # the compared numbers once more, as the last lines of standard error
+    for text in check_lines(evidence.get("correct_rows") or []):
+        print(text, file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

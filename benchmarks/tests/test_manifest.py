@@ -57,15 +57,59 @@ def copy(tmp_path):
     return str(root)
 
 
-def test_additions_are_files_only(copy):
-    """README.md's four recipes, carried out."""
-    bdir = os.path.join(copy, "benchmarks")
-    with open(os.path.join(copy, "BENCHMARK.json")) as f:
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+
+
+def add_sessionrec_cell(copy: str, parts=("configs/seq-tiny.json",
+                                          "events/sessions.py",
+                                          "checks/seqrec_loss.py")) -> dict:
+    """README.md's recipe "a training configuration of another template",
+    carried out with the test data under tests/data/sessionrec: new files
+    under configs/, events/ and checks/, a cell over the mix that is
+    there, and the cell appended to the metrics it reports. Nothing that
+    was there is edited."""
+    for part in parts:
+        shutil.copy(os.path.join(DATA, "sessionrec", os.path.basename(part)),
+                    os.path.join(copy, "benchmarks", part))
+    path = os.path.join(copy, "BENCHMARK.json")
+    with open(path) as f:
         bench = json.load(f)
+    bench["configs"].append({
+        "name": "seq-tiny",
+        "source": "https://example.org/test-data-not-a-supported-model",
+        "file": "benchmarks/configs/seq-tiny.json", "reduced": [],
+        "why": "test data: a sessionrec train at a size the CPU holds"})
+    bench["workloads"].append({
+        "name": "seq-tiny.train", "config": "seq-tiny",
+        "traffic": "train-backtoback", "chips": 1,
+        "why": "test data: whole sessionrec trains back to back"})
+    for group, name in (("end_to_end", "train_wall_s"),
+                        ("per_layer", "train_wall_median_s"),
+                        ("per_layer", "compiles_in_window.train")):
+        next(m for m in bench[group] if m["name"] == name)[
+            "workloads"].append("seq-tiny.train")
+    with open(path, "w") as f:
+        json.dump(bench, f)
+    return bench
+
+
+def test_additions_are_files_only(copy):
+    """README.md's recipes, carried out."""
+    bdir = os.path.join(copy, "benchmarks")
+    # a training configuration of another template: three files, entries
+    bench = add_sessionrec_cell(copy)
+    assert manifest.check(bench, copy) == []
+    args = argparse.Namespace(tiny=True, seed=3, seconds=1.0, trace=0)
+    spec = run.build_spec(bench, manifest.find_cell(bench, "seq-tiny.train"),
+                          args, "/nonexistent", copy)
+    assert (spec["config"]["template"], spec["config"]["events"],
+            spec["config"]["check"]) == ("sessionrec", "sessions", "seqrec_loss")
+    assert spec["config"]["algorithm_params"]["d_model"] == 32
     # a configuration: a file of sizes
     with open(os.path.join(bdir, "configs", "rec-ml20m-r64.json")) as f:
         cfg = json.load(f)
-    cfg.update(name="rec-new-r32", rank=32)
+    cfg.update(name="rec-new-r32",
+               algorithm_params={"rank": 32, "num_iterations": 20})
     with open(os.path.join(bdir, "configs", "rec-new-r32.json"), "w") as f:
         json.dump(cfg, f)
     bench["configs"].append({
@@ -100,11 +144,13 @@ def test_additions_are_files_only(copy):
     cell = manifest.find_cell(bench, "new-r32.train-cold")
     args = argparse.Namespace(tiny=True, seed=3, seconds=1.0, trace=0)
     spec = run.build_spec(bench, cell, args, "/nonexistent", copy)
-    assert spec["config"]["rank"] == 8          # the tiny section applies
+    # the tiny section applies
+    assert spec["config"]["algorithm_params"]["rank"] == 8
     assert spec["traffic"] == {"kind": "train", "warm_jobs": 2}
     args.tiny = False
     spec = run.build_spec(bench, cell, args, "/nonexistent", copy)
-    assert spec["config"]["rank"] == 32 and spec["config"]["n_users"] == 138_493
+    assert spec["config"]["algorithm_params"]["rank"] == 32
+    assert spec["config"]["n_users"] == 138_493
     # the new metric is read from evidence by its file alone
     evidence = {"kind": "train", "jobs": [
         {"wall_s": 1.0, "spans": {"ingest_intern": 0.25}},
@@ -211,12 +257,9 @@ def test_a_count_function_and_a_reduction_are_files(copy, monkeypatch):
     with open(os.path.join(bdir, "counts", "copy_kernel.py"), "w") as f:
         f.write("def counts(evidence, reader, n_events):\n"
                 "    return 0.0, n_events * 819e9\n")
-    os.makedirs(os.path.join(bdir, "readers"))
-    for name, body in (("__init__", ""), ("job_count", (
-            "def read(evidence, reader):\n"
-            "    return float(len(evidence.get('jobs', [])))\n"))):
-        with open(os.path.join(bdir, "readers", name + ".py"), "w") as f:
-            f.write(body)
+    with open(os.path.join(bdir, "readers", "job_count.py"), "w") as f:
+        f.write("def read(evidence, reader):\n"
+                "    return float(len(evidence.get('jobs', [])))\n")
     for mod in [m for m in sys.modules if m.split(".")[0] == "benchmarks"]:
         monkeypatch.delitem(sys.modules, mod)
     monkeypatch.syspath_prepend(copy)
@@ -227,3 +270,47 @@ def test_a_count_function_and_a_reduction_are_files(copy, monkeypatch):
     assert readers.read(ev, {"reader": {
         "kind": "trace_roofline", "pattern": "copy_kernel",
         "counts": "copy_kernel"}}) == pytest.approx(50.0)
+
+
+@pytest.mark.parametrize("lack", ["events", "check", "algorithm_params",
+                                  "template"])
+def test_a_train_configuration_must_bring_its_parts(copy, lack):
+    """A `train` cell's configuration that names no generator or check
+    (or names one with no file) is refused before any run, by check()
+    and by run.py's build_spec alike."""
+    bench = add_sessionrec_cell(copy)
+    cell = manifest.find_cell(bench, "seq-tiny.train")
+    args = argparse.Namespace(tiny=True, seed=3, seconds=1.0, trace=0)
+    path = os.path.join(copy, "benchmarks", "configs", "seq-tiny.json")
+    with open(path) as f:
+        cfg = json.load(f)
+    if lack in manifest.TRAIN_PARTS:
+        # the name without its file
+        os.remove(os.path.join(copy, "benchmarks", manifest.TRAIN_PARTS[lack],
+                               cfg[lack] + ".py"))
+        assert any(f"for its {lack}" in b for b in manifest.check(bench, copy))
+        with pytest.raises(manifest.ManifestError, match="no file"):
+            run.build_spec(bench, cell, args, "/nonexistent", copy)
+    del cfg[lack]
+    with open(path, "w") as f:
+        json.dump(cfg, f)
+    assert any("seq-tiny" in b and lack in b
+               for b in manifest.check(bench, copy))
+    with pytest.raises(manifest.ManifestError, match=lack):
+        run.build_spec(bench, cell, args, "/nonexistent", copy)
+
+
+def test_a_mix_of_an_unknown_kind_is_refused(copy):
+    from benchmarks import child
+
+    assert set(child.KINDS) == set(manifest.KINDS)
+    with open(os.path.join(copy, "benchmarks", "traffic", "serve.json"), "w") as f:
+        json.dump({"kind": "serve"}, f)
+    bench = manifest.load_benchmark(copy)
+    bench["workloads"].append({
+        "name": "x.serve", "config": bench["configs"][0]["name"],
+        "traffic": "serve", "chips": 1, "why": "a kind the harness has not"})
+    assert any("kind 'serve'" in b for b in manifest.check(bench, copy))
+    with pytest.raises(manifest.ManifestError, match="kind 'serve'"):
+        run.build_spec(bench, bench["workloads"][-1], argparse.Namespace(
+            tiny=True, seed=1, seconds=1.0, trace=0), "/nonexistent", copy)

@@ -67,3 +67,48 @@ def test_a_trace_without_device_operations_is_refused():
 def test_union_merges_overlaps():
     busy, merged = T.union_s([("a", 0.0, 1.0), ("b", 0.5, 1.0), ("c", 3.0, 1.0)])
     assert busy == pytest.approx(2.5) and merged == [(0.0, 1.5), (3.0, 4.0)]
+
+
+def test_a_gap_is_cut_where_the_programs_span_changes():
+    """The program's `pio:<span>` annotations are kept beside the
+    benchmark's own, and the one long gap before the first device
+    operation is cut where the innermost span changes: the breakdown
+    names what the host was doing, not a position."""
+    trace = {
+        "devices": {"/device:TPU:0": {
+            "ops": [("%fusion.1 = f32[8] fusion(%a)", 14.0, 3.0),
+                    ("%fusion.2 = f32[8] fusion(%b)", 17.0005, 0.5)],
+            "modules": [("jit_train(1)", 14.0, 3.5005)]}},
+        "annotations": sorted([
+            ("bench_job", 10.0, 8.0),
+            ("pio:train_read", 10.1, 2.9),        # parent of the next three
+            ("pio:ingest_digest", 10.1, 0.8),
+            ("pio:ingest_scan", 10.9, 1.3),
+            ("pio:ingest_decode", 12.2, 0.7),
+            ("pio:als_pack", 13.1, 0.6),
+            ("pio:train_persist", 17.6, 0.1)], key=lambda a: a[1])}
+    r = T.reduce(trace, host_label="train_host", top=20)
+    assert r["window_s"] == pytest.approx(8.0)
+    assert r["busy_s"] == pytest.approx(3.5)
+    gaps = dict(r["idle_gaps_top"])
+    assert gaps["train_host:ingest_scan"] == pytest.approx(1.3)
+    assert gaps["train_host:ingest_digest"] == pytest.approx(0.8)
+    assert gaps["train_host:ingest_decode"] == pytest.approx(0.7)
+    assert gaps["train_host:als_pack"] == pytest.approx(0.6)
+    # inside train_read but under none of its children
+    assert gaps["train_host:train_read"] == pytest.approx(0.1)
+    assert gaps["train_host:train_persist"] == pytest.approx(0.1)
+    assert gaps["train_host:between_device_ops"] == pytest.approx(5e-4)
+    # what no span covers keeps the gap's position, once per stretch
+    before = [t for n, t in r["idle_gaps_top"]
+              if n == "train_host:before_the_first_device_op"]
+    assert sorted(before) == pytest.approx([0.1, 0.1, 0.3])
+    # the pieces add up to the idle time
+    assert sum(t for _, t in r["idle_gaps_top"]) == pytest.approx(
+        r["window_s"] - r["busy_s"])
+
+
+def test_load_keeps_both_kinds_of_annotation():
+    assert "pio:als_solve".startswith(T.ANNOTATION_PREFIXES)
+    assert "bench_job".startswith(T.ANNOTATION_PREFIXES)
+    assert not "$core.py:331 dispatch".startswith(T.ANNOTATION_PREFIXES)

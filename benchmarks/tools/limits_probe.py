@@ -51,8 +51,9 @@ def train(cfg, seeds):
 
     enable_compile_cache()
     ctx = WorkflowContext.create(mode="Training", batch="")
-    n = cfg["num_iterations"]
-    algos = [ALSAlgorithm(AlgorithmParams(rank=cfg["rank"], num_iterations=it,
+    algo = cfg["algorithm_params"]
+    n = algo["num_iterations"]
+    algos = [ALSAlgorithm(AlgorithmParams(rank=algo["rank"], num_iterations=it,
                                           reg=cfg["reg"]))
              for it in (n, n - 1)]
     for seed in seeds:
@@ -102,7 +103,8 @@ def score(cfg, seeds, rows=256, num=10):
     ids_u = datagen.entity_ids(cfg["n_users"], "u")
     ids_i = datagen.entity_ids(cfg["n_items"], "i")
     for seed in seeds:
-        gen = datagen.factors(cfg["n_users"], cfg["n_items"], cfg["rank"], seed)
+        gen = datagen.factors(cfg["n_users"], cfg["n_items"],
+                              cfg["algorithm_params"]["rank"], seed)
         model = ALSModel(user_vocab=ids_u, item_vocab=ids_i, U=gen["U"], V=gen["V"])
         users = datagen.query_users(cfg["n_users"], 1.0, rows, seed)
         _, scores, idx, _ = model._score_topk(

@@ -21,7 +21,7 @@ TPU-native design:
 from __future__ import annotations
 
 import dataclasses
-import functools
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -30,14 +30,22 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.core.params import Params
+from predictionio_tpu.obs import jax_stats, train_stats
+from predictionio_tpu.obs.tracing import span
+from predictionio_tpu.ops import moe
 from predictionio_tpu.ops.attention import (
-    blockwise_attention, ring_attention_traced,
+    blockwise_attention, ring_attention_traced, rope,
 )
 
 
 @dataclasses.dataclass
 class SeqRecParams(Params):
-    """Hyperparameters; json keys camelCase per engine.json convention."""
+    """Hyperparameters; json keys camelCase per engine.json convention.
+
+    The layer spec (`mixer` .. `first_dense_layers`) says what a block is
+    made of. Its defaults are the block this model began with: pre-LN,
+    fused multi-head attention, a 4d GELU feed-forward, learned positions
+    and a tied softmax."""
 
     d_model: int = 64
     n_heads: int = 2
@@ -53,100 +61,396 @@ class SeqRecParams(Params):
     #: a mesh with a "seq" axis; serving always uses the local kernel.
     attention_impl: str = "flash"
 
+    # -- the layer spec -------------------------------------------------
+    #: "mha": fused q/k/v of d_model / n_heads a head. "mla": latent
+    #: attention — per-head queries of qk_nope + qk_rope, keys and values
+    #: expanded from one shared latent of kv_lora_rank, one rotary key of
+    #: qk_rope shared by all heads, values of v_head_dim.
+    mixer: str = "mha"
+    #: "gelu" (two matrices), "swiglu" (three), or "moe": routed SwiGLU
+    #: experts of moe_width plus one shared SwiGLU of n_shared_experts x
+    #: moe_width, after `first_dense_layers` layers of dense swiglu
+    ffn: str = "gelu"
+    #: "layer" (scale and bias) or "rms" (scale)
+    norm: str = "layer"
+    norm_eps: float = 1e-6
+    #: "learned" (a table of max_len rows added to the embeddings) or
+    #: "rope" (rotary, in attention; halves pairing, ops/attention.rope)
+    positions: str = "learned"
+    rope_theta: float = 10000.0
+    #: softmax over the item embeddings, or over a head matrix of its own
+    tied_head: bool = True
+    #: width of a dense feed-forward; 0 = 4 d_model
+    ffn_width: int = 0
+    first_dense_layers: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    kv_lora_rank: int = 0
+    #: the router's width: every expert of a layer, wherever it lives
+    n_routed_experts: int = 0
+    #: [first, end) of them are held (and trained) here; the others lie
+    #: on other chips and what they would add is left out (ops/moe.py)
+    held_experts: Sequence[int] = (0, 0)
+    experts_per_token: int = 0
+    moe_width: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    #: after each step b += rate * sign(mean load - load) on the router's
+    #: selection bias (auxiliary-loss-free balancing); 0 leaves it at 0
+    bias_update_rate: float = 0.0
+    #: coefficient of the sequence-wise balance loss, summed over layers
+    balance_loss_alpha: float = 0.0
+
+    #: draw the initial weights on the device (jax.random) instead of on
+    #: the host in numpy: the same seed gives the same weights either way,
+    #: but not the same as the other way
+    device_init: bool = False
+
+    #: memory for time: recompute each block in the backward pass
+    #: instead of keeping what it computed, and run feed-forwards and the
+    #: softmax loss `TOKEN_BLOCK` tokens at a time. It changes what is
+    #: kept, never what is computed (`MEMORY_FIELDS`: no part of a run's
+    #: identity)
+    remat: bool = False
+
+    def ffn_kind(self, layer: int) -> str:
+        if self.ffn == "moe" and layer < self.first_dense_layers:
+            return "swiglu"
+        return self.ffn
+
+    def dense_width(self) -> int:
+        return self.ffn_width or 4 * self.d_model
+
+    def spec_key(self, memory: bool = True) -> Tuple:
+        """Every hyperparameter that shapes a train's program (epochs
+        excluded: training further is the resume use case), hashable;
+        without `memory`, those that shape its trajectory: a run resumed
+        under another memory setting is the same run."""
+        spec = dataclasses.asdict(self)
+        for name in ("epochs",) + (() if memory else MEMORY_FIELDS):
+            del spec[name]
+        return tuple(sorted((k, tuple(v) if isinstance(v, (list, tuple))
+                             else v) for k, v in spec.items()))
+
+    def check(self) -> None:
+        for name, kinds in (("mixer", ("mha", "mla")),
+                            ("ffn", ("gelu", "swiglu", "moe")),
+                            ("norm", ("layer", "rms")),
+                            ("positions", ("learned", "rope")),
+                            ("attention_impl", ("flash", "ring"))):
+            if getattr(self, name) not in kinds:
+                raise ValueError(f"unknown {name} {getattr(self, name)!r}: "
+                                 f"expected one of {kinds}")
+        if self.ffn == "moe":
+            lo, hi = self.held_experts
+            if not 0 <= lo < hi <= self.n_routed_experts:
+                raise ValueError(
+                    f"held_experts {tuple(self.held_experts)} is no range "
+                    f"of the {self.n_routed_experts} routed experts")
+            if not 0 < self.experts_per_token <= self.n_routed_experts:
+                raise ValueError("experts_per_token must be 1.."
+                                 "n_routed_experts")
+
+
+#: settings that change where a train's work lies and what it keeps in
+#: memory, not what it computes
+MEMORY_FIELDS = ("attention_impl", "remat")
+
+#: query and key block of the attention, and the tokens a feed-forward or
+#: the loss takes at a time under `remat`. Constants, from one chip run
+#: each at 16,384 tokens a step of 8,192-token sessions (PERF.md section
+#: 6, PR 27): a step took 1.27 s with attention blocks of 512, 1.35 at
+#: 1024, 1.32 at 2048; 1.35, 1.35, 1.36 with token blocks of 1024, 2048,
+#: 4096; the compiler counted the same temporaries for all of them.
+ATTENTION_BLOCK = 512
+TOKEN_BLOCK = 2048
+
 
 def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
                 vocab_multiple: int = 1) -> Dict:
     """Weights as a pytree. Vocabulary row 0 is the padding item; the table
     is padded up to a multiple of the tp axis size so it shards evenly
-    (dead rows never appear as targets and are masked at predict time)."""
+    (dead rows never appear as targets and are masked at predict time).
+    Matrices are N(0, 1/n_in), the tables N(0, 1/d); with `device_init`
+    the draws are jax.random's from the seed, leaf by leaf."""
+    p.check()
     d, v = p.d_model, n_items + 1
     v = -(-v // vocab_multiple) * vocab_multiple
-    scale = d ** -0.5
+    key = jax.random.key(p.seed)
+    drawn = 0
 
-    def norm():
-        return {"scale": jnp.ones((d,), jnp.float32),
-                "bias": jnp.zeros((d,), jnp.float32)}
+    def normal(shape, std):
+        nonlocal drawn
+        drawn += 1
+        if p.device_init:
+            return jax.random.normal(jax.random.fold_in(key, drawn), shape,
+                                     jnp.float32) * jnp.float32(std)
+        return jnp.asarray(rng.normal(size=shape) * std, jnp.float32)
+
+    def norm(width=d):
+        w = {"scale": jnp.ones((width,), jnp.float32)}
+        if p.norm == "layer":
+            w["bias"] = jnp.zeros((width,), jnp.float32)
+        return w
 
     def dense(n_in, n_out):
-        return jnp.asarray(
-            rng.normal(size=(n_in, n_out)) * (n_in ** -0.5), jnp.float32)
+        return normal((n_in, n_out), n_in ** -0.5)
 
-    layers = []
-    for _ in range(p.n_layers):
-        layers.append({
-            "ln1": norm(), "ln2": norm(),
-            "wqkv": dense(d, 3 * d), "wo": dense(d, d),
-            "w1": dense(d, 4 * d), "w2": dense(4 * d, d),
-        })
-    return {
-        "emb": jnp.asarray(rng.normal(size=(v, d)) * scale, jnp.float32),
-        "pos": jnp.asarray(rng.normal(size=(p.max_len, d)) * scale,
-                           jnp.float32),
-        "ln_f": norm(),
-        "layers": layers,
-    }
+    def swiglu(width, experts=()):
+        return {"w_gate": normal((*experts, d, width), d ** -0.5),
+                "w_up": normal((*experts, d, width), d ** -0.5),
+                "w_down": normal((*experts, width, d), width ** -0.5)}
+
+    def mixer():
+        if p.mixer == "mha":
+            return {"wqkv": dense(d, 3 * d), "wo": dense(d, d)}
+        h = p.n_heads
+        return {"wq": dense(d, h * (p.qk_nope_head_dim + p.qk_rope_head_dim)),
+                "wkva": dense(d, p.kv_lora_rank + p.qk_rope_head_dim),
+                "kv_norm": norm(p.kv_lora_rank),
+                "wkvb": dense(p.kv_lora_rank,
+                              h * (p.qk_nope_head_dim + p.v_head_dim)),
+                "wo": dense(h * p.v_head_dim, d)}
+
+    def ffn(i):
+        kind = p.ffn_kind(i)
+        if kind == "gelu":
+            return {"w1": dense(d, p.dense_width()),
+                    "w2": dense(p.dense_width(), d)}
+        if kind == "swiglu":
+            return swiglu(p.dense_width())
+        lo, hi = p.held_experts
+        out = {"router": dense(d, p.n_routed_experts),
+               "router_bias": jnp.zeros((p.n_routed_experts,), jnp.float32),
+               "experts": swiglu(p.moe_width, (hi - lo,))}
+        if p.n_shared_experts:
+            out["shared"] = swiglu(p.n_shared_experts * p.moe_width)
+        return out
+
+    # draws in this order: the host path's are the original block's
+    layers = [{"ln1": norm(), "ln2": norm(), **mixer(), **ffn(i)}
+              for i in range(p.n_layers)]
+    params = {"emb": normal((v, d), d ** -0.5)}
+    if p.positions == "learned":
+        params["pos"] = normal((p.max_len, d), d ** -0.5)
+    params["ln_f"] = norm()
+    params["layers"] = layers
+    if not p.tied_head:
+        params["head"] = dense(d, v)
+    return params
 
 
-def _layer_norm(x, ln):
+def _rms_norm(x, scale, eps):
+    return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * scale
+
+
+def _norm(x, w, p: SeqRecParams):
+    if p.norm == "rms":
+        return _rms_norm(x, w["scale"], p.norm_eps)
     mu = x.mean(-1, keepdims=True)
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + 1e-6) * ln["scale"] + ln["bias"]
+    return (x - mu) * jax.lax.rsqrt(var + p.norm_eps) * w["scale"] + w["bias"]
 
 
-def forward(params: Dict, seqs: jax.Array, n_heads: int,
-            mesh: Optional[Mesh] = None,
-            attention_impl: str = "flash") -> jax.Array:
+def _by_token_blocks(fn, p: SeqRecParams, *arrays):
+    """fn over [T, ...] arrays; under `remat`, `TOKEN_BLOCK` rows at a
+    time with each block's internals recomputed in the backward pass
+    (all at once when that does not divide T)."""
+    t, block = arrays[0].shape[0], TOKEN_BLOCK
+    if not p.remat or t <= block or t % block:
+        return fn(*arrays)
+    out = jax.lax.map(
+        jax.checkpoint(lambda xs: fn(*xs)),
+        tuple(a.reshape(t // block, block, *a.shape[1:]) for a in arrays))
+    return jax.tree.map(lambda o: o.reshape(t, *o.shape[2:]), out)
+
+
+def _swiglu(w, x):
+    return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
+
+
+def _attention(layer, x, key_mask, p: SeqRecParams, mesh, use_ring):
+    """The mixer on normed x [B, L, D] -> [B, L, D]."""
+    b, l, d = x.shape
+    h = p.n_heads
+    positions = jnp.arange(l)
+    if p.mixer == "mha":
+        q, k, v = (t.reshape(b, l, h, d // h) for t in
+                   jnp.split(x @ layer["wqkv"], 3, axis=-1))    # MXU
+        if p.positions == "rope":
+            q, k = (rope(t, positions, p.rope_theta) for t in (q, k))
+    else:
+        nope, rot = p.qk_nope_head_dim, p.qk_rope_head_dim
+        q = (x @ layer["wq"]).reshape(b, l, h, nope + rot)
+        latent, k_rot = jnp.split(x @ layer["wkva"], [p.kv_lora_rank], -1)
+        kv = (_rms_norm(latent, layer["kv_norm"]["scale"], p.norm_eps)
+              @ layer["wkvb"]).reshape(b, l, h, -1)
+        k_nope, v = jnp.split(kv, [nope], axis=-1)
+        # one rotary key for all heads; the queries' rotary part per head
+        k_rot = rope(k_rot[:, :, None, :], positions, p.rope_theta)
+        q = jnp.concatenate(
+            [q[..., :nope], rope(q[..., nope:], positions, p.rope_theta)], -1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rot, (b, l, h, rot))], -1)
+    if use_ring:
+        att = ring_attention_traced(q, k, v, mesh, axis="seq", causal=True,
+                                    key_mask=key_mask)
+    else:
+        att = blockwise_attention(q, k, v, block_k=ATTENTION_BLOCK,
+                                  causal=True, key_mask=key_mask)
+    return att.reshape(b, l, -1) @ layer["wo"]
+
+
+def _moe(layer, x, p: SeqRecParams):
+    """The expert layer on normed x [B, L, D] -> ([B, L, D], balance
+    numbers). Every position is routed, padding too (its output is cut at
+    the end of the forward pass)."""
+    b, l, d = x.shape
+    flat = x.reshape(b * l, d)
+    with jax.named_scope("seqrec_router"):
+        routing = moe.route(flat, layer["router"], layer["router_bias"],
+                            p.experts_per_token, p.routed_scaling_factor,
+                            p.norm_topk_prob)
+    with jax.named_scope("seqrec_experts"):
+        ex = layer["experts"]
+        y, held_tokens, dropped = moe.held_experts(
+            flat, ex["w_gate"], ex["w_up"], ex["w_down"], routing,
+            p.held_experts[0], pass_rows=b * l)
+    if "shared" in layer:
+        with jax.named_scope("seqrec_shared_expert"):
+            y = y + _by_token_blocks(
+                lambda t: _swiglu(layer["shared"], t), p, flat)
+    stats = {"load": moe.expert_load(routing.experts, p.n_routed_experts),
+             "held_tokens": held_tokens, "dropped": dropped,
+             "balance": moe.sequence_balance_loss(routing, b)}
+    return y.reshape(b, l, d), stats
+
+
+def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
+             mesh: Optional[Mesh] = None) -> Tuple[jax.Array, List[Dict]]:
+    """[B, L] int32 item ids (0 = pad) -> ([B, L, D] hidden states, the
+    balance numbers of each expert layer)."""
+    b, l = seqs.shape
+    h = params["emb"][seqs]
+    if "pos" in params:
+        h = h + params["pos"][None, :l]
+    pad = (seqs == 0)[..., None]
+    key_mask = seqs != 0       # left-padding sits in the causal PAST; the
+    use_ring = (p.attention_impl == "ring" and mesh is not None
+                and "seq" in mesh.axis_names)
+    if p.attention_impl == "ring" and not use_ring:
+        raise ValueError('attention_impl="ring" requires a mesh with a '
+                         '"seq" axis')
+
+    def block(h, layer, kind):     # key mask keeps it out of the softmax
+        with jax.named_scope("seqrec_attention"):
+            h = h + _attention(layer, _norm(h, layer["ln1"], p), key_mask,
+                               p, mesh, use_ring)
+        x = _norm(h, layer["ln2"], p)
+        if kind == "moe":
+            y, stats = _moe(layer, x, p)
+            return h + y, stats
+        fn = (lambda t: jax.nn.gelu(t @ layer["w1"]) @ layer["w2"]) \
+            if kind == "gelu" else (lambda t: _swiglu(layer, t))
+        y = _by_token_blocks(fn, p, x.reshape(b * l, -1))
+        return h + y.reshape(b, l, -1), None
+
+    if p.remat:
+        block = jax.checkpoint(block, static_argnums=2)
+    expert_layers = []
+    for i, layer in enumerate(params["layers"]):
+        h, stats = block(h, layer, p.ffn_kind(i))
+        if stats is not None:
+            expert_layers.append(stats)
+    return jnp.where(pad, 0.0, _norm(h, params["ln_f"], p)), expert_layers
+
+
+def forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
+            mesh: Optional[Mesh] = None) -> jax.Array:
     """[B, L] int32 item ids (0 = pad) -> [B, L, D] hidden states.
 
     attention_impl="ring" + a mesh with a "seq" axis runs the attention
     sequence-parallel (ring_attention_traced): each device holds L/p of
     the sequence and K/V blocks rotate via ppermute — exact, O(L/p) HBM
     per device."""
-    b, l = seqs.shape
-    d = params["emb"].shape[1]
-    h = params["emb"][seqs] + params["pos"][None, :l]
-    pad = (seqs == 0)[..., None]
-    key_mask = seqs != 0       # left-padding sits in the causal PAST; the
-    if attention_impl not in ("flash", "ring"):
-        raise ValueError(f"unknown attention_impl {attention_impl!r}: "
-                         "expected 'flash' or 'ring'")
-    use_ring = (attention_impl == "ring" and mesh is not None
-                and "seq" in mesh.axis_names)
-    if attention_impl == "ring" and not use_ring:
-        raise ValueError('attention_impl="ring" requires a mesh with a '
-                         '"seq" axis')
-    for layer in params["layers"]:  # key mask keeps it out of the softmax
-        x = _layer_norm(h, layer["ln1"])
-        qkv = x @ layer["wqkv"]                       # [B, L, 3D] MXU
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        split = lambda t: t.reshape(b, l, n_heads, d // n_heads)
-        if use_ring:
-            att = ring_attention_traced(
-                split(q), split(k), split(v), mesh, axis="seq",
-                causal=True, key_mask=key_mask)
-        else:
-            att = blockwise_attention(split(q), split(k), split(v),
-                                      causal=True, key_mask=key_mask)
-        h = h + att.reshape(b, l, d) @ layer["wo"]
-        x = _layer_norm(h, layer["ln2"])
-        h = h + jax.nn.gelu(x @ layer["w1"]) @ layer["w2"]
-    return jnp.where(pad, 0.0, _layer_norm(h, params["ln_f"]))
+    return _forward(params, seqs, p, mesh)[0]
 
 
-def _loss_fn(params, seqs, targets, n_heads, mesh=None,
-             attention_impl="flash"):
-    """Next-item softmax cross-entropy, tied output embedding, pad-masked."""
-    hidden = forward(params, seqs, n_heads, mesh, attention_impl)  # [B,L,D]
-    logits = hidden @ params["emb"].T                 # [B, L, V] MXU
-    mask = (targets > 0).astype(jnp.float32)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+def head_matrix(params: Dict) -> jax.Array:
+    """[D, V]: the head the spec names, the item embeddings when tied."""
+    return params["head"] if "head" in params else params["emb"].T
+
+
+def _loss_fn(params, seqs, targets, p: SeqRecParams, mesh=None):
+    """Next-item softmax cross-entropy, pad-masked, plus the expert
+    layers' balance loss. -> (loss, the expert layers' balance numbers)."""
+    hidden, expert_layers = _forward(params, seqs, p, mesh)       # [B,L,D]
+    head = head_matrix(params)
+
+    def nll_of(hid, tgt):
+        logits = hid @ head                           # [T, V] MXU
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+        return nll * (tgt > 0)
+
+    with jax.named_scope("seqrec_head_loss"):
+        nll = _by_token_blocks(nll_of, p,
+                               hidden.reshape(-1, hidden.shape[-1]),
+                               targets.reshape(-1))
+        loss = nll.sum() / jnp.maximum((targets > 0).sum(), 1)
+    if p.balance_loss_alpha and expert_layers:
+        loss = loss + p.balance_loss_alpha * sum(
+            s["balance"] for s in expert_layers)
+    return loss, expert_layers
+
+
+def grad_group(path) -> str:
+    """The group a parameter's gradient norm is recorded under: tables
+    and head by name, a layer's parameters by layer and part."""
+    names = [str(getattr(k, "key", getattr(k, "idx", k))) for k in path]
+    if names[0] != "layers":
+        return {"emb": "embedding", "pos": "positions",
+                "ln_f": "final_norm"}.get(names[0], names[0])
+    part = {"router": "router", "router_bias": "router",
+            "experts": "experts", "shared": "shared_expert",
+            "ln1": "norms", "ln2": "norms"}.get(
+        names[2], "attention" if names[2] in (
+            "wqkv", "wq", "wkva", "kv_norm", "wkvb", "wo") else "ffn")
+    return f"layer{names[1]}.{part}"
+
+
+def _group_norms(grads) -> Dict[str, jax.Array]:
+    squares: Dict[str, jax.Array] = {}
+    for path, g in jax.tree_util.tree_leaves_with_path(grads):
+        name = grad_group(path)
+        squares[name] = squares.get(name, 0.0) + (g.astype(
+            jnp.float32) ** 2).sum()
+    return {name: jnp.sqrt(v) for name, v in squares.items()}
+
+
+def make_optimizer(p: SeqRecParams):
+    """adamw; a router's selection bias is no trained parameter (it
+    takes no gradient and must not decay)."""
+    import optax
+
+    if p.ffn != "moe":
+        return optax.adamw(p.learning_rate)
+    return optax.adamw(
+        p.learning_rate, mask=lambda params:
+        jax.tree_util.tree_map_with_path(
+            lambda path, _: getattr(path[-1], "key", None) != "router_bias",
+            params))
 
 
 def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
-    """One donated jitted step. With a mesh, batch is sharded over "data"
-    and embedding/ffn rows over "model"; XLA inserts the psums."""
+    """One donated jitted step -> (params, opt_state, the step's numbers:
+    loss, by group the gradient's norm and the norm of what the step
+    added to the parameters, and per expert layer the tokens routed to
+    each expert, to each held expert, and dropped). With a mesh,
+    batch is sharded over "data" and embedding/ffn rows over "model";
+    XLA inserts the psums."""
 
     def step(params, opt_state, seqs, targets):
         if mesh is not None and "data" in mesh.axis_names:
@@ -157,11 +461,24 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
             sh = NamedSharding(mesh, P("data", seq_dim))
             seqs = jax.lax.with_sharding_constraint(seqs, sh)
             targets = jax.lax.with_sharding_constraint(targets, sh)
-        loss, grads = jax.value_and_grad(_loss_fn)(
-            params, seqs, targets, p.n_heads, mesh, p.attention_impl)
+        (loss, expert_layers), grads = jax.value_and_grad(
+            _loss_fn, has_aux=True)(params, seqs, targets, p, mesh)
         updates, opt_state = optimizer.update(grads, opt_state, params)
+        stats = {"loss": loss, "grad_norm": _group_norms(grads)}
+        if expert_layers:
+            # a selection bias is moved by its layer's load, not by adamw
+            moe_layers = [layer for i, layer in enumerate(updates["layers"])
+                          if p.ffn_kind(i) == "moe"]
+            for layer, s in zip(moe_layers, expert_layers):
+                layer["router_bias"] = moe.bias_update(
+                    jnp.zeros_like(layer["router_bias"]), s["load"],
+                    p.bias_update_rate)
+            stats.update({
+                key: jnp.stack([s[key] for s in expert_layers])
+                for key in ("load", "held_tokens", "dropped")})
+        stats["update_norm"] = _group_norms(updates)
         params = jax.tree.map(lambda w, u: w + u, params, updates)
-        return params, opt_state, loss
+        return params, opt_state, stats
 
     return jax.jit(step, donate_argnums=(0, 1))
 
@@ -176,7 +493,7 @@ def shard_params(params: Dict, mesh: Mesh) -> Dict:
         name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
         if name == "emb":
             return P("model", None)
-        if name in ("wqkv", "w1"):
+        if name in ("wqkv", "w1", "head"):
             return P(None, "model")
         if name == "w2":
             return P("model", None)
@@ -213,6 +530,8 @@ class SeqRecModel:
     item_vocab: np.ndarray     # index i -> item id string for code i+1
     params: Dict               # numpy pytree
     hyper: SeqRecParams
+    #: what training saw, a few numbers a step (train_seqrec's docstring)
+    record: Optional[Dict] = None
 
     def __getstate__(self):
         d = dict(self.__dict__)
@@ -220,12 +539,16 @@ class SeqRecModel:
         return d
 
     def _device_params(self):
+        """(weights on the device, the jitted forward pass over them)."""
         cached = getattr(self, "_resident", None)
         if cached is None or cached[0] is not self.params:
             dev = jax.tree.map(jnp.asarray, self.params)
-            cached = (self.params, dev)
+            # serving always uses the local attention kernel
+            hyper = dataclasses.replace(self.hyper, attention_impl="flash")
+            cached = (self.params, dev,
+                      jax.jit(lambda w, seqs: forward(w, seqs, hyper)))
             self._resident = cached
-        return cached[1]
+        return cached[1:]
 
     def item_code(self, item_id: str) -> Optional[int]:
         i = np.searchsorted(self.item_vocab, item_id)
@@ -243,9 +566,9 @@ class SeqRecModel:
         seq = np.zeros((1, l), np.int32)
         tail = codes[-l:]
         seq[0, -len(tail):] = tail
-        dev = self._device_params()
-        hidden = _predict_hidden(dev, jnp.asarray(seq), self.hyper.n_heads)
-        logits = np.array(hidden[0, -1] @ dev["emb"].T)   # writable copy
+        dev, hidden_of = self._device_params()
+        hidden = hidden_of(dev, jnp.asarray(seq))
+        logits = np.array(hidden[0, -1] @ head_matrix(dev))  # writable copy
         logits[0] = -np.inf                     # padding id
         logits[len(self.item_vocab) + 1:] = -np.inf   # vocab-padding rows
         if exclude_seen:
@@ -255,11 +578,6 @@ class SeqRecModel:
         top = top[np.argsort(-logits[top])]
         return [(str(self.item_vocab[i - 1]), float(logits[i]))
                 for i in top if np.isfinite(logits[i])]
-
-
-@functools.partial(jax.jit, static_argnames="n_heads")
-def _predict_hidden(params, seqs, n_heads):
-    return forward(params, seqs, n_heads)
 
 
 def seqrec_fingerprint(item_vocab: np.ndarray, p: SeqRecParams,
@@ -274,8 +592,7 @@ def seqrec_fingerprint(item_vocab: np.ndarray, p: SeqRecParams,
     import hashlib
 
     h = hashlib.blake2b(digest_size=16)
-    h.update(repr((p.d_model, p.n_heads, p.n_layers, p.max_len,
-                   p.learning_rate, p.batch_size, p.seed)).encode())
+    h.update(repr(p.spec_key(memory=False)).encode())
     h.update("\x00".join(str(it) for it in item_vocab).encode())
     for s in sessions:
         h.update("\x00".join(str(it) for it in s).encode())
@@ -288,21 +605,35 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
     """End-to-end: id-assign, pad, adamw train, return pickled-friendly
     model. `sessions` are per-user time-ordered item-id lists. With a
     `workflow.checkpoint.Checkpointer`, (params, opt_state) snapshot every
-    `interval` epochs and a preempted run resumes from the latest one."""
-    import optax
+    `interval` epochs and a preempted run resumes from the latest one.
 
-    all_items = np.asarray(sorted({it for s in sessions for it in s}),
-                           dtype=object)
-    code = {it: i + 1 for i, it in enumerate(all_items)}
-    coded = [[code[it] for it in s] for s in sessions if len(s) >= 2]
-    if not coded:
-        raise ValueError("need at least one session with >= 2 events")
-    inputs, targets = pad_sessions(coded, p.max_len)
+    The model carries a short record of the steps this call ran
+    (`SeqRecModel.record`), one entry a step in each list: `loss`,
+    `grad_norm` and `update_norm` {group: norm} (groups: `grad_group`;
+    the update is what the step added to the group's parameters, the
+    selection bias's own update with its router's), `rows` (the
+    sessions of the batch, as indices into the sessions of two events or
+    more, in the order given) and, with expert layers, `load` [expert
+    layer, routed expert], `held_tokens` [expert layer, held expert] and
+    `dropped` [expert layer]."""
+    p.check()
+    with span("seqrec_prepare"):
+        all_items = np.asarray(sorted({it for s in sessions for it in s}),
+                               dtype=object)
+        code = {it: i + 1 for i, it in enumerate(all_items)}
+        coded = [[code[it] for it in s] for s in sessions if len(s) >= 2]
+        if not coded:
+            raise ValueError("need at least one session with >= 2 events")
+        inputs, targets = pad_sessions(coded, p.max_len)
+        fp = seqrec_fingerprint(all_items, p, sessions)
 
-    rng = np.random.default_rng(p.seed)
-    tp = mesh.shape.get("model", 1) if mesh is not None else 1
-    params = init_params(rng, len(all_items), p, vocab_multiple=tp)
-    fp = seqrec_fingerprint(all_items, p, sessions)
+    with span("seqrec_init"):
+        rng = np.random.default_rng(p.seed)
+        tp = mesh.shape.get("model", 1) if mesh is not None else 1
+        params = init_params(rng, len(all_items), p, vocab_multiple=tp)
+        jax.block_until_ready(params)
+    train_stats.seqrec_param_bytes().set(
+        sum(leaf.nbytes for leaf in jax.tree.leaves(params)))
     epoch0 = 0
     restored_opt_leaves = None
     snap = checkpointer.latest(fingerprint=fp) \
@@ -316,12 +647,15 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
         if same:
             epoch0, params = e, restored
             restored_opt_leaves = state.get("opt_leaves")
-    # shard BEFORE optimizer.init so adamw's mu/nu inherit the tp layout
-    # (a replicated opt state would double-replicate the embedding table)
-    if mesh is not None and "model" in mesh.axis_names:
-        params = shard_params(params, mesh)
-    optimizer = optax.adamw(p.learning_rate)
-    opt_state = optimizer.init(params)
+    with span("seqrec_put"):
+        # shard BEFORE optimizer.init so adamw's mu/nu inherit the tp
+        # layout (a replicated opt state would double-replicate the
+        # embedding table)
+        if mesh is not None and "model" in mesh.axis_names:
+            params = shard_params(params, mesh)
+        optimizer = make_optimizer(p)
+        opt_state = optimizer.init(params)
+        jax.block_until_ready(opt_state)
     if restored_opt_leaves is not None:
         # snapshots hold the opt state as a flat leaf list (numpy-only
         # pytrees survive the restricted snapshot unpickler); rebuild it
@@ -353,20 +687,42 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
                 "or shape/dtype mismatch) — resuming params at epoch %d "
                 "with RESET adam moments",
                 len(restored_opt_leaves), treedef.num_leaves, epoch0)
-    step = make_train_step(mesh, p, optimizer)
+    # one jitted step per (mesh, spec), kept across trains: a retrain in
+    # the same process neither traces nor compiles it again
+    from predictionio_tpu.ops.fn_cache import mesh_cached_fn
+
+    step = mesh_cached_fn(
+        "seqrec_train_step", mesh, p.spec_key(),
+        lambda: make_train_step(mesh, p, make_optimizer(p)))
 
     n = len(inputs)
     bs = min(p.batch_size, n)
+    batch_starts = range(0, n - bs + 1, bs)
+    jax_stats.listen_to_compiler()
+    steps: List[Dict] = []     # each step's numbers, still on the device
+    rows: List[np.ndarray] = []
     for epoch in range(epoch0, p.epochs):
         # shuffle a FRESH arange keyed by epoch: a resumed run replays the
         # identical batch order the uninterrupted run would have used
         order = np.arange(n)
         np.random.default_rng(p.seed + epoch).shuffle(order)
-        for lo in range(0, n - bs + 1, bs):
-            idx = order[lo:lo + bs]
-            params, opt_state, _loss = step(
-                params, opt_state, jnp.asarray(inputs[idx]),
-                jnp.asarray(targets[idx]))
+        with span("seqrec_steps"):
+            for lo in batch_starts:
+                idx = order[lo:lo + bs]
+                compiles_before = jax_stats.backend_compile_count()
+                t0 = time.perf_counter()
+                params, opt_state, stats = step(
+                    params, opt_state, jnp.asarray(inputs[idx]),
+                    jnp.asarray(targets[idx]))
+                # a step's numbers are ready when its program has ended
+                jax.block_until_ready(stats["loss"])
+                # a warm step only: compile seconds would drown the step's
+                if jax_stats.backend_compile_count() == compiles_before:
+                    train_stats.seqrec_step_seconds().observe(
+                        time.perf_counter() - t0)
+                steps.append(stats)
+                rows.append(idx)
+            jax.block_until_ready(params)
         done = epoch + 1
         if checkpointer is not None and checkpointer.due(done) \
                 and done < p.epochs:
@@ -386,5 +742,24 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
             lambda: jax.jit(lambda t: t,
                             out_shardings=NamedSharding(mesh, P())))
         params = replicate(params)
-    host = jax.tree.map(np.asarray, params)
-    return SeqRecModel(item_vocab=all_items, params=host, hyper=p)
+    with span("seqrec_fetch"):
+        host = jax.tree.map(np.asarray, params)
+        record = _training_record(jax.device_get(steps), rows)
+    train_stats.seqrec_fetch_bytes().inc(
+        sum(leaf.nbytes for leaf in jax.tree.leaves(host)))
+    train_stats.observe_seqrec_record(record, targets, rows)
+    return SeqRecModel(item_vocab=all_items, params=host, hyper=p,
+                       record=record)
+
+
+def _training_record(steps: List[Dict], rows: List[np.ndarray]) -> Dict:
+    """Per-step numbers (host values) -> the record's lists."""
+    record = {"rows": [r.tolist() for r in rows],
+              "loss": [float(s["loss"]) for s in steps],
+              **{key: [{k: float(v) for k, v in s[key].items()}
+                       for s in steps] for key in ("grad_norm",
+                                                   "update_norm")}}
+    for key in ("load", "held_tokens", "dropped"):
+        if steps and key in steps[0]:
+            record[key] = [np.asarray(s[key]).tolist() for s in steps]
+    return record

@@ -31,6 +31,29 @@ spans, each with the count of what crossed that boundary:
 * ``pio_train_als_put_bytes_total`` / ``pio_train_als_fetch_bytes_total``
   — bytes sent to the device by ``ALSData.put`` and fetched back as
   factors.
+
+The sequence model's train (`models/seqrec.train_seqrec`) is wrapped in
+`seqrec_prepare` (vocabulary, coding, padding), `seqrec_init` (drawing
+the weights), `seqrec_put` (sharding, the optimizer's state),
+`seqrec_steps` (an epoch's steps, each waited for) and
+`seqrec_fetch` spans, with:
+
+* ``pio_train_seqrec_param_bytes`` — bytes of the last train's weights.
+* ``pio_train_seqrec_step_seconds`` — one step's wall from its dispatch
+  until its loss is ready, one sample a step; warm steps only.
+* ``pio_train_seqrec_tokens_total`` / ``pio_train_seqrec_pad_tokens_total``
+  — positions of the trained batches that carry a target / that are
+  padding.
+* ``pio_train_seqrec_expert_tokens_total{layer}`` — tokens the experts
+  held here received, by expert layer.
+* ``pio_train_seqrec_expert_load_max_over_mean`` — the busiest routed
+  expert's tokens over the mean, over all the router's experts, mean
+  over a train's steps and expert layers (one sample a train).
+* ``pio_train_seqrec_dropped_tokens_total`` — tokens routed to an expert
+  held here whose output row is all zeros (read from the layer's
+  output; expected 0).
+* ``pio_train_seqrec_fetch_bytes_total`` — bytes of weights fetched from
+  the device after training.
 """
 
 from __future__ import annotations
@@ -101,3 +124,83 @@ def observe_row_fill(data) -> None:
         slots = rows.tgt.shape[0] * rows.tgt.shape[1] * rows.row_len
         if slots:
             hist.observe(data.nnz / slots, side=side)
+
+
+#: 1 ms .. ~2 min doubling — one optimizer step
+STEP_BUCKETS = exponential_buckets(0.001, 2.0, 17)
+
+#: max load over mean load: 1 is perfect balance
+LOAD_RATIO_BUCKETS = (1.0, 1.05, 1.1, 1.2, 1.35, 1.5, 2.0, 3.0, 5.0, 10.0,
+                      100.0)
+
+
+def seqrec_param_bytes(registry: MetricsRegistry = None):
+    return (registry or default_registry()).gauge(
+        "pio_train_seqrec_param_bytes",
+        "Bytes of the sequence model's weights in the last train")
+
+
+def seqrec_step_seconds(registry: MetricsRegistry = None):
+    return (registry or default_registry()).histogram(
+        "pio_train_seqrec_step_seconds",
+        "One step's wall time in the sequence model's train, from its "
+        "dispatch until its loss is ready", buckets=STEP_BUCKETS)
+
+
+def seqrec_tokens(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_tokens_total",
+        "Positions of the trained batches that carry a target")
+
+
+def seqrec_pad_tokens(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_pad_tokens_total",
+        "Positions of the trained batches that are padding")
+
+
+def seqrec_expert_tokens(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_expert_tokens_total",
+        "Tokens the experts held here received, by expert layer",
+        labelnames=("layer",))
+
+
+def seqrec_expert_load_ratio(registry: MetricsRegistry = None):
+    return (registry or default_registry()).histogram(
+        "pio_train_seqrec_expert_load_max_over_mean",
+        "Busiest routed expert's tokens over the mean, mean over a "
+        "train's steps and expert layers", buckets=LOAD_RATIO_BUCKETS)
+
+
+def seqrec_dropped_tokens(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_dropped_tokens_total",
+        "Tokens routed to an expert held here whose output is all zeros")
+
+
+def seqrec_fetch_bytes(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_fetch_bytes_total",
+        "Bytes of sequence-model weights fetched from the device after "
+        "training")
+
+
+def observe_seqrec_record(record: dict, targets, rows) -> None:
+    """The token and expert counters from one train's record
+    (models/seqrec.train_seqrec): `targets` the padded target ids of all
+    sessions, `rows` the sessions of each step's batch."""
+    import numpy as np
+
+    real = sum(int((targets[r] > 0).sum()) for r in rows)
+    seqrec_tokens().inc(real)
+    seqrec_pad_tokens().inc(sum(targets[r].size for r in rows) - real)
+    if "load" not in record or not record["load"]:
+        return
+    load = np.asarray(record["load"], np.float64)      # [step, layer, expert]
+    seqrec_expert_load_ratio().observe(
+        float((load.max(-1) / np.maximum(load.mean(-1), 1e-9)).mean()))
+    held = np.asarray(record["held_tokens"]).sum(axis=(0, 2))
+    for layer, tokens in enumerate(held.tolist()):
+        seqrec_expert_tokens().inc(tokens, layer=str(layer))
+    seqrec_dropped_tokens().inc(int(np.asarray(record["dropped"]).sum()))

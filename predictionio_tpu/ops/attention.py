@@ -95,49 +95,169 @@ def _flash_finish(o, l, dtype):
     return jnp.einsum("bhqd->bqhd", out).astype(dtype)
 
 
+def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions on the last axis of x [B, L, H, D] (D even), in
+    the "halves" pairing: dimension i rotates with dimension i + D/2 by
+    the angle position * theta^(-2i/D). positions: [L] or [B, L]."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[..., None] * freq    # [(B,) L, half]
+    if ang.ndim == 2:
+        ang = ang[None]
+    cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(
+        jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _block_scores(q_i, k_j, mask_j, i, j, block_q, block_k, causal, scale):
+    """Masked scores [B, H, bq, bk] of query block i against key block j
+    (NEG_INF where the key is padding or lies in the causal future)."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q_i, k_j,
+                   preferred_element_type=jnp.float32) * scale
+    if causal:
+        s = _causal_mask(s, i * block_q, j * block_k)
+    return jnp.where(mask_j[:, None, None, :], s, NEG_INF)
+
+
+def _block_pairs(n_q: int, n_k: int, block_q: int, block_k: int,
+                 causal: bool) -> jax.Array:
+    """The (query block, key block) pairs that hold an unmasked score,
+    query-major: a causal pair whose keys all lie in the future is left
+    out, so causal attention does half the work."""
+    return jnp.asarray(
+        [(i, j) for i in range(n_q) for j in range(n_k)
+         if not causal or j * block_k <= i * block_q + block_q - 1],
+        jnp.int32)
+
+
+def _rows(x, i, n):
+    """Rows [i*n, (i+1)*n) of axis 2 of x [B, H, L, ...]."""
+    return jax.lax.dynamic_slice_in_dim(x, i * n, n, axis=2)
+
+
+def _put_rows(x, i, n, rows):
+    return jax.lax.dynamic_update_slice_in_dim(x, rows, i * n, axis=2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _blockwise(q, k, v, key_mask, block_q, block_k, causal):
+    return _blockwise_fwd(q, k, v, key_mask, block_q, block_k, causal)[0]
+
+
+def _blockwise_fwd(q, k, v, key_mask, block_q, block_k, causal):
+    """q [B, H, Lq, Dk], k [B, H, Lk, Dk], v [B, H, Lk, Dv], lengths
+    block multiples. One scan over the block pairs; the running
+    (output, max, denominator) of every query block live in the carry."""
+    b, h, lq, dk = q.shape
+    dv = v.shape[-1]
+    scale = dk ** -0.5
+    pairs = _block_pairs(lq // block_q, k.shape[2] // block_k, block_q,
+                         block_k, causal)
+
+    def step(carry, ij):
+        o, m, l = carry
+        i, j = ij[0], ij[1]
+        s = _block_scores(_rows(q, i, block_q), _rows(k, j, block_k),
+                          jax.lax.dynamic_slice_in_dim(
+                              key_mask, j * block_k, block_k, axis=1),
+                          i, j, block_q, block_k, causal, scale)
+        m_i, l_i = _rows(m, i, block_q), _rows(l, i, block_q)
+        m_new = jnp.maximum(m_i, s.max(axis=-1))
+        alpha = jnp.exp(m_i - m_new)
+        # explicit zero for masked scores: with the finite NEG_INF
+        # sentinel, exp(s - m_new) would be 1 (not 0) in all-masked rows
+        p = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_new[..., None]), 0.0)
+        o_i = _rows(o, i, block_q) * alpha[..., None] + jnp.einsum(
+            "bhqk,bhkd->bhqd", p, _rows(v, j, block_k).astype(jnp.float32))
+        return (_put_rows(o, i, block_q, o_i),
+                _put_rows(m, i, block_q, m_new),
+                _put_rows(l, i, block_q, l_i * alpha + p.sum(axis=-1))), None
+
+    (o, m, l), _ = jax.lax.scan(
+        step, (jnp.zeros((b, h, lq, dv), jnp.float32),
+               jnp.full((b, h, lq), NEG_INF, jnp.float32),
+               jnp.zeros((b, h, lq), jnp.float32)), pairs)
+    l = jnp.where(l == 0.0, 1.0, l)
+    out = (o / l[..., None]).astype(q.dtype)
+    return out, (q, k, v, key_mask, out, m + jnp.log(l))
+
+
+def _blockwise_bwd(block_q, block_k, causal, res, d_out):
+    """The backward pass recomputes each block's probabilities from the
+    saved log-sum-exp instead of keeping them: O(L x block) memory where
+    differentiating the forward scan keeps every block's."""
+    q, k, v, key_mask, out, lse = res
+    scale = q.shape[-1] ** -0.5
+    d_out = d_out.astype(jnp.float32)
+    delta = (d_out * out.astype(jnp.float32)).sum(axis=-1)   # [B, H, Lq]
+    pairs = _block_pairs(q.shape[2] // block_q, k.shape[2] // block_k,
+                         block_q, block_k, causal)
+
+    def step(carry, ij):
+        dq, dk, dv = carry
+        i, j = ij[0], ij[1]
+        q_i, k_j, v_j = (_rows(q, i, block_q), _rows(k, j, block_k),
+                         _rows(v, j, block_k))
+        s = _block_scores(q_i, k_j, jax.lax.dynamic_slice_in_dim(
+            key_mask, j * block_k, block_k, axis=1),
+            i, j, block_q, block_k, causal, scale)
+        p = jnp.where(s > NEG_INF / 2,
+                      jnp.exp(s - _rows(lse, i, block_q)[..., None]), 0.0)
+        do_i = _rows(d_out, i, block_q)
+        dp = jnp.einsum("bhqd,bhkd->bhqk", do_i, v_j.astype(jnp.float32))
+        ds = p * (dp - _rows(delta, i, block_q)[..., None]) * scale
+        dq_i = _rows(dq, i, block_q) + jnp.einsum(
+            "bhqk,bhkd->bhqd", ds, k_j.astype(jnp.float32))
+        dk_j = _rows(dk, j, block_k) + jnp.einsum(
+            "bhqk,bhqd->bhkd", ds, q_i.astype(jnp.float32))
+        dv_j = _rows(dv, j, block_k) + jnp.einsum(
+            "bhqk,bhqd->bhkd", p, do_i)
+        return (_put_rows(dq, i, block_q, dq_i),
+                _put_rows(dk, j, block_k, dk_j),
+                _put_rows(dv, j, block_k, dv_j)), None
+
+    (dq, dk, dv), _ = jax.lax.scan(
+        step, tuple(jnp.zeros(t.shape, jnp.float32) for t in (q, k, v)),
+        pairs)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), None
+
+
+_blockwise.defvjp(_blockwise_fwd, _blockwise_bwd)
+
+
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         block_k: int = 512, causal: bool = False,
-                        key_mask: Optional[jax.Array] = None) -> jax.Array:
-    """Flash-style single-device attention: stream over K/V blocks with the
-    running-max/denominator recurrence so the [Lq, Lk] score matrix never
-    materializes. O(L * block_k) memory; exact (not approximate).
+                        key_mask: Optional[jax.Array] = None,
+                        block_q: Optional[int] = None) -> jax.Array:
+    """Flash-style single-device attention: stream over blocks of queries
+    and of keys with the running-max/denominator recurrence so the
+    [Lq, Lk] score matrix never materializes, forward or backward
+    (custom_vjp: the backward pass recomputes a block's probabilities).
+    O(L * block) memory; exact (not approximate). q, k: [B, L, H, Dk];
+    v: [B, Lk, H, Dv], Dv free (latent attention has 192 and 128);
+    -> [B, Lq, H, Dv]. block_q defaults to block_k.
     key_mask: optional [B, Lk] bool, False = key is padding (ignored).
-    Sequence lengths that are not a block_k multiple are handled by padding
-    K/V up to one and masking the pad keys out."""
-    b, lq, h, d = q.shape
+    Lengths that are not a block multiple are handled by padding up to
+    one: pad keys are masked out, pad queries cut off the result."""
+    b, lq, h, _ = q.shape
     lk = k.shape[1]
     block_k = min(block_k, lk)
-    # non-divisible lengths: pad K/V up to a block multiple and mask the
-    # pad keys out (cheaper than shrinking the block and re-tiling)
-    pad = -lk % block_k
+    block_q = min(block_q or block_k, lq)
     if key_mask is None:
         key_mask = jnp.ones((b, lk), bool)
-    if pad:
-        zeros = jnp.zeros((b, pad, h, d), k.dtype)
-        k = jnp.concatenate([k, zeros], axis=1)
-        v = jnp.concatenate([v, zeros], axis=1)
-        key_mask = jnp.concatenate(
-            [key_mask, jnp.zeros((b, pad), bool)], axis=1)
-    n_blocks = (lk + pad) // block_k
-    scale = d ** -0.5
-    kb = k.reshape(b, n_blocks, block_k, h, d)
-    vb = v.reshape(b, n_blocks, block_k, h, d)
-    mb = key_mask.reshape(b, n_blocks, block_k)
-
-    def step(carry, xs):
-        j, k_j, v_j, m_j = xs
-        o, m, l = _flash_step(q, k_j, v_j, *carry, 0, j * block_k,
-                              causal, scale, key_mask_j=m_j)
-        return (o, m, l), None
-
-    o0 = jnp.zeros((b, h, lq, d), jnp.float32)
-    m0 = jnp.full((b, h, lq), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((b, h, lq), jnp.float32)
-    (o, _, l), _ = jax.lax.scan(
-        step, (o0, m0, l0),
-        (jnp.arange(n_blocks), jnp.moveaxis(kb, 1, 0), jnp.moveaxis(vb, 1, 0),
-         jnp.moveaxis(mb, 1, 0)))
-    return _flash_finish(o, l, q.dtype)
+    pad_k, pad_q = -lk % block_k, -lq % block_q
+    if pad_k:
+        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
+        key_mask = jnp.pad(key_mask, ((0, 0), (0, pad_k)))
+    if pad_q:
+        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
+    heads_first = lambda t: jnp.swapaxes(t, 1, 2)
+    out = _blockwise(heads_first(q), heads_first(k), heads_first(v),
+                     key_mask, block_q, block_k, causal)
+    return heads_first(out)[:, :lq]
 
 
 def _ring_attention_local(q, k, v, key_mask, *, axis: str, causal: bool,

@@ -1,0 +1,193 @@
+"""Sparse experts: a router over all of a layer's experts, and an expert
+layer that is told which of them it holds.
+
+Under expert parallelism each chip holds a contiguous range of a layer's
+routed experts and every chip routes every token over all of them (the
+router is small and replicated). `held_experts` computes what the experts
+held here add to the layer's output for the tokens routed to them; what
+the absent experts would have added is left out, and the sum over all
+the shares (plus whatever every chip computes alike, counted once) is the
+whole layer. On one chip the layer runs without its exchange: nothing
+here stands in for the other chips.
+
+No token is dropped. The tokens routed to the held experts are sorted by
+expert and multiplied group by group (`jax.lax.ragged_dot`, a grouped
+matrix product: each expert's rows against that expert's matrices). The
+number of such rows varies from step to step between 0 and
+tokens x min(k, held); shapes are static, so the rows are taken
+`pass_rows` at a time in a loop that runs as many passes as there are
+rows: the work follows the load, and a router that sends every token to
+one expert costs more passes, not tokens. The loop's length is not known
+when the program is traced, so the layer brings its own backward pass
+(`custom_vjp`), which walks the same passes again and keeps one pass's
+intermediates at a time.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+
+
+class Routing(NamedTuple):
+    #: [T, k] the experts chosen for each token, out of all n_routed
+    experts: jax.Array
+    #: [T, k] the weight of each chosen expert in the token's output
+    gates: jax.Array
+    #: [T, n_routed] sigmoid affinities, for the balance terms
+    scores: jax.Array
+
+
+def route(x: jax.Array, w_router: jax.Array, bias: jax.Array, top_k: int,
+          scaling: float = 1.0, norm_topk: bool = True) -> Routing:
+    """Sigmoid router with bias-steered selection (auxiliary-loss-free
+    balancing): the top-k of score + bias are chosen, the gates are the
+    chosen scores themselves (normalised to sum 1 when `norm_topk`, then
+    scaled), so the bias moves which experts are picked and never the
+    output's weights, and takes no gradient. The affinities are computed
+    at the highest matmul precision: a rounding that flips the k-th and
+    (k+1)-th expert of a token changes which weights it trains."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, experts = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    gates = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk:
+        gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-20)
+    return Routing(experts, gates * scaling, scores)
+
+
+def expert_load(experts: jax.Array, n_routed: int) -> jax.Array:
+    """[n_routed] tokens routed to each expert (every chosen slot
+    counts once)."""
+    return jnp.bincount(experts.reshape(-1), length=n_routed)
+
+
+def sequence_balance_loss(routing: Routing, n_sequences: int) -> jax.Array:
+    """Sequence-wise balance loss (DeepSeek-V3 eq. 17-20), without its
+    coefficient: for each sequence sum_e f_e P_e, with f_e the share of
+    the sequence's k T slots that chose expert e times n_routed, and P_e
+    the sequence's mean of the affinities normalised over the experts;
+    averaged over the sequences. Tokens are sequence-major."""
+    t, k = routing.experts.shape
+    e = routing.scores.shape[-1]
+    per_seq = t // n_sequences
+    chosen = jax.nn.one_hot(routing.experts, e, dtype=jnp.float32).sum(1)
+    f = chosen.reshape(n_sequences, per_seq, e).sum(1) * (e / (k * per_seq))
+    norm = routing.scores / routing.scores.sum(axis=-1, keepdims=True)
+    p = norm.reshape(n_sequences, per_seq, e).mean(axis=1)
+    return (jax.lax.stop_gradient(f) * p).sum(axis=-1).mean()
+
+
+def bias_update(bias: jax.Array, load: jax.Array, rate: float) -> jax.Array:
+    """After a step: an overloaded expert's bias falls by `rate`, an
+    underloaded one's rises (b += rate * sign(mean load - load))."""
+    load = load.astype(jnp.float32)
+    return bias + rate * jnp.sign(load.mean() - load)
+
+
+def _swiglu_grouped(xs, w_gate, w_up, w_down, group_sizes):
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, group_sizes)) \
+        * jax.lax.ragged_dot(xs, w_up, group_sizes)
+    return jax.lax.ragged_dot(h, w_down, group_sizes)
+
+
+def _pass_rows(lo, plan, k, pass_rows):
+    """Of the sorted slots [lo, lo + pass_rows): (slot, its token, the
+    rows of each held expert among them, which rows are slots at all)."""
+    order, starts, ends, n_rows = plan
+    slot = jax.lax.dynamic_slice_in_dim(order, lo, pass_rows)
+    sizes = jnp.clip(ends, lo, lo + pass_rows) \
+        - jnp.clip(starts, lo, lo + pass_rows)
+    valid = (lo + jnp.arange(pass_rows) < n_rows)[:, None]
+    return slot, slot // k, sizes, valid
+
+
+def _pass_out(xs, gate, w_gate, w_up, w_down, sizes, valid):
+    # rows past the last group are no expert's: what a grouped product
+    # leaves there is masked on the way in and on the way out
+    out = _swiglu_grouped(jnp.where(valid, xs, 0.0), w_gate, w_up, w_down,
+                          sizes)
+    return jnp.where(valid, out * gate[:, None], 0.0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _grouped_experts(x, gates, w_gate, w_up, w_down, plan, k, pass_rows):
+    return _grouped_experts_fwd(x, gates, w_gate, w_up, w_down, plan, k,
+                                pass_rows)[0]
+
+
+def _grouped_experts_fwd(x, gates, w_gate, w_up, w_down, plan, k, pass_rows):
+    n_rows = plan[3]
+
+    def one_pass(carry):
+        lo, y = carry
+        slot, token, sizes, valid = _pass_rows(lo, plan, k, pass_rows)
+        out = _pass_out(x[token], gates[slot], w_gate, w_up, w_down, sizes,
+                        valid)
+        return lo + pass_rows, y.at[token].add(out.astype(y.dtype))
+
+    _, y = jax.lax.while_loop(
+        lambda c: c[0] < n_rows, one_pass,
+        (jnp.zeros((), n_rows.dtype), jnp.zeros_like(x)))
+    return y, (x, gates, w_gate, w_up, w_down, plan)
+
+
+def _grouped_experts_bwd(k, pass_rows, res, d_y):
+    x, gates, w_gate, w_up, w_down, plan = res
+    n_rows = plan[3]
+
+    def one_pass(carry):
+        lo, d_x, d_gates, d_w = carry
+        slot, token, sizes, valid = _pass_rows(lo, plan, k, pass_rows)
+        _, vjp = jax.vjp(
+            lambda *a: _pass_out(*a, sizes, valid), x[token], gates[slot],
+            w_gate, w_up, w_down)
+        d_xs, d_gate, *d_w_pass = vjp(d_y[token])
+        return (lo + pass_rows, d_x.at[token].add(d_xs),
+                d_gates.at[slot].add(d_gate),
+                tuple(a + b for a, b in zip(d_w, d_w_pass)))
+
+    _, d_x, d_gates, d_w = jax.lax.while_loop(
+        lambda c: c[0] < n_rows, one_pass,
+        (jnp.zeros((), n_rows.dtype), jnp.zeros_like(x),
+         jnp.zeros_like(gates),
+         tuple(jnp.zeros_like(w) for w in (w_gate, w_up, w_down))))
+    return (d_x, d_gates, *d_w, None)
+
+
+_grouped_experts.defvjp(_grouped_experts_fwd, _grouped_experts_bwd)
+
+
+def held_experts(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+                 w_down: jax.Array, routing: Routing, first_held: int,
+                 pass_rows: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """What the experts [first_held, first_held + n_held) add to the
+    layer's output. x: [T, d]; w_gate, w_up: [n_held, d, w]; w_down:
+    [n_held, w, d] (SwiGLU experts). Returns (y [T, d], tokens each held
+    expert received [n_held], tokens dropped: those routed to a held
+    expert whose row of y is all zeros, read from the output and not from
+    the loop's own count; a token whose input is all zeros would read
+    the same). The routed slots are multiplied `pass_rows` at a time."""
+    t, k = routing.experts.shape
+    n_held = w_gate.shape[0]
+    local = routing.experts.reshape(-1) - first_held
+    here = (local >= 0) & (local < n_held)
+    # absent experts sort last; the held experts' slots come first, by expert
+    key = jnp.where(here, local, n_held)
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.bincount(key, length=n_held + 1)[:n_held]
+    ends = jnp.cumsum(counts)
+    pass_rows = min(pass_rows, t * k)
+    plan = (jnp.pad(order, (0, pass_rows)), ends - counts, ends,
+            counts.sum())
+    y = _grouped_experts(x, routing.gates.reshape(-1), w_gate, w_up, w_down,
+                         plan, k, pass_rows)
+    dropped = (here.reshape(t, k).any(-1) & ~(y != 0).any(-1)).sum()
+    # counted here and now: left to the scheduler, the count is taken at
+    # the end of the step and every layer's y is kept until then
+    y, dropped = jax.lax.optimization_barrier((y, dropped))
+    return y, counts, dropped

@@ -6,7 +6,9 @@ A release is the deployable identity of one train run. Two digests make
   * ``params_digest`` — sha256 over the EngineInstance's four canonical
     params JSON strings (they are serialized with ``sort_keys=True`` by
     ``run_train``, so the digest is stable across processes).
-  * ``model_digest`` — sha256 of the serialized model blob itself.
+  * ``model_digest`` — sha256 of the serialized model blob itself
+    (``run_train`` takes it beside the write of the blob and hands it to
+    ``record_release`` with the size; other callers hand over the blob).
 
 ``record_release`` is called by ``workflow.train.run_train`` after the
 instance is COMPLETED; failures are logged, never raised — a missing
@@ -60,9 +62,19 @@ def model_digest(blob: Optional[bytes]) -> str:
     return hashlib.sha256(blob).hexdigest()
 
 
+_digest_of_blob = model_digest
+
+
 def record_release(instance: EngineInstance, train_seconds: float,
-                   blob: Optional[bytes] = None) -> Optional[Release]:
+                   blob: Optional[bytes] = None, *,
+                   model_digest: Optional[str] = None,
+                   model_size_bytes: Optional[int] = None
+                   ) -> Optional[Release]:
     """Register a COMPLETED instance as the variant's next release.
+
+    The stored blob's digest and size come from ``blob``, or, from a
+    caller that took them while writing the blob, from ``model_digest``
+    and ``model_size_bytes`` (then no byte is read here).
 
     Returns the inserted Release, or None when registration failed (the
     train itself already succeeded; manifest writing is best-effort).
@@ -77,8 +89,10 @@ def record_release(instance: EngineInstance, train_seconds: float,
         engine_variant=instance.engine_variant,
         instance_id=instance.id,
         params_digest=params_digest(instance),
-        model_digest=model_digest(blob),
-        model_size_bytes=len(blob) if blob else 0,
+        model_digest=(_digest_of_blob(blob) if model_digest is None
+                      else model_digest),
+        model_size_bytes=((len(blob) if blob else 0)
+                          if model_size_bytes is None else model_size_bytes),
         status="REGISTERED",
         train_seconds=train_seconds,
         batch=instance.batch,

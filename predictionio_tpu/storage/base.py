@@ -19,13 +19,15 @@ UNFILTERED distinguishes "no filter" from "must be absent" (None).
 from __future__ import annotations
 
 import abc
+import contextlib
 import dataclasses
 import datetime as _dt
+import io
 import os
 import random
 import re
 import secrets
-from typing import Dict, Iterator, List, Optional, Sequence
+from typing import BinaryIO, Dict, Iterator, List, Optional, Sequence
 
 from predictionio_tpu.data.datamap import PropertyMap
 from predictionio_tpu.data.event import Event, UTC
@@ -323,8 +325,22 @@ class EvaluationInstances(abc.ABC):
 class Models(abc.ABC):
     """Binary model blob store (Models.scala:33-86)."""
 
+    #: True where `open_write` hands out the store's own file, so bytes
+    #: reach the store as they are written; False where it buffers the
+    #: blob for `insert` (a store that keeps a blob in a row)
+    streams_writes = False
+
     @abc.abstractmethod
     def insert(self, model: Model) -> None: ...
+
+    @contextlib.contextmanager
+    def open_write(self, model_id: str) -> Iterator[BinaryIO]:
+        """A binary writable for `model_id`'s blob. On a clean exit the
+        blob is in the store, whole, as after `insert`; on an exception
+        nothing of it is visible and the previous blob, if any, stays."""
+        buf = io.BytesIO()
+        yield buf
+        self.insert(Model(id=model_id, models=buf.getvalue()))
 
     @abc.abstractmethod
     def get(self, model_id: str) -> Optional[Model]: ...

@@ -5,12 +5,19 @@ LocalFSModels (storage/localfs/.../LocalFSModels.scala:32-62), HDFSModels
 (storage/hdfs/.../HDFSModels.scala:31-63) and S3Models
 (storage/s3/.../S3Models.scala:36-101) — via fsspec URL schemes: a plain
 path, ``hdfs://``, ``s3://``, ``memory://``. File-per-model, like all three.
+
+A write, whether `insert` of a blob in memory or `open_write` for a
+writer that streams (``pio train`` pickles a release straight into it),
+goes to a temporary name in the store's root and is renamed onto the
+model's name when the writer is done: a reader sees the old file or the
+whole new one.
 """
 
 from __future__ import annotations
 
+import contextlib
 import uuid
-from typing import Optional
+from typing import BinaryIO, Iterator, Optional
 
 from predictionio_tpu.storage import base
 from predictionio_tpu.storage.base import Model
@@ -29,16 +36,19 @@ class FSModels(base.Models):
             raise ValueError(f"invalid model id {model_id!r}")
         return f"{self.root}/pio_model_{model_id}.bin"
 
-    def insert(self, model: Model) -> None:
+    streams_writes = True
+
+    @contextlib.contextmanager
+    def open_write(self, model_id: str) -> Iterator[BinaryIO]:
         # write-then-rename: a concurrent get() during a deploy must see
         # either the old blob or the new one, never a torn half-write.
         # The temp name stays inside the store root (same fs, same dir)
         # so the final mv is a metadata move, not a copy.
-        path = self._path(model.id)
+        path = self._path(model_id)
         tmp = f"{path}.tmp-{uuid.uuid4().hex}"
         try:
             with self.fs.open(tmp, "wb") as f:
-                f.write(model.models)
+                yield f
             self.fs.mv(tmp, path)
         except BaseException:
             try:
@@ -47,6 +57,10 @@ class FSModels(base.Models):
             except Exception:
                 pass
             raise
+
+    def insert(self, model: Model) -> None:
+        with self.open_write(model.id) as f:
+            f.write(model.models)
 
     def get(self, model_id: str) -> Optional[Model]:
         path = self._path(model_id)

@@ -39,6 +39,35 @@ def persist_bytes():
         "Bytes of serialised models `pio train` wrote to the model store")
 
 
+#: 1 ms .. ~33 s doubling — one sample a persist, a release of KBs to GBs
+PERSIST_SECONDS_BUCKETS = exponential_buckets(0.001, 2.0, 16)
+
+
+def observe_persist(size: int, streamed: bool, write_seconds: float,
+                    hash_seconds: float) -> None:
+    """One persisted release: its bytes, whether the pickler wrote them
+    into the store's own file (the one-pass route) or into a buffer for a
+    row insert, and where the writing thread and the hash thread spent
+    their time."""
+    registry = default_registry()
+    persist_bytes().inc(size)
+    registry.counter(
+        "pio_train_persist_streamed_bytes_total",
+        "Bytes of serialised models the pickler wrote straight into the "
+        "model store's file, of pio_train_persist_bytes_total"
+    ).inc(size if streamed else 0)
+    registry.histogram(
+        "pio_train_persist_write_seconds",
+        "Time the pickling thread spent inside the model store's write, "
+        "one sample a persist", buckets=PERSIST_SECONDS_BUCKETS
+    ).observe(write_seconds)
+    registry.histogram(
+        "pio_train_persist_hash_seconds",
+        "Time the digest thread spent in sha256 update, one sample a "
+        "persist", buckets=PERSIST_SECONDS_BUCKETS
+    ).observe(hash_seconds)
+
+
 @contextlib.contextmanager
 def workflow_run_metrics(workflow: str, metric_prefix: str):
     """Instrument one workflow run; yields the phase sink.

@@ -13,18 +13,20 @@ from __future__ import annotations
 import datetime as _dt
 import json
 import logging
-from typing import Optional
+from typing import Optional, Tuple
 
 from predictionio_tpu.core.engine import Engine
 from predictionio_tpu.core.params import EngineParams, params_to_json
 from predictionio_tpu.data.event import UTC
-from predictionio_tpu.storage.base import EngineInstance, Model
+from predictionio_tpu.storage.base import EngineInstance
 from predictionio_tpu.storage.registry import Storage
 from predictionio_tpu.workflow.context import WorkflowContext, WorkflowParams
 from predictionio_tpu.workflow.instrument import (
-    persist_bytes, workflow_run_metrics,
+    observe_persist, workflow_run_metrics,
 )
-from predictionio_tpu.workflow.serialization import serialize_models
+from predictionio_tpu.workflow.serialization import (
+    DigestingWriter, dump_models,
+)
 
 logger = logging.getLogger("pio.workflow")
 
@@ -65,7 +67,7 @@ def run_train(engine: Engine,
     # on the backend mutating the record in place
     logger.info("EngineInstance %s created (INIT)", instance_id)
 
-    blob = None
+    model_digest, model_size = "", 0
     # the whole run is one trace: a parent pipeline (or a multi-process
     # launcher) hands its context via PIO_TRACE_CONTEXT so this train's
     # record joins the parent's trace id in the flight recorder
@@ -87,12 +89,10 @@ def run_train(engine: Engine,
                 if wp.save_model:
                     persisted = engine.persist_models(ctx, instance_id,
                                                       result)
-                    blob = serialize_models(persisted)
-                    Storage.get_model_data_models().insert(
-                        Model(id=instance_id, models=blob))
-                    persist_bytes().inc(len(blob))
+                    model_digest, model_size = _persist(instance_id,
+                                                        persisted)
                     logger.info("models saved (%d bytes) for instance %s",
-                                len(blob), instance_id)
+                                model_size, instance_id)
 
                 instance.status = "COMPLETED"
                 instance.end_time = _dt.datetime.now(tz=UTC)
@@ -109,13 +109,28 @@ def run_train(engine: Engine,
                 instance,
                 train_seconds=(instance.end_time - instance.start_time
                                ).total_seconds(),
-                blob=blob)
+                model_digest=model_digest, model_size_bytes=model_size)
     if getattr(ctx, "checkpointer", None) is not None:
         # resume is for crashed/preempted runs only: a completed run clears
         # its snapshots so the next train never resumes from stale factors
         ctx.checkpointer.clear()
     logger.info("training completed: instance %s", instance_id)
     return instance
+
+
+def _persist(instance_id: str, models) -> Tuple[str, int]:
+    """Write the release in one pass: the pickler writes into the model
+    store's writable (its own temporary file on a file store, a buffer
+    for the row insert elsewhere) through a writer that takes the sha256
+    of the same bytes beside the write. Returns (digest, size) of exactly
+    what the store now holds; the blob is visible only once this returns."""
+    store = Storage.get_model_data_models()
+    with store.open_write(instance_id) as f:
+        with DigestingWriter(f) as out:
+            dump_models(models, out)
+    observe_persist(out.size, store.streams_writes, out.write_seconds,
+                    out.hash_seconds)
+    return out.hexdigest(), out.size
 
 
 def load_for_deploy(engine: Engine, instance: EngineInstance,

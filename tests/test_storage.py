@@ -410,6 +410,81 @@ def test_fs_models_insert_is_atomic_under_concurrent_get(tmp_path):
                 if ".tmp-" in f]
 
 
+def _model_store(kind, tmp_path):
+    from predictionio_tpu.storage.fs_models import FSModels
+    from predictionio_tpu.storage.sqlite_backend import SqliteModels
+    if kind == "sqlite":
+        return SqliteModels(SqliteClient(str(tmp_path / "models.db")))
+    return FSModels("memory://pio-test-open-write" if kind == "memory"
+                    else str(tmp_path / "store"))
+
+
+@pytest.mark.parametrize("kind", ["localfs", "memory", "sqlite"])
+def test_models_open_write_is_whole_or_nothing(tmp_path, kind):
+    """`open_write` on every store: what was written is the blob after a
+    clean exit, and after an exception the previous blob stays and no
+    temporary file does. Only a file store streams."""
+    ms = _model_store(kind, tmp_path)
+    assert ms.streams_writes == (kind != "sqlite")
+    ms.delete("m1")
+    with ms.open_write("m1") as f:
+        f.write(b"first-")
+        f.write(memoryview(b"half"))
+    assert ms.get("m1").models == b"first-half"
+    with pytest.raises(RuntimeError, match="mid-write"):
+        with ms.open_write("m1") as f:
+            f.write(b"torn")
+            raise RuntimeError("mid-write")
+    assert ms.get("m1").models == b"first-half"
+    with pytest.raises(RuntimeError):
+        with ms.open_write("never") as f:
+            f.write(b"x")
+            raise RuntimeError("mid-write")
+    assert ms.get("never") is None
+    if kind == "localfs":
+        assert os.listdir(tmp_path / "store") == ["pio_model_m1.bin"]
+    elif kind == "memory":
+        assert [p for p in ms.fs.ls(ms.root, detail=False)
+                if ".tmp-" in p] == []
+    ms.delete("m1")
+
+
+def test_fs_models_get_during_a_slow_streamed_write_sees_the_old_blob(
+        tmp_path):
+    """While a writer is still streaming into `open_write`, a reader
+    gets the whole old blob; the new one appears with the rename."""
+    import threading
+
+    from predictionio_tpu.storage.fs_models import FSModels
+
+    ms = FSModels(str(tmp_path / "slow"))
+    old, new = b"o" * 200_000, b"n" * 300_000
+    ms.insert(Model(id="hot", models=old))
+    half_written, go_on = threading.Event(), threading.Event()
+
+    def writer():
+        with ms.open_write("hot") as f:
+            f.write(new[:150_000])
+            f.flush()
+            half_written.set()
+            assert go_on.wait(timeout=10)
+            f.write(new[150_000:])
+
+    t = threading.Thread(target=writer)
+    t.start()
+    try:
+        assert half_written.wait(timeout=10)
+        assert [n for n in os.listdir(tmp_path / "slow") if ".tmp-" in n]
+        for _ in range(3):
+            assert ms.get("hot").models == old
+    finally:
+        go_on.set()
+        t.join(timeout=10)
+    assert not t.is_alive()
+    assert ms.get("hot").models == new
+    assert os.listdir(tmp_path / "slow") == ["pio_model_hot.bin"]
+
+
 def _pg_driver_available():
     for mod in ("psycopg2", "pg8000"):
         try:

@@ -166,17 +166,14 @@ class Project:
     @classmethod
     def from_root(cls, root, paths: Optional[Sequence[str]] = None
                   ) -> "Project":
-        """Scan the real tree: ``predictionio_tpu/**/*.py`` plus
-        ``bench.py`` (it has its own temp-write and env-knob surfaces).
-        ``paths`` restricts the scan to specific root-relative files."""
+        """Scan the real tree: ``predictionio_tpu/**/*.py``, nothing
+        else. ``paths`` restricts the scan to specific root-relative
+        files."""
         root = pathlib.Path(root).resolve()
         if paths:
             candidates = [root / p for p in paths]
         else:
             candidates = sorted((root / "predictionio_tpu").rglob("*.py"))
-            bench = root / "bench.py"
-            if bench.is_file():
-                candidates.append(bench)
         files, errors = [], []
         for p in candidates:
             rel = p.relative_to(root).as_posix()

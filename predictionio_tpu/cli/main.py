@@ -1945,7 +1945,7 @@ def check(paths, rules, as_json, baseline_path, write_baseline,
           no_baseline, list_rules):
     """Static analysis: enforce the fleet's safety invariants.
 
-    Scans predictionio_tpu/ plus bench.py (or just PATHS, root-relative)
+    Scans predictionio_tpu/ (or just PATHS, root-relative)
     with the checker engine; exits 1 when any finding is not covered by
     the committed baseline or an inline `# pio: ignore[RULE]: reason`.
     """

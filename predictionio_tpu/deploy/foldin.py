@@ -6,7 +6,7 @@ still invisible until a full ``pio train`` + redeploy. This subsystem
 makes the model *move* with the event stream: fresh events become
 updated factor rows applied to the live :class:`deploy.ServingUnit`,
 with "seconds from event ingested → reflected in recommendations" as a
-benched, metered headline number.
+metered headline number.
 
 The shape follows iALS++ (arXiv:2110.14044) and ALX (arXiv:2112.02194):
 with the opposite side's factors frozen, one entity's row is a cheap
@@ -770,7 +770,7 @@ class FoldInController:
     # -- lifecycle -----------------------------------------------------------
     def start(self) -> None:
         """Arm the push tap and (when called on a running loop) the
-        apply task. Callers without a loop (bench, tests) drive
+        apply task. Callers without a loop (tests) drive
         `apply_pending` themselves."""
         from predictionio_tpu.data.write_buffer import add_flush_tap
 

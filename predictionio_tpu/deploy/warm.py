@@ -122,7 +122,7 @@ def resolve_warmup_query(result, explicit: Optional[Any] = None):
 @dataclasses.dataclass
 class WarmupReport:
     """What the warmup pass actually exercised (surfaced by
-    /deploy/status.json and asserted by the swap bench/tests)."""
+    /deploy/status.json and asserted by the swap tests)."""
 
     buckets: List[int] = dataclasses.field(default_factory=list)
     queries: int = 0

@@ -542,7 +542,7 @@ class ALSAlgorithm(Algorithm):
         line later — at batch-scoring rates that object churn costs more
         than the matmul. The contract: byte-identical serialized output
         to `to_dict(batch_predict(...))` (asserted by the batchpredict
-        parity tests and the bench)."""
+        parity tests)."""
         reqs = [(q.user, q.num, tuple(q.black_list or ()),
                  tuple(q.white_list) if q.white_list is not None else None)
                 for _, q in queries]
@@ -559,8 +559,7 @@ class ALSAlgorithm(Algorithm):
         (`recommend_batch_arrays`) that feed `ListArray.from_arrays`
         directly. Returns the column parallel to `queries` (pad rows
         included; the caller slices them off). Value-identical to the
-        dict lanes — asserted by the batchpredict parity tests and the
-        bench."""
+        dict lanes — asserted by the batchpredict parity tests."""
         import pyarrow as pa
 
         reqs = [(q.user, q.num, tuple(q.black_list or ()),

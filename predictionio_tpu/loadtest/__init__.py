@@ -1,13 +1,11 @@
 """`pio loadtest` — the whole-fleet workload simulator (ROADMAP item 5).
 
-Every number the repo produced before this package came from bench
-configs exercising ONE subsystem at a time (ingest alone, serving
-alone, scoring alone). This package drives them *concurrently*: a
+This package drives the subsystems *concurrently*: a
 synthetic user population (population.py — Zipfian item popularity,
 diurnal arrival curves, lazy per-user session state) emits mixed
 traffic — events to the event server, queries through the router,
-feedback closing the fold-in loop — in open-loop mode with the ingest
-bench's latency-accounting discipline (harness.py), against an
+feedback closing the fold-in loop — in open-loop mode with one
+latency-accounting discipline (harness.py), against an
 in-process fleet (fleet.py) whose incidents a declarative scenario
 file injects (scenario.py), while a runtime invariant engine
 (invariants.py) turns the `pio check`-era guarantees into live

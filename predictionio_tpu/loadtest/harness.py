@@ -1,10 +1,7 @@
 """The open-loop load harness: ONE implementation of "offered load vs
-observed ack", extracted from the two places bench.py had grown it
-independently (`ingest_write`'s grouped/partitioned submitters and
-`fleet_scaling`'s stage accounting) and now shared with the loadtest
-simulator.
+observed ack", shared by every lane of the loadtest simulator.
 
-The discipline, exactly as the ingest bench established it:
+The discipline:
 
 * **Open loop** — the submit schedule never slows because the system
   lags; only a bounded outstanding window provides backpressure, so a
@@ -31,9 +28,9 @@ __all__ = ["LatencyLedger", "OpenLoopResult", "drive_open_loop"]
 class LatencyLedger:
     """Thread-safe latency accounting shared by every lane: record in
     seconds from any thread, read percentiles once at the end. The
-    percentile is the sorted-index estimator the ingest bench used
+    percentile is the sorted-index estimator
     (``sorted[int(q/100 * n)]``), not an interpolation — comparable
-    across every config that reports p99."""
+    across every lane that reports p99."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -124,7 +121,7 @@ def drive_open_loop(items: Iterable, submit: Callable,
 
     ``schedule`` — optional arrival offsets (seconds from drive start),
     one per item, ascending: the open-loop pacing. Without it items are
-    offered back-to-back (the bench's max-rate shape). The window still
+    offered back-to-back (the max-rate shape). The window still
     backpressures a schedule that outruns the system, and the deadline
     (``timeout_s``, measured from start) bounds the whole drive.
 

@@ -193,6 +193,7 @@ def run_storm(scenario: Scenario, fleet, *,
             t.start()
             retrain_threads.append(t)
         elif incident.kind == "burn_slo":
+            # pio: ignore[PIO003]: an incident injector, outside any request; each POST it sends starts its own trace at the replica
             t = threading.Thread(
                 target=_burn_slo,
                 args=(fleet, incident.duration_s or 2.0),
@@ -365,6 +366,7 @@ def run_tenant_storm(scenario: Scenario, fleet, *,
             record_event("loadtest_incident", incident.to_dict())
             logger.info("incident @%.1fs: burn_slo tenant=%s",
                         incident.at_s, incident.tenant or "<all>")
+            # pio: ignore[PIO003]: an incident injector, outside any request; each POST it sends starts its own trace at the gate
             t = threading.Thread(
                 target=fleet.burn_tenant,
                 args=(incident.tenant, incident.duration_s or 2.0),

@@ -38,6 +38,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from predictionio_tpu.data.bimap import vocab_index
+from predictionio_tpu.obs.tracing import span
 from predictionio_tpu.utils.device import memory_limit_bytes, on_tpu
 
 #: max dense A entries before falling back to host counting (f32 ~2GB)
@@ -99,15 +100,13 @@ def cooccurrence_topn(mesh, user_idx: np.ndarray, item_idx: np.ndarray,
     ni_pad = blk * n_shards
 
     def _put_incidence():
-        from predictionio_tpu.utils.profiling import phase
-
         # build uint8 on host (quarter the f32 bytes over the host->device
         # link) — the kernel widens to the compute dtype on device, where
         # the cast fuses into the matmul read for free
-        with phase("incidence_build"):
+        with span("incidence_build"):
             a = np.zeros((n_users, ni_pad), np.uint8)
             a[user_idx, item_idx] = 1
-        with phase("incidence_transfer"):
+        with span("incidence_transfer"):
             a_dev = jax.device_put(a, NamedSharding(mesh, P(None, axis)))
             jax.block_until_ready(a_dev)
         return a_dev

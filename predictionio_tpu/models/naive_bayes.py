@@ -20,6 +20,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from predictionio_tpu.obs.tracing import span
+
 
 # ---------------------------------------------------------------------------
 # Categorical NB (e2 parity)
@@ -262,14 +264,12 @@ def train_multinomial_nb(X: np.ndarray, labels: Sequence[str],
         pad = (-len(label_codes)) % shard
 
         def _put_x_sharded():
-            from predictionio_tpu.utils.profiling import phase
-
-            with phase("nb_compact"):
+            with span("nb_compact"):
                 Xc = _compact_for_transfer(X)
                 if pad:
                     Xc = np.concatenate(
                         [Xc, np.zeros((pad, n_features), Xc.dtype)])
-            with phase("nb_transfer"):
+            with span("nb_transfer"):
                 xd = jax.device_put(Xc, NamedSharding(mesh, P(axis, None)))
                 jax.block_until_ready(xd)
             return xd
@@ -295,11 +295,9 @@ def train_multinomial_nb(X: np.ndarray, labels: Sequence[str],
         import jax
 
         def _put_x():
-            from predictionio_tpu.utils.profiling import phase
-
-            with phase("nb_compact"):
+            with span("nb_compact"):
                 Xc = _compact_for_transfer(X)
-            with phase("nb_transfer"):
+            with span("nb_transfer"):
                 xd = jax.device_put(Xc)
                 jax.block_until_ready(xd)
             return xd

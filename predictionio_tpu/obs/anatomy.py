@@ -27,8 +27,7 @@ plane enabled, every histogram observation made under a live trace
 stamps its bucket's exemplar slot with (trace_id, value, ts).
 
 ``PIO_ANATOMY=0`` kills the whole plane (stage accounting AND exemplar
-capture) — the bench's anatomy on/off leg holds the enabled path to
-within 5% of this switch.
+capture).
 """
 
 from __future__ import annotations

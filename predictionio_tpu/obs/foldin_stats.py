@@ -3,7 +3,7 @@
 The fold-in controller (deploy/foldin.py) turns fresh events into
 updated factor rows between full retrains; these metrics make its
 headline number — seconds from event ingested to reflected in
-recommendations — observable in production, not just in the bench:
+recommendations — observable in production:
 
 * ``pio_foldin_pending_rows`` — entity rows (users + items) dirtied by
   fresh events and waiting for the next apply. Grows past

@@ -15,8 +15,7 @@ that follows all share one trace id. The response echoes the request's
 own context in the same header, and every completed request is recorded
 in the in-memory flight recorder, exposed at ``GET /debug/traces.json``
 (and via ``pio traces``). ``PIO_TRACING=0`` disables the trace layer
-(no contextvars, no recorder writes) while keeping every metric — the
-bench measures tracing overhead against exactly that state.
+(no contextvars, no recorder writes) while keeping every metric.
 
 ``add_metrics_routes(app, *registries)`` mounts ``GET /metrics``
 (Prometheus text exposition 0.0.4), ``GET /metrics.json``, and

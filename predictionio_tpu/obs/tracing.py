@@ -4,8 +4,7 @@ Every request through the observability middleware gets a request ID
 (taken from an incoming ``X-Request-ID`` header or generated) and an
 active :class:`Trace` carried in a :mod:`contextvars` context, so
 ``span("predict")`` anywhere below the handler records a named stage
-timing without threading arguments through every signature — the same
-pattern as ``utils.profiling.phase`` but per-request and async-safe.
+timing without threading arguments through every signature.
 
 Beyond the original per-request contextvar, a trace now has an IDENTITY
 that survives process and thread boundaries (obs/trace_context.py): a
@@ -50,8 +49,7 @@ logger = logging.getLogger("pio.obs")
 
 REQUEST_ID_HEADER = "X-Request-ID"
 
-#: env kill-switch for the tracing layer (metrics stay on): the bench
-#: measures its overhead against exactly this off state
+#: env kill-switch for the tracing layer (metrics stay on)
 TRACING_ENV = "PIO_TRACING"
 
 

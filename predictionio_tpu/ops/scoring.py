@@ -478,7 +478,7 @@ class ItemScorer:
     # -- status --------------------------------------------------------------
 
     def status(self) -> dict:
-        """The /deploy/status.json + bench echo block."""
+        """The /deploy/status.json block."""
         return {
             "mode": self.mode,
             "activeMode": self.active_mode,
@@ -722,7 +722,7 @@ class ShardedScorer:
         return merge_topk(shortlists, k)
 
     def status(self) -> dict:
-        """The /deploy/status.json + bench echo block (sharded form)."""
+        """The /deploy/status.json block (sharded form)."""
         return {
             "mode": self.mode,
             "activeMode": self.active_mode,

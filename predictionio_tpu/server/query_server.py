@@ -1017,7 +1017,7 @@ class QueryServer:
         return result.serving.serve(query, predictions)
 
     def _predict_batch(self, queries):
-        """Active-unit batch path (tests/bench call this directly)."""
+        """Active-unit batch path (tests call this directly)."""
         return self._predict_batch_unit(self._unit, queries)
 
     def _predict_batch_unit(self, unit: ServingUnit, queries):

@@ -4,9 +4,8 @@ holds, partition by partition.
 
 The write path promises exactly-once: every acked submit is durably
 present exactly once, across retries, commit-lane splits, compaction
-crashes and recovery. The bench configs assert this with row COUNTS;
-counts cannot see a compensating pair (one lost + one duplicated
-event). This audit compares *identities*: the emitter's ledger of
+crashes and recovery. Row COUNTS cannot see a compensating pair (one
+lost + one duplicated event). This audit compares *identities*: the emitter's ledger of
 acked event ids (WriteBuffer futures resolve to the ids assigned at
 submit) against a full scan of the store — per partition when the
 store is partitioned, so a duplicate that leaked ACROSS partitions

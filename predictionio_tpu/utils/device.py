@@ -32,7 +32,7 @@ def _set_jax_option(name: str, value) -> None:
 def enable_compile_cache() -> str:
     """Point JAX's persistent compilation cache at a directory a later
     process will find again, and return that directory. Call before the
-    process compiles anything (CLI group, bench worker, driver entry).
+    process compiles anything (CLI group, driver entry).
 
     ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and the code
     sets no other directory. Unset: `DEFAULT_COMPILE_CACHE_DIR`.

@@ -1059,7 +1059,7 @@ def run_batch_predict(engine: Optional[Engine],
     Explicit arguments beat the resolved config (env >
     engine.json ``batchpredict`` section (``variant_conf``) >
     server.json). ``loaded=(result, ctx)`` skips the model-store restore
-    (benches/tests with synthetic models); ``worker=(rank, size)`` pins
+    (tests with synthetic models); ``worker=(rank, size)`` pins
     the shard identity instead of reading the PIO_* process env.
     """
     cfg = config or batchpredict_config(variant_conf)

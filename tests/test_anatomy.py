@@ -377,6 +377,40 @@ async def test_stage_sums_approximate_wall_under_burst():
     assert stages >= wall * 0.25 - 0.05, (stages, wall)
 
 
+@pytest.mark.parametrize("switch", ["PIO_TRACING", "PIO_ANATOMY"])
+async def test_query_server_answers_with_a_plane_switched_off(
+        monkeypatch, switch):
+    """Either kill switch leaves serving whole: concurrent queries
+    answer through the batcher, the request metrics still count, and
+    the plane that is off records nothing."""
+    import asyncio
+
+    from test_query_batcher import make_server
+
+    monkeypatch.setenv(switch, "0")
+    server = make_server()
+    c = TestClient(TestServer(server.app))
+    await c.start_server()
+    try:
+        out = await asyncio.gather(*[
+            c.post("/queries.json", json={"user": f"u{i % 9}", "num": 3})
+            for i in range(8)])
+        for resp in out:
+            assert resp.status == 200
+            assert len((await resp.json())["itemScores"]) == 3
+    finally:
+        await c.close()
+    assert server.registry.get(
+        "pio_query_duration_seconds").total_count() == 8
+    stage_hist = server.registry.get(STAGE_HISTOGRAM)
+    stages = stage_hist.total_count() if stage_hist is not None else 0
+    if switch == "PIO_ANATOMY":
+        assert stages == 0
+        assert tc.recorder().traces()          # tracing stays on
+    else:
+        assert tc.recorder().traces() == []
+
+
 def test_ingest_anatomy_observes_every_submit():
     from predictionio_tpu.data.write_buffer import WriteBuffer
     from test_faults import ev

@@ -103,6 +103,36 @@ def test_sharded_merge_equals_single_process(tmp_path):
     assert not leftovers, leftovers
 
 
+def test_shard_identity_from_the_process_env(tmp_path, monkeypatch):
+    """A batchpredict fleet is N processes with two env vars each: with
+    no `worker=` a run takes its shard from PIO_PROCESS_ID /
+    PIO_NUM_PROCESSES, and the last shard to finish merges the file a
+    single process writes."""
+    result = _synth_result()
+    inp = tmp_path / "q.jsonl"
+    n = _write_queries(inp)
+    single = tmp_path / "single.jsonl"
+    run_batch_predict(None, None, str(inp), str(single),
+                      chunk_size=16, loaded=(result, None), worker=(0, 1))
+
+    merged = tmp_path / "merged.jsonl"
+    monkeypatch.setenv("PIO_NUM_PROCESSES", "2")
+    reports = []
+    for rank in ("1", "0"):
+        monkeypatch.setenv("PIO_PROCESS_ID", rank)
+        reports.append(run_batch_predict(
+            None, None, str(inp), str(merged), chunk_size=16,
+            loaded=(result, None)))
+    assert [r.worker for r in reports] == [(1, 2), (0, 2)]
+    assert not reports[0].merged and reports[1].merged
+    assert reports[0].written + reports[1].written == n
+    assert merged.read_bytes() == single.read_bytes()
+    monkeypatch.setenv("PIO_PROCESS_ID", "2")
+    with pytest.raises(ValueError, match="PIO_PROCESS_ID=2 outside"):
+        run_batch_predict(None, None, str(inp), str(merged),
+                          chunk_size=16, loaded=(result, None))
+
+
 def test_sharded_parquet_values_equal_single(tmp_path):
     """Sharded parquet fragments merge into the same VALUES as a
     single-process parquet run (row-group layout may differ)."""

@@ -104,6 +104,38 @@ def test_group_commit_coalesces_concurrent_submits(sqlite_store):
     assert reg.get("pio_ingest_flush_size").total_count() <= 20
 
 
+@pytest.mark.parametrize("backend", ["sqlite", "parquet"])
+def test_concurrent_per_request_inserts_land_exactly_once(
+        backend, sqlite_store, parquet_store):
+    """The unbuffered write path: one `insert` a request from several
+    request threads at once (the event server's executor with the write
+    buffer off). Every event is stored once, under the id its caller
+    was given."""
+    store = sqlite_store if backend == "sqlite" else parquet_store
+    clients, per = 8, 12
+    ids, errors = [[] for _ in range(clients)], []
+    start = threading.Barrier(clients)
+
+    def client(c):
+        try:
+            start.wait(10)
+            for k in range(per):
+                ids[c].append(store.insert(ev(c * per + k), APP))
+        except Exception as e:      # surfaced below, on the test thread
+            errors.append(e)
+
+    threads = [threading.Thread(target=client, args=(c,))
+               for c in range(clients)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads) and not errors, errors
+    given = [i for mine in ids for i in mine]
+    assert len(set(given)) == clients * per
+    assert sorted(stored_ids(store)) == sorted(given)
+
+
 def test_retry_fail_n_then_recover_no_loss_no_dup(sqlite_store):
     reg = MetricsRegistry()
     faulty = FaultyEvents(sqlite_store, fail_n=3, when="before")

@@ -472,12 +472,44 @@ def test_storm_smoke_mixed_lanes_retrain(tmp_path):
     assert report["active_users"] > 0
 
 
+def test_storm_chaos_smoke_parquet(tmp_path):
+    """The chaos storm shrunken into tier-1: on the parquet backend a
+    replica is killed and restarted and a compaction crashes mid-storm,
+    with zero dropped acks and exactly-once by audit."""
+    from predictionio_tpu.obs.loadtest_stats import loadtest_incidents
+    from predictionio_tpu.obs.registry import MetricsRegistry
+
+    registry = MetricsRegistry()
+    report = _storm(tmp_path, {
+        "name": "chaos-smoke", "population": 100, "items": 40,
+        "durationS": 5.0, "seed": 11, "baseRate": 25.0, "amplitude": 0.3,
+        "mix": {"events": 0.7, "queries": 0.25, "feedback": 0.05},
+        "replicas": 2, "partitions": 2, "backend": "parquet",
+        "maxOutstanding": 64,
+        "incidents": [
+            {"kind": "kill_replica", "atS": 1.2, "target": 1,
+             "restartAfterS": 1.5},
+            {"kind": "kill_compaction", "atS": 2.8},
+        ],
+    }, check_freshness=False, registry=registry)
+    assert report["ok"], report["invariants"]
+    fired = loadtest_incidents(registry)
+    assert fired.value(kind="kill_replica") == 1
+    assert fired.value(kind="kill_compaction") == 1
+    lanes = report["lanes"]
+    assert lanes["events"]["acked"] > 0 and lanes["queries"]["acked"] > 0
+    assert all(lane["dropped"] == 0 for lane in lanes.values())
+    assert report["audit"]["ok"], report["audit"]["summary"]
+    assert report["audit"]["expected"] > 0
+
+
 @pytest.mark.slow
 def test_storm_full_chaos(tmp_path):
     """Full chaos at test scale: replica kill + restart, compaction
     crash, SLO burn and quality degradation all mid-storm — zero
     dropped acks and exactly-once by audit. Excluded from tier-1
-    (-m 'not slow'); bench's chaos leg runs the judged variant."""
+    (-m 'not slow'); `test_storm_chaos_smoke_parquet` is its shrunken
+    tier-1 form."""
     report = _storm(tmp_path, {
         "name": "chaos", "population": 2_000, "items": 300,
         "durationS": 10.0, "seed": 13, "baseRate": 80.0,

@@ -450,6 +450,112 @@ async def test_budget_lru_eviction_on_miss_and_sweep(
         await c.close()
 
 
+async def test_three_engine_families_consolidate_under_one_budget(
+        mt_store, device_resident):
+    """Three engine FAMILIES in one host (recommendation on int8
+    factors, similarproduct on bf16, recommended_user on the default
+    scorer), under a budget smaller than their residencies together:
+    the eviction / warm-reload cycle turns, every query answers, each
+    tenant answers the same before and after its reload, and the host
+    ends under the budget."""
+    from predictionio_tpu.engines import recommended_user as ru_mod
+    from predictionio_tpu.engines import similarproduct as sp_mod
+    from predictionio_tpu.engines.common import Item
+    from predictionio_tpu.utils.server_config import ScorerConfig
+
+    n_items, rank = 400, 16
+    rng = np.random.default_rng(23)
+
+    def unit_rows():
+        V = rng.normal(size=(n_items, rank)).astype(np.float32)
+        return V / np.linalg.norm(V, axis=1, keepdims=True)
+
+    ids = np.sort(np.asarray([f"i{i:04d}" for i in range(n_items)],
+                             dtype=object))
+    uids = np.sort(np.asarray([f"u{i:04d}" for i in range(n_items)],
+                              dtype=object))
+    families = {
+        "rec": (make_engine(), make_model(seed=23, n_items=n_items,
+                                          rank=rank),
+                ALSAlgorithm(AlgorithmParams(rank=rank)),
+                RecommendationServing(), ScorerConfig(mode="fused_int8"),
+                {"user": "u1", "num": 5}),
+        "sim": (Engine(sp_mod.SimilarProductDataSource,
+                       sp_mod.SimilarProductPreparator,
+                       {"als": sp_mod.ALSAlgorithm},
+                       sp_mod.SimilarProductServing),
+                sp_mod.SimilarityModel(
+                    item_vocab=ids, V=unit_rows(),
+                    items={i: Item(categories=None)
+                           for i in range(n_items)}),
+                sp_mod.ALSAlgorithm(), sp_mod.SimilarProductServing(),
+                ScorerConfig(mode="fused_bf16"),
+                {"items": ["i0007"], "num": 5}),
+        "social": (Engine(ru_mod.RecommendedUserDataSource,
+                          ru_mod.RecommendedUserPreparator,
+                          {"als": ru_mod.ALSAlgorithm},
+                          ru_mod.RecommendedUserServing),
+                   ru_mod.RecommendedUserModel(
+                       user_vocab=uids, V=unit_rows(), users={}),
+                   ru_mod.ALSAlgorithm(), ru_mod.RecommendedUserServing(),
+                   None, {"users": ["u0007"], "num": 5}),
+    }
+    specs = []
+    for name, (eng, model, algo, serving, scorer, _q) in families.items():
+        instance = EngineInstance(
+            id=f"mt-{name}", status="COMPLETED", engine_id="mt-families",
+            engine_version="1", engine_variant=name,
+            data_source_params=json.dumps({"app_name": f"{name}App"}),
+            algorithms_params=json.dumps(
+                [{"name": "als", "params": {"rank": rank}}]))
+        Storage.get_meta_data_engine_instances().insert(instance)
+        blob = serialize_models([model])
+        Storage.get_model_data_models().insert(
+            Model(id=instance.id, models=blob))
+        specs.append(TenantSpec(
+            name=name, engine=eng,
+            train_result=TrainResult(models=[model], algorithms=[algo],
+                                     serving=serving,
+                                     engine_params=EngineParams()),
+            instance=instance, ctx=None,
+            release=record_release(instance, train_seconds=0.0, blob=blob),
+            scorer_config=scorer,
+            serving_config=ServingConfig(batch_max=8, batch_linger_s=0.0),
+            deploy_config=DeployConfig(warmup=False, drain_timeout_s=5.0)))
+    host = make_host(specs, min_resident=1, reload_wait_s=30.0)
+    c = TestClient(TestServer(host.app))
+    await c.start_server()
+
+    async def answer(name):
+        r = await c.post(f"/t/{name}/queries.json", json=families[name][5])
+        assert r.status == 200, (name, await r.text())
+        body = await r.json()
+        assert len(next(iter(body.values()))) == 5, body
+        return body
+
+    try:
+        first = {name: await answer(name) for name in families}
+        listing = (await (await c.get("/tenants.json")).json())["tenants"]
+        assert {t["tenant"]: t["scorerMode"] for t in listing} == {
+            "rec": "fused_int8", "sim": "fused_bf16", "social": "exact"}
+        standalone = {name: host.tenants[name].server.warm_bytes
+                      for name in families}
+        # the two scorer-backed tenants hold factors on the device
+        assert standalone["rec"] > 0 and standalone["sim"] > 0, standalone
+        total = sum(standalone.values())
+        host.config.budget_bytes = int(0.8 * total)
+        for _ in range(2):
+            for name in families:
+                assert await answer(name) == first[name]
+                await host.enforce_budget()
+        server = host.tenants["rec"].server    # one registry serves all
+        assert server._evict_total.value(reason="budget") > 0
+        assert server._reload_total.value(status="warm_reload") > 0
+        assert host.resident_bytes() <= host.config.budget_bytes < total
+    finally:
+        await c.close()
+
+
 async def test_min_resident_floor_holds(mt_store, device_resident):
     """The sweep never evicts below min_resident even when the budget is
     absurdly small — some tenant must keep serving."""

@@ -626,9 +626,22 @@ def _bp_result():
         serving=RecommendationServing(), engine_params=EngineParams())
 
 
-def test_batchpredict_lane_parity_exact_vs_fused(tmp_path):
+@pytest.mark.parametrize("exact_lane", ["host", "device"])
+def test_batchpredict_lane_parity_exact_vs_fused(tmp_path, monkeypatch,
+                                                 exact_lane):
     from predictionio_tpu.workflow.batch_predict import run_batch_predict
 
+    # exact mode picks host BLAS or the device from two timing probes
+    # (`ALSModel._use_host`); pin their results so that each lane is
+    # compared with the fused kernel whatever this machine's speed (on a
+    # throttled CPU the probes pick the device, and the bytes differ)
+    if exact_lane == "host":
+        monkeypatch.setattr(als_mod, "_HOST_FLOPS", 1e18)
+        monkeypatch.setattr(als_mod, "_DEVICE_ROUNDTRIP_S", 1.0)
+    else:
+        monkeypatch.setattr(als_mod, "_DEVICE_ROUNDTRIP_S", 0.0)
+    assert _bp_result().models[0]._use_host(16, False) \
+        == (exact_lane == "host")
     inp = tmp_path / "queries.jsonl"
     with open(inp, "w") as f:
         for i in range(40):
@@ -645,5 +658,18 @@ def test_batchpredict_lane_parity_exact_vs_fused(tmp_path):
                                 chunk_size=16, loaded=(_bp_result(), None))
         assert rep.merged
         outs[mode] = open(out, "rb").read()
-    # byte-identical output: the fused f32 kernel IS the exact scorer
-    assert outs["fused"] == outs["exact"]
+    if exact_lane == "host":
+        # byte-identical output: the fused f32 kernel IS the exact scorer
+        assert outs["fused"] == outs["exact"]
+        return
+    # exact mode's own device program sums in another order: the same
+    # items in the same order, scores to float32 rounding
+    fused, exact = ([json.loads(line) for line in outs[mode].splitlines()]
+                    for mode in ("fused", "exact"))
+    assert len(fused) == len(exact) == 40
+    for f, e in zip(fused, exact):
+        assert f["query"] == e["query"]
+        fs, es = f["prediction"]["itemScores"], e["prediction"]["itemScores"]
+        assert [s["item"] for s in fs] == [s["item"] for s in es]
+        np.testing.assert_allclose([s["score"] for s in fs],
+                                   [s["score"] for s in es], rtol=2e-6)

@@ -786,6 +786,24 @@ def test_repo_is_clean_under_pio_check(repo_project):
     assert not report.findings, "\n" + report.render()
 
 
+def test_project_scan_is_the_package_only(tmp_path):
+    """`Project.from_root` scans `predictionio_tpu/**/*.py` and nothing
+    else: a script at the root is not part of the checked program (its
+    names would join the call graph and its findings the report)."""
+    pkg = tmp_path / "predictionio_tpu" / "sub"
+    pkg.mkdir(parents=True)
+    (pkg / "mod.py").write_text("x = 1\n")
+    (tmp_path / "script.py").write_text("print('a root-level script')\n")
+    (tmp_path / "README.md").write_text("# readme\n")
+    project = Project.from_root(tmp_path)
+    assert [f.path for f in project.files] == ["predictionio_tpu/sub/mod.py"]
+    assert project.doc_text("README.md") == "# readme\n"
+    assert not run_check(project).findings
+    # an explicit path is still honoured
+    named = Project.from_root(tmp_path, paths=["script.py"])
+    assert [f.path for f in named.files] == ["script.py"]
+
+
 def test_baseline_has_not_rotted(repo_project):
     """Every grandfathered entry still matches a live finding — a fixed
     finding must leave the baseline too (shrink-only discipline)."""
@@ -864,7 +882,7 @@ def _documented_knob_tokens():
 
 
 def test_every_read_knob_is_documented(repo_project):
-    """Every PIO_* env var the package (or bench.py) reads appears in
+    """Every PIO_* env var the package reads appears in
     README.md/OBSERVABILITY.md — a knob you can set but cannot find is
     config rot."""
     read = {k for _, _, k in env_knob_reads(repo_project)}

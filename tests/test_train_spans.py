@@ -194,6 +194,134 @@ def test_a_train_leaves_every_boundary_span_once(rated_app):
         app=rated_app) > 0
 
 
+def run_counts(prefix):
+    runs = default_registry().get(f"{prefix}_runs_total")
+    return {status: runs.value(status=status) if runs is not None else 0
+            for status in ("completed", "failed")}
+
+
+def test_failed_train_publishes_closed_spans(rated_app):
+    """A train that fails in its algorithm has already published the
+    spans that closed before it failed, and its record says `error`."""
+    from fake_engine import Algo0, AlgoParams, DataSource0, Preparator0, \
+        Serving0
+    from predictionio_tpu.core import Engine, EngineParams
+    from predictionio_tpu.workflow import run_train
+
+    class BoomAlgo(Algo0):
+        def train(self, ctx, pd):
+            with tracing.span("boom_step"):
+                pass
+            raise RuntimeError("boom")
+
+    closed = ("train_read", "train_prepare", "boom_step", "train_algorithm")
+    never = ("train_persist", "train_release")
+    counts0 = {name: span_count(name) for name in closed + never}
+    runs0 = run_counts("pio_train")
+    trace_context.recorder().clear()
+    with pytest.raises(RuntimeError, match="boom"):
+        run_train(Engine(DataSource0, Preparator0, {"a": BoomAlgo}, Serving0),
+                  EngineParams(
+                      algorithm_params_list=[("a", AlgoParams(id=1))]))
+    for name in closed:
+        assert span_count(name) == counts0[name] + 1, name
+    for name in never:
+        assert span_count(name) == counts0[name], name
+    assert run_counts("pio_train") == {
+        "completed": runs0["completed"], "failed": runs0["failed"] + 1}
+    record = [t for t in trace_context.recorder().traces()
+              if t["name"] == "train"][-1]
+    assert record["status"] == "error"
+    rows = {r["name"]: r for r in record["timeline"]}
+    assert set(rows) == set(closed)
+    assert record["timeline"][rows["boom_step"]["parent"]]["name"] \
+        == "train_algorithm"
+
+
+def test_evaluation_run_records_spans(rated_app):
+    """`run_evaluation` is one job too: the sweep's spans land on its
+    trace and in the scrape, and the run counts once by outcome."""
+    from predictionio_tpu.core import Evaluation
+    from predictionio_tpu.core.params import EngineParams
+    from predictionio_tpu.engines.recommendation import (
+        AlgorithmParams, DataSourceParams, PrecisionAtK, engine,
+    )
+    from predictionio_tpu.workflow import run_evaluation
+
+    params = [EngineParams(
+        data_source_params=DataSourceParams(
+            app_name=rated_app, eval_params={"kFold": 2, "queryNum": 3}),
+        algorithm_params_list=[("als", AlgorithmParams(
+            rank=r, num_iterations=2))]) for r in (3, 4)]
+    sweep_spans = ("eval_split", "eval_build", "eval_data_put",
+                   "eval_train_group", "eval_metrics")
+    counts0 = {name: span_count(name) for name in sweep_spans}
+    runs0 = run_counts("pio_eval")
+    trace_context.recorder().clear()
+    result = run_evaluation(
+        Evaluation(engine=engine(), metric=PrecisionAtK(k=3),
+                   output_path=None), params)
+    assert result.sweep["mode"] == "batched"
+    for name in sweep_spans:
+        assert span_count(name) > counts0[name], name
+    assert run_counts("pio_eval") == {
+        "completed": runs0["completed"] + 1, "failed": runs0["failed"]}
+    record = [t for t in trace_context.recorder().traces()
+              if t["name"] == "evaluate"][-1]
+    assert record["status"] == "ok"
+    assert set(sweep_spans) <= set(record["spans"])
+
+
+# -- the steps inside a model's build ----------------------------------------
+
+def build_naive_bayes(monkeypatch, mesh8):
+    from predictionio_tpu.models import naive_bayes
+
+    rng = np.random.default_rng(29)
+    X = rng.poisson(1.0, size=(64, 9)).astype(np.float32)
+    labels = np.where(rng.random(64) < 0.5, "a", "b")
+    host = naive_bayes.train_multinomial_nb(X, labels)
+    # the size gate routes a test-sized X to the host counter
+    monkeypatch.setattr(naive_bayes, "DEVICE_MIN_SIZE", 0)
+    model = naive_bayes.train_multinomial_nb(X, labels)
+    # the device's one-hot matmul counts what the host counted
+    np.testing.assert_allclose(model.log_prob, host.log_prob, atol=1e-6)
+    np.testing.assert_array_equal(model.predict(X), host.predict(X))
+
+
+def build_cooccurrence(monkeypatch, mesh8):
+    from predictionio_tpu.models.cooccurrence import (
+        cooccurrence_topn, distinct_pairs,
+    )
+
+    rng = np.random.default_rng(29)
+    users, items = distinct_pairs(rng.integers(0, 30, 200),
+                                  rng.integers(0, 20, 200))
+    counts, _ = cooccurrence_topn(mesh8, users, items, 30, 20, 4)
+    assert counts.shape == (20, 4) and counts.max() > 0
+
+
+@pytest.mark.parametrize("name, build", [
+    ("nb_compact", build_naive_bayes),
+    ("nb_transfer", build_naive_bayes),
+    ("incidence_build", build_cooccurrence),
+    ("incidence_transfer", build_cooccurrence),
+])
+def test_model_build_steps_are_spans(name, build, monkeypatch, mesh8):
+    """The host steps of a model's device path (compaction and upload of
+    naive Bayes' X, the cooccurrence incidence matrix) are spans of the
+    job that runs them: under the caller's open span, in the scrape."""
+    own = MetricsRegistry()
+    with tracing.adopt("job", registry=own) as trace:
+        with tracing.span("caller"):
+            build(monkeypatch, mesh8)
+    step, = [s for s in trace.spans if s.name == name]
+    assert step.parent.name == "caller"
+    assert 0 <= step.start_ns <= step.end_ns
+    assert span_count(name, own) == 1
+    assert trace.spans_by_name()[name] <= trace.spans_by_name()["caller"]
+
+
 # -- the profiler's trace, and the compiler's own events ---------------------
 
 def single_mesh():

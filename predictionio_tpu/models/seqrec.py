@@ -34,7 +34,7 @@ from predictionio_tpu.obs import jax_stats, train_stats
 from predictionio_tpu.obs.tracing import span
 from predictionio_tpu.ops import moe
 from predictionio_tpu.ops.attention import (
-    blockwise_attention, ring_attention_traced, rope,
+    blockwise_attention, ring_attention_traced, rope, routes_into,
 )
 
 
@@ -158,10 +158,14 @@ class SeqRecParams(Params):
 #: memory, not what it computes
 MEMORY_FIELDS = ("attention_impl", "remat")
 
-#: query and key block of the attention, and the tokens a feed-forward or
-#: the loss takes at a time under `remat`. Constants, from one chip run
-#: each at 16,384 tokens a step of 8,192-token sessions (PERF.md section
-#: 6, PR 27): a step took 1.27 s with attention blocks of 512, 1.35 at
+#: query and key block of the attention where it runs as a scan of XLA
+#: operations (`attention_route`: off a v5e, in a step sharded over a
+#: mesh and for shapes the Pallas kernels do not tile; the kernels bring
+#: blocks of their own) and the multiple a session's length is padded to
+#: on either route; the tokens a feed-forward or the loss takes at a time
+#: under `remat`. Constants, from one chip run each at 16,384 tokens a
+#: step of 8,192-token sessions (PERF.md section 6, PR 27, the scan on
+#: the chip): a step took 1.27 s with attention blocks of 512, 1.35 at
 #: 1024, 1.32 at 2048; 1.35, 1.35, 1.36 with token blocks of 1024, 2048,
 #: 4096; the compiler counted the same temporaries for all of them.
 ATTENTION_BLOCK = 512
@@ -299,7 +303,8 @@ def _attention(layer, x, key_mask, p: SeqRecParams, mesh, use_ring):
                                     key_mask=key_mask)
     else:
         att = blockwise_attention(q, k, v, block_k=ATTENTION_BLOCK,
-                                  causal=True, key_mask=key_mask)
+                                  causal=True, key_mask=key_mask,
+                                  devices=1 if mesh is None else mesh.size)
     return att.reshape(b, l, -1) @ layer["wo"]
 
 
@@ -448,9 +453,11 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
     """One donated jitted step -> (params, opt_state, the step's numbers:
     loss, by group the gradient's norm and the norm of what the step
     added to the parameters, and per expert layer the tokens routed to
-    each expert, to each held expert, and dropped). With a mesh,
-    batch is sharded over "data" and embedding/ffn rows over "model";
-    XLA inserts the psums."""
+    each expert, to each held expert, and dropped; `attention_pallas`,
+    a constant of the trace: whether `blockwise_attention` folded every
+    layer's blocks with the Pallas kernels). With a mesh, batch is
+    sharded over "data" and embedding/ffn rows over "model"; XLA inserts
+    the psums."""
 
     def step(params, opt_state, seqs, targets):
         if mesh is not None and "data" in mesh.axis_names:
@@ -461,10 +468,13 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
             sh = NamedSharding(mesh, P("data", seq_dim))
             seqs = jax.lax.with_sharding_constraint(seqs, sh)
             targets = jax.lax.with_sharding_constraint(targets, sh)
-        (loss, expert_layers), grads = jax.value_and_grad(
-            _loss_fn, has_aux=True)(params, seqs, targets, p, mesh)
+        routes: set = set()
+        with routes_into(routes):
+            (loss, expert_layers), grads = jax.value_and_grad(
+                _loss_fn, has_aux=True)(params, seqs, targets, p, mesh)
         updates, opt_state = optimizer.update(grads, opt_state, params)
-        stats = {"loss": loss, "grad_norm": _group_norms(grads)}
+        stats = {"loss": loss, "grad_norm": _group_norms(grads),
+                 "attention_pallas": jnp.asarray(routes == {"pallas"})}
         if expert_layers:
             # a selection bias is moved by its layer's load, not by adamw
             moe_layers = [layer for i, layer in enumerate(updates["layers"])
@@ -744,10 +754,14 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
         params = replicate(params)
     with span("seqrec_fetch"):
         host = jax.tree.map(np.asarray, params)
-        record = _training_record(jax.device_get(steps), rows)
+        steps = jax.device_get(steps)
+        record = _training_record(steps, rows)
     train_stats.seqrec_fetch_bytes().inc(
         sum(leaf.nbytes for leaf in jax.tree.leaves(host)))
-    train_stats.observe_seqrec_record(record, targets, rows)
+    # one compiled step made every step of the train: one route
+    train_stats.observe_seqrec_record(
+        record, targets, rows,
+        "pallas" if steps and steps[0]["attention_pallas"] else "xla")
     return SeqRecModel(item_vocab=all_items, params=host, hyper=p,
                        record=record)
 

@@ -44,6 +44,10 @@ the weights), `seqrec_put` (sharding, the optimizer's state),
 * ``pio_train_seqrec_tokens_total`` / ``pio_train_seqrec_pad_tokens_total``
   — positions of the trained batches that carry a target / that are
   padding.
+* ``pio_train_seqrec_attention_tokens_total{impl}`` — positions of the
+  trained batches, padding too, by the route ``blockwise_attention``
+  took when their step was traced: ``pallas`` (every layer through the
+  kernels of ops/attention_pallas.py) or ``xla``.
 * ``pio_train_seqrec_expert_tokens_total{layer}`` — tokens the experts
   held here received, by expert layer.
 * ``pio_train_seqrec_expert_load_max_over_mean`` — the busiest routed
@@ -159,6 +163,14 @@ def seqrec_pad_tokens(registry: MetricsRegistry = None):
         "Positions of the trained batches that are padding")
 
 
+def seqrec_attention_tokens(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_attention_tokens_total",
+        "Positions of the trained batches by the route their step's "
+        "attention was traced on (ops/attention.attention_route)",
+        labelnames=("impl",))
+
+
 def seqrec_expert_tokens(registry: MetricsRegistry = None):
     return (registry or default_registry()).counter(
         "pio_train_seqrec_expert_tokens_total",
@@ -186,15 +198,19 @@ def seqrec_fetch_bytes(registry: MetricsRegistry = None):
         "training")
 
 
-def observe_seqrec_record(record: dict, targets, rows) -> None:
+def observe_seqrec_record(record: dict, targets, rows,
+                          attention_impl: str) -> None:
     """The token and expert counters from one train's record
     (models/seqrec.train_seqrec): `targets` the padded target ids of all
-    sessions, `rows` the sessions of each step's batch."""
+    sessions, `rows` the sessions of each step's batch, `attention_impl`
+    the route its step was traced on."""
     import numpy as np
 
     real = sum(int((targets[r] > 0).sum()) for r in rows)
+    positions = sum(targets[r].size for r in rows)
     seqrec_tokens().inc(real)
-    seqrec_pad_tokens().inc(sum(targets[r].size for r in rows) - real)
+    seqrec_pad_tokens().inc(positions - real)
+    seqrec_attention_tokens().inc(positions, impl=attention_impl)
     if "load" not in record or not record["load"]:
         return
     load = np.asarray(record["load"], np.float64)      # [step, layer, expert]

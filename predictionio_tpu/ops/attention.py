@@ -28,15 +28,18 @@ matmuls hit the MXU in bf16, the recurrence stays stable in f32).
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
-from typing import Optional
+from typing import Iterator, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-NEG_INF = -1e30    # large-negative instead of -inf: avoids NaN in exp(m - m)
+from predictionio_tpu.ops import attention_pallas
+from predictionio_tpu.ops.attention_pallas import NEG_INF
 
 
 def _causal_mask(scores: jax.Array, q_off, k_off) -> jax.Array:
@@ -227,10 +230,57 @@ def _blockwise_bwd(block_q, block_k, causal, res, d_out):
 _blockwise.defvjp(_blockwise_fwd, _blockwise_bwd)
 
 
+def _blocks_and_pads(lq: int, lk: int, block_k: int,
+                     block_q: Optional[int]) -> Tuple[int, int, int, int]:
+    """(block_q, block_k, pad_q, pad_k) of `blockwise_attention`."""
+    block_k = min(block_k, lk)
+    block_q = min(block_q or block_k, lq)
+    return block_q, block_k, -lq % block_q, -lk % block_k
+
+
+def attention_route(device_kind: str, lq: int, lk: int, dk: int, dv: int,
+                    block_k: int = 512, block_q: Optional[int] = None,
+                    devices: int = 1) -> str:
+    """Which implementation `blockwise_attention` runs for these sizes on
+    a device of this kind (`jax.Device.device_kind`), in a program traced
+    for `devices` devices: "pallas", the kernels of
+    ops/attention_pallas.py, on the TPUs their blocks and VMEM limits
+    were measured on (`attention_pallas.KINDS`), for shapes they tile
+    (the lengths as `blockwise_attention` pads them), in a program for
+    one device (the compiler partitions no Mosaic kernel: a program
+    sharded over a mesh keeps the scan); "xla", the scan over block
+    pairs, everywhere else."""
+    _, _, pad_q, pad_k = _blocks_and_pads(lq, lk, block_k, block_q)
+    if (device_kind in attention_pallas.KINDS and devices == 1
+            and attention_pallas.tiles(lq + pad_q, lk + pad_k, dk, dv)):
+        return "pallas"
+    return "xla"
+
+
+def _device_kind() -> str:
+    return jax.devices()[0].device_kind
+
+
+_ROUTES: contextvars.ContextVar[Optional[Set[str]]] = contextvars.ContextVar(
+    "blockwise_attention_routes", default=None)
+
+
+@contextlib.contextmanager
+def routes_into(routes: Set[str]) -> Iterator[None]:
+    """While the block runs (a trace), every `blockwise_attention` call
+    adds the route it took to `routes`."""
+    token = _ROUTES.set(routes)
+    try:
+        yield
+    finally:
+        _ROUTES.reset(token)
+
+
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         block_k: int = 512, causal: bool = False,
                         key_mask: Optional[jax.Array] = None,
-                        block_q: Optional[int] = None) -> jax.Array:
+                        block_q: Optional[int] = None,
+                        devices: int = 1) -> jax.Array:
     """Flash-style single-device attention: stream over blocks of queries
     and of keys with the running-max/denominator recurrence so the
     [Lq, Lk] score matrix never materializes, forward or backward
@@ -240,14 +290,17 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     -> [B, Lq, H, Dv]. block_q defaults to block_k.
     key_mask: optional [B, Lk] bool, False = key is padding (ignored).
     Lengths that are not a block multiple are handled by padding up to
-    one: pad keys are masked out, pad queries cut off the result."""
-    b, lq, h, _ = q.shape
-    lk = k.shape[1]
-    block_k = min(block_k, lk)
-    block_q = min(block_q or block_k, lq)
+    one: pad keys are masked out, pad queries cut off the result.
+    `attention_route` says from the device's kind, the sizes and
+    `devices` (how many devices the calling program is traced for: a
+    mesh's size) whether the blocks are folded by Pallas kernels (with
+    blocks of their own) or by a scan of XLA operations."""
+    b, lq, h, dk = q.shape
+    lk, dv = k.shape[1], v.shape[-1]
+    block_q, block_k, pad_q, pad_k = _blocks_and_pads(lq, lk, block_k,
+                                                      block_q)
     if key_mask is None:
         key_mask = jnp.ones((b, lk), bool)
-    pad_k, pad_q = -lk % block_k, -lq % block_q
     if pad_k:
         k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
@@ -255,8 +308,17 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if pad_q:
         q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
     heads_first = lambda t: jnp.swapaxes(t, 1, 2)
-    out = _blockwise(heads_first(q), heads_first(k), heads_first(v),
-                     key_mask, block_q, block_k, causal)
+    route = attention_route(_device_kind(), lq, lk, dk, dv, block_k, block_q,
+                            devices)
+    heard = _ROUTES.get()
+    if heard is not None:
+        heard.add(route)
+    if route == "pallas":
+        out = attention_pallas.flash_attention_pallas(
+            heads_first(q), heads_first(k), heads_first(v), key_mask, causal)
+    else:
+        out = _blockwise(heads_first(q), heads_first(k), heads_first(v),
+                         key_mask, block_q, block_k, causal)
     return heads_first(out)[:, :lq]
 
 

@@ -1,0 +1,334 @@
+"""Blockwise attention as Pallas TPU kernels, forward and backward.
+
+The same mathematics as ``ops/attention._blockwise`` (the flash
+recurrence over blocks of queries and keys, a backward pass that
+recomputes a block's probabilities from the saved log-sum-exp), with the
+block pair's scores and the running accumulators held in VMEM: a query
+block's output, maximum and denominator are written to HBM once, and so
+is every row of ``dq``, ``dk`` and ``dv``.
+
+Two kernels, each a grid over (batch, head, block pair). The pairs are a
+table the kernel is handed (scalar prefetch), so a causal pair whose
+keys all lie in the future is no grid step at all; only a pair on the
+diagonal, or one that holds a padding key, builds a mask.
+
+* ``flash_attention_pallas_fwd``: pairs query-major, scores [bq, bk];
+  output, maximum and denominator of a query block accumulate over its
+  key blocks.
+* ``flash_attention_pallas_bwd``: pairs key-major, scores transposed
+  [bk, bq] so that ``lse`` and ``delta`` lie along lanes and four of the
+  five products need no transposed operand; ``dk`` and ``dv`` of a key
+  block accumulate over its query blocks, and ``dq`` of one (batch row,
+  head) stays in VMEM whole until every pair has added to it: that bounds
+  the length the kernels take (``tiles``).
+
+Precision: every product takes its operands in bfloat16 (what the TPU's
+default does to float32 operands), rounded once on their way in, and
+accumulates in float32; scores, maxima, exponentials, denominators,
+``lse``, ``delta`` and all accumulators are float32.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+NEG_INF = -1e30    # large-negative instead of -inf: avoids NaN in exp(m - m)
+
+#: query and key block of both kernels: the largest of 1024, 512, 256, 128
+#: that divides the length. A constant, from chip runs of each kernel alone
+#: at 2 x 16 heads x 8,192 positions, widths 192 / 128 (PERF.md section 6,
+#: PR 30), query x key block: a forward call took 11.4 ms at 512 x 512,
+#: 8.5 at 512 x 1024, 8.2 at 1024 x 1024, 9.2 at 1024 x 2048, 15.0 at
+#: 2048 x 2048; a backward call 17.5 at 512 x 512, 17.2 at 512 x 1024,
+#: 16.8 at 1024 x 1024, 29.6 at 2048 x 1024.
+BLOCK = 1024
+
+#: the device kinds (`jax.Device.device_kind`) the block and the two limits
+#: below were measured on; `ops/attention.attention_route` sends no other
+#: kind here. A v5e core has 128 MiB of VMEM: a generation with less would
+#: refuse at compile time what these limits let through, and one with more
+#: is a chip run away from being listed.
+KINDS = ("TPU v5 lite",)
+
+#: what Mosaic may use of a v5e core's 128 MiB (its default is 16): a
+#: pair's scores and their temporaries take some 30 MiB at 1024 x 1024,
+#: `dq` of one (batch row, head) twice its size (an output block has two
+#: buffers)
+_VMEM_LIMIT = 100 * 1024 * 1024
+_DQ_VMEM = 48 * 1024 * 1024
+
+_NT = (((1,), (1,)), ((), ()))     # a @ b.T
+
+
+def _block(length: int) -> int:
+    """The largest of BLOCK, BLOCK / 2, / 4, / 8 that divides the length,
+    0 if none does."""
+    return next((b for b in (BLOCK, BLOCK // 2, BLOCK // 4, BLOCK // 8)
+                 if length % b == 0), 0)
+
+
+def tiles(lq: int, lk: int, dk: int, dv: int) -> bool:
+    """Whether the kernels lay these shapes out: lengths in whole lane
+    tiles, widths in whole (q, k: half) lane tiles, and `dq` of a
+    (batch row, head), float32 on whole lane tiles, within its VMEM."""
+    return (_block(lq) > 0 and _block(lk) > 0 and dk % 64 == 0
+            and dv % 128 == 0
+            and 2 * lq * -(-dk // 128) * 128 * 4 <= _DQ_VMEM)
+
+
+def _block_pairs(n_q: int, n_k: int, bq: int, bk: int, causal: bool,
+                 key_major: bool) -> np.ndarray:
+    """[pairs, 2] (query block, key block) with an unmasked score."""
+    pairs = [(i, j) for i in range(n_q) for j in range(n_k)
+             if not causal or j * bk <= i * bq + bq - 1]
+    if key_major:
+        pairs.sort(key=lambda ij: (ij[1], ij[0]))
+    return np.asarray(pairs, np.int32)
+
+
+def _keep(mask, i, j, bq, bk, causal, keys_on_rows):
+    """Which scores of pair (i, j) count: the key is no padding and, if
+    causal, not in the query's future. mask: the key block's, laid along
+    the scores' key axis."""
+    keep = mask > 0
+    if not causal:
+        return keep
+    shape = (bk, bq) if keys_on_rows else (bq, bk)
+    key_axis = 0 if keys_on_rows else 1
+    keys = j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, key_axis)
+    queries = i * bq + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                1 - key_axis)
+    return keep & (keys <= queries)
+
+
+def _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal):
+    """Run fold(masked) with masked True only where the pair needs it."""
+    needs = full_ref[b * n_k + j] == 0
+    if causal:
+        needs = needs | (j * bk + bk - 1 > i * bq)
+    pl.when(needs)(lambda: fold(True))
+    pl.when(jnp.logical_not(needs))(lambda: fold(False))
+
+
+def _fwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
+                *refs, scale, causal, bq, bk, n_k, save_lse):
+    if save_lse:
+        o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+    else:
+        o_ref, m_scr, l_scr, acc_scr = refs
+    b, p = pl.program_id(0), pl.program_id(2)
+    i, j = qi_ref[p], kj_ref[p]
+    last_j = jnp.minimum(((i + 1) * bq - 1) // bk, n_k - 1) \
+        if causal else n_k - 1
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def fold(masked):
+        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0], _NT,
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            keep = _keep(mask_ref[0], i, j, bq, bk, causal, False)
+            s = jnp.where(keep, s, NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        prob = jnp.exp(s - m_new)
+        if masked:
+            # explicit zero for masked scores: with the finite NEG_INF
+            # sentinel, exp(s - m_new) would be 1 (not 0) in all-masked
+            # rows
+            prob = jnp.where(keep, prob, 0.0)
+        l_scr[...] = l_scr[...] * alpha + prob.sum(axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
+            prob.astype(v_ref.dtype), v_ref[0, 0],
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+
+    _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal)
+
+    @pl.when(j == last_j)
+    def _():
+        l = l_scr[...]
+        l = jnp.where(l == 0.0, 1.0, l)     # a fully masked row gives 0
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        if save_lse:
+            lse_ref[0, 0] = m_scr[...] + jnp.log(l)
+
+
+def _bwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
+                do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                dk_scr, dv_scr, *, scale, causal, bq, bk, n_q, n_k):
+    b, p = pl.program_id(0), pl.program_id(2)
+    i, j = qi_ref[p], kj_ref[p]
+    first_i = (j * bk) // bq if causal else 0
+
+    @pl.when(p == 0)
+    def _():
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+
+    @pl.when(i == first_i)
+    def _():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def fold(masked):
+        q, k, do = q_ref[0, 0], k_ref[0, 0], do_ref[0, 0]
+        s = jax.lax.dot_general(k, q, _NT,
+                                preferred_element_type=jnp.float32) * scale
+        prob = jnp.exp(s - lse_ref[0, 0])
+        if masked:
+            prob = jnp.where(_keep(mask_ref[0], i, j, bq, bk, causal, True),
+                             prob, 0.0)
+        dv_scr[...] += jnp.dot(prob.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v_ref[0, 0], do, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = prob * (dp - delta_ref[0, 0]) * scale
+        dk_scr[...] += jnp.dot(ds.astype(q.dtype), q,
+                               preferred_element_type=jnp.float32)
+        rows = pl.ds(pl.multiple_of(i * bq, bq), bq)
+        dq_ref[0, 0, rows, :] += jnp.dot(ds.T.astype(k.dtype), k,
+                                         preferred_element_type=jnp.float32)
+
+    _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal)
+
+    @pl.when(i == n_q - 1)
+    def _():
+        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _call(kernel, name, key_mask, pairs, heads, bk, in_specs, out_specs,
+          out_shape, scratch_shapes, interpret):
+    """The pallas_call over (batch row, head, pair), applied to the pair
+    table: each pair's query and key block and, per (batch row, key
+    block), whether every key in it is real. Index maps see (b, h, pair,
+    qi, kj, full)."""
+    b, lk = key_mask.shape
+    full = key_mask.reshape(b, lk // bk, bk).all(axis=-1)
+    return functools.partial(
+        pl.pallas_call(
+            kernel, name=name, out_shape=out_shape,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3, grid=(b, heads, len(pairs)),
+                in_specs=in_specs, out_specs=out_specs,
+                scratch_shapes=scratch_shapes),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT),
+            interpret=interpret),
+        jnp.asarray(pairs[:, 0]), jnp.asarray(pairs[:, 1]),
+        full.reshape(-1).astype(jnp.int32))
+
+
+def _specs(bq, bk):
+    """Block specs by what a block follows: the pair's query block or its
+    key block."""
+    def by_query(width):
+        return pl.BlockSpec((1, 1, bq, width),
+                            lambda b, h, p, qi, kj, full: (b, h, qi[p], 0))
+
+    def by_key(width):
+        return pl.BlockSpec((1, 1, bk, width),
+                            lambda b, h, p, qi, kj, full: (b, h, kj[p], 0))
+
+    return by_query, by_key
+
+
+def _forward(q, k, v, key_mask, causal, bq, bk, interpret, save_lse):
+    b, h, lq, dk = q.shape
+    lk, dv = k.shape[2], v.shape[3]
+    pairs = _block_pairs(lq // bq, lk // bk, bq, bk, causal, key_major=False)
+    by_query, by_key = _specs(bq, bk)
+    out_shape = [jax.ShapeDtypeStruct((b, h, lq, dv), jnp.float32)]
+    out_specs = [by_query(dv)]
+    if save_lse:
+        out_shape.append(jax.ShapeDtypeStruct((b, h, lq, 1), jnp.float32))
+        out_specs.append(by_query(1))
+    return _call(
+        functools.partial(_fwd_kernel, scale=dk ** -0.5, causal=causal,
+                          bq=bq, bk=bk, n_k=lk // bk, save_lse=save_lse),
+        "flash_attention_pallas_fwd", key_mask, pairs, h, bk,
+        [by_query(dk), by_key(dk), by_key(dv),
+         pl.BlockSpec((1, 1, bk),
+                      lambda b, h, p, qi, kj, full: (b, 0, kj[p]))],
+        out_specs, out_shape,
+        [pltpu.VMEM((bq, 1), jnp.float32), pltpu.VMEM((bq, 1), jnp.float32),
+         pltpu.VMEM((bq, dv), jnp.float32)], interpret)(
+        q, k, v, key_mask.astype(jnp.int32)[:, None, :])
+
+
+def _backward(q, k, v, key_mask, d_out, lse, delta, causal, bq, bk,
+              interpret):
+    b, h, lq, dk = q.shape
+    lk, dv = k.shape[2], v.shape[3]
+    pairs = _block_pairs(lq // bq, lk // bk, bq, bk, causal, key_major=True)
+    by_query, by_key = _specs(bq, bk)
+    row = pl.BlockSpec((1, 1, 1, bq),
+                       lambda b, h, p, qi, kj, full: (b, h, 0, qi[p]))
+    dq, d_k, d_v = _call(
+        functools.partial(_bwd_kernel, scale=dk ** -0.5, causal=causal,
+                          bq=bq, bk=bk, n_q=lq // bq, n_k=lk // bk),
+        "flash_attention_pallas_bwd", key_mask, pairs, h, bk,
+        [by_query(dk), by_key(dk), by_key(dv),
+         pl.BlockSpec((1, bk, 1),
+                      lambda b, h, p, qi, kj, full: (b, kj[p], 0)),
+         by_query(dv), row, row],
+        [pl.BlockSpec((1, 1, lq, dk),
+                      lambda b, h, p, qi, kj, full: (b, h, 0, 0)),
+         by_key(dk), by_key(dv)],
+        [jax.ShapeDtypeStruct(t.shape, jnp.float32) for t in (q, k, v)],
+        [pltpu.VMEM((bk, dk), jnp.float32),
+         pltpu.VMEM((bk, dv), jnp.float32)], interpret)(
+        q, k, v, key_mask.astype(jnp.int32)[:, :, None], d_out,
+        lse[:, :, None, :], delta[:, :, None, :])
+    seen = -(-lq // bk) * bk      # causal keys past every query: no pair
+    if causal and seen < lk:
+        d_k, d_v = (t.at[:, :, seen:].set(0.0) for t in (d_k, d_v))
+    return dq, d_k, d_v
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def flash_attention_pallas(q, k, v, key_mask, causal: bool,
+                           interpret: bool = False):
+    """q [B, H, Lq, Dk], k [B, H, Lk, Dk], v [B, H, Lk, Dv] of one dtype,
+    lengths multiples of 128; key_mask [B, Lk] bool, False = padding ->
+    [B, H, Lq, Dv] in that dtype. `interpret` runs the kernels in the
+    Pallas interpreter (the CPU tests)."""
+    return _fwd(q, k, v, key_mask, causal, interpret, save_lse=False)[0]
+
+
+def _fwd(q, k, v, key_mask, causal, interpret, save_lse=True):
+    if not q.dtype == k.dtype == v.dtype:
+        raise ValueError(f"q, k, v of one dtype expected, got {q.dtype}, "
+                         f"{k.dtype}, {v.dtype}")
+    ops = tuple(t.astype(jnp.bfloat16) for t in (q, k, v))
+    out, *lse = _forward(*ops, key_mask, causal, _block(q.shape[2]),
+                         _block(k.shape[2]), interpret, save_lse)
+    out = out.astype(q.dtype)
+    if not save_lse:
+        return out, None
+    return out, (*ops, key_mask, out, lse[0][..., 0])
+
+
+def _bwd(causal, interpret, res, d_out):
+    q, k, v, key_mask, out, lse = res
+    delta = (d_out.astype(jnp.float32)
+             * out.astype(jnp.float32)).sum(axis=-1)          # [B, H, Lq]
+    grads = _backward(q, k, v, key_mask, d_out.astype(jnp.bfloat16), lse,
+                      delta, causal, _block(q.shape[2]), _block(k.shape[2]),
+                      interpret)
+    return (*(g.astype(out.dtype) for g in grads), None)
+
+
+flash_attention_pallas.defvjp(_fwd, _bwd)

@@ -448,3 +448,73 @@ def test_a_trace_inside_a_trace_is_not_counted_again():
     assert during == [0.0]
     events.on_duration(trace, 2.0, fun_name="outer")
     assert events.durations[trace].value() == 2.0
+
+
+def test_a_sequence_model_train_counts_its_attention_tokens_by_route():
+    """`pio_train_seqrec_attention_tokens_total{impl}`: every position of
+    the trained batches under the route `blockwise_attention` took when
+    the train's step was traced (on the CPU: `xla`), which the step
+    itself reports, so a step from the cache is counted like a new one."""
+    import jax
+
+    from predictionio_tpu.models import seqrec
+    from predictionio_tpu.ops.attention import attention_route
+
+    p = seqrec.SeqRecParams(d_model=16, n_heads=2, n_layers=1, max_len=8,
+                            batch_size=2, epochs=2)
+    assert attention_route(jax.devices()[0].device_kind, 8, 8, 8, 8,
+                           seqrec.ATTENTION_BLOCK) == "xla"
+    reg = default_registry()
+
+    def counted(label):
+        c = reg.get("pio_train_seqrec_attention_tokens_total")
+        return c.value(impl=label) if c is not None else 0
+
+    def positions():
+        return sum(reg.get(name).value() for name in (
+            "pio_train_seqrec_tokens_total",
+            "pio_train_seqrec_pad_tokens_total"))
+
+    seqrec.train_seqrec(None, [["a", "b"]], p)       # the series exist
+    before = {label: counted(label) for label in ("xla", "pallas")}
+    positions0 = positions()
+    sessions = [[f"i{(s + j) % 11}" for j in range(6 + s)] for s in range(4)]
+    for trains in (1, 2):               # the second: the step of the first
+        seqrec.train_seqrec(None, sessions, p)
+        # 2 epochs x 2 steps x 2 sessions x 8 positions, padding included
+        assert counted("xla") - before["xla"] == trains * 2 * 2 * 2 * 8
+        assert counted("pallas") == before["pallas"]
+        assert positions() - positions0 == trains * 2 * 2 * 2 * 8
+
+
+def test_a_step_says_which_route_its_attention_was_traced_on(monkeypatch):
+    """The step's `attention_pallas` is what `blockwise_attention` chose
+    at trace time, not what the spec would suggest: with the kernels'
+    route forced (and interpreted) it reads True, under a mesh of two
+    devices the same spec reads False."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from predictionio_tpu.models import seqrec
+    from predictionio_tpu.ops import attention, attention_pallas
+
+    monkeypatch.setattr(attention, "_device_kind",
+                        lambda: attention_pallas.KINDS[0])
+    kernels = attention_pallas.flash_attention_pallas
+    monkeypatch.setattr(
+        attention_pallas, "flash_attention_pallas",
+        lambda q, k, v, mask, causal: kernels(q, k, v, mask, causal, True))
+    p = seqrec.SeqRecParams(d_model=128, n_heads=1, n_layers=1, max_len=128,
+                            batch_size=2)
+    optimizer = seqrec.make_optimizer(p)
+    seqs = jnp.asarray(np.random.default_rng(0).integers(1, 9, (2, 128)),
+                       jnp.int32)
+    for mesh, pallas in (
+            (None, True),
+            (Mesh(np.asarray(jax.devices()[:2]), ("data",)), False)):
+        params = seqrec.init_params(np.random.default_rng(0), 8, p)
+        _, _, stats = seqrec.make_train_step(mesh, p, optimizer)(
+            params, optimizer.init(params), seqs, seqs)
+        assert bool(stats["attention_pallas"]) is pallas
+        assert np.isfinite(float(stats["loss"]))

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +32,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from predictionio_tpu.core.params import Params
 from predictionio_tpu.obs import jax_stats, train_stats
 from predictionio_tpu.obs.tracing import span
-from predictionio_tpu.ops import moe
+from predictionio_tpu.ops import linear_attention, moe
 from predictionio_tpu.ops.attention import (
     blockwise_attention, ring_attention_traced, rope, routes_into,
 )
@@ -62,16 +62,27 @@ class SeqRecParams(Params):
     attention_impl: str = "flash"
 
     # -- the layer spec -------------------------------------------------
-    #: "mha": fused q/k/v of d_model / n_heads a head. "mla": latent
-    #: attention — per-head queries of qk_nope + qk_rope, keys and values
-    #: expanded from one shared latent of kv_lora_rank, one rotary key of
-    #: qk_rope shared by all heads, values of v_head_dim.
-    mixer: str = "mha"
+    #: one kind for every layer, or one period of kinds repeated over
+    #: the layers (layer i has mixer[i % len(mixer)]). "mha": fused q/k/v
+    #: of d_model / n_heads a head. "mla": latent attention — per-head
+    #: queries of qk_nope + qk_rope, keys and values expanded from one
+    #: shared latent of kv_lora_rank, one rotary key of qk_rope shared by
+    #: all heads, values of v_head_dim. "gqa": n_heads query heads of
+    #: head_dim over n_kv_heads key/value heads, queries and keys normed
+    #: over the head width, rotary positions on the leading rotary_dim of
+    #: it, the output gated by a sigmoid of a projection of the input.
+    #: "gdn": linear attention by the gated delta rule
+    #: (ops/linear_attention.py) behind a causal convolution of
+    #: linear_conv_kernel taps, linear_key_heads key heads of
+    #: linear_key_head_dim serving linear_value_heads value heads of
+    #: linear_value_head_dim, the output normed a head and gated.
+    mixer: Union[str, Sequence[str]] = "mha"
     #: "gelu" (two matrices), "swiglu" (three), or "moe": routed SwiGLU
     #: experts of moe_width plus one shared SwiGLU of n_shared_experts x
     #: moe_width, after `first_dense_layers` layers of dense swiglu
     ffn: str = "gelu"
-    #: "layer" (scale and bias) or "rms" (scale)
+    #: "layer" (scale and bias), "rms" (scale) or "rms_zero_centered"
+    #: (1 + a weight drawn 0)
     norm: str = "layer"
     norm_eps: float = 1e-6
     #: "learned" (a table of max_len rows added to the embeddings) or
@@ -87,6 +98,14 @@ class SeqRecParams(Params):
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     kv_lora_rank: int = 0
+    n_kv_heads: int = 0
+    head_dim: int = 0
+    rotary_dim: int = 0
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 0
     #: the router's width: every expert of a layer, wherever it lives
     n_routed_experts: int = 0
     #: [first, end) of them are held (and trained) here; the others lie
@@ -95,6 +114,11 @@ class SeqRecParams(Params):
     experts_per_token: int = 0
     moe_width: int = 0
     n_shared_experts: int = 0
+    #: the shared expert's output times a sigmoid of x . w (one column)
+    shared_expert_gate: bool = False
+    #: the router's affinities: each output's "sigmoid", or the "softmax"
+    #: over all of them
+    router_scoring: str = "sigmoid"
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
     #: after each step b += rate * sign(mean load - load) on the router's
@@ -114,6 +138,15 @@ class SeqRecParams(Params):
     #: kept, never what is computed (`MEMORY_FIELDS`: no part of a run's
     #: identity)
     remat: bool = False
+
+    def mixer_kind(self, layer: int) -> str:
+        period = (self.mixer,) if isinstance(self.mixer, str) \
+            else tuple(self.mixer)
+        return period[layer % len(period)]
+
+    def mixer_kinds(self) -> Tuple[str, ...]:
+        """Each layer's mixer."""
+        return tuple(self.mixer_kind(i) for i in range(self.n_layers))
 
     def ffn_kind(self, layer: int) -> str:
         if self.ffn == "moe" and layer < self.first_dense_layers:
@@ -135,14 +168,54 @@ class SeqRecParams(Params):
                              else v) for k, v in spec.items()))
 
     def check(self) -> None:
-        for name, kinds in (("mixer", ("mha", "mla")),
-                            ("ffn", ("gelu", "swiglu", "moe")),
-                            ("norm", ("layer", "rms")),
+        if not self.mixer:
+            raise ValueError("mixer names no kind")
+        unknown = set(self.mixer_kinds()) - set(MIXERS)
+        if unknown:
+            raise ValueError(f"unknown mixer {sorted(unknown)}: expected "
+                             f"among {MIXERS}")
+        for name, kinds in (("ffn", ("gelu", "swiglu", "moe")),
+                            ("norm", ("layer", "rms", "rms_zero_centered")),
                             ("positions", ("learned", "rope")),
-                            ("attention_impl", ("flash", "ring"))):
+                            ("attention_impl", ("flash", "ring")),
+                            ("router_scoring", ("sigmoid", "softmax"))):
             if getattr(self, name) not in kinds:
                 raise ValueError(f"unknown {name} {getattr(self, name)!r}: "
                                  f"expected one of {kinds}")
+        new = set(self.mixer_kinds()) & {"gqa", "gdn"}
+        if new:
+            # what these mixers are not defined with: their norms are RMS
+            # norms, their only positions rotary (gqa) or the convolution's
+            # (gdn), and the ring takes one key/value head a query head
+            for name, refused in (("norm", "layer"),
+                                  ("positions", "learned"),
+                                  ("attention_impl", "ring")):
+                if getattr(self, name) == refused:
+                    raise ValueError(f"{name} {refused!r} does not go with "
+                                     f"the mixers {sorted(new)}")
+        if "gqa" in new:
+            if self.head_dim <= 0 or self.n_kv_heads <= 0 \
+                    or self.n_heads % self.n_kv_heads:
+                raise ValueError(
+                    f"gqa needs head_dim > 0 and n_kv_heads a divisor of "
+                    f"n_heads: {self.head_dim}, {self.n_kv_heads}, "
+                    f"{self.n_heads}")
+            if not 0 < self.rotary_dim <= self.head_dim or self.rotary_dim % 2:
+                raise ValueError(f"rotary_dim {self.rotary_dim} is no even "
+                                 f"part of head_dim {self.head_dim}")
+        if "gdn" in new:
+            sizes = (self.linear_key_heads, self.linear_value_heads,
+                     self.linear_key_head_dim, self.linear_value_head_dim,
+                     self.linear_conv_kernel)
+            if min(sizes) <= 0 \
+                    or self.linear_value_heads % self.linear_key_heads:
+                raise ValueError(
+                    f"gdn needs its five linear_* sizes > 0 and "
+                    f"linear_key_heads a divisor of linear_value_heads: "
+                    f"{sizes}")
+        if self.shared_expert_gate and not (self.ffn == "moe"
+                                            and self.n_shared_experts):
+            raise ValueError("shared_expert_gate without a shared expert")
         if self.ffn == "moe":
             lo, hi = self.held_experts
             if not 0 <= lo < hi <= self.n_routed_experts:
@@ -153,6 +226,8 @@ class SeqRecParams(Params):
                 raise ValueError("experts_per_token must be 1.."
                                  "n_routed_experts")
 
+
+MIXERS = ("mha", "mla", "gqa", "gdn")
 
 #: settings that change where a train's work lies and what it keeps in
 #: memory, not what it computes
@@ -170,6 +245,14 @@ MEMORY_FIELDS = ("attention_impl", "remat")
 #: 4096; the compiler counted the same temporaries for all of them.
 ATTENTION_BLOCK = 512
 TOKEN_BLOCK = 2048
+#: key heads of a linear-attention layer taken at a time under `remat`
+#: (with the value heads they serve). A constant, from chip runs of the
+#: step at 16,384 positions, 16 key and 32 value heads of 128 (PERF.md
+#: section 6, PR 31): a step took 1.109 s at 2, 1.053 at 4, 0.964 at 8;
+#: the compiler counted 5.11, 5.97 and 8.25 GiB of temporaries (10.97
+#: with all 16 at once) beside 6.99 GiB of weights and moments, and 8
+#: leaves a 16 GB chip under 0.2 GB.
+LINEAR_KEY_HEADS = 4
 
 
 def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
@@ -194,10 +277,20 @@ def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
         return jnp.asarray(rng.normal(size=shape) * std, jnp.float32)
 
     def norm(width=d):
+        if p.norm == "rms_zero_centered":
+            return {"scale": jnp.zeros((width,), jnp.float32)}
         w = {"scale": jnp.ones((width,), jnp.float32)}
         if p.norm == "layer":
             w["bias"] = jnp.zeros((width,), jnp.float32)
         return w
+
+    def uniform(shape, hi):
+        nonlocal drawn
+        drawn += 1
+        if p.device_init:
+            return jax.random.uniform(jax.random.fold_in(key, drawn), shape,
+                                      jnp.float32, 0.0, hi)
+        return jnp.asarray(rng.uniform(0.0, hi, size=shape), jnp.float32)
 
     def dense(n_in, n_out):
         return normal((n_in, n_out), n_in ** -0.5)
@@ -207,10 +300,29 @@ def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
                 "w_up": normal((*experts, d, width), d ** -0.5),
                 "w_down": normal((*experts, width, d), width ** -0.5)}
 
-    def mixer():
-        if p.mixer == "mha":
+    def mixer(i):
+        kind, h = p.mixer_kind(i), p.n_heads
+        if kind == "mha":
             return {"wqkv": dense(d, 3 * d), "wo": dense(d, d)}
-        h = p.n_heads
+        if kind == "gqa":
+            return {"wq_gate": dense(d, 2 * h * p.head_dim),
+                    "wk": dense(d, p.n_kv_heads * p.head_dim),
+                    "wv": dense(d, p.n_kv_heads * p.head_dim),
+                    "q_norm": norm(p.head_dim), "k_norm": norm(p.head_dim),
+                    "wo": dense(h * p.head_dim, d)}
+        if kind == "gdn":
+            keys = p.linear_key_heads * p.linear_key_head_dim
+            values = p.linear_value_heads * p.linear_value_head_dim
+            return {"w_qkvz": dense(d, 2 * keys + 2 * values),
+                    "w_ba": dense(d, 2 * p.linear_value_heads),
+                    "conv": dense(p.linear_conv_kernel, 2 * keys + values),
+                    # the decay's rate a head: log of U(0, 16)
+                    "A_log": jnp.log(uniform((p.linear_value_heads,), 16.0)),
+                    "dt_bias": jnp.ones((p.linear_value_heads,),
+                                        jnp.float32),
+                    "o_norm": {"scale": jnp.ones(
+                        (p.linear_value_head_dim,), jnp.float32)},
+                    "w_out": dense(values, d)}
         return {"wq": dense(d, h * (p.qk_nope_head_dim + p.qk_rope_head_dim)),
                 "wkva": dense(d, p.kv_lora_rank + p.qk_rope_head_dim),
                 "kv_norm": norm(p.kv_lora_rank),
@@ -231,10 +343,12 @@ def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
                "experts": swiglu(p.moe_width, (hi - lo,))}
         if p.n_shared_experts:
             out["shared"] = swiglu(p.n_shared_experts * p.moe_width)
+        if p.shared_expert_gate:
+            out["shared_gate"] = dense(d, 1)
         return out
 
     # draws in this order: the host path's are the original block's
-    layers = [{"ln1": norm(), "ln2": norm(), **mixer(), **ffn(i)}
+    layers = [{"ln1": norm(), "ln2": norm(), **mixer(i), **ffn(i)}
               for i in range(p.n_layers)]
     params = {"emb": normal((v, d), d ** -0.5)}
     if p.positions == "learned":
@@ -253,6 +367,8 @@ def _rms_norm(x, scale, eps):
 def _norm(x, w, p: SeqRecParams):
     if p.norm == "rms":
         return _rms_norm(x, w["scale"], p.norm_eps)
+    if p.norm == "rms_zero_centered":
+        return _rms_norm(x, 1.0 + w["scale"], p.norm_eps)
     mu = x.mean(-1, keepdims=True)
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
     return (x - mu) * jax.lax.rsqrt(var + p.norm_eps) * w["scale"] + w["bias"]
@@ -275,16 +391,71 @@ def _swiglu(w, x):
     return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
 
 
-def _attention(layer, x, key_mask, p: SeqRecParams, mesh, use_ring):
-    """The mixer on normed x [B, L, D] -> [B, L, D]."""
+def _linear_attention(layer, x, key_mask, p: SeqRecParams):
+    """The "gdn" mixer on normed x [B, L, D] -> [B, L, D]. A padding
+    position's input is 0: it writes nothing into the state, and a
+    left-padded session is the unpadded one. Heads are independent from
+    the projection to the output matrix; under `remat` they are taken
+    `LINEAR_KEY_HEADS` key heads (and the value heads they serve) at a
+    time, each group's internals recomputed in the backward pass."""
+    b, l, _ = x.shape
+    hk, hv = p.linear_key_heads, p.linear_value_heads
+    dk, dv = p.linear_key_head_dim, p.linear_value_head_dim
+    x = jnp.where(key_mask[..., None], x, 0.0)
+    cuts = [hk * dk, 2 * hk * dk, 2 * hk * dk + hv * dv]
+    q, k, v, z = jnp.split(x @ layer["w_qkvz"], cuts, axis=-1)
+    conv_q, conv_k, conv_v = jnp.split(layer["conv"], cuts[:2], axis=-1)
+    # the decay compounds over a session: its projection at the highest
+    # precision, as the router's
+    ba = jnp.dot(x, layer["w_ba"], precision=jax.lax.Precision.HIGHEST)
+    beta = jax.nn.sigmoid(ba[..., :hv])
+    g = -jnp.exp(layer["A_log"]) * jax.nn.softplus(
+        ba[..., hv:] + layer["dt_bias"])
+    n = hk // LINEAR_KEY_HEADS \
+        if p.remat and hk % LINEAR_KEY_HEADS == 0 else 1
+
+    def groups(t):          # [..., heads x width] -> [n, ..., heads / n x width]
+        return jnp.moveaxis(t.reshape(*t.shape[:-1], n, -1), -2, 0)
+
+    def unit(t):            # [B, L, heads x dk] -> unit length, a value head
+        t = t.reshape(b, l, -1, dk)
+        t = t * jax.lax.rsqrt((t * t).sum(-1, keepdims=True) + 1e-6)
+        return jnp.repeat(t, hv // hk, axis=2)
+
+    def group(args):
+        q, k, v, z, conv_q, conv_k, conv_v, g, beta = args
+        q, k, v = (linear_attention.causal_conv(t, w) for t, w in
+                   ((q, conv_q), (k, conv_k), (v, conv_v)))
+        o = linear_attention.gated_delta_rule(
+            unit(q) * dk ** -0.5, unit(k), v.reshape(b, l, -1, dv), g, beta)
+        o = _rms_norm(o, layer["o_norm"]["scale"], p.norm_eps) \
+            * jax.nn.silu(z.reshape(b, l, -1, dv))
+        return o.reshape(b, l, -1)
+
+    args = tuple(map(groups, (q, k, v, z, conv_q, conv_k, conv_v, g, beta)))
+    o = jax.lax.map(jax.checkpoint(group), args) if n > 1 \
+        else group(tuple(t[0] for t in args))[None]
+    return jnp.moveaxis(o, 0, 2).reshape(b, l, -1) @ layer["w_out"]
+
+
+def _attention(layer, x, key_mask, p: SeqRecParams, kind, mesh, use_ring):
+    """A softmax-attention mixer on normed x [B, L, D] -> [B, L, D]."""
     b, l, d = x.shape
     h = p.n_heads
     positions = jnp.arange(l)
-    if p.mixer == "mha":
+    gate = None
+    if kind == "mha":
         q, k, v = (t.reshape(b, l, h, d // h) for t in
                    jnp.split(x @ layer["wqkv"], 3, axis=-1))    # MXU
         if p.positions == "rope":
             q, k = (rope(t, positions, p.rope_theta) for t in (q, k))
+    elif kind == "gqa":
+        q, gate = jnp.split(x @ layer["wq_gate"], 2, axis=-1)
+        q, k = (rope(_norm(t.reshape(b, l, -1, p.head_dim), w, p), positions,
+                     p.rope_theta, p.rotary_dim)
+                for t, w in ((q, layer["q_norm"]),
+                             (x @ layer["wk"], layer["k_norm"])))
+        v = (x @ layer["wv"]).reshape(b, l, -1, p.head_dim)
     else:
         nope, rot = p.qk_nope_head_dim, p.qk_rope_head_dim
         q = (x @ layer["wq"]).reshape(b, l, h, nope + rot)
@@ -305,7 +476,10 @@ def _attention(layer, x, key_mask, p: SeqRecParams, mesh, use_ring):
         att = blockwise_attention(q, k, v, block_k=ATTENTION_BLOCK,
                                   causal=True, key_mask=key_mask,
                                   devices=1 if mesh is None else mesh.size)
-    return att.reshape(b, l, -1) @ layer["wo"]
+    att = att.reshape(b, l, -1)
+    if gate is not None:
+        att = att * jax.nn.sigmoid(gate)
+    return att @ layer["wo"]
 
 
 def _moe(layer, x, p: SeqRecParams):
@@ -317,16 +491,21 @@ def _moe(layer, x, p: SeqRecParams):
     with jax.named_scope("seqrec_router"):
         routing = moe.route(flat, layer["router"], layer["router_bias"],
                             p.experts_per_token, p.routed_scaling_factor,
-                            p.norm_topk_prob)
+                            p.norm_topk_prob, p.router_scoring)
     with jax.named_scope("seqrec_experts"):
         ex = layer["experts"]
         y, held_tokens, dropped = moe.held_experts(
             flat, ex["w_gate"], ex["w_up"], ex["w_down"], routing,
             p.held_experts[0], pass_rows=b * l)
     if "shared" in layer:
+        def shared(t):
+            out = _swiglu(layer["shared"], t)
+            if "shared_gate" in layer:
+                out = out * jax.nn.sigmoid(t @ layer["shared_gate"])
+            return out
+
         with jax.named_scope("seqrec_shared_expert"):
-            y = y + _by_token_blocks(
-                lambda t: _swiglu(layer["shared"], t), p, flat)
+            y = y + _by_token_blocks(shared, p, flat)
     stats = {"load": moe.expert_load(routing.experts, p.n_routed_experts),
              "held_tokens": held_tokens, "dropped": dropped,
              "balance": moe.sequence_balance_loss(routing, b)}
@@ -334,9 +513,10 @@ def _moe(layer, x, p: SeqRecParams):
 
 
 def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
-             mesh: Optional[Mesh] = None) -> Tuple[jax.Array, List[Dict]]:
+             mesh: Optional[Mesh] = None
+             ) -> Tuple[jax.Array, List[Dict], Dict[str, int]]:
     """[B, L] int32 item ids (0 = pad) -> ([B, L, D] hidden states, the
-    balance numbers of each expert layer)."""
+    balance numbers of each expert layer, the layers run by mixer)."""
     b, l = seqs.shape
     h = params["emb"][seqs]
     if "pos" in params:
@@ -349,10 +529,15 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
         raise ValueError('attention_impl="ring" requires a mesh with a '
                          '"seq" axis')
 
-    def block(h, layer, kind):     # key mask keeps it out of the softmax
-        with jax.named_scope("seqrec_attention"):
-            h = h + _attention(layer, _norm(h, layer["ln1"], p), key_mask,
-                               p, mesh, use_ring)
+    def block(h, layer, mixer, kind):
+        x = _norm(h, layer["ln1"], p)
+        if mixer == "gdn":
+            with jax.named_scope("seqrec_linear_attention"):
+                h = h + _linear_attention(layer, x, key_mask, p)
+        else:                      # key mask keeps it out of the softmax
+            with jax.named_scope("seqrec_attention"):
+                h = h + _attention(layer, x, key_mask, p, mixer, mesh,
+                                   use_ring)
         x = _norm(h, layer["ln2"], p)
         if kind == "moe":
             y, stats = _moe(layer, x, p)
@@ -363,13 +548,16 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
         return h + y.reshape(b, l, -1), None
 
     if p.remat:
-        block = jax.checkpoint(block, static_argnums=2)
-    expert_layers = []
+        block = jax.checkpoint(block, static_argnums=(2, 3))
+    expert_layers, mixers = [], {}
     for i, layer in enumerate(params["layers"]):
-        h, stats = block(h, layer, p.ffn_kind(i))
+        mixer = p.mixer_kind(i)
+        h, stats = block(h, layer, mixer, p.ffn_kind(i))
+        mixers[mixer] = mixers.get(mixer, 0) + 1
         if stats is not None:
             expert_layers.append(stats)
-    return jnp.where(pad, 0.0, _norm(h, params["ln_f"], p)), expert_layers
+    return (jnp.where(pad, 0.0, _norm(h, params["ln_f"], p)), expert_layers,
+            mixers)
 
 
 def forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
@@ -390,8 +578,9 @@ def head_matrix(params: Dict) -> jax.Array:
 
 def _loss_fn(params, seqs, targets, p: SeqRecParams, mesh=None):
     """Next-item softmax cross-entropy, pad-masked, plus the expert
-    layers' balance loss. -> (loss, the expert layers' balance numbers)."""
-    hidden, expert_layers = _forward(params, seqs, p, mesh)       # [B,L,D]
+    layers' balance loss. -> (loss, (the expert layers' balance numbers,
+    the layers run by mixer))."""
+    hidden, expert_layers, mixers = _forward(params, seqs, p, mesh)
     head = head_matrix(params)
 
     def nll_of(hid, tgt):
@@ -408,7 +597,8 @@ def _loss_fn(params, seqs, targets, p: SeqRecParams, mesh=None):
     if p.balance_loss_alpha and expert_layers:
         loss = loss + p.balance_loss_alpha * sum(
             s["balance"] for s in expert_layers)
-    return loss, expert_layers
+    return loss, (expert_layers, {kind: jnp.asarray(n, jnp.int32)
+                                  for kind, n in mixers.items()})
 
 
 def grad_group(path) -> str:
@@ -420,9 +610,13 @@ def grad_group(path) -> str:
                 "ln_f": "final_norm"}.get(names[0], names[0])
     part = {"router": "router", "router_bias": "router",
             "experts": "experts", "shared": "shared_expert",
-            "ln1": "norms", "ln2": "norms"}.get(
-        names[2], "attention" if names[2] in (
-            "wqkv", "wq", "wkva", "kv_norm", "wkvb", "wo") else "ffn")
+            "shared_gate": "shared_expert", "ln1": "norms", "ln2": "norms",
+            **dict.fromkeys(("wqkv", "wq", "wkva", "kv_norm", "wkvb", "wo",
+                             "wq_gate", "wk", "wv", "q_norm", "k_norm"),
+                            "attention"),
+            **dict.fromkeys(("w_qkvz", "w_ba", "conv", "A_log", "dt_bias",
+                             "o_norm", "w_out"), "linear_attention")}.get(
+        names[2], "ffn")
     return f"layer{names[1]}.{part}"
 
 
@@ -453,9 +647,11 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
     """One donated jitted step -> (params, opt_state, the step's numbers:
     loss, by group the gradient's norm and the norm of what the step
     added to the parameters, and per expert layer the tokens routed to
-    each expert, to each held expert, and dropped; `attention_pallas`,
-    a constant of the trace: whether `blockwise_attention` folded every
-    layer's blocks with the Pallas kernels). With a mesh, batch is
+    each expert, to each held expert, and dropped; two constants of the
+    trace: `mixer_layers`, the layers it ran by mixer, and
+    `attention_pallas`, whether `blockwise_attention` folded every
+    softmax-attention layer's blocks with the Pallas kernels). With a
+    mesh, batch is
     sharded over "data" and embedding/ffn rows over "model"; XLA inserts
     the psums."""
 
@@ -470,10 +666,11 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
             targets = jax.lax.with_sharding_constraint(targets, sh)
         routes: set = set()
         with routes_into(routes):
-            (loss, expert_layers), grads = jax.value_and_grad(
+            (loss, (expert_layers, mixers)), grads = jax.value_and_grad(
                 _loss_fn, has_aux=True)(params, seqs, targets, p, mesh)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         stats = {"loss": loss, "grad_norm": _group_norms(grads),
+                 "mixer_layers": mixers,
                  "attention_pallas": jnp.asarray(routes == {"pallas"})}
         if expert_layers:
             # a selection bias is moved by its layer's load, not by adamw
@@ -503,9 +700,9 @@ def shard_params(params: Dict, mesh: Mesh) -> Dict:
         name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
         if name == "emb":
             return P("model", None)
-        if name in ("wqkv", "w1", "head"):
+        if name in ("wqkv", "w1", "head", "wq_gate", "w_qkvz"):
             return P(None, "model")
-        if name == "w2":
+        if name in ("w2", "w_out"):
             return P("model", None)
         return P()
 
@@ -758,10 +955,13 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
         record = _training_record(steps, rows)
     train_stats.seqrec_fetch_bytes().inc(
         sum(leaf.nbytes for leaf in jax.tree.leaves(host)))
-    # one compiled step made every step of the train: one route
+    # one compiled step made every step of the train: one route, one
+    # pattern of mixers
     train_stats.observe_seqrec_record(
         record, targets, rows,
-        "pallas" if steps and steps[0]["attention_pallas"] else "xla")
+        "pallas" if steps and steps[0]["attention_pallas"] else "xla",
+        {kind: int(n) for kind, n in steps[0]["mixer_layers"].items()}
+        if steps else {})
     return SeqRecModel(item_vocab=all_items, params=host, hyper=p,
                        record=record)
 

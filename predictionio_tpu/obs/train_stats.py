@@ -46,8 +46,12 @@ the weights), `seqrec_put` (sharding, the optimizer's state),
   padding.
 * ``pio_train_seqrec_attention_tokens_total{impl}`` — positions of the
   trained batches, padding too, by the route ``blockwise_attention``
-  took when their step was traced: ``pallas`` (every layer through the
-  kernels of ops/attention_pallas.py) or ``xla``.
+  took when their step was traced: ``pallas`` (every softmax-attention
+  layer through the kernels of ops/attention_pallas.py) or ``xla``. A
+  model without such a layer counts nothing here.
+* ``pio_train_seqrec_mixer_tokens_total{mixer}`` — positions of the
+  trained batches, padding too, times the layers of each mixer
+  (``mha``, ``mla``, ``gqa``, ``gdn``) the compiled step ran.
 * ``pio_train_seqrec_expert_tokens_total{layer}`` — tokens the experts
   held here received, by expert layer.
 * ``pio_train_seqrec_expert_load_max_over_mean`` — the busiest routed
@@ -171,6 +175,13 @@ def seqrec_attention_tokens(registry: MetricsRegistry = None):
         labelnames=("impl",))
 
 
+def seqrec_mixer_tokens(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_mixer_tokens_total",
+        "Positions of the trained batches times the layers of each "
+        "mixer the compiled step ran", labelnames=("mixer",))
+
+
 def seqrec_expert_tokens(registry: MetricsRegistry = None):
     return (registry or default_registry()).counter(
         "pio_train_seqrec_expert_tokens_total",
@@ -199,18 +210,22 @@ def seqrec_fetch_bytes(registry: MetricsRegistry = None):
 
 
 def observe_seqrec_record(record: dict, targets, rows,
-                          attention_impl: str) -> None:
+                          attention_impl: str, mixer_layers: dict) -> None:
     """The token and expert counters from one train's record
     (models/seqrec.train_seqrec): `targets` the padded target ids of all
     sessions, `rows` the sessions of each step's batch, `attention_impl`
-    the route its step was traced on."""
+    the route its step was traced on, `mixer_layers` the layers that
+    step ran by mixer."""
     import numpy as np
 
     real = sum(int((targets[r] > 0).sum()) for r in rows)
     positions = sum(targets[r].size for r in rows)
     seqrec_tokens().inc(real)
     seqrec_pad_tokens().inc(positions - real)
-    seqrec_attention_tokens().inc(positions, impl=attention_impl)
+    for mixer, layers in mixer_layers.items():
+        seqrec_mixer_tokens().inc(positions * layers, mixer=mixer)
+    if set(mixer_layers) - {"gdn"}:
+        seqrec_attention_tokens().inc(positions, impl=attention_impl)
     if "load" not in record or not record["load"]:
         return
     load = np.asarray(record["load"], np.float64)      # [step, layer, expert]

@@ -98,10 +98,17 @@ def _flash_finish(o, l, dtype):
     return jnp.einsum("bhqd->bqhd", out).astype(dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         rotary_dim: Optional[int] = None) -> jax.Array:
     """Rotary positions on the last axis of x [B, L, H, D] (D even), in
     the "halves" pairing: dimension i rotates with dimension i + D/2 by
-    the angle position * theta^(-2i/D). positions: [L] or [B, L]."""
+    the angle position * theta^(-2i/D). positions: [L] or [B, L]. With
+    `rotary_dim` < D only the leading `rotary_dim` dimensions rotate
+    (halves pairing within them) and the others pass through."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        return jnp.concatenate(
+            [rope(x[..., :rotary_dim], positions, theta),
+             x[..., rotary_dim:]], axis=-1)
     half = x.shape[-1] // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None] * freq    # [(B,) L, half]
@@ -285,9 +292,13 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     and of keys with the running-max/denominator recurrence so the
     [Lq, Lk] score matrix never materializes, forward or backward
     (custom_vjp: the backward pass recomputes a block's probabilities).
-    O(L * block) memory; exact (not approximate). q, k: [B, L, H, Dk];
-    v: [B, Lk, H, Dv], Dv free (latent attention has 192 and 128);
-    -> [B, Lq, H, Dv]. block_q defaults to block_k.
+    O(L * block) memory; exact (not approximate). q: [B, Lq, H, Dk]; k:
+    [B, Lk, Hkv, Dk]; v: [B, Lk, Hkv, Dv], Dv free (latent attention has
+    192 and 128); -> [B, Lq, H, Dv]. Hkv divides H: key/value head j
+    serves the query heads [j H / Hkv, (j + 1) H / Hkv) (grouped query
+    heads), and takes the sum of their gradients; the kernels read each
+    key/value head where it lies, the scan repeats it. block_q defaults
+    to block_k.
     key_mask: optional [B, Lk] bool, False = key is padding (ignored).
     Lengths that are not a block multiple are handled by padding up to
     one: pad keys are masked out, pad queries cut off the result.
@@ -297,6 +308,10 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     blocks of their own) or by a scan of XLA operations."""
     b, lq, h, dk = q.shape
     lk, dv = k.shape[1], v.shape[-1]
+    if h % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(f"{k.shape[2]} key and {v.shape[2]} value heads "
+                         f"under {h} query heads")
+    group = h // k.shape[2]
     block_q, block_k, pad_q, pad_k = _blocks_and_pads(lq, lk, block_k,
                                                       block_q)
     if key_mask is None:
@@ -317,6 +332,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         out = attention_pallas.flash_attention_pallas(
             heads_first(q), heads_first(k), heads_first(v), key_mask, causal)
     else:
+        if group > 1:
+            k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
         out = _blockwise(heads_first(q), heads_first(k), heads_first(v),
                          key_mask, block_q, block_k, causal)
     return heads_first(out)[:, :lq]

@@ -231,9 +231,11 @@ def _call(kernel, name, key_mask, pairs, heads, bk, in_specs, out_specs,
         full.reshape(-1).astype(jnp.int32))
 
 
-def _specs(bq, bk):
-    """Block specs by what a block follows: the pair's query block or its
-    key block."""
+def _specs(bq, bk, group=1):
+    """Block specs by what a block follows: the pair's query block, its
+    key block (per query head: the gradients), or the key block of the
+    key/value head that serves the query head (`group` query heads
+    each)."""
     def by_query(width):
         return pl.BlockSpec((1, 1, bq, width),
                             lambda b, h, p, qi, kj, full: (b, h, qi[p], 0))
@@ -242,14 +244,19 @@ def _specs(bq, bk):
         return pl.BlockSpec((1, 1, bk, width),
                             lambda b, h, p, qi, kj, full: (b, h, kj[p], 0))
 
-    return by_query, by_key
+    def by_kv_head(width):
+        return pl.BlockSpec(
+            (1, 1, bk, width),
+            lambda b, h, p, qi, kj, full: (b, h // group, kj[p], 0))
+
+    return by_query, by_key, by_kv_head
 
 
 def _forward(q, k, v, key_mask, causal, bq, bk, interpret, save_lse):
     b, h, lq, dk = q.shape
     lk, dv = k.shape[2], v.shape[3]
     pairs = _block_pairs(lq // bq, lk // bk, bq, bk, causal, key_major=False)
-    by_query, by_key = _specs(bq, bk)
+    by_query, _, by_kv_head = _specs(bq, bk, h // k.shape[1])
     out_shape = [jax.ShapeDtypeStruct((b, h, lq, dv), jnp.float32)]
     out_specs = [by_query(dv)]
     if save_lse:
@@ -259,7 +266,7 @@ def _forward(q, k, v, key_mask, causal, bq, bk, interpret, save_lse):
         functools.partial(_fwd_kernel, scale=dk ** -0.5, causal=causal,
                           bq=bq, bk=bk, n_k=lk // bk, save_lse=save_lse),
         "flash_attention_pallas_fwd", key_mask, pairs, h, bk,
-        [by_query(dk), by_key(dk), by_key(dv),
+        [by_query(dk), by_kv_head(dk), by_kv_head(dv),
          pl.BlockSpec((1, 1, bk),
                       lambda b, h, p, qi, kj, full: (b, 0, kj[p]))],
         out_specs, out_shape,
@@ -273,21 +280,23 @@ def _backward(q, k, v, key_mask, d_out, lse, delta, causal, bq, bk,
     b, h, lq, dk = q.shape
     lk, dv = k.shape[2], v.shape[3]
     pairs = _block_pairs(lq // bq, lk // bk, bq, bk, causal, key_major=True)
-    by_query, by_key = _specs(bq, bk)
+    group = h // k.shape[1]
+    by_query, by_key, by_kv_head = _specs(bq, bk, group)
     row = pl.BlockSpec((1, 1, 1, bq),
                        lambda b, h, p, qi, kj, full: (b, h, 0, qi[p]))
     dq, d_k, d_v = _call(
         functools.partial(_bwd_kernel, scale=dk ** -0.5, causal=causal,
                           bq=bq, bk=bk, n_q=lq // bq, n_k=lk // bk),
         "flash_attention_pallas_bwd", key_mask, pairs, h, bk,
-        [by_query(dk), by_key(dk), by_key(dv),
+        [by_query(dk), by_kv_head(dk), by_kv_head(dv),
          pl.BlockSpec((1, bk, 1),
                       lambda b, h, p, qi, kj, full: (b, kj[p], 0)),
          by_query(dv), row, row],
         [pl.BlockSpec((1, 1, lq, dk),
                       lambda b, h, p, qi, kj, full: (b, h, 0, 0)),
          by_key(dk), by_key(dv)],
-        [jax.ShapeDtypeStruct(t.shape, jnp.float32) for t in (q, k, v)],
+        [jax.ShapeDtypeStruct((b, h, length, width), jnp.float32)
+         for length, width in ((lq, dk), (lk, dk), (lk, dv))],
         [pltpu.VMEM((bk, dk), jnp.float32),
          pltpu.VMEM((bk, dv), jnp.float32)], interpret)(
         q, k, v, key_mask.astype(jnp.int32)[:, :, None], d_out,
@@ -295,16 +304,23 @@ def _backward(q, k, v, key_mask, d_out, lse, delta, causal, bq, bk,
     seen = -(-lq // bk) * bk      # causal keys past every query: no pair
     if causal and seen < lk:
         d_k, d_v = (t.at[:, :, seen:].set(0.0) for t in (d_k, d_v))
+    if group > 1:       # a key/value head's gradient: its query heads' sum
+        d_k, d_v = (t.reshape(b, h // group, group, lk, -1).sum(axis=2)
+                    for t in (d_k, d_v))
     return dq, d_k, d_v
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def flash_attention_pallas(q, k, v, key_mask, causal: bool,
                            interpret: bool = False):
-    """q [B, H, Lq, Dk], k [B, H, Lk, Dk], v [B, H, Lk, Dv] of one dtype,
-    lengths multiples of 128; key_mask [B, Lk] bool, False = padding ->
-    [B, H, Lq, Dv] in that dtype. `interpret` runs the kernels in the
-    Pallas interpreter (the CPU tests)."""
+    """q [B, H, Lq, Dk], k [B, Hkv, Lk, Dk], v [B, Hkv, Lk, Dv] of one
+    dtype, Hkv a divisor of H (key/value head j serves the query heads
+    [j H / Hkv, (j + 1) H / Hkv): the kernels' index maps read it where
+    it lies, and the backward kernel's per-query-head `dk`, `dv` are
+    summed over the group after it), lengths multiples of 128; key_mask
+    [B, Lk] bool, False = padding -> [B, H, Lq, Dv] in that dtype.
+    `interpret` runs the kernels in the Pallas interpreter (the CPU
+    tests)."""
     return _fwd(q, k, v, key_mask, causal, interpret, save_lse=False)[0]
 
 

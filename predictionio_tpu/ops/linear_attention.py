@@ -1,0 +1,120 @@
+"""Linear attention by the gated delta rule (Gated DeltaNet,
+arXiv:2412.06464), and the short causal convolution in front of it.
+
+Each head keeps a state S [dk, dv] instead of keys and values. Position
+t first lets the state decay, then replaces what the state holds under
+its key by a share beta_t of its value, then reads with its query:
+
+    S' = exp(g_t) S_{t-1}
+    S_t = S' + k_t (beta_t (v_t - S'^T k_t))^T
+    o_t = S_t^T q_t
+
+`gated_delta_rule` computes this `CHUNK` positions at a time (the WY
+form of the paper's section 3.3): inside a chunk the positions' updates
+are coupled by a unit lower triangular system over the chunk's keys,
+solved for all chunks at once; between chunks one state update, a
+`lax.scan` over the chunks that carries S. All of it XLA operations; no
+kernel.
+
+Backward pass: the scan's own, with the scan's body under
+`jax.checkpoint`, so that a chunk keeps the state it started from
+(B x H x dk x dv a chunk: 512 MiB a layer at 32 heads of 128 x 128 and
+256 chunks) and recomputes its four products; the caller bounds what
+else is kept (`models/seqrec` takes a layer's heads a group at a time
+under `remat`). PERF.md section 6, PR 31, has the readings behind the
+choice.
+
+Precision: the system's matrix (beta k_i . k_j, decayed), its inverse
+and the two products with the inverse are computed in float32 at the
+highest matmul precision: each entry of the inverse is a sum over paths
+through the chunk, and a bfloat16 rounding of its operands compounds
+along a path. Every other product takes the backend's default (on the
+TPU one bfloat16 pass, float32 accumulation); decays and their
+exponentials are float32 elementwise.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+#: positions a chunk. A constant, from the paper's kernels (64) and chip
+#: runs at 1 x 32 heads x 16,384 x 128 (PERF.md section 6, PR 31): the
+#: rule alone took 24.8 ms forward and 81.6 forward + backward at 64,
+#: 27.6 and 90.4 at 128 (half the scan's steps, four times the
+#: triangular system); the cell's step 1.053 s against 1.078.
+CHUNK = 64
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
+    """Depthwise causal convolution with SiLU: x [B, L, C], w [K, C] ->
+    silu(sum_j w[j] x[t - (K - 1) + j]) [B, L, C], zeros before the
+    sequence, no bias."""
+    taps, l = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return jax.nn.silu(sum(w[j] * padded[:, j:j + l] for j in range(taps)))
+
+
+def _unit_lower_inverse(a: jax.Array) -> jax.Array:
+    """(I + a)^-1 for strictly lower triangular a [..., C, C], C a power
+    of two: a is nilpotent, so the inverse is sum_m (-a)^m =
+    (I - a)(I + a^2)(I + a^4)..., log2(C) - 1 squarings."""
+    c = a.shape[-1]
+    x = -a
+    t = jnp.eye(c, dtype=a.dtype) + x
+    for _ in range(c.bit_length() - 2):
+        x = jnp.matmul(x, x, precision=_HIGHEST)
+        t = t + jnp.matmul(t, x, precision=_HIGHEST)
+    return t
+
+
+def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                     beta: jax.Array) -> jax.Array:
+    """q, k [B, L, H, dk] (normalised and scaled by the caller), v
+    [B, L, H, dv], g [B, L, H] (the decay's logarithm, <= 0), beta
+    [B, L, H] -> o [B, L, H, dv], from a state of 0. Any length: the
+    last chunk is filled with positions that write nothing (k = 0)."""
+    b, l, h, _ = q.shape
+    pad = -l % CHUNK
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, g, beta))
+    n = (l + pad) // CHUNK
+
+    def chunks(t):          # [B, L, H, ...] -> [N, B, H, C, ...]
+        t = t.astype(jnp.float32).reshape(b, n, CHUNK, h, *t.shape[3:])
+        return jnp.moveaxis(t, (1, 3), (0, 2))
+
+    q, k, v, g, beta = map(chunks, (q, k, v, g, beta))
+    gc = jnp.cumsum(g, axis=-1)                  # decay since chunk start
+    at = jnp.arange(CHUNK)
+    lower = at[:, None] >= at[None, :]
+    # exp(gc_i - gc_j) for j <= i, 0 above the diagonal (whose
+    # exponents are positive and may overflow)
+    decay = jnp.where(lower, jnp.exp(jnp.where(
+        lower, gc[..., :, None] - gc[..., None, :], 0.0)), 0.0)
+    k_beta = k * beta[..., None]
+    a = jnp.einsum("...id,...jd->...ij", k_beta, k, precision=_HIGHEST) \
+        * jnp.where(at[:, None] > at[None, :], decay, 0.0)
+    t = _unit_lower_inverse(a)
+    u = jnp.matmul(t, v * beta[..., None], precision=_HIGHEST)
+    w = jnp.matmul(t, k_beta * jnp.exp(gc)[..., None], precision=_HIGHEST)
+    qk = jnp.einsum("...id,...jd->...ij", q, k) * decay
+    last = gc[..., -1:]
+    xs = (u, w, qk, q * jnp.exp(gc)[..., None],
+          k * jnp.exp(last - gc)[..., None], jnp.exp(last)[..., None])
+
+    def chunk(s, x):
+        u_i, w_i, qk_i, q_i, k_i, decay_i = x
+        v_new = u_i - w_i @ s                    # [B, H, C, dv]
+        o_i = q_i @ s + qk_i @ v_new
+        return s * decay_i + jnp.swapaxes(k_i, -1, -2) @ v_new, o_i
+
+    _, o = jax.lax.scan(
+        jax.checkpoint(chunk),
+        jnp.zeros((b, h, k.shape[-1], v.shape[-1]), jnp.float32), xs)
+    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, l + pad, h, -1)
+    return o[:, :l].astype(v.dtype)

@@ -37,22 +37,27 @@ class Routing(NamedTuple):
     experts: jax.Array
     #: [T, k] the weight of each chosen expert in the token's output
     gates: jax.Array
-    #: [T, n_routed] sigmoid affinities, for the balance terms
+    #: [T, n_routed] the affinities (sigmoids, or the softmax's
+    #: probabilities), for the balance terms
     scores: jax.Array
 
 
 def route(x: jax.Array, w_router: jax.Array, bias: jax.Array, top_k: int,
-          scaling: float = 1.0, norm_topk: bool = True) -> Routing:
-    """Sigmoid router with bias-steered selection (auxiliary-loss-free
-    balancing): the top-k of score + bias are chosen, the gates are the
-    chosen scores themselves (normalised to sum 1 when `norm_topk`, then
-    scaled), so the bias moves which experts are picked and never the
-    output's weights, and takes no gradient. The affinities are computed
-    at the highest matmul precision: a rounding that flips the k-th and
-    (k+1)-th expert of a token changes which weights it trains."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), w_router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+          scaling: float = 1.0, norm_topk: bool = True,
+          scoring: str = "sigmoid") -> Routing:
+    """Router with bias-steered selection (auxiliary-loss-free
+    balancing): the affinities are each output's sigmoid (`scoring`
+    "sigmoid") or the softmax over all outputs ("softmax"); the top-k of
+    score + bias are chosen, the gates are the chosen scores themselves
+    (normalised to sum 1 when `norm_topk`, then scaled), so the bias
+    moves which experts are picked and never the output's weights, and
+    takes no gradient. The affinities are computed at the highest matmul
+    precision: a rounding that flips the k-th and (k+1)-th expert of a
+    token changes which weights it trains."""
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" \
+        else jax.nn.sigmoid(logits)
     _, experts = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
     gates = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk:

@@ -32,15 +32,20 @@ def one_chip(chips):
     return SingleDeviceSharding(chips[0])
 
 
-@pytest.mark.parametrize("name,b,h,length,dk,dv,causal", [
+@pytest.mark.parametrize("name,b,h,kv_heads,length,dk,dv,causal", [
     # kimivl-a3b-ep8.train: 2 sessions x 16 heads x 8,192, q/k 192, v 128
-    ("cell", 2, 16, 8192, 192, 128, True),
+    ("cell", 2, 16, 16, 8192, 192, 128, True),
     # the longest `tiles` lets through at that width: dq fills its VMEM
-    ("longest", 1, 2, 24576, 192, 128, True),
-    ("half-a-lane-tile", 1, 2, 1536, 64, 128, False),
+    ("longest", 1, 2, 2, 24576, 192, 128, True),
+    ("half-a-lane-tile", 1, 2, 2, 1536, 64, 128, False),
+    # qwen3next-a3b-ep16.train: 1 session x 16 query heads over 2
+    # key/value heads x 16,384, q/k = v = 256: blocks of 1024 and `dq` of
+    # a head (32 MiB in its two buffers) within the kernels' VMEM
+    ("grouped-cell", 1, 16, 2, 16384, 256, 256, True),
+    ("grouped-longest", 1, 4, 1, 24576, 256, 256, True),
 ])
-def test_attention_kernels_compile_for_v5e(one_chip, name, b, h, length, dk,
-                                           dv, causal):
+def test_attention_kernels_compile_for_v5e(one_chip, name, b, h, kv_heads,
+                                           length, dk, dv, causal):
     from predictionio_tpu.ops import attention_pallas
 
     assert attention_pallas.tiles(length, length, dk, dv)
@@ -53,8 +58,8 @@ def test_attention_kernels_compile_for_v5e(one_chip, name, b, h, length, dk,
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
-        shape(b, h, length, dk), shape(b, h, length, dk),
-        shape(b, h, length, dv), shape(b, length, dtype=jnp.bool_),
+        shape(b, h, length, dk), shape(b, kv_heads, length, dk),
+        shape(b, kv_heads, length, dv), shape(b, length, dtype=jnp.bool_),
         shape(b, h, length, dv)).compile()
     text = compiled.as_text()
     for kernel in ("flash_attention_pallas_fwd", "flash_attention_pallas_bwd"):

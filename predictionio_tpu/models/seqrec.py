@@ -32,7 +32,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from predictionio_tpu.core.params import Params
 from predictionio_tpu.obs import jax_stats, train_stats
 from predictionio_tpu.obs.tracing import span
-from predictionio_tpu.ops import linear_attention, moe
+from predictionio_tpu.ops import (
+    linear_attention, linear_attention_pallas, moe,
+)
 from predictionio_tpu.ops.attention import (
     blockwise_attention, ring_attention_traced, rope, routes_into,
 )
@@ -246,12 +248,14 @@ MEMORY_FIELDS = ("attention_impl", "remat")
 ATTENTION_BLOCK = 512
 TOKEN_BLOCK = 2048
 #: key heads of a linear-attention layer taken at a time under `remat`
-#: (with the value heads they serve). A constant, from chip runs of the
-#: step at 16,384 positions, 16 key and 32 value heads of 128 (PERF.md
-#: section 6, PR 31): a step took 1.109 s at 2, 1.053 at 4, 0.964 at 8;
-#: the compiler counted 5.11, 5.97 and 8.25 GiB of temporaries (10.97
-#: with all 16 at once) beside 6.99 GiB of weights and moments, and 8
-#: leaves a 16 GB chip under 0.2 GB.
+#: (with the value heads they serve) where the delta rule runs as a scan
+#: (`linear_attention.gated_delta_rule_route`: off a v5e, under a mesh);
+#: the Pallas kernels keep little enough for all heads at once. A
+#: constant, from chip runs of the scan's step at 16,384 positions, 16
+#: key and 32 value heads of 128 (PERF.md section 6, PR 31): a step took
+#: 1.109 s at 2, 1.053 at 4, 0.964 at 8; the compiler counted 5.11, 5.97
+#: and 8.25 GiB of temporaries (10.97 with all 16 at once) beside 6.99
+#: GiB of weights and moments, and 8 leaves a 16 GB chip under 0.2 GB.
 LINEAR_KEY_HEADS = 4
 
 
@@ -391,13 +395,18 @@ def _swiglu(w, x):
     return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
 
 
-def _linear_attention(layer, x, key_mask, p: SeqRecParams):
-    """The "gdn" mixer on normed x [B, L, D] -> [B, L, D]. A padding
-    position's input is 0: it writes nothing into the state, and a
-    left-padded session is the unpadded one. Heads are independent from
-    the projection to the output matrix; under `remat` they are taken
-    `LINEAR_KEY_HEADS` key heads (and the value heads they serve) at a
-    time, each group's internals recomputed in the backward pass."""
+def _linear_attention(layer, x, key_mask, p: SeqRecParams, devices: int):
+    """The "gdn" mixer on normed x [B, L, D] -> [B, L, D], in a program
+    traced for `devices` devices. A padding position's input is 0: it
+    writes nothing into the state, and a left-padded session is the
+    unpadded one. Heads are independent from the projection to the
+    output matrix. Under `remat`, where the rule runs as a scan
+    (`gated_delta_rule_route`) they are taken `LINEAR_KEY_HEADS` key heads
+    (and the value heads they serve) at a time, each group's internals
+    recomputed in the backward pass; on the kernels' route all at once,
+    and of what lies between the projections only what the kernels hand
+    their backward pass is kept (`linear_attention_pallas.KEPT`): the
+    convolution, norms and gate are recomputed, no kernel runs again."""
     b, l, _ = x.shape
     hk, hv = p.linear_key_heads, p.linear_value_heads
     dk, dv = p.linear_key_head_dim, p.linear_value_head_dim
@@ -411,8 +420,9 @@ def _linear_attention(layer, x, key_mask, p: SeqRecParams):
     beta = jax.nn.sigmoid(ba[..., :hv])
     g = -jnp.exp(layer["A_log"]) * jax.nn.softplus(
         ba[..., hv:] + layer["dt_bias"])
+    scan = linear_attention.route_here(dk, dv, devices) == "xla"
     n = hk // LINEAR_KEY_HEADS \
-        if p.remat and hk % LINEAR_KEY_HEADS == 0 else 1
+        if scan and p.remat and hk % LINEAR_KEY_HEADS == 0 else 1
 
     def groups(t):          # [..., heads x width] -> [n, ..., heads / n x width]
         return jnp.moveaxis(t.reshape(*t.shape[:-1], n, -1), -2, 0)
@@ -427,14 +437,21 @@ def _linear_attention(layer, x, key_mask, p: SeqRecParams):
         q, k, v = (linear_attention.causal_conv(t, w) for t, w in
                    ((q, conv_q), (k, conv_k), (v, conv_v)))
         o = linear_attention.gated_delta_rule(
-            unit(q) * dk ** -0.5, unit(k), v.reshape(b, l, -1, dv), g, beta)
+            unit(q) * dk ** -0.5, unit(k), v.reshape(b, l, -1, dv), g, beta,
+            devices=devices)
         o = _rms_norm(o, layer["o_norm"]["scale"], p.norm_eps) \
             * jax.nn.silu(z.reshape(b, l, -1, dv))
         return o.reshape(b, l, -1)
 
     args = tuple(map(groups, (q, k, v, z, conv_q, conv_k, conv_v, g, beta)))
-    o = jax.lax.map(jax.checkpoint(group), args) if n > 1 \
-        else group(tuple(t[0] for t in args))[None]
+    if n > 1:
+        o = jax.lax.map(jax.checkpoint(group), args)
+    else:
+        if p.remat and not scan:
+            group = jax.checkpoint(
+                group, policy=jax.checkpoint_policies.save_only_these_names(
+                    *linear_attention_pallas.KEPT))
+        o = group(tuple(t[0] for t in args))[None]
     return jnp.moveaxis(o, 0, 2).reshape(b, l, -1) @ layer["w_out"]
 
 
@@ -528,12 +545,13 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
     if p.attention_impl == "ring" and not use_ring:
         raise ValueError('attention_impl="ring" requires a mesh with a '
                          '"seq" axis')
+    devices = 1 if mesh is None else mesh.size
 
     def block(h, layer, mixer, kind):
         x = _norm(h, layer["ln1"], p)
         if mixer == "gdn":
             with jax.named_scope("seqrec_linear_attention"):
-                h = h + _linear_attention(layer, x, key_mask, p)
+                h = h + _linear_attention(layer, x, key_mask, p, devices)
         else:                      # key mask keeps it out of the softmax
             with jax.named_scope("seqrec_attention"):
                 h = h + _attention(layer, x, key_mask, p, mixer, mesh,
@@ -647,10 +665,12 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
     """One donated jitted step -> (params, opt_state, the step's numbers:
     loss, by group the gradient's norm and the norm of what the step
     added to the parameters, and per expert layer the tokens routed to
-    each expert, to each held expert, and dropped; two constants of the
-    trace: `mixer_layers`, the layers it ran by mixer, and
+    each expert, to each held expert, and dropped; three constants of
+    the trace: `mixer_layers`, the layers it ran by mixer,
     `attention_pallas`, whether `blockwise_attention` folded every
-    softmax-attention layer's blocks with the Pallas kernels). With a
+    softmax-attention layer's blocks with the Pallas kernels, and
+    `linear_attention_pallas`, whether `gated_delta_rule` ran every
+    linear-attention layer's recurrence as Pallas kernels). With a
     mesh, batch is
     sharded over "data" and embedding/ffn rows over "model"; XLA inserts
     the psums."""
@@ -664,14 +684,16 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
             sh = NamedSharding(mesh, P("data", seq_dim))
             seqs = jax.lax.with_sharding_constraint(seqs, sh)
             targets = jax.lax.with_sharding_constraint(targets, sh)
-        routes: set = set()
-        with routes_into(routes):
+        routes, rule_routes = set(), set()
+        with routes_into(routes), linear_attention.routes_into(rule_routes):
             (loss, (expert_layers, mixers)), grads = jax.value_and_grad(
                 _loss_fn, has_aux=True)(params, seqs, targets, p, mesh)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         stats = {"loss": loss, "grad_norm": _group_norms(grads),
                  "mixer_layers": mixers,
-                 "attention_pallas": jnp.asarray(routes == {"pallas"})}
+                 "attention_pallas": jnp.asarray(routes == {"pallas"}),
+                 "linear_attention_pallas": jnp.asarray(
+                     rule_routes == {"pallas"})}
         if expert_layers:
             # a selection bias is moved by its layer's load, not by adamw
             moe_layers = [layer for i, layer in enumerate(updates["layers"])
@@ -955,11 +977,12 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
         record = _training_record(steps, rows)
     train_stats.seqrec_fetch_bytes().inc(
         sum(leaf.nbytes for leaf in jax.tree.leaves(host)))
-    # one compiled step made every step of the train: one route, one
-    # pattern of mixers
+    # one compiled step made every step of the train: one route a kind
+    # of layer, one pattern of mixers
     train_stats.observe_seqrec_record(
         record, targets, rows,
-        "pallas" if steps and steps[0]["attention_pallas"] else "xla",
+        *("pallas" if steps and steps[0][key] else "xla"
+          for key in ("attention_pallas", "linear_attention_pallas")),
         {kind: int(n) for kind, n in steps[0]["mixer_layers"].items()}
         if steps else {})
     return SeqRecModel(item_vocab=all_items, params=host, hyper=p,

@@ -49,6 +49,12 @@ the weights), `seqrec_put` (sharding, the optimizer's state),
   took when their step was traced: ``pallas`` (every softmax-attention
   layer through the kernels of ops/attention_pallas.py) or ``xla``. A
   model without such a layer counts nothing here.
+* ``pio_train_seqrec_linear_attention_tokens_total{impl}`` — positions of
+  the trained batches, padding too, times the linear-attention
+  (``gdn``) layers, by the route ``gated_delta_rule`` took when their
+  step was traced: ``pallas`` (every such layer's recurrence through
+  the kernels of ops/linear_attention_pallas.py) or ``xla``. A model
+  without such a layer counts nothing here.
 * ``pio_train_seqrec_mixer_tokens_total{mixer}`` — positions of the
   trained batches, padding too, times the layers of each mixer
   (``mha``, ``mla``, ``gqa``, ``gdn``) the compiled step ran.
@@ -175,6 +181,15 @@ def seqrec_attention_tokens(registry: MetricsRegistry = None):
         labelnames=("impl",))
 
 
+def seqrec_linear_attention_tokens(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_linear_attention_tokens_total",
+        "Positions of the trained batches times the linear-attention "
+        "layers, by the route their step's delta rule was traced on "
+        "(ops/linear_attention.gated_delta_rule_route)",
+        labelnames=("impl",))
+
+
 def seqrec_mixer_tokens(registry: MetricsRegistry = None):
     return (registry or default_registry()).counter(
         "pio_train_seqrec_mixer_tokens_total",
@@ -210,12 +225,14 @@ def seqrec_fetch_bytes(registry: MetricsRegistry = None):
 
 
 def observe_seqrec_record(record: dict, targets, rows,
-                          attention_impl: str, mixer_layers: dict) -> None:
+                          attention_impl: str, linear_attention_impl: str,
+                          mixer_layers: dict) -> None:
     """The token and expert counters from one train's record
     (models/seqrec.train_seqrec): `targets` the padded target ids of all
     sessions, `rows` the sessions of each step's batch, `attention_impl`
-    the route its step was traced on, `mixer_layers` the layers that
-    step ran by mixer."""
+    and `linear_attention_impl` the routes its step's softmax and linear
+    attention were traced on, `mixer_layers` the layers that step ran by
+    mixer."""
     import numpy as np
 
     real = sum(int((targets[r] > 0).sum()) for r in rows)
@@ -226,6 +243,9 @@ def observe_seqrec_record(record: dict, targets, rows,
         seqrec_mixer_tokens().inc(positions * layers, mixer=mixer)
     if set(mixer_layers) - {"gdn"}:
         seqrec_attention_tokens().inc(positions, impl=attention_impl)
+    if "gdn" in mixer_layers:
+        seqrec_linear_attention_tokens().inc(
+            positions * mixer_layers["gdn"], impl=linear_attention_impl)
     if "load" not in record or not record["load"]:
         return
     load = np.asarray(record["load"], np.float64)      # [step, layer, expert]

@@ -11,18 +11,25 @@ its key by a share beta_t of its value, then reads with its query:
 
 `gated_delta_rule` computes this `CHUNK` positions at a time (the WY
 form of the paper's section 3.3): inside a chunk the positions' updates
-are coupled by a unit lower triangular system over the chunk's keys,
-solved for all chunks at once; between chunks one state update, a
-`lax.scan` over the chunks that carries S. All of it XLA operations; no
-kernel.
+are coupled by a unit lower triangular system over the chunk's keys;
+between chunks one state update. Two implementations of the same
+mathematics, picked by `gated_delta_rule_route` from the device's kind,
+the widths and the devices the program is traced for:
 
-Backward pass: the scan's own, with the scan's body under
-`jax.checkpoint`, so that a chunk keeps the state it started from
-(B x H x dk x dv a chunk: 512 MiB a layer at 32 heads of 128 x 128 and
-256 chunks) and recomputes its four products; the caller bounds what
-else is kept (`models/seqrec` takes a layer's heads a group at a time
-under `remat`). PERF.md section 6, PR 31, has the readings behind the
-choice.
+* the Pallas kernels of ops/linear_attention_pallas.py (a v5e, a
+  program for one device, widths in whole lane tiles): a head's state
+  stays in VMEM across its chunks and a chunk's system, inverse and
+  scores never reach HBM; a backward kernel of their own, which keeps
+  the rule's inputs and each chunk's starting state and inverse;
+* `_scan`, XLA operations everywhere else: the systems solved for all
+  chunks at once, then a `lax.scan` over the chunks that carries S. Its
+  backward pass is the scan's own, with the scan's body under
+  `jax.checkpoint`, so that a chunk keeps the state it started from
+  (B x H x dk x dv a chunk: 512 MiB a layer at 32 heads of 128 x 128 and
+  256 chunks) and recomputes its four products; the caller bounds what
+  else is kept (`models/seqrec` takes a layer's heads a group at a time
+  under `remat`). PERF.md section 6, PRs 31 and 32, has the readings
+  behind the choices.
 
 Precision: the system's matrix (beta k_i . k_j, decayed), its inverse
 and the two products with the inverse are computed in float32 at the
@@ -35,14 +42,21 @@ exponentials are float32 elementwise.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+from typing import Iterator, Optional, Set
+
 import jax
 import jax.numpy as jnp
 
-#: positions a chunk. A constant, from the paper's kernels (64) and chip
-#: runs at 1 x 32 heads x 16,384 x 128 (PERF.md section 6, PR 31): the
-#: rule alone took 24.8 ms forward and 81.6 forward + backward at 64,
-#: 27.6 and 90.4 at 128 (half the scan's steps, four times the
-#: triangular system); the cell's step 1.053 s against 1.078.
+from predictionio_tpu.ops import attention_pallas, linear_attention_pallas
+
+#: positions a chunk, on either route. A constant, from the paper's
+#: kernels (64) and chip runs of the scan at 1 x 32 heads x 16,384 x 128
+#: (PERF.md section 6, PR 31): the rule alone took 24.8 ms forward and
+#: 81.6 forward + backward at 64, 27.6 and 90.4 at 128 (half the scan's
+#: steps, four times the triangular system); the cell's step 1.053 s
+#: against 1.078.
 CHUNK = 64
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -70,19 +84,13 @@ def _unit_lower_inverse(a: jax.Array) -> jax.Array:
     return t
 
 
-def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
-                     beta: jax.Array) -> jax.Array:
-    """q, k [B, L, H, dk] (normalised and scaled by the caller), v
-    [B, L, H, dv], g [B, L, H] (the decay's logarithm, <= 0), beta
-    [B, L, H] -> o [B, L, H, dv], from a state of 0. Any length: the
-    last chunk is filled with positions that write nothing (k = 0)."""
+def _scan(q, k, v, g, beta):
+    """The rule over whole chunks [B, L, H, ...] -> o [B, L, H, dv]
+    float32: the chunks' systems solved for all chunks at once, then a
+    `lax.scan` over the chunks that carries S, its body recomputed in
+    the backward pass."""
     b, l, h, _ = q.shape
-    pad = -l % CHUNK
-    if pad:
-        q, k, v, g, beta = (
-            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-            for t in (q, k, v, g, beta))
-    n = (l + pad) // CHUNK
+    n = l // CHUNK
 
     def chunks(t):          # [B, L, H, ...] -> [N, B, H, C, ...]
         t = t.astype(jnp.float32).reshape(b, n, CHUNK, h, *t.shape[3:])
@@ -116,5 +124,74 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     _, o = jax.lax.scan(
         jax.checkpoint(chunk),
         jnp.zeros((b, h, k.shape[-1], v.shape[-1]), jnp.float32), xs)
-    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, l + pad, h, -1)
+    return jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, l, h, -1)
+
+
+def gated_delta_rule_route(device_kind: str, dk: int, dv: int,
+                           devices: int = 1) -> str:
+    """Which implementation `gated_delta_rule` runs for these sizes on a
+    device of this kind (`jax.Device.device_kind`), in a program traced
+    for `devices` devices: "pallas", the kernels of
+    ops/linear_attention_pallas.py, on the TPUs the attention kernels
+    are listed for (`attention_pallas.KINDS`), for widths they tile (any
+    length: `gated_delta_rule` pads it to whole chunks), in a program
+    for one device (the compiler partitions no Mosaic kernel); "xla",
+    the scan over the chunks, everywhere else."""
+    if (device_kind in attention_pallas.KINDS and devices == 1
+            and linear_attention_pallas.tiles(dk, dv)):
+        return "pallas"
+    return "xla"
+
+
+def _device_kind() -> str:
+    return jax.devices()[0].device_kind
+
+
+def route_here(dk: int, dv: int, devices: int = 1) -> str:
+    """`gated_delta_rule_route` on this process's device."""
+    return gated_delta_rule_route(_device_kind(), dk, dv, devices)
+
+
+_ROUTES: contextvars.ContextVar[Optional[Set[str]]] = contextvars.ContextVar(
+    "gated_delta_rule_routes", default=None)
+
+
+@contextlib.contextmanager
+def routes_into(routes: Set[str]) -> Iterator[None]:
+    """While the block runs (a trace), every `gated_delta_rule` call adds
+    the route it took to `routes`."""
+    token = _ROUTES.set(routes)
+    try:
+        yield
+    finally:
+        _ROUTES.reset(token)
+
+
+def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                     beta: jax.Array, devices: int = 1) -> jax.Array:
+    """q, k [B, L, H, dk] (normalised and scaled by the caller), v
+    [B, L, H, dv], g [B, L, H] (the decay's logarithm, <= 0), beta
+    [B, L, H] -> o [B, L, H, dv], from a state of 0. Any length: it is
+    filled up with positions that write nothing (k = 0).
+    `gated_delta_rule_route` says from the device's kind, the sizes and
+    `devices` (how many devices the calling program is traced for: a
+    mesh's size) whether the chunks are taken by Pallas kernels or by
+    XLA operations and a scan."""
+    l = q.shape[1]
+    route = route_here(q.shape[-1], v.shape[-1], devices)
+    heard = _ROUTES.get()
+    if heard is not None:
+        heard.add(route)
+    # whole chunks; for the kernels, whole grid steps
+    pad = -l % (CHUNK * (linear_attention_pallas.CHUNKS[-1]
+                         if route == "pallas" else 1))
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, g, beta))
+    if route == "pallas":
+        o = linear_attention_pallas.gated_delta_rule_pallas(q, k, v, g, beta,
+                                                            CHUNK)
+    else:
+        o = _scan(q, k, v, g, beta)
     return o[:, :l].astype(v.dtype)

@@ -107,3 +107,125 @@ def test_a_train_step_whose_shapes_tile_compiles_for_v5e(
             seqrec.make_train_step(mesh, p, optimizer).lower(
                 put(params), put(jax.eval_shape(optimizer.init, params)),
                 seqs, seqs)
+
+
+@pytest.mark.parametrize("name,b,h,length,dk,dv", [
+    # qwen3next-a3b-ep16.train: 1 session x 32 value heads x 16,384, 128
+    ("cell", 1, 32, 16384, 128, 128),
+    # the widest state `tiles` lets through, a length of an odd number of
+    # pairs of chunks (one pair a grid step) and an odd number of heads
+    ("widest-odd", 2, 3, 64 * 6, 256, 256),
+    ("dk-over-dv", 1, 2, 1024, 256, 128),
+])
+def test_delta_rule_kernels_compile_for_v5e(one_chip, name, b, h, length, dk,
+                                            dv):
+    from predictionio_tpu.ops import linear_attention, linear_attention_pallas
+
+    assert linear_attention_pallas.tiles(dk, dv)
+
+    def loss(q, k, v, g, beta, w):
+        return (linear_attention_pallas.gated_delta_rule_pallas(
+            q, k, v, g, beta, linear_attention.CHUNK) * w).sum()
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
+        shape(b, length, h, dk), shape(b, length, h, dk),
+        shape(b, length, h, dv), shape(b, length, h), shape(b, length, h),
+        shape(b, length, h, dv)).compile().as_text()
+    for kernel in ("gated_delta_rule_pallas_fwd",
+                   "gated_delta_rule_pallas_bwd"):
+        assert kernel in text
+
+
+def _kernel_calls(text, name):
+    import re
+
+    return len(re.findall(rf"{name}[.0-9]* = ", text))
+
+
+def test_the_hybrid_cells_step_fits_a_v5e_with_all_heads_at_once(
+        chips, one_chip, monkeypatch):
+    """qwen3next-a3b-ep16.train's step compiled for one described v5e: on
+    the kernels' route a linear layer takes all its heads at once, its
+    forward kernel runs twice a layer (the block's pass and `remat`'s)
+    and no third time, and arguments + temporaries leave the 16 GB chip
+    1 GB and more (the rule by which the head groups went; PERF.md
+    section 6, PR 32)."""
+    import json
+    import os
+
+    from predictionio_tpu.models import seqrec
+    from predictionio_tpu.ops import attention, linear_attention
+
+    kind = chips[0].device_kind
+    monkeypatch.setattr(attention, "_device_kind", lambda: kind)
+    monkeypatch.setattr(linear_attention, "_device_kind", lambda: kind)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs",
+                           "seqrec-qwen3-next-80b-a3b-ep16.json")) as f:
+        cfg = json.load(f)
+    p = seqrec.SeqRecParams(**cfg["algorithm_params"])
+    assert p.remat and p.mixer_kinds().count("gdn") == 3
+    optimizer = seqrec.make_optimizer(p)
+    params = jax.eval_shape(
+        lambda: seqrec.init_params(None, cfg["n_items"], p))
+    put = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    seqs = jax.ShapeDtypeStruct((p.batch_size, p.max_len), jnp.int32,
+                                sharding=one_chip)
+    compiled = seqrec.make_train_step(None, p, optimizer).lower(
+        put(params), put(jax.eval_shape(optimizer.init, params)), seqs,
+        seqs).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(text, "gated_delta_rule_pallas_fwd") == 2 * 3
+    assert _kernel_calls(text, "gated_delta_rule_pallas_bwd") == 3
+    assert _kernel_calls(text, "flash_attention_pallas_bwd") == 1
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert held <= 15.75 * 2 ** 30 - 1e9, held
+
+
+@pytest.mark.parametrize("name,mesh_shape,kernels", [
+    ("one-chip", None, True),
+    ("2x2-mesh", (2, 2), False),
+])
+def test_a_hybrid_step_keeps_the_scan_under_a_mesh(chips, monkeypatch, name,
+                                                   mesh_shape, kernels):
+    """A small period of linear and full layers at the cell's head
+    widths: kernels for both in a program for one chip, the scans in a
+    step traced for four."""
+    from predictionio_tpu.models import seqrec
+    from predictionio_tpu.ops import attention, linear_attention
+
+    kind = chips[0].device_kind
+    monkeypatch.setattr(attention, "_device_kind", lambda: kind)
+    monkeypatch.setattr(linear_attention, "_device_kind", lambda: kind)
+    p = seqrec.SeqRecParams(
+        d_model=256, n_heads=2, n_layers=2, max_len=256, batch_size=4,
+        mixer=("gdn", "gqa"), norm="rms", positions="rope", n_kv_heads=1,
+        head_dim=128, rotary_dim=32, linear_key_heads=2,
+        linear_value_heads=4, linear_key_head_dim=128,
+        linear_value_head_dim=128, linear_conv_kernel=4, remat=True)
+    if mesh_shape is None:
+        mesh, whole, rows = None, SingleDeviceSharding(chips[0]), None
+    else:
+        mesh = Mesh(np.asarray(chips).reshape(mesh_shape), ("data", "model"))
+        whole = NamedSharding(mesh, P())
+        rows = NamedSharding(mesh, P("data"))
+    optimizer = seqrec.make_optimizer(p)
+    params = jax.eval_shape(
+        lambda: seqrec.init_params(np.random.default_rng(0), 50, p))
+    put = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=whole),
+        tree)
+    seqs = jax.ShapeDtypeStruct((4, 256), jnp.int32, sharding=rows or whole)
+    text = seqrec.make_train_step(mesh, p, optimizer).lower(
+        put(params), put(jax.eval_shape(optimizer.init, params)), seqs,
+        seqs).compile().as_text()
+    for kernel in ("gated_delta_rule_pallas_fwd",
+                   "gated_delta_rule_pallas_bwd",
+                   "flash_attention_pallas_bwd"):
+        assert (kernel in text) is kernels, kernel

@@ -518,3 +518,103 @@ def test_a_step_says_which_route_its_attention_was_traced_on(monkeypatch):
             params, optimizer.init(params), seqs, seqs)
         assert bool(stats["attention_pallas"]) is pallas
         assert np.isfinite(float(stats["loss"]))
+
+
+def _gdn_spec(**over):
+    from predictionio_tpu.models import seqrec
+
+    return seqrec.SeqRecParams(**{**dict(
+        d_model=32, n_heads=2, n_layers=3, max_len=64, batch_size=2,
+        mixer=("gdn", "gdn", "gqa"), norm="rms", positions="rope",
+        n_kv_heads=1, head_dim=16, rotary_dim=8, linear_key_heads=1,
+        linear_value_heads=2, linear_key_head_dim=128,
+        linear_value_head_dim=128, linear_conv_kernel=4, remat=True),
+        **over})
+
+
+def _on_rule_kernels(monkeypatch):
+    """`gated_delta_rule` as a v5e would route it, the kernels
+    interpreted."""
+    from predictionio_tpu.ops import (
+        attention_pallas, linear_attention, linear_attention_pallas,
+    )
+
+    monkeypatch.setattr(linear_attention, "_device_kind",
+                        lambda: attention_pallas.KINDS[0])
+    kernels = linear_attention_pallas.gated_delta_rule_pallas
+    monkeypatch.setattr(linear_attention_pallas, "gated_delta_rule_pallas",
+                        lambda *a: kernels(*a, True))
+
+
+def test_a_sequence_model_train_counts_its_linear_attention_tokens_by_route(
+        monkeypatch):
+    """`pio_train_seqrec_linear_attention_tokens_total{impl}`: every
+    position of the trained batches times the linear-attention layers,
+    under the route `gated_delta_rule` took when the train's step was
+    traced, which the step itself reports: on the CPU `xla`; with the
+    kernels' route forced (and interpreted) `pallas`. A model without
+    such a layer counts nothing."""
+    from predictionio_tpu.models import seqrec
+
+    reg = default_registry()
+
+    def counted(label):
+        c = reg.get("pio_train_seqrec_linear_attention_tokens_total")
+        return c.value(impl=label) if c is not None else 0
+
+    sessions = [[f"i{(s + j) % 11}" for j in range(40 + s)] for s in range(4)]
+    before = {label: counted(label) for label in ("xla", "pallas")}
+    seqrec.train_seqrec(None, sessions, seqrec.SeqRecParams(
+        d_model=16, n_heads=2, n_layers=1, max_len=8, batch_size=2))
+    assert {label: counted(label) for label in before} == before
+    # 1 epoch x 2 steps x 2 sessions x 64 positions x 2 gdn layers
+    seqrec.train_seqrec(None, sessions, _gdn_spec(epochs=1))
+    assert counted("xla") - before["xla"] == 2 * 2 * 64 * 2
+    assert counted("pallas") == before["pallas"]
+
+    _on_rule_kernels(monkeypatch)
+    # another seed: another step than the cached one
+    seqrec.train_seqrec(None, sessions, _gdn_spec(epochs=1, seed=8))
+    assert counted("pallas") - before["pallas"] == 2 * 2 * 64 * 2
+    assert counted("xla") - before["xla"] == 2 * 2 * 64 * 2
+
+
+def test_a_step_says_which_route_its_delta_rule_was_traced_on(monkeypatch):
+    """The step's `linear_attention_pallas` is what `gated_delta_rule`
+    chose at trace time: with the kernels' route forced (and
+    interpreted) it reads True and the heads are taken all at once,
+    under a mesh of two devices the same spec reads False; with the
+    kernels' operands left float32, as the CPU leaves the scan's, the
+    loss and the gradient norms are the scan's."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from predictionio_tpu.models import seqrec
+    from predictionio_tpu.ops import linear_attention_pallas
+
+    p = _gdn_spec()
+    optimizer = seqrec.make_optimizer(p)
+    seqs = jnp.asarray(np.random.default_rng(0).integers(1, 9, (2, 64)),
+                       jnp.int32)
+
+    def step(mesh):
+        params = seqrec.init_params(np.random.default_rng(0), 8, p)
+        return seqrec.make_train_step(mesh, p, optimizer)(
+            params, optimizer.init(params), seqs, seqs)[2]
+
+    scan = step(None)
+    assert not bool(scan["linear_attention_pallas"])
+    _on_rule_kernels(monkeypatch)
+    monkeypatch.setattr(linear_attention_pallas, "_BF16", jnp.float32)
+    for mesh, pallas in (
+            (None, True),
+            (Mesh(np.asarray(jax.devices()[:2]), ("data",)), False)):
+        stats = step(mesh)
+        assert bool(stats["linear_attention_pallas"]) is pallas
+        assert not bool(stats["attention_pallas"])
+        assert abs(float(stats["loss"]) - float(scan["loss"])) \
+            < 1e-5 * float(scan["loss"])
+        for group, norm in scan["grad_norm"].items():
+            assert abs(float(stats["grad_norm"][group]) - float(norm)) \
+                < 2e-3 * float(norm), group

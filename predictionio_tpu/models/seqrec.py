@@ -72,7 +72,11 @@ class SeqRecParams(Params):
     #: all heads, values of v_head_dim. "gqa": n_heads query heads of
     #: head_dim over n_kv_heads key/value heads, queries and keys normed
     #: over the head width, rotary positions on the leading rotary_dim of
-    #: it, the output gated by a sigmoid of a projection of the input.
+    #: it, the output gated by a sigmoid of a projection of the input
+    #: (`attention_gate`). "conv": a gated short convolution — one
+    #: projection to three streams of d_model, the first times the third
+    #: through a depthwise causal convolution of conv_kernel taps without
+    #: activation, times the second, an output projection.
     #: "gdn": linear attention by the gated delta rule
     #: (ops/linear_attention.py) behind a causal convolution of
     #: linear_conv_kernel taps, linear_key_heads key heads of
@@ -103,6 +107,10 @@ class SeqRecParams(Params):
     n_kv_heads: int = 0
     head_dim: int = 0
     rotary_dim: int = 0
+    #: whether a "gqa" mixer's output is gated; without, its query
+    #: projection has no gate's half (`wq` in place of `wq_gate`)
+    attention_gate: bool = True
+    conv_kernel: int = 0
     linear_key_heads: int = 0
     linear_value_heads: int = 0
     linear_key_head_dim: int = 0
@@ -123,6 +131,8 @@ class SeqRecParams(Params):
     router_scoring: str = "sigmoid"
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    #: added to the chosen scores' sum where the gates are normalised
+    router_norm_eps: float = 1e-20
     #: after each step b += rate * sign(mean load - load) on the router's
     #: selection bias (auxiliary-loss-free balancing); 0 leaves it at 0
     bias_update_rate: float = 0.0
@@ -184,11 +194,12 @@ class SeqRecParams(Params):
             if getattr(self, name) not in kinds:
                 raise ValueError(f"unknown {name} {getattr(self, name)!r}: "
                                  f"expected one of {kinds}")
-        new = set(self.mixer_kinds()) & {"gqa", "gdn"}
+        new = set(self.mixer_kinds()) & {"gqa", "gdn", "conv"}
         if new:
             # what these mixers are not defined with: their norms are RMS
             # norms, their only positions rotary (gqa) or the convolution's
-            # (gdn), and the ring takes one key/value head a query head
+            # (gdn, conv), and the ring takes one key/value head a query
+            # head
             for name, refused in (("norm", "layer"),
                                   ("positions", "learned"),
                                   ("attention_impl", "ring")):
@@ -205,6 +216,9 @@ class SeqRecParams(Params):
             if not 0 < self.rotary_dim <= self.head_dim or self.rotary_dim % 2:
                 raise ValueError(f"rotary_dim {self.rotary_dim} is no even "
                                  f"part of head_dim {self.head_dim}")
+        if "conv" in new and self.conv_kernel < 1:
+            raise ValueError(f"conv needs conv_kernel >= 1: "
+                             f"{self.conv_kernel}")
         if "gdn" in new:
             sizes = (self.linear_key_heads, self.linear_value_heads,
                      self.linear_key_head_dim, self.linear_value_head_dim,
@@ -229,7 +243,7 @@ class SeqRecParams(Params):
                                  "n_routed_experts")
 
 
-MIXERS = ("mha", "mla", "gqa", "gdn")
+MIXERS = ("mha", "mla", "gqa", "gdn", "conv")
 
 #: settings that change where a train's work lies and what it keeps in
 #: memory, not what it computes
@@ -309,11 +323,17 @@ def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
         if kind == "mha":
             return {"wqkv": dense(d, 3 * d), "wo": dense(d, d)}
         if kind == "gqa":
-            return {"wq_gate": dense(d, 2 * h * p.head_dim),
+            wq = {"wq_gate": dense(d, 2 * h * p.head_dim)} \
+                if p.attention_gate else {"wq": dense(d, h * p.head_dim)}
+            return {**wq,
                     "wk": dense(d, p.n_kv_heads * p.head_dim),
                     "wv": dense(d, p.n_kv_heads * p.head_dim),
                     "q_norm": norm(p.head_dim), "k_norm": norm(p.head_dim),
                     "wo": dense(h * p.head_dim, d)}
+        if kind == "conv":
+            return {"conv_in": dense(d, 3 * d),
+                    "conv_taps": dense(p.conv_kernel, d),
+                    "conv_out": dense(d, d)}
         if kind == "gdn":
             keys = p.linear_key_heads * p.linear_key_head_dim
             values = p.linear_value_heads * p.linear_value_head_dim
@@ -395,6 +415,19 @@ def _swiglu(w, x):
     return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
 
 
+def _short_conv(layer, x, key_mask):
+    """The "conv" mixer on normed x [B, L, D] -> [B, L, D]: [b | c | u] =
+    x W_in; y = (c * conv(b * u)) W_out, the convolution depthwise,
+    causal, without activation. A padding position's input is 0, so it
+    adds nothing to the taps' sums, and a left-padded session is the
+    unpadded one. The elementwise chain is XLA's to fuse."""
+    x = jnp.where(key_mask[..., None], x, 0.0)
+    b, c, u = jnp.split(x @ layer["conv_in"], 3, axis=-1)
+    mixed = linear_attention.causal_conv(b * u, layer["conv_taps"],
+                                         activation=None)
+    return (c * mixed) @ layer["conv_out"]
+
+
 def _linear_attention(layer, x, key_mask, p: SeqRecParams, devices: int):
     """The "gdn" mixer on normed x [B, L, D] -> [B, L, D], in a program
     traced for `devices` devices. A padding position's input is 0: it
@@ -467,7 +500,10 @@ def _attention(layer, x, key_mask, p: SeqRecParams, kind, mesh, use_ring):
         if p.positions == "rope":
             q, k = (rope(t, positions, p.rope_theta) for t in (q, k))
     elif kind == "gqa":
-        q, gate = jnp.split(x @ layer["wq_gate"], 2, axis=-1)
+        if p.attention_gate:
+            q, gate = jnp.split(x @ layer["wq_gate"], 2, axis=-1)
+        else:
+            q = x @ layer["wq"]
         q, k = (rope(_norm(t.reshape(b, l, -1, p.head_dim), w, p), positions,
                      p.rope_theta, p.rotary_dim)
                 for t, w in ((q, layer["q_norm"]),
@@ -508,7 +544,8 @@ def _moe(layer, x, p: SeqRecParams):
     with jax.named_scope("seqrec_router"):
         routing = moe.route(flat, layer["router"], layer["router_bias"],
                             p.experts_per_token, p.routed_scaling_factor,
-                            p.norm_topk_prob, p.router_scoring)
+                            p.norm_topk_prob, p.router_scoring,
+                            p.router_norm_eps)
     with jax.named_scope("seqrec_experts"):
         ex = layer["experts"]
         y, held_tokens, dropped = moe.held_experts(
@@ -549,7 +586,10 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
 
     def block(h, layer, mixer, kind):
         x = _norm(h, layer["ln1"], p)
-        if mixer == "gdn":
+        if mixer == "conv":
+            with jax.named_scope("seqrec_short_conv"):
+                h = h + _short_conv(layer, x, key_mask)
+        elif mixer == "gdn":
             with jax.named_scope("seqrec_linear_attention"):
                 h = h + _linear_attention(layer, x, key_mask, p, devices)
         else:                      # key mask keeps it out of the softmax
@@ -633,7 +673,9 @@ def grad_group(path) -> str:
                              "wq_gate", "wk", "wv", "q_norm", "k_norm"),
                             "attention"),
             **dict.fromkeys(("w_qkvz", "w_ba", "conv", "A_log", "dt_bias",
-                             "o_norm", "w_out"), "linear_attention")}.get(
+                             "o_norm", "w_out"), "linear_attention"),
+            **dict.fromkeys(("conv_in", "conv_taps", "conv_out"),
+                            "short_conv")}.get(
         names[2], "ffn")
     return f"layer{names[1]}.{part}"
 
@@ -722,9 +764,9 @@ def shard_params(params: Dict, mesh: Mesh) -> Dict:
         name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
         if name == "emb":
             return P("model", None)
-        if name in ("wqkv", "w1", "head", "wq_gate", "w_qkvz"):
+        if name in ("wqkv", "w1", "head", "wq_gate", "w_qkvz", "conv_in"):
             return P(None, "model")
-        if name in ("w2", "w_out"):
+        if name in ("w2", "w_out", "conv_out"):
             return P("model", None)
         return P()
 

@@ -57,7 +57,7 @@ the weights), `seqrec_put` (sharding, the optimizer's state),
   without such a layer counts nothing here.
 * ``pio_train_seqrec_mixer_tokens_total{mixer}`` — positions of the
   trained batches, padding too, times the layers of each mixer
-  (``mha``, ``mla``, ``gqa``, ``gdn``) the compiled step ran.
+  (``mha``, ``mla``, ``gqa``, ``gdn``, ``conv``) the compiled step ran.
 * ``pio_train_seqrec_expert_tokens_total{layer}`` — tokens the experts
   held here received, by expert layer.
 * ``pio_train_seqrec_expert_load_max_over_mean`` — the busiest routed
@@ -241,7 +241,7 @@ def observe_seqrec_record(record: dict, targets, rows,
     seqrec_pad_tokens().inc(positions - real)
     for mixer, layers in mixer_layers.items():
         seqrec_mixer_tokens().inc(positions * layers, mixer=mixer)
-    if set(mixer_layers) - {"gdn"}:
+    if set(mixer_layers) & {"mha", "mla", "gqa"}:
         seqrec_attention_tokens().inc(positions, impl=attention_impl)
     if "gdn" in mixer_layers:
         seqrec_linear_attention_tokens().inc(

@@ -46,7 +46,10 @@ NEG_INF = -1e30    # large-negative instead of -inf: avoids NaN in exp(m - m)
 #: PR 30), query x key block: a forward call took 11.4 ms at 512 x 512,
 #: 8.5 at 512 x 1024, 8.2 at 1024 x 1024, 9.2 at 1024 x 2048, 15.0 at
 #: 2048 x 2048; a backward call 17.5 at 512 x 512, 17.2 at 512 x 1024,
-#: 16.8 at 1024 x 1024, 29.6 at 2048 x 1024.
+#: 16.8 at 1024 x 1024, 29.6 at 2048 x 1024. At 1 x 32 (8 key/value) heads
+#: x 32,768 positions, widths 64 / 64 (PR 33), one block for both: forward
+#: 122.4 ms at 512, 68.6 at 1024, 74.4 at 2048; forward + backward 271.9,
+#: 198.6, 217.5: the block does not follow the width.
 BLOCK = 1024
 
 #: the device kinds (`jax.Device.device_kind`) the block and the two limits
@@ -75,10 +78,11 @@ def _block(length: int) -> int:
 
 def tiles(lq: int, lk: int, dk: int, dv: int) -> bool:
     """Whether the kernels lay these shapes out: lengths in whole lane
-    tiles, widths in whole (q, k: half) lane tiles, and `dq` of a
-    (batch row, head), float32 on whole lane tiles, within its VMEM."""
+    tiles, widths in half lane tiles (a block's trailing dimension is
+    the array's whole width), and `dq` of a (batch row, head), float32
+    on whole lane tiles, within its VMEM."""
     return (_block(lq) > 0 and _block(lk) > 0 and dk % 64 == 0
-            and dv % 128 == 0
+            and dv % 64 == 0
             and 2 * lq * -(-dk // 128) * 128 * 4 <= _DQ_VMEM)
 
 

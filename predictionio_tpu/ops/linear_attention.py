@@ -1,5 +1,6 @@
 """Linear attention by the gated delta rule (Gated DeltaNet,
-arXiv:2412.06464), and the short causal convolution in front of it.
+arXiv:2412.06464), and the short causal convolution in front of it
+(`causal_conv`, which a gated short-convolution mixer calls too).
 
 Each head keeps a state S [dk, dv] instead of keys and values. Position
 t first lets the state decay, then replaces what the state holds under
@@ -44,7 +45,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-from typing import Iterator, Optional, Set
+from typing import Callable, Iterator, Optional, Set
 
 import jax
 import jax.numpy as jnp
@@ -62,13 +63,16 @@ CHUNK = 64
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
-    """Depthwise causal convolution with SiLU: x [B, L, C], w [K, C] ->
-    silu(sum_j w[j] x[t - (K - 1) + j]) [B, L, C], zeros before the
-    sequence, no bias."""
+def causal_conv(x: jax.Array, w: jax.Array,
+                activation: Optional[Callable] = jax.nn.silu) -> jax.Array:
+    """Depthwise causal convolution: x [B, L, C], w [K, C] ->
+    activation(sum_j w[j] x[t - (K - 1) + j]) [B, L, C], zeros before
+    the sequence, no bias. `activation`: SiLU in front of the delta
+    rule, None (the taps' sum as it is) in a gated short convolution."""
     taps, l = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    return jax.nn.silu(sum(w[j] * padded[:, j:j + l] for j in range(taps)))
+    out = sum(w[j] * padded[:, j:j + l] for j in range(taps))
+    return out if activation is None else activation(out)
 
 
 def _unit_lower_inverse(a: jax.Array) -> jax.Array:

@@ -44,12 +44,13 @@ class Routing(NamedTuple):
 
 def route(x: jax.Array, w_router: jax.Array, bias: jax.Array, top_k: int,
           scaling: float = 1.0, norm_topk: bool = True,
-          scoring: str = "sigmoid") -> Routing:
+          scoring: str = "sigmoid", norm_eps: float = 1e-20) -> Routing:
     """Router with bias-steered selection (auxiliary-loss-free
     balancing): the affinities are each output's sigmoid (`scoring`
     "sigmoid") or the softmax over all outputs ("softmax"); the top-k of
     score + bias are chosen, the gates are the chosen scores themselves
-    (normalised to sum 1 when `norm_topk`, then scaled), so the bias
+    (normalised when `norm_topk`: over their sum + `norm_eps`, which a
+    model family fixes; then scaled), so the bias
     moves which experts are picked and never the output's weights, and
     takes no gradient. The affinities are computed at the highest matmul
     precision: a rounding that flips the k-th and (k+1)-th expert of a
@@ -61,7 +62,7 @@ def route(x: jax.Array, w_router: jax.Array, bias: jax.Array, top_k: int,
     _, experts = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
     gates = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk:
-        gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-20)
+        gates = gates / (gates.sum(axis=-1, keepdims=True) + norm_eps)
     return Routing(experts, gates * scaling, scores)
 
 

@@ -237,7 +237,11 @@ def test_blockwise_attention_pads_for_the_pallas_kernels(monkeypatch):
     ("TPU v5 lite", 200, 200, 192, 128, 128, 1, "pallas"),   # pads to 256
     ("TPU v5 lite", 32, 32, 32, 32, 512, 1, "xla"),     # the default block
     ("TPU v5 lite", 8192, 8192, 96, 128, 512, 1, "xla"),   # off the lanes
-    ("TPU v5 lite", 8192, 8192, 192, 64, 512, 1, "xla"),
+    ("TPU v5 lite", 8192, 8192, 192, 96, 512, 1, "xla"),
+    # half a lane tile of v: a block's trailing dimension is the array's
+    ("TPU v5 lite", 8192, 8192, 192, 64, 512, 1, "pallas"),
+    ("TPU v5 lite", 32768, 32768, 64, 64, 512, 1, "pallas"),   # dq 32 MiB
+    ("TPU v5 lite", 65536, 65536, 64, 64, 512, 1, "xla"),   # dq over VMEM
     ("TPU v5 lite", 640, 1000, 192, 128, 512, 1, "pallas"),   # pad to 1024
     ("TPU v5 lite", 200, 200, 192, 128, 512, 1, "xla"),   # a block of 200
     ("TPU v5 lite", 8192, 300, 192, 128, 512, 1, "xla"),

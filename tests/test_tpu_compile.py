@@ -43,6 +43,11 @@ def one_chip(chips):
     # a head (32 MiB in its two buffers) within the kernels' VMEM
     ("grouped-cell", 1, 16, 2, 16384, 256, 256, True),
     ("grouped-longest", 1, 4, 1, 24576, 256, 256, True),
+    # lfm2-a2b-ep8.train: 1 session x 32 query heads over 8 key/value
+    # heads x 32,768, q/k = v = 64, half a lane tile: `dq` of a head is
+    # 32 MiB in its two buffers, the longest `tiles` lets through there
+    ("narrow-cell", 1, 32, 8, 32768, 64, 64, True),
+    ("wide-qk-narrow-v", 1, 4, 2, 2048, 192, 64, True),
 ])
 def test_attention_kernels_compile_for_v5e(one_chip, name, b, h, kv_heads,
                                            length, dk, dv, causal):
@@ -229,3 +234,42 @@ def test_a_hybrid_step_keeps_the_scan_under_a_mesh(chips, monkeypatch, name,
                    "gated_delta_rule_pallas_bwd",
                    "flash_attention_pallas_bwd"):
         assert (kernel in text) is kernels, kernel
+
+
+def test_the_conv_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
+    """lfm2-a2b-ep8.train's step compiled for one described v5e: 32,768
+    positions through a dense convolution layer, the attention layer on
+    the kernels (two forward calls under `remat`, one backward) and
+    three convolution layers with their experts, the head tied; arguments
+    + temporaries leave the 16 GB chip 1 GB and more."""
+    import json
+    import os
+
+    from predictionio_tpu.models import seqrec
+    from predictionio_tpu.ops import attention
+
+    kind = chips[0].device_kind
+    monkeypatch.setattr(attention, "_device_kind", lambda: kind)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "seqrec-lfm2-24b-a2b-ep8.json")) as f:
+        cfg = json.load(f)
+    p = seqrec.SeqRecParams(**cfg["algorithm_params"])
+    assert p.remat and p.tied_head and p.max_len == 32768
+    assert p.mixer_kinds() == ("conv", "gqa", "conv", "conv", "conv")
+    optimizer = seqrec.make_optimizer(p)
+    params = jax.eval_shape(
+        lambda: seqrec.init_params(None, cfg["n_items"], p))
+    put = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    seqs = jax.ShapeDtypeStruct((p.batch_size, p.max_len), jnp.int32,
+                                sharding=one_chip)
+    compiled = seqrec.make_train_step(None, p, optimizer).lower(
+        put(params), put(jax.eval_shape(optimizer.init, params)), seqs,
+        seqs).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(text, "flash_attention_pallas_fwd") == 2
+    assert _kernel_calls(text, "flash_attention_pallas_bwd") == 1
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert held <= 15.75 * 2 ** 30 - 1e9, held

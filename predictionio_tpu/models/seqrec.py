@@ -794,12 +794,24 @@ def pad_sessions(sessions: Sequence[Sequence[int]], max_len: int
     return inputs, targets
 
 
+def _on_one_device(leaf) -> jax.Array:
+    """A weight where the serving forward pass, a program for one device,
+    wants it: a trained leaf stays where it is unless a mesh holds it."""
+    if isinstance(leaf, jax.Array) and len(leaf.sharding.device_set) == 1:
+        return leaf
+    return jnp.asarray(np.asarray(leaf))
+
+
 @dataclasses.dataclass
 class SeqRecModel:
-    """Trained weights + id maps; picklable pytree-of-numpy."""
+    """Trained weights + id maps. Out of `train_seqrec` the weights are
+    `jax.Array`s where the train left them, their copies to the host
+    under way; a release holds them as numpy arrays (the serialiser
+    writes a `jax.Array` as one), so out of the model store they are
+    numpy and load without a device."""
 
     item_vocab: np.ndarray     # index i -> item id string for code i+1
-    params: Dict               # numpy pytree
+    params: Dict               # pytree of jax.Array (trained) or numpy (loaded)
     hyper: SeqRecParams
     #: what training saw, a few numbers a step (train_seqrec's docstring)
     record: Optional[Dict] = None
@@ -813,7 +825,7 @@ class SeqRecModel:
         """(weights on the device, the jitted forward pass over them)."""
         cached = getattr(self, "_resident", None)
         if cached is None or cached[0] is not self.params:
-            dev = jax.tree.map(jnp.asarray, self.params)
+            dev = jax.tree.map(_on_one_device, self.params)
             # serving always uses the local attention kernel
             hyper = dataclasses.replace(self.hyper, attention_impl="flash")
             cached = (self.params, dev,
@@ -1014,11 +1026,20 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
                             out_shardings=NamedSharding(mesh, P())))
         params = replicate(params)
     with span("seqrec_fetch"):
-        host = jax.tree.map(np.asarray, params)
+        # the steps' few numbers first: behind the weights they would
+        # wait for every copy in front of them
         steps = jax.device_get(steps)
+        # the weights' copies to the host start here and nobody waits for
+        # them: whoever reads a leaf (the release's pickler, leaf by leaf
+        # under its write) waits for that leaf alone
+        leaves, treedef = jax.tree.flatten(params)
+        for leaf in leaves:
+            leaf.copy_to_host_async()
+        # rebuilt from its leaves, so that a release lists a dict's keys
+        # in the one order whatever built the tree
+        params = jax.tree.unflatten(treedef, leaves)
         record = _training_record(steps, rows)
-    train_stats.seqrec_fetch_bytes().inc(
-        sum(leaf.nbytes for leaf in jax.tree.leaves(host)))
+    train_stats.seqrec_fetch_bytes().inc(sum(leaf.nbytes for leaf in leaves))
     # one compiled step made every step of the train: one route a kind
     # of layer, one pattern of mixers
     train_stats.observe_seqrec_record(
@@ -1027,7 +1048,7 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
           for key in ("attention_pallas", "linear_attention_pallas")),
         {kind: int(n) for kind, n in steps[0]["mixer_layers"].items()}
         if steps else {})
-    return SeqRecModel(item_vocab=all_items, params=host, hyper=p,
+    return SeqRecModel(item_vocab=all_items, params=params, hyper=p,
                        record=record)
 
 

@@ -67,7 +67,8 @@ the weights), `seqrec_put` (sharding, the optimizer's state),
   held here whose output row is all zeros (read from the layer's
   output; expected 0).
 * ``pio_train_seqrec_fetch_bytes_total`` — bytes of weights fetched from
-  the device after training.
+  the device after training (their copies start in ``seqrec_fetch`` and
+  arrive under the release's write).
 """
 
 from __future__ import annotations

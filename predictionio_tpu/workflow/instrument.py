@@ -31,11 +31,13 @@ PERSIST_SECONDS_BUCKETS = exponential_buckets(0.001, 2.0, 16)
 
 
 def observe_persist(size: int, streamed: bool, write_seconds: float,
-                    hash_seconds: float) -> None:
+                    hash_seconds: float, device_bytes: int,
+                    fetch_wait_seconds: float) -> None:
     """One persisted release: its bytes, whether the pickler wrote them
     into the store's own file (the one-pass route) or into a buffer for a
-    row insert, and where the writing thread and the hash thread spent
-    their time."""
+    row insert, where the writing thread and the hash thread spent
+    their time, how many of the bytes reached the pickler as device
+    arrays and how long it waited for their host copies."""
     registry = default_registry()
     persist_bytes().inc(size)
     registry.counter(
@@ -53,6 +55,17 @@ def observe_persist(size: int, streamed: bool, write_seconds: float,
         "Time the digest thread spent in sha256 update, one sample a "
         "persist", buckets=PERSIST_SECONDS_BUCKETS
     ).observe(hash_seconds)
+    registry.counter(
+        "pio_train_persist_device_bytes_total",
+        "Bytes of the release that reached the pickler as device arrays, "
+        "of pio_train_persist_bytes_total"
+    ).inc(device_bytes)
+    registry.histogram(
+        "pio_train_persist_fetch_wait_seconds",
+        "Time the pickling thread waited for device arrays' host copies "
+        "still in flight, one sample a persist",
+        buckets=PERSIST_SECONDS_BUCKETS
+    ).observe(fetch_wait_seconds)
 
 
 @contextlib.contextmanager

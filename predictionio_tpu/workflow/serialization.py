@@ -2,8 +2,9 @@
 
 Replaces the reference's Kryo/chill model blob machinery
 (core/.../workflow/CoreWorkflow.scala:76-81, CreateServer.scala:62-76): every
-model is a picklable Python object; pytrees of jax Arrays are converted to
-numpy first so blobs are host-portable and loadable without devices.
+model is a picklable Python object. A ``jax.Array`` anywhere in a model is
+written as the numpy array of its value, so blobs are host-portable and
+load without devices, with numpy leaves.
 
 A release is written in one pass: ``dump_models`` pickles into whatever
 writable it is given (protocol 5 hands each array's buffer to ``write``
@@ -12,6 +13,13 @@ the model store's file, counting the bytes and feeding each buffer to a
 sha256 on a worker thread, so the digest of exactly the stored bytes is
 ready when the file closes and nobody reads the blob a second time.
 ``serialize_models`` is the same stream into memory.
+
+Nothing is pulled from a device up front. The pickler takes a device
+array's host copy when it meets the array in the object graph, waiting for
+that leaf alone, so a trainer that started its weights' copies
+(``copy_to_host_async``) has the first leaves on their way to the file
+while the rest are still in flight. The stream is the one the same model
+with numpy leaves gives, byte for byte.
 """
 
 from __future__ import annotations
@@ -20,9 +28,12 @@ import hashlib
 import io
 import pickle
 import queue
+import sys
 import threading
 import time
-from typing import Any, BinaryIO, List, Optional
+from typing import Any, BinaryIO, List, NamedTuple, Optional
+
+import numpy as np
 
 
 class _RetrainSentinel:
@@ -36,26 +47,43 @@ class _RetrainSentinel:
 RETRAIN_ON_DEPLOY = _RetrainSentinel()
 
 
-def _to_host(obj: Any) -> Any:
-    """Pull any jax arrays in a pytree down to numpy."""
-    try:
-        import jax
+class DeviceFetch(NamedTuple):
+    """What one dump took from devices."""
 
-        leaves, treedef = jax.tree.flatten(obj)
-        if any(isinstance(x, jax.Array) for x in leaves):
-            return jax.tree.unflatten(
-                treedef, [jax.device_get(x) if isinstance(x, jax.Array) else x
-                          for x in leaves])
-    except (ImportError, TypeError):
-        pass
-    return obj
+    #: bytes that reached the pickler as device arrays
+    device_bytes: int
+    #: time the pickling thread waited for host copies still in flight
+    wait_seconds: float
 
 
-def dump_models(models: List[Any], fileobj) -> None:
+class _ReleasePickler(pickle.Pickler):
+    """Protocol 5, and a ``jax.Array`` reduced as the numpy array of its
+    value is: a leaf's type decides the path, a model of host arrays
+    never takes it."""
+
+    def __init__(self, fileobj):
+        super().__init__(fileobj, protocol=pickle.HIGHEST_PROTOCOL)
+        self.device_bytes = 0
+        self.wait_seconds = 0.0
+
+    def reducer_override(self, obj):
+        # no jax in the process, no jax.Array in the model
+        jax = sys.modules.get("jax")
+        if jax is None or not isinstance(obj, jax.Array):
+            return NotImplemented
+        t0 = time.perf_counter()
+        host = np.asarray(obj)      # read-only: DigestingWriter's rule holds
+        self.wait_seconds += time.perf_counter() - t0
+        self.device_bytes += host.nbytes
+        return host.__reduce_ex__(pickle.HIGHEST_PROTOCOL)
+
+
+def dump_models(models: List[Any], fileobj) -> DeviceFetch:
     """Write the release's pickle stream to ``fileobj`` (anything with a
     ``write`` that takes bytes-like objects)."""
-    payload = [RETRAIN_ON_DEPLOY if m is None else _to_host(m) for m in models]
-    pickle.dump(payload, fileobj, protocol=pickle.HIGHEST_PROTOCOL)
+    pickler = _ReleasePickler(fileobj)
+    pickler.dump([RETRAIN_ON_DEPLOY if m is None else m for m in models])
+    return DeviceFetch(pickler.device_bytes, pickler.wait_seconds)
 
 
 def serialize_models(models: List[Any]) -> bytes:
@@ -86,8 +114,9 @@ class DigestingWriter:
 
     The hash thread sees each buffer after ``write`` was called with it,
     so whoever writes must not mutate a buffer it has handed over: the
-    pickler hands over its own finished frames and views of host arrays
-    nothing else touches during a persist. The queue is bounded, so the
+    pickler hands over its own finished frames, views of host arrays
+    nothing else touches during a persist, and views of device arrays'
+    host copies, which are read-only. The queue is bounded, so the
     writer never runs more than a few buffers ahead of the hash.
 
     ``close()`` (or leaving the ``with`` block) joins the thread; only

@@ -122,14 +122,16 @@ def _persist(instance_id: str, models) -> Tuple[str, int]:
     """Write the release in one pass: the pickler writes into the model
     store's writable (its own temporary file on a file store, a buffer
     for the row insert elsewhere) through a writer that takes the sha256
-    of the same bytes beside the write. Returns (digest, size) of exactly
-    what the store now holds; the blob is visible only once this returns."""
+    of the same bytes beside the write, taking each device array's host
+    copy as it reaches it. Returns (digest, size) of exactly what the
+    store now holds; the blob is visible only once this returns."""
     store = Storage.get_model_data_models()
     with store.open_write(instance_id) as f:
         with DigestingWriter(f) as out:
-            dump_models(models, out)
+            fetched = dump_models(models, out)
     observe_persist(out.size, store.streams_writes, out.write_seconds,
-                    out.hash_seconds)
+                    out.hash_seconds, fetched.device_bytes,
+                    fetched.wait_seconds)
     return out.hexdigest(), out.size
 
 

@@ -78,6 +78,81 @@ def test_sessionrec_train_and_predict(session_app):
     assert algo.predict(model, Query(items=["nope"], num=3)).item_scores == []
 
 
+@pytest.mark.parametrize("model_store", ["localfs", "sqlite"])
+def test_a_release_takes_its_weights_from_the_device(session_app, tmp_path,
+                                                     monkeypatch,
+                                                     model_store):
+    """`train_seqrec` hands `run_train` device arrays; the persist counts
+    every byte of them as fetched under its write, with one sample of the
+    wait; what the store holds is numpy; and once `run_train` has
+    returned nothing keeps the trained weights on the device."""
+    import weakref
+
+    import jax
+
+    from predictionio_tpu.engines import sessionrec
+    from predictionio_tpu.obs.registry import default_registry
+    from predictionio_tpu.workflow.serialization import deserialize_models
+
+    sources = {"DB": {"TYPE": "sqlite", "PATH": str(tmp_path / "t.db")},
+               "FS": {"TYPE": "localfs", "PATH": str(tmp_path / "models")}}
+    Storage.configure({"sources": sources, "repositories": {
+        "METADATA": {"NAME": "pio", "SOURCE": "DB"},
+        "EVENTDATA": {"NAME": "pio", "SOURCE": "DB"},
+        "MODELDATA": {"NAME": "pio", "SOURCE":
+                      "FS" if model_store == "localfs" else "DB"}}})
+    trained = {}
+    train_seqrec = sessionrec.train_seqrec
+
+    def train_and_note(*args, **kwargs):
+        model = train_seqrec(*args, **kwargs)
+        leaves = jax.tree.leaves(model.params)
+        assert all(isinstance(leaf, jax.Array) for leaf in leaves)
+        live = {id(a) for a in jax.live_arrays()}
+        assert all(id(leaf) in live for leaf in leaves)
+        trained["refs"] = [weakref.ref(leaf) for leaf in leaves]
+        trained["bytes"] = sum(leaf.nbytes for leaf in leaves)
+        return model
+
+    monkeypatch.setattr(sessionrec, "train_seqrec", train_and_note)
+    reg = default_registry()
+
+    def read(name, how):
+        metric = reg.get(name)
+        return how(metric) if metric is not None else 0
+
+    def series():
+        return {
+            "fetch_spans": read("pio_span_duration_seconds",
+                                lambda m: m.count(span="seqrec_fetch")),
+            "fetched": read("pio_train_seqrec_fetch_bytes_total",
+                            lambda m: m.value()),
+            "persisted": read("pio_train_persist_bytes_total",
+                              lambda m: m.value()),
+            "device": read("pio_train_persist_device_bytes_total",
+                           lambda m: m.value()),
+            "waits": read("pio_train_persist_fetch_wait_seconds",
+                          lambda m: m.count())}
+
+    before = series()
+    instance = run_train(
+        engine(), default_engine_params(
+            "SessApp", d_model=32, n_heads=2, n_layers=1, max_len=16,
+            epochs=1, batch_size=32))
+    gained = {k: v - before[k] for k, v in series().items()}
+    assert gained["fetch_spans"] == 1
+    assert gained["fetched"] == gained["device"] == trained["bytes"] > 0
+    assert gained["waits"] == 1
+    assert trained["bytes"] < gained["persisted"]
+    assert [ref() for ref in trained["refs"]] == [None] * len(trained["refs"])
+    blob = Storage.get_model_data_models().get(instance.id).models
+    params = deserialize_models(blob)[0].params
+    assert all(isinstance(leaf, np.ndarray)
+               for leaf in jax.tree.leaves(params))
+    assert sum(leaf.nbytes for leaf in jax.tree.leaves(params)) \
+        == trained["bytes"]
+
+
 def test_sessionrec_sharded_2d_mesh(session_app, mesh8):
     """Full train step over a 4 (data) x 2 (model) mesh."""
     import jax
@@ -98,6 +173,11 @@ def test_sessionrec_sharded_2d_mesh(session_app, mesh8):
     model = train_seqrec(mesh, td.sessions, params)
     recs = model.recommend_next(["i03", "i04", "i05"], 5)
     assert any(it == "i06" for it, _ in recs)
+    # the mesh still holds the trained weights; serving, a program for
+    # one device, took them whole onto one
+    assert len(model.params["emb"].sharding.device_set) == 8
+    assert all(len(w.sharding.device_set) == 1
+               for w in jax.tree.leaves(model._device_params()[0]))
 
 
 def test_sessionrec_eval_folds(session_app):

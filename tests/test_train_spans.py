@@ -487,6 +487,43 @@ def test_a_sequence_model_train_counts_its_attention_tokens_by_route():
         assert positions() - positions0 == trains * 2 * 2 * 2 * 8
 
 
+@pytest.mark.parametrize("shape", [None, (4, 2)], ids=["one_device", "mesh"])
+def test_a_sequence_model_train_starts_its_weights_copies_once(shape):
+    """`seqrec_fetch` is one span a train, around starting the weights'
+    copies and reading the steps' numbers; its counter gains the bytes of
+    the weights, which the model holds as the device's arrays (a mesh's
+    sharded ones among them) and numpy reads whole."""
+    import jax
+    from jax.sharding import Mesh
+
+    from predictionio_tpu.models import seqrec
+
+    mesh = None if shape is None else Mesh(
+        np.asarray(jax.devices()[:8]).reshape(shape), ("data", "model"))
+    p = seqrec.SeqRecParams(d_model=16, n_heads=2, n_layers=1, max_len=8,
+                            batch_size=4, epochs=1)
+    sessions = [[f"i{(s + j) % 11}" for j in range(6 + s % 3)]
+                for s in range(8)]
+    reg = default_registry()
+
+    def fetched():
+        c = reg.get("pio_train_seqrec_fetch_bytes_total")
+        return c.value() if c is not None else 0
+
+    bytes0 = fetched()
+    own = MetricsRegistry()
+    with tracing.adopt("job", registry=own) as trace:
+        model = seqrec.train_seqrec(mesh, sessions, p)
+    leaves = jax.tree.leaves(model.params)
+    assert span_count("seqrec_fetch", own) == 1
+    assert [s.name for s in trace.spans][-1] == "seqrec_fetch"
+    assert all(isinstance(leaf, jax.Array) for leaf in leaves)
+    assert fetched() - bytes0 == sum(leaf.nbytes for leaf in leaves) \
+        == reg.get("pio_train_seqrec_param_bytes").value()
+    assert len(model.record["loss"]) == 2
+    assert np.asarray(model.params["emb"]).shape == model.params["emb"].shape
+
+
 def test_a_step_says_which_route_its_attention_was_traced_on(monkeypatch):
     """The step's `attention_pallas` is what `blockwise_attention` chose
     at trace time, not what the spec would suggest: with the kernels'

@@ -176,6 +176,157 @@ def test_streamed_release_is_serialize_models_byte_for_byte(tmp_path, make):
     assert [m is None for m in got] == [m is None for m in models]
 
 
+# -- device leaves: fetched where the pickler meets them ----------------------
+
+def _seqrec_model(leaf):
+    """A sequence model whose weights `leaf` makes from numpy arrays."""
+    from predictionio_tpu.models.seqrec import SeqRecModel, SeqRecParams
+
+    rng = np.random.default_rng(34)
+    params = {"emb": rng.normal(size=(300, 64)).astype(np.float32),
+              "layers": [{"wq": rng.normal(size=(64, 64)).astype(np.float32),
+                          "bias": np.zeros(8, np.float32)}
+                         for _ in range(2)],
+              "head": rng.normal(size=(64, 300)).astype(np.float32)}
+    return SeqRecModel(
+        item_vocab=np.asarray([f"i{i}" for i in range(299)], dtype=object),
+        params={k: ([{n: leaf(w) for n, w in layer.items()} for layer in v]
+                    if isinstance(v, list) else leaf(v))
+                for k, v in params.items()},
+        hyper=SeqRecParams(d_model=64, n_heads=2), record={"loss": [1.5]})
+
+
+def _pytree_model(leaf):
+    return {"w": leaf(np.arange(40_000, dtype=np.float32).reshape(200, 200)),
+            "b": leaf(np.ones(8, np.float32)), "n": 3}
+
+
+def _on_device(array):
+    import jax.numpy as jnp
+
+    return jnp.asarray(array)
+
+
+def _as_read(array):
+    """numpy as a trainer hands over what it read from a device."""
+    return np.asarray(_on_device(array))
+
+
+def _by_dump_models(models, tmp_path):
+    import io
+
+    from predictionio_tpu.workflow.serialization import dump_models
+
+    buf = io.BytesIO()
+    fetched = dump_models(models, buf)
+    return buf.getvalue(), fetched.device_bytes
+
+
+def _by_serialize_models(models, tmp_path):
+    return serialize_models(models), None
+
+
+def _by_persist(models, tmp_path):
+    """`_persist` into a file store: (the stored bytes, the bytes the
+    device counter gained), the returned digest held to the bytes."""
+    import hashlib
+
+    from predictionio_tpu.obs.registry import default_registry
+    from predictionio_tpu.workflow.train import _persist
+
+    Storage.configure({
+        "sources": {"DB": {"TYPE": "sqlite", "PATH": str(tmp_path / "p.db")},
+                    "M": MODEL_STORES["localfs"](tmp_path)},
+        "repositories": {
+            "METADATA": {"NAME": "pio", "SOURCE": "DB"},
+            "EVENTDATA": {"NAME": "pio", "SOURCE": "DB"},
+            "MODELDATA": {"NAME": "pio", "SOURCE": "M"},
+        },
+    })
+    try:
+        before = _persist_series()["device"]
+        digest, size = _persist("m", models)
+        stored = Storage.get_model_data_models().get("m").models
+    finally:
+        Storage.reset()
+    assert (digest, size) == (hashlib.sha256(stored).hexdigest(), len(stored))
+    return stored, _persist_series()["device"] - before
+
+
+@pytest.mark.parametrize("route", [_by_dump_models, _by_serialize_models,
+                                   _by_persist])
+@pytest.mark.parametrize("make", [_seqrec_model, _pytree_model])
+def test_device_leaves_dump_to_the_host_leaves_stream(tmp_path, make, route):
+    """A model with `jax.Array` leaves is the byte stream, and so the
+    sha256, of the same model with numpy leaves, whichever way the
+    release is written; it loads with numpy leaves and no device; and
+    the device's share of it is counted."""
+    import jax
+
+    want, none_fetched = route([make(_as_read), None], tmp_path)
+    got, fetched = route([make(_on_device), None], tmp_path)
+    assert got == want
+    weights = [x for x in jax.tree.leaves(
+        getattr(make(_on_device), "params", make(_on_device)))
+        if isinstance(x, jax.Array)]
+    if fetched is not None:
+        assert none_fetched == 0
+        assert fetched == sum(x.nbytes for x in weights) > 0
+    model, retrain = deserialize_models(got)
+    assert retrain is None
+    leaves = jax.tree.leaves(getattr(model, "params", model))
+    assert sum(isinstance(x, np.ndarray) for x in leaves) == len(weights)
+    assert not any(isinstance(x, jax.Array) for x in leaves)
+
+
+@pytest.mark.parametrize("make", [
+    _als_model, _list_with_none,
+    lambda: [_seqrec_model(np.array), None],
+    lambda: [_seqrec_model(_as_read)]],
+    ids=["als", "list_with_none", "seqrec_writable", "seqrec_read_only"])
+def test_a_model_of_host_arrays_dumps_to_plain_pickle_bytes(make):
+    """No device array, no other path: the stream is what
+    `pickle.dumps` at the highest protocol makes of the payload (what
+    every release before device leaves was), and nothing was fetched."""
+    import io
+    import pickle
+
+    from predictionio_tpu.workflow.serialization import (
+        RETRAIN_ON_DEPLOY, dump_models,
+    )
+
+    models = make()
+    buf = io.BytesIO()
+    fetched = dump_models(models, buf)
+    assert buf.getvalue() == pickle.dumps(
+        [RETRAIN_ON_DEPLOY if m is None else m for m in models],
+        protocol=pickle.HIGHEST_PROTOCOL)
+    assert fetched == (0, 0.0)
+
+
+@pytest.mark.parametrize("dtype, shape", [
+    ("float32", (33, 5)), ("bfloat16", (33, 5)), ("int32", (7,)),
+    ("float32", ()), ("bfloat16", ()), ("float32", (0, 4))])
+def test_device_leaves_of_every_dtype_round_trip(dtype, shape):
+    """float32, bfloat16 and int32 leaves, a 0-d one and an empty one:
+    out of the blob each is numpy with its dtype, shape and values."""
+    import jax.numpy as jnp
+
+    values = (np.arange(int(np.prod(shape)), dtype=np.float32) - 3
+              ).reshape(shape)
+    leaf = jnp.asarray(values, dtype=dtype)
+    blob = serialize_models([{"leaf": leaf, "twice": [leaf, leaf]}])
+    host = np.asarray(leaf)
+    assert blob == serialize_models([{"leaf": host, "twice": [host, host]}])
+    out = deserialize_models(blob)[0]
+    for got in (out["leaf"], *out["twice"]):
+        assert isinstance(got, np.ndarray)
+        assert (got.dtype, got.shape) == (leaf.dtype, shape)
+        np.testing.assert_array_equal(got.astype(np.float32),
+                                      np.asarray(leaf, dtype=np.float32))
+    assert out["twice"][0] is out["twice"][1]    # one leaf, pickled once
+
+
 def test_digesting_writer_keeps_order_and_stops_its_thread():
     """Many writes of every kind the pickler makes, under a short switch
     interval: the digest is of the bytes in their order, and the hash
@@ -263,6 +414,10 @@ def _persist_series():
         "hashes": read("pio_train_persist_hash_seconds",
                        lambda m: m.count()),
         "hash_s": read("pio_train_persist_hash_seconds", lambda m: m.sum_()),
+        "device": read("pio_train_persist_device_bytes_total",
+                       lambda m: m.value()),
+        "waits": read("pio_train_persist_fetch_wait_seconds",
+                      lambda m: m.count()),
     }
 
 
@@ -290,6 +445,10 @@ def test_release_digest_and_size_are_of_the_stored_bytes(model_store):
     assert after["hashes"] - before["hashes"] == 1
     assert after["write_s"] > before["write_s"]
     assert after["hash_s"] > before["hash_s"]
+    # a model of host values: nothing came from a device, one sample all
+    # the same
+    assert after["device"] == before["device"]
+    assert after["waits"] - before["waits"] == 1
 
 
 class _SecondLeafBreaks:
@@ -297,18 +456,31 @@ class _SecondLeafBreaks:
         raise RuntimeError("boom at the second leaf")
 
 
-def test_failed_pickle_leaves_no_blob_no_release_and_init(model_store,
-                                                          tmp_path):
+def _deleted_device_leaf():
+    """A device array whose host copy raises when the pickler asks."""
+    import jax.numpy as jnp
+
+    leaf = jnp.ones(1000)
+    leaf.delete()
+    return leaf
+
+
+@pytest.mark.parametrize("second, message", [
+    (_SecondLeafBreaks, "second leaf"),
+    (_deleted_device_leaf, "deleted")])
+def test_failed_pickle_leaves_no_blob_no_release_and_init(
+        model_store, tmp_path, second, message):
     """A train that fails while pickling, after the first leaf is
     already in the store's temporary file, leaves nothing under the
-    model's name, the instance INIT and no release."""
+    model's name, the instance INIT and no release: a leaf whose
+    `__reduce__` raises, and a device leaf whose host copy does."""
     class TwoLeafAlgo(Algo0):
         def train(self, ctx, pd):
             return {"first": np.ones(100_000, dtype=np.float32),
-                    "second": _SecondLeafBreaks()}
+                    "second": second()}
 
     eng = Engine(DataSource0, Preparator0, {"a": TwoLeafAlgo}, Serving0)
-    with pytest.raises(RuntimeError, match="second leaf"):
+    with pytest.raises(RuntimeError, match=message):
         run_train(eng, ep())
     instances = Storage.get_meta_data_engine_instances().get_all()
     assert [i.status for i in instances] == ["INIT"]

@@ -967,8 +967,10 @@ def traces(ip, port, trace_id, limit, since, show_events, as_json):
                    "fresh temp dir).")
 def profile(ip, port, accesskey, seconds, outdir):
     """Capture a bounded on-demand device profile from a live query
-    server (POST /debug/profile): a jax.profiler trace plus the
-    per-compile-family dispatch-time attribution table."""
+    server (POST /debug/profile): a jax.profiler trace, the capture's
+    device seconds by compile family and `jax.named_scope` for the
+    programs that publish a scope table, and the host seconds spent
+    dispatching each compile family."""
     import urllib.error
     import urllib.request
 
@@ -995,10 +997,20 @@ def profile(ip, port, accesskey, seconds, outdir):
         sys.exit(1)
     click.echo(f"[INFO] Captured {out.get('seconds')}s device profile "
                f"-> {out.get('traceDir')}")
+    for family, by_scope in (out.get("scopes") or {}).items():
+        click.echo(f"[INFO] Device seconds of this capture by scope, "
+                   f"{family}:")
+        for scope, secs in sorted(by_scope.items(), key=lambda kv: -kv[1]):
+            click.echo(f"[INFO]   {scope or '(under no scope)':<24} "
+                       f"{secs:.6f}s")
+    if out.get("scopes"):
+        click.echo(f"[INFO]   {'(other programs)':<24} "
+                   f"{out.get('untabledSeconds', 0.0):.6f}s")
     dispatch = out.get("dispatch") or {}
     if dispatch:
-        click.echo("[INFO] Device seconds by compile family "
-                   "(cumulative since process start):")
+        click.echo("[INFO] Host seconds dispatching, by compile family "
+                   "(cumulative since process start; nothing waits for "
+                   "the device, so not device time):")
         for family, secs in dispatch.items():
             click.echo(f"[INFO]   {family:<24} {secs:.3f}s")
     else:

@@ -652,16 +652,18 @@ def make_train_fn(mesh: Mesh, data_dims, params: ALSParams):
     chunk = _make_chunk_core(mesh, data_dims, params, params.num_iterations)
 
     def train(by_user, by_item, key):
-        V = (jax.random.normal(key, (n_items_pad, k), jnp.float32)
-             / jnp.sqrt(jnp.asarray(k, jnp.float32)))
-        # padding item rows start (and stay) zero: random pad rows would
-        # pollute the implicit solvers' global V^T V Gramian — the full
-        # sweep zeroes them exactly on its first item solve, but block
-        # coordinate descent only decays them, and snapshot/resume
-        # truncates at n_items, so nonzero pads would make a resumed run
-        # diverge from the uninterrupted one
-        V = jnp.where((jnp.arange(n_items_pad) < n_items)[:, None], V, 0.0)
-        U0 = jnp.zeros((n_users_pad, k), jnp.float32)
+        with jax.named_scope("als_init"):
+            V = (jax.random.normal(key, (n_items_pad, k), jnp.float32)
+                 / jnp.sqrt(jnp.asarray(k, jnp.float32)))
+            # padding item rows start (and stay) zero: random pad rows
+            # would pollute the implicit solvers' global V^T V Gramian —
+            # the full sweep zeroes them exactly on its first item solve,
+            # but block coordinate descent only decays them, and
+            # snapshot/resume truncates at n_items, so nonzero pads would
+            # make a resumed run diverge from the uninterrupted one
+            V = jnp.where((jnp.arange(n_items_pad) < n_items)[:, None], V,
+                          0.0)
+            U0 = jnp.zeros((n_users_pad, k), jnp.float32)
         return chunk(by_user, by_item, U0, V)
 
     return jax.jit(train)
@@ -681,6 +683,10 @@ def make_chunk_fn(mesh: Mesh, data_dims, params: ALSParams, iters: int):
 #: solver that means one per (rank, block_size) family on fixed data, the
 #: bound the solver tests assert via `fn_cache.family_keys`
 TRAIN_FAMILY = "als_train"
+#: the `jax.named_scope`s of a half-sweep: the train program publishes
+#: which of its instructions belongs to which (`ops/fn_cache`), and a
+#: capture's device time reads by these names (`pio profile`)
+TRAIN_SCOPES = ("als_init", "als_gram", "als_reg", "als_solve")
 
 
 def _cached_train_fn(mesh: Mesh, data_dims, params: ALSParams,
@@ -709,7 +715,8 @@ def _cached_train_fn(mesh: Mesh, data_dims, params: ALSParams,
     key_params = (dataclasses.replace(params, block_size=0)
                   if params.solver == "full" else params)
     key = (data_dims, dataclasses.astuple(key_params), chunk_iters)
-    return mesh_cached_fn(TRAIN_FAMILY, mesh, key, build)
+    return mesh_cached_fn(TRAIN_FAMILY, mesh, key, build,
+                          scopes=TRAIN_SCOPES)
 
 
 def _process_shard_range(mesh: Mesh) -> Tuple[int, int]:

@@ -384,6 +384,17 @@ def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
     return params
 
 
+#: the `jax.named_scope`s of the train step, one a layer of the model or
+#: part of the step: the step's compiled program publishes which of its
+#: instructions belongs to which (`ops/fn_cache.mesh_cached_fn`), and a
+#: capture's device time reads by these names (`pio profile`)
+STEP_SCOPES = (
+    "seqrec_embed", "seqrec_norm", "seqrec_attention",
+    "seqrec_linear_attention", "seqrec_short_conv", "seqrec_router",
+    "seqrec_experts", "seqrec_shared_expert", "seqrec_ffn",
+    "seqrec_head_loss", "seqrec_optimizer", "seqrec_record")
+
+
 def _rms_norm(x, scale, eps):
     return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * scale
 
@@ -560,9 +571,11 @@ def _moe(layer, x, p: SeqRecParams):
 
         with jax.named_scope("seqrec_shared_expert"):
             y = y + _by_token_blocks(shared, p, flat)
-    stats = {"load": moe.expert_load(routing.experts, p.n_routed_experts),
-             "held_tokens": held_tokens, "dropped": dropped,
-             "balance": moe.sequence_balance_loss(routing, b)}
+    with jax.named_scope("seqrec_router"):
+        stats = {"load": moe.expert_load(routing.experts,
+                                         p.n_routed_experts),
+                 "held_tokens": held_tokens, "dropped": dropped,
+                 "balance": moe.sequence_balance_loss(routing, b)}
     return y.reshape(b, l, d), stats
 
 
@@ -572,9 +585,10 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
     """[B, L] int32 item ids (0 = pad) -> ([B, L, D] hidden states, the
     balance numbers of each expert layer, the layers run by mixer)."""
     b, l = seqs.shape
-    h = params["emb"][seqs]
-    if "pos" in params:
-        h = h + params["pos"][None, :l]
+    with jax.named_scope("seqrec_embed"):
+        h = params["emb"][seqs]
+        if "pos" in params:
+            h = h + params["pos"][None, :l]
     pad = (seqs == 0)[..., None]
     key_mask = seqs != 0       # left-padding sits in the causal PAST; the
     use_ring = (p.attention_impl == "ring" and mesh is not None
@@ -585,7 +599,8 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
     devices = 1 if mesh is None else mesh.size
 
     def block(h, layer, mixer, kind):
-        x = _norm(h, layer["ln1"], p)
+        with jax.named_scope("seqrec_norm"):
+            x = _norm(h, layer["ln1"], p)
         if mixer == "conv":
             with jax.named_scope("seqrec_short_conv"):
                 h = h + _short_conv(layer, x, key_mask)
@@ -596,13 +611,15 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
             with jax.named_scope("seqrec_attention"):
                 h = h + _attention(layer, x, key_mask, p, mixer, mesh,
                                    use_ring)
-        x = _norm(h, layer["ln2"], p)
+        with jax.named_scope("seqrec_norm"):
+            x = _norm(h, layer["ln2"], p)
         if kind == "moe":
             y, stats = _moe(layer, x, p)
             return h + y, stats
         fn = (lambda t: jax.nn.gelu(t @ layer["w1"]) @ layer["w2"]) \
             if kind == "gelu" else (lambda t: _swiglu(layer, t))
-        y = _by_token_blocks(fn, p, x.reshape(b * l, -1))
+        with jax.named_scope("seqrec_ffn"):
+            y = _by_token_blocks(fn, p, x.reshape(b * l, -1))
         return h + y.reshape(b, l, -1), None
 
     if p.remat:
@@ -614,8 +631,9 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
         mixers[mixer] = mixers.get(mixer, 0) + 1
         if stats is not None:
             expert_layers.append(stats)
-    return (jnp.where(pad, 0.0, _norm(h, params["ln_f"], p)), expert_layers,
-            mixers)
+    with jax.named_scope("seqrec_norm"):
+        h = _norm(h, params["ln_f"], p)
+    return jnp.where(pad, 0.0, h), expert_layers, mixers
 
 
 def forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
@@ -730,8 +748,11 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
         with routes_into(routes), linear_attention.routes_into(rule_routes):
             (loss, (expert_layers, mixers)), grads = jax.value_and_grad(
                 _loss_fn, has_aux=True)(params, seqs, targets, p, mesh)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        stats = {"loss": loss, "grad_norm": _group_norms(grads),
+        with jax.named_scope("seqrec_optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("seqrec_record"):
+            grad_norm = _group_norms(grads)
+        stats = {"loss": loss, "grad_norm": grad_norm,
                  "mixer_layers": mixers,
                  "attention_pallas": jnp.asarray(routes == {"pallas"}),
                  "linear_attention_pallas": jnp.asarray(
@@ -740,15 +761,19 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
             # a selection bias is moved by its layer's load, not by adamw
             moe_layers = [layer for i, layer in enumerate(updates["layers"])
                           if p.ffn_kind(i) == "moe"]
-            for layer, s in zip(moe_layers, expert_layers):
-                layer["router_bias"] = moe.bias_update(
-                    jnp.zeros_like(layer["router_bias"]), s["load"],
-                    p.bias_update_rate)
-            stats.update({
-                key: jnp.stack([s[key] for s in expert_layers])
-                for key in ("load", "held_tokens", "dropped")})
-        stats["update_norm"] = _group_norms(updates)
-        params = jax.tree.map(lambda w, u: w + u, params, updates)
+            with jax.named_scope("seqrec_optimizer"):
+                for layer, s in zip(moe_layers, expert_layers):
+                    layer["router_bias"] = moe.bias_update(
+                        jnp.zeros_like(layer["router_bias"]), s["load"],
+                        p.bias_update_rate)
+            with jax.named_scope("seqrec_record"):
+                stats.update({
+                    key: jnp.stack([s[key] for s in expert_layers])
+                    for key in ("load", "held_tokens", "dropped")})
+        with jax.named_scope("seqrec_record"):
+            stats["update_norm"] = _group_norms(updates)
+        with jax.named_scope("seqrec_optimizer"):
+            params = jax.tree.map(lambda w, u: w + u, params, updates)
         return params, opt_state, stats
 
     return jax.jit(step, donate_argnums=(0, 1))
@@ -976,7 +1001,8 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
 
     step = mesh_cached_fn(
         "seqrec_train_step", mesh, p.spec_key(),
-        lambda: make_train_step(mesh, p, make_optimizer(p)))
+        lambda: make_train_step(mesh, p, make_optimizer(p)),
+        scopes=STEP_SCOPES)
 
     n = len(inputs)
     bs = min(p.batch_size, n)

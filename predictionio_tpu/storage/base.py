@@ -338,9 +338,12 @@ class Models(abc.ABC):
         """A binary writable for `model_id`'s blob. On a clean exit the
         blob is in the store, whole, as after `insert`; on an exception
         nothing of it is visible and the previous blob, if any, stays."""
+        from predictionio_tpu.obs.tracing import span
+
         buf = io.BytesIO()
         yield buf
-        self.insert(Model(id=model_id, models=buf.getvalue()))
+        with span("persist_commit"):
+            self.insert(Model(id=model_id, models=buf.getvalue()))
 
     @abc.abstractmethod
     def get(self, model_id: str) -> Optional[Model]: ...

@@ -44,12 +44,15 @@ class FSModels(base.Models):
         # either the old blob or the new one, never a torn half-write.
         # The temp name stays inside the store root (same fs, same dir)
         # so the final mv is a metadata move, not a copy.
+        from predictionio_tpu.obs.tracing import span
+
         path = self._path(model_id)
         tmp = f"{path}.tmp-{uuid.uuid4().hex}"
         try:
             with self.fs.open(tmp, "wb") as f:
                 yield f
-            self.fs.mv(tmp, path)
+            with span("persist_commit"):
+                self.fs.mv(tmp, path)
         except BaseException:
             try:
                 if self.fs.exists(tmp):

@@ -44,11 +44,19 @@ def enable_compile_cache() -> str:
     less than the compile it saves.
     """
     _set_jax_option("jax_persistent_cache_min_compile_time_secs", 0)
-    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if env_dir:
-        return env_dir
-    _set_jax_option("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
-    return DEFAULT_COMPILE_CACHE_DIR
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        _set_jax_option("jax_compilation_cache_dir",
+                        DEFAULT_COMPILE_CACHE_DIR)
+    return compile_cache_dir()
+
+
+def compile_cache_dir() -> str:
+    """Where the persistent compilation cache lies, or would lie, for
+    this process: `enable_compile_cache`'s answer without turning the
+    cache on. What the program writes beside its compiled programs
+    (`obs/profiler`'s scope tables) goes under it."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+        or DEFAULT_COMPILE_CACHE_DIR
 
 
 def on_tpu() -> bool:

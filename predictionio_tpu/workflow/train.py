@@ -87,8 +87,9 @@ def run_train(engine: Engine,
 
             with span("train_persist"):
                 if wp.save_model:
-                    persisted = engine.persist_models(ctx, instance_id,
-                                                      result)
+                    with span("persist_models"):
+                        persisted = engine.persist_models(ctx, instance_id,
+                                                          result)
                     model_digest, model_size = _persist(instance_id,
                                                         persisted)
                     logger.info("models saved (%d bytes) for instance %s",
@@ -96,7 +97,8 @@ def run_train(engine: Engine,
 
                 instance.status = "COMPLETED"
                 instance.end_time = _dt.datetime.now(tz=UTC)
-                instances.update(instance)
+                with span("persist_instance_update"):
+                    instances.update(instance)
         record_event("train_completed", {
             "instance": instance_id, "variant": engine_variant})
 
@@ -124,11 +126,25 @@ def _persist(instance_id: str, models) -> Tuple[str, int]:
     for the row insert elsewhere) through a writer that takes the sha256
     of the same bytes beside the write, taking each device array's host
     copy as it reaches it. Returns (digest, size) of exactly what the
-    store now holds; the blob is visible only once this returns."""
+    store now holds; the blob is visible only once this returns.
+
+    Its parts are spans of their own, so that each train shows them
+    (`persist_dump`: the pickler and the write; `persist_close`: the hash
+    thread's join and, where the store handed out its own file, that
+    file's close, the flush to the mount; `persist_commit`, in the
+    store: the rename or the row's insert)."""
+    from predictionio_tpu.obs.tracing import span
+
     store = Storage.get_model_data_models()
     with store.open_write(instance_id) as f:
         with DigestingWriter(f) as out:
-            fetched = dump_models(models, out)
+            with span("persist_dump"):
+                fetched = dump_models(models, out)
+            with span("persist_close"):
+                out.close()
+                if store.streams_writes:
+                    # the store closes it again on its way out: a no-op
+                    f.close()
     observe_persist(out.size, store.streams_writes, out.write_seconds,
                     out.hash_seconds, fetched.device_bytes,
                     fetched.wait_seconds)

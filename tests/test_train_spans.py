@@ -25,6 +25,11 @@ TRAIN_SPANS = {
     "als_solve": "train_algorithm",
     "als_fetch": "train_algorithm",
     "train_persist": None,
+    "persist_models": "train_persist",
+    "persist_dump": "train_persist",
+    "persist_close": "train_persist",
+    "persist_commit": "train_persist",
+    "persist_instance_update": "train_persist",
     "train_release": None,
 }
 

@@ -546,10 +546,11 @@ def _attention(layer, x, key_mask, p: SeqRecParams, kind, mesh, use_ring):
     return att @ layer["wo"]
 
 
-def _moe(layer, x, p: SeqRecParams):
+def _moe(layer, x, p: SeqRecParams, devices: int = 1):
     """The expert layer on normed x [B, L, D] -> ([B, L, D], balance
-    numbers). Every position is routed, padding too (its output is cut at
-    the end of the forward pass)."""
+    numbers), in a program traced for `devices` devices. Every position
+    is routed, padding too (its output is cut at the end of the forward
+    pass)."""
     b, l, d = x.shape
     flat = x.reshape(b * l, d)
     with jax.named_scope("seqrec_router"):
@@ -561,7 +562,7 @@ def _moe(layer, x, p: SeqRecParams):
         ex = layer["experts"]
         y, held_tokens, dropped = moe.held_experts(
             flat, ex["w_gate"], ex["w_up"], ex["w_down"], routing,
-            p.held_experts[0], pass_rows=b * l)
+            p.held_experts[0], pass_rows=b * l, devices=devices)
     if "shared" in layer:
         def shared(t):
             out = _swiglu(layer["shared"], t)
@@ -614,7 +615,7 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
         with jax.named_scope("seqrec_norm"):
             x = _norm(h, layer["ln2"], p)
         if kind == "moe":
-            y, stats = _moe(layer, x, p)
+            y, stats = _moe(layer, x, p, devices)
             return h + y, stats
         fn = (lambda t: jax.nn.gelu(t @ layer["w1"]) @ layer["w2"]) \
             if kind == "gelu" else (lambda t: _swiglu(layer, t))
@@ -725,12 +726,14 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
     """One donated jitted step -> (params, opt_state, the step's numbers:
     loss, by group the gradient's norm and the norm of what the step
     added to the parameters, and per expert layer the tokens routed to
-    each expert, to each held expert, and dropped; three constants of
+    each expert, to each held expert, and dropped; four constants of
     the trace: `mixer_layers`, the layers it ran by mixer,
     `attention_pallas`, whether `blockwise_attention` folded every
-    softmax-attention layer's blocks with the Pallas kernels, and
+    softmax-attention layer's blocks with the Pallas kernels,
     `linear_attention_pallas`, whether `gated_delta_rule` ran every
-    linear-attention layer's recurrence as Pallas kernels). With a
+    linear-attention layer's recurrence as Pallas kernels, and
+    `expert_product_pallas`, whether `held_experts` multiplied every
+    expert layer's groups with the Pallas kernels). With a
     mesh, batch is
     sharded over "data" and embedding/ffn rows over "model"; XLA inserts
     the psums."""
@@ -744,8 +747,9 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
             sh = NamedSharding(mesh, P("data", seq_dim))
             seqs = jax.lax.with_sharding_constraint(seqs, sh)
             targets = jax.lax.with_sharding_constraint(targets, sh)
-        routes, rule_routes = set(), set()
-        with routes_into(routes), linear_attention.routes_into(rule_routes):
+        routes, rule_routes, product_routes = set(), set(), set()
+        with routes_into(routes), linear_attention.routes_into(rule_routes), \
+                moe.routes_into(product_routes):
             (loss, (expert_layers, mixers)), grads = jax.value_and_grad(
                 _loss_fn, has_aux=True)(params, seqs, targets, p, mesh)
         with jax.named_scope("seqrec_optimizer"):
@@ -756,7 +760,9 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
                  "mixer_layers": mixers,
                  "attention_pallas": jnp.asarray(routes == {"pallas"}),
                  "linear_attention_pallas": jnp.asarray(
-                     rule_routes == {"pallas"})}
+                     rule_routes == {"pallas"}),
+                 "expert_product_pallas": jnp.asarray(
+                     product_routes == {"pallas"})}
         if expert_layers:
             # a selection bias is moved by its layer's load, not by adamw
             moe_layers = [layer for i, layer in enumerate(updates["layers"])
@@ -1071,7 +1077,8 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
     train_stats.observe_seqrec_record(
         record, targets, rows,
         *("pallas" if steps and steps[0][key] else "xla"
-          for key in ("attention_pallas", "linear_attention_pallas")),
+          for key in ("attention_pallas", "linear_attention_pallas",
+                      "expert_product_pallas")),
         {kind: int(n) for kind, n in steps[0]["mixer_layers"].items()}
         if steps else {})
     return SeqRecModel(item_vocab=all_items, params=params, hyper=p,

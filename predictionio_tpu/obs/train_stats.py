@@ -205,6 +205,14 @@ def seqrec_expert_tokens(registry: MetricsRegistry = None):
         labelnames=("layer",))
 
 
+def seqrec_expert_product_tokens(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_expert_product_tokens_total",
+        "Routed slots the experts held here multiplied, by the route "
+        "their step's grouped products were traced on "
+        "(ops/moe.grouped_product_route)", labelnames=("impl",))
+
+
 def seqrec_expert_load_ratio(registry: MetricsRegistry = None):
     return (registry or default_registry()).histogram(
         "pio_train_seqrec_expert_load_max_over_mean",
@@ -227,6 +235,7 @@ def seqrec_fetch_bytes(registry: MetricsRegistry = None):
 
 def observe_seqrec_record(record: dict, targets, rows,
                           attention_impl: str, linear_attention_impl: str,
+                          expert_product_impl: str,
                           mixer_layers: dict) -> None:
     """The token and expert counters from one train's record
     (models/seqrec.train_seqrec): `targets` the padded target ids of all
@@ -255,4 +264,6 @@ def observe_seqrec_record(record: dict, targets, rows,
     held = np.asarray(record["held_tokens"]).sum(axis=(0, 2))
     for layer, tokens in enumerate(held.tolist()):
         seqrec_expert_tokens().inc(tokens, layer=str(layer))
+    seqrec_expert_product_tokens().inc(int(held.sum()),
+                                       impl=expert_product_impl)
     seqrec_dropped_tokens().inc(int(np.asarray(record["dropped"]).sum()))

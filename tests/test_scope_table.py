@@ -367,12 +367,15 @@ def test_an_als_train_publishes_its_table_under_a_mesh(mesh8):
     data = als.ALSData.build(
         rng.integers(0, 160, n), rng.integers(0, 96, n),
         rng.integers(1, 6, n).astype(np.float32), 160, 96, n_shards=8)
-    before = len(tables_of(als.TRAIN_FAMILY))
+    before = tables_of(als.TRAIN_FAMILY)
     als.train_als(mesh8, data, als.ALSParams(rank=8, num_iterations=2,
                                              seed=35))
     tables = tables_of(als.TRAIN_FAMILY)
-    assert len(tables) == before + 1
+    # one more; a process that has trained eight such programs already
+    # (other test files, the same worker) drops its oldest for it
+    assert len(tables) == min(len(before) + 1, profiler.MAX_TABLES_PER_FAMILY)
     table = tables[-1]
+    assert all(table is not old for old in before)
     assert table["compiled"] == 0 and table["module"] == "jit_train"
     scopes = {scope for scope, _ in table["instructions"].values()}
     assert {"als_init", "als_gram", "als_solve"} <= scopes

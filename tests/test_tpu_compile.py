@@ -162,11 +162,12 @@ def test_the_hybrid_cells_step_fits_a_v5e_with_all_heads_at_once(
     import os
 
     from predictionio_tpu.models import seqrec
-    from predictionio_tpu.ops import attention, linear_attention
+    from predictionio_tpu.ops import attention, linear_attention, moe
 
     kind = chips[0].device_kind
     monkeypatch.setattr(attention, "_device_kind", lambda: kind)
     monkeypatch.setattr(linear_attention, "_device_kind", lambda: kind)
+    monkeypatch.setattr(moe, "_device_kind", lambda: kind)
     with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
                            "configs",
                            "seqrec-qwen3-next-80b-a3b-ep16.json")) as f:
@@ -188,6 +189,9 @@ def test_the_hybrid_cells_step_fits_a_v5e_with_all_heads_at_once(
     assert _kernel_calls(text, "gated_delta_rule_pallas_fwd") == 2 * 3
     assert _kernel_calls(text, "gated_delta_rule_pallas_bwd") == 3
     assert _kernel_calls(text, "flash_attention_pallas_bwd") == 1
+    # four expert layers, twelve grouped products each
+    assert _kernel_calls(text, "grouped_product_pallas_[a-z_]*") == 4 * 12
+    assert "ragged-dot" not in text
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert held <= 15.75 * 2 ** 30 - 1e9, held
@@ -246,10 +250,11 @@ def test_the_conv_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
     import os
 
     from predictionio_tpu.models import seqrec
-    from predictionio_tpu.ops import attention
+    from predictionio_tpu.ops import attention, moe
 
     kind = chips[0].device_kind
     monkeypatch.setattr(attention, "_device_kind", lambda: kind)
+    monkeypatch.setattr(moe, "_device_kind", lambda: kind)
     with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
                            "configs", "seqrec-lfm2-24b-a2b-ep8.json")) as f:
         cfg = json.load(f)
@@ -270,6 +275,54 @@ def test_the_conv_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
     text = compiled.as_text()
     assert _kernel_calls(text, "flash_attention_pallas_fwd") == 2
     assert _kernel_calls(text, "flash_attention_pallas_bwd") == 1
+    assert _kernel_calls(text, "grouped_product_pallas_[a-z_]*") == 4 * 12
+    assert "ragged-dot" not in text
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert held <= 15.75 * 2 ** 30 - 1e9, held
+
+
+@pytest.mark.parametrize("name,tokens,k,d,w,held,devices,kernels", [
+    # a pass of each sequence cell's expert layers, forward and backward:
+    # kimivl-a3b-ep8.train, lfm2-a2b-ep8.train, qwen3next-a3b-ep16.train
+    ("kimi", 16384, 6, 2048, 1408, 8, 1, True),
+    ("lfm2", 32768, 4, 2048, 1536, 8, 1, True),
+    ("qwen", 16384, 10, 2048, 512, 32, 1, True),
+    # a program for four devices keeps `ragged_dot`
+    ("kimi-on-a-mesh", 16384, 6, 2048, 1408, 8, 4, False),
+])
+def test_grouped_product_kernels_compile_for_v5e(
+        chips, one_chip, monkeypatch, name, tokens, k, d, w, held, devices,
+        kernels):
+    """`held_experts` and its backward pass at a cell's sizes: the three
+    kernels at `tiles`' row tile by a whole matrix (float32 in two
+    buffers, its rounded copy beside it) within the VMEM limit; twelve
+    products a pass and no `ragged-dot`."""
+    from predictionio_tpu.ops import moe, moe_pallas
+
+    monkeypatch.setattr(moe, "_device_kind", lambda: chips[0].device_kind)
+    assert moe_pallas.tiles(tokens, d, w, held) is not None
+
+    def loss(x, w_gate, w_up, w_down, experts, gates):
+        y, _, _ = moe.held_experts(
+            x, w_gate, w_up, w_down, moe.Routing(experts, gates, None), 0,
+            tokens, devices)
+        return (y * y).sum()
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 5))).lower(
+        shape(tokens, d), shape(held, d, w), shape(held, d, w),
+        shape(held, w, d), shape(tokens, k, dtype=jnp.int32),
+        shape(tokens, k)).compile()
+    text = compiled.as_text()
+    calls = {kernel: _kernel_calls(text, f"grouped_product_pallas_{kernel}")
+             for kernel in ("rows", "rows_t", "groups")}
+    if kernels:
+        assert calls == {"rows": 6, "rows_t": 3, "groups": 3}
+        assert "ragged-dot" not in text
+    else:
+        assert not any(calls.values()) and "ragged-dot" in text
+    for grad in compiled.out_info:
+        assert grad.dtype == jnp.float32

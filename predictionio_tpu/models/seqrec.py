@@ -21,6 +21,8 @@ TPU-native design:
 from __future__ import annotations
 
 import dataclasses
+import functools
+import operator
 import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
@@ -138,6 +140,19 @@ class SeqRecParams(Params):
     bias_update_rate: float = 0.0
     #: coefficient of the sequence-wise balance loss, summed over layers
     balance_loss_alpha: float = 0.0
+    #: the layer stack runs this many times a step over the same weights,
+    #: the last norm applied after every pass and its output fed on
+    n_loops: int = 1
+    #: a second norm on each sub-layer's OUTPUT before it joins the
+    #: residual: h + norm(mixer(norm(h))) (sandwich norms)
+    post_norm: bool = False
+    #: an exit gate after each pass (a sigmoid of one column of the
+    #: pass's hidden state and a bias) and the loss an expectation over
+    #: the passes' exits: per target sum_r p_r CE_r - exit_entropy_beta
+    #: H(p), p_r = gate_r prod_(j<r) (1 - gate_j), the last pass taking
+    #: what is left. Serving answers from the last pass
+    exit_gate: bool = False
+    exit_entropy_beta: float = 0.0
 
     #: draw the initial weights on the device (jax.random) instead of on
     #: the host in numpy: the same seed gives the same weights either way,
@@ -232,6 +247,13 @@ class SeqRecParams(Params):
         if self.shared_expert_gate and not (self.ffn == "moe"
                                             and self.n_shared_experts):
             raise ValueError("shared_expert_gate without a shared expert")
+        if self.n_loops < 1:
+            raise ValueError(f"n_loops must be >= 1: {self.n_loops}")
+        if self.exit_gate and self.n_loops == 1:
+            raise ValueError("exit_gate without a second pass to leave "
+                             "before (n_loops 1)")
+        if self.post_norm and self.norm == "layer":
+            raise ValueError("post_norm does not go with norm 'layer'")
         if self.ffn == "moe":
             lo, hi = self.held_experts
             if not 0 <= lo < hi <= self.n_routed_experts:
@@ -271,6 +293,16 @@ TOKEN_BLOCK = 2048
 #: and 8.25 GiB of temporaries (10.97 with all 16 at once) beside 6.99
 #: GiB of weights and moments, and 8 leaves a 16 GB chip under 0.2 GB.
 LINEAR_KEY_HEADS = 4
+#: passes of a looped stack (`n_loops`) the compiled step holds side by
+#: side: 1 is a `while` loop around one copy of the stack, True the stack
+#: written out a pass after another. A constant, from one chip run each
+#: at 8,192 positions through six layers of width 2048 four times
+#: (PERF.md section 6, PR 38): a step took 1.224 s as a loop and 1.235 s
+#: written out, a process's first step 34.7 s against 62.2 s, and the
+#: allocator reserved 6.14 GB of temporaries against 9.52 GB (a weight's
+#: gradient adds up in the loop's carry; written out, the passes' four
+#: parts of it are alive at once).
+LOOP_UNROLL = 1
 
 
 def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
@@ -371,9 +403,12 @@ def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
             out["shared_gate"] = dense(d, 1)
         return out
 
+    def norms():            # a leaf of its own each: a step donates them
+        names = ("ln1", "ln2") + (("post1", "post2") if p.post_norm else ())
+        return {name: norm() for name in names}
+
     # draws in this order: the host path's are the original block's
-    layers = [{"ln1": norm(), "ln2": norm(), **mixer(i), **ffn(i)}
-              for i in range(p.n_layers)]
+    layers = [{**norms(), **mixer(i), **ffn(i)} for i in range(p.n_layers)]
     params = {"emb": normal((v, d), d ** -0.5)}
     if p.positions == "learned":
         params["pos"] = normal((p.max_len, d), d ** -0.5)
@@ -381,6 +416,9 @@ def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
     params["layers"] = layers
     if not p.tied_head:
         params["head"] = dense(d, v)
+    if p.exit_gate:
+        params["exit_gate"] = {"w": jnp.zeros((d,), jnp.float32),
+                               "b": jnp.zeros((), jnp.float32)}
     return params
 
 
@@ -582,9 +620,12 @@ def _moe(layer, x, p: SeqRecParams, devices: int = 1):
 
 def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
              mesh: Optional[Mesh] = None
-             ) -> Tuple[jax.Array, List[Dict], Dict[str, int]]:
-    """[B, L] int32 item ids (0 = pad) -> ([B, L, D] hidden states, the
-    balance numbers of each expert layer, the layers run by mixer)."""
+             ) -> Tuple[Sequence[jax.Array], List[Dict], Dict[str, int]]:
+    """[B, L] int32 item ids (0 = pad) -> (the [B, L, D] hidden states of
+    each pass of the stack, the last norm's output, the last pass last
+    (one entry at `n_loops` 1, a [n_loops, B, L, D] array otherwise), the
+    balance numbers of each expert layer run, pass by pass, the layer
+    passes run by mixer)."""
     b, l = seqs.shape
     with jax.named_scope("seqrec_embed"):
         h = params["emb"][seqs]
@@ -599,42 +640,72 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
                          '"seq" axis')
     devices = 1 if mesh is None else mesh.size
 
+    def joined(h, y, layer, name):
+        """The residual h + y, y through its own norm first under
+        `post_norm`."""
+        if name in layer:
+            with jax.named_scope("seqrec_norm"):
+                y = _norm(y, layer[name], p)
+        return h + y
+
     def block(h, layer, mixer, kind):
         with jax.named_scope("seqrec_norm"):
             x = _norm(h, layer["ln1"], p)
         if mixer == "conv":
             with jax.named_scope("seqrec_short_conv"):
-                h = h + _short_conv(layer, x, key_mask)
+                h = joined(h, _short_conv(layer, x, key_mask), layer, "post1")
         elif mixer == "gdn":
             with jax.named_scope("seqrec_linear_attention"):
-                h = h + _linear_attention(layer, x, key_mask, p, devices)
+                h = joined(h, _linear_attention(layer, x, key_mask, p,
+                                                devices), layer, "post1")
         else:                      # key mask keeps it out of the softmax
             with jax.named_scope("seqrec_attention"):
-                h = h + _attention(layer, x, key_mask, p, mixer, mesh,
-                                   use_ring)
+                h = joined(h, _attention(layer, x, key_mask, p, mixer, mesh,
+                                         use_ring), layer, "post1")
         with jax.named_scope("seqrec_norm"):
             x = _norm(h, layer["ln2"], p)
         if kind == "moe":
             y, stats = _moe(layer, x, p, devices)
-            return h + y, stats
+            return joined(h, y, layer, "post2"), stats
         fn = (lambda t: jax.nn.gelu(t @ layer["w1"]) @ layer["w2"]) \
             if kind == "gelu" else (lambda t: _swiglu(layer, t))
         with jax.named_scope("seqrec_ffn"):
             y = _by_token_blocks(fn, p, x.reshape(b * l, -1))
-        return h + y.reshape(b, l, -1), None
+        return joined(h, y.reshape(b, l, -1), layer, "post2"), None
 
     if p.remat:
         block = jax.checkpoint(block, static_argnums=(2, 3))
-    expert_layers, mixers = [], {}
-    for i, layer in enumerate(params["layers"]):
-        mixer = p.mixer_kind(i)
-        h, stats = block(h, layer, mixer, p.ffn_kind(i))
-        mixers[mixer] = mixers.get(mixer, 0) + 1
-        if stats is not None:
-            expert_layers.append(stats)
-    with jax.named_scope("seqrec_norm"):
-        h = _norm(h, params["ln_f"], p)
-    return jnp.where(pad, 0.0, h), expert_layers, mixers
+
+    def stack(h):
+        """One pass: every layer, then the last norm."""
+        expert_layers = []
+        for i, layer in enumerate(params["layers"]):
+            h, stats = block(h, layer, p.mixer_kind(i), p.ffn_kind(i))
+            if stats is not None:
+                expert_layers.append(stats)
+        with jax.named_scope("seqrec_norm"):
+            h = _norm(h, params["ln_f"], p)
+        return h, expert_layers
+
+    mixers: Dict[str, int] = {}
+    for mixer in p.mixer_kinds():
+        mixers[mixer] = mixers.get(mixer, 0) + p.n_loops
+    if p.n_loops == 1:
+        h, expert_layers = stack(h)
+        return (jnp.where(pad, 0.0, h),), expert_layers, mixers
+
+    def one_pass(h, _):
+        # the weights are the body's constants: the program holds the
+        # stack once, and the scan's backward pass adds a weight's
+        # gradient up over the passes
+        h, expert_layers = stack(h)
+        return h, (jnp.where(pad, 0.0, h), expert_layers)
+
+    _, (passes, by_pass) = jax.lax.scan(one_pass, h, None, length=p.n_loops,
+                                        unroll=LOOP_UNROLL)
+    expert_layers = [jax.tree.map(lambda t: t[r], stats)
+                     for r in range(p.n_loops) for stats in by_pass]
+    return passes, expert_layers, mixers
 
 
 def forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
@@ -644,8 +715,10 @@ def forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
     attention_impl="ring" + a mesh with a "seq" axis runs the attention
     sequence-parallel (ring_attention_traced): each device holds L/p of
     the sequence and K/V blocks rotate via ppermute — exact, O(L/p) HBM
-    per device."""
-    return _forward(params, seqs, p, mesh)[0]
+    per device. Under `n_loops` the last pass's states: a position
+    leaves at the first pass whose cumulative exit probability reaches
+    the threshold, and at a threshold of 1 that is the last."""
+    return _forward(params, seqs, p, mesh)[0][-1]
 
 
 def head_matrix(params: Dict) -> jax.Array:
@@ -653,11 +726,25 @@ def head_matrix(params: Dict) -> jax.Array:
     return params["head"] if "head" in params else params["emb"].T
 
 
+def exit_distribution(z: jax.Array) -> jax.Array:
+    """Gate logits z [R, ...] of the R passes -> log p [R, ...], the
+    distribution over the pass a position leaves at: p_r = s(z_r)
+    prod_(j<r) (1 - s(z_j)) and the last pass what is left, prod_(j<R)
+    (1 - s(z_j)); its own gate is not read."""
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-z[:-1]), axis=0)
+    before = jnp.concatenate([jnp.zeros_like(z[:1]), stay[:-1]])
+    return jnp.concatenate([jax.nn.log_sigmoid(z[:-1]) + before, stay[-1:]])
+
+
 def _loss_fn(params, seqs, targets, p: SeqRecParams, mesh=None):
     """Next-item softmax cross-entropy, pad-masked, plus the expert
-    layers' balance loss. -> (loss, (the expert layers' balance numbers,
-    the layers run by mixer))."""
-    hidden, expert_layers, mixers = _forward(params, seqs, p, mesh)
+    layers' balance loss; under `exit_gate` the cross-entropy of every
+    pass weighed by the probability of leaving there, less
+    `exit_entropy_beta` times that distribution's entropy. -> (loss,
+    (the expert layers' balance numbers, the layer passes run by mixer,
+    under `exit_gate` each pass's own loss `loop_loss` [R] and its mean
+    exit probability `exit_share` [R])."""
+    passes, expert_layers, mixers = _forward(params, seqs, p, mesh)
     head = head_matrix(params)
 
     def nll_of(hid, tgt):
@@ -666,16 +753,38 @@ def _loss_fn(params, seqs, targets, p: SeqRecParams, mesh=None):
         nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
         return nll * (tgt > 0)
 
+    exits = {}
     with jax.named_scope("seqrec_head_loss"):
-        nll = _by_token_blocks(nll_of, p,
-                               hidden.reshape(-1, hidden.shape[-1]),
-                               targets.reshape(-1))
-        loss = nll.sum() / jnp.maximum((targets > 0).sum(), 1)
+        if not p.exit_gate:
+            hidden = passes[-1]
+            nll = _by_token_blocks(nll_of, p,
+                                   hidden.reshape(-1, hidden.shape[-1]),
+                                   targets.reshape(-1))
+            loss = nll.sum() / jnp.maximum((targets > 0).sum(), 1)
+        else:
+            # a pass after another through the head, so that a block's
+            # logits are one pass's: [R x T] rows, TOKEN_BLOCK at a time
+            r, d = p.n_loops, passes.shape[-1]
+            nll = _by_token_blocks(
+                nll_of, p, passes.reshape(-1, d),
+                jnp.tile(targets.reshape(-1), r)).reshape(r, -1)
+            gate = params["exit_gate"]
+            # float32 elementwise: no rounded product under the gate
+            logp = exit_distribution(
+                (passes.reshape(r, -1, d) * gate["w"]).sum(-1) + gate["b"])
+            prob = jnp.exp(logp)
+            real = targets.reshape(-1) > 0
+            n_targets = jnp.maximum(real.sum(), 1)
+            per_target = (prob * nll).sum(0) \
+                + p.exit_entropy_beta * (prob * logp).sum(0)
+            loss = (per_target * real).sum() / n_targets
+            exits = {"loop_loss": nll.sum(-1) / n_targets,
+                     "exit_share": (prob * real).sum(-1) / n_targets}
     if p.balance_loss_alpha and expert_layers:
         loss = loss + p.balance_loss_alpha * sum(
             s["balance"] for s in expert_layers)
     return loss, (expert_layers, {kind: jnp.asarray(n, jnp.int32)
-                                  for kind, n in mixers.items()})
+                                  for kind, n in mixers.items()}, exits)
 
 
 def grad_group(path) -> str:
@@ -687,7 +796,8 @@ def grad_group(path) -> str:
                 "ln_f": "final_norm"}.get(names[0], names[0])
     part = {"router": "router", "router_bias": "router",
             "experts": "experts", "shared": "shared_expert",
-            "shared_gate": "shared_expert", "ln1": "norms", "ln2": "norms",
+            "shared_gate": "shared_expert",
+            **dict.fromkeys(("ln1", "ln2", "post1", "post2"), "norms"),
             **dict.fromkeys(("wqkv", "wq", "wkva", "kv_norm", "wkvb", "wo",
                              "wq_gate", "wk", "wv", "q_norm", "k_norm"),
                             "attention"),
@@ -733,7 +843,11 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
     `linear_attention_pallas`, whether `gated_delta_rule` ran every
     linear-attention layer's recurrence as Pallas kernels, and
     `expert_product_pallas`, whether `held_experts` multiplied every
-    expert layer's groups with the Pallas kernels). With a
+    expert layer's groups with the Pallas kernels); under `n_loops` a
+    fifth, `layer_passes`, the stack's layers run in the `first` pass
+    and in the `repeat`s, and `mixer_layers` counts a layer once a pass;
+    under `exit_gate` `loop_loss` and `exit_share` [pass]: each pass's
+    own cross-entropy and the mean probability of leaving there). With a
     mesh, batch is
     sharded over "data" and embedding/ffn rows over "model"; XLA inserts
     the psums."""
@@ -750,8 +864,9 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
         routes, rule_routes, product_routes = set(), set(), set()
         with routes_into(routes), linear_attention.routes_into(rule_routes), \
                 moe.routes_into(product_routes):
-            (loss, (expert_layers, mixers)), grads = jax.value_and_grad(
-                _loss_fn, has_aux=True)(params, seqs, targets, p, mesh)
+            (loss, (expert_layers, mixers, exits)), grads = \
+                jax.value_and_grad(_loss_fn, has_aux=True)(
+                    params, seqs, targets, p, mesh)
         with jax.named_scope("seqrec_optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
         with jax.named_scope("seqrec_record"):
@@ -762,15 +877,27 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
                  "linear_attention_pallas": jnp.asarray(
                      rule_routes == {"pallas"}),
                  "expert_product_pallas": jnp.asarray(
-                     product_routes == {"pallas"})}
+                     product_routes == {"pallas"}),
+                 **exits}
+        if p.n_loops > 1:
+            # the stack's layers by the pass they ran in
+            stats["layer_passes"] = {
+                name: jnp.asarray(n * p.n_layers, jnp.int32)
+                for name, n in (("first", 1), ("repeat", p.n_loops - 1))}
         if expert_layers:
-            # a selection bias is moved by its layer's load, not by adamw
+            # a selection bias is moved by its layer's load, not by adamw:
+            # the tokens of all its passes
             moe_layers = [layer for i, layer in enumerate(updates["layers"])
                           if p.ffn_kind(i) == "moe"]
             with jax.named_scope("seqrec_optimizer"):
-                for layer, s in zip(moe_layers, expert_layers):
+                for n, layer in enumerate(moe_layers):
+                    # (no 0 + load where there is one pass: the step's
+                    # program is then the one it was)
                     layer["router_bias"] = moe.bias_update(
-                        jnp.zeros_like(layer["router_bias"]), s["load"],
+                        jnp.zeros_like(layer["router_bias"]),
+                        functools.reduce(operator.add, (
+                            s["load"] for s in
+                            expert_layers[n::len(moe_layers)])),
                         p.bias_update_rate)
             with jax.named_scope("seqrec_record"):
                 stats.update({
@@ -929,7 +1056,9 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
     sessions of the batch, as indices into the sessions of two events or
     more, in the order given) and, with expert layers, `load` [expert
     layer, routed expert], `held_tokens` [expert layer, held expert] and
-    `dropped` [expert layer]."""
+    `dropped` [expert layer] (under `n_loops` an expert layer once a
+    pass, the first pass's layers first) and, under `exit_gate`,
+    `loop_loss` and `exit_share` [pass]."""
     p.check()
     with span("seqrec_prepare"):
         all_items = np.asarray(sorted({it for s in sessions for it in s}),
@@ -1080,7 +1209,9 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
           for key in ("attention_pallas", "linear_attention_pallas",
                       "expert_product_pallas")),
         {kind: int(n) for kind, n in steps[0]["mixer_layers"].items()}
-        if steps else {})
+        if steps else {},
+        {name: int(n) for name, n in steps[0]["layer_passes"].items()}
+        if steps and "layer_passes" in steps[0] else None)
     return SeqRecModel(item_vocab=all_items, params=params, hyper=p,
                        record=record)
 
@@ -1092,7 +1223,7 @@ def _training_record(steps: List[Dict], rows: List[np.ndarray]) -> Dict:
               **{key: [{k: float(v) for k, v in s[key].items()}
                        for s in steps] for key in ("grad_norm",
                                                    "update_norm")}}
-    for key in ("load", "held_tokens", "dropped"):
+    for key in ("load", "held_tokens", "dropped", "loop_loss", "exit_share"):
         if steps and key in steps[0]:
             record[key] = [np.asarray(s[key]).tolist() for s in steps]
     return record

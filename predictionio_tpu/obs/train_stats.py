@@ -58,8 +58,16 @@ the weights), `seqrec_put` (sharding, the optimizer's state),
 * ``pio_train_seqrec_mixer_tokens_total{mixer}`` — positions of the
   trained batches, padding too, times the layers of each mixer
   (``mha``, ``mla``, ``gqa``, ``gdn``, ``conv``) the compiled step ran.
+* ``pio_train_seqrec_layer_pass_tokens_total{pass}`` — positions of the
+  trained batches, padding too, times the layers the compiled step ran
+  in the stack's ``first`` pass and in its ``repeat``s (`n_loops`): a
+  step that ran one pass counts 0 repeats.
+* ``pio_train_seqrec_loop_loss{loop}`` / ``pio_train_seqrec_exit_share{loop}``
+  — under an exit gate, each pass's own next-item loss and the mean
+  probability of leaving at it, over the targets of a train's last step.
 * ``pio_train_seqrec_expert_tokens_total{layer}`` — tokens the experts
-  held here received, by expert layer.
+  held here received, by expert layer (under `n_loops` an expert layer
+  once a pass).
 * ``pio_train_seqrec_expert_load_max_over_mean`` — the busiest routed
   expert's tokens over the mean, over all the router's experts, mean
   over a train's steps and expert layers (one sample a train).
@@ -198,6 +206,28 @@ def seqrec_mixer_tokens(registry: MetricsRegistry = None):
         "mixer the compiled step ran", labelnames=("mixer",))
 
 
+def seqrec_layer_pass_tokens(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_layer_pass_tokens_total",
+        "Positions of the trained batches times the layers the compiled "
+        "step ran in the stack's first pass and in its repeats",
+        labelnames=("pass",))
+
+
+def seqrec_loop_loss(registry: MetricsRegistry = None):
+    return (registry or default_registry()).gauge(
+        "pio_train_seqrec_loop_loss",
+        "Each pass's own next-item loss in the last train's last step",
+        labelnames=("loop",))
+
+
+def seqrec_exit_share(registry: MetricsRegistry = None):
+    return (registry or default_registry()).gauge(
+        "pio_train_seqrec_exit_share",
+        "Mean probability of leaving at each pass over the targets of the "
+        "last train's last step", labelnames=("loop",))
+
+
 def seqrec_expert_tokens(registry: MetricsRegistry = None):
     return (registry or default_registry()).counter(
         "pio_train_seqrec_expert_tokens_total",
@@ -236,13 +266,15 @@ def seqrec_fetch_bytes(registry: MetricsRegistry = None):
 def observe_seqrec_record(record: dict, targets, rows,
                           attention_impl: str, linear_attention_impl: str,
                           expert_product_impl: str,
-                          mixer_layers: dict) -> None:
+                          mixer_layers: dict,
+                          layer_passes: dict = None) -> None:
     """The token and expert counters from one train's record
     (models/seqrec.train_seqrec): `targets` the padded target ids of all
     sessions, `rows` the sessions of each step's batch, `attention_impl`
     and `linear_attention_impl` the routes its step's softmax and linear
-    attention were traced on, `mixer_layers` the layers that step ran by
-    mixer."""
+    attention were traced on, `mixer_layers` the layer passes that step
+    ran by mixer, `layer_passes` those of its first pass and of its
+    repeats (None from a step of one pass: all are first)."""
     import numpy as np
 
     real = sum(int((targets[r] > 0).sum()) for r in rows)
@@ -251,6 +283,15 @@ def observe_seqrec_record(record: dict, targets, rows,
     seqrec_pad_tokens().inc(positions - real)
     for mixer, layers in mixer_layers.items():
         seqrec_mixer_tokens().inc(positions * layers, mixer=mixer)
+    if layer_passes is None:
+        layer_passes = {"first": sum(mixer_layers.values()), "repeat": 0}
+    for name, layers in layer_passes.items():
+        seqrec_layer_pass_tokens().inc(positions * layers, **{"pass": name})
+    for key, gauge in (("loop_loss", seqrec_loop_loss),
+                       ("exit_share", seqrec_exit_share)):
+        if record.get(key):
+            for loop, value in enumerate(record[key][-1]):
+                gauge().set(value, loop=str(loop))
     if set(mixer_layers) & {"mha", "mla", "gqa"}:
         seqrec_attention_tokens().inc(positions, impl=attention_impl)
     if "gdn" in mixer_layers:

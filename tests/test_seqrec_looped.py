@@ -1,0 +1,637 @@
+"""The sequence model as a looped decoder: a stack of sandwich-normed
+multi-head-attention layers run `n_loops` times over the same weights,
+the last norm after every pass, an exit gate after each pass and the
+loss an expectation over the passes' exits, against the plain reference
+the benchmark brings (benchmarks/checks/seqrec_looped_reference.py), on
+seeded random weights at a small size. Only `mha` + `swiglu` is held to
+a reference here; that the loop runs with the other mixers and with
+expert layers is held by a step's shapes and counts alone."""
+
+import dataclasses
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.checks import seqrec_looped_reference as ref
+from predictionio_tpu.models import seqrec
+from predictionio_tpu.obs import profiler
+
+VOCAB, L, R, N = 97, 24, 4, 2
+
+
+def small_spec(**over) -> seqrec.SeqRecParams:
+    """d 64; two layers of 4 heads of 16 with rotary positions and a
+    SwiGLU of 96, a norm before and after each sub-layer, run four times;
+    an untied head; an exit gate and an entropy term of 0.05."""
+    base = dict(
+        d_model=64, n_heads=4, n_layers=N, n_loops=R, max_len=L, seed=11,
+        mixer="mha", ffn="swiglu", ffn_width=96, norm="rms", norm_eps=1e-6,
+        post_norm=True, positions="rope", rope_theta=1e6, tied_head=False,
+        exit_gate=True, exit_entropy_beta=0.05, remat=True)
+    return seqrec.SeqRecParams(**{**base, **over})
+
+
+@pytest.fixture(autouse=True)
+def small_blocks(monkeypatch):
+    """Blocks small enough that a session of 24 takes three attention
+    blocks, a step's 48 tokens four token blocks and the loss's 4 x 48
+    rows sixteen."""
+    monkeypatch.setattr(seqrec, "ATTENTION_BLOCK", 8)
+    monkeypatch.setattr(seqrec, "TOKEN_BLOCK", 12)
+
+
+def batch(seed=0, rows=2, pad=0):
+    rng = np.random.default_rng(seed)
+    s = rng.integers(1, VOCAB, size=(rows, L + 1))
+    s[:, :pad] = 0
+    return s[:, :-1].astype(np.int32), s[:, 1:].astype(np.int32)
+
+
+def weights(p, seed=3):
+    """The spec's draws, with every norm's weight moved off 1 and the
+    gate off 0, so that they matter."""
+    params = seqrec.init_params(np.random.default_rng(seed), VOCAB - 1, p)
+    rng = np.random.default_rng(seed + 1)
+    moved = {"ln1": 0.1, "ln2": 0.1, "post1": 0.1, "post2": 0.1,
+             "ln_f": 0.1, "exit_gate": 0.3}
+
+    def move(path, w):
+        for k in path:
+            if getattr(k, "key", None) in moved:
+                return w + jnp.asarray(
+                    rng.normal(size=w.shape) * moved[k.key], jnp.float32)
+        return w
+
+    return jax.tree_util.tree_map_with_path(move, params)
+
+
+def ref_spec(p, **over):
+    return ref.Spec.of(dataclasses.asdict(p), **over)
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-30))
+
+
+def loss_and_grads(params, seqs, targets, p):
+    with jax.default_matmul_precision("highest"):
+        return jax.value_and_grad(seqrec._loss_fn, has_aux=True)(
+            params, jnp.asarray(seqs), jnp.asarray(targets), p)
+
+
+@pytest.mark.parametrize("remat", [True, False])
+@pytest.mark.parametrize("pad", [0, 5])
+def test_loss_and_every_gradient_match_the_reference(pad, remat):
+    """float32 on both sides, on the CPU; the orders of summation differ
+    (blocked attention, token blocks, the scan's sum of a weight's
+    gradient over the passes), which costs a few float32 roundings a
+    value: 2e-5 of each array's largest entry. A lower precision
+    anywhere reads 1e-3 and more (the int8 case below)."""
+    p = small_spec(remat=remat)
+    params = weights(p)
+    seqs, targets = batch(pad=pad)
+    (loss, (_, mixers, exits)), grads = loss_and_grads(params, seqs, targets,
+                                                       p)
+    want_loss, want_grads, want = ref.loss_and_grads(params, seqs, targets,
+                                                     ref_spec(p))
+    assert abs(float(loss) - want_loss) < 2e-6 * want_loss
+    np.testing.assert_allclose(exits["loop_loss"], want["loop_loss"],
+                               rtol=2e-6)
+    np.testing.assert_allclose(exits["exit_share"], want["exit_share"],
+                               rtol=2e-6)
+    assert float(exits["exit_share"].sum()) == pytest.approx(1.0, abs=1e-6)
+    got = dict(jax.tree_util.tree_leaves_with_path(grads))
+    assert set(got) == set(dict(
+        jax.tree_util.tree_leaves_with_path(want_grads)))
+    for path, want_g in jax.tree_util.tree_leaves_with_path(want_grads):
+        assert rel(got[path], want_g) < 2e-5, jax.tree_util.keystr(path)
+    # every group of the record: the post norms with the norms, the gate
+    groups = seqrec._group_norms(grads)
+    assert set(groups) == set(ref.group_norms(want_grads)) == {
+        "embedding", "head", "final_norm", "exit_gate",
+        *(f"layer{i}.{part}" for i in range(N)
+          for part in ("attention", "ffn", "norms"))}
+    # a layer counts once a pass
+    assert {k: int(v) for k, v in mixers.items()} == {"mha": R * N}
+    # and the control: the reference's own int8 products
+    _, low, _ = ref.loss_and_grads(params, seqs, targets,
+                                   ref_spec(p, precision="int8"))
+    for name in ("wqkv", "w_down"):
+        assert rel(low["layers"][1][name],
+                   want_grads["layers"][1][name]) > 1e-3
+
+
+def test_every_passes_logits_match_the_reference():
+    p = small_spec()
+    params = weights(p)
+    seqs, _ = batch(seed=5, rows=1, pad=3)
+    with jax.default_matmul_precision("highest"):
+        passes, _, _ = seqrec._forward(params, jnp.asarray(seqs), p)
+        states = ref.pass_states(params, seqs[0], ref_spec(p))
+        assert passes.shape == (R, 1, L, 64) and len(states) == R
+        for r in range(R):
+            logits = passes[r, 0] @ seqrec.head_matrix(params)
+            assert logits.shape == (L, VOCAB)
+            assert rel(logits, states[r] @ params["head"]) < 1e-5, r
+        # `forward`, what serving reads, is the last pass
+        np.testing.assert_array_equal(
+            seqrec.forward(params, jnp.asarray(seqs), p), passes[-1])
+    assert not np.asarray(passes[:, 0, :3]).any()      # padding reads 0
+
+
+def test_shared_passes_are_an_unshared_stack_of_copies():
+    """R passes of N shared layers = an unshared model of R x N layers
+    holding the same weights R times, and a shared weight's gradient =
+    the sum of its R copies': the reference as the unshared twin (pass r
+    takes layers [r N, (r + 1) N) of a list of R x N)."""
+    p = small_spec()
+    params = weights(p)
+    seqs, targets = batch(seed=7)
+    (loss, _), grads = loss_and_grads(params, seqs, targets, p)
+    copies = {**params, "layers": [
+        jax.tree.map(jnp.copy, layer) for _ in range(R)
+        for layer in params["layers"]]}
+    twin_loss, twin, _ = ref.loss_and_grads(copies, seqs, targets,
+                                            ref_spec(p, unshared=True))
+    assert len(twin["layers"]) == R * N
+    assert abs(float(loss) - twin_loss) < 2e-6 * twin_loss
+    for i in range(N):
+        for name, got in grads["layers"][i].items():
+            parts = [twin["layers"][r * N + i][name] for r in range(R)]
+            want = jax.tree.map(lambda *t: sum(t), *parts)
+            for g, w, first in zip(jax.tree.leaves(got),
+                                   jax.tree.leaves(want),
+                                   jax.tree.leaves(parts[0])):
+                assert rel(g, w) < 2e-5, (i, name)
+                # and no one copy's: the passes all send something
+                assert rel(g, first) > 1e-2, (i, name)
+    for name in ("emb", "head"):
+        assert rel(grads[name], twin[name]) < 2e-5
+
+
+def test_the_scanned_loop_is_the_unrolled_one(monkeypatch):
+    """One `lax.scan` over the passes against the stack written out four
+    times: the same operations a pass, but the compiler fuses them
+    otherwise and the backward pass adds a weight's gradient up in
+    another order (the scan's carry against a tree of sums). The stated
+    bound: the loss to 1e-6 of itself, every gradient to 5e-6 of its
+    largest entry, float32 roundings both."""
+    p = small_spec()
+    params = weights(p)
+    seqs, targets = batch(seed=8, pad=2)
+
+    def loops():
+        """`while` loops in the forward pass's compiled program."""
+        return jax.jit(lambda w: seqrec._forward(
+            w, jnp.asarray(seqs), dataclasses.replace(p, remat=False))[0]
+        ).lower(params).compile().as_text().count(" while(")
+
+    (loss, _), grads = loss_and_grads(params, seqs, targets, p)
+    scanned = loops()
+    monkeypatch.setattr(seqrec, "LOOP_UNROLL", True)
+    (unrolled, _), unrolled_grads = loss_and_grads(params, seqs, targets, p)
+    assert float(loss) == pytest.approx(float(unrolled), rel=1e-6)
+    for (path, g), u in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree.leaves(unrolled_grads)):
+        assert rel(g, u) < 5e-6, jax.tree_util.keystr(path)
+    # the scanned program holds the stack, and so the loops inside its
+    # attention layers, once inside the loop of passes; the unrolled one
+    # R times and no loop around them
+    assert scanned > 1 and (scanned - 1) * R == loops()
+
+
+def test_one_pass_without_post_norms_and_gate_is_todays_program():
+    """`n_loops` 1, `post_norm` and `exit_gate` off: the fields' defaults.
+    The weights are the draws of a spec that never heard of them, bit for
+    bit, and the looped spec's are those plus norms of 1 and a gate of 0
+    (no draw is spent on them); the step's program holds no loop of
+    passes, no gate and no extra number."""
+    assert (seqrec.SeqRecParams().n_loops, seqrec.SeqRecParams().post_norm,
+            seqrec.SeqRecParams().exit_gate) == (1, False, False)
+    plain = small_spec(n_loops=1, post_norm=False, exit_gate=False)
+    looped = small_spec()
+    a = seqrec.init_params(np.random.default_rng(0), 40, plain)
+    b = seqrec.init_params(np.random.default_rng(0), 40, looped)
+    assert sorted(a) == ["emb", "head", "layers", "ln_f"]
+    assert sorted(set(b) - set(a)) == ["exit_gate"]
+    assert sorted(set(b["layers"][0]) - set(a["layers"][0])) == ["post1",
+                                                                 "post2"]
+    for path, leaf in jax.tree_util.tree_leaves_with_path(a):
+        assert np.array_equal(leaf, dict(
+            jax.tree_util.tree_leaves_with_path(b))[path])
+    assert all(np.asarray(layer[n]["scale"] == 1).all()
+               for layer in b["layers"] for n in ("post1", "post2"))
+    assert not np.asarray(b["exit_gate"]["w"]).any()
+    assert b["exit_gate"]["b"].shape == () and float(b["exit_gate"]["b"]) == 0
+    # the leaves are each their own: a donated step takes each once
+    assert len({id(leaf) for leaf in jax.tree.leaves(b)}) == len(
+        jax.tree.leaves(b))
+    seqs, targets = batch(seed=1)
+    passes, _, mixers = seqrec._forward(a, jnp.asarray(seqs), plain)
+    assert isinstance(passes, tuple) and len(passes) == 1
+    assert mixers == {"mha": N}
+    optimizer = seqrec.make_optimizer(plain)
+    _, _, stats = seqrec.make_train_step(None, plain, optimizer)(
+        jax.tree.map(jnp.copy, a), optimizer.init(a), jnp.asarray(seqs),
+        jnp.asarray(targets))
+    assert not {"layer_passes", "loop_loss", "exit_share"} & set(stats)
+    # the fields are architecture: part of a run's identity
+    assert plain.spec_key() != looped.spec_key()
+    assert small_spec(exit_entropy_beta=0.1).spec_key() != looped.spec_key()
+    assert plain.spec_key(memory=False) != looped.spec_key(memory=False)
+
+
+def test_the_exit_distribution_by_hand():
+    """Four passes, two positions: p_1 = s_1, p_2 = s_2 (1 - s_1), p_3 =
+    s_3 (1 - s_1)(1 - s_2), p_4 = (1 - s_1)(1 - s_2)(1 - s_3); they sum to
+    1 whatever the gates say, and the last pass's own gate is not read."""
+    z = np.asarray([[0.3, -2.0], [-1.0, 4.0], [2.0, 0.5], [9.0, -9.0]])
+    s = 1 / (1 + np.exp(-z))
+    want = np.stack([s[0], s[1] * (1 - s[0]),
+                     s[2] * (1 - s[0]) * (1 - s[1]),
+                     (1 - s[0]) * (1 - s[1]) * (1 - s[2])])
+    logp = seqrec.exit_distribution(jnp.asarray(z, jnp.float32))
+    np.testing.assert_allclose(np.exp(logp), want, rtol=1e-6)
+    np.testing.assert_allclose(np.exp(logp).sum(0), [1.0, 1.0], rtol=1e-6)
+    np.testing.assert_allclose(
+        np.exp(ref.exit_log_probabilities(jnp.asarray(z, jnp.float32))),
+        want, rtol=5e-6)
+    grad = jax.grad(lambda t: (jnp.exp(seqrec.exit_distribution(t))
+                               * jnp.arange(8.0).reshape(4, 2)).sum())(
+        jnp.asarray(z, jnp.float32))
+    assert not np.asarray(grad[-1]).any() and np.asarray(grad[:-1]).all()
+    # two passes: p = (s_1, 1 - s_1)
+    np.testing.assert_allclose(
+        np.exp(seqrec.exit_distribution(jnp.asarray(z[:2], jnp.float32))),
+        [s[0], 1 - s[0]], rtol=1e-6)
+
+
+def test_the_last_passes_gate_gets_no_gradient_and_the_entropy_counts():
+    """The loss does not read lambda_R: with the last pass's state alone
+    feeding a gate of its own, that gate's gradient is 0 while the other
+    passes' is not. The entropy term is beta x sum p log p a target."""
+    p = small_spec()
+    params = weights(p)
+    seqs, targets = batch(seed=4)
+
+    def loss_of(gates):
+        """A gate a pass: `gates` [R, d] in place of the one column."""
+        passes, _, _ = seqrec._forward(params, jnp.asarray(seqs), p)
+        z = (passes.reshape(R, -1, 64) * gates[:, None]).sum(-1)
+        logp = seqrec.exit_distribution(z)
+        return (jnp.exp(logp) * jnp.arange(1.0, R + 1)[:, None]).sum()
+
+    by_pass = jax.grad(loss_of)(jnp.tile(params["exit_gate"]["w"], (R, 1)))
+    assert not np.asarray(by_pass[-1]).any()
+    assert all(np.asarray(by_pass[r]).any() for r in range(R - 1))
+    (with_h, _), _ = loss_and_grads(params, seqs, targets, p)
+    (without, _), _ = loss_and_grads(
+        params, seqs, targets, dataclasses.replace(p, exit_entropy_beta=0.0))
+    ref_without, _, _ = ref.loss_and_grads(
+        params, seqs, targets, ref_spec(p, exit_entropy_beta=0.0))
+    assert float(without) == pytest.approx(ref_without, rel=2e-6)
+    # 0.05 x H(p), H between 0 and log 4
+    assert 0 < float(without - with_h) < 0.05 * np.log(R)
+
+
+def test_a_step_adds_what_the_references_adamw_adds():
+    """By parameter group, the norm of step 1's update against the
+    reference's adamw step from its own gradients, what a learning rate
+    ten times off reads, each pass's loss and share and the layer passes
+    the step reports."""
+    p = small_spec(learning_rate=1e-3)
+    params = weights(p)
+    seqs, targets = batch(seed=2)
+    _, grads, passes = ref.loss_and_grads(params, seqs, targets, ref_spec(p))
+    want = ref.first_update_norms(params, grads, ref_spec(p))
+    off = ref.first_update_norms(params, grads,
+                                 ref_spec(p, learning_rate=1e-2))
+    optimizer = seqrec.make_optimizer(p)
+    with jax.default_matmul_precision("highest"):
+        _, _, stats = seqrec.make_train_step(None, p, optimizer)(
+            jax.tree.map(jnp.copy, params), optimizer.init(params),
+            jnp.asarray(seqs), jnp.asarray(targets))
+    got = {k: float(v) for k, v in stats["update_norm"].items()}
+    assert set(got) == set(want) and "exit_gate" in got
+    for group, norm in want.items():
+        assert abs(got[group] - norm) < 2e-4 * norm, group
+    assert off["layer1.attention"] > 9 * got["layer1.attention"]
+    want_norms = ref.group_norms(grads)
+    for group, norm in want_norms.items():
+        assert abs(float(stats["grad_norm"][group]) - norm) < 2e-4 * norm
+    np.testing.assert_allclose(stats["loop_loss"], passes["loop_loss"],
+                               rtol=1e-5)
+    np.testing.assert_allclose(stats["exit_share"], passes["exit_share"],
+                               rtol=1e-5)
+    assert {k: int(v) for k, v in stats["layer_passes"].items()} == \
+        {"first": N, "repeat": (R - 1) * N}
+    assert {k: int(v) for k, v in stats["mixer_layers"].items()} == \
+        {"mha": R * N}
+
+
+@pytest.mark.parametrize("fault,apart", [
+    ({"n_loops": R - 1}, "loss"),            # a pass left out
+    ({"last_pass_only": True}, "wqkv"),      # the earlier passes' part dropped
+    ({"post_norm": False}, "loss"),
+    ({"exit_entropy_beta": 0.0}, "loss"),
+])
+def test_the_references_fault_controls_are_faults(fault, apart):
+    """Each control of the benchmark's check moves what it should: the
+    loss (a pass or the post norms or the entropy left out) or the shared
+    weights' gradient with the loss unmoved (the gradient through the
+    last pass only)."""
+    p = small_spec()
+    params = weights(p)
+    seqs, targets = batch(seed=6)
+    loss, grads, _ = ref.loss_and_grads(params, seqs, targets, ref_spec(p))
+    bad_loss, bad, passes = ref.loss_and_grads(params, seqs, targets,
+                                               ref_spec(p, **fault))
+    if apart == "loss":
+        assert abs(bad_loss - loss) > 1e-3 * loss
+    else:
+        assert bad_loss == pytest.approx(loss, rel=1e-6)
+        assert rel(bad["layers"][0][apart], grads["layers"][0][apart]) > 0.05
+        # nothing reaches the table but through the passes before the last
+        assert not np.asarray(bad["emb"]).any()
+    assert len(passes["loop_loss"]) == fault.get("n_loops", R)
+
+
+def test_recommend_next_scores_with_the_last_pass():
+    p = small_spec(epochs=0, batch_size=2)
+    sessions = [[f"i{(s + j) % 30:02d}" for j in range(L + 1)]
+                for s in range(6)]                        # items i00..i29
+    model = seqrec.train_seqrec(None, sessions, p)
+    model.params = weights(p)        # norms off 1: the passes differ
+    model.params = {**model.params, **{
+        k: v[:31] if k == "emb" else v[:, :31] for k, v in
+        model.params.items() if k in ("emb", "head")}}
+    scores = dict(model.recommend_next(["i01", "i02"], 30))
+    assert len(scores) == 28                  # 30 items, two seen
+    seq = np.zeros((1, L), np.int32)
+    seq[0, -2:] = [model.item_code("i01"), model.item_code("i02")]
+    with jax.default_matmul_precision("highest"):
+        states = ref.pass_states(model.params, seq[0], ref_spec(p))
+    last = np.asarray(states[-1][-1] @ model.params["head"])
+    first = np.asarray(states[0][-1] @ model.params["head"])
+    assert scores["i07"] == pytest.approx(float(last[8]), rel=1e-4, abs=1e-5)
+    assert abs(float(first[8]) - float(last[8])) > 1e-3
+
+
+def test_a_train_counts_its_layer_passes_and_reports_each_pass():
+    """`pio_train_seqrec_layer_pass_tokens_total{pass}`: positions of the
+    trained batches times the layers the compiled step ran in its first
+    pass and in its repeats; a step of one pass counts 0 repeats. The
+    gauges hold the last step's loss and share a pass, the record every
+    step's."""
+    from predictionio_tpu.obs.registry import default_registry
+
+    reg = default_registry()
+
+    def counted(name, **labels):
+        c = reg.get(name)
+        return c.value(**labels) if c is not None else 0
+
+    series = [("pio_train_seqrec_layer_pass_tokens_total", {"pass": "first"}),
+              ("pio_train_seqrec_layer_pass_tokens_total",
+               {"pass": "repeat"}),
+              ("pio_train_seqrec_mixer_tokens_total", {"mixer": "mha"})]
+    sessions = [[f"i{(3 * s + j * (1 + s % 2)) % 50:02d}"
+                 for j in range(L + 1)] for s in range(4)]
+    positions = 2 * 2 * L
+    before = [counted(name, **labels) for name, labels in series]
+    model = seqrec.train_seqrec(None, sessions, small_spec(
+        epochs=1, batch_size=2, device_init=True))
+    gained = [counted(name, **labels) - b
+              for (name, labels), b in zip(series, before)]
+    assert gained == [N * positions, (R - 1) * N * positions,
+                      R * N * positions]
+    record = model.record
+    assert len(record["loss"]) == 2
+    assert np.asarray(record["loop_loss"]).shape == (2, R)
+    assert np.asarray(record["exit_share"]).shape == (2, R)
+    np.testing.assert_allclose(np.asarray(record["exit_share"]).sum(-1), 1.0,
+                               rtol=1e-5)
+    # the gate starts at 0: every pass but the last leaves half of what came
+    np.testing.assert_allclose(record["exit_share"][0],
+                               [0.5, 0.25, 0.125, 0.125], rtol=1e-6)
+    assert "exit_gate" in record["grad_norm"][0]
+    assert "exit_gate" in record["update_norm"][0]
+    for key, name in (("loop_loss", "pio_train_seqrec_loop_loss"),
+                      ("exit_share", "pio_train_seqrec_exit_share")):
+        for loop in range(R):
+            assert counted(name, loop=str(loop)) == pytest.approx(
+                record[key][-1][loop])
+    # one pass: all its layers are first passes
+    before = [counted(name, **labels) for name, labels in series]
+    seqrec.train_seqrec(None, sessions, small_spec(
+        epochs=1, batch_size=2, n_loops=1, exit_gate=False))
+    gained = [counted(name, **labels) - b
+              for (name, labels), b in zip(series, before)]
+    assert gained == [N * positions, 0, N * positions]
+
+
+def test_a_looped_stack_of_other_mixers_and_experts_runs():
+    """`n_loops` with the other mixers and with expert layers: not held
+    to a reference; the balance numbers come once a pass and layer (the
+    first pass's layers first), a selection bias moves by its layer's
+    tokens over all passes, and the gradients are finite."""
+    p = seqrec.SeqRecParams(
+        d_model=32, n_heads=4, n_layers=3, n_loops=2, max_len=L, seed=5,
+        mixer=("conv", "gqa", "gdn"), ffn="moe", first_dense_layers=1,
+        ffn_width=48, norm="rms", positions="rope", n_kv_heads=2, head_dim=8,
+        rotary_dim=8, conv_kernel=3, linear_key_heads=2,
+        linear_value_heads=4, linear_key_head_dim=8, linear_value_head_dim=8,
+        linear_conv_kernel=4, n_routed_experts=8, held_experts=(0, 8),
+        experts_per_token=2, moe_width=16, bias_update_rate=0.01,
+        post_norm=True, remat=True)
+    params = seqrec.init_params(np.random.default_rng(0), VOCAB - 1, p)
+    seqs, targets = batch(seed=3)
+    optimizer = seqrec.make_optimizer(p)
+    after, _, stats = seqrec.make_train_step(None, p, optimizer)(
+        jax.tree.map(jnp.copy, params), optimizer.init(params),
+        jnp.asarray(seqs), jnp.asarray(targets))
+    load = np.asarray(stats["load"])
+    assert load.shape == (2 * 2, 8)              # two expert layers, twice
+    assert (load.sum(-1) == 2 * 2 * L).all()
+    assert {k: int(v) for k, v in stats["mixer_layers"].items()} == \
+        {"conv": 2, "gqa": 2, "gdn": 2}
+    assert {k: int(v) for k, v in stats["layer_passes"].items()} == \
+        {"first": 3, "repeat": 3}
+    assert all(np.isfinite(float(v)) and float(v) > 0
+               for v in stats["grad_norm"].values())
+    from predictionio_tpu.ops import moe
+
+    for n, layer in enumerate(after["layers"][1:]):
+        want = moe.bias_update(jnp.zeros(8), jnp.asarray(load[n] + load[2 + n]),
+                               0.01)
+        np.testing.assert_allclose(layer["router_bias"], want, atol=1e-7)
+
+
+def test_the_scope_table_of_a_scanned_step_names_the_bodys_instructions():
+    """The stack lies in the body of the scan's `while`, its feed-forward
+    and loss blocks in loops inside that: the table reaches them all, an
+    instruction of the body stands once however often it runs, and the
+    join adds an instruction's seconds, which a capture gives summed
+    over its events, to its scope and skips the loops themselves."""
+    p = small_spec()
+    params = weights(p)
+    seqs, targets = batch(seed=1)
+    optimizer = seqrec.make_optimizer(p)
+    step = seqrec.make_train_step(None, p, optimizer)
+    text = step.lower(params, optimizer.init(params), jnp.asarray(seqs),
+                      jnp.asarray(targets)).compile().as_text()
+    module, table = profiler.parse_scope_table(text, seqrec.STEP_SCOPES)
+    assert module.startswith("jit_step")
+    _, _, computations = profiler._computations(text)
+    loops = [ins for lines in computations.values()
+             for ins in profiler._instructions(lines)
+             if ins.opcode == "while"]
+    assert loops and all("c" in table[ins.key][1] for ins in loops)
+    bodies = {run for ins in loops for run in ins.runs}
+    inside = {ins.key: table[ins.key] for name in bodies
+              for ins in profiler._instructions(computations[name])
+              if ins.key in table}
+    scopes = {row[0] for row in inside.values()}
+    assert {"seqrec_attention", "seqrec_ffn", "seqrec_norm",
+            "seqrec_head_loss"} <= scopes
+    # forward and backward instructions of the body
+    assert any("t" in row[1] for row in inside.values())
+    assert any("t" not in row[1] for row in inside.values())
+    named = [key for key, row in inside.items()
+             if row[0] == "seqrec_attention" and "c" not in row[1]]
+    seconds = {key: 4 * 0.001 for key in named}     # four events, summed
+    seconds[loops[0].key] = 1.0                     # the loop: skipped
+    by_scope = profiler.scope_seconds(
+        seconds, [{"family": "seqrec_train_step", "instructions": table}])
+    assert by_scope["seqrec_train_step"] == {
+        "seqrec_attention": pytest.approx(0.004 * len(named))}
+
+
+def test_the_model_trains_and_serves_from_an_engine_json(tmp_path):
+    """`pio train` and `pio deploy`'s predict from a variant file alone:
+    the new keys of the layer spec (`n_loops`, `post_norm`, `exit_gate`,
+    `exit_entropy_beta`) reach the model like the old ones."""
+    import datetime as dt
+
+    from predictionio_tpu.core.params import engine_params_from_json
+    from predictionio_tpu.data import Event
+    from predictionio_tpu.data.eventstore import clear_cache
+    from predictionio_tpu.engines.sessionrec import (
+        AlgorithmParams, DataSourceParams, Query, engine,
+    )
+    from predictionio_tpu.storage import App, Storage
+    from predictionio_tpu.workflow import run_train
+    from predictionio_tpu.workflow.train import load_for_deploy
+
+    Storage.configure({
+        "sources": {"DB": {"TYPE": "sqlite", "PATH": str(tmp_path / "t.db")}},
+        "repositories": {name: {"NAME": "pio", "SOURCE": "DB"}
+                         for name in ("METADATA", "EVENTDATA", "MODELDATA")}})
+    clear_cache()
+    try:
+        app_id = Storage.get_meta_data_apps().insert(App(id=0, name="Loop"))
+        store = Storage.get_events()
+        store.init_channel(app_id)
+        t0 = dt.datetime(2026, 1, 1, tzinfo=dt.timezone.utc)
+        store.insert_batch([
+            Event(event="view", entity_type="user", entity_id=f"u{u}",
+                  target_entity_type="item",
+                  target_entity_id=f"i{(u + j) % 15:02d}",
+                  event_time=t0 + dt.timedelta(minutes=u * 100 + j))
+            for u in range(40) for j in range(4 + u % 5)], app_id)
+        spec = dataclasses.asdict(small_spec(max_len=16, epochs=30,
+                                             batch_size=20,
+                                             learning_rate=3e-3))
+        variant = json.loads(json.dumps({
+            "datasource": {"params": {"appName": "Loop"}},
+            "algorithms": [{"name": "seqrec", "params": spec}]}))
+        params = engine_params_from_json(
+            variant, DataSourceParams, None, {"seqrec": AlgorithmParams})
+        eng = engine()
+        instance = run_train(eng, params)
+        assert instance.status == "COMPLETED"
+        result, _ = load_for_deploy(eng, instance)
+        algo, model = result.algorithms[0], result.models[0]
+        assert (model.hyper.n_loops, model.hyper.post_norm,
+                model.hyper.exit_gate, model.hyper.exit_entropy_beta) == \
+            (R, True, True, 0.05)
+        assert model.record["loss"][-1] < model.record["loss"][0]
+        assert len(model.record["loop_loss"][-1]) == R
+        assert isinstance(model.params["exit_gate"]["w"], np.ndarray)
+        pred = algo.predict(model, Query(items=["i03", "i04", "i05"], num=3))
+        items = [s.item for s in pred.item_scores]
+        assert "i06" in items and "i05" not in items
+    finally:
+        Storage.reset()
+        clear_cache()
+
+
+@pytest.mark.parametrize("over,match", [
+    ({"n_loops": 0}, "n_loops"),
+    ({"n_loops": -2}, "n_loops"),
+    ({"n_loops": 1}, "exit_gate"),
+    ({"norm": "layer", "positions": "learned"}, "post_norm"),
+])
+def test_check_refuses_what_does_not_exist(over, match):
+    with pytest.raises(ValueError, match=match):
+        small_spec(**over).check()
+    # and lets through what does: a loop without a gate, a gate without
+    # its entropy term, post norms on one pass
+    small_spec(exit_gate=False).check()
+    small_spec(exit_entropy_beta=0.0).check()
+    small_spec(n_loops=1, exit_gate=False).check()
+
+
+def test_a_loop_without_a_gate_reads_the_last_pass_alone():
+    """`n_loops` without `exit_gate`: one head over the last pass's
+    state, no gate among the weights, no per-pass numbers."""
+    p = small_spec(exit_gate=False, post_norm=False)
+    params = weights(p)
+    assert "exit_gate" not in params
+    seqs, targets = batch(seed=9)
+    (loss, (_, _, exits)), grads = loss_and_grads(params, seqs, targets, p)
+    assert exits == {}
+    with jax.default_matmul_precision("highest"):
+        last = ref.pass_states(params, seqs[0], ref_spec(p))[-1]
+        hidden = seqrec.forward(params, jnp.asarray(seqs), p)
+    assert rel(hidden[0], last) < 1e-5
+    logits = hidden.reshape(-1, 64) @ params["head"]
+    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits),
+                               jnp.asarray(targets).reshape(-1, 1), 1)
+    assert float(loss) == pytest.approx(float(nll.mean()), rel=1e-5)
+    assert all(np.asarray(g).any() for g in jax.tree.leaves(grads))
+
+
+def test_the_looped_step_under_a_mesh_is_the_step(mesh8):
+    """Batch over "data", the projections' columns over "model"; the
+    post norms and the gate replicate. The sharded step's loss, per-pass
+    numbers and gradient norms are the one-device step's."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    p = small_spec(learning_rate=1e-3)
+    params = seqrec.init_params(np.random.default_rng(3), VOCAB - 1, p,
+                                vocab_multiple=2)
+    seqs, targets = batch(seed=6, rows=4)
+    optimizer = seqrec.make_optimizer(p)
+    _, _, want = seqrec.make_train_step(None, p, optimizer)(
+        jax.tree.map(jnp.copy, params), optimizer.init(params),
+        jnp.asarray(seqs), jnp.asarray(targets))
+    mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(4, 2),
+                axis_names=("data", "model"))
+    sharded = seqrec.shard_params(jax.tree.map(jnp.copy, params), mesh)
+    assert sharded["layers"][0]["post1"]["scale"].sharding.spec == P()
+    assert sharded["exit_gate"]["w"].sharding.spec == P()
+    assert sharded["layers"][0]["wqkv"].sharding.spec == P(None, "model")
+    _, _, got = seqrec.make_train_step(mesh, p, optimizer)(
+        sharded, optimizer.init(sharded), jnp.asarray(seqs),
+        jnp.asarray(targets))
+    assert abs(float(got["loss"]) - float(want["loss"])) < 1e-5
+    np.testing.assert_allclose(got["loop_loss"], want["loop_loss"],
+                               rtol=1e-5)
+    for group, norm in want["grad_norm"].items():
+        assert abs(float(got["grad_norm"][group]) - float(norm)) \
+            < 2e-3 * float(norm), group

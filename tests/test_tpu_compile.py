@@ -48,6 +48,9 @@ def one_chip(chips):
     # 32 MiB in its two buffers, the longest `tiles` lets through there
     ("narrow-cell", 1, 32, 8, 32768, 64, 64, True),
     ("wide-qk-narrow-v", 1, 4, 2, 2048, 192, 64, True),
+    # ouro-2.6b-pp8.train: 1 session x 16 heads (one key/value head a
+    # query head) x 8,192, q/k = v = 128: the kernels' fourth shape
+    ("looped-cell", 1, 16, 16, 8192, 128, 128, True),
 ])
 def test_attention_kernels_compile_for_v5e(one_chip, name, b, h, kv_heads,
                                            length, dk, dv, causal):
@@ -277,6 +280,48 @@ def test_the_conv_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
     assert _kernel_calls(text, "flash_attention_pallas_bwd") == 1
     assert _kernel_calls(text, "grouped_product_pallas_[a-z_]*") == 4 * 12
     assert "ragged-dot" not in text
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert held <= 15.75 * 2 ** 30 - 1e9, held
+
+
+def test_the_looped_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
+    """ouro-2.6b-pp8.train's step compiled for one described v5e: 8,192
+    positions four times through six layers as ONE loop of passes around
+    one copy of the stack (the kernels' calls from a `while` body: two
+    forward a layer under `remat`, one backward, six layers, once), the
+    whole vocabulary's head a pass at a time; arguments + temporaries
+    leave the 16 GB chip 1 GB and more. (The stack written out four
+    times compiles to 12.1 GB of temporaries beside 6.1 of arguments and
+    does not fit: PERF.md section 6, PR 38.)"""
+    import json
+    import os
+
+    from predictionio_tpu.models import seqrec
+    from predictionio_tpu.ops import attention
+
+    kind = chips[0].device_kind
+    monkeypatch.setattr(attention, "_device_kind", lambda: kind)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "seqrec-ouro-2.6b-pp8.json")) as f:
+        cfg = json.load(f)
+    p = seqrec.SeqRecParams(**cfg["algorithm_params"])
+    assert (p.n_loops, p.n_layers, p.max_len, p.remat) == (4, 6, 8192, True)
+    assert seqrec.LOOP_UNROLL == 1
+    optimizer = seqrec.make_optimizer(p)
+    params = jax.eval_shape(
+        lambda: seqrec.init_params(None, cfg["n_items"], p))
+    put = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    seqs = jax.ShapeDtypeStruct((p.batch_size, p.max_len), jnp.int32,
+                                sharding=one_chip)
+    compiled = seqrec.make_train_step(None, p, optimizer).lower(
+        put(params), put(jax.eval_shape(optimizer.init, params)), seqs,
+        seqs).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(text, "flash_attention_pallas_fwd") == 2 * 6
+    assert _kernel_calls(text, "flash_attention_pallas_bwd") == 6
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert held <= 15.75 * 2 ** 30 - 1e9, held

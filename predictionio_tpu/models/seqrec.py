@@ -34,9 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from predictionio_tpu.core.params import Params
 from predictionio_tpu.obs import jax_stats, train_stats
 from predictionio_tpu.obs.tracing import span
-from predictionio_tpu.ops import (
-    linear_attention, linear_attention_pallas, moe,
-)
+from predictionio_tpu.ops import linear_attention, moe
 from predictionio_tpu.ops.attention import (
     blockwise_attention, ring_attention_traced, rope, routes_into,
 )
@@ -285,9 +283,14 @@ ATTENTION_BLOCK = 512
 TOKEN_BLOCK = 2048
 #: key heads of a linear-attention layer taken at a time under `remat`
 #: (with the value heads they serve) where the delta rule runs as a scan
-#: (`linear_attention.gated_delta_rule_route`: off a v5e, under a mesh);
-#: the Pallas kernels keep little enough for all heads at once. A
-#: constant, from chip runs of the scan's step at 16,384 positions, 16
+#: (`linear_attention.gated_delta_rule_route`: off a v5e, under a mesh):
+#: there the chain around the rule is XLA's and a group's internals are
+#: recomputed in the backward pass. On the kernels' route all heads go at
+#: once: `gated_delta_chain`'s backward pass keeps the projection's
+#: output, q, k, v at the key heads, the rule's output and the chunks'
+#: states and inverses (2.3 GB a layer at the sizes below, alive through
+#: that layer's backward pass) and recomputes nothing. A constant, from
+#: chip runs of the scan's step at 16,384 positions, 16
 #: key and 32 value heads of 128 (PERF.md section 6, PR 31): a step took
 #: 1.109 s at 2, 1.053 at 4, 0.964 at 8; the compiler counted 5.11, 5.97
 #: and 8.25 GiB of temporaries (10.97 with all 16 at once) beside 6.99
@@ -482,59 +485,34 @@ def _linear_attention(layer, x, key_mask, p: SeqRecParams, devices: int):
     traced for `devices` devices. A padding position's input is 0: it
     writes nothing into the state, and a left-padded session is the
     unpadded one. Heads are independent from the projection to the
-    output matrix. Under `remat`, where the rule runs as a scan
-    (`gated_delta_rule_route`) they are taken `LINEAR_KEY_HEADS` key heads
-    (and the value heads they serve) at a time, each group's internals
-    recomputed in the backward pass; on the kernels' route all at once,
-    and of what lies between the projections only what the kernels hand
-    their backward pass is kept (`linear_attention_pallas.KEPT`): the
-    convolution, norms and gate are recomputed, no kernel runs again."""
-    b, l, _ = x.shape
+    output matrix. Everything between the two projections is
+    `linear_attention.gated_delta_chain`, on the rule's route
+    (`gated_delta_rule_route`). Where the rule runs as a scan the chain
+    around it is XLA's (convolution, SiLU, unit length, the head norm and
+    gate), and under `remat` the heads are taken `LINEAR_KEY_HEADS` key
+    heads (and the value heads they serve) at a time, each group's
+    internals recomputed in the backward pass. On the kernels' route all
+    heads go at once through fused passes with a backward pass of their
+    own, which keeps the projection's output, q, k, v, the rule's output
+    and the chunks' states and inverses from the forward pass it follows
+    and recomputes nothing; under `remat` that forward pass is the
+    block's recomputation, so the chain and the rule's forward kernel run
+    twice a step and what is kept lives through one layer's backward
+    pass."""
     hk, hv = p.linear_key_heads, p.linear_value_heads
     dk, dv = p.linear_key_head_dim, p.linear_value_head_dim
     x = jnp.where(key_mask[..., None], x, 0.0)
-    cuts = [hk * dk, 2 * hk * dk, 2 * hk * dk + hv * dv]
-    q, k, v, z = jnp.split(x @ layer["w_qkvz"], cuts, axis=-1)
-    conv_q, conv_k, conv_v = jnp.split(layer["conv"], cuts[:2], axis=-1)
     # the decay compounds over a session: its projection at the highest
     # precision, as the router's
     ba = jnp.dot(x, layer["w_ba"], precision=jax.lax.Precision.HIGHEST)
     beta = jax.nn.sigmoid(ba[..., :hv])
     g = -jnp.exp(layer["A_log"]) * jax.nn.softplus(
         ba[..., hv:] + layer["dt_bias"])
-    scan = linear_attention.route_here(dk, dv, devices) == "xla"
-    n = hk // LINEAR_KEY_HEADS \
-        if scan and p.remat and hk % LINEAR_KEY_HEADS == 0 else 1
-
-    def groups(t):          # [..., heads x width] -> [n, ..., heads / n x width]
-        return jnp.moveaxis(t.reshape(*t.shape[:-1], n, -1), -2, 0)
-
-    def unit(t):            # [B, L, heads x dk] -> unit length, a value head
-        t = t.reshape(b, l, -1, dk)
-        t = t * jax.lax.rsqrt((t * t).sum(-1, keepdims=True) + 1e-6)
-        return jnp.repeat(t, hv // hk, axis=2)
-
-    def group(args):
-        q, k, v, z, conv_q, conv_k, conv_v, g, beta = args
-        q, k, v = (linear_attention.causal_conv(t, w) for t, w in
-                   ((q, conv_q), (k, conv_k), (v, conv_v)))
-        o = linear_attention.gated_delta_rule(
-            unit(q) * dk ** -0.5, unit(k), v.reshape(b, l, -1, dv), g, beta,
-            devices=devices)
-        o = _rms_norm(o, layer["o_norm"]["scale"], p.norm_eps) \
-            * jax.nn.silu(z.reshape(b, l, -1, dv))
-        return o.reshape(b, l, -1)
-
-    args = tuple(map(groups, (q, k, v, z, conv_q, conv_k, conv_v, g, beta)))
-    if n > 1:
-        o = jax.lax.map(jax.checkpoint(group), args)
-    else:
-        if p.remat and not scan:
-            group = jax.checkpoint(
-                group, policy=jax.checkpoint_policies.save_only_these_names(
-                    *linear_attention_pallas.KEPT))
-        o = group(tuple(t[0] for t in args))[None]
-    return jnp.moveaxis(o, 0, 2).reshape(b, l, -1) @ layer["w_out"]
+    return linear_attention.gated_delta_chain(
+        x @ layer["w_qkvz"], layer["conv"], g, beta, layer["o_norm"]["scale"],
+        (hk, hv, dk, dv), p.norm_eps, devices,
+        LINEAR_KEY_HEADS if p.remat and hk % LINEAR_KEY_HEADS == 0 else None) \
+        @ layer["w_out"]
 
 
 def _attention(layer, x, key_mask, p: SeqRecParams, kind, mesh, use_ring):

@@ -55,6 +55,14 @@ the weights), `seqrec_put` (sharding, the optimizer's state),
   step was traced: ``pallas`` (every such layer's recurrence through
   the kernels of ops/linear_attention_pallas.py) or ``xla``. A model
   without such a layer counts nothing here.
+* ``pio_train_seqrec_linear_attention_chain_tokens_total{impl}`` — the
+  same positions times layers, by the route of what such a layer runs
+  around the rule (convolution, SiLU, unit length, head norm and gate)
+  when their step was traced: ``pallas`` (every such layer through the
+  fused passes of ops/linear_attention_pallas.gated_delta_chain_pallas)
+  or ``xla``. The chain's route is the rule's
+  (ops/linear_attention.gated_delta_chain decides both), so the two
+  counters move together.
 * ``pio_train_seqrec_mixer_tokens_total{mixer}`` — positions of the
   trained batches, padding too, times the layers of each mixer
   (``mha``, ``mla``, ``gqa``, ``gdn``, ``conv``) the compiled step ran.
@@ -199,6 +207,16 @@ def seqrec_linear_attention_tokens(registry: MetricsRegistry = None):
         labelnames=("impl",))
 
 
+def seqrec_linear_attention_chain_tokens(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_linear_attention_chain_tokens_total",
+        "Positions of the trained batches times the linear-attention "
+        "layers, by the route their step's chain around the delta rule "
+        "was traced on (ops/linear_attention.gated_delta_chain: the "
+        "rule's)",
+        labelnames=("impl",))
+
+
 def seqrec_mixer_tokens(registry: MetricsRegistry = None):
     return (registry or default_registry()).counter(
         "pio_train_seqrec_mixer_tokens_total",
@@ -272,9 +290,10 @@ def observe_seqrec_record(record: dict, targets, rows,
     (models/seqrec.train_seqrec): `targets` the padded target ids of all
     sessions, `rows` the sessions of each step's batch, `attention_impl`
     and `linear_attention_impl` the routes its step's softmax and linear
-    attention were traced on, `mixer_layers` the layer passes that step
-    ran by mixer, `layer_passes` those of its first pass and of its
-    repeats (None from a step of one pass: all are first)."""
+    attention (the rule and the chain around it) were traced on,
+    `mixer_layers` the layer passes that step ran by mixer,
+    `layer_passes` those of its first pass and of its repeats (None from
+    a step of one pass: all are first)."""
     import numpy as np
 
     real = sum(int((targets[r] > 0).sum()) for r in rows)
@@ -295,8 +314,10 @@ def observe_seqrec_record(record: dict, targets, rows,
     if set(mixer_layers) & {"mha", "mla", "gqa"}:
         seqrec_attention_tokens().inc(positions, impl=attention_impl)
     if "gdn" in mixer_layers:
-        seqrec_linear_attention_tokens().inc(
-            positions * mixer_layers["gdn"], impl=linear_attention_impl)
+        for counter in (seqrec_linear_attention_tokens,
+                        seqrec_linear_attention_chain_tokens):
+            counter().inc(positions * mixer_layers["gdn"],
+                          impl=linear_attention_impl)
     if "load" not in record or not record["load"]:
         return
     load = np.asarray(record["load"], np.float64)      # [step, layer, expert]

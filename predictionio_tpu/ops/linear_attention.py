@@ -21,16 +21,19 @@ the widths and the devices the program is traced for:
   program for one device, widths in whole lane tiles): a head's state
   stays in VMEM across its chunks and a chunk's system, inverse and
   scores never reach HBM; a backward kernel of their own, which keeps
-  the rule's inputs and each chunk's starting state and inverse;
+  the rule's inputs and each chunk's starting state and inverse. On
+  this route a layer runs everything between its two projections as
+  fused passes around the kernels (`gated_delta_chain`, whose route is
+  the rule's);
 * `_scan`, XLA operations everywhere else: the systems solved for all
   chunks at once, then a `lax.scan` over the chunks that carries S. Its
   backward pass is the scan's own, with the scan's body under
   `jax.checkpoint`, so that a chunk keeps the state it started from
   (B x H x dk x dv a chunk: 512 MiB a layer at 32 heads of 128 x 128 and
   256 chunks) and recomputes its four products; the caller bounds what
-  else is kept (`models/seqrec` takes a layer's heads a group at a time
-  under `remat`). PERF.md section 6, PRs 31 and 32, has the readings
-  behind the choices.
+  else is kept (`gated_delta_chain` takes a layer's heads `group` key
+  heads at a time, which `models/seqrec` asks for under `remat`).
+  PERF.md section 6, PRs 31 and 32, has the readings behind the choices.
 
 Precision: the system's matrix (beta k_i . k_j, decayed), its inverse
 and the two products with the inverse are computed in float32 at the
@@ -162,8 +165,8 @@ _ROUTES: contextvars.ContextVar[Optional[Set[str]]] = contextvars.ContextVar(
 
 @contextlib.contextmanager
 def routes_into(routes: Set[str]) -> Iterator[None]:
-    """While the block runs (a trace), every `gated_delta_rule` call adds
-    the route it took to `routes`."""
+    """While the block runs (a trace), every `gated_delta_rule` and
+    `gated_delta_chain` call adds the route it took to `routes`."""
     token = _ROUTES.set(routes)
     try:
         yield
@@ -171,21 +174,85 @@ def routes_into(routes: Set[str]) -> Iterator[None]:
         _ROUTES.reset(token)
 
 
+def _heard(route: str) -> None:
+    heard = _ROUTES.get()
+    if heard is not None:
+        heard.add(route)
+
+
+def gated_delta_chain(qkvz: jax.Array, taps: jax.Array, g: jax.Array,
+                      beta: jax.Array, scale: jax.Array, heads, eps: float,
+                      devices: int = 1,
+                      group: Optional[int] = None) -> jax.Array:
+    """A linear-attention layer between its two projections: qkvz
+    [B, L, ...] (the input projection's output, columns [q | k | v | z]:
+    Hk x dk, Hk x dk, Hv x dv, Hv x dv), taps [K, q, k and v's columns]
+    (`causal_conv`'s), g, beta [B, L, Hv], scale [dv], heads (Hk, Hv, dk,
+    dv) -> [B, L, Hv x dv], the output projection's input: the gated
+    delta rule on SiLU(conv(q, k, v)), q and k of unit length a head and
+    q scaled by dk ** -0.5, its output normed a head (RMS, `eps`,
+    `scale`) times SiLU(z). The route is the rule's
+    (`gated_delta_rule_route`), and whoever listens hears it:
+
+    * "pallas": `linear_attention_pallas.gated_delta_chain_pallas`, all
+      heads at once as fused passes around the rule's kernels with a
+      backward pass of their own, which keeps what the forward pass it
+      follows made and recomputes nothing (`group` is not read);
+    * "xla": the plain chain, XLA's to fuse, around `gated_delta_rule`'s
+      scan. `group` key heads (a divisor of Hk) and the value heads they
+      serve are taken at a time, each group's internals recomputed in the
+      backward pass; None: all at once, nothing recomputed here."""
+    hk, hv, dk, dv = heads
+    route = route_here(dk, dv, devices)
+    _heard(route)
+    if route == "pallas":
+        return linear_attention_pallas.gated_delta_chain_pallas(
+            qkvz, taps, g, beta, scale, tuple(heads), eps, CHUNK)
+    b, l, _ = qkvz.shape
+    cuts = [hk * dk, 2 * hk * dk, 2 * hk * dk + hv * dv]
+    q, k, v, z = jnp.split(qkvz, cuts, axis=-1)
+    conv_q, conv_k, conv_v = jnp.split(taps, cuts[:2], axis=-1)
+    n = hk // group if group else 1
+
+    def groups(t):          # [..., heads x width] -> [n, ..., heads / n x width]
+        return jnp.moveaxis(t.reshape(*t.shape[:-1], n, -1), -2, 0)
+
+    def unit(t):            # [B, L, heads x dk] -> unit length, a key head
+        t = t.reshape(b, l, -1, dk)
+        return t * jax.lax.rsqrt((t * t).sum(-1, keepdims=True) + 1e-6)
+
+    def one(args):
+        q, k, v, z, conv_q, conv_k, conv_v, g, beta = args
+        q, k, v = (causal_conv(t, w) for t, w in
+                   ((q, conv_q), (k, conv_k), (v, conv_v)))
+        o = gated_delta_rule(unit(q) * dk ** -0.5, unit(k),
+                             v.reshape(b, l, -1, dv), g, beta, devices=devices)
+        o = o * jax.lax.rsqrt((o * o).mean(-1, keepdims=True) + eps) * scale
+        return (o * jax.nn.silu(z.reshape(b, l, -1, dv))).reshape(b, l, -1)
+
+    args = tuple(map(groups, (q, k, v, z, conv_q, conv_k, conv_v, g, beta)))
+    if n > 1:
+        o = jax.lax.map(jax.checkpoint(one), args)
+    else:
+        o = one(tuple(t[0] for t in args))[None]
+    return jnp.moveaxis(o, 0, 2).reshape(b, l, -1)
+
+
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                      beta: jax.Array, devices: int = 1) -> jax.Array:
-    """q, k [B, L, H, dk] (normalised and scaled by the caller), v
-    [B, L, H, dv], g [B, L, H] (the decay's logarithm, <= 0), beta
-    [B, L, H] -> o [B, L, H, dv], from a state of 0. Any length: it is
-    filled up with positions that write nothing (k = 0).
+    """q, k [B, L, Hk, dk] (normalised and scaled by the caller; Hk key
+    heads, each serving H / Hk value heads in a row), v [B, L, H, dv], g
+    [B, L, H] (the decay's logarithm, <= 0), beta [B, L, H] -> o
+    [B, L, H, dv], from a state of 0. Any length: it is filled up with
+    positions that write nothing (k = 0). The kernels read a key head
+    where it lies; the scan takes q and k repeated to the value heads.
     `gated_delta_rule_route` says from the device's kind, the sizes and
     `devices` (how many devices the calling program is traced for: a
     mesh's size) whether the chunks are taken by Pallas kernels or by
     XLA operations and a scan."""
     l = q.shape[1]
     route = route_here(q.shape[-1], v.shape[-1], devices)
-    heard = _ROUTES.get()
-    if heard is not None:
-        heard.add(route)
+    _heard(route)
     # whole chunks; for the kernels, whole grid steps
     pad = -l % (CHUNK * (linear_attention_pallas.CHUNKS[-1]
                          if route == "pallas" else 1))
@@ -197,5 +264,7 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         o = linear_attention_pallas.gated_delta_rule_pallas(q, k, v, g, beta,
                                                             CHUNK)
     else:
-        o = _scan(q, k, v, g, beta)
+        group = v.shape[2] // q.shape[2]
+        o = _scan(*(jnp.repeat(t, group, axis=2) if group > 1 else t
+                    for t in (q, k)), v, g, beta)
     return o[:, :l].astype(v.dtype)

@@ -345,6 +345,56 @@ def test_a_step_adds_what_the_references_adamw_adds():
     assert np.asarray(stats["load"]).shape == (4, 16)
 
 
+@pytest.mark.parametrize("pad", [0, 5])
+def test_a_step_on_the_kernels_route_is_the_step_on_the_scans(monkeypatch,
+                                                              pad):
+    """A linear layer whose widths the kernels tile, on both routes (the
+    device's kind patched, the kernels interpreted, their products'
+    operands left float32): the same loss and gradient norms by group,
+    and the step reports the route, which `gated_delta_chain` takes for
+    the rule and the chain around it alike: the kernels' step runs the
+    fused chain once a linear layer and no rule outside it."""
+    from predictionio_tpu.ops import attention_pallas, linear_attention_pallas
+
+    monkeypatch.setattr(linear_attention, "CHUNK", 64)
+    p = small_spec(n_layers=2, mixer=("gdn", "gqa"), linear_key_heads=1,
+                   linear_value_heads=2, linear_key_head_dim=128,
+                   linear_value_head_dim=128)
+    params = weights(p)
+    optimizer = seqrec.make_optimizer(p)
+    seqs, targets = batch(seed=4, pad=pad)
+
+    def step():
+        with jax.default_matmul_precision("highest"):
+            return seqrec.make_train_step(None, p, optimizer)(
+                jax.tree.map(jnp.copy, params), optimizer.init(params),
+                jnp.asarray(seqs), jnp.asarray(targets))[2]
+
+    scan = step()
+    assert not scan["linear_attention_pallas"]
+    monkeypatch.setattr(linear_attention, "_device_kind",
+                        lambda: attention_pallas.KINDS[0])
+    monkeypatch.setattr(linear_attention_pallas, "_BF16", jnp.float32)
+    chain, calls = linear_attention_pallas.gated_delta_chain_pallas, []
+    monkeypatch.setattr(
+        linear_attention_pallas, "gated_delta_chain_pallas",
+        lambda *a: calls.append(a[5]) or chain(*a, True))
+    monkeypatch.setattr(linear_attention_pallas, "gated_delta_rule_pallas",
+                        lambda *a: pytest.fail("the rule outside the chain"))
+    kernels = step()
+    assert kernels["linear_attention_pallas"]
+    assert calls == [(1, 2, 128, 128)]
+    assert abs(float(kernels["loss"]) - float(scan["loss"])) \
+        < 2e-6 * float(scan["loss"])
+    assert set(kernels["grad_norm"]) == set(scan["grad_norm"])
+    for group, norm in scan["grad_norm"].items():
+        assert abs(float(kernels["grad_norm"][group]) - float(norm)) \
+            < 1e-4 * float(norm), group
+    for group, norm in scan["update_norm"].items():
+        assert abs(float(kernels["update_norm"][group]) - float(norm)) \
+            < 1e-3 * float(norm), group
+
+
 def test_a_train_counts_its_positions_by_mixer():
     """`pio_train_seqrec_mixer_tokens_total{mixer}`: positions of the
     trained batches times the layers of each kind the step ran; the
@@ -359,7 +409,14 @@ def test_a_train_counts_its_positions_by_mixer():
 
     series = [("pio_train_seqrec_mixer_tokens_total", {"mixer": "gdn"}),
               ("pio_train_seqrec_mixer_tokens_total", {"mixer": "gqa"}),
-              ("pio_train_seqrec_attention_tokens_total", {"impl": "xla"})]
+              ("pio_train_seqrec_attention_tokens_total", {"impl": "xla"}),
+              # the chain around the rule follows the rule's route
+              ("pio_train_seqrec_linear_attention_tokens_total",
+               {"impl": "xla"}),
+              ("pio_train_seqrec_linear_attention_chain_tokens_total",
+               {"impl": "xla"}),
+              ("pio_train_seqrec_linear_attention_chain_tokens_total",
+               {"impl": "pallas"})]
     before = [counted(name, **labels) for name, labels in series]
     p = small_spec(epochs=1, batch_size=2, device_init=True)
     sessions = [[f"i{(3 * s + j * (1 + s % 2)) % 50:02d}"
@@ -370,7 +427,8 @@ def test_a_train_counts_its_positions_by_mixer():
     gained = [counted(name, **labels) - b
               for (name, labels), b in zip(series, before)]
     positions = 2 * 2 * L
-    assert gained == [3 * positions, positions, positions]
+    assert gained == [3 * positions, positions, positions, 3 * positions,
+                      3 * positions, 0]
 
 
 def test_recommend_next_through_the_hybrid():

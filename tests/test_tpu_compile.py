@@ -147,6 +147,83 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip, name, b, h, length, dk,
         assert kernel in text
 
 
+@pytest.mark.parametrize("name,b,hk,h,length", [
+    # qwen3next-a3b-ep16.train: 16 key heads serve 32 value heads: a grid
+    # step's two value heads share one key head read where it lies, and
+    # the backward kernel adds their `dq`, `dk` in VMEM
+    ("cell", 1, 16, 32, 16384),
+    # four value heads a key head: two grid steps' partial sums a key head
+    ("a-part-of-a-group", 1, 1, 4, 256),
+    ("three-a-key-head-one-a-step", 1, 2, 6, 256),
+])
+def test_delta_rule_kernels_at_key_heads_compile_for_v5e(one_chip, name, b,
+                                                         hk, h, length):
+    from predictionio_tpu.ops import linear_attention, linear_attention_pallas
+
+    def loss(q, k, v, g, beta, w):
+        return (linear_attention_pallas.gated_delta_rule_pallas(
+            q, k, v, g, beta, linear_attention.CHUNK) * w).sum()
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    grad = jax.grad(loss, (0, 1, 2, 3, 4))
+    operands = (shape(b, length, hk, 128), shape(b, length, hk, 128),
+                shape(b, length, h, 128), shape(b, length, h),
+                shape(b, length, h), shape(b, length, h, 128))
+    # dq, dk come back at the key heads
+    assert [t.shape for t in jax.eval_shape(grad, *operands)[:2]] \
+        == [(b, length, hk, 128)] * 2
+    compiled = jax.jit(grad).lower(*operands).compile()
+    text = compiled.as_text()
+    for kernel in ("gated_delta_rule_pallas_fwd",
+                   "gated_delta_rule_pallas_bwd"):
+        assert kernel in text
+
+
+@pytest.mark.parametrize("name,b,length,heads,taps", [
+    # qwen3next-a3b-ep16.train: 16,384 positions, 16 key heads serving 32
+    # value heads of 128, a convolution of 4: blocks of 512 rows by four
+    # heads, a block's three earlier rows read from the eight before it
+    ("cell", 1, 16384, (16, 32, 128, 128), 4),
+    # heads of 256, an odd number of them, two batch rows, a length that
+    # is filled up to whole blocks, a convolution that reaches 16 rows
+    ("wide-odd", 2, 1000, (1, 3, 256, 256), 12),
+])
+def test_the_chains_fused_passes_compile_for_v5e(one_chip, name, b, length,
+                                                 heads, taps):
+    """`gated_delta_chain_pallas` forward and backward at real widths:
+    Mosaic takes the passes' blocks, their unaligned row offsets into
+    VMEM and the projection's gradient written in place, a block of
+    columns a kernel."""
+    from predictionio_tpu.ops import linear_attention, linear_attention_pallas
+
+    hk, hv, dk, dv = heads
+    total = 2 * hk * dk + 2 * hv * dv
+
+    def loss(qkvz, w_taps, g, beta, scale, w):
+        return (linear_attention_pallas.gated_delta_chain_pallas(
+            qkvz, w_taps, g, beta, scale, heads, 1e-6,
+            linear_attention.CHUNK) * w).sum()
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
+        shape(b, length, total), shape(taps, total - hv * dv),
+        shape(b, length, hv), shape(b, length, hv), shape(dv),
+        shape(b, length, hv * dv)).compile().as_text()
+    for kernel, calls in (("gdn_chain_front_fwd", 3),
+                          ("gdn_chain_back_fwd", 0),   # nobody reads it
+                          ("gdn_chain_front_bwd", 3),
+                          ("gdn_chain_back_bwd", 1),
+                          ("gated_delta_rule_pallas_fwd", 1),
+                          ("gated_delta_rule_pallas_bwd", 1)):
+        # (outside a step's scopes the compiler names a call
+        # `jvp_<kernel>_.<n>`)
+        assert _kernel_calls(text, f"{kernel}[_.0-9]*") == calls, kernel
+
+
 def _kernel_calls(text, name):
     import re
 
@@ -158,7 +235,9 @@ def test_the_hybrid_cells_step_fits_a_v5e_with_all_heads_at_once(
     """qwen3next-a3b-ep16.train's step compiled for one described v5e: on
     the kernels' route a linear layer takes all its heads at once, its
     forward kernel runs twice a layer (the block's pass and `remat`'s)
-    and no third time, and arguments + temporaries leave the 16 GB chip
+    and no third time, the fused passes around it likewise (the front's
+    three calls and the back's one twice forward, once backward: PERF.md
+    section 6, PR 39), and arguments + temporaries leave the 16 GB chip
     1 GB and more (the rule by which the head groups went; PERF.md
     section 6, PR 32)."""
     import json
@@ -191,6 +270,11 @@ def test_the_hybrid_cells_step_fits_a_v5e_with_all_heads_at_once(
     text = compiled.as_text()
     assert _kernel_calls(text, "gated_delta_rule_pallas_fwd") == 2 * 3
     assert _kernel_calls(text, "gated_delta_rule_pallas_bwd") == 3
+    for kernel, calls in (("gdn_chain_front_fwd", 3 * 2),
+                          ("gdn_chain_back_fwd", 2),
+                          ("gdn_chain_front_bwd", 3),
+                          ("gdn_chain_back_bwd", 1)):
+        assert _kernel_calls(text, kernel) == 3 * calls, kernel
     assert _kernel_calls(text, "flash_attention_pallas_bwd") == 1
     # four expert layers, twelve grouped products each
     assert _kernel_calls(text, "grouped_product_pallas_[a-z_]*") == 4 * 12

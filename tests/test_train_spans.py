@@ -575,17 +575,19 @@ def _gdn_spec(**over):
 
 
 def _on_rule_kernels(monkeypatch):
-    """`gated_delta_rule` as a v5e would route it, the kernels
-    interpreted."""
+    """`gated_delta_rule` and the chain around it as a v5e would route
+    them, the kernels interpreted."""
     from predictionio_tpu.ops import (
         attention_pallas, linear_attention, linear_attention_pallas,
     )
 
     monkeypatch.setattr(linear_attention, "_device_kind",
                         lambda: attention_pallas.KINDS[0])
-    kernels = linear_attention_pallas.gated_delta_rule_pallas
-    monkeypatch.setattr(linear_attention_pallas, "gated_delta_rule_pallas",
-                        lambda *a: kernels(*a, True))
+    for name in ("gated_delta_rule_pallas", "gated_delta_chain_pallas"):
+        kernels = getattr(linear_attention_pallas, name)
+        monkeypatch.setattr(
+            linear_attention_pallas, name,
+            lambda *a, kernels=kernels: kernels(*a, True))
 
 
 def test_a_sequence_model_train_counts_its_linear_attention_tokens_by_route(

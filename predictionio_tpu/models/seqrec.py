@@ -34,7 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from predictionio_tpu.core.params import Params
 from predictionio_tpu.obs import jax_stats, train_stats
 from predictionio_tpu.obs.tracing import span
-from predictionio_tpu.ops import linear_attention, moe
+from predictionio_tpu.ops import linear_attention, moe, state_space
 from predictionio_tpu.ops.attention import (
     blockwise_attention, ring_attention_traced, rope, routes_into,
 )
@@ -91,8 +91,10 @@ class SeqRecParams(Params):
     #: (1 + a weight drawn 0)
     norm: str = "layer"
     norm_eps: float = 1e-6
-    #: "learned" (a table of max_len rows added to the embeddings) or
+    #: "learned" (a table of max_len rows added to the embeddings),
     #: "rope" (rotary, in attention; halves pairing, ops/attention.rope)
+    #: or "none" (attention reads no position: the order is carried by
+    #: the recurrent mixers beside it)
     positions: str = "learned"
     rope_theta: float = 10000.0
     #: softmax over the item embeddings, or over a head matrix of its own
@@ -110,12 +112,37 @@ class SeqRecParams(Params):
     #: whether a "gqa" mixer's output is gated; without, its query
     #: projection has no gate's half (`wq` in place of `wq_gate`)
     attention_gate: bool = True
+    #: whether a "gqa" mixer norms its queries and keys over the head
+    qk_norm: bool = True
     conv_kernel: int = 0
     linear_key_heads: int = 0
     linear_value_heads: int = 0
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel: int = 0
+    #: the "ssm" mixer's sizes as ONE record, the published counts
+    #: (`StateSpaceMixer`'s fields: heads, head_dim, groups, state,
+    #: conv_kernel, chunk); None where no layer is one
+    ssm: Optional[Dict[str, int]] = None
+    #: a layer of ONE sub-layer each (one norm, one residual) in place of
+    #: a mixer and then a feed-forward: the kinds in order, mixers and
+    #: feed-forwards alike ("gqa", "moe", "ssm", ...), one period of
+    #: them repeated over the layers as `mixer`'s is; a feed-forward's
+    #: kind is then its layer's own. () = every layer is `mixer` then
+    #: `ffn`
+    sublayers: Sequence[str] = ()
+    #: the tensor-parallel share held here is one of this many. n_heads,
+    #: n_kv_heads, the ssm record's heads and groups and the shared
+    #: expert's width stay the published counts; a "gqa" mixer holds
+    #: n_heads / ways query heads with the key/value heads they read, an
+    #: "ssm" mixer its heads and groups likewise, a shared expert its
+    #: columns (the weights are drawn at the held sizes: which rank's
+    #: they are is the loader's to say, no step reads it). The held
+    #: part's output projection gives this chip's partial sum, and that
+    #: partial result goes on to the next layer: nothing stands in for
+    #: the other ranks or their all-reduce (as `held_experts` for the
+    #: routed experts)
+    tensor_ways: int = 1
     #: the router's width: every expert of a layer, wherever it lives
     n_routed_experts: int = 0
     #: [first, end) of them are held (and trained) here; the others lie
@@ -123,6 +150,14 @@ class SeqRecParams(Params):
     held_experts: Sequence[int] = (0, 0)
     experts_per_token: int = 0
     moe_width: int = 0
+    #: what an expert (routed or shared) computes (`moe.EXPERT_KINDS`):
+    #: "swiglu", three matrices, or "relu2", relu(x W_up)^2 W_down
+    expert_act: str = "swiglu"
+    #: the routed experts live in a latent of this width between a down-
+    #: and an up-projection of the layer's own (the router and the shared
+    #: expert read the full state; the gates apply in the latent); 0 =
+    #: they read and write d_model
+    moe_latent_size: int = 0
     n_shared_experts: int = 0
     #: the shared expert's output times a sigmoid of x . w (one column)
     shared_expert_gate: bool = False
@@ -151,6 +186,15 @@ class SeqRecParams(Params):
     #: what is left. Serving answers from the last pass
     exit_gate: bool = False
     exit_entropy_beta: float = 0.0
+    #: a multi-token-prediction module after the stack (training only):
+    #: its sub-layers' kinds, as `sublayers` names them. Position t's
+    #: [rms(Emb(item t+1)) | rms(the stack's state before its last norm)]
+    #: through a projection of 2 d -> d, these layers with weights of
+    #: their own and a last norm, then the MODEL's head, scored against
+    #: item t + 2; loss = main + mtp_loss_weight x the module's. Serving
+    #: answers from the main head alone
+    mtp_layers: Sequence[str] = ()
+    mtp_loss_weight: float = 0.0
 
     #: draw the initial weights on the device (jax.random) instead of on
     #: the host in numpy: the same seed gives the same weights either way,
@@ -164,19 +208,52 @@ class SeqRecParams(Params):
     #: identity)
     remat: bool = False
 
-    def mixer_kind(self, layer: int) -> str:
+    def mixer_kind(self, layer: int) -> Optional[str]:
+        """The layer's mixer; None where the layer is a feed-forward
+        alone (`sublayers`)."""
+        if self.sublayers:
+            return _sub_layer(self.sublayers[layer % len(self.sublayers)])[0]
         period = (self.mixer,) if isinstance(self.mixer, str) \
             else tuple(self.mixer)
         return period[layer % len(period)]
 
     def mixer_kinds(self) -> Tuple[str, ...]:
-        """Each layer's mixer."""
-        return tuple(self.mixer_kind(i) for i in range(self.n_layers))
+        """Each layer's mixer, of the layers that have one."""
+        kinds = (self.mixer_kind(i) for i in range(self.n_layers))
+        return tuple(kind for kind in kinds if kind)
 
-    def ffn_kind(self, layer: int) -> str:
+    def ffn_kind(self, layer: int) -> Optional[str]:
+        """The layer's feed-forward; None where the layer is a mixer
+        alone (`sublayers`)."""
+        if self.sublayers:
+            return _sub_layer(self.sublayers[layer % len(self.sublayers)])[1]
         if self.ffn == "moe" and layer < self.first_dense_layers:
             return "swiglu"
         return self.ffn
+
+    def mtp_kinds(self) -> Tuple[Tuple[Optional[str], Optional[str]], ...]:
+        """(mixer, feed-forward) of the multi-token-prediction module's
+        layers."""
+        return tuple(map(_sub_layer, self.mtp_layers))
+
+    def layer_kinds(self) -> Tuple[Tuple[Optional[str], Optional[str]], ...]:
+        """(mixer, feed-forward) of every layer a train step runs: the
+        stack's, then the module's."""
+        return tuple((self.mixer_kind(i), self.ffn_kind(i))
+                     for i in range(self.n_layers)) + self.mtp_kinds()
+
+    def has_experts(self) -> bool:
+        return any(ffn == "moe" for _, ffn in self.layer_kinds())
+
+    def state_space(self) -> "StateSpaceMixer":
+        """The "ssm" mixer's record at the sizes held here."""
+        return StateSpaceMixer(**self.ssm).held(self.tensor_ways)
+
+    def held(self, count: int) -> int:
+        """How many of `count` heads or columns this tensor share
+        holds; of fewer key/value heads than ranks, the one its query
+        heads read."""
+        return max(1, count // self.tensor_ways)
 
     def dense_width(self) -> int:
         return self.ffn_width or 4 * self.d_model
@@ -189,30 +266,39 @@ class SeqRecParams(Params):
         spec = dataclasses.asdict(self)
         for name in ("epochs",) + (() if memory else MEMORY_FIELDS):
             del spec[name]
-        return tuple(sorted((k, tuple(v) if isinstance(v, (list, tuple))
-                             else v) for k, v in spec.items()))
+        return tuple(sorted(
+            (k, tuple(v) if isinstance(v, (list, tuple))
+             else tuple(sorted(v.items())) if isinstance(v, dict) else v)
+            for k, v in spec.items()))
 
     def check(self) -> None:
         if not self.mixer:
             raise ValueError("mixer names no kind")
-        unknown = set(self.mixer_kinds()) - set(MIXERS)
+        for name in ("sublayers", "mtp_layers"):
+            unknown = set(getattr(self, name)) - set(MIXERS) - set(FFNS)
+            if unknown:
+                raise ValueError(f"unknown {name} {sorted(unknown)}: "
+                                 f"expected among {MIXERS + FFNS}")
+        mixers = {mixer for mixer, _ in self.layer_kinds() if mixer}
+        unknown = mixers - set(MIXERS)
         if unknown:
             raise ValueError(f"unknown mixer {sorted(unknown)}: expected "
                              f"among {MIXERS}")
-        for name, kinds in (("ffn", ("gelu", "swiglu", "moe")),
+        for name, kinds in (("ffn", FFNS),
                             ("norm", ("layer", "rms", "rms_zero_centered")),
-                            ("positions", ("learned", "rope")),
+                            ("positions", ("learned", "rope", "none")),
                             ("attention_impl", ("flash", "ring")),
-                            ("router_scoring", ("sigmoid", "softmax"))):
+                            ("router_scoring", ("sigmoid", "softmax")),
+                            ("expert_act", moe.EXPERT_KINDS)):
             if getattr(self, name) not in kinds:
                 raise ValueError(f"unknown {name} {getattr(self, name)!r}: "
                                  f"expected one of {kinds}")
-        new = set(self.mixer_kinds()) & {"gqa", "gdn", "conv"}
+        new = mixers & {"gqa", "gdn", "conv", "ssm"}
         if new:
             # what these mixers are not defined with: their norms are RMS
-            # norms, their only positions rotary (gqa) or the convolution's
-            # (gdn, conv), and the ring takes one key/value head a query
-            # head
+            # norms, their only positions rotary (gqa), the convolution's
+            # (gdn, conv) or the state's (ssm), and the ring takes one
+            # key/value head a query head
             for name, refused in (("norm", "layer"),
                                   ("positions", "learned"),
                                   ("attention_impl", "ring")):
@@ -226,7 +312,9 @@ class SeqRecParams(Params):
                     f"gqa needs head_dim > 0 and n_kv_heads a divisor of "
                     f"n_heads: {self.head_dim}, {self.n_kv_heads}, "
                     f"{self.n_heads}")
-            if not 0 < self.rotary_dim <= self.head_dim or self.rotary_dim % 2:
+            if self.positions == "rope" and (
+                    not 0 < self.rotary_dim <= self.head_dim
+                    or self.rotary_dim % 2):
                 raise ValueError(f"rotary_dim {self.rotary_dim} is no even "
                                  f"part of head_dim {self.head_dim}")
         if "conv" in new and self.conv_kernel < 1:
@@ -242,7 +330,37 @@ class SeqRecParams(Params):
                     f"gdn needs its five linear_* sizes > 0 and "
                     f"linear_key_heads a divisor of linear_value_heads: "
                     f"{sizes}")
-        if self.shared_expert_gate and not (self.ffn == "moe"
+        if self.positions == "none" and mixers - {"gqa", "ssm"}:
+            raise ValueError("positions 'none' goes with the mixers gqa "
+                             f"and ssm, not {sorted(mixers - {'gqa', 'ssm'})}")
+        if "ssm" in new:
+            StateSpaceMixer(**(self.ssm or {})).check()
+        ways = self.tensor_ways
+        if ways < 1:
+            raise ValueError(f"tensor_ways {ways} must be >= 1")
+        if ways > 1:
+            # who is told its share: gqa, ssm and the shared expert
+            untold = (mixers - {"gqa", "ssm"}) | ({
+                ffn for _, ffn in self.layer_kinds() if ffn} - {"moe"})
+            uneven = []
+            if "gqa" in mixers and (self.n_heads % ways or (
+                    self.n_kv_heads % ways and ways % self.n_kv_heads)):
+                uneven.append("n_heads or n_kv_heads")
+            if "ssm" in mixers and (self.ssm["heads"] % ways
+                                    or self.ssm["groups"] % ways):
+                uneven.append("the ssm record's heads or groups")
+            if self.n_shared_experts * self.moe_width % ways:
+                uneven.append("the shared expert's width")
+            if untold or uneven:
+                raise ValueError(
+                    f"a tensor share of {ways} ways: {sorted(untold)} hold "
+                    f"no share of their own, {uneven} do not divide")
+        if self.mtp_layers and self.n_loops > 1:
+            raise ValueError("mtp_layers does not go with n_loops > 1")
+        if self.moe_latent_size < 0 or self.mtp_loss_weight < 0:
+            raise ValueError("moe_latent_size and mtp_loss_weight must be "
+                             ">= 0")
+        if self.shared_expert_gate and not (self.has_experts()
                                             and self.n_shared_experts):
             raise ValueError("shared_expert_gate without a shared expert")
         if self.n_loops < 1:
@@ -252,7 +370,7 @@ class SeqRecParams(Params):
                              "before (n_loops 1)")
         if self.post_norm and self.norm == "layer":
             raise ValueError("post_norm does not go with norm 'layer'")
-        if self.ffn == "moe":
+        if self.has_experts():
             lo, hi = self.held_experts
             if not 0 <= lo < hi <= self.n_routed_experts:
                 raise ValueError(
@@ -263,7 +381,96 @@ class SeqRecParams(Params):
                                  "n_routed_experts")
 
 
-MIXERS = ("mha", "mla", "gqa", "gdn", "conv")
+@dataclasses.dataclass(frozen=True)
+class StateSpaceMixer:
+    """The "ssm" mixer (a Mamba-2 layer around `ops/state_space.scan`) as
+    one record: its sizes, the part of them a tensor share holds, its
+    weights and its layer function. `SeqRecParams.ssm` holds the
+    published sizes as a dict of these fields.
+
+    [z | x | B | C] = u W_in (widths H P, H P, G N, G N), dt = u W_dt
+    (H, at the highest precision: a decay compounds over a session);
+    [x | B | C] <- silu(causal_conv(.) + b_conv), depthwise; dt <-
+    softplus(dt + dt_bias); the scan at rate exp(A_log) with the skip D;
+    y <- rms(y silu(z)) w over each group's H P / G columns; y W_out."""
+
+    heads: int = 0
+    head_dim: int = 0
+    #: B/C pairs, each read by heads / groups heads in a row, and the
+    #: groups the output norm is taken over
+    groups: int = 0
+    state: int = 0
+    conv_kernel: int = 0
+    chunk: int = state_space.CHUNK
+
+    def check(self) -> None:
+        if min(dataclasses.astuple(self)) <= 0 or self.heads % self.groups:
+            raise ValueError(f"ssm needs its six sizes > 0 and groups a "
+                             f"divisor of heads: {self}")
+
+    def held(self, ways: int) -> "StateSpaceMixer":
+        """What one of `ways` tensor ranks holds: whole groups with
+        their heads."""
+        return dataclasses.replace(self, heads=self.heads // ways,
+                                   groups=self.groups // ways)
+
+    def widths(self) -> Tuple[int, int]:
+        """(the heads' columns H P, a B or C's columns G N)."""
+        return self.heads * self.head_dim, self.groups * self.state
+
+    def init(self, d: int, dense, uniform) -> Dict:
+        """Weights from the caller's draws (`dense(n_in, n_out)` N(0,
+        1/n_in), `uniform(shape, hi)` U(0, hi)): the convolution's bias
+        as one more row of its taps, A_log = log U(1, 16), D 1, dt_bias
+        the inverse softplus of a log-uniform step in [0.001, 0.1]
+        floored at 1e-4."""
+        hp, gn = self.widths()
+        step = jnp.maximum(jnp.exp(jnp.log(0.001) + uniform(
+            (self.heads,), 1.0) * (jnp.log(0.1) - jnp.log(0.001))), 1e-4)
+        return {"w_in": dense(d, 2 * hp + 2 * gn),
+                "w_dt": dense(d, self.heads),
+                "conv": dense(self.conv_kernel, hp + 2 * gn),
+                "conv_bias": dense(self.conv_kernel, hp + 2 * gn)[0],
+                "A_log": jnp.log(1.0 + uniform((self.heads,), 15.0)),
+                "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+                "D": jnp.ones((self.heads,), jnp.float32),
+                "norm": {"scale": jnp.ones((hp,), jnp.float32)},
+                "w_out": dense(hp, d)}
+
+    def apply(self, w: Dict, x: jax.Array, key_mask: jax.Array,
+              eps: float) -> jax.Array:
+        """Normed x [B, L, D] -> [B, L, D] (this share's partial sum). A
+        padding position's input is 0 and its step dt is 0: it neither
+        decays the state nor writes into it, and a left-padded session
+        is the unpadded one."""
+        b, l, _ = x.shape
+        hp, gn = self.widths()
+        x = jnp.where(key_mask[..., None], x, 0.0)
+        z, xbc = jnp.split(x @ w["w_in"], [hp], axis=-1)
+        dt = jax.nn.softplus(jnp.dot(
+            x, w["w_dt"], precision=jax.lax.Precision.HIGHEST) + w["dt_bias"])
+        xbc = jax.nn.silu(linear_attention.causal_conv(
+            xbc, w["conv"], activation=None) + w["conv_bias"])
+        xs, bs, cs = jnp.split(xbc, [hp, hp + gn], axis=-1)
+        y = state_space.scan(
+            xs.reshape(b, l, self.heads, self.head_dim),
+            jnp.where(key_mask[..., None], dt, 0.0), jnp.exp(w["A_log"]),
+            bs.reshape(b, l, self.groups, self.state),
+            cs.reshape(b, l, self.groups, self.state), w["D"], self.chunk)
+        y = (y.reshape(b, l, hp) * jax.nn.silu(z)).reshape(
+            b, l, self.groups, -1)
+        y = y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + eps)
+        return (y.reshape(b, l, hp) * w["norm"]["scale"]) @ w["w_out"]
+
+
+MIXERS = ("mha", "mla", "gqa", "gdn", "conv", "ssm")
+FFNS = ("gelu", "swiglu", "moe")
+
+
+def _sub_layer(kind: str) -> Tuple[Optional[str], Optional[str]]:
+    """A layer of one sub-layer of this kind as (mixer, feed-forward)."""
+    return (kind if kind in MIXERS else None,
+            kind if kind in FFNS else None)
 
 #: settings that change where a train's work lies and what it keeps in
 #: memory, not what it computes
@@ -348,22 +555,37 @@ def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
     def dense(n_in, n_out):
         return normal((n_in, n_out), n_in ** -0.5)
 
-    def swiglu(width, experts=()):
+    def swiglu(width, experts=(), d=d):
         return {"w_gate": normal((*experts, d, width), d ** -0.5),
                 "w_up": normal((*experts, d, width), d ** -0.5),
                 "w_down": normal((*experts, width, d), width ** -0.5)}
 
-    def mixer(i):
-        kind, h = p.mixer_kind(i), p.n_heads
+    def expert(width, experts=(), d=d):
+        """An expert's matrices, routed or shared: `expert_act`'s."""
+        if p.expert_act == "swiglu":
+            return swiglu(width, experts, d)
+        return {"w_up": normal((*experts, d, width), d ** -0.5),
+                "w_down": normal((*experts, width, d), width ** -0.5)}
+
+    def mixer(kind):
+        h = p.n_heads
+        if kind is None:
+            return {}
         if kind == "mha":
             return {"wqkv": dense(d, 3 * d), "wo": dense(d, d)}
+        if kind == "ssm":
+            return {"ssm": p.state_space().init(d, dense, uniform)}
         if kind == "gqa":
+            # of a tensor share, the heads held here
+            h, kv = p.held(h), p.held(p.n_kv_heads)
             wq = {"wq_gate": dense(d, 2 * h * p.head_dim)} \
                 if p.attention_gate else {"wq": dense(d, h * p.head_dim)}
+            qk_norms = {"q_norm": norm(p.head_dim),
+                        "k_norm": norm(p.head_dim)} if p.qk_norm else {}
             return {**wq,
-                    "wk": dense(d, p.n_kv_heads * p.head_dim),
-                    "wv": dense(d, p.n_kv_heads * p.head_dim),
-                    "q_norm": norm(p.head_dim), "k_norm": norm(p.head_dim),
+                    "wk": dense(d, kv * p.head_dim),
+                    "wv": dense(d, kv * p.head_dim),
+                    **qk_norms,
                     "wo": dense(h * p.head_dim, d)}
         if kind == "conv":
             return {"conv_in": dense(d, 3 * d),
@@ -389,8 +611,9 @@ def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
                               h * (p.qk_nope_head_dim + p.v_head_dim)),
                 "wo": dense(h * p.v_head_dim, d)}
 
-    def ffn(i):
-        kind = p.ffn_kind(i)
+    def ffn(kind):
+        if kind is None:
+            return {}
         if kind == "gelu":
             return {"w1": dense(d, p.dense_width()),
                     "w2": dense(p.dense_width(), d)}
@@ -398,20 +621,30 @@ def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
             return swiglu(p.dense_width())
         lo, hi = p.held_experts
         out = {"router": dense(d, p.n_routed_experts),
-               "router_bias": jnp.zeros((p.n_routed_experts,), jnp.float32),
-               "experts": swiglu(p.moe_width, (hi - lo,))}
+               "router_bias": jnp.zeros((p.n_routed_experts,), jnp.float32)}
+        if p.moe_latent_size:
+            out["latent"] = {"w_dn": dense(d, p.moe_latent_size),
+                             "w_up": dense(p.moe_latent_size, d)}
+        out["experts"] = expert(p.moe_width, (hi - lo,),
+                                p.moe_latent_size or d)
         if p.n_shared_experts:
-            out["shared"] = swiglu(p.n_shared_experts * p.moe_width)
+            out["shared"] = expert(p.held(p.n_shared_experts * p.moe_width))
         if p.shared_expert_gate:
             out["shared_gate"] = dense(d, 1)
         return out
 
-    def norms():            # a leaf of its own each: a step donates them
-        names = ("ln1", "ln2") + (("post1", "post2") if p.post_norm else ())
-        return {name: norm() for name in names}
+    def layer_of(mixer_kind, ffn_kind):
+        # a norm is a leaf of its own: a step donates them. A layer of
+        # one sub-layer has that sub-layer's norms alone
+        names = [name for name, kind in (("ln1", mixer_kind),
+                                         ("ln2", ffn_kind)) if kind]
+        if p.post_norm:
+            names += [name.replace("ln", "post") for name in names]
+        # draws in this order: the host path's are the original block's
+        return {**{name: norm() for name in names}, **mixer(mixer_kind),
+                **ffn(ffn_kind)}
 
-    # draws in this order: the host path's are the original block's
-    layers = [{**norms(), **mixer(i), **ffn(i)} for i in range(p.n_layers)]
+    layers = [layer_of(*kinds) for kinds in p.layer_kinds()[:p.n_layers]]
     params = {"emb": normal((v, d), d ** -0.5)}
     if p.positions == "learned":
         params["pos"] = normal((p.max_len, d), d ** -0.5)
@@ -422,6 +655,11 @@ def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
     if p.exit_gate:
         params["exit_gate"] = {"w": jnp.zeros((d,), jnp.float32),
                                "b": jnp.zeros((), jnp.float32)}
+    if p.mtp_layers:
+        params["mtp"] = {
+            "norm_e": norm(), "norm_h": norm(), "w_eh": dense(2 * d, d),
+            "layers": [layer_of(*kinds) for kinds in p.mtp_kinds()],
+            "ln_f": norm()}
     return params
 
 
@@ -433,7 +671,8 @@ STEP_SCOPES = (
     "seqrec_embed", "seqrec_norm", "seqrec_attention",
     "seqrec_linear_attention", "seqrec_short_conv", "seqrec_router",
     "seqrec_experts", "seqrec_shared_expert", "seqrec_ffn",
-    "seqrec_head_loss", "seqrec_optimizer", "seqrec_record")
+    "seqrec_head_loss", "seqrec_optimizer", "seqrec_record",
+    "seqrec_state_space", "seqrec_latent_projection", "seqrec_mtp")
 
 
 def _rms_norm(x, scale, eps):
@@ -465,6 +704,10 @@ def _by_token_blocks(fn, p: SeqRecParams, *arrays):
 
 def _swiglu(w, x):
     return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
+
+
+def _relu2(w, x):
+    return jnp.square(jax.nn.relu(x @ w["w_up"])) @ w["w_down"]
 
 
 def _short_conv(layer, x, key_mask):
@@ -531,10 +774,16 @@ def _attention(layer, x, key_mask, p: SeqRecParams, kind, mesh, use_ring):
             q, gate = jnp.split(x @ layer["wq_gate"], 2, axis=-1)
         else:
             q = x @ layer["wq"]
-        q, k = (rope(_norm(t.reshape(b, l, -1, p.head_dim), w, p), positions,
-                     p.rope_theta, p.rotary_dim)
-                for t, w in ((q, layer["q_norm"]),
-                             (x @ layer["wk"], layer["k_norm"])))
+        def head_rows(t, norm_name):
+            t = t.reshape(b, l, -1, p.head_dim)
+            if p.qk_norm:
+                t = _norm(t, layer[norm_name], p)
+            if p.positions == "rope":
+                t = rope(t, positions, p.rope_theta, p.rotary_dim)
+            return t
+
+        q, k = (head_rows(t, name) for t, name in (
+            (q, "q_norm"), (x @ layer["wk"], "k_norm")))
         v = (x @ layer["wv"]).reshape(b, l, -1, p.head_dim)
     else:
         nope, rot = p.qk_nope_head_dim, p.qk_rope_head_dim
@@ -574,14 +823,22 @@ def _moe(layer, x, p: SeqRecParams, devices: int = 1):
                             p.experts_per_token, p.routed_scaling_factor,
                             p.norm_topk_prob, p.router_scoring,
                             p.router_norm_eps)
+    rows = flat
+    if "latent" in layer:      # the routed experts' own, narrower space
+        with jax.named_scope("seqrec_latent_projection"):
+            rows = flat @ layer["latent"]["w_dn"]
     with jax.named_scope("seqrec_experts"):
         ex = layer["experts"]
         y, held_tokens, dropped = moe.held_experts(
-            flat, ex["w_gate"], ex["w_up"], ex["w_down"], routing,
+            rows, ex.get("w_gate"), ex["w_up"], ex["w_down"], routing,
             p.held_experts[0], pass_rows=b * l, devices=devices)
+    if "latent" in layer:
+        with jax.named_scope("seqrec_latent_projection"):
+            y = y @ layer["latent"]["w_up"]
     if "shared" in layer:
         def shared(t):
-            out = _swiglu(layer["shared"], t)
+            out = (_swiglu if p.expert_act == "swiglu" else _relu2)(
+                layer["shared"], t)
             if "shared_gate" in layer:
                 out = out * jax.nn.sigmoid(t @ layer["shared_gate"])
             return out
@@ -597,13 +854,19 @@ def _moe(layer, x, p: SeqRecParams, devices: int = 1):
 
 
 def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
-             mesh: Optional[Mesh] = None
-             ) -> Tuple[Sequence[jax.Array], List[Dict], Dict[str, int]]:
+             mesh: Optional[Mesh] = None,
+             next_items: Optional[jax.Array] = None
+             ) -> Tuple[Sequence[jax.Array], List[Dict], Dict[str, int],
+                        Optional[jax.Array]]:
     """[B, L] int32 item ids (0 = pad) -> (the [B, L, D] hidden states of
     each pass of the stack, the last norm's output, the last pass last
     (one entry at `n_loops` 1, a [n_loops, B, L, D] array otherwise), the
     balance numbers of each expert layer run, pass by pass, the layer
-    passes run by mixer)."""
+    passes run by mixer, the multi-token-prediction module's state or
+    None). With `next_items` [B, L] (each position's next item: the
+    targets) under `mtp_layers` the module runs too: its last norm's
+    output [B, L, D] is the fourth value, its expert layers' numbers come
+    after the stack's."""
     b, l = seqs.shape
     with jax.named_scope("seqrec_embed"):
         h = params["emb"][seqs]
@@ -627,19 +890,12 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
         return h + y
 
     def block(h, layer, mixer, kind):
-        with jax.named_scope("seqrec_norm"):
-            x = _norm(h, layer["ln1"], p)
-        if mixer == "conv":
-            with jax.named_scope("seqrec_short_conv"):
-                h = joined(h, _short_conv(layer, x, key_mask), layer, "post1")
-        elif mixer == "gdn":
-            with jax.named_scope("seqrec_linear_attention"):
-                h = joined(h, _linear_attention(layer, x, key_mask, p,
-                                                devices), layer, "post1")
-        else:                      # key mask keeps it out of the softmax
-            with jax.named_scope("seqrec_attention"):
-                h = joined(h, _attention(layer, x, key_mask, p, mixer, mesh,
-                                         use_ring), layer, "post1")
+        """A layer: its mixer, then its feed-forward; either may be None
+        (a layer of one sub-layer)."""
+        if mixer is not None:
+            h = mixed(h, layer, mixer)
+        if kind is None:
+            return h, None
         with jax.named_scope("seqrec_norm"):
             x = _norm(h, layer["ln2"], p)
         if kind == "moe":
@@ -651,39 +907,83 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
             y = _by_token_blocks(fn, p, x.reshape(b * l, -1))
         return joined(h, y.reshape(b, l, -1), layer, "post2"), None
 
+    def mixed(h, layer, mixer):
+        with jax.named_scope("seqrec_norm"):
+            x = _norm(h, layer["ln1"], p)
+        if mixer == "ssm":
+            with jax.named_scope("seqrec_state_space"):
+                return joined(h, p.state_space().apply(
+                    layer["ssm"], x, key_mask, p.norm_eps), layer, "post1")
+        if mixer == "conv":
+            with jax.named_scope("seqrec_short_conv"):
+                return joined(h, _short_conv(layer, x, key_mask), layer,
+                              "post1")
+        if mixer == "gdn":
+            with jax.named_scope("seqrec_linear_attention"):
+                return joined(h, _linear_attention(layer, x, key_mask, p,
+                                                   devices), layer, "post1")
+        with jax.named_scope("seqrec_attention"):
+            # the key mask keeps padding out of the softmax
+            return joined(h, _attention(layer, x, key_mask, p, mixer, mesh,
+                                        use_ring), layer, "post1")
+
     if p.remat:
         block = jax.checkpoint(block, static_argnums=(2, 3))
 
-    def stack(h):
-        """One pass: every layer, then the last norm."""
+    def layers_of(h, layers, kinds):
+        """The layers in turn -> (h, the expert layers' numbers)."""
         expert_layers = []
-        for i, layer in enumerate(params["layers"]):
-            h, stats = block(h, layer, p.mixer_kind(i), p.ffn_kind(i))
+        for layer, (mixer, kind) in zip(layers, kinds):
+            h, stats = block(h, layer, mixer, kind)
             if stats is not None:
                 expert_layers.append(stats)
-        with jax.named_scope("seqrec_norm"):
-            h = _norm(h, params["ln_f"], p)
         return h, expert_layers
 
+    def stack(h):
+        """One pass: every layer, then the last norm; beside it the
+        state the norm read."""
+        before, expert_layers = layers_of(h, params["layers"],
+                                          p.layer_kinds())
+        with jax.named_scope("seqrec_norm"):
+            h = _norm(before, params["ln_f"], p)
+        return h, expert_layers, before
+
+    with_mtp = bool(p.mtp_layers) and next_items is not None
     mixers: Dict[str, int] = {}
     for mixer in p.mixer_kinds():
         mixers[mixer] = mixers.get(mixer, 0) + p.n_loops
     if p.n_loops == 1:
-        h, expert_layers = stack(h)
-        return (jnp.where(pad, 0.0, h),), expert_layers, mixers
+        h, expert_layers, before = stack(h)
+        passes, module_state = (jnp.where(pad, 0.0, h),), None
+        if with_mtp:
+            module = params["mtp"]
+            with jax.named_scope("seqrec_mtp"):
+                h = jnp.concatenate(
+                    [_norm(params["emb"][next_items], module["norm_e"], p),
+                     _norm(before, module["norm_h"], p)], -1) @ module["w_eh"]
+            h, module_experts = layers_of(h, module["layers"],
+                                          p.mtp_kinds())
+            with jax.named_scope("seqrec_mtp"):
+                h = _norm(h, module["ln_f"], p)
+            for mixer, _ in p.mtp_kinds():
+                if mixer:
+                    mixers[mixer] = mixers.get(mixer, 0) + 1
+            module_state = jnp.where(pad, 0.0, h)
+            expert_layers = expert_layers + module_experts
+        return passes, expert_layers, mixers, module_state
 
     def one_pass(h, _):
         # the weights are the body's constants: the program holds the
         # stack once, and the scan's backward pass adds a weight's
         # gradient up over the passes
-        h, expert_layers = stack(h)
+        h, expert_layers, _ = stack(h)
         return h, (jnp.where(pad, 0.0, h), expert_layers)
 
     _, (passes, by_pass) = jax.lax.scan(one_pass, h, None, length=p.n_loops,
                                         unroll=LOOP_UNROLL)
     expert_layers = [jax.tree.map(lambda t: t[r], stats)
                      for r in range(p.n_loops) for stats in by_pass]
-    return passes, expert_layers, mixers
+    return passes, expert_layers, mixers, None
 
 
 def forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
@@ -721,8 +1021,10 @@ def _loss_fn(params, seqs, targets, p: SeqRecParams, mesh=None):
     `exit_entropy_beta` times that distribution's entropy. -> (loss,
     (the expert layers' balance numbers, the layer passes run by mixer,
     under `exit_gate` each pass's own loss `loop_loss` [R] and its mean
-    exit probability `exit_share` [R])."""
-    passes, expert_layers, mixers = _forward(params, seqs, p, mesh)
+    exit probability `exit_share` [R], under `mtp_layers` the module's
+    own loss `mtp_loss`, which joins the loss `mtp_loss_weight` times)."""
+    passes, expert_layers, mixers, module_state = _forward(
+        params, seqs, p, mesh, targets)
     head = head_matrix(params)
 
     def nll_of(hid, tgt):
@@ -758,6 +1060,18 @@ def _loss_fn(params, seqs, targets, p: SeqRecParams, mesh=None):
             loss = (per_target * real).sum() / n_targets
             exits = {"loop_loss": nll.sum(-1) / n_targets,
                      "exit_share": (prob * real).sum(-1) / n_targets}
+    if p.mtp_layers:
+        # position t's module state is scored against item t + 2, the
+        # next position's target; a session's last position has none
+        # (and a padding position in front of a session neither)
+        later = jnp.where(seqs != 0, jnp.concatenate(
+            [targets[:, 1:], jnp.zeros_like(targets[:, :1])], axis=1), 0)
+        with jax.named_scope("seqrec_mtp"):
+            nll = _by_token_blocks(
+                nll_of, p, module_state.reshape(-1, module_state.shape[-1]),
+                later.reshape(-1))
+            exits["mtp_loss"] = nll.sum() / jnp.maximum((later > 0).sum(), 1)
+            loss = loss + p.mtp_loss_weight * exits["mtp_loss"]
     if p.balance_loss_alpha and expert_layers:
         loss = loss + p.balance_loss_alpha * sum(
             s["balance"] for s in expert_layers)
@@ -769,12 +1083,18 @@ def grad_group(path) -> str:
     """The group a parameter's gradient norm is recorded under: tables
     and head by name, a layer's parameters by layer and part."""
     names = [str(getattr(k, "key", getattr(k, "idx", k))) for k in path]
+    prefix = "layer"
+    if names[:2] == ["mtp", "layers"]:
+        # the module's layers by their own parts ("mtp0.attention"), its
+        # norms and projection together as "mtp"
+        names, prefix = names[1:], "mtp"
     if names[0] != "layers":
         return {"emb": "embedding", "pos": "positions",
                 "ln_f": "final_norm"}.get(names[0], names[0])
     part = {"router": "router", "router_bias": "router",
             "experts": "experts", "shared": "shared_expert",
-            "shared_gate": "shared_expert",
+            "shared_gate": "shared_expert", "ssm": "state_space",
+            "latent": "latent_projection",
             **dict.fromkeys(("ln1", "ln2", "post1", "post2"), "norms"),
             **dict.fromkeys(("wqkv", "wq", "wkva", "kv_norm", "wkvb", "wo",
                              "wq_gate", "wk", "wv", "q_norm", "k_norm"),
@@ -784,7 +1104,7 @@ def grad_group(path) -> str:
             **dict.fromkeys(("conv_in", "conv_taps", "conv_out"),
                             "short_conv")}.get(
         names[2], "ffn")
-    return f"layer{names[1]}.{part}"
+    return f"{prefix}{names[1]}.{part}"
 
 
 def _group_norms(grads) -> Dict[str, jax.Array]:
@@ -796,12 +1116,19 @@ def _group_norms(grads) -> Dict[str, jax.Array]:
     return {name: jnp.sqrt(v) for name, v in squares.items()}
 
 
+def _expert_norms(experts: Dict) -> jax.Array:
+    """[held expert]: the norm of each expert's own matrices in a layer's
+    stacked experts."""
+    return jnp.sqrt(sum((w.astype(jnp.float32) ** 2).sum(
+        tuple(range(1, w.ndim))) for w in jax.tree.leaves(experts)))
+
+
 def make_optimizer(p: SeqRecParams):
     """adamw; a router's selection bias is no trained parameter (it
     takes no gradient and must not decay)."""
     import optax
 
-    if p.ffn != "moe":
+    if not p.has_experts():
         return optax.adamw(p.learning_rate)
     return optax.adamw(
         p.learning_rate, mask=lambda params:
@@ -825,8 +1152,12 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
     fifth, `layer_passes`, the stack's layers run in the `first` pass
     and in the `repeat`s, and `mixer_layers` counts a layer once a pass;
     under `exit_gate` `loop_loss` and `exit_share` [pass]: each pass's
-    own cross-entropy and the mean probability of leaving there). With a
-    mesh, batch is
+    own cross-entropy and the mean probability of leaving there; under
+    `sublayers` or `mtp_layers` `layer_passes` too, every layer run
+    counted `first`, the module's among them, and `mtp_loss`, the
+    module's own cross-entropy; of "relu2" experts `expert_update_norm`
+    [expert layer, held expert], the experts' part of the update expert
+    by expert). With a mesh, batch is
     sharded over "data" and embedding/ffn rows over "model"; XLA inserts
     the psums."""
 
@@ -857,16 +1188,19 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
                  "expert_product_pallas": jnp.asarray(
                      product_routes == {"pallas"}),
                  **exits}
-        if p.n_loops > 1:
-            # the stack's layers by the pass they ran in
+        if p.n_loops > 1 or p.sublayers or p.mtp_layers:
+            # the layers run by the pass they ran in (a layer need not
+            # have a mixer, so `mixer_layers` does not add up to them)
+            first = len(p.layer_kinds())
             stats["layer_passes"] = {
-                name: jnp.asarray(n * p.n_layers, jnp.int32)
-                for name, n in (("first", 1), ("repeat", p.n_loops - 1))}
+                name: jnp.asarray(n, jnp.int32) for name, n in (
+                    ("first", first), ("repeat", (p.n_loops - 1) * first))}
         if expert_layers:
             # a selection bias is moved by its layer's load, not by adamw:
             # the tokens of all its passes
-            moe_layers = [layer for i, layer in enumerate(updates["layers"])
-                          if p.ffn_kind(i) == "moe"]
+            moe_layers = [layer for layer, (_, ffn) in zip(
+                updates["layers"] + updates.get("mtp", {}).get("layers", []),
+                p.layer_kinds()) if ffn == "moe"]
             with jax.named_scope("seqrec_optimizer"):
                 for n, layer in enumerate(moe_layers):
                     # (no 0 + load where there is one pass: the step's
@@ -881,6 +1215,16 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
                 stats.update({
                     key: jnp.stack([s[key] for s in expert_layers])
                     for key in ("load", "held_tokens", "dropped")})
+                if p.expert_act == "relu2":
+                    # a hidden unit that none of an expert's tokens switched
+                    # on has a gradient of exactly 0 and adamw leaves it
+                    # where it is: the norm of a layer's update counts the
+                    # units that a few tokens reached in its emptiest
+                    # experts. By held expert, a reader weighs it by the
+                    # expert's tokens
+                    stats["expert_update_norm"] = jnp.stack([
+                        _expert_norms(layer["experts"])
+                        for layer in moe_layers])
         with jax.named_scope("seqrec_record"):
             stats["update_norm"] = _group_norms(updates)
         with jax.named_scope("seqrec_optimizer"):
@@ -1035,8 +1379,13 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
     more, in the order given) and, with expert layers, `load` [expert
     layer, routed expert], `held_tokens` [expert layer, held expert] and
     `dropped` [expert layer] (under `n_loops` an expert layer once a
-    pass, the first pass's layers first) and, under `exit_gate`,
-    `loop_loss` and `exit_share` [pass]."""
+    pass, the first pass's layers first; the multi-token-prediction
+    module's expert layers after the stack's), of "relu2" experts
+    `expert_update_norm` [expert layer, held expert], `update_norm`'s
+    "experts" groups expert by expert, and, under `exit_gate`,
+    `loop_loss` and `exit_share` [pass]; under `mtp_layers`, `mtp_loss`,
+    the module's own cross-entropy (`loss` holds it `mtp_loss_weight`
+    times)."""
     p.check()
     with span("seqrec_prepare"):
         all_items = np.asarray(sorted({it for s in sessions for it in s}),
@@ -1201,7 +1550,8 @@ def _training_record(steps: List[Dict], rows: List[np.ndarray]) -> Dict:
               **{key: [{k: float(v) for k, v in s[key].items()}
                        for s in steps] for key in ("grad_norm",
                                                    "update_norm")}}
-    for key in ("load", "held_tokens", "dropped", "loop_loss", "exit_share"):
+    for key in ("load", "held_tokens", "dropped", "expert_update_norm",
+                "loop_loss", "exit_share", "mtp_loss"):
         if steps and key in steps[0]:
             record[key] = [np.asarray(s[key]).tolist() for s in steps]
     return record

@@ -65,11 +65,18 @@ the weights), `seqrec_put` (sharding, the optimizer's state),
   counters move together.
 * ``pio_train_seqrec_mixer_tokens_total{mixer}`` — positions of the
   trained batches, padding too, times the layers of each mixer
-  (``mha``, ``mla``, ``gqa``, ``gdn``, ``conv``) the compiled step ran.
+  (``mha``, ``mla``, ``gqa``, ``gdn``, ``conv``, ``ssm``) the compiled
+  step ran, a multi-token-prediction module's among them; a layer that
+  is a feed-forward alone counts under no mixer.
 * ``pio_train_seqrec_layer_pass_tokens_total{pass}`` — positions of the
   trained batches, padding too, times the layers the compiled step ran
   in the stack's ``first`` pass and in its ``repeat``s (`n_loops`): a
-  step that ran one pass counts 0 repeats.
+  step that ran one pass counts 0 repeats. Every layer counts, a layer
+  of one sub-layer and a multi-token-prediction module's too.
+* ``pio_train_seqrec_mtp_loss`` — under a multi-token-prediction module,
+  the module's own cross-entropy (position t against item t + 2) in a
+  train's last step, beside the record's ``loss``, which holds it
+  ``mtp_loss_weight`` times.
 * ``pio_train_seqrec_loop_loss{loop}`` / ``pio_train_seqrec_exit_share{loop}``
   — under an exit gate, each pass's own next-item loss and the mean
   probability of leaving at it, over the targets of a train's last step.
@@ -246,6 +253,13 @@ def seqrec_exit_share(registry: MetricsRegistry = None):
         "last train's last step", labelnames=("loop",))
 
 
+def seqrec_mtp_loss(registry: MetricsRegistry = None):
+    return (registry or default_registry()).gauge(
+        "pio_train_seqrec_mtp_loss",
+        "The multi-token-prediction module's own loss in the last "
+        "train's last step")
+
+
 def seqrec_expert_tokens(registry: MetricsRegistry = None):
     return (registry or default_registry()).counter(
         "pio_train_seqrec_expert_tokens_total",
@@ -311,6 +325,8 @@ def observe_seqrec_record(record: dict, targets, rows,
         if record.get(key):
             for loop, value in enumerate(record[key][-1]):
                 gauge().set(value, loop=str(loop))
+    if record.get("mtp_loss"):
+        seqrec_mtp_loss().set(record["mtp_loss"][-1])
     if set(mixer_layers) & {"mha", "mla", "gqa"}:
         seqrec_attention_tokens().inc(positions, impl=attention_impl)
     if "gdn" in mixer_layers:

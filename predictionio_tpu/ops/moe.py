@@ -20,7 +20,8 @@ rows: the work follows the load, and a router that sends every token to
 one expert costs more passes, not tokens. The loop's length is not known
 when the program is traced, so the layer brings its own backward pass
 (`custom_vjp`), which walks the same passes again, keeps one pass's
-intermediates at a time and writes its six gradient products out.
+intermediates at a time and writes its six gradient products out
+(four where an expert is two matrices around a squared ReLU).
 
 Which kernel multiplies a pass is `grouped_product_route`'s to say, from
 the device's kind, the sizes and the devices the program is traced for:
@@ -110,6 +111,11 @@ def bias_update(bias: jax.Array, load: jax.Array, rate: float) -> jax.Array:
     return bias + rate * jnp.sign(load.mean() - load)
 
 
+#: what an expert computes: three matrices around a SiLU gate, or two
+#: around a squared ReLU
+EXPERT_KINDS = ("swiglu", "relu2")
+
+
 def grouped_product_route(device_kind: str, rows: int, d: int, w: int,
                           groups: int, devices: int = 1) -> str:
     """Which kernel multiplies a pass of `rows` sorted rows by `groups`
@@ -197,13 +203,15 @@ def _pass_rows(lo, plan, k, pass_rows):
 def _pass_forward(xs, w_gate, w_up, w_down, valid, products):
     """A pass's rows through their experts, before the gates: (the rows
     as the products took them, gate and up products, the hidden rows,
-    the output)."""
+    the output). Without `w_gate` the experts are two matrices around a
+    squared ReLU and there is no gate product (None)."""
     # rows past the last group are no expert's: what a grouped product
     # leaves there is masked on the way in and on the way out
     xs = _operand(jnp.where(valid, xs, 0.0), products.tile)
-    g = products.rows_by_matrix(xs, w_gate)
+    g = None if w_gate is None else products.rows_by_matrix(xs, w_gate)
     u = products.rows_by_matrix(xs, w_up)
-    h = _operand(jax.nn.silu(g) * u, products.tile)
+    h = _operand(jnp.square(jax.nn.relu(u)) if g is None
+                 else jax.nn.silu(g) * u, products.tile)
     out = jnp.where(valid, products.rows_by_matrix(h, w_down), 0.0)
     return xs, g, u, h, out
 
@@ -212,13 +220,19 @@ def _pass_grads(xs, gate, w_gate, w_up, w_down, valid, d_rows, products):
     """The gradients of a pass's gated output (`_pass_forward`'s by
     `gate`) for its rows' gradient `d_rows`: (d_xs, d_gate, d_w_gate,
     d_w_up, d_w_down), the pass computed again and the six gradient
-    products written out, each float32."""
+    products written out, each float32; of two-matrix experts (no
+    `w_gate`) four products and no d_w_gate."""
     xs, g, u, h, out = _pass_forward(xs, w_gate, w_up, w_down, valid,
                                      products)
     d_rows = jnp.where(valid, d_rows, 0.0)
     d_gate = (out * d_rows).sum(-1)
     d_out = _operand(d_rows * gate[:, None], products.tile)
     d_h = products.rows_by_matrix_t(d_out, w_down)
+    if w_gate is None:
+        d_u = _operand(d_h * 2.0 * jax.nn.relu(u), products.tile)
+        return (jnp.where(valid, products.rows_by_matrix_t(d_u, w_up), 0.0),
+                d_gate, products.rows_t_by_rows(xs, d_u),
+                products.rows_t_by_rows(h, d_out))
     sig = jax.nn.sigmoid(g)
     d_g = _operand(d_h * u * sig * (1.0 + g * (1.0 - sig)), products.tile)
     d_u = _operand(d_h * g * sig, products.tile)
@@ -273,21 +287,25 @@ def _grouped_experts_bwd(k, pass_rows, tile, res, d_y):
         lambda c: c[0] < n_rows, one_pass,
         (jnp.zeros((), n_rows.dtype), jnp.zeros_like(x),
          jnp.zeros_like(gates),
-         tuple(jnp.zeros_like(w) for w in (w_gate, w_up, w_down))))
-    return (d_x, d_gates, *d_w, None)
+         tuple(jnp.zeros_like(w) for w in (w_gate, w_up, w_down)
+               if w is not None)))
+    return (d_x, d_gates, *((None,) if w_gate is None else ()), *d_w, None)
 
 
 _grouped_experts.defvjp(_grouped_experts_fwd, _grouped_experts_bwd)
 
 
-def held_experts(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+def held_experts(x: jax.Array, w_gate: Optional[jax.Array], w_up: jax.Array,
                  w_down: jax.Array, routing: Routing, first_held: int,
                  pass_rows: int, devices: int = 1
                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """What the experts [first_held, first_held + n_held) add to the
     layer's output. x: [T, d]; w_gate, w_up: [n_held, d, w]; w_down:
-    [n_held, w, d] (SwiGLU experts). Returns (y [T, d], tokens each held
-    expert received [n_held], tokens dropped: those routed to a held
+    [n_held, w, d]. With `w_gate` the experts are "swiglu": silu(x
+    W_gate) (x W_up) W_down; with None "relu2": relu(x W_up)^2 W_down,
+    two matrices an expert, four gradient products for six; the sort,
+    the passes and the route are the same. Returns (y [T, d], tokens
+    each held expert received [n_held], tokens dropped: those routed to a held
     expert whose row of y is all zeros, read from the output and not from
     the loop's own count; a token whose input is all zeros would read
     the same). The routed slots are multiplied `pass_rows` at a time, by
@@ -295,7 +313,7 @@ def held_experts(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     sizes and `devices` (how many devices the calling program is traced
     for: a mesh's size)."""
     t, k = routing.experts.shape
-    n_held, d, w = w_gate.shape
+    n_held, d, w = w_up.shape
     local = routing.experts.reshape(-1) - first_held
     here = (local >= 0) & (local < n_held)
     # absent experts sort last; the held experts' slots come first, by expert

@@ -175,13 +175,18 @@ def small_blocks(monkeypatch):
 
 def every_mixer_spec(**over):
     """d 64, `remat` on; a convolution layer with the dense feed-forward,
-    then a linear-attention and a grouped-query attention layer with 16
-    experts top-3 and a gated shared expert each."""
+    then a linear-attention, a grouped-query attention and a state-space
+    layer with 16 experts top-3 in a latent of 32 and a gated shared
+    expert each, and a multi-token-prediction module of one attention
+    layer."""
     from predictionio_tpu.models import seqrec
 
     return seqrec.SeqRecParams(**{**dict(
-        d_model=64, n_heads=4, n_layers=3, max_len=24, seed=11,
-        mixer=("conv", "gdn", "gqa"), ffn="moe", first_dense_layers=1,
+        d_model=64, n_heads=4, n_layers=4, max_len=24, seed=11,
+        mixer=("conv", "gdn", "gqa", "ssm"), ffn="moe", first_dense_layers=1,
+        ssm=dict(heads=4, head_dim=8, groups=2, state=8, conv_kernel=4,
+                 chunk=8), moe_latent_size=32, mtp_layers=("gqa",),
+        mtp_loss_weight=0.1,
         ffn_width=96, norm="rms", norm_eps=1e-5, positions="rope",
         rope_theta=1e6, tied_head=False, n_kv_heads=2, head_dim=16,
         rotary_dim=4, conv_kernel=3, linear_key_heads=4,
@@ -201,6 +206,9 @@ def test_a_train_publishes_its_steps_table_once_and_compiles_nothing_for_it(
     from predictionio_tpu.models import seqrec
 
     jax_stats.listen_to_compiler()
+    # (kept as a label whatever this worker compiled before: past
+    # MAX_COMPILE_FUNS names a new one folds into "other")
+    jax_stats._compiler_events._funs.add("jit(step)")
     p = every_mixer_spec()
 
     def step_compiles():

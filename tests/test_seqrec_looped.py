@@ -130,7 +130,7 @@ def test_every_passes_logits_match_the_reference():
     params = weights(p)
     seqs, _ = batch(seed=5, rows=1, pad=3)
     with jax.default_matmul_precision("highest"):
-        passes, _, _ = seqrec._forward(params, jnp.asarray(seqs), p)
+        passes, *_ = seqrec._forward(params, jnp.asarray(seqs), p)
         states = ref.pass_states(params, seqs[0], ref_spec(p))
         assert passes.shape == (R, 1, L, 64) and len(states) == R
         for r in range(R):
@@ -231,7 +231,7 @@ def test_one_pass_without_post_norms_and_gate_is_todays_program():
     assert len({id(leaf) for leaf in jax.tree.leaves(b)}) == len(
         jax.tree.leaves(b))
     seqs, targets = batch(seed=1)
-    passes, _, mixers = seqrec._forward(a, jnp.asarray(seqs), plain)
+    passes, _, mixers, _ = seqrec._forward(a, jnp.asarray(seqs), plain)
     assert isinstance(passes, tuple) and len(passes) == 1
     assert mixers == {"mha": N}
     optimizer = seqrec.make_optimizer(plain)
@@ -280,7 +280,7 @@ def test_the_last_passes_gate_gets_no_gradient_and_the_entropy_counts():
 
     def loss_of(gates):
         """A gate a pass: `gates` [R, d] in place of the one column."""
-        passes, _, _ = seqrec._forward(params, jnp.asarray(seqs), p)
+        passes, *_ = seqrec._forward(params, jnp.asarray(seqs), p)
         z = (passes.reshape(R, -1, 64) * gates[:, None]).sum(-1)
         logp = seqrec.exit_distribution(z)
         return (jnp.exp(logp) * jnp.arange(1.0, R + 1)[:, None]).sum()

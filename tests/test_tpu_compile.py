@@ -411,6 +411,61 @@ def test_the_looped_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
     assert held <= 15.75 * 2 ** 30 - 1e9, held
 
 
+def test_the_state_space_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
+    """nemotron3-super-tp8ep64.train's step compiled for one described
+    v5e: 8,192 positions through one period of single-sub-layer layers
+    (an attention, five state-space and five latent expert layers at this
+    chip's tensor and expert share) and the multi-token-prediction
+    module; the two attention layers (the stack's and the module's)
+    through the Pallas attention kernels at 4 query heads on 1 key/value
+    head of 128, the six expert layers' two-matrix experts through the
+    grouped-product kernels at 1024 x 2688 (no `ragged-dot`), the scan as
+    XLA operations; arguments +
+    temporaries (6.8 + 4.1 GiB: PERF.md section 6, PR 40) leave the 16
+    GB chip 1 GB and more."""
+    import json
+    import os
+
+    from predictionio_tpu.models import seqrec
+    from predictionio_tpu.ops import attention, moe, moe_pallas
+
+    kind = chips[0].device_kind
+    monkeypatch.setattr(attention, "_device_kind", lambda: kind)
+    monkeypatch.setattr(moe, "_device_kind", lambda: kind)
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmarks", "configs",
+            "seqrec-nemotron3-super-120b-a12b-tp8ep64.json")) as f:
+        cfg = json.load(f)
+    p = seqrec.SeqRecParams(**cfg["algorithm_params"])
+    assert (len(p.layer_kinds()), p.max_len, p.remat) == (13, 8192, True)
+    assert moe_pallas.tiles(8192, p.moe_latent_size, p.moe_width, 8) == 128
+    optimizer = seqrec.make_optimizer(p)
+    params = jax.eval_shape(
+        lambda: seqrec.init_params(None, cfg["n_items"], p))
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params)) \
+        == 607_038_960
+    put = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    seqs = jax.ShapeDtypeStruct((p.batch_size, p.max_len), jnp.int32,
+                                sharding=one_chip)
+    compiled = seqrec.make_train_step(None, p, optimizer).lower(
+        put(params), put(jax.eval_shape(optimizer.init, params)), seqs,
+        seqs).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(text, "flash_attention_pallas_fwd") == 2 * 2
+    assert _kernel_calls(text, "flash_attention_pallas_bwd") == 2
+    # a layer's two products forward, again in `remat`'s recomputation and
+    # again inside the layer's own backward pass; four gradient products
+    assert _kernel_calls(text, "grouped_product_pallas_rows") == 6 * 6
+    assert _kernel_calls(text, "grouped_product_pallas_rows_t") == 6 * 2
+    assert _kernel_calls(text, "grouped_product_pallas_groups") == 6 * 2
+    assert "ragged-dot" not in text
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert held <= 15.75 * 2 ** 30 - 1e9, held
+
+
 @pytest.mark.parametrize("name,tokens,k,d,w,held,devices,kernels", [
     # a pass of each sequence cell's expert layers, forward and backward:
     # kimivl-a3b-ep8.train, lfm2-a2b-ep8.train, qwen3next-a3b-ep16.train

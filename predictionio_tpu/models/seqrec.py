@@ -36,7 +36,8 @@ from predictionio_tpu.obs import jax_stats, train_stats
 from predictionio_tpu.obs.tracing import span
 from predictionio_tpu.ops import linear_attention, moe, state_space
 from predictionio_tpu.ops.attention import (
-    blockwise_attention, ring_attention_traced, rope, routes_into,
+    blockwise_attention, ring_attention_traced, rope, rotary_attention,
+    routes_into, split_heads,
 )
 
 
@@ -758,6 +759,20 @@ def _linear_attention(layer, x, key_mask, p: SeqRecParams, devices: int):
         @ layer["w_out"]
 
 
+def _qkv_grad_dtype():
+    """What `_attention` lets `rotary_attention` round the gradient of
+    `x @ wqkv` to where the kernels' route writes it token-first:
+    bfloat16, the type that product's two backward products read it in
+    anyway, BECAUSE `_attention` multiplies at the default precision and
+    only while the default is the TPU's one bfloat16 pass; None (float32)
+    under `jax.default_matmul_precision` of anything higher
+    (tests/test_seqrec_looped.py holds the product and this together).
+    Half the bytes of that array: 14 ms of a 1,139 ms step in
+    ouro-2.6b-pp8.train (PERF.md section 6, PR 41)."""
+    ambient = jax.config.jax_default_matmul_precision
+    return jnp.bfloat16 if ambient in (None, "default", "bfloat16") else None
+
+
 def _attention(layer, x, key_mask, p: SeqRecParams, kind, mesh, use_ring):
     """A softmax-attention mixer on normed x [B, L, D] -> [B, L, D]."""
     b, l, d = x.shape
@@ -765,10 +780,23 @@ def _attention(layer, x, key_mask, p: SeqRecParams, kind, mesh, use_ring):
     positions = jnp.arange(l)
     gate = None
     if kind == "mha":
-        q, k, v = (t.reshape(b, l, h, d // h) for t in
-                   jnp.split(x @ layer["wqkv"], 3, axis=-1))    # MXU
-        if p.positions == "rope":
-            q, k = (rope(t, positions, p.rope_theta) for t in (q, k))
+        rotary = p.positions == "rope"
+        qkv = x @ layer["wqkv"]                                 # MXU
+        if rotary and not use_ring:
+            # the projection whole: where the kernels read a head as a
+            # block of its columns nothing between the two products is
+            # relaid. The product above runs at the default precision,
+            # and so do its two backward products: where that is one
+            # bfloat16 pass they round this call's gradient themselves,
+            # and it may be written so (a product at a higher precision
+            # here would have to take float32 back: `_qkv_grad_dtype`).
+            return rotary_attention(
+                qkv, h, p.rope_theta, block_k=ATTENTION_BLOCK, causal=True,
+                key_mask=key_mask, devices=1 if mesh is None else mesh.size,
+                grad_dtype=_qkv_grad_dtype()) @ layer["wo"]
+        # the ring's shards, and heads without rotary positions
+        q, k, v = split_heads(qkv, h, positions if rotary else None,
+                              p.rope_theta)
     elif kind == "gqa":
         if p.attention_gate:
             q, gate = jnp.split(x @ layer["wq_gate"], 2, axis=-1)
@@ -1141,15 +1169,17 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
     """One donated jitted step -> (params, opt_state, the step's numbers:
     loss, by group the gradient's norm and the norm of what the step
     added to the parameters, and per expert layer the tokens routed to
-    each expert, to each held expert, and dropped; four constants of
+    each expert, to each held expert, and dropped; five constants of
     the trace: `mixer_layers`, the layers it ran by mixer,
     `attention_pallas`, whether `blockwise_attention` folded every
     softmax-attention layer's blocks with the Pallas kernels,
+    `attention_rows`, present and True where those kernels read every
+    such layer's heads token-first, where the projections wrote them,
     `linear_attention_pallas`, whether `gated_delta_rule` ran every
     linear-attention layer's recurrence as Pallas kernels, and
     `expert_product_pallas`, whether `held_experts` multiplied every
     expert layer's groups with the Pallas kernels); under `n_loops` a
-    fifth, `layer_passes`, the stack's layers run in the `first` pass
+    sixth, `layer_passes`, the stack's layers run in the `first` pass
     and in the `repeat`s, and `mixer_layers` counts a layer once a pass;
     under `exit_gate` `loop_loss` and `exit_share` [pass]: each pass's
     own cross-entropy and the mean probability of leaving there; under
@@ -1171,7 +1201,9 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
             seqs = jax.lax.with_sharding_constraint(seqs, sh)
             targets = jax.lax.with_sharding_constraint(targets, sh)
         routes, rule_routes, product_routes = set(), set(), set()
-        with routes_into(routes), linear_attention.routes_into(rule_routes), \
+        layouts = set()
+        with routes_into(routes, layouts), \
+                linear_attention.routes_into(rule_routes), \
                 moe.routes_into(product_routes):
             (loss, (expert_layers, mixers, exits)), grads = \
                 jax.value_and_grad(_loss_fn, has_aux=True)(
@@ -1188,6 +1220,10 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
                  "expert_product_pallas": jnp.asarray(
                      product_routes == {"pallas"}),
                  **exits}
+        if layouts == {"rows"}:
+            # (a step whose kernels read head-first reports what it did
+            # before there were two layouts: its program is the one it was)
+            stats["attention_rows"] = jnp.asarray(True)
         if p.n_loops > 1 or p.sublayers or p.mtp_layers:
             # the layers run by the pass they ran in (a layer need not
             # have a mixer, so `mixer_layers` does not add up to them)
@@ -1538,7 +1574,8 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
         {kind: int(n) for kind, n in steps[0]["mixer_layers"].items()}
         if steps else {},
         {name: int(n) for name, n in steps[0]["layer_passes"].items()}
-        if steps and "layer_passes" in steps[0] else None)
+        if steps and "layer_passes" in steps[0] else None,
+        "rows" if steps and steps[0].get("attention_rows") else "heads")
     return SeqRecModel(item_vocab=all_items, params=params, hyper=p,
                        record=record)
 

@@ -49,6 +49,14 @@ the weights), `seqrec_put` (sharding, the optimizer's state),
   took when their step was traced: ``pallas`` (every softmax-attention
   layer through the kernels of ops/attention_pallas.py) or ``xla``. A
   model without such a layer counts nothing here.
+* ``pio_train_seqrec_attention_layout_tokens_total{layout}`` — the
+  positions counted under ``impl="pallas"`` above, by where the kernels
+  read a head when their step was traced: ``rows`` (every
+  softmax-attention layer's q, k, v token-first, [B, L, heads x width]
+  as a projection writes them, a head a block of columns:
+  ops/attention.rotary_attention at widths of whole lane tiles) or
+  ``heads`` ([B, heads, L, width] behind a transpose: every caller of
+  ``blockwise_attention``). A step on the scan counts nothing here.
 * ``pio_train_seqrec_linear_attention_tokens_total{impl}`` — positions of
   the trained batches, padding too, times the linear-attention
   (``gdn``) layers, by the route ``gated_delta_rule`` took when their
@@ -205,6 +213,15 @@ def seqrec_attention_tokens(registry: MetricsRegistry = None):
         labelnames=("impl",))
 
 
+def seqrec_attention_layout_tokens(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_attention_layout_tokens_total",
+        "Positions of the trained batches whose step's attention was "
+        "traced on the Pallas kernels, by where the kernels read a head "
+        "(ops/attention.attention_layout)",
+        labelnames=("layout",))
+
+
 def seqrec_linear_attention_tokens(registry: MetricsRegistry = None):
     return (registry or default_registry()).counter(
         "pio_train_seqrec_linear_attention_tokens_total",
@@ -299,7 +316,8 @@ def observe_seqrec_record(record: dict, targets, rows,
                           attention_impl: str, linear_attention_impl: str,
                           expert_product_impl: str,
                           mixer_layers: dict,
-                          layer_passes: dict = None) -> None:
+                          layer_passes: dict = None,
+                          attention_layout: str = "heads") -> None:
     """The token and expert counters from one train's record
     (models/seqrec.train_seqrec): `targets` the padded target ids of all
     sessions, `rows` the sessions of each step's batch, `attention_impl`
@@ -307,7 +325,8 @@ def observe_seqrec_record(record: dict, targets, rows,
     attention (the rule and the chain around it) were traced on,
     `mixer_layers` the layer passes that step ran by mixer,
     `layer_passes` those of its first pass and of its repeats (None from
-    a step of one pass: all are first)."""
+    a step of one pass: all are first), `attention_layout` where its
+    attention kernels read a head."""
     import numpy as np
 
     real = sum(int((targets[r] > 0).sum()) for r in rows)
@@ -329,6 +348,9 @@ def observe_seqrec_record(record: dict, targets, rows,
         seqrec_mtp_loss().set(record["mtp_loss"][-1])
     if set(mixer_layers) & {"mha", "mla", "gqa"}:
         seqrec_attention_tokens().inc(positions, impl=attention_impl)
+        if attention_impl == "pallas":
+            seqrec_attention_layout_tokens().inc(positions,
+                                                 layout=attention_layout)
     if "gdn" in mixer_layers:
         for counter in (seqrec_linear_attention_tokens,
                         seqrec_linear_attention_chain_tokens):

@@ -121,6 +121,21 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
                            axis=-1).astype(x.dtype)
 
 
+def split_heads(qkv: jax.Array, heads: int,
+                positions: Optional[jax.Array] = None,
+                theta: Optional[float] = None):
+    """A projection's columns [q | k | v], qkv [B, L, 3 x H x D] -> q, k,
+    v [B, L, H, D]; with `positions`, q and k turned by `rope`. (The one
+    place that knows this layout head-first; `attention_pallas.
+    rotary_attention_pallas` knows it token-first.)"""
+    b, l, _ = qkv.shape
+    q, k, v = (t.reshape(b, l, heads, -1)
+               for t in jnp.split(qkv, 3, axis=-1))
+    if positions is not None:
+        q, k = (rope(t, positions, theta) for t in (q, k))
+    return q, k, v
+
+
 def _block_scores(q_i, k_j, mask_j, i, j, block_q, block_k, causal, scale):
     """Masked scores [B, H, bq, bk] of query block i against key block j
     (NEG_INF where the key is padding or lies in the causal future)."""
@@ -264,23 +279,57 @@ def attention_route(device_kind: str, lq: int, lk: int, dk: int, dv: int,
     return "xla"
 
 
+def attention_layout(device_kind: str, lq: int, lk: int, dk: int, dv: int,
+                     block_k: int = 512, block_q: Optional[int] = None,
+                     devices: int = 1) -> str:
+    """Where `rotary_attention`'s operands of these sizes lie on their
+    way through `attention_route`'s implementation: "rows", the kernels
+    reading a head as a block of columns of the projection's own [B, L,
+    heads x width] (`attention_pallas.layout`: both widths whole lane
+    tiles); "heads", the kernels on [B, heads, L, width]; "xla", the
+    scan. The entry comes first, the widths second: it is
+    `rotary_attention` that may be token-first, and `blockwise_attention`
+    is head-first on the kernels' route whatever the widths (its callers
+    hold [B, L, H, D]: grouped heads with per-head norms, partial rotary
+    or none, the latent form)."""
+    if attention_route(device_kind, lq, lk, dk, dv, block_k, block_q,
+                       devices) == "xla":
+        return "xla"
+    return attention_pallas.layout(dk, dv)
+
+
 def _device_kind() -> str:
     return jax.devices()[0].device_kind
 
 
-_ROUTES: contextvars.ContextVar[Optional[Set[str]]] = contextvars.ContextVar(
+_ROUTES: contextvars.ContextVar[
+    Optional[Tuple[Set[str], Optional[Set[str]]]]] = contextvars.ContextVar(
     "blockwise_attention_routes", default=None)
 
 
 @contextlib.contextmanager
-def routes_into(routes: Set[str]) -> Iterator[None]:
-    """While the block runs (a trace), every `blockwise_attention` call
-    adds the route it took to `routes`."""
-    token = _ROUTES.set(routes)
+def routes_into(routes: Set[str],
+                layouts: Optional[Set[str]] = None) -> Iterator[None]:
+    """While the block runs (a trace), every `blockwise_attention` or
+    `rotary_attention` call adds the route it took to `routes` and, on
+    the kernels' route, where they read a head ("rows" | "heads":
+    `attention_layout`) to `layouts`."""
+    token = _ROUTES.set((routes, layouts))
     try:
         yield
     finally:
         _ROUTES.reset(token)
+
+
+def _hear(layout: str) -> None:
+    """Tell a listener the `attention_layout` a call took."""
+    heard = _ROUTES.get()
+    if heard is None:
+        return
+    routes, layouts = heard
+    routes.add("xla" if layout == "xla" else "pallas")
+    if layouts is not None and layout != "xla":
+        layouts.add(layout)
 
 
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -305,7 +354,9 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     `attention_route` says from the device's kind, the sizes and
     `devices` (how many devices the calling program is traced for: a
     mesh's size) whether the blocks are folded by Pallas kernels (with
-    blocks of their own) or by a scan of XLA operations."""
+    blocks of their own, on operands head-first behind a transpose:
+    `rotary_attention` is the entry that keeps them token-first) or by
+    a scan of XLA operations."""
     b, lq, h, dk = q.shape
     lk, dv = k.shape[1], v.shape[-1]
     if h % k.shape[2] or k.shape[2] != v.shape[2]:
@@ -325,9 +376,7 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     heads_first = lambda t: jnp.swapaxes(t, 1, 2)
     route = attention_route(_device_kind(), lq, lk, dk, dv, block_k, block_q,
                             devices)
-    heard = _ROUTES.get()
-    if heard is not None:
-        heard.add(route)
+    _hear("heads" if route == "pallas" else "xla")
     if route == "pallas":
         out = attention_pallas.flash_attention_pallas(
             heads_first(q), heads_first(k), heads_first(v), key_mask, causal)
@@ -337,6 +386,43 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         out = _blockwise(heads_first(q), heads_first(k), heads_first(v),
                          key_mask, block_q, block_k, causal)
     return heads_first(out)[:, :lq]
+
+
+def rotary_attention(qkv: jax.Array, heads: int, theta: float,
+                     block_k: int = 512, causal: bool = False,
+                     key_mask: Optional[jax.Array] = None,
+                     block_q: Optional[int] = None,
+                     devices: int = 1, grad_dtype=None) -> jax.Array:
+    """Multi-head attention with rotary positions (0 .. L - 1, `rope`'s
+    pairing) on a projection's output: qkv [B, L, 3 x H x D], the columns
+    [q | k | v] of `x @ wqkv` -> [B, L, H x D], on `attention_layout`'s
+    route. "rows": the arrays stay token-first from the projection to
+    the output (`attention_pallas.rotary_attention_pallas`: one pass over
+    the projection's columns rotates and rounds, the kernels read a head
+    as a block of columns, the gradient comes back as one array).
+    Elsewhere `split_heads` and `blockwise_attention` on [B, L, H, D].
+    `grad_dtype`: the type the "rows" route rounds qkv's gradient to
+    where it writes it, float32 (none) unless the caller says otherwise.
+    A caller whose `x @ wqkv` runs at the TPU's default precision may say
+    bfloat16: that product's two backward products round their operands
+    so, and so they do on the other routes, where this is not read."""
+    b, l, width = qkv.shape
+    d = width // (3 * heads)
+    if attention_layout(_device_kind(), l, l, d, d, block_k, block_q,
+                        devices) != "rows":
+        return blockwise_attention(
+            *split_heads(qkv, heads, jnp.arange(l), theta), block_k=block_k,
+            causal=causal, key_mask=key_mask, block_q=block_q,
+            devices=devices).reshape(b, l, -1)
+    _hear("rows")
+    _, _, _, pad = _blocks_and_pads(l, l, block_k, block_q)
+    if key_mask is None:
+        key_mask = jnp.ones((b, l), bool)
+    if pad:     # pad keys are masked out, pad queries cut off the result
+        qkv = jnp.pad(qkv, ((0, 0), (0, pad), (0, 0)))
+        key_mask = jnp.pad(key_mask, ((0, 0), (0, pad)))
+    return attention_pallas.rotary_attention_pallas(
+        qkv, key_mask, heads, theta, causal, grad_dtype=grad_dtype)[:, :l]
 
 
 def _ring_attention_local(q, k, v, key_mask, *, axis: str, causal: bool,

@@ -22,6 +22,18 @@ diagonal, or one that holds a padding key, builds a mask.
   head) stays in VMEM whole until every pair has added to it: that bounds
   the length the kernels take (``tiles``).
 
+Two layouts of the operands in HBM, one kernel pair: a head's block is
+[block, width] of VMEM either way, and only the index maps know where it
+came from. The entry chooses. `flash_attention_pallas` takes them
+head-first, q [B, H, L, Dk]: a head is an index of axis 1 (any width the
+kernels tile, grouped query heads). `rotary_attention_pallas` takes a
+projection's output token-first, q [B, L, H x Dk]: a head is a block of
+columns, which Mosaic takes where a head's width is a whole number of
+lane tiles (`layout`: its caller asks first), with as many key/value
+heads as query heads. There nothing between a projection and a kernel
+changes an array's layout, and one pass lies between them: rotary
+positions and the rounding.
+
 Precision: every product takes its operands in bfloat16 (what the TPU's
 default does to float32 operands), rounded once on their way in, and
 accumulates in float32; scores, maxima, exponentials, denominators,
@@ -84,6 +96,14 @@ def tiles(lq: int, lk: int, dk: int, dv: int) -> bool:
     return (_block(lq) > 0 and _block(lk) > 0 and dk % 64 == 0
             and dv % 64 == 0
             and 2 * lq * -(-dk // 128) * 128 * 4 <= _DQ_VMEM)
+
+
+def layout(dk: int, dv: int) -> str:
+    """Where the kernels may read a head of these widths: "rows",
+    token-first, as a block of columns of [B, L, heads x width], where
+    both are whole lane tiles (what `rotary_attention_pallas` needs);
+    "heads", head-first [B, heads, L, width] alone, everywhere else."""
+    return "rows" if dk % 128 == 0 and dv % 128 == 0 else "heads"
 
 
 def _block_pairs(n_q: int, n_k: int, bq: int, bk: int, causal: bool,
@@ -235,37 +255,62 @@ def _call(kernel, name, key_mask, pairs, heads, bk, in_specs, out_specs,
         full.reshape(-1).astype(jnp.int32))
 
 
-def _specs(bq, bk, group=1):
+def _specs(bq, bk, group=1, rows=False):
     """Block specs by what a block follows: the pair's query block, its
     key block (per query head: the gradients), or the key block of the
     key/value head that serves the query head (`group` query heads
-    each)."""
+    each); `whole`, all of a head's positions. A head is an index of
+    axis 1 of [B, heads, L, width] or, under `rows`, a block of columns
+    of [B, 1, L, heads x width]."""
+    def spec(length, width, block, head=lambda h: h):
+        def index(b, h, p, qi, kj, full):
+            if rows:
+                return (b, 0, block(p, qi, kj), head(h))
+            return (b, head(h), block(p, qi, kj), 0)
+        return pl.BlockSpec((1, 1, length, width), index)
+
     def by_query(width):
-        return pl.BlockSpec((1, 1, bq, width),
-                            lambda b, h, p, qi, kj, full: (b, h, qi[p], 0))
+        return spec(bq, width, lambda p, qi, kj: qi[p])
 
     def by_key(width):
-        return pl.BlockSpec((1, 1, bk, width),
-                            lambda b, h, p, qi, kj, full: (b, h, kj[p], 0))
+        return spec(bk, width, lambda p, qi, kj: kj[p])
 
     def by_kv_head(width):
-        return pl.BlockSpec(
-            (1, 1, bk, width),
-            lambda b, h, p, qi, kj, full: (b, h // group, kj[p], 0))
+        return spec(bk, width, lambda p, qi, kj: kj[p], lambda h: h // group)
 
-    return by_query, by_key, by_kv_head
+    def whole(length, width):
+        return spec(length, width, lambda p, qi, kj: 0)
+
+    return by_query, by_key, by_kv_head, whole
 
 
-def _forward(q, k, v, key_mask, causal, bq, bk, interpret, save_lse):
-    b, h, lq, dk = q.shape
-    lk, dv = k.shape[2], v.shape[3]
+def _sizes(q, k, v, heads):
+    """(batch, query heads, key/value heads, lq, lk, dk, dv) of operands
+    head-first (`heads` None) or token-first [B, 1, L, heads x width]
+    (as many key/value heads as query heads: the one caller's)."""
+    if heads is None:
+        return (q.shape[0], q.shape[1], k.shape[1], q.shape[2], k.shape[2],
+                q.shape[3], v.shape[3])
+    return (q.shape[0], heads, heads, q.shape[2], k.shape[2],
+            q.shape[3] // heads, v.shape[3] // heads)
+
+
+def _shape(b, h, length, width, rows):
+    return (b, 1, length, h * width) if rows else (b, h, length, width)
+
+
+def _forward(q, k, v, key_mask, causal, bq, bk, interpret, save_lse,
+             heads=None):
+    b, h, kv, lq, lk, dk, dv = _sizes(q, k, v, heads)
+    rows = heads is not None
     pairs = _block_pairs(lq // bq, lk // bk, bq, bk, causal, key_major=False)
-    by_query, _, by_kv_head = _specs(bq, bk, h // k.shape[1])
-    out_shape = [jax.ShapeDtypeStruct((b, h, lq, dv), jnp.float32)]
+    by_query, _, by_kv_head, _ = _specs(bq, bk, h // kv, rows)
+    out_shape = [jax.ShapeDtypeStruct(_shape(b, h, lq, dv, rows),
+                                      jnp.float32)]
     out_specs = [by_query(dv)]
-    if save_lse:
+    if save_lse:        # head-first in either layout
         out_shape.append(jax.ShapeDtypeStruct((b, h, lq, 1), jnp.float32))
-        out_specs.append(by_query(1))
+        out_specs.append(_specs(bq, bk)[0](1))
     return _call(
         functools.partial(_fwd_kernel, scale=dk ** -0.5, causal=causal,
                           bq=bq, bk=bk, n_k=lk // bk, save_lse=save_lse),
@@ -280,12 +325,12 @@ def _forward(q, k, v, key_mask, causal, bq, bk, interpret, save_lse):
 
 
 def _backward(q, k, v, key_mask, d_out, lse, delta, causal, bq, bk,
-              interpret):
-    b, h, lq, dk = q.shape
-    lk, dv = k.shape[2], v.shape[3]
+              interpret, heads=None):
+    b, h, kv, lq, lk, dk, dv = _sizes(q, k, v, heads)
+    rows = heads is not None
     pairs = _block_pairs(lq // bq, lk // bk, bq, bk, causal, key_major=True)
-    group = h // k.shape[1]
-    by_query, by_key, by_kv_head = _specs(bq, bk, group)
+    group = h // kv
+    by_query, by_key, by_kv_head, whole = _specs(bq, bk, group, rows)
     row = pl.BlockSpec((1, 1, 1, bq),
                        lambda b, h, p, qi, kj, full: (b, h, 0, qi[p]))
     dq, d_k, d_v = _call(
@@ -296,10 +341,8 @@ def _backward(q, k, v, key_mask, d_out, lse, delta, causal, bq, bk,
          pl.BlockSpec((1, bk, 1),
                       lambda b, h, p, qi, kj, full: (b, kj[p], 0)),
          by_query(dv), row, row],
-        [pl.BlockSpec((1, 1, lq, dk),
-                      lambda b, h, p, qi, kj, full: (b, h, 0, 0)),
-         by_key(dk), by_key(dv)],
-        [jax.ShapeDtypeStruct((b, h, length, width), jnp.float32)
+        [whole(lq, dk), by_key(dk), by_key(dv)],
+        [jax.ShapeDtypeStruct(_shape(b, h, length, width, rows), jnp.float32)
          for length, width in ((lq, dk), (lk, dk), (lk, dv))],
         [pltpu.VMEM((bk, dk), jnp.float32),
          pltpu.VMEM((bk, dv), jnp.float32)], interpret)(
@@ -323,32 +366,202 @@ def flash_attention_pallas(q, k, v, key_mask, causal: bool,
     it lies, and the backward kernel's per-query-head `dk`, `dv` are
     summed over the group after it), lengths multiples of 128; key_mask
     [B, Lk] bool, False = padding -> [B, H, Lq, Dv] in that dtype.
+    The operands lie head-first; `rotary_attention_pallas` is the entry
+    that reaches the same kernels token-first.
     `interpret` runs the kernels in the Pallas interpreter (the CPU
     tests)."""
     return _fwd(q, k, v, key_mask, causal, interpret, save_lse=False)[0]
 
 
-def _fwd(q, k, v, key_mask, causal, interpret, save_lse=True):
+def _fwd(q, k, v, key_mask, causal, interpret, save_lse=True, heads=None,
+         dtype=None):
+    """-> (the output in `dtype`, the operands' by default; what the
+    backward pass keeps). With `heads` = H the operands lie token-first,
+    where a projection writes them (`layout` "rows"): q, k [B, L, H x
+    Dk], v [B, L, H x Dv] -> [B, L, H x Dv], and so do `_bwd`'s
+    gradients."""
     if not q.dtype == k.dtype == v.dtype:
         raise ValueError(f"q, k, v of one dtype expected, got {q.dtype}, "
                          f"{k.dtype}, {v.dtype}")
     ops = tuple(t.astype(jnp.bfloat16) for t in (q, k, v))
-    out, *lse = _forward(*ops, key_mask, causal, _block(q.shape[2]),
-                         _block(k.shape[2]), interpret, save_lse)
-    out = out.astype(q.dtype)
+    if heads is not None:       # [B, 1, L, .]: no array moves for it
+        ops = tuple(t[:, None] for t in ops)
+    lengths = ops[0].shape[2], ops[1].shape[2]
+    out, *lse = _forward(*ops, key_mask, causal, *map(_block, lengths),
+                         interpret, save_lse, heads)
+    out = out.astype(dtype or q.dtype)
+    shown = out if heads is None else out[:, 0]
     if not save_lse:
-        return out, None
-    return out, (*ops, key_mask, out, lse[0][..., 0])
+        return shown, None
+    return shown, (*ops, key_mask, out, lse[0][..., 0])
 
 
-def _bwd(causal, interpret, res, d_out):
+def _bwd(causal, interpret, res, d_out, heads=None):
     q, k, v, key_mask, out, lse = res
-    delta = (d_out.astype(jnp.float32)
-             * out.astype(jnp.float32)).sum(axis=-1)          # [B, H, Lq]
+    if heads is not None:
+        d_out = d_out[:, None]
+    delta = _head_sums(d_out.astype(jnp.float32) * out.astype(jnp.float32),
+                       heads)                                   # [B, H, Lq]
     grads = _backward(q, k, v, key_mask, d_out.astype(jnp.bfloat16), lse,
                       delta, causal, _block(q.shape[2]), _block(k.shape[2]),
-                      interpret)
+                      interpret, heads)
+    if heads is not None:
+        grads = tuple(g[:, 0] for g in grads)
     return (*(g.astype(out.dtype) for g in grads), None)
 
 
+def _head_sums(t, heads):
+    """Each head's sum over its width -> [B, H, L]: of t [B, H, L, D],
+    or of token-first t [B, 1, L, H x D] as a product with the heads'
+    indicator columns (a sum over a block of lanes with no array split
+    into heads), exact to float32."""
+    if heads is None:
+        return t.sum(axis=-1)
+    ones = jnp.repeat(jnp.eye(heads, dtype=t.dtype), t.shape[-1] // heads,
+                      axis=0)
+    return jnp.einsum("blc,ch->bhl", t[:, 0], ones,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 flash_attention_pallas.defvjp(_fwd, _bwd)
+
+
+# -- rotary positions and the cast, on the projection's columns ----------
+
+def rotary_table(length: int, width: int, theta: float):
+    """(cos, sin) [L, width] float32 of positions 0 .. L - 1 in the
+    "halves" pairing of `ops/attention.rope`, laid out for a head's
+    columns where they lie: column i of a head rotates with column
+    i +- width / 2, its partner a roll of the head's lanes by half its
+    width, so the sine carries the sign: [-sin | sin], beside [cos |
+    cos]."""
+    half = width // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = jnp.arange(length, dtype=jnp.float32)[:, None] * freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    return (jnp.concatenate([cos, cos], axis=-1),
+            jnp.concatenate([-sin, sin], axis=-1))
+
+
+def _rotary_fwd_kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, qo_ref, ko_ref,
+                       vo_ref, *, half):
+    cos, sin = cos_ref[...], sin_ref[...]
+    for x_ref, o_ref in ((q_ref, qo_ref), (k_ref, ko_ref)):
+        x = x_ref[0]
+        o_ref[0] = (x * cos + pltpu.roll(x, half, 1) * sin).astype(
+            o_ref.dtype)
+    vo_ref[0] = v_ref[0].astype(vo_ref.dtype)
+
+
+def _rotary_bwd_kernel(dq_ref, dk_ref, dv_ref, cos_ref, sin_ref, o_ref, *,
+                       heads, half):
+    c = pl.program_id(2)
+
+    def turned_back(d_ref):
+        # the rotation's transpose: the partner's product, rolled home
+        d = d_ref[0]
+        o_ref[0] = (d * cos_ref[...] + pltpu.roll(d * sin_ref[...], half, 1)
+                    ).astype(o_ref.dtype)
+
+    pl.when(c < heads)(lambda: turned_back(dq_ref))
+    pl.when((c >= heads) & (c < 2 * heads))(lambda: turned_back(dk_ref))
+
+    @pl.when(c >= 2 * heads)
+    def _():
+        o_ref[0] = dv_ref[0].astype(o_ref.dtype)
+
+
+def _rotary_call(kernel, name, grid, in_specs, out_specs, out_shape,
+                 interpret):
+    return pl.pallas_call(
+        kernel, name=name, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret)
+
+
+def _rotary(qkv, cos, sin, heads, interpret):
+    """The projection [B, L, 3 x H x D] float32, read where it lies ->
+    q, k (rotated in float32) and v [B, L, H x D], each rounded once to
+    bfloat16: a grid over (batch row, row block, head)."""
+    b, l, width = qkv.shape
+    d, rows = width // (3 * heads), _block(l)
+    block = lambda first: pl.BlockSpec(
+        (1, rows, d), lambda b, i, c: (b, i, first + c))
+    table = pl.BlockSpec((rows, d), lambda b, i, c: (i, 0))
+    return _rotary_call(
+        functools.partial(_rotary_fwd_kernel, half=d // 2),
+        "attention_rotary_fwd", (b, l // rows, heads),
+        [block(0), block(heads), block(2 * heads), table, table],
+        [block(0)] * 3,
+        [jax.ShapeDtypeStruct((b, l, heads * d), jnp.bfloat16)] * 3,
+        interpret)(qkv, qkv, qkv, cos, sin)
+
+
+def _rotary_backward(dq, dk, dv, cos, sin, heads, interpret,
+                     dtype=jnp.float32):
+    """dq, dk, dv [B, L, H x D] float32 -> the projection's gradient
+    [B, L, 3 x H x D] in `dtype`, the rotation transposed in float32 (and
+    rounded once, where `dtype` is narrower): a grid over (batch row, row
+    block, the projection's 3 H blocks of columns), each gradient's block
+    read while the grid is in its columns."""
+    b, l, width = dq.shape
+    d, rows = width // heads, _block(l)
+    part = lambda n: pl.BlockSpec(
+        (1, rows, d), lambda b, i, c: (
+            b, i, jnp.clip(c - n * heads, 0, heads - 1)))
+    table = pl.BlockSpec((rows, d), lambda b, i, c: (i, 0))
+    return _rotary_call(
+        functools.partial(_rotary_bwd_kernel, heads=heads, half=d // 2),
+        "attention_rotary_bwd", (b, l // rows, 3 * heads),
+        [part(0), part(1), part(2), table, table],
+        pl.BlockSpec((1, rows, d), lambda b, i, c: (b, i, c)),
+        jax.ShapeDtypeStruct((b, l, 3 * width), dtype),
+        interpret)(dq, dk, dv, cos, sin)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def rotary_attention_pallas(qkv, key_mask, heads: int, theta: float,
+                            causal: bool, interpret: bool = False,
+                            grad_dtype=None):
+    """Multi-head attention with rotary positions from its projection to
+    its output, token-first all the way: qkv [B, L, 3 x H x D] float32,
+    the columns [q | k | v] of `x @ wqkv` (D a whole number of lane
+    tiles, L a multiple of 128), key_mask [B, L] bool -> [B, L, H x D]
+    float32. One pass over the projection's columns rotates q and k in
+    float32 and rounds q, k, v once to bfloat16 (`attention_rotary_fwd`);
+    the kernels read its outputs where they lie; backward the kernels'
+    float32 `dq`, `dk`, `dv` go through the rotation's transpose into one
+    [B, L, 3 x H x D] array (`attention_rotary_bwd`): no array between
+    the projection and the kernels is split, joined or relaid. That
+    gradient is float32 as the kernels gave it, unless the caller names
+    a `grad_dtype`: then the pass writes it rounded once to that type,
+    after the float32 transpose (the cotangent keeps qkv's type and
+    holds the rounded values). That is for the caller that owns `x @
+    wqkv` to ask for: where its product takes its operands in one
+    bfloat16 pass, the two backward products that read this gradient
+    round it so themselves, and bfloat16 here is their rounding a pass
+    earlier at half the bytes; under a product of higher precision it
+    would lose what that product keeps."""
+    return _rotary_attention_fwd(qkv, key_mask, heads, theta, causal,
+                                 interpret, grad_dtype, save_lse=False)[0]
+
+
+def _rotary_attention_fwd(qkv, key_mask, heads, theta, causal, interpret,
+                          grad_dtype=None, save_lse=True):
+    table = rotary_table(qkv.shape[1], qkv.shape[2] // (3 * heads), theta)
+    return _fwd(*_rotary(qkv, *table, heads, interpret), key_mask, causal,
+                interpret, save_lse, heads, qkv.dtype)
+
+
+def _rotary_attention_bwd(heads, theta, causal, interpret, grad_dtype, res,
+                          d_out):
+    dq, dk, dv, _ = _bwd(causal, interpret, res, d_out, heads)
+    table = rotary_table(dq.shape[1], dq.shape[2] // heads, theta)
+    return _rotary_backward(dq, dk, dv, *table, heads, interpret,
+                            grad_dtype or dq.dtype).astype(dq.dtype), None
+
+
+rotary_attention_pallas.defvjp(_rotary_attention_fwd, _rotary_attention_bwd)

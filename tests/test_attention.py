@@ -130,18 +130,19 @@ def test_blockwise_prime_length_padded_blocks():
 
 # -- the Pallas kernels (ops/attention_pallas.py), interpreted on the CPU --
 
-def _pallas_case(b, h, lq, lk, dk, dv, left_pad, seed):
+def _pallas_case(b, h, lq, lk, dk, dv, left_pad, seed, group=1):
     """(q, k, v, w [B, L, H, D], key_mask) with row 0's first `left_pad`
     keys padding: under a causal mask its first queries see no key at
-    all. The values are ones bfloat16 holds, so that the kernels' rounding
-    of their operands on the way in changes nothing and what is left to
-    compare is the rounding inside them."""
+    all; k and v at h / group heads. The values are ones bfloat16 holds,
+    so that the kernels' rounding of their operands on the way in changes
+    nothing and what is left to compare is the rounding inside them."""
     rng = np.random.default_rng(seed)
-    mk = lambda l, d: jnp.asarray(rng.normal(size=(b, l, h, d)),
-                                  jnp.bfloat16).astype(jnp.float32)
+    mk = lambda l, d, heads=h: jnp.asarray(
+        rng.normal(size=(b, l, heads, d)), jnp.bfloat16).astype(jnp.float32)
     mask = np.ones((b, lk), bool)
     mask[0, :left_pad] = False
-    return mk(lq, dk), mk(lk, dk), mk(lk, dv), mk(lq, dv), jnp.asarray(mask)
+    return (mk(lq, dk), mk(lk, dk, h // group), mk(lk, dv, h // group),
+            mk(lq, dv), jnp.asarray(mask))
 
 
 #: what the kernels may differ by from `mha` in float32 on the same
@@ -155,34 +156,66 @@ def _pallas_case(b, h, lq, lk, dk, dv, left_pad, seed):
 _FORWARD_TOL, _GRAD_TOL = 2e-3, 6e-3
 
 
-@pytest.mark.parametrize("name,lq,lk,dk,dv,causal,left_pad", [
-    ("causal", 384, 384, 24, 16, True, 0),            # 3 x 3 blocks of 128
-    ("full", 384, 384, 24, 16, False, 0),
-    ("one-block", 256, 256, 24, 16, True, 0),
+@pytest.mark.parametrize("name,lq,lk,dk,dv,causal,left_pad,group,layout", [
+    ("causal", 384, 384, 24, 16, True, 0, 1, "heads"),   # 3 x 3 blocks of 128
+    ("full", 384, 384, 24, 16, False, 0, 1, "heads"),
+    ("one-block", 256, 256, 24, 16, True, 0, 1, "heads"),
     # 384 keys in blocks of 128 under a query block of 256, and the other
     # way round
-    ("wider-query-blocks", 256, 384, 24, 16, True, 0),
-    ("wider-key-blocks", 384, 256, 24, 16, True, 0),
-    ("blocks-of-512", 1536, 1536, 24, 16, True, 0),
-    ("cell-widths", 384, 384, 192, 128, True, 0),
-    ("left-padding-and-masked-rows", 384, 384, 24, 16, True, 140),
-    ("left-padding-full", 256, 384, 24, 16, False, 130),
-    ("more-keys-than-queries", 256, 512, 24, 16, True, 3),
+    ("wider-query-blocks", 256, 384, 24, 16, True, 0, 1, "heads"),
+    ("wider-key-blocks", 384, 256, 24, 16, True, 0, 1, "heads"),
+    ("blocks-of-512", 1536, 1536, 24, 16, True, 0, 1, "heads"),
+    ("cell-widths", 384, 384, 192, 128, True, 0, 1, "heads"),
+    ("left-padding-and-masked-rows", 384, 384, 24, 16, True, 140, 1, "heads"),
+    ("left-padding-full", 256, 384, 24, 16, False, 130, 1, "heads"),
+    ("more-keys-than-queries", 256, 512, 24, 16, True, 3, 1, "heads"),
+    # four query heads a key/value head (head-first: the one layout
+    # that takes grouped heads)
+    ("grouped", 256, 256, 24, 16, True, 0, 4, "heads"),
+    ("grouped-left-padding-full", 256, 384, 128, 128, False, 130, 4,
+     "heads"),
+    # token-first: a head is a block of columns of [B, L, heads x width],
+    # whole lane tiles wide, behind the rotary pass over [q | k | v]
+    ("rows-128", 384, 384, 128, 128, True, 0, 1, "rows"),
+    ("rows-256", 256, 256, 256, 256, True, 0, 1, "rows"),
+    ("rows-256-left-padding", 256, 256, 256, 256, True, 140, 1, "rows"),
+    ("rows-left-padding-and-masked-rows", 384, 384, 128, 128, True, 140, 1,
+     "rows"),
+    ("rows-left-padding-full", 384, 384, 128, 128, False, 130, 1, "rows"),
 ])
-def test_pallas_kernels_match_dense(name, lq, lk, dk, dv, causal, left_pad):
-    """Forward and jax.grad of the kernels against `mha`."""
+def test_pallas_kernels_match_dense(name, lq, lk, dk, dv, causal, left_pad,
+                                    group, layout):
+    """Forward and jax.grad of the kernels against `mha`, on operands
+    head-first (`flash_attention_pallas`) or token-first (the kernels as
+    `rotary_attention_pallas` reaches them, float32 gradients: q and k
+    are turned by `rope` and rounded once on the dense side too)."""
+    from predictionio_tpu.ops import attention_pallas
+    from predictionio_tpu.ops.attention import rope
     from predictionio_tpu.ops.attention_pallas import flash_attention_pallas
 
-    q, k, v, w, mask = _pallas_case(2, 2, lq, lk, dk, dv, left_pad,
-                                    seed=len(name))
+    heads = 2 * group
+    if layout == "rows":
+        assert attention_pallas.layout(dk, dv) == "rows"
+        assert lq == lk and dk == dv and group == 1
+    q, k, v, w, mask = _pallas_case(2, heads, lq, lk, dk, dv, left_pad,
+                                    seed=len(name), group=group)
     heads_first = lambda t: jnp.swapaxes(t, 1, 2)
+    flat = lambda t: t.reshape(*t.shape[:2], -1)
 
     def kernel(q, k, v):
+        if layout == "rows":
+            return attention_pallas.rotary_attention_pallas(
+                jnp.concatenate([flat(q), flat(k), flat(v)], axis=-1), mask,
+                heads, 1e4, causal, True).reshape(2, lq, heads, dv)
         return heads_first(flash_attention_pallas(
             heads_first(q), heads_first(k), heads_first(v), mask, causal,
             True))
 
     def dense(q, k, v):
+        if layout == "rows":
+            q, k = (rope(t, jnp.arange(lq), 1e4).astype(jnp.bfloat16).astype(
+                jnp.float32) for t in (q, k))
+        k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
         return mha(q, k, v, causal=causal, key_mask=mask)
 
     got, want = kernel(q, k, v), dense(q, k, v)
@@ -198,18 +231,136 @@ def test_pallas_kernels_match_dense(name, lq, lk, dk, dv, causal, left_pad):
             g, want_g, atol=_GRAD_TOL * float(jnp.abs(want_g).max()))
 
 
-def test_blockwise_attention_pads_for_the_pallas_kernels(monkeypatch):
-    """`blockwise_attention` on the kernels' route with lengths it has to
-    pad (200 -> 256 positions): pad keys masked, pad queries cut off; and
-    the route it took is what a listener hears."""
+@pytest.mark.parametrize("heads,width,length,left_pad", [
+    (2, 128, 256, 0), (3, 128, 384, 5), (2, 256, 128, 0)])
+def test_rotary_on_the_flat_columns_is_rope_on_heads(heads, width, length,
+                                                     left_pad):
+    """The pass between a projection and the token-first kernels
+    (`attention_pallas._rotary`, interpreted): q and k of [q | k | v]
+    rotated where they lie equal `rope` on [B, L, H, D], v passes, each
+    rounded once to bfloat16; its transpose (`_rotary_backward`) is
+    `rope`'s gradient to float32 round-off, in float32 unless it is
+    told a narrower type, and then that gradient rounded once."""
+    from predictionio_tpu.ops import attention_pallas
+    from predictionio_tpu.ops.attention import rope
+
+    theta = 1e6
+    rng = np.random.default_rng(heads + width)
+    qkv = jnp.asarray(rng.normal(size=(2, length, 3 * heads * width)),
+                      jnp.float32)
+    table = attention_pallas.rotary_table(length, width, theta)
+    split = lambda t: tuple(p.reshape(2, length, heads, width)
+                            for p in jnp.split(t, 3, axis=-1))
+    positions = jnp.arange(length)
+
+    def turned(qkv):
+        q, k, v = split(qkv)
+        return rope(q, positions, theta), rope(k, positions, theta), v
+
+    got = attention_pallas._rotary(qkv, *table, heads, True)
+    for g, want in zip(got, turned(qkv)):
+        assert g.dtype == jnp.bfloat16 and g.shape == (2, length,
+                                                       heads * width)
+        want = want.reshape(g.shape)
+        # float32 round-off (a fused multiply-add or not) before one
+        # rounding to bfloat16: a value may land on the neighbour
+        np.testing.assert_allclose(g.astype(jnp.float32), want,
+                                   atol=2 ** -8 * float(jnp.abs(want).max()))
+        assert float((g == want.astype(jnp.bfloat16)).mean()) > 0.999
+    grads = tuple(jnp.asarray(rng.normal(size=g.shape), jnp.float32)
+                  for g in got)
+    want = jax.vjp(turned, qkv)[1](
+        tuple(g.reshape(2, length, heads, width) for g in grads))[0]
+    back = attention_pallas._rotary_backward(*grads, *table, heads, True)
+    assert back.dtype == jnp.float32
+    np.testing.assert_allclose(back, want,
+                               atol=1e-6 * float(jnp.abs(want).max()))
+    # float32 round-off, then the one rounding the backward products of
+    # a projection at the default precision would give it
+    rounded = attention_pallas._rotary_backward(*grads, *table, heads, True,
+                                                jnp.bfloat16)
+    assert rounded.dtype == jnp.bfloat16
+    assert float((rounded == back.astype(jnp.bfloat16)).mean()) > 0.999
+    np.testing.assert_allclose(rounded.astype(jnp.float32), want,
+                               atol=2 ** -8 * float(jnp.abs(want).max()))
+
+
+def _interpreted_kernels(monkeypatch):
+    """`blockwise_attention` and `rotary_attention` as a v5e would route
+    them, the kernels in the Pallas interpreter."""
     from predictionio_tpu.ops import attention, attention_pallas
 
     monkeypatch.setattr(attention, "_device_kind",
                         lambda: attention_pallas.KINDS[0])
     kernels = attention_pallas.flash_attention_pallas
+    rotary = attention_pallas.rotary_attention_pallas
     monkeypatch.setattr(
         attention_pallas, "flash_attention_pallas",
         lambda q, k, v, mask, causal: kernels(q, k, v, mask, causal, True))
+    monkeypatch.setattr(
+        attention_pallas, "rotary_attention_pallas",
+        lambda qkv, mask, heads, theta, causal, grad_dtype=None: rotary(
+            qkv, mask, heads, theta, causal, True, grad_dtype))
+
+
+def test_rotary_attention_is_one_result_on_every_layout(monkeypatch):
+    """`rotary_attention` on a projection's [q | k | v] columns: token
+    first through the kernels ("rows": a head 128 wide), head-first
+    through them (`layout` patched to "heads") and as the scan give one
+    result and one gradient up to the kernels' bfloat16 products, with
+    lengths it has to pad (200 -> 256) and a left-padded row; a listener
+    hears the route and the layout. The token-first gradient is float32
+    as the kernels gave it; a caller that names `grad_dtype` gets that
+    gradient rounded once, and the other routes do not read the name."""
+    from predictionio_tpu.ops import attention, attention_pallas
+    from predictionio_tpu.ops.attention import rotary_attention
+
+    rng = np.random.default_rng(41)
+    qkv = jnp.asarray(rng.normal(size=(2, 200, 3 * 2 * 128)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(2, 200, 2 * 128)), jnp.float32)
+    mask = jnp.asarray(np.arange(200)[None, :] >= np.array([[0], [37]]))
+
+    def run(devices=1, grad_dtype=None):
+        routes, layouts = set(), set()
+        with attention.routes_into(routes, layouts):
+            out, pull = jax.vjp(lambda t: rotary_attention(
+                t, 2, 1e4, block_k=128, causal=True, key_mask=mask,
+                devices=devices, grad_dtype=grad_dtype), qkv)
+        return routes, layouts, out, pull(w)[0]
+
+    scan = run()
+    assert scan[:2] == ({"xla"}, set())
+    _interpreted_kernels(monkeypatch)
+    rows = run()
+    assert rows[:2] == ({"pallas"}, {"rows"})
+    assert run(devices=4)[:2] == ({"xla"}, set())
+    as_bfloat16 = lambda t: t.astype(jnp.bfloat16).astype(jnp.float32)
+    rounded = run(grad_dtype=jnp.bfloat16)
+    assert rounded[3].dtype == rows[3].dtype == jnp.float32
+    assert (rounded[2] == rows[2]).all()
+    assert (rounded[3] == as_bfloat16(rows[3])).all()
+    assert not (rows[3] == as_bfloat16(rows[3])).all()
+    monkeypatch.setattr(attention_pallas, "layout", lambda dk, dv: "heads")
+    heads = run()
+    assert heads[:2] == ({"pallas"}, {"heads"})
+    assert (run(grad_dtype=jnp.bfloat16)[3] == heads[3]).all()
+    assert rows[2].shape == (2, 200, 256) and not np.asarray(
+        rows[2][1, :37]).any()
+    # both layouts round the same operands to bfloat16 for the same
+    # products: they differ by the rotation's float32 round-off alone
+    for other, tols in ((heads, (2e-3, 5e-3)), (scan, (1e-2, 1e-2))):
+        for got, want, tol in zip(rows[2:], other[2:], tols):
+            np.testing.assert_allclose(
+                got, want, atol=tol * float(jnp.abs(want).max()))
+
+
+def test_blockwise_attention_pads_for_the_pallas_kernels(monkeypatch):
+    """`blockwise_attention` on the kernels' route with lengths it has to
+    pad (200 -> 256 positions): pad keys masked, pad queries cut off; and
+    the route it took is what a listener hears."""
+    from predictionio_tpu.ops import attention
+
+    _interpreted_kernels(monkeypatch)
     rng = np.random.default_rng(9)
     q, k, v = (jnp.asarray(rng.normal(size=(2, 200, 2, d)), jnp.float32)
                for d in (64, 64, 128))
@@ -257,3 +408,102 @@ def test_attention_route(kind, lq, lk, dk, dv, block, devices, route):
 
     assert attention_route(kind, lq, lk, dk, dv, block_k=block,
                            devices=devices) == route
+
+
+@pytest.mark.parametrize("kind,length,dk,dv,devices,layout", [
+    ("TPU v5 lite", 8192, 128, 128, 1, "rows"),     # ouro-2.6b-pp8.train
+    ("TPU v5 lite", 16384, 256, 256, 1, "rows"),
+    ("TPU v5 lite", 8192, 128, 256, 1, "rows"),
+    ("TPU v5 lite", 8192, 192, 128, 1, "heads"),    # kimivl-a3b-ep8.train
+    ("TPU v5 lite", 32768, 64, 64, 1, "heads"),     # lfm2-a2b-ep8.train
+    ("TPU v5 lite", 8192, 192, 64, 1, "heads"),
+    # whatever the widths: a mesh, another chip and a `dq` over its VMEM
+    # keep the scan
+    ("TPU v5 lite", 8192, 128, 128, 4, "xla"),
+    ("TPU v5 lite", 8192, 192, 128, 2, "xla"),
+    ("TPU v4", 8192, 128, 128, 1, "xla"),
+    ("cpu", 8192, 128, 128, 1, "xla"),
+    ("TPU v5 lite", 65536, 128, 128, 1, "xla"),
+])
+def test_attention_layout(kind, length, dk, dv, devices, layout):
+    """Where `rotary_attention`'s operands lie follows the widths alone,
+    on the route `attention_route` gives. (`blockwise_attention`, which
+    the grouped and the latent mixers call at any of these widths, is
+    head-first on the kernels' route: the next test.)"""
+    from predictionio_tpu.ops.attention import (
+        attention_layout, attention_route,
+    )
+
+    assert attention_layout(kind, length, length, dk, dv,
+                            devices=devices) == layout
+    assert (attention_route(kind, length, length, dk, dv, devices=devices)
+            == "xla") == (layout == "xla")
+
+
+@pytest.mark.parametrize("heads,kv_heads,width", [
+    (2, 2, 128), (4, 1, 128), (2, 1, 256)])
+def test_blockwise_attention_is_head_first_at_any_width(monkeypatch, heads,
+                                                        kv_heads, width):
+    """The entry chooses the layout before the widths do: at widths of
+    whole lane tiles, grouped or not, `blockwise_attention`'s [B, L, H,
+    D] callers are heard on `layout="heads"`."""
+    from predictionio_tpu.ops import attention
+
+    _interpreted_kernels(monkeypatch)
+    rng = np.random.default_rng(width + heads)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 128, n, width)), jnp.float32)
+               for n in (heads, kv_heads, kv_heads))
+    routes, layouts = set(), set()
+    with attention.routes_into(routes, layouts):
+        got = blockwise_attention(q, k, v, block_k=128, causal=True)
+    assert (routes, layouts) == ({"pallas"}, {"heads"})
+    want = mha(q, *(jnp.repeat(t, heads // kv_heads, axis=2)
+                    for t in (k, v)), causal=True)
+    np.testing.assert_allclose(got, want,
+                               atol=1e-2 * float(jnp.abs(want).max()))
+
+
+def test_a_steps_hash_keeps_the_kernels_and_drops_their_locations():
+    """`benchmarks/tools/step_text_hash.py`: the text it hashes holds a
+    Mosaic kernel's grid, blocks and index maps and not the source lines
+    that built it: the same kernel called from two lines hashes alike
+    where the raw payloads differ, and another index map does not."""
+    import importlib.util
+    import os
+    import re
+
+    from jax.experimental import pallas as pl
+
+    spec = importlib.util.spec_from_file_location(
+        "step_text_hash", os.path.join(
+            os.path.dirname(__file__), "..", "benchmarks", "tools",
+            "step_text_hash.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = x_ref[...] * 2.0
+
+    def call(index):
+        return pl.pallas_call(
+            kernel, grid=(2,), out_shape=jax.ShapeDtypeStruct(
+                (256, 128), jnp.float32),
+            in_specs=[pl.BlockSpec((128, 128), index)],
+            out_specs=pl.BlockSpec((128, 128), lambda i: (i, 0)))
+
+    here = lambda x: call(lambda i: (i, 0))(x)
+    there = lambda x: call(
+        lambda i: (i, 0))(x)                # the same, a line further down
+    other = lambda x: call(lambda i: (1 - i, 0))(x)
+
+    def payload(f):
+        text = jax.jit(f).trace(jax.ShapeDtypeStruct(
+            (256, 128), jnp.float32)).lower(
+                lowering_platforms=("tpu",)).as_text()
+        return tool._BODY.search(tool._CONFIG.search(text).group(0)).group(1)
+
+    a, b, c = (payload(f) for f in (here, there, other))
+    assert a != b
+    assert tool.kernel_text(a) == tool.kernel_text(b)
+    assert tool.kernel_text(a) != tool.kernel_text(c)
+    assert not re.search(r"loc\(", tool.kernel_text(a))

@@ -635,3 +635,178 @@ def test_the_looped_step_under_a_mesh_is_the_step(mesh8):
     for group, norm in want["grad_norm"].items():
         assert abs(float(got["grad_norm"][group]) - float(norm)) \
             < 2e-3 * float(norm), group
+
+
+def _on_attention_kernels(monkeypatch):
+    """`blockwise_attention` and `rotary_attention` as a v5e would route
+    them, the kernels in the Pallas interpreter, with blocks a session
+    of 128 fills."""
+    from predictionio_tpu.ops import attention, attention_pallas
+
+    monkeypatch.setattr(seqrec, "ATTENTION_BLOCK", 512)
+    monkeypatch.setattr(attention, "_device_kind",
+                        lambda: attention_pallas.KINDS[0])
+    kernels = attention_pallas.flash_attention_pallas
+    rotary = attention_pallas.rotary_attention_pallas
+    monkeypatch.setattr(
+        attention_pallas, "flash_attention_pallas",
+        lambda q, k, v, mask, causal: kernels(q, k, v, mask, causal, True))
+    monkeypatch.setattr(
+        attention_pallas, "rotary_attention_pallas",
+        lambda qkv, mask, heads, theta, causal, grad_dtype=None: rotary(
+            qkv, mask, heads, theta, causal, True, grad_dtype))
+
+
+def test_the_looped_step_is_one_step_on_both_layouts(monkeypatch):
+    """The looped `mha` spec at heads a lane tile wide (2 x 128), the
+    kernels interpreted: its loss, each pass's loss and every gradient
+    group with the kernels reading q, k, v token-first (the widths'
+    layout: nothing between `x @ wqkv` and the kernels is relaid) and
+    head-first (`layout` patched: the split, `rope` on [B, L, H, D], the
+    transposes). Both round the same operands to bfloat16 for the same
+    products; the rotation's float32 round-off moves a few of them to
+    the neighbouring bfloat16."""
+    from predictionio_tpu.ops import attention, attention_pallas
+
+    _on_attention_kernels(monkeypatch)
+    p = small_spec(d_model=256, n_heads=2, n_layers=1, n_loops=2,
+                   max_len=128, ffn_width=128)
+    params = weights(p)
+    rng = np.random.default_rng(5)
+    s = rng.integers(1, VOCAB, size=(1, 129))
+    s[:, :9] = 0
+    seqs, targets = s[:, :-1].astype(np.int32), s[:, 1:].astype(np.int32)
+
+    def run():
+        routes, layouts = set(), set()
+        with attention.routes_into(routes, layouts):
+            (loss, (_, _, exits)), grads = jax.value_and_grad(
+                seqrec._loss_fn, has_aux=True)(
+                    params, jnp.asarray(seqs), jnp.asarray(targets), p)
+        assert routes == {"pallas"}
+        return layouts, loss, exits, grads
+
+    layouts, loss, exits, grads = run()
+    assert layouts == {"rows"}
+    monkeypatch.setattr(attention_pallas, "layout", lambda dk, dv: "heads")
+    layouts, want_loss, want_exits, want_grads = run()
+    assert layouts == {"heads"}
+    assert abs(float(loss) - float(want_loss)) < 2e-5 * float(want_loss)
+    np.testing.assert_allclose(exits["loop_loss"], want_exits["loop_loss"],
+                               rtol=2e-5)
+    got, want = seqrec._group_norms(grads), seqrec._group_norms(want_grads)
+    assert set(got) == set(want) and "layer0.attention" in want
+    for group, norm in want.items():
+        assert abs(float(got[group]) - float(norm)) < 2e-3 * float(norm), \
+            group
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree.leaves(want_grads)):
+        assert rel(g, w) < 5e-3, jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("ambient,grad_dtype", [
+    (None, jnp.bfloat16), ("bfloat16", jnp.bfloat16), ("highest", None),
+    ("float32", None)])
+def test_the_projections_gradient_is_rounded_where_its_products_round_it(
+        ambient, grad_dtype, monkeypatch):
+    """The `mha` mixer asks the token-first route for a bfloat16 gradient
+    of `x @ wqkv` only while that product, and so its two backward
+    products, take their operands in one bfloat16 pass: the product
+    carries no precision of its own (the default's) AND the default is
+    the TPU's; under a higher default it asks for none, and the op's
+    gradient stays float32."""
+    from predictionio_tpu.ops import attention, attention_pallas
+
+    _on_attention_kernels(monkeypatch)
+    asked = []
+    rotary = attention_pallas.rotary_attention_pallas
+    monkeypatch.setattr(
+        attention_pallas, "rotary_attention_pallas",
+        lambda *a, grad_dtype=None: asked.append(grad_dtype) or rotary(
+            *a, grad_dtype=grad_dtype))
+    p = small_spec(d_model=256, n_heads=2, n_layers=1, n_loops=1,
+                   max_len=128, exit_gate=False)
+    rng = np.random.default_rng(2)
+    layer = {"wqkv": jnp.asarray(rng.normal(size=(256, 768)), jnp.float32),
+             "wo": jnp.asarray(rng.normal(size=(256, 256)), jnp.float32)}
+    x = jnp.asarray(rng.normal(size=(1, 128, 256)), jnp.float32)
+    mask = jnp.ones((1, 128), bool)
+
+    def traced():
+        return jax.make_jaxpr(lambda layer, x: seqrec._attention(
+            layer, x, mask, p, "mha", None, False))(layer, x)
+
+    if ambient is None:
+        jaxpr = traced()
+    else:
+        with jax.default_matmul_precision(ambient):
+            jaxpr = traced()
+    assert asked == [grad_dtype]
+    products = [e for e in jaxpr.jaxpr.eqns if e.primitive.name
+                == "dot_general"]
+    assert len(products) == 2       # x @ wqkv, att @ wo
+    if ambient is None:     # (under a default, the product takes it)
+        assert all(e.params["precision"] is None for e in products)
+
+
+def _tiny_spec(config, **over):
+    """A benchmark configuration's tiny section, one layer pattern of it
+    at widths the attention kernels tile."""
+    import os
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", f"{config}.json")) as f:
+        params = json.load(f)["tiny"]["algorithm_params"]
+    return seqrec.SeqRecParams(**{**params, "max_len": 128, "batch_size": 1,
+                                  "epochs": 1, "remat": False,
+                                  "device_init": False, **over})
+
+
+@pytest.mark.parametrize("config,over,layout", [
+    # 2 heads of 128: whole lane tiles, the kernels read a projection's
+    # columns
+    ("seqrec-ouro-2.6b-pp8",
+     dict(d_model=256, n_heads=2, n_layers=1, n_loops=2), "rows"),
+    # q/k 128 + 64 = 192, v 128 (the cell's widths): head-first
+    ("seqrec-kimi-vl-a3b-ep8",
+     dict(n_heads=1, n_layers=2, qk_nope_head_dim=128, qk_rope_head_dim=64,
+          v_head_dim=128), "heads"),
+    # 2 query heads on 1 key/value head of 64 (the cell's width)
+    ("seqrec-lfm2-24b-a2b-ep8",
+     dict(n_heads=2, n_kv_heads=1, head_dim=64, rotary_dim=64, n_layers=2,
+          mixer=["conv", "gqa"]), "heads"),
+])
+def test_a_train_counts_where_its_attention_kernels_read_a_head(
+        config, over, layout, monkeypatch):
+    """`pio_train_seqrec_attention_layout_tokens_total{layout}`: every
+    position of a train on the kernels' route, under where its compiled
+    step says the kernels read a head; `impl="pallas"` either way. The
+    tiny Ouro, Kimi and LFM2 specs at widths the kernels tile."""
+    from predictionio_tpu.obs.registry import default_registry
+
+    _on_attention_kernels(monkeypatch)
+    p = _tiny_spec(config, **over)
+    reg = default_registry()
+
+    def counted(name, **labels):
+        c = reg.get(name)
+        return c.value(**labels) if c is not None else 0
+
+    names = ("pio_train_seqrec_attention_layout_tokens_total",
+             "pio_train_seqrec_attention_tokens_total")
+    before = {(name, label): counted(name, **{key: label})
+              for name, key, labels in (
+                  (names[0], "layout", ("rows", "heads")),
+                  (names[1], "impl", ("pallas", "xla")))
+              for label in labels}
+    sessions = [[f"i{(s + j) % 13}" for j in range(100 + s)]
+                for s in range(2)]
+    model = seqrec.train_seqrec(None, sessions, p)
+    assert np.isfinite(model.record["loss"]).all()
+    gained = {key: counted(key[0], **{
+        "layout" if key[0] == names[0] else "impl": key[1]}) - value
+        for key, value in before.items()}
+    positions = 2 * 128         # two steps of one session, padding too
+    other = "heads" if layout == "rows" else "rows"
+    assert gained == {(names[0], layout): positions, (names[0], other): 0,
+                      (names[1], "pallas"): positions, (names[1], "xla"): 0}

@@ -74,6 +74,34 @@ def test_attention_kernels_compile_for_v5e(one_chip, name, b, h, kv_heads,
         assert kernel in text
 
 
+@pytest.mark.parametrize("grad_dtype", [None, jnp.bfloat16],
+                         ids=["float32-gradient", "bfloat16-gradient"])
+def test_rotary_attention_compiles_for_v5e_token_first(one_chip, grad_dtype):
+    """ouro-2.6b-pp8.train's attention between its two projections (1
+    session x 16 heads x 8,192 x 128, a head a block of 128 columns of
+    [1, 8192, 2048]): the rotary pass over the projection's columns, the
+    kernels with `dq` of a head resident in VMEM, the pass back in either
+    type, and no transpose or copy of a [B, L, .] array among them."""
+    from predictionio_tpu.ops import attention_pallas
+
+    assert attention_pallas.layout(128, 128) == "rows"
+    assert attention_pallas.tiles(8192, 8192, 128, 128)
+
+    def loss(qkv, mask, w):
+        return (attention_pallas.rotary_attention_pallas(
+            qkv, mask, 16, 1e6, True, False, grad_dtype) * w).sum()
+
+    shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+    text = jax.jit(jax.grad(loss)).lower(
+        shape(1, 8192, 3 * 2048), shape(1, 8192, dtype=jnp.bool_),
+        shape(1, 8192, 2048)).compile().as_text()
+    for kernel in ("attention_rotary_fwd", "flash_attention_pallas_fwd",
+                   "flash_attention_pallas_bwd", "attention_rotary_bwd"):
+        assert kernel in text
+    assert not _relayouts(text, 8192)
+
+
 @pytest.mark.parametrize("name,mesh_shape,kernels", [
     ("one-chip", None, True),
     # batch over "data", a "model" axis beside it: the compiler partitions
@@ -228,6 +256,25 @@ def _kernel_calls(text, name):
     import re
 
     return len(re.findall(rf"{name}[.0-9]* = ", text))
+
+
+def _relayouts(text, length, scope=None):
+    """The `copy` and `transpose` instructions of a compiled program's
+    text (under a `jax.named_scope`, if given) whose result is an array
+    of `length` positions by 128 numbers or more: a relayout of an
+    activation, not of a mask or a head's row sums."""
+    import re
+
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s+(?:ROOT\s+)?%?(\S+) = \w+\[([0-9,]*)\]\S*\s+"
+                     r"(copy|transpose)\(", line)
+        if not m or (scope and scope not in line):
+            continue
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        if length in dims and np.prod(dims) >= length * 128:
+            found.append(m.group(1))
+    return found
 
 
 def test_the_hybrid_cells_step_fits_a_v5e_with_all_heads_at_once(
@@ -406,6 +453,12 @@ def test_the_looped_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
     text = compiled.as_text()
     assert _kernel_calls(text, "flash_attention_pallas_fwd") == 2 * 6
     assert _kernel_calls(text, "flash_attention_pallas_bwd") == 6
+    # token-first: the rotary pass in front of every forward call, its
+    # transpose behind every backward call, and no activation relaid
+    # between a projection and a kernel
+    assert _kernel_calls(text, "attention_rotary_fwd") == 2 * 6
+    assert _kernel_calls(text, "attention_rotary_bwd") == 6
+    assert not _relayouts(text, p.max_len, "seqrec_attention")
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert held <= 15.75 * 2 ** 30 - 1e9, held

@@ -711,17 +711,22 @@ def _relu2(w, x):
     return jnp.square(jax.nn.relu(x @ w["w_up"])) @ w["w_down"]
 
 
-def _short_conv(layer, x, key_mask):
-    """The "conv" mixer on normed x [B, L, D] -> [B, L, D]: [b | c | u] =
-    x W_in; y = (c * conv(b * u)) W_out, the convolution depthwise,
-    causal, without activation. A padding position's input is 0, so it
-    adds nothing to the taps' sums, and a left-padded session is the
-    unpadded one. The elementwise chain is XLA's to fuse."""
+def _short_conv(layer, x, key_mask, devices: int = 1):
+    """The "conv" mixer on normed x [B, L, D] -> [B, L, D], in a program
+    traced for `devices` devices: [b | c | u] = x W_in; y = (c * conv(b *
+    u)) W_out, the convolution depthwise, causal, without activation. A
+    padding position's input is 0, so it adds nothing to the taps' sums,
+    and a left-padded session is the unpadded one. Everything between
+    the two products is `linear_attention.gated_short_conv`, on its
+    route (`gated_short_conv_route`): fused passes over the projection's
+    columns where they lie, or the elementwise chain XLA fuses. The
+    passes write the projection's gradient in the type its two backward
+    products read it in (`_qkv_grad_dtype`: XLA's chain rounds its halves
+    to bfloat16 on the way to those products too)."""
     x = jnp.where(key_mask[..., None], x, 0.0)
-    b, c, u = jnp.split(x @ layer["conv_in"], 3, axis=-1)
-    mixed = linear_attention.causal_conv(b * u, layer["conv_taps"],
-                                         activation=None)
-    return (c * mixed) @ layer["conv_out"]
+    return linear_attention.gated_short_conv(
+        x @ layer["conv_in"], layer["conv_taps"], devices,
+        grad_dtype=_qkv_grad_dtype()) @ layer["conv_out"]
 
 
 def _linear_attention(layer, x, key_mask, p: SeqRecParams, devices: int):
@@ -761,14 +766,16 @@ def _linear_attention(layer, x, key_mask, p: SeqRecParams, devices: int):
 
 def _qkv_grad_dtype():
     """What `_attention` lets `rotary_attention` round the gradient of
-    `x @ wqkv` to where the kernels' route writes it token-first:
+    `x @ wqkv` to where the kernels' route writes it token-first, and
+    `_short_conv` lets `gated_short_conv` round that of `x @ conv_in` to:
     bfloat16, the type that product's two backward products read it in
-    anyway, BECAUSE `_attention` multiplies at the default precision and
+    anyway, BECAUSE the mixers multiply at the default precision and
     only while the default is the TPU's one bfloat16 pass; None (float32)
     under `jax.default_matmul_precision` of anything higher
-    (tests/test_seqrec_looped.py holds the product and this together).
-    Half the bytes of that array: 14 ms of a 1,139 ms step in
-    ouro-2.6b-pp8.train (PERF.md section 6, PR 41)."""
+    (tests/test_seqrec_looped.py and tests/test_seqrec_conv.py hold the
+    product and this together). Half the bytes of that array: 14 ms of a
+    1,139 ms step in ouro-2.6b-pp8.train, 14 of 839 in lfm2-a2b-ep8.train
+    (PERF.md section 6, PRs 41 and 42)."""
     ambient = jax.config.jax_default_matmul_precision
     return jnp.bfloat16 if ambient in (None, "default", "bfloat16") else None
 
@@ -944,8 +951,8 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
                     layer["ssm"], x, key_mask, p.norm_eps), layer, "post1")
         if mixer == "conv":
             with jax.named_scope("seqrec_short_conv"):
-                return joined(h, _short_conv(layer, x, key_mask), layer,
-                              "post1")
+                return joined(h, _short_conv(layer, x, key_mask, devices),
+                              layer, "post1")
         if mixer == "gdn":
             with jax.named_scope("seqrec_linear_attention"):
                 return joined(h, _linear_attention(layer, x, key_mask, p,
@@ -1175,6 +1182,8 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
     softmax-attention layer's blocks with the Pallas kernels,
     `attention_rows`, present and True where those kernels read every
     such layer's heads token-first, where the projections wrote them,
+    `short_conv_pallas`, present where a "conv" layer ran: whether
+    `gated_short_conv` ran every one as the fused passes,
     `linear_attention_pallas`, whether `gated_delta_rule` ran every
     linear-attention layer's recurrence as Pallas kernels, and
     `expert_product_pallas`, whether `held_experts` multiplied every
@@ -1201,9 +1210,9 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
             seqs = jax.lax.with_sharding_constraint(seqs, sh)
             targets = jax.lax.with_sharding_constraint(targets, sh)
         routes, rule_routes, product_routes = set(), set(), set()
-        layouts = set()
+        layouts, conv_routes = set(), set()
         with routes_into(routes, layouts), \
-                linear_attention.routes_into(rule_routes), \
+                linear_attention.routes_into(rule_routes, conv_routes), \
                 moe.routes_into(product_routes):
             (loss, (expert_layers, mixers, exits)), grads = \
                 jax.value_and_grad(_loss_fn, has_aux=True)(
@@ -1224,6 +1233,10 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
             # (a step whose kernels read head-first reports what it did
             # before there were two layouts: its program is the one it was)
             stats["attention_rows"] = jnp.asarray(True)
+        if conv_routes:
+            # (likewise: only a step with a "conv" layer says how it ran)
+            stats["short_conv_pallas"] = jnp.asarray(
+                conv_routes == {"pallas"})
         if p.n_loops > 1 or p.sublayers or p.mtp_layers:
             # the layers run by the pass they ran in (a layer need not
             # have a mixer, so `mixer_layers` does not add up to them)
@@ -1575,7 +1588,8 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
         if steps else {},
         {name: int(n) for name, n in steps[0]["layer_passes"].items()}
         if steps and "layer_passes" in steps[0] else None,
-        "rows" if steps and steps[0].get("attention_rows") else "heads")
+        "rows" if steps and steps[0].get("attention_rows") else "heads",
+        "pallas" if steps and steps[0].get("short_conv_pallas") else "xla")
     return SeqRecModel(item_vocab=all_items, params=params, hyper=p,
                        record=record)
 

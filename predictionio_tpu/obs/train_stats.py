@@ -71,6 +71,14 @@ the weights), `seqrec_put` (sharding, the optimizer's state),
   or ``xla``. The chain's route is the rule's
   (ops/linear_attention.gated_delta_chain decides both), so the two
   counters move together.
+* ``pio_train_seqrec_short_conv_chain_tokens_total{impl}`` — positions
+  of the trained batches, padding too, times the short-convolution
+  (``conv``) layers, by the route of what such a layer runs between its
+  two projections (the gates and the causal convolution) when their step
+  was traced: ``pallas`` (every such layer through the fused passes of
+  ops/short_conv_pallas.py) or ``xla``
+  (ops/linear_attention.gated_short_conv_route decides). A model without
+  such a layer counts nothing here.
 * ``pio_train_seqrec_mixer_tokens_total{mixer}`` — positions of the
   trained batches, padding too, times the layers of each mixer
   (``mha``, ``mla``, ``gqa``, ``gdn``, ``conv``, ``ssm``) the compiled
@@ -241,6 +249,16 @@ def seqrec_linear_attention_chain_tokens(registry: MetricsRegistry = None):
         labelnames=("impl",))
 
 
+def seqrec_short_conv_chain_tokens(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_short_conv_chain_tokens_total",
+        "Positions of the trained batches times the short-convolution "
+        "layers, by the route their step's chain between the layer's two "
+        "projections was traced on "
+        "(ops/linear_attention.gated_short_conv_route)",
+        labelnames=("impl",))
+
+
 def seqrec_mixer_tokens(registry: MetricsRegistry = None):
     return (registry or default_registry()).counter(
         "pio_train_seqrec_mixer_tokens_total",
@@ -317,7 +335,8 @@ def observe_seqrec_record(record: dict, targets, rows,
                           expert_product_impl: str,
                           mixer_layers: dict,
                           layer_passes: dict = None,
-                          attention_layout: str = "heads") -> None:
+                          attention_layout: str = "heads",
+                          short_conv_impl: str = "xla") -> None:
     """The token and expert counters from one train's record
     (models/seqrec.train_seqrec): `targets` the padded target ids of all
     sessions, `rows` the sessions of each step's batch, `attention_impl`
@@ -326,7 +345,8 @@ def observe_seqrec_record(record: dict, targets, rows,
     `mixer_layers` the layer passes that step ran by mixer,
     `layer_passes` those of its first pass and of its repeats (None from
     a step of one pass: all are first), `attention_layout` where its
-    attention kernels read a head."""
+    attention kernels read a head, `short_conv_impl` the route its
+    short-convolution layers' chain was traced on."""
     import numpy as np
 
     real = sum(int((targets[r] > 0).sum()) for r in rows)
@@ -356,6 +376,9 @@ def observe_seqrec_record(record: dict, targets, rows,
                         seqrec_linear_attention_chain_tokens):
             counter().inc(positions * mixer_layers["gdn"],
                           impl=linear_attention_impl)
+    if "conv" in mixer_layers:
+        seqrec_short_conv_chain_tokens().inc(
+            positions * mixer_layers["conv"], impl=short_conv_impl)
     if "load" not in record or not record["load"]:
         return
     load = np.asarray(record["load"], np.float64)      # [step, layer, expert]

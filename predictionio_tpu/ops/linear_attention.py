@@ -1,6 +1,9 @@
 """Linear attention by the gated delta rule (Gated DeltaNet,
 arXiv:2412.06464), and the short causal convolution in front of it
-(`causal_conv`, which a gated short-convolution mixer calls too).
+(`causal_conv`), which a gated short-convolution mixer calls too: what
+that mixer runs between its two projections is `gated_short_conv`, on a
+route of its own (`gated_short_conv_route`: the fused passes of
+ops/short_conv_pallas.py, or `causal_conv` between the two gates).
 
 Each head keeps a state S [dk, dv] instead of keys and values. Position
 t first lets the state decay, then replaces what the state holds under
@@ -48,12 +51,14 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-from typing import Callable, Iterator, Optional, Set
+from typing import Callable, Iterator, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from predictionio_tpu.ops import attention_pallas, linear_attention_pallas
+from predictionio_tpu.ops import (
+    attention_pallas, linear_attention_pallas, short_conv_pallas,
+)
 
 #: positions a chunk, on either route. A constant, from the paper's
 #: kernels (64) and chip runs of the scan at 1 x 32 heads x 16,384 x 128
@@ -76,6 +81,43 @@ def causal_conv(x: jax.Array, w: jax.Array,
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     out = sum(w[j] * padded[:, j:j + l] for j in range(taps))
     return out if activation is None else activation(out)
+
+
+def gated_short_conv_route(device_kind: str, l: int, d: int, taps: int,
+                           devices: int = 1) -> str:
+    """Which implementation `gated_short_conv` runs for a session of `l`
+    positions, `d` columns a part and `taps` taps on a device of this
+    kind, in a program traced for `devices` devices: "pallas", the
+    passes of ops/short_conv_pallas.py, on the TPUs the attention
+    kernels are listed for, in a program for one device, where the
+    passes tile the sizes (`short_conv_pallas.tiles`: whole lane tiles,
+    whole row blocks, the taps within a halo); "xla" everywhere else."""
+    if (device_kind in attention_pallas.KINDS and devices == 1
+            and short_conv_pallas.tiles(l, d, taps)):
+        return "pallas"
+    return "xla"
+
+
+def gated_short_conv(bcu: jax.Array, taps: jax.Array, devices: int = 1,
+                     grad_dtype=None) -> jax.Array:
+    """A gated short convolution between its two projections: bcu
+    [B, L, 3D] (the input projection's output, columns [b | c | u]),
+    taps [K, D] -> c * conv(b * u) [B, L, D], the output projection's
+    input; the convolution is `causal_conv`'s without activation. The
+    route is `gated_short_conv_route`'s, and whoever listens hears it
+    (`routes_into`'s second set): "pallas", two fused passes over the
+    projection's columns where they lie with a backward pass of their
+    own; "xla", the plain chain, XLA's to fuse. `grad_dtype`: the type
+    the passes round bcu's gradient to where they write it (None:
+    float32; XLA's chain decides for itself what its consumers read)."""
+    route = gated_short_conv_route(_device_kind(), bcu.shape[1],
+                                   bcu.shape[2] // 3, taps.shape[0], devices)
+    _heard(route, short_conv=True)
+    if route == "pallas":
+        return short_conv_pallas.gated_short_conv_pallas(
+            bcu, taps, grad_dtype=grad_dtype)
+    b, c, u = jnp.split(bcu, 3, axis=-1)
+    return c * causal_conv(b * u, taps, activation=None)
 
 
 def _unit_lower_inverse(a: jax.Array) -> jax.Array:
@@ -159,25 +201,28 @@ def route_here(dk: int, dv: int, devices: int = 1) -> str:
     return gated_delta_rule_route(_device_kind(), dk, dv, devices)
 
 
-_ROUTES: contextvars.ContextVar[Optional[Set[str]]] = contextvars.ContextVar(
+_ROUTES: contextvars.ContextVar[
+    Optional[Tuple[Set[str], Optional[Set[str]]]]] = contextvars.ContextVar(
     "gated_delta_rule_routes", default=None)
 
 
 @contextlib.contextmanager
-def routes_into(routes: Set[str]) -> Iterator[None]:
+def routes_into(routes: Set[str],
+                short_conv: Optional[Set[str]] = None) -> Iterator[None]:
     """While the block runs (a trace), every `gated_delta_rule` and
-    `gated_delta_chain` call adds the route it took to `routes`."""
-    token = _ROUTES.set(routes)
+    `gated_delta_chain` call adds the route it took to `routes`, every
+    `gated_short_conv` call its own to `short_conv`."""
+    token = _ROUTES.set((routes, short_conv))
     try:
         yield
     finally:
         _ROUTES.reset(token)
 
 
-def _heard(route: str) -> None:
+def _heard(route: str, short_conv: bool = False) -> None:
     heard = _ROUTES.get()
-    if heard is not None:
-        heard.add(route)
+    if heard is not None and heard[short_conv] is not None:
+        heard[short_conv].add(route)
 
 
 def gated_delta_chain(qkvz: jax.Array, taps: jax.Array, g: jax.Array,

@@ -576,3 +576,113 @@ def test_the_step_under_a_mesh_is_the_step(mesh8):
     for group, norm in want["grad_norm"].items():
         assert abs(float(got["grad_norm"][group]) - float(norm)) \
             < 2e-3 * float(norm), group
+
+
+def _on_conv_passes(monkeypatch):
+    """`gated_short_conv` as a v5e would route it, the passes
+    interpreted."""
+    from predictionio_tpu.ops import attention_pallas, short_conv_pallas
+
+    monkeypatch.setattr(linear_attention, "_device_kind",
+                        lambda: attention_pallas.KINDS[0])
+    passes = short_conv_pallas.gated_short_conv_pallas
+    monkeypatch.setattr(short_conv_pallas, "gated_short_conv_pallas",
+                        lambda bcu, taps, **kw: passes(bcu, taps, True, **kw))
+
+
+def test_a_step_says_which_route_its_short_convolution_was_traced_on(
+        monkeypatch):
+    """The step's `short_conv_pallas` is what `gated_short_conv` chose
+    at trace time from the device's kind, the devices and the shapes: on
+    the CPU False; with the passes' route forced (and interpreted) at a
+    width of whole lane tiles and a length of whole row blocks True, the
+    loss and every gradient norm the plain chain's; under a mesh of two
+    devices False again; at the small spec's 64 columns False whatever
+    the device; and a step without a convolution layer says nothing."""
+    from jax.sharding import Mesh
+
+    p = small_spec(d_model=128, max_len=128, learning_rate=1e-3)
+    optimizer = seqrec.make_optimizer(p)
+    rng = np.random.default_rng(5)
+    seqs = rng.integers(1, VOCAB, size=(2, 129))
+    seqs[0, :40] = 0                        # a left-padded session
+    seqs, targets = (jnp.asarray(t, jnp.int32)
+                     for t in (seqs[:, :-1], seqs[:, 1:]))
+
+    def step(spec, mesh=None):
+        # (float32 products: the layer then asks the passes for a float32
+        # gradient too, `_qkv_grad_dtype`, and the two routes are one
+        # mathematics to rounding; at the default the passes round the
+        # projection's gradient to bfloat16 where the TPU's products
+        # would, which the CPU's float32 products do not repeat)
+        params = weights(spec)
+        with jax.default_matmul_precision("float32"):
+            return seqrec.make_train_step(mesh, spec, optimizer)(
+                params, optimizer.init(params), seqs[:, :spec.max_len],
+                targets[:, :spec.max_len])[2]
+
+    plain = step(p)
+    assert "short_conv_pallas" in plain and not plain["short_conv_pallas"]
+    _on_conv_passes(monkeypatch)
+    for mesh, pallas in (
+            (None, True),
+            (Mesh(np.asarray(jax.devices()[:2]), ("data",)), False)):
+        stats = step(p, mesh)
+        assert bool(stats["short_conv_pallas"]) is pallas
+        assert not bool(stats["linear_attention_pallas"])
+        assert abs(float(stats["loss"]) - float(plain["loss"])) \
+            < 2e-6 * float(plain["loss"])
+        # (adamw's first step is a sign where a gradient is not 0: an
+        # update's norm moves by the entries that lie within its epsilon)
+        for key, within in (("grad_norm", 2e-5), ("update_norm", 2e-3)):
+            for group, norm in plain[key].items():
+                assert abs(float(stats[key][group]) - float(norm)) \
+                    < within * float(norm), (key, group)
+    assert not step(small_spec())["short_conv_pallas"]
+    no_conv = step(small_spec(mixer="gqa", n_layers=2, max_len=L))
+    assert "short_conv_pallas" not in no_conv
+
+
+@pytest.mark.parametrize("ambient,grad_dtype", [
+    (None, jnp.bfloat16), ("bfloat16", jnp.bfloat16), ("highest", None),
+    ("float32", None)])
+def test_the_projections_gradient_is_rounded_where_its_products_round_it(
+        ambient, grad_dtype, monkeypatch):
+    """The `conv` mixer asks the passes for a bfloat16 gradient of `x @
+    conv_in` only while that product, and so its two backward products,
+    take their operands in one bfloat16 pass: the product carries no
+    precision of its own (the default's) AND the default is the TPU's;
+    under a higher default it asks for none, and the gradient stays
+    float32."""
+    from predictionio_tpu.ops import short_conv_pallas
+
+    _on_conv_passes(monkeypatch)
+    asked = []
+    passes = short_conv_pallas.gated_short_conv_pallas
+    monkeypatch.setattr(
+        short_conv_pallas, "gated_short_conv_pallas",
+        lambda *a, grad_dtype=None: asked.append(grad_dtype) or passes(
+            *a, grad_dtype=grad_dtype))
+    rng = np.random.default_rng(2)
+    layer = {name: jnp.asarray(rng.normal(size=shape), jnp.float32)
+             for name, shape in (("conv_in", (128, 384)),
+                                 ("conv_taps", (3, 128)),
+                                 ("conv_out", (128, 128)))}
+    x = jnp.asarray(rng.normal(size=(1, 128, 128)), jnp.float32)
+    mask = jnp.ones((1, 128), bool)
+
+    def traced():
+        return jax.make_jaxpr(
+            lambda layer, x: seqrec._short_conv(layer, x, mask))(layer, x)
+
+    if ambient is None:
+        jaxpr = traced()
+    else:
+        with jax.default_matmul_precision(ambient):
+            jaxpr = traced()
+    assert asked == [grad_dtype]
+    products = [e for e in jaxpr.jaxpr.eqns if e.primitive.name
+                == "dot_general"]
+    assert len(products) == 2       # x @ conv_in, y @ conv_out
+    if ambient is None:     # (under a default, the product takes it)
+        assert all(e.params["precision"] is None for e in products)

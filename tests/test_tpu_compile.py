@@ -252,6 +252,46 @@ def test_the_chains_fused_passes_compile_for_v5e(one_chip, name, b, length,
         assert _kernel_calls(text, f"{kernel}[_.0-9]*") == calls, kernel
 
 
+@pytest.mark.parametrize("name,b,length,d,taps", [
+    # lfm2-a2b-ep8.train: one session of 32,768, b, c, u of 2048 columns
+    # (sixteen lane tiles each), three taps: forward blocks of 512 x 512,
+    # backward blocks of 256 rows x all 6,144 columns in and out
+    ("cell", 1, 32768, 2048, 3),
+    # two batch rows, one row block, a width that only 128 divides, a
+    # convolution that reaches 16 rows
+    ("narrow-odd", 2, 128, 384, 12),
+])
+def test_the_short_convolutions_fused_passes_compile_for_v5e(one_chip, name,
+                                                             b, length, d,
+                                                             taps):
+    """`gated_short_conv_pallas` forward and backward at real widths:
+    Mosaic takes three blocks of the one projection's output at their
+    column offsets, the unaligned row offsets into VMEM, and a backward
+    block as wide as the projection whose three column ranges are
+    written a chunk of columns at a time."""
+    from predictionio_tpu.ops import short_conv_pallas
+
+    assert short_conv_pallas.tiles(length, d, taps)
+
+    def loss(bcu, w_taps, w):
+        return (short_conv_pallas.gated_short_conv_pallas(bcu, w_taps)
+                * w).sum()
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(
+        shape(b, length, 3 * d), shape(taps, d),
+        shape(b, length, d)).compile().as_text()
+    # (the forward pass's output is read by nobody here: `w` is the
+    # cotangent; outside a step's scopes a call is `jvp_<kernel>_.<n>`)
+    assert _kernel_calls(text, "short_conv_chain_bwd[_.0-9]*") == 1
+    # the projection's gradient is the kernel's one output: nothing
+    # concatenates or pads three parts into it
+    assert not any(op in line for line in text.splitlines()
+                   for op in (" concatenate(", " pad("))
+
+
 def _kernel_calls(text, name):
     import re
 
@@ -378,17 +418,19 @@ def test_the_conv_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
     """lfm2-a2b-ep8.train's step compiled for one described v5e: 32,768
     positions through a dense convolution layer, the attention layer on
     the kernels (two forward calls under `remat`, one backward) and
-    three convolution layers with their experts, the head tied; arguments
-    + temporaries leave the 16 GB chip 1 GB and more."""
+    three convolution layers with their experts, the head tied, every
+    convolution layer's chain as the fused passes (likewise two forward,
+    one backward); arguments + temporaries leave the 16 GB chip 1 GB and
+    more."""
     import json
     import os
 
     from predictionio_tpu.models import seqrec
-    from predictionio_tpu.ops import attention, moe
+    from predictionio_tpu.ops import attention, linear_attention, moe
 
     kind = chips[0].device_kind
-    monkeypatch.setattr(attention, "_device_kind", lambda: kind)
-    monkeypatch.setattr(moe, "_device_kind", lambda: kind)
+    for module in (attention, linear_attention, moe):
+        monkeypatch.setattr(module, "_device_kind", lambda: kind)
     with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
                            "configs", "seqrec-lfm2-24b-a2b-ep8.json")) as f:
         cfg = json.load(f)
@@ -410,6 +452,19 @@ def test_the_conv_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
     assert _kernel_calls(text, "flash_attention_pallas_fwd") == 2
     assert _kernel_calls(text, "flash_attention_pallas_bwd") == 1
     assert _kernel_calls(text, "grouped_product_pallas_[a-z_]*") == 4 * 12
+    assert _kernel_calls(text, "short_conv_chain_fwd") == 4 * 2
+    assert _kernel_calls(text, "short_conv_chain_bwd") == 4
+    # the passes' instructions carry the layer's scope and their phase:
+    # what `step_scope_ms.short_conv` adds their device time to
+    from predictionio_tpu.obs import profiler
+
+    rows = profiler.parse_scope_table(text, seqrec.STEP_SCOPES)[1]
+    passes = sorted((key.split(".")[0], *row) for key, row in rows.items()
+                    if "short_conv_chain" in key)
+    assert passes == sorted(
+        [("short_conv_chain_fwd", "seqrec_short_conv", "")] * 4
+        + [("short_conv_chain_fwd", "seqrec_short_conv", "tr")] * 4
+        + [("short_conv_chain_bwd", "seqrec_short_conv", "t")] * 4), passes
     assert "ragged-dot" not in text
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes

@@ -666,3 +666,52 @@ def test_a_step_says_which_route_its_delta_rule_was_traced_on(monkeypatch):
         for group, norm in scan["grad_norm"].items():
             assert abs(float(stats["grad_norm"][group]) - float(norm)) \
                 < 2e-3 * float(norm), group
+
+
+def test_a_sequence_model_train_counts_its_short_convolution_tokens_by_route(
+        monkeypatch):
+    """`pio_train_seqrec_short_conv_chain_tokens_total{impl}`: every
+    position of the trained batches times the short-convolution layers,
+    under the route `gated_short_conv` took when the train's step was
+    traced, which the step itself reports: on the CPU `xla`; with the
+    passes' route forced (and interpreted) `pallas`. The counter has no
+    other label, and a model without such a layer counts nothing."""
+    from predictionio_tpu.models import seqrec
+    from predictionio_tpu.ops import (
+        attention_pallas, linear_attention, short_conv_pallas,
+    )
+
+    reg = default_registry()
+
+    def counted(label):
+        c = reg.get("pio_train_seqrec_short_conv_chain_tokens_total")
+        return c.value(impl=label) if c is not None else 0
+
+    def spec(**over):
+        return seqrec.SeqRecParams(**{**dict(
+            d_model=128, n_heads=2, n_layers=3, max_len=128, batch_size=2,
+            epochs=1, mixer=("conv", "mha", "conv"), conv_kernel=3,
+            norm="rms", positions="rope", remat=True), **over})
+
+    sessions = [[f"i{(s + j) % 11}" for j in range(140 + s)]
+                for s in range(4)]
+    before = {label: counted(label) for label in ("xla", "pallas")}
+    seqrec.train_seqrec(None, sessions, seqrec.SeqRecParams(
+        d_model=16, n_heads=2, n_layers=1, max_len=8, batch_size=2))
+    assert {label: counted(label) for label in before} == before
+    # 1 epoch x 2 steps x 2 sessions x 128 positions x 2 conv layers
+    seqrec.train_seqrec(None, sessions, spec())
+    assert counted("xla") - before["xla"] == 2 * 2 * 128 * 2
+    assert counted("pallas") == before["pallas"]
+
+    monkeypatch.setattr(linear_attention, "_device_kind",
+                        lambda: attention_pallas.KINDS[0])
+    passes = short_conv_pallas.gated_short_conv_pallas
+    monkeypatch.setattr(short_conv_pallas, "gated_short_conv_pallas",
+                        lambda bcu, taps, **kw: passes(bcu, taps, True, **kw))
+    # another seed: another step than the cached one
+    seqrec.train_seqrec(None, sessions, spec(seed=8))
+    assert counted("pallas") - before["pallas"] == 2 * 2 * 128 * 2
+    assert counted("xla") - before["xla"] == 2 * 2 * 128 * 2
+    counter = reg.get("pio_train_seqrec_short_conv_chain_tokens_total")
+    assert tuple(counter.labelnames) == ("impl",)

@@ -1,21 +1,25 @@
-"""Session-based sequence recommendation: a causal transformer over each
-user's event stream (SASRec-style next-item prediction).
+"""Session-based sequence recommendation: a causal decoder over each user's
+event stream, trained to predict the next item (the `sessionrec` template).
 
 The reference has no sequence models — its closest notion is the MarkovChain
 top-N transition engine (e2/.../engine/MarkovChain.scala:25-87, first-order
 only). This model family is the long-context upgrade of that component: the
-per-user ordered event sequence IS the long axis, attention replaces the
-transition matrix, and the same DASE Engine surface serves it.
+per-user ordered event sequence IS the long axis, a stack of layers replaces
+the transition matrix, and the same DASE Engine surface serves it.
 
-TPU-native design:
-  * all shapes static (sessions padded/truncated to max_len; id 0 = padding);
-  * one jitted train step: causal flash attention (ops/attention.py) + tied
-    item-embedding softmax, optax adamw, donated optimizer state;
-  * multi-axis sharding via NamedSharding constraints, XLA inserts the
-    collectives: batch over the "data" axis (dp), item-embedding rows and
-    attention heads over the "model" axis (tp). For sessions longer than one
-    chip's HBM, ``attention_impl="ring"`` swaps the local flash kernel for
-    ring attention over a "seq" axis (sp).
+The stack is a spec (`SeqRecParams`): each layer a mixer then a feed-forward,
+or one of the two alone, every kind of either one record of one table
+(`KINDS`: six mixers, three feed-forwards). The default is the SASRec block
+this began with; the benchmark's five sequence configurations (latent
+attention with routed experts, the gated delta rule, gated short
+convolutions, a looped stack with exit gates, state-space layers with latent
+experts and a multi-token-prediction module) are specs of the same table.
+
+TPU-native design: all shapes static (sessions padded/truncated to max_len;
+id 0 = padding); one jitted train step with donated state (adamw), its layers
+on the routes `ops/` choose from the device and the shapes; batch over the
+mesh's "data" axis, table rows and projections over "model" (`shard_params`),
+and ring attention over a "seq" axis where the mesh has one.
 """
 
 from __future__ import annotations
@@ -58,35 +62,14 @@ class SeqRecParams(Params):
     batch_size: int = 128
     epochs: int = 10
     seed: int = 7
-    #: "flash" (local blockwise kernel) or "ring" (sequence parallelism:
-    #: K/V blocks rotate over the mesh's "seq" axis via ppermute — sp for
-    #: sessions longer than one chip's HBM). "ring" requires training on
-    #: a mesh with a "seq" axis; serving always uses the local kernel.
-    attention_impl: str = "flash"
 
     # -- the layer spec -------------------------------------------------
     #: one kind for every layer, or one period of kinds repeated over
-    #: the layers (layer i has mixer[i % len(mixer)]). "mha": fused q/k/v
-    #: of d_model / n_heads a head. "mla": latent attention — per-head
-    #: queries of qk_nope + qk_rope, keys and values expanded from one
-    #: shared latent of kv_lora_rank, one rotary key of qk_rope shared by
-    #: all heads, values of v_head_dim. "gqa": n_heads query heads of
-    #: head_dim over n_kv_heads key/value heads, queries and keys normed
-    #: over the head width, rotary positions on the leading rotary_dim of
-    #: it, the output gated by a sigmoid of a projection of the input
-    #: (`attention_gate`). "conv": a gated short convolution — one
-    #: projection to three streams of d_model, the first times the third
-    #: through a depthwise causal convolution of conv_kernel taps without
-    #: activation, times the second, an output projection.
-    #: "gdn": linear attention by the gated delta rule
-    #: (ops/linear_attention.py) behind a causal convolution of
-    #: linear_conv_kernel taps, linear_key_heads key heads of
-    #: linear_key_head_dim serving linear_value_heads value heads of
-    #: linear_value_head_dim, the output normed a head and gated.
+    #: the layers (layer i has mixer[i % len(mixer)]): a mixer of `KINDS`,
+    #: whose record says what it is and which of the fields below it reads
     mixer: Union[str, Sequence[str]] = "mha"
-    #: "gelu" (two matrices), "swiglu" (three), or "moe": routed SwiGLU
-    #: experts of moe_width plus one shared SwiGLU of n_shared_experts x
-    #: moe_width, after `first_dense_layers` layers of dense swiglu
+    #: a feed-forward of `KINDS`; the routed one after
+    #: `first_dense_layers` layers of dense swiglu
     ffn: str = "gelu"
     #: "layer" (scale and bias), "rms" (scale) or "rms_zero_centered"
     #: (1 + a weight drawn 0)
@@ -134,11 +117,10 @@ class SeqRecParams(Params):
     sublayers: Sequence[str] = ()
     #: the tensor-parallel share held here is one of this many. n_heads,
     #: n_kv_heads, the ssm record's heads and groups and the shared
-    #: expert's width stay the published counts; a "gqa" mixer holds
-    #: n_heads / ways query heads with the key/value heads they read, an
-    #: "ssm" mixer its heads and groups likewise, a shared expert its
-    #: columns (the weights are drawn at the held sizes: which rank's
-    #: they are is the loader's to say, no step reads it). The held
+    #: expert's width stay the published counts; a kind that is told its
+    #: share says what it holds of them (its record's `held`; the
+    #: weights are drawn at the held sizes: which rank's they are is
+    #: the loader's to say, no step reads it). The held
     #: part's output projection gives this chip's partial sum, and that
     #: partial result goes on to the next layer: nothing stands in for
     #: the other ranks or their all-reduce (as `held_experts` for the
@@ -228,8 +210,9 @@ class SeqRecParams(Params):
         alone (`sublayers`)."""
         if self.sublayers:
             return _sub_layer(self.sublayers[layer % len(self.sublayers)])[1]
-        if self.ffn == "moe" and layer < self.first_dense_layers:
-            return "swiglu"
+        record = KINDS[self.ffn]
+        if record.routed and layer < self.first_dense_layers:
+            return record.first_dense
         return self.ffn
 
     def mtp_kinds(self) -> Tuple[Tuple[Optional[str], Optional[str]], ...]:
@@ -243,12 +226,22 @@ class SeqRecParams(Params):
         return tuple((self.mixer_kind(i), self.ffn_kind(i))
                      for i in range(self.n_layers)) + self.mtp_kinds()
 
+    def mixers(self) -> set:
+        """The mixers of the stack's layers and the module's."""
+        return {mixer for mixer, _ in self.layer_kinds() if mixer}
+
     def has_experts(self) -> bool:
-        return any(ffn == "moe" for _, ffn in self.layer_kinds())
+        return any(ffn and KINDS[ffn].routed for _, ffn in self.layer_kinds())
+
+    def held_kind(self, kind: str):
+        """The kind's record (`KINDS`) at the sizes held here."""
+        record = KINDS[kind].of(self)
+        return record.held(self.tensor_ways) if record.share else record
 
     def state_space(self) -> "StateSpaceMixer":
-        """The "ssm" mixer's record at the sizes held here."""
-        return StateSpaceMixer(**self.ssm).held(self.tensor_ways)
+        """The "ssm" mixer's record at the sizes held here (the probes'
+        name for `held_kind`)."""
+        return self.held_kind("ssm")
 
     def held(self, count: int) -> int:
         """How many of `count` heads or columns this tensor share
@@ -273,85 +266,57 @@ class SeqRecParams(Params):
             for k, v in spec.items()))
 
     def check(self) -> None:
+        """What crosses kinds; a kind's own sizes are its record's to
+        refuse (`KINDS`)."""
         if not self.mixer:
             raise ValueError("mixer names no kind")
         for name in ("sublayers", "mtp_layers"):
-            unknown = set(getattr(self, name)) - set(MIXERS) - set(FFNS)
+            unknown = set(getattr(self, name)) - set(KINDS)
             if unknown:
                 raise ValueError(f"unknown {name} {sorted(unknown)}: "
                                  f"expected among {MIXERS + FFNS}")
-        mixers = {mixer for mixer, _ in self.layer_kinds() if mixer}
-        unknown = mixers - set(MIXERS)
-        if unknown:
-            raise ValueError(f"unknown mixer {sorted(unknown)}: expected "
-                             f"among {MIXERS}")
         for name, kinds in (("ffn", FFNS),
                             ("norm", ("layer", "rms", "rms_zero_centered")),
                             ("positions", ("learned", "rope", "none")),
-                            ("attention_impl", ("flash", "ring")),
                             ("router_scoring", ("sigmoid", "softmax")),
                             ("expert_act", moe.EXPERT_KINDS)):
             if getattr(self, name) not in kinds:
                 raise ValueError(f"unknown {name} {getattr(self, name)!r}: "
                                  f"expected one of {kinds}")
-        new = mixers & {"gqa", "gdn", "conv", "ssm"}
-        if new:
-            # what these mixers are not defined with: their norms are RMS
-            # norms, their only positions rotary (gqa), the convolution's
-            # (gdn, conv) or the state's (ssm), and the ring takes one
-            # key/value head a query head
-            for name, refused in (("norm", "layer"),
-                                  ("positions", "learned"),
-                                  ("attention_impl", "ring")):
-                if getattr(self, name) == refused:
-                    raise ValueError(f"{name} {refused!r} does not go with "
-                                     f"the mixers {sorted(new)}")
-        if "gqa" in new:
-            if self.head_dim <= 0 or self.n_kv_heads <= 0 \
-                    or self.n_heads % self.n_kv_heads:
-                raise ValueError(
-                    f"gqa needs head_dim > 0 and n_kv_heads a divisor of "
-                    f"n_heads: {self.head_dim}, {self.n_kv_heads}, "
-                    f"{self.n_heads}")
-            if self.positions == "rope" and (
-                    not 0 < self.rotary_dim <= self.head_dim
-                    or self.rotary_dim % 2):
-                raise ValueError(f"rotary_dim {self.rotary_dim} is no even "
-                                 f"part of head_dim {self.head_dim}")
-        if "conv" in new and self.conv_kernel < 1:
-            raise ValueError(f"conv needs conv_kernel >= 1: "
-                             f"{self.conv_kernel}")
-        if "gdn" in new:
-            sizes = (self.linear_key_heads, self.linear_value_heads,
-                     self.linear_key_head_dim, self.linear_value_head_dim,
-                     self.linear_conv_kernel)
-            if min(sizes) <= 0 \
-                    or self.linear_value_heads % self.linear_key_heads:
-                raise ValueError(
-                    f"gdn needs its five linear_* sizes > 0 and "
-                    f"linear_key_heads a divisor of linear_value_heads: "
-                    f"{sizes}")
-        if self.positions == "none" and mixers - {"gqa", "ssm"}:
-            raise ValueError("positions 'none' goes with the mixers gqa "
-                             f"and ssm, not {sorted(mixers - {'gqa', 'ssm'})}")
-        if "ssm" in new:
-            StateSpaceMixer(**(self.ssm or {})).check()
+        mixers = self.mixers()
+        unknown = mixers - set(MIXERS)
+        if unknown:
+            raise ValueError(f"unknown mixer {sorted(unknown)}: expected "
+                             f"among {MIXERS}")
+        for name, defined in (("norm", "norms"), ("positions", "positions")):
+            # what a mixer is not defined with: the later ones' norms are
+            # RMS norms and their only positions rotary, the
+            # convolution's or the state's
+            value = getattr(self, name)
+            against = sorted(kind for kind in mixers
+                             if value not in getattr(KINDS[kind], defined))
+            if against and value == "none":
+                carry = " and ".join(kind for kind in MIXERS
+                                     if "none" in KINDS[kind].positions)
+                raise ValueError(f"positions 'none' goes with the mixers "
+                                 f"{carry}, not {against}")
+            if against:
+                raise ValueError(f"{name} {value!r} does not go with the "
+                                 f"mixers {against}")
+        used = mixers | {ffn for _, ffn in self.layer_kinds()}
+        records = {kind: record.of(self) for kind, record in KINDS.items()
+                   if kind in used}
+        for record in records.values():
+            record.check()
         ways = self.tensor_ways
         if ways < 1:
             raise ValueError(f"tensor_ways {ways} must be >= 1")
         if ways > 1:
-            # who is told its share: gqa, ssm and the shared expert
-            untold = (mixers - {"gqa", "ssm"}) | ({
-                ffn for _, ffn in self.layer_kinds() if ffn} - {"moe"})
-            uneven = []
-            if "gqa" in mixers and (self.n_heads % ways or (
-                    self.n_kv_heads % ways and ways % self.n_kv_heads)):
-                uneven.append("n_heads or n_kv_heads")
-            if "ssm" in mixers and (self.ssm["heads"] % ways
-                                    or self.ssm["groups"] % ways):
-                uneven.append("the ssm record's heads or groups")
-            if self.n_shared_experts * self.moe_width % ways:
-                uneven.append("the shared expert's width")
+            # who is told its share says so (`share`: what must divide)
+            untold = [kind for kind, record in records.items()
+                      if not record.share]
+            uneven = [record.share for record in records.values()
+                      if record.share and not record.divides(ways)]
             if untold or uneven:
                 raise ValueError(
                     f"a tensor share of {ways} ways: {sorted(untold)} hold "
@@ -382,12 +347,305 @@ class SeqRecParams(Params):
                                  "n_routed_experts")
 
 
+# -- the layer kinds ------------------------------------------------------
+# A kind is one frozen record, and `KINDS` below the one table of them.
+# Its sizes are the spec's flat fields it owns (`of`: the published
+# counts; `held` a tensor share's, where `share` names what a share must
+# divide and is None where the kind holds none). `check` refuses its own
+# bad sizes. `init` draws its weights from the caller's draws
+# (`dense(n_in, n_out, experts=())` N(0, 1/n_in), `uniform(shape, hi)`
+# U(0, hi), `norm(width)` the spec's norm): leaf names, nesting and the
+# order of the draws are a release's and a checkpoint's. `apply` is its
+# layer function on the normed state (a mixer's gives y, a feed-forward's
+# y and its balance numbers or None), `scope` the one of `STEP_SCOPES` it
+# runs under, `grad_groups` where each leaf's gradient norm is recorded,
+# `columns` and `rows` the leaves `shard_params` splits over "model". A
+# mixer names the `norms` and `positions` it is defined with, whether it
+# runs over a mesh's "seq" axis (`ring`) and the `family` its tokens are
+# counted under; a feed-forward whether it is `routed`.
+
+
+@dataclasses.dataclass(frozen=True)
+class MultiHeadAttention:
+    """The "mha" mixer: fused q/k/v of d_model / n_heads a head."""
+
+    heads: int = 0
+    rotary: bool = False
+
+    role, scope, family = "mixer", "seqrec_attention", "attention"
+    grad_groups = dict.fromkeys(("wqkv", "wo"), "attention")
+    columns, rows, share, ring = ("wqkv",), (), None, True
+    norms = ("layer", "rms", "rms_zero_centered")
+    positions = ("learned", "rope")
+
+    @classmethod
+    def of(cls, p: SeqRecParams) -> "MultiHeadAttention":
+        return cls(p.n_heads, p.positions == "rope")
+
+    def check(self) -> None:
+        """(every count goes as far as the head split's reshape)"""
+
+    def init(self, d: int, dense, uniform, norm) -> Dict:
+        return {"wqkv": dense(d, 3 * d), "wo": dense(d, d)}
+
+    def apply(self, w, x, key_mask, p: SeqRecParams, mesh):
+        qkv = x @ w["wqkv"]                                     # MXU
+        if self.rotary and not _rings(mesh):
+            # the projection whole: where the kernels read a head as a
+            # block of its columns nothing between the two products is
+            # relaid. The product above runs at the default precision,
+            # and so do its two backward products: where that is one
+            # bfloat16 pass they round this call's gradient themselves,
+            # and it may be written so (a product at a higher precision
+            # here would have to take float32 back: `_qkv_grad_dtype`).
+            return rotary_attention(
+                qkv, self.heads, p.rope_theta, block_k=ATTENTION_BLOCK,
+                causal=True, key_mask=key_mask,
+                devices=1 if mesh is None else mesh.size,
+                grad_dtype=_qkv_grad_dtype()) @ w["wo"]
+        # the ring's shards, and heads without rotary positions
+        q, k, v = split_heads(
+            qkv, self.heads, jnp.arange(x.shape[1]) if self.rotary else None,
+            p.rope_theta)
+        return _attend(q, k, v, None, w["wo"], key_mask, mesh)
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttention:
+    """The "mla" mixer, latent attention: per-head queries of qk_nope +
+    qk_rope (_head_dim), keys and values expanded from one shared latent
+    of kv_lora_rank, one rotary key of qk_rope shared by all heads,
+    values of v_head_dim."""
+
+    heads: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    v_dim: int = 0
+    kv_rank: int = 0
+
+    role, scope, family = "mixer", "seqrec_attention", "attention"
+    grad_groups = dict.fromkeys(("wq", "wkva", "kv_norm", "wkvb", "wo"),
+                                "attention")
+    columns, rows, share, ring = (), (), None, True
+    norms = ("layer", "rms", "rms_zero_centered")
+    positions = ("learned", "rope")
+
+    @classmethod
+    def of(cls, p: SeqRecParams) -> "LatentAttention":
+        return cls(p.n_heads, p.qk_nope_head_dim, p.qk_rope_head_dim,
+                   p.v_head_dim, p.kv_lora_rank)
+
+    def check(self) -> None:
+        """(every size goes as far as the products' shapes)"""
+
+    def init(self, d: int, dense, uniform, norm) -> Dict:
+        h = self.heads
+        return {"wq": dense(d, h * (self.nope_dim + self.rope_dim)),
+                "wkva": dense(d, self.kv_rank + self.rope_dim),
+                "kv_norm": norm(self.kv_rank),
+                "wkvb": dense(self.kv_rank, h * (self.nope_dim + self.v_dim)),
+                "wo": dense(h * self.v_dim, d)}
+
+    def apply(self, w, x, key_mask, p: SeqRecParams, mesh):
+        b, l, _ = x.shape
+        h, nope, rot = self.heads, self.nope_dim, self.rope_dim
+        positions = jnp.arange(l)
+        q = (x @ w["wq"]).reshape(b, l, h, nope + rot)
+        latent, k_rot = jnp.split(x @ w["wkva"], [self.kv_rank], -1)
+        kv = (_rms_norm(latent, w["kv_norm"]["scale"], p.norm_eps)
+              @ w["wkvb"]).reshape(b, l, h, -1)
+        k_nope, v = jnp.split(kv, [nope], axis=-1)
+        # one rotary key for all heads; the queries' rotary part per head
+        k_rot = rope(k_rot[:, :, None, :], positions, p.rope_theta)
+        q = jnp.concatenate(
+            [q[..., :nope], rope(q[..., nope:], positions, p.rope_theta)], -1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rot, (b, l, h, rot))], -1)
+        return _attend(q, k, v, None, w["wo"], key_mask, mesh)
+
+
+@dataclasses.dataclass(frozen=True)
+class GroupedQueryAttention:
+    """The "gqa" mixer: n_heads query heads of head_dim over n_kv_heads
+    key/value heads, queries and keys normed over the head width
+    (`qk_norm`), rotary positions on the leading rotary_dim of it (None
+    where the spec's positions are not rotary), the output gated by a
+    sigmoid of a projection of the input (`attention_gate`)."""
+
+    heads: int = 0
+    kv_heads: int = 0
+    head_dim: int = 0
+    rotary_dim: Optional[int] = None
+    gate: bool = True
+    qk_norm: bool = True
+
+    role, scope, family = "mixer", "seqrec_attention", "attention"
+    grad_groups = dict.fromkeys(("wq_gate", "wq", "wk", "wv", "q_norm",
+                                 "k_norm", "wo"), "attention")
+    # the ring takes one key/value head a query head
+    columns, rows, ring = ("wq_gate",), (), False
+    share = "n_heads or n_kv_heads"
+    norms = ("rms", "rms_zero_centered")
+    positions = ("rope", "none")
+
+    @classmethod
+    def of(cls, p: SeqRecParams) -> "GroupedQueryAttention":
+        return cls(p.n_heads, p.n_kv_heads, p.head_dim,
+                   p.rotary_dim if p.positions == "rope" else None,
+                   p.attention_gate, p.qk_norm)
+
+    def check(self) -> None:
+        if self.head_dim <= 0 or self.kv_heads <= 0 \
+                or self.heads % self.kv_heads:
+            raise ValueError(
+                f"gqa needs head_dim > 0 and n_kv_heads a divisor of "
+                f"n_heads: {self.head_dim}, {self.kv_heads}, {self.heads}")
+        if self.rotary_dim is not None and (
+                not 0 < self.rotary_dim <= self.head_dim
+                or self.rotary_dim % 2):
+            raise ValueError(f"rotary_dim {self.rotary_dim} is no even "
+                             f"part of head_dim {self.head_dim}")
+
+    def divides(self, ways: int) -> bool:
+        return not (self.heads % ways or (self.kv_heads % ways
+                                          and ways % self.kv_heads))
+
+    def held(self, ways: int) -> "GroupedQueryAttention":
+        """What one of `ways` tensor ranks holds: its query heads with
+        the key/value heads they read; of fewer key/value heads than
+        ranks, the one."""
+        return dataclasses.replace(self, heads=self.heads // ways,
+                                   kv_heads=max(1, self.kv_heads // ways))
+
+    def init(self, d: int, dense, uniform, norm) -> Dict:
+        h, kv = self.heads, self.kv_heads
+        # without the gate the query projection has no gate's half
+        wq = {"wq_gate": dense(d, 2 * h * self.head_dim)} \
+            if self.gate else {"wq": dense(d, h * self.head_dim)}
+        qk_norms = {"q_norm": norm(self.head_dim),
+                    "k_norm": norm(self.head_dim)} if self.qk_norm else {}
+        return {**wq,
+                "wk": dense(d, kv * self.head_dim),
+                "wv": dense(d, kv * self.head_dim),
+                **qk_norms,
+                "wo": dense(h * self.head_dim, d)}
+
+    def apply(self, w, x, key_mask, p: SeqRecParams, mesh):
+        b, l, _ = x.shape
+        positions = jnp.arange(l)
+        gate = None
+        if self.gate:
+            q, gate = jnp.split(x @ w["wq_gate"], 2, axis=-1)
+        else:
+            q = x @ w["wq"]
+
+        def head_rows(t, norm_name):
+            t = t.reshape(b, l, -1, self.head_dim)
+            if self.qk_norm:
+                t = _norm(t, w[norm_name], p)
+            if self.rotary_dim is not None:
+                t = rope(t, positions, p.rope_theta, self.rotary_dim)
+            return t
+
+        q, k = (head_rows(t, name) for t, name in (
+            (q, "q_norm"), (x @ w["wk"], "k_norm")))
+        v = (x @ w["wv"]).reshape(b, l, -1, self.head_dim)
+        return _attend(q, k, v, gate, w["wo"], key_mask, mesh)
+
+
+@dataclasses.dataclass(frozen=True)
+class GatedDeltaNet:
+    """The "gdn" mixer: linear attention by the gated delta rule
+    (`_linear_attention`) behind a causal convolution of
+    linear_conv_kernel taps, linear_key_heads key heads of
+    linear_key_head_dim serving linear_value_heads value heads of
+    linear_value_head_dim, the output normed a head and gated."""
+
+    key_heads: int = 0
+    value_heads: int = 0
+    key_dim: int = 0
+    value_dim: int = 0
+    conv_kernel: int = 0
+
+    role, scope = "mixer", "seqrec_linear_attention"
+    family = "linear_attention"
+    grad_groups = dict.fromkeys(("w_qkvz", "w_ba", "conv", "A_log",
+                                 "dt_bias", "o_norm", "w_out"),
+                                "linear_attention")
+    columns, rows, share, ring = ("w_qkvz",), ("w_out",), None, False
+    norms = ("rms", "rms_zero_centered")
+    positions = ("rope",)
+
+    @classmethod
+    def of(cls, p: SeqRecParams) -> "GatedDeltaNet":
+        return cls(p.linear_key_heads, p.linear_value_heads,
+                   p.linear_key_head_dim, p.linear_value_head_dim,
+                   p.linear_conv_kernel)
+
+    def check(self) -> None:
+        sizes = dataclasses.astuple(self)
+        if min(sizes) <= 0 or self.value_heads % self.key_heads:
+            raise ValueError(
+                f"gdn needs its five linear_* sizes > 0 and "
+                f"linear_key_heads a divisor of linear_value_heads: "
+                f"{sizes}")
+
+    def init(self, d: int, dense, uniform, norm) -> Dict:
+        keys = self.key_heads * self.key_dim
+        values = self.value_heads * self.value_dim
+        return {"w_qkvz": dense(d, 2 * keys + 2 * values),
+                "w_ba": dense(d, 2 * self.value_heads),
+                "conv": dense(self.conv_kernel, 2 * keys + values),
+                # the decay's rate a head: log of U(0, 16)
+                "A_log": jnp.log(uniform((self.value_heads,), 16.0)),
+                "dt_bias": jnp.ones((self.value_heads,), jnp.float32),
+                "o_norm": {"scale": jnp.ones((self.value_dim,),
+                                             jnp.float32)},
+                "w_out": dense(values, d)}
+
+    def apply(self, w, x, key_mask, p: SeqRecParams, mesh):
+        return _linear_attention(w, x, key_mask, p,
+                                 1 if mesh is None else mesh.size)
+
+
+@dataclasses.dataclass(frozen=True)
+class ShortConvolution:
+    """The "conv" mixer: a gated short convolution (`_short_conv`) — one
+    projection to three streams of d_model, the first times the third
+    through a depthwise causal convolution of conv_kernel taps without
+    activation, times the second, an output projection."""
+
+    kernel: int = 0
+
+    role, scope, family = "mixer", "seqrec_short_conv", "short_conv"
+    grad_groups = dict.fromkeys(("conv_in", "conv_taps", "conv_out"),
+                                "short_conv")
+    columns, rows, share, ring = ("conv_in",), ("conv_out",), None, False
+    norms = ("rms", "rms_zero_centered")
+    positions = ("rope",)
+
+    @classmethod
+    def of(cls, p: SeqRecParams) -> "ShortConvolution":
+        return cls(p.conv_kernel)
+
+    def check(self) -> None:
+        if self.kernel < 1:
+            raise ValueError(f"conv needs conv_kernel >= 1: {self.kernel}")
+
+    def init(self, d: int, dense, uniform, norm) -> Dict:
+        return {"conv_in": dense(d, 3 * d),
+                "conv_taps": dense(self.kernel, d),
+                "conv_out": dense(d, d)}
+
+    def apply(self, w, x, key_mask, p: SeqRecParams, mesh):
+        return _short_conv(w, x, key_mask, 1 if mesh is None else mesh.size)
+
+
 @dataclasses.dataclass(frozen=True)
 class StateSpaceMixer:
-    """The "ssm" mixer (a Mamba-2 layer around `ops/state_space.scan`) as
-    one record: its sizes, the part of them a tensor share holds, its
-    weights and its layer function. `SeqRecParams.ssm` holds the
-    published sizes as a dict of these fields.
+    """The "ssm" mixer (a Mamba-2 layer around `ops/state_space.scan`).
+    `SeqRecParams.ssm` holds the published sizes as a dict of these
+    fields; its weights lie under the layer's "ssm".
 
     [z | x | B | C] = u W_in (widths H P, H P, G N, G N), dt = u W_dt
     (H, at the highest precision: a decay compounds over a session);
@@ -404,10 +662,26 @@ class StateSpaceMixer:
     conv_kernel: int = 0
     chunk: int = state_space.CHUNK
 
+    role, scope, family = "mixer", "seqrec_state_space", None
+    grad_groups = {"ssm": "state_space"}
+    # (`w_out` by its name alone, as the linear attention's: the layout
+    # this kind was given with it)
+    columns, rows, ring = (), ("w_out",), False
+    share = "the ssm record's heads or groups"
+    norms = ("rms", "rms_zero_centered")
+    positions = ("rope", "none")
+
+    @classmethod
+    def of(cls, p: SeqRecParams) -> "StateSpaceMixer":
+        return cls(**(p.ssm or {}))
+
     def check(self) -> None:
         if min(dataclasses.astuple(self)) <= 0 or self.heads % self.groups:
             raise ValueError(f"ssm needs its six sizes > 0 and groups a "
                              f"divisor of heads: {self}")
+
+    def divides(self, ways: int) -> bool:
+        return not (self.heads % ways or self.groups % ways)
 
     def held(self, ways: int) -> "StateSpaceMixer":
         """What one of `ways` tensor ranks holds: whole groups with
@@ -419,31 +693,30 @@ class StateSpaceMixer:
         """(the heads' columns H P, a B or C's columns G N)."""
         return self.heads * self.head_dim, self.groups * self.state
 
-    def init(self, d: int, dense, uniform) -> Dict:
-        """Weights from the caller's draws (`dense(n_in, n_out)` N(0,
-        1/n_in), `uniform(shape, hi)` U(0, hi)): the convolution's bias
-        as one more row of its taps, A_log = log U(1, 16), D 1, dt_bias
-        the inverse softplus of a log-uniform step in [0.001, 0.1]
-        floored at 1e-4."""
+    def init(self, d: int, dense, uniform, norm) -> Dict:
+        """The convolution's bias as one more row of its taps, A_log =
+        log U(1, 16), D 1, dt_bias the inverse softplus of a log-uniform
+        step in [0.001, 0.1] floored at 1e-4."""
         hp, gn = self.widths()
         step = jnp.maximum(jnp.exp(jnp.log(0.001) + uniform(
             (self.heads,), 1.0) * (jnp.log(0.1) - jnp.log(0.001))), 1e-4)
-        return {"w_in": dense(d, 2 * hp + 2 * gn),
-                "w_dt": dense(d, self.heads),
-                "conv": dense(self.conv_kernel, hp + 2 * gn),
-                "conv_bias": dense(self.conv_kernel, hp + 2 * gn)[0],
-                "A_log": jnp.log(1.0 + uniform((self.heads,), 15.0)),
-                "dt_bias": step + jnp.log(-jnp.expm1(-step)),
-                "D": jnp.ones((self.heads,), jnp.float32),
-                "norm": {"scale": jnp.ones((hp,), jnp.float32)},
-                "w_out": dense(hp, d)}
+        return {"ssm": {
+            "w_in": dense(d, 2 * hp + 2 * gn),
+            "w_dt": dense(d, self.heads),
+            "conv": dense(self.conv_kernel, hp + 2 * gn),
+            "conv_bias": dense(self.conv_kernel, hp + 2 * gn)[0],
+            "A_log": jnp.log(1.0 + uniform((self.heads,), 15.0)),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "D": jnp.ones((self.heads,), jnp.float32),
+            "norm": {"scale": jnp.ones((hp,), jnp.float32)},
+            "w_out": dense(hp, d)}}
 
-    def apply(self, w: Dict, x: jax.Array, key_mask: jax.Array,
-              eps: float) -> jax.Array:
+    def apply(self, w, x, key_mask, p: SeqRecParams, mesh):
         """Normed x [B, L, D] -> [B, L, D] (this share's partial sum). A
         padding position's input is 0 and its step dt is 0: it neither
         decays the state nor writes into it, and a left-padded session
         is the unpadded one."""
+        w = w["ssm"]
         b, l, _ = x.shape
         hp, gn = self.widths()
         x = jnp.where(key_mask[..., None], x, 0.0)
@@ -460,22 +733,153 @@ class StateSpaceMixer:
             cs.reshape(b, l, self.groups, self.state), w["D"], self.chunk)
         y = (y.reshape(b, l, hp) * jax.nn.silu(z)).reshape(
             b, l, self.groups, -1)
-        y = y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + eps)
+        y = y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + p.norm_eps)
         return (y.reshape(b, l, hp) * w["norm"]["scale"]) @ w["w_out"]
 
 
-MIXERS = ("mha", "mla", "gqa", "gdn", "conv", "ssm")
-FFNS = ("gelu", "swiglu", "moe")
+def _swiglu_weights(dense, d: int, width: int, experts=()) -> Dict:
+    return {"w_gate": dense(d, width, experts),
+            "w_up": dense(d, width, experts),
+            "w_down": dense(width, d, experts)}
+
+
+@dataclasses.dataclass(frozen=True)
+class GeluFeedForward:
+    """The "gelu" feed-forward: two matrices of ffn_width."""
+
+    width: int = 0
+
+    role, scope, routed = "ffn", "seqrec_ffn", False
+    grad_groups = dict.fromkeys(("w1", "w2"), "ffn")
+    columns, rows, share = ("w1",), ("w2",), None
+
+    @classmethod
+    def of(cls, p: SeqRecParams) -> "GeluFeedForward":
+        return cls(p.dense_width())
+
+    def check(self) -> None:
+        """(a width of 0 is 4 d_model: `dense_width`)"""
+
+    def init(self, d: int, dense, uniform, norm) -> Dict:
+        return {"w1": dense(d, self.width), "w2": dense(self.width, d)}
+
+    def apply(self, w, x, key_mask, p: SeqRecParams, mesh):
+        return _by_tokens(lambda t: jax.nn.gelu(t @ w["w1"]) @ w["w2"],
+                          p, x, self.scope), None
+
+
+@dataclasses.dataclass(frozen=True)
+class SwigluFeedForward:
+    """The "swiglu" feed-forward: three matrices of ffn_width."""
+
+    width: int = 0
+
+    role, scope, routed = "ffn", "seqrec_ffn", False
+    grad_groups = dict.fromkeys(("w_gate", "w_up", "w_down"), "ffn")
+    columns, rows, share = (), (), None
+
+    @classmethod
+    def of(cls, p: SeqRecParams) -> "SwigluFeedForward":
+        return cls(p.dense_width())
+
+    def check(self) -> None:
+        """(a width of 0 is 4 d_model: `dense_width`)"""
+
+    def init(self, d: int, dense, uniform, norm) -> Dict:
+        return _swiglu_weights(dense, d, self.width)
+
+    def apply(self, w, x, key_mask, p: SeqRecParams, mesh):
+        return _by_tokens(lambda t: _swiglu(w, t), p, x, self.scope), None
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertLayer:
+    """The "moe" feed-forward (`_moe`): a router over n_routed_experts,
+    of which `held_experts` are held here, each `expert_act`'s matrices
+    of moe_width, in a latent of moe_latent_size where that is not 0,
+    plus one shared expert of n_shared_experts x moe_width (0 = none),
+    gated or not."""
+
+    n_routed: int = 0
+    held_experts: Tuple[int, int] = (0, 0)
+    width: int = 0
+    act: str = "swiglu"
+    latent: int = 0
+    shared_width: int = 0
+    shared_gate: bool = False
+
+    role, scope, routed = "ffn", "seqrec_experts", True
+    #: the kind in its place in the spec's first `first_dense_layers`
+    first_dense = "swiglu"
+    grad_groups = {"router": "router", "router_bias": "router",
+                   "latent": "latent_projection", "experts": "experts",
+                   "shared": "shared_expert", "shared_gate": "shared_expert"}
+    columns, rows, share = (), (), "the shared expert's width"
+
+    @classmethod
+    def of(cls, p: SeqRecParams) -> "ExpertLayer":
+        return cls(p.n_routed_experts, tuple(p.held_experts), p.moe_width,
+                   p.expert_act, p.moe_latent_size,
+                   p.n_shared_experts * p.moe_width, p.shared_expert_gate)
+
+    def check(self) -> None:
+        """(the held range and the top-k: `SeqRecParams.check`, last)"""
+
+    def divides(self, ways: int) -> bool:
+        return not self.shared_width % ways
+
+    def held(self, ways: int) -> "ExpertLayer":
+        """What one of `ways` tensor ranks holds: its columns of the
+        shared expert (the routed experts' share is `held_experts`)."""
+        return dataclasses.replace(self,
+                                   shared_width=self.shared_width // ways)
+
+    def _expert(self, dense, d: int, width: int, experts=()) -> Dict:
+        """An expert's matrices, routed or shared: `act`'s."""
+        if self.act == "swiglu":
+            return _swiglu_weights(dense, d, width, experts)
+        return {"w_up": dense(d, width, experts),
+                "w_down": dense(width, d, experts)}
+
+    def init(self, d: int, dense, uniform, norm) -> Dict:
+        lo, hi = self.held_experts
+        out = {"router": dense(d, self.n_routed),
+               "router_bias": jnp.zeros((self.n_routed,), jnp.float32)}
+        if self.latent:
+            out["latent"] = {"w_dn": dense(d, self.latent),
+                             "w_up": dense(self.latent, d)}
+        out["experts"] = self._expert(dense, self.latent or d, self.width,
+                                      (hi - lo,))
+        if self.shared_width:
+            out["shared"] = self._expert(dense, d, self.shared_width)
+        if self.shared_gate:
+            out["shared_gate"] = dense(d, 1)
+        return out
+
+    def apply(self, w, x, key_mask, p: SeqRecParams, mesh):
+        return _moe(w, x, p, 1 if mesh is None else mesh.size)
+
+
+#: the one table of layer kinds: a kind's name in a spec -> its record
+KINDS = {"mha": MultiHeadAttention, "mla": LatentAttention,
+         "gqa": GroupedQueryAttention, "gdn": GatedDeltaNet,
+         "conv": ShortConvolution, "ssm": StateSpaceMixer,
+         "gelu": GeluFeedForward, "swiglu": SwigluFeedForward,
+         "moe": ExpertLayer}
+MIXERS = tuple(name for name, kind in KINDS.items() if kind.role == "mixer")
+FFNS = tuple(name for name, kind in KINDS.items() if kind.role == "ffn")
 
 
 def _sub_layer(kind: str) -> Tuple[Optional[str], Optional[str]]:
     """A layer of one sub-layer of this kind as (mixer, feed-forward)."""
-    return (kind if kind in MIXERS else None,
-            kind if kind in FFNS else None)
+    role = KINDS[kind].role
+    return (kind if role == "mixer" else None,
+            kind if role == "ffn" else None)
+
 
 #: settings that change where a train's work lies and what it keeps in
 #: memory, not what it computes
-MEMORY_FIELDS = ("attention_impl", "remat")
+MEMORY_FIELDS = ("remat",)
 
 #: query and key block of the attention where it runs as a scan of XLA
 #: operations (`attention_route`: off a v5e, in a step sharded over a
@@ -553,86 +957,8 @@ def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
                                       jnp.float32, 0.0, hi)
         return jnp.asarray(rng.uniform(0.0, hi, size=shape), jnp.float32)
 
-    def dense(n_in, n_out):
-        return normal((n_in, n_out), n_in ** -0.5)
-
-    def swiglu(width, experts=(), d=d):
-        return {"w_gate": normal((*experts, d, width), d ** -0.5),
-                "w_up": normal((*experts, d, width), d ** -0.5),
-                "w_down": normal((*experts, width, d), width ** -0.5)}
-
-    def expert(width, experts=(), d=d):
-        """An expert's matrices, routed or shared: `expert_act`'s."""
-        if p.expert_act == "swiglu":
-            return swiglu(width, experts, d)
-        return {"w_up": normal((*experts, d, width), d ** -0.5),
-                "w_down": normal((*experts, width, d), width ** -0.5)}
-
-    def mixer(kind):
-        h = p.n_heads
-        if kind is None:
-            return {}
-        if kind == "mha":
-            return {"wqkv": dense(d, 3 * d), "wo": dense(d, d)}
-        if kind == "ssm":
-            return {"ssm": p.state_space().init(d, dense, uniform)}
-        if kind == "gqa":
-            # of a tensor share, the heads held here
-            h, kv = p.held(h), p.held(p.n_kv_heads)
-            wq = {"wq_gate": dense(d, 2 * h * p.head_dim)} \
-                if p.attention_gate else {"wq": dense(d, h * p.head_dim)}
-            qk_norms = {"q_norm": norm(p.head_dim),
-                        "k_norm": norm(p.head_dim)} if p.qk_norm else {}
-            return {**wq,
-                    "wk": dense(d, kv * p.head_dim),
-                    "wv": dense(d, kv * p.head_dim),
-                    **qk_norms,
-                    "wo": dense(h * p.head_dim, d)}
-        if kind == "conv":
-            return {"conv_in": dense(d, 3 * d),
-                    "conv_taps": dense(p.conv_kernel, d),
-                    "conv_out": dense(d, d)}
-        if kind == "gdn":
-            keys = p.linear_key_heads * p.linear_key_head_dim
-            values = p.linear_value_heads * p.linear_value_head_dim
-            return {"w_qkvz": dense(d, 2 * keys + 2 * values),
-                    "w_ba": dense(d, 2 * p.linear_value_heads),
-                    "conv": dense(p.linear_conv_kernel, 2 * keys + values),
-                    # the decay's rate a head: log of U(0, 16)
-                    "A_log": jnp.log(uniform((p.linear_value_heads,), 16.0)),
-                    "dt_bias": jnp.ones((p.linear_value_heads,),
-                                        jnp.float32),
-                    "o_norm": {"scale": jnp.ones(
-                        (p.linear_value_head_dim,), jnp.float32)},
-                    "w_out": dense(values, d)}
-        return {"wq": dense(d, h * (p.qk_nope_head_dim + p.qk_rope_head_dim)),
-                "wkva": dense(d, p.kv_lora_rank + p.qk_rope_head_dim),
-                "kv_norm": norm(p.kv_lora_rank),
-                "wkvb": dense(p.kv_lora_rank,
-                              h * (p.qk_nope_head_dim + p.v_head_dim)),
-                "wo": dense(h * p.v_head_dim, d)}
-
-    def ffn(kind):
-        if kind is None:
-            return {}
-        if kind == "gelu":
-            return {"w1": dense(d, p.dense_width()),
-                    "w2": dense(p.dense_width(), d)}
-        if kind == "swiglu":
-            return swiglu(p.dense_width())
-        lo, hi = p.held_experts
-        out = {"router": dense(d, p.n_routed_experts),
-               "router_bias": jnp.zeros((p.n_routed_experts,), jnp.float32)}
-        if p.moe_latent_size:
-            out["latent"] = {"w_dn": dense(d, p.moe_latent_size),
-                             "w_up": dense(p.moe_latent_size, d)}
-        out["experts"] = expert(p.moe_width, (hi - lo,),
-                                p.moe_latent_size or d)
-        if p.n_shared_experts:
-            out["shared"] = expert(p.held(p.n_shared_experts * p.moe_width))
-        if p.shared_expert_gate:
-            out["shared_gate"] = dense(d, 1)
-        return out
+    def dense(n_in, n_out, experts=()):
+        return normal((*experts, n_in, n_out), n_in ** -0.5)
 
     def layer_of(mixer_kind, ffn_kind):
         # a norm is a leaf of its own: a step donates them. A layer of
@@ -641,9 +967,12 @@ def init_params(rng: np.random.Generator, n_items: int, p: SeqRecParams,
                                          ("ln2", ffn_kind)) if kind]
         if p.post_norm:
             names += [name.replace("ln", "post") for name in names]
+        layer = {name: norm() for name in names}
         # draws in this order: the host path's are the original block's
-        return {**{name: norm() for name in names}, **mixer(mixer_kind),
-                **ffn(ffn_kind)}
+        for kind in (mixer_kind, ffn_kind):
+            if kind:
+                layer.update(p.held_kind(kind).init(d, dense, uniform, norm))
+        return layer
 
     layers = [layer_of(*kinds) for kinds in p.layer_kinds()[:p.n_layers]]
     params = {"emb": normal((v, d), d ** -0.5)}
@@ -765,7 +1094,7 @@ def _linear_attention(layer, x, key_mask, p: SeqRecParams, devices: int):
 
 
 def _qkv_grad_dtype():
-    """What `_attention` lets `rotary_attention` round the gradient of
+    """What the "mha" mixer lets `rotary_attention` round the gradient of
     `x @ wqkv` to where the kernels' route writes it token-first, and
     `_short_conv` lets `gated_short_conv` round that of `x @ conv_in` to:
     bfloat16, the type that product's two backward products read it in
@@ -780,60 +1109,17 @@ def _qkv_grad_dtype():
     return jnp.bfloat16 if ambient in (None, "default", "bfloat16") else None
 
 
-def _attention(layer, x, key_mask, p: SeqRecParams, kind, mesh, use_ring):
-    """A softmax-attention mixer on normed x [B, L, D] -> [B, L, D]."""
-    b, l, d = x.shape
-    h = p.n_heads
-    positions = jnp.arange(l)
-    gate = None
-    if kind == "mha":
-        rotary = p.positions == "rope"
-        qkv = x @ layer["wqkv"]                                 # MXU
-        if rotary and not use_ring:
-            # the projection whole: where the kernels read a head as a
-            # block of its columns nothing between the two products is
-            # relaid. The product above runs at the default precision,
-            # and so do its two backward products: where that is one
-            # bfloat16 pass they round this call's gradient themselves,
-            # and it may be written so (a product at a higher precision
-            # here would have to take float32 back: `_qkv_grad_dtype`).
-            return rotary_attention(
-                qkv, h, p.rope_theta, block_k=ATTENTION_BLOCK, causal=True,
-                key_mask=key_mask, devices=1 if mesh is None else mesh.size,
-                grad_dtype=_qkv_grad_dtype()) @ layer["wo"]
-        # the ring's shards, and heads without rotary positions
-        q, k, v = split_heads(qkv, h, positions if rotary else None,
-                              p.rope_theta)
-    elif kind == "gqa":
-        if p.attention_gate:
-            q, gate = jnp.split(x @ layer["wq_gate"], 2, axis=-1)
-        else:
-            q = x @ layer["wq"]
-        def head_rows(t, norm_name):
-            t = t.reshape(b, l, -1, p.head_dim)
-            if p.qk_norm:
-                t = _norm(t, layer[norm_name], p)
-            if p.positions == "rope":
-                t = rope(t, positions, p.rope_theta, p.rotary_dim)
-            return t
+def _rings(mesh: Optional[Mesh]) -> bool:
+    """Whether attention runs as a ring over the mesh's "seq" axis; the
+    blockwise route everywhere else (serving: no mesh)."""
+    return mesh is not None and "seq" in mesh.axis_names
 
-        q, k = (head_rows(t, name) for t, name in (
-            (q, "q_norm"), (x @ layer["wk"], "k_norm")))
-        v = (x @ layer["wv"]).reshape(b, l, -1, p.head_dim)
-    else:
-        nope, rot = p.qk_nope_head_dim, p.qk_rope_head_dim
-        q = (x @ layer["wq"]).reshape(b, l, h, nope + rot)
-        latent, k_rot = jnp.split(x @ layer["wkva"], [p.kv_lora_rank], -1)
-        kv = (_rms_norm(latent, layer["kv_norm"]["scale"], p.norm_eps)
-              @ layer["wkvb"]).reshape(b, l, h, -1)
-        k_nope, v = jnp.split(kv, [nope], axis=-1)
-        # one rotary key for all heads; the queries' rotary part per head
-        k_rot = rope(k_rot[:, :, None, :], positions, p.rope_theta)
-        q = jnp.concatenate(
-            [q[..., :nope], rope(q[..., nope:], positions, p.rope_theta)], -1)
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rot, (b, l, h, rot))], -1)
-    if use_ring:
+
+def _attend(q, k, v, gate, wo, key_mask, mesh):
+    """What the softmax-attention mixers share: q, k, v [B, L, heads, .]
+    -> [B, L, D]. The key mask keeps padding out of the softmax."""
+    b, l = q.shape[:2]
+    if _rings(mesh):
         att = ring_attention_traced(q, k, v, mesh, axis="seq", causal=True,
                                     key_mask=key_mask)
     else:
@@ -843,7 +1129,22 @@ def _attention(layer, x, key_mask, p: SeqRecParams, kind, mesh, use_ring):
     att = att.reshape(b, l, -1)
     if gate is not None:
         att = att * jax.nn.sigmoid(gate)
-    return att @ layer["wo"]
+    return att @ wo
+
+
+def _attention(layer, x, key_mask, p: SeqRecParams, kind, mesh, use_ring):
+    """A softmax-attention mixer on normed x [B, L, D] -> [B, L, D] (the
+    probes' name for its record's `apply`; the mesh says whether it
+    rings)."""
+    return p.held_kind(kind).apply(layer, x, key_mask, p, mesh)
+
+
+def _by_tokens(fn, p: SeqRecParams, x, scope: str):
+    """A dense feed-forward's fn over normed x [B, L, D] as rows."""
+    b, l, _ = x.shape
+    with jax.named_scope(scope):
+        y = _by_token_blocks(fn, p, x.reshape(b * l, -1))
+    return y.reshape(b, l, -1)
 
 
 def _moe(layer, x, p: SeqRecParams, devices: int = 1):
@@ -908,13 +1209,7 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
         if "pos" in params:
             h = h + params["pos"][None, :l]
     pad = (seqs == 0)[..., None]
-    key_mask = seqs != 0       # left-padding sits in the causal PAST; the
-    use_ring = (p.attention_impl == "ring" and mesh is not None
-                and "seq" in mesh.axis_names)
-    if p.attention_impl == "ring" and not use_ring:
-        raise ValueError('attention_impl="ring" requires a mesh with a '
-                         '"seq" axis')
-    devices = 1 if mesh is None else mesh.size
+    key_mask = seqs != 0       # left-padding sits in the causal PAST
 
     def joined(h, y, layer, name):
         """The residual h + y, y through its own norm first under
@@ -927,40 +1222,20 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
     def block(h, layer, mixer, kind):
         """A layer: its mixer, then its feed-forward; either may be None
         (a layer of one sub-layer)."""
+        stats = None
         if mixer is not None:
-            h = mixed(h, layer, mixer)
-        if kind is None:
-            return h, None
-        with jax.named_scope("seqrec_norm"):
-            x = _norm(h, layer["ln2"], p)
-        if kind == "moe":
-            y, stats = _moe(layer, x, p, devices)
-            return joined(h, y, layer, "post2"), stats
-        fn = (lambda t: jax.nn.gelu(t @ layer["w1"]) @ layer["w2"]) \
-            if kind == "gelu" else (lambda t: _swiglu(layer, t))
-        with jax.named_scope("seqrec_ffn"):
-            y = _by_token_blocks(fn, p, x.reshape(b * l, -1))
-        return joined(h, y.reshape(b, l, -1), layer, "post2"), None
-
-    def mixed(h, layer, mixer):
-        with jax.named_scope("seqrec_norm"):
-            x = _norm(h, layer["ln1"], p)
-        if mixer == "ssm":
-            with jax.named_scope("seqrec_state_space"):
-                return joined(h, p.state_space().apply(
-                    layer["ssm"], x, key_mask, p.norm_eps), layer, "post1")
-        if mixer == "conv":
-            with jax.named_scope("seqrec_short_conv"):
-                return joined(h, _short_conv(layer, x, key_mask, devices),
-                              layer, "post1")
-        if mixer == "gdn":
-            with jax.named_scope("seqrec_linear_attention"):
-                return joined(h, _linear_attention(layer, x, key_mask, p,
-                                                   devices), layer, "post1")
-        with jax.named_scope("seqrec_attention"):
-            # the key mask keeps padding out of the softmax
-            return joined(h, _attention(layer, x, key_mask, p, mixer, mesh,
-                                        use_ring), layer, "post1")
+            record = p.held_kind(mixer)
+            with jax.named_scope("seqrec_norm"):
+                x = _norm(h, layer["ln1"], p)
+            with jax.named_scope(record.scope):
+                h = joined(h, record.apply(layer, x, key_mask, p, mesh),
+                           layer, "post1")
+        if kind is not None:
+            with jax.named_scope("seqrec_norm"):
+                x = _norm(h, layer["ln2"], p)
+            y, stats = p.held_kind(kind).apply(layer, x, key_mask, p, mesh)
+            h = joined(h, y, layer, "post2")
+        return h, stats
 
     if p.remat:
         block = jax.checkpoint(block, static_argnums=(2, 3))
@@ -1025,8 +1300,8 @@ def forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
             mesh: Optional[Mesh] = None) -> jax.Array:
     """[B, L] int32 item ids (0 = pad) -> [B, L, D] hidden states.
 
-    attention_impl="ring" + a mesh with a "seq" axis runs the attention
-    sequence-parallel (ring_attention_traced): each device holds L/p of
+    A mesh with a "seq" axis runs the attention sequence-parallel
+    (ring_attention_traced): each device holds L/p of
     the sequence and K/V blocks rotate via ppermute — exact, O(L/p) HBM
     per device. Under `n_loops` the last pass's states: a position
     leaves at the first pass whose cumulative exit probability reaches
@@ -1114,6 +1389,14 @@ def _loss_fn(params, seqs, targets, p: SeqRecParams, mesh=None):
                                   for kind, n in mixers.items()}, exits)
 
 
+#: a layer's leaf -> the part its gradient norm is recorded under: the
+#: norms, and what each kind's record says of its own
+_GRAD_GROUPS = {
+    **dict.fromkeys(("ln1", "ln2", "post1", "post2"), "norms"),
+    **{leaf: group for kind in KINDS.values()
+       for leaf, group in kind.grad_groups.items()}}
+
+
 def grad_group(path) -> str:
     """The group a parameter's gradient norm is recorded under: tables
     and head by name, a layer's parameters by layer and part."""
@@ -1126,19 +1409,7 @@ def grad_group(path) -> str:
     if names[0] != "layers":
         return {"emb": "embedding", "pos": "positions",
                 "ln_f": "final_norm"}.get(names[0], names[0])
-    part = {"router": "router", "router_bias": "router",
-            "experts": "experts", "shared": "shared_expert",
-            "shared_gate": "shared_expert", "ssm": "state_space",
-            "latent": "latent_projection",
-            **dict.fromkeys(("ln1", "ln2", "post1", "post2"), "norms"),
-            **dict.fromkeys(("wqkv", "wq", "wkva", "kv_norm", "wkvb", "wo",
-                             "wq_gate", "wk", "wv", "q_norm", "k_norm"),
-                            "attention"),
-            **dict.fromkeys(("w_qkvz", "w_ba", "conv", "A_log", "dt_bias",
-                             "o_norm", "w_out"), "linear_attention"),
-            **dict.fromkeys(("conv_in", "conv_taps", "conv_out"),
-                            "short_conv")}.get(
-        names[2], "ffn")
+    part = _GRAD_GROUPS[names[2]]
     return f"{prefix}{names[1]}.{part}"
 
 
@@ -1204,8 +1475,7 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
         if mesh is not None and "data" in mesh.axis_names:
             # with ring attention the sequence dim lives on "seq"; laying
             # the tokens out that way up front saves XLA a full reshard
-            seq_dim = "seq" if ("seq" in mesh.axis_names
-                                and p.attention_impl == "ring") else None
+            seq_dim = "seq" if _rings(mesh) else None
             sh = NamedSharding(mesh, P("data", seq_dim))
             seqs = jax.lax.with_sharding_constraint(seqs, sh)
             targets = jax.lax.with_sharding_constraint(targets, sh)
@@ -1249,7 +1519,7 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
             # the tokens of all its passes
             moe_layers = [layer for layer, (_, ffn) in zip(
                 updates["layers"] + updates.get("mtp", {}).get("layers", []),
-                p.layer_kinds()) if ffn == "moe"]
+                p.layer_kinds()) if ffn and KINDS[ffn].routed]
             with jax.named_scope("seqrec_optimizer"):
                 for n, layer in enumerate(moe_layers):
                     # (no 0 + load where there is one pass: the step's
@@ -1291,12 +1561,11 @@ def shard_params(params: Dict, mesh: Mesh) -> Dict:
 
     def spec_of(path, leaf):
         name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        if name == "emb":
+        if name == "emb" or any(name in kind.rows for kind in KINDS.values()):
             return P("model", None)
-        if name in ("wqkv", "w1", "head", "wq_gate", "w_qkvz", "conv_in"):
+        if name == "head" or any(name in kind.columns
+                                 for kind in KINDS.values()):
             return P(None, "model")
-        if name in ("w2", "w_out", "conv_out"):
-            return P("model", None)
         return P()
 
     return jax.tree_util.tree_map_with_path(
@@ -1355,10 +1624,9 @@ class SeqRecModel:
         cached = getattr(self, "_resident", None)
         if cached is None or cached[0] is not self.params:
             dev = jax.tree.map(_on_one_device, self.params)
-            # serving always uses the local attention kernel
-            hyper = dataclasses.replace(self.hyper, attention_impl="flash")
+            # (no mesh: serving always uses the local attention kernel)
             cached = (self.params, dev,
-                      jax.jit(lambda w, seqs: forward(w, seqs, hyper)))
+                      jax.jit(lambda w, seqs: forward(w, seqs, self.hyper)))
             self._resident = cached
         return cached[1:]
 
@@ -1436,6 +1704,10 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
     the module's own cross-entropy (`loss` holds it `mtp_loss_weight`
     times)."""
     p.check()
+    unringed = sorted(kind for kind in p.mixers() if not KINDS[kind].ring)
+    if _rings(mesh) and unringed:
+        raise ValueError(f'a mesh with a "seq" axis (ring attention) does '
+                         f'not go with the mixers {unringed}')
     with span("seqrec_prepare"):
         all_items = np.asarray(sorted({it for s in sessions for it in s}),
                                dtype=object)
@@ -1555,8 +1827,6 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
         # jitted identity with replicated out_shardings gathers them over
         # the interconnect so every host can extract the full model —
         # ledger-cached per mesh so retrains don't re-trace the gather
-        from predictionio_tpu.ops.fn_cache import mesh_cached_fn
-
         replicate = mesh_cached_fn(
             "seqrec_replicate", mesh, (),
             lambda: jax.jit(lambda t: t,
@@ -1579,13 +1849,18 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
     train_stats.seqrec_fetch_bytes().inc(sum(leaf.nbytes for leaf in leaves))
     # one compiled step made every step of the train: one route a kind
     # of layer, one pattern of mixers
+    mixer_layers = {kind: int(n) for kind, n in
+                    steps[0]["mixer_layers"].items()} if steps else {}
+    family_layers: Dict[str, int] = {}
+    for kind, n in mixer_layers.items():
+        family = KINDS[kind].family
+        family_layers[family] = family_layers.get(family, 0) + n
     train_stats.observe_seqrec_record(
         record, targets, rows,
         *("pallas" if steps and steps[0][key] else "xla"
           for key in ("attention_pallas", "linear_attention_pallas",
                       "expert_product_pallas")),
-        {kind: int(n) for kind, n in steps[0]["mixer_layers"].items()}
-        if steps else {},
+        mixer_layers, family_layers,
         {name: int(n) for name, n in steps[0]["layer_passes"].items()}
         if steps and "layer_passes" in steps[0] else None,
         "rows" if steps and steps[0].get("attention_rows") else "heads",

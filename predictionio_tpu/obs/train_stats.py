@@ -333,7 +333,7 @@ def seqrec_fetch_bytes(registry: MetricsRegistry = None):
 def observe_seqrec_record(record: dict, targets, rows,
                           attention_impl: str, linear_attention_impl: str,
                           expert_product_impl: str,
-                          mixer_layers: dict,
+                          mixer_layers: dict, family_layers: dict,
                           layer_passes: dict = None,
                           attention_layout: str = "heads",
                           short_conv_impl: str = "xla") -> None:
@@ -343,6 +343,8 @@ def observe_seqrec_record(record: dict, targets, rows,
     and `linear_attention_impl` the routes its step's softmax and linear
     attention (the rule and the chain around it) were traced on,
     `mixer_layers` the layer passes that step ran by mixer,
+    `family_layers` the same by the family each mixer's record counts its
+    tokens under ("attention", "linear_attention", "short_conv", None),
     `layer_passes` those of its first pass and of its repeats (None from
     a step of one pass: all are first), `attention_layout` where its
     attention kernels read a head, `short_conv_impl` the route its
@@ -366,19 +368,19 @@ def observe_seqrec_record(record: dict, targets, rows,
                 gauge().set(value, loop=str(loop))
     if record.get("mtp_loss"):
         seqrec_mtp_loss().set(record["mtp_loss"][-1])
-    if set(mixer_layers) & {"mha", "mla", "gqa"}:
+    if "attention" in family_layers:
         seqrec_attention_tokens().inc(positions, impl=attention_impl)
         if attention_impl == "pallas":
             seqrec_attention_layout_tokens().inc(positions,
                                                  layout=attention_layout)
-    if "gdn" in mixer_layers:
+    if "linear_attention" in family_layers:
         for counter in (seqrec_linear_attention_tokens,
                         seqrec_linear_attention_chain_tokens):
-            counter().inc(positions * mixer_layers["gdn"],
+            counter().inc(positions * family_layers["linear_attention"],
                           impl=linear_attention_impl)
-    if "conv" in mixer_layers:
+    if "short_conv" in family_layers:
         seqrec_short_conv_chain_tokens().inc(
-            positions * mixer_layers["conv"], impl=short_conv_impl)
+            positions * family_layers["short_conv"], impl=short_conv_impl)
     if "load" not in record or not record["load"]:
         return
     load = np.asarray(record["load"], np.float64)      # [step, layer, expert]

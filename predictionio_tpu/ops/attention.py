@@ -4,7 +4,7 @@ The reference has no attention models (SURVEY.md §5: "long-context /
 sequence parallelism — absent"), but this framework treats long-context and
 distributed execution as first-class: engines that embed sequence models
 (session-based recommendation, event-stream encoders) need attention that
-scales past a single chip's HBM. Three strategies, one contract:
+scales past a single chip's HBM. Two strategies, one contract:
 
 * ``mha`` — dense reference implementation (single device, or fully
   replicated); the numerical ground truth the parallel paths are tested
@@ -15,10 +15,6 @@ scales past a single chip's HBM. Three strategies, one contract:
   the flash-attention running-max/denominator recurrence. HBM per device is
   O(L/p); comms ride ICI neighbor-to-neighbor, overlapping with the block
   matmuls (the Ring Attention construction, cf. PAPERS.md).
-* ``ulysses_attention`` — all-to-all sequence↔head resharding: each device
-  gathers the FULL sequence for H/p heads (two ``all_to_all``s), runs dense
-  attention locally, and reshards back. Cheaper comms volume than ring for
-  moderate L; requires heads % devices == 0.
 
 All paths use the same [batch, seq, heads, head_dim] layout, jit/shard_map
 compile to static shapes, and keep the softmax in float32 regardless of
@@ -544,47 +540,3 @@ def _sharded_fn(local_fn, mesh: Mesh, axis: str, causal: bool,
 
     return mesh_cached_fn(f"attention_{local_fn.__name__.strip('_')}",
                           mesh, (axis, causal, batch_axis), build)
-
-
-def _ulysses_local(q, k, v, key_mask, *, axis: str, causal: bool,
-                   batch_axis=None):  # batch_axis: spec-only, unused here
-    """shard_map body: reshard seq-sharded -> head-sharded, dense attention
-    on the full sequence for the local head group, reshard back. The key
-    mask is all-gathered to full length (tiny: [B, L] bool)."""
-    # [B, L/p, H, D] --all_to_all--> [B, L, H/p, D]
-    def seq_to_heads(x):
-        return jax.lax.all_to_all(x, axis, split_axis=2, concat_axis=1,
-                                  tiled=True)
-
-    def heads_to_seq(x):
-        return jax.lax.all_to_all(x, axis, split_axis=1, concat_axis=2,
-                                  tiled=True)
-
-    full_mask = jax.lax.all_gather(key_mask, axis, axis=1, tiled=True)
-    out = mha(seq_to_heads(q), seq_to_heads(k), seq_to_heads(v),
-              causal=causal, key_mask=full_mask)
-    return heads_to_seq(out)
-
-
-def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
-                      axis: str = "seq", causal: bool = False,
-                      key_mask: Optional[jax.Array] = None) -> jax.Array:
-    """All-to-all sequence parallelism (DeepSpeed-Ulysses construction):
-    two ``all_to_all``s swap the sharded dimension seq↔heads so each device
-    runs dense attention over the FULL sequence for H/p heads. Requires
-    heads divisible by the axis size. Same sharded [B, L, H, D] contract as
-    ``ring_attention``."""
-    p_size = mesh.shape[axis]
-    if q.shape[2] % p_size:
-        raise ValueError(
-            f"heads {q.shape[2]} not divisible by mesh axis size {p_size}")
-    if q.shape[1] % p_size:
-        raise ValueError(
-            f"seq len {q.shape[1]} not divisible by mesh axis size {p_size}")
-    fn = _sharded_fn(_ulysses_local, mesh, axis, causal)
-    sharding = NamedSharding(mesh, P(None, axis, None, None))
-    if key_mask is None:
-        key_mask = jnp.ones(q.shape[:2], bool)
-    km = jax.device_put(key_mask, NamedSharding(mesh, P(None, axis)))
-    return fn(jax.device_put(q, sharding), jax.device_put(k, sharding),
-              jax.device_put(v, sharding), km)

@@ -1,4 +1,4 @@
-"""Long-context attention: ring / Ulysses sequence parallelism vs the dense
+"""Long-context attention: ring sequence parallelism vs the dense
 reference, on the 8-device virtual CPU mesh (conftest.py)."""
 
 import jax
@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from predictionio_tpu.ops.attention import (
-    blockwise_attention, mha, ring_attention, ulysses_attention,
+    blockwise_attention, mha, ring_attention,
 )
 
 
@@ -43,15 +43,6 @@ def test_ring_attention_matches_dense(mesh8, causal):
                                atol=1e-5)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_ulysses_attention_matches_dense(mesh8, causal):
-    q, k, v = qkv(seed=3)
-    dense = mha(q, k, v, causal=causal)
-    uly = ulysses_attention(q, k, v, mesh8, axis="data", causal=causal)
-    np.testing.assert_allclose(np.asarray(uly), np.asarray(dense),
-                               atol=1e-5)
-
-
 def test_ring_attention_bf16_inputs(mesh8):
     q, k, v = qkv(seed=4, dtype=jnp.bfloat16)
     dense = mha(q.astype(jnp.float32), k.astype(jnp.float32),
@@ -67,13 +58,6 @@ def test_ring_rejects_indivisible_seq(mesh8):
     x = jnp.asarray(rng.normal(size=(1, 12, 4, 8)).astype(np.float32))
     with pytest.raises(ValueError, match="not divisible"):
         ring_attention(x, x, x, mesh8, axis="data")
-
-
-def test_ulysses_rejects_indivisible_heads(mesh8):
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.normal(size=(1, 64, 4, 8)).astype(np.float32))
-    with pytest.raises(ValueError, match="heads"):
-        ulysses_attention(x, x, x, mesh8, axis="data")
 
 
 def test_key_mask_blocks_padding_keys():
@@ -97,7 +81,7 @@ def test_key_mask_blocks_padding_keys():
                                atol=1e-5)
 
 
-def test_ring_and_ulysses_key_mask(mesh8):
+def test_ring_key_mask(mesh8):
     rng = np.random.default_rng(10)
     b, l, h, d = 2, 64, 8, 16
     q, k, v = (jnp.asarray(rng.normal(size=(b, l, h, d)).astype(np.float32))
@@ -106,10 +90,7 @@ def test_ring_and_ulysses_key_mask(mesh8):
     dense = mha(q, k, v, causal=True, key_mask=key_mask)
     ring = ring_attention(q, k, v, mesh8, axis="data", causal=True,
                           key_mask=key_mask)
-    uly = ulysses_attention(q, k, v, mesh8, axis="data", causal=True,
-                            key_mask=key_mask)
     np.testing.assert_allclose(np.asarray(ring), np.asarray(dense), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(uly), np.asarray(dense), atol=1e-5)
 
 
 def test_blockwise_non_divisible_block_k():

@@ -535,7 +535,6 @@ def test_the_model_trains_and_serves_from_an_engine_json(tmp_path):
 
 @pytest.mark.parametrize("over,match", [
     ({"mixer": ("conv", "flash")}, "unknown mixer"),
-    ({"mixer": "conv", "attention_impl": "ring"}, "ring"),
     ({"mixer": "conv", "positions": "learned"}, "learned"),
     ({"mixer": "conv", "norm": "layer"}, "layer"),
     ({"conv_kernel": 0}, "conv_kernel"),
@@ -547,6 +546,17 @@ def test_the_model_trains_and_serves_from_an_engine_json(tmp_path):
 def test_check_refuses_the_combinations_that_do_not_exist(over, match):
     with pytest.raises(ValueError, match=match):
         small_spec(**over).check()
+
+
+def test_a_seq_mesh_is_refused_where_a_mixer_does_not_ring(mesh8):
+    """The ring takes one key/value head a query head: a train over a
+    mesh with a "seq" axis is refused before anything is traced."""
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(2, 4),
+                axis_names=("data", "seq"))
+    with pytest.raises(ValueError, match="ring"):
+        seqrec.train_seqrec(mesh, [["a", "b", "c"]] * 4, small_spec())
 
 
 def test_the_step_under_a_mesh_is_the_step(mesh8):

@@ -503,7 +503,6 @@ def test_the_hybrid_trains_and_serves_from_an_engine_json(tmp_path):
 @pytest.mark.parametrize("over,match", [
     ({"mixer": ("gdn", "flash")}, "unknown mixer"),
     ({"mixer": ()}, "names no kind"),
-    ({"attention_impl": "ring"}, "ring"),
     ({"positions": "learned"}, "learned"),
     ({"norm": "layer"}, "layer"),
     ({"n_kv_heads": 3}, "divisor"),
@@ -518,6 +517,17 @@ def test_the_hybrid_trains_and_serves_from_an_engine_json(tmp_path):
 def test_check_refuses_the_combinations_that_do_not_exist(over, match):
     with pytest.raises(ValueError, match=match):
         small_spec(**over).check()
+
+
+def test_a_seq_mesh_is_refused_where_a_mixer_does_not_ring(mesh8):
+    """The ring takes one key/value head a query head: a train over a
+    mesh with a "seq" axis is refused before anything is traced."""
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(2, 4),
+                axis_names=("data", "seq"))
+    with pytest.raises(ValueError, match="ring"):
+        seqrec.train_seqrec(mesh, [["a", "b", "c"]] * 4, small_spec())
 
 
 def test_a_mixer_is_a_name_or_one_period():

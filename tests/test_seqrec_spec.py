@@ -359,7 +359,7 @@ def test_memory_settings_are_no_part_of_a_runs_identity():
     not. The program's cache key tells them all apart."""
     vocab = np.asarray(["a", "b"], dtype=object)
     p = small_spec()
-    same = dataclasses.replace(p, remat=False, attention_impl="ring")
+    same = dataclasses.replace(p, remat=False)
     assert seqrec.seqrec_fingerprint(vocab, p) == \
         seqrec.seqrec_fingerprint(vocab, same)
     assert p.spec_key() != same.spec_key()

@@ -152,8 +152,7 @@ def layer_fns(kind, p, key_mask):
     -> [B, L, D]."""
     spec = ref_spec(p)
     if kind == "ssm":
-        return (lambda w, x: p.state_space().apply(w["ssm"], x, key_mask,
-                                                   p.norm_eps),
+        return (lambda w, x: p.state_space().apply(w, x, key_mask, p, None),
                 lambda w, x: jnp.stack([ref.state_space(
                     w["ssm"], row, ok, spec) for row, ok in zip(x, key_mask)]))
     if kind == "gqa":

@@ -231,8 +231,9 @@ def test_sessionrec_resume_rejects_mismatched_opt_state(tmp_path, caplog):
 
 
 def test_sessionrec_ring_attention_matches_flash(mesh8):
-    """attention_impl="ring" (sequence parallelism over a "seq" axis) is
-    exact: same data + seed must reproduce the flash-trained model."""
+    """A mesh with a "seq" axis trains with ring attention (sequence
+    parallelism), and that is exact: same data + seed must reproduce the
+    flash-trained model."""
     import jax
     from jax.sharding import Mesh
 
@@ -245,8 +246,7 @@ def test_sessionrec_ring_attention_matches_flash(mesh8):
 
     mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(2, 4),
                 axis_names=("data", "seq"))
-    ring = train_seqrec(mesh, sessions,
-                        SeqRecParams(**base, attention_impl="ring"))
+    ring = train_seqrec(mesh, sessions, SeqRecParams(**base))
     np.testing.assert_allclose(
         np.asarray(ring.params["emb"]), np.asarray(flash.params["emb"]),
         atol=2e-4)
@@ -254,12 +254,3 @@ def test_sessionrec_ring_attention_matches_flash(mesh8):
     assert recs
 
 
-def test_sessionrec_ring_requires_seq_axis():
-    from predictionio_tpu.models.seqrec import SeqRecParams, train_seqrec
-
-    sessions = [["a", "b", "c"] for _ in range(4)]
-    with pytest.raises(ValueError, match="seq"):
-        train_seqrec(None, sessions,
-                     SeqRecParams(d_model=8, n_heads=2, n_layers=1,
-                                  max_len=8, epochs=1, batch_size=4,
-                                  attention_impl="ring"))

@@ -9,11 +9,13 @@ the transition matrix, and the same DASE Engine surface serves it.
 
 The stack is a spec (`SeqRecParams`): each layer a mixer then a feed-forward,
 or one of the two alone, every kind of either one record of one table
-(`KINDS`: six mixers, three feed-forwards). The default is the SASRec block
-this began with; the benchmark's five sequence configurations (latent
+(`KINDS`: seven mixers, three feed-forwards). The default is the SASRec block
+this began with; the benchmark's six sequence configurations (latent
 attention with routed experts, the gated delta rule, gated short
 convolutions, a looped stack with exit gates, state-space layers with latent
-experts and a multi-token-prediction module) are specs of the same table.
+experts and a multi-token-prediction module, sliding-window layers beside
+full ones with head counts and rotary tables of their own) are specs of the
+same table.
 
 TPU-native design: all shapes static (sessions padded/truncated to max_len;
 id 0 = padding); one jitted train step with donated state (adamw), its layers
@@ -40,8 +42,8 @@ from predictionio_tpu.obs import jax_stats, train_stats
 from predictionio_tpu.obs.tracing import span
 from predictionio_tpu.ops import linear_attention, moe, state_space
 from predictionio_tpu.ops.attention import (
-    blockwise_attention, ring_attention_traced, rope, rotary_attention,
-    routes_into, split_heads,
+    YarnScaling, band_pairs, blockwise_attention, ring_attention_traced,
+    rope, rotary_attention, routes_into, split_heads,
 )
 
 
@@ -81,6 +83,11 @@ class SeqRecParams(Params):
     #: the recurrent mixers beside it)
     positions: str = "learned"
     rope_theta: float = 10000.0
+    #: how the "gqa" mixer's rotary table is stretched past the length it
+    #: was trained on, as ONE record (`ops/attention.YarnScaling`'s
+    #: fields: factor, original_max_len, beta_fast, beta_slow,
+    #: attention_factor); None where it is not
+    rope_scaling: Optional[Dict[str, float]] = None
     #: softmax over the item embeddings, or over a head matrix of its own
     tied_head: bool = True
     #: width of a dense feed-forward; 0 = 4 d_model
@@ -93,9 +100,11 @@ class SeqRecParams(Params):
     n_kv_heads: int = 0
     head_dim: int = 0
     rotary_dim: int = 0
-    #: whether a "gqa" mixer's output is gated; without, its query
-    #: projection has no gate's half (`wq` in place of `wq_gate`)
-    attention_gate: bool = True
+    #: whether a "gqa" or "swa" mixer's output is gated: True, by a
+    #: sigmoid of a second query projection, element by element
+    #: (`wq_gate`); "head", by one column a query head (`wq` and
+    #: `w_head_gate`); False, not (`wq` alone)
+    attention_gate: Union[bool, str] = True
     #: whether a "gqa" mixer norms its queries and keys over the head
     qk_norm: bool = True
     conv_kernel: int = 0
@@ -108,6 +117,11 @@ class SeqRecParams(Params):
     #: (`StateSpaceMixer`'s fields: heads, head_dim, groups, state,
     #: conv_kernel, chunk); None where no layer is one
     ssm: Optional[Dict[str, int]] = None
+    #: the "swa" mixer's own sizes as ONE record (`WindowAttention`:
+    #: heads, window, rope_theta, rotary_dim; its key/value heads, head
+    #: width, gate and q/k norm are the "gqa" mixer's fields above);
+    #: None where no layer is one
+    swa: Optional[Dict[str, float]] = None
     #: a layer of ONE sub-layer each (one norm, one residual) in place of
     #: a mixer and then a feed-forward: the kinds in order, mixers and
     #: feed-forwards alike ("gqa", "moe", "ssm", ...), one period of
@@ -156,6 +170,10 @@ class SeqRecParams(Params):
     bias_update_rate: float = 0.0
     #: coefficient of the sequence-wise balance loss, summed over layers
     balance_loss_alpha: float = 0.0
+    #: the step records the routed experts' part of the update expert by
+    #: expert (`expert_update_norm`), for a reader that weighs it by the
+    #: expert's tokens; of "relu2" experts it always does
+    expert_update_by_expert: bool = False
     #: the layer stack runs this many times a step over the same weights,
     #: the last norm applied after every pass and its output fed on
     n_loops: int = 1
@@ -258,7 +276,9 @@ class SeqRecParams(Params):
         without `memory`, those that shape its trajectory: a run resumed
         under another memory setting is the same run."""
         spec = dataclasses.asdict(self)
-        for name in ("epochs",) + (() if memory else MEMORY_FIELDS):
+        unset = tuple(f.name for f in dataclasses.fields(self)
+                      if f.name in LATER_FIELDS and spec[f.name] == f.default)
+        for name in ("epochs",) + unset + (() if memory else MEMORY_FIELDS):
             del spec[name]
         return tuple(sorted(
             (k, tuple(v) if isinstance(v, (list, tuple))
@@ -468,20 +488,26 @@ class LatentAttention:
 class GroupedQueryAttention:
     """The "gqa" mixer: n_heads query heads of head_dim over n_kv_heads
     key/value heads, queries and keys normed over the head width
-    (`qk_norm`), rotary positions on the leading rotary_dim of it (None
-    where the spec's positions are not rotary), the output gated by a
-    sigmoid of a projection of the input (`attention_gate`)."""
+    (`qk_norm`), rotary positions at base `theta` on the leading
+    rotary_dim of it (None where the spec's positions are not rotary),
+    the table stretched by `scaling` where there is one, the output
+    gated by a sigmoid of a projection of the input (`attention_gate`:
+    element by element, or one column a head), causal over the whole
+    session (the "swa" kind below is this one under a `window`)."""
 
     heads: int = 0
     kv_heads: int = 0
     head_dim: int = 0
     rotary_dim: Optional[int] = None
-    gate: bool = True
+    gate: Union[bool, str] = True
     qk_norm: bool = True
+    theta: float = 10000.0
+    scaling: Optional[YarnScaling] = None
+    window: Optional[int] = None
 
     role, scope, family = "mixer", "seqrec_attention", "attention"
-    grad_groups = dict.fromkeys(("wq_gate", "wq", "wk", "wv", "q_norm",
-                                 "k_norm", "wo"), "attention")
+    grad_groups = dict.fromkeys(("wq_gate", "wq", "w_head_gate", "wk", "wv",
+                                 "q_norm", "k_norm", "wo"), "attention")
     # the ring takes one key/value head a query head
     columns, rows, ring = ("wq_gate",), (), False
     share = "n_heads or n_kv_heads"
@@ -492,19 +518,23 @@ class GroupedQueryAttention:
     def of(cls, p: SeqRecParams) -> "GroupedQueryAttention":
         return cls(p.n_heads, p.n_kv_heads, p.head_dim,
                    p.rotary_dim if p.positions == "rope" else None,
-                   p.attention_gate, p.qk_norm)
+                   p.attention_gate, p.qk_norm, p.rope_theta,
+                   YarnScaling(**p.rope_scaling) if p.rope_scaling else None)
 
-    def check(self) -> None:
+    def check(self, kind: str = "gqa", heads: str = "n_heads") -> None:
         if self.head_dim <= 0 or self.kv_heads <= 0 \
                 or self.heads % self.kv_heads:
             raise ValueError(
-                f"gqa needs head_dim > 0 and n_kv_heads a divisor of "
-                f"n_heads: {self.head_dim}, {self.kv_heads}, {self.heads}")
+                f"{kind} needs head_dim > 0 and n_kv_heads a divisor of "
+                f"{heads}: {self.head_dim}, {self.kv_heads}, {self.heads}")
         if self.rotary_dim is not None and (
                 not 0 < self.rotary_dim <= self.head_dim
                 or self.rotary_dim % 2):
             raise ValueError(f"rotary_dim {self.rotary_dim} is no even "
                              f"part of head_dim {self.head_dim}")
+        if self.gate not in (True, False, "head"):
+            raise ValueError(f"attention_gate {self.gate!r}: expected "
+                             f"True, False or 'head'")
 
     def divides(self, ways: int) -> bool:
         return not (self.heads % ways or (self.kv_heads % ways
@@ -519,9 +549,12 @@ class GroupedQueryAttention:
 
     def init(self, d: int, dense, uniform, norm) -> Dict:
         h, kv = self.heads, self.kv_heads
-        # without the gate the query projection has no gate's half
+        # without the gate the query projection has no gate's half; a
+        # gate of one column a head is a matrix of its own
         wq = {"wq_gate": dense(d, 2 * h * self.head_dim)} \
-            if self.gate else {"wq": dense(d, h * self.head_dim)}
+            if self.gate is True else {"wq": dense(d, h * self.head_dim)}
+        if self.gate == "head":
+            wq["w_head_gate"] = dense(d, h)
         qk_norms = {"q_norm": norm(self.head_dim),
                     "k_norm": norm(self.head_dim)} if self.qk_norm else {}
         return {**wq,
@@ -534,23 +567,63 @@ class GroupedQueryAttention:
         b, l, _ = x.shape
         positions = jnp.arange(l)
         gate = None
-        if self.gate:
+        if self.gate is True:
             q, gate = jnp.split(x @ w["wq_gate"], 2, axis=-1)
         else:
             q = x @ w["wq"]
+        if self.gate == "head":
+            gate = (x @ w["w_head_gate"])[..., None]        # [B, L, H, 1]
 
         def head_rows(t, norm_name):
             t = t.reshape(b, l, -1, self.head_dim)
             if self.qk_norm:
                 t = _norm(t, w[norm_name], p)
             if self.rotary_dim is not None:
-                t = rope(t, positions, p.rope_theta, self.rotary_dim)
+                t = rope(t, positions, self.theta, self.rotary_dim,
+                         self.scaling)
             return t
 
         q, k = (head_rows(t, name) for t, name in (
             (q, "q_norm"), (x @ w["wk"], "k_norm")))
         v = (x @ w["wv"]).reshape(b, l, -1, self.head_dim)
-        return _attend(q, k, v, gate, w["wo"], key_mask, mesh)
+        return _attend(q, k, v, gate, w["wo"], key_mask, mesh, self.window)
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowAttention(GroupedQueryAttention):
+    """The "swa" mixer, sliding-window attention: the "gqa" mixer's layer
+    function with a query seeing its own key and the `window` - 1 before
+    it (t - window < s <= t; positions are indices, so a left-padded
+    session is the unpadded one), and with sizes of its own beside a
+    model's full layers: `SeqRecParams.swa` holds its query heads, its
+    window and its rotary base and width as a dict of `heads`, `window`,
+    `rope_theta`, `rotary_dim` (no scaling: a band never reaches past the
+    trained length); key/value heads, head width, gate and q/k norm are
+    the spec's, as the full layers'. Its weights lie under the layer's
+    "swa"."""
+
+    scope = "seqrec_window_attention"
+    grad_groups = {"swa": "window_attention"}
+    columns, share = (), None
+    positions = ("rope",)
+
+    @classmethod
+    def of(cls, p: SeqRecParams) -> "WindowAttention":
+        own = dict(p.swa or {})
+        return cls(kv_heads=p.n_kv_heads, head_dim=p.head_dim,
+                   gate=p.attention_gate, qk_norm=p.qk_norm,
+                   theta=own.pop("rope_theta", 10000.0), **own)
+
+    def check(self) -> None:
+        if not isinstance(self.window, int) or self.window < 1:
+            raise ValueError(f"swa needs a window >= 1: {self.window}")
+        super().check("swa", "its heads")
+
+    def init(self, d: int, dense, uniform, norm) -> Dict:
+        return {"swa": super().init(d, dense, uniform, norm)}
+
+    def apply(self, w, x, key_mask, p: SeqRecParams, mesh):
+        return super().apply(w["swa"], x, key_mask, p, mesh)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -862,7 +935,8 @@ class ExpertLayer:
 
 #: the one table of layer kinds: a kind's name in a spec -> its record
 KINDS = {"mha": MultiHeadAttention, "mla": LatentAttention,
-         "gqa": GroupedQueryAttention, "gdn": GatedDeltaNet,
+         "gqa": GroupedQueryAttention, "swa": WindowAttention,
+         "gdn": GatedDeltaNet,
          "conv": ShortConvolution, "ssm": StateSpaceMixer,
          "gelu": GeluFeedForward, "swiglu": SwigluFeedForward,
          "moe": ExpertLayer}
@@ -880,6 +954,10 @@ def _sub_layer(kind: str) -> Tuple[Optional[str], Optional[str]]:
 #: settings that change where a train's work lies and what it keeps in
 #: memory, not what it computes
 MEMORY_FIELDS = ("remat",)
+#: fields the spec gained after runs had taken checkpoints under it: part
+#: of a run's identity (`spec_key`) only where they are set, so that an
+#: older run keeps the identity it had
+LATER_FIELDS = ("rope_scaling", "swa", "expert_update_by_expert")
 
 #: query and key block of the attention where it runs as a scan of XLA
 #: operations (`attention_route`: off a v5e, in a step sharded over a
@@ -1002,7 +1080,8 @@ STEP_SCOPES = (
     "seqrec_linear_attention", "seqrec_short_conv", "seqrec_router",
     "seqrec_experts", "seqrec_shared_expert", "seqrec_ffn",
     "seqrec_head_loss", "seqrec_optimizer", "seqrec_record",
-    "seqrec_state_space", "seqrec_latent_projection", "seqrec_mtp")
+    "seqrec_state_space", "seqrec_latent_projection", "seqrec_mtp",
+    "seqrec_window_attention")
 
 
 def _rms_norm(x, scale, eps):
@@ -1115,9 +1194,12 @@ def _rings(mesh: Optional[Mesh]) -> bool:
     return mesh is not None and "seq" in mesh.axis_names
 
 
-def _attend(q, k, v, gate, wo, key_mask, mesh):
+def _attend(q, k, v, gate, wo, key_mask, mesh, window=None):
     """What the softmax-attention mixers share: q, k, v [B, L, heads, .]
-    -> [B, L, D]. The key mask keeps padding out of the softmax."""
+    -> [B, L, D]. The key mask keeps padding out of the softmax. `gate`:
+    None, the output's own shape flat [B, L, heads x .], or one column a
+    head [B, L, heads, 1]. Under a `window` a query sees that many keys
+    up to its own (no ring takes one: `WindowAttention.ring`)."""
     b, l = q.shape[:2]
     if _rings(mesh):
         att = ring_attention_traced(q, k, v, mesh, axis="seq", causal=True,
@@ -1125,9 +1207,12 @@ def _attend(q, k, v, gate, wo, key_mask, mesh):
     else:
         att = blockwise_attention(q, k, v, block_k=ATTENTION_BLOCK,
                                   causal=True, key_mask=key_mask,
-                                  devices=1 if mesh is None else mesh.size)
+                                  devices=1 if mesh is None else mesh.size,
+                                  window=window)
+    if gate is not None and gate.ndim == 4:
+        att = att * jax.nn.sigmoid(gate)
     att = att.reshape(b, l, -1)
-    if gate is not None:
+    if gate is not None and gate.ndim == 3:
         att = att * jax.nn.sigmoid(gate)
     return att @ wo
 
@@ -1467,7 +1552,8 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
     counted `first`, the module's among them, and `mtp_loss`, the
     module's own cross-entropy; of "relu2" experts `expert_update_norm`
     [expert layer, held expert], the experts' part of the update expert
-    by expert). With a mesh, batch is
+    by expert; the same under `expert_update_by_expert`). With a mesh,
+    batch is
     sharded over "data" and embedding/ffn rows over "model"; XLA inserts
     the psums."""
 
@@ -1534,13 +1620,14 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
                 stats.update({
                     key: jnp.stack([s[key] for s in expert_layers])
                     for key in ("load", "held_tokens", "dropped")})
-                if p.expert_act == "relu2":
+                if p.expert_act == "relu2" or p.expert_update_by_expert:
                     # a hidden unit that none of an expert's tokens switched
                     # on has a gradient of exactly 0 and adamw leaves it
                     # where it is: the norm of a layer's update counts the
                     # units that a few tokens reached in its emptiest
-                    # experts. By held expert, a reader weighs it by the
-                    # expert's tokens
+                    # experts (and of any kind's an expert with no token
+                    # does not move). By held expert, a reader weighs it
+                    # by the expert's tokens
                     stats["expert_update_norm"] = jnp.stack([
                         _expert_norms(layer["experts"])
                         for layer in moe_layers])
@@ -1697,8 +1784,9 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
     layer, routed expert], `held_tokens` [expert layer, held expert] and
     `dropped` [expert layer] (under `n_loops` an expert layer once a
     pass, the first pass's layers first; the multi-token-prediction
-    module's expert layers after the stack's), of "relu2" experts
-    `expert_update_norm` [expert layer, held expert], `update_norm`'s
+    module's expert layers after the stack's), of "relu2" experts and
+    under `expert_update_by_expert` `expert_update_norm` [expert layer,
+    held expert], `update_norm`'s
     "experts" groups expert by expert, and, under `exit_gate`,
     `loop_loss` and `exit_share` [pass]; under `mtp_layers`, `mtp_loss`,
     the module's own cross-entropy (`loss` holds it `mtp_loss_weight`
@@ -1855,6 +1943,18 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
     for kind, n in mixer_layers.items():
         family = KINDS[kind].family
         family_layers[family] = family_layers.get(family, 0) + n
+    # of the mixers under a window: a session and head's pairs inside
+    # the band and in the blocks its route visits, a layer each
+    window_pairs = None
+    for kind, n in mixer_layers.items():
+        band = p.held_kind(kind)
+        if getattr(band, "window", None):
+            pairs = band_pairs(
+                jax.devices()[0].device_kind, p.max_len, band.head_dim,
+                band.head_dim, band.window, ATTENTION_BLOCK,
+                1 if mesh is None else mesh.size)
+            window_pairs = tuple(n * new + old for new, old in zip(
+                pairs, window_pairs or (0, 0)))
     train_stats.observe_seqrec_record(
         record, targets, rows,
         *("pallas" if steps and steps[0][key] else "xla"
@@ -1864,7 +1964,8 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
         {name: int(n) for name, n in steps[0]["layer_passes"].items()}
         if steps and "layer_passes" in steps[0] else None,
         "rows" if steps and steps[0].get("attention_rows") else "heads",
-        "pallas" if steps and steps[0].get("short_conv_pallas") else "xla")
+        "pallas" if steps and steps[0].get("short_conv_pallas") else "xla",
+        window_pairs)
     return SeqRecModel(item_vocab=all_items, params=params, hyper=p,
                        record=record)
 

@@ -81,9 +81,19 @@ the weights), `seqrec_put` (sharding, the optimizer's state),
   such a layer counts nothing here.
 * ``pio_train_seqrec_mixer_tokens_total{mixer}`` — positions of the
   trained batches, padding too, times the layers of each mixer
-  (``mha``, ``mla``, ``gqa``, ``gdn``, ``conv``, ``ssm``) the compiled
-  step ran, a multi-token-prediction module's among them; a layer that
+  (``mha``, ``mla``, ``gqa``, ``swa``, ``gdn``, ``conv``, ``ssm``) the
+  compiled step ran, a multi-token-prediction module's among them; a layer that
   is a feed-forward alone counts under no mixer.
+* ``pio_train_seqrec_window_band_pairs_total`` /
+  ``pio_train_seqrec_window_block_pairs_total`` — of the sliding-window
+  (``swa``) layers of the trained batches, a session, layer and query
+  head: the (query, key) pairs inside the band (a query's own key and
+  the window - 1 before it) and the pairs of the blocks that the route
+  their step took visits for them (its pair table's entries times a
+  block pair's scores: ops/attention.band_pairs). Their ratio is how
+  much of the computed scores counts. A model without such a layer
+  counts nothing here; the windowed calls' route and layout are counted
+  with the full layers' under the two attention counters above.
 * ``pio_train_seqrec_layer_pass_tokens_total{pass}`` — positions of the
   trained batches, padding too, times the layers the compiled step ran
   in the stack's ``first`` pass and in its ``repeat``s (`n_loops`): a
@@ -266,6 +276,21 @@ def seqrec_mixer_tokens(registry: MetricsRegistry = None):
         "mixer the compiled step ran", labelnames=("mixer",))
 
 
+def seqrec_window_band_pairs(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_window_band_pairs_total",
+        "(query, key) pairs inside the band of the trained batches' "
+        "sliding-window layers, a session, layer and query head")
+
+
+def seqrec_window_block_pairs(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_window_block_pairs_total",
+        "Pairs of the blocks the route of the trained batches' "
+        "sliding-window layers visits, a session, layer and query head "
+        "(ops/attention.band_pairs)")
+
+
 def seqrec_layer_pass_tokens(registry: MetricsRegistry = None):
     return (registry or default_registry()).counter(
         "pio_train_seqrec_layer_pass_tokens_total",
@@ -336,7 +361,8 @@ def observe_seqrec_record(record: dict, targets, rows,
                           mixer_layers: dict, family_layers: dict,
                           layer_passes: dict = None,
                           attention_layout: str = "heads",
-                          short_conv_impl: str = "xla") -> None:
+                          short_conv_impl: str = "xla",
+                          window_pairs: tuple = None) -> None:
     """The token and expert counters from one train's record
     (models/seqrec.train_seqrec): `targets` the padded target ids of all
     sessions, `rows` the sessions of each step's batch, `attention_impl`
@@ -348,7 +374,10 @@ def observe_seqrec_record(record: dict, targets, rows,
     `layer_passes` those of its first pass and of its repeats (None from
     a step of one pass: all are first), `attention_layout` where its
     attention kernels read a head, `short_conv_impl` the route its
-    short-convolution layers' chain was traced on."""
+    short-convolution layers' chain was traced on, `window_pairs` (the
+    pairs inside the band, the pairs of the blocks visited) of a session
+    and head over its step's sliding-window layers (None: no such
+    layer)."""
     import numpy as np
 
     real = sum(int((targets[r] > 0).sum()) for r in rows)
@@ -373,6 +402,10 @@ def observe_seqrec_record(record: dict, targets, rows,
         if attention_impl == "pallas":
             seqrec_attention_layout_tokens().inc(positions,
                                                  layout=attention_layout)
+    if window_pairs is not None:
+        sessions = sum(len(r) for r in rows)
+        seqrec_window_band_pairs().inc(sessions * window_pairs[0])
+        seqrec_window_block_pairs().inc(sessions * window_pairs[1])
     if "linear_attention" in family_layers:
         for counter in (seqrec_linear_attention_tokens,
                         seqrec_linear_attention_chain_tokens):

@@ -26,7 +26,9 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import dataclasses
 import functools
+import math
 from typing import Iterator, Optional, Set, Tuple
 
 import jax
@@ -38,13 +40,17 @@ from predictionio_tpu.ops import attention_pallas
 from predictionio_tpu.ops.attention_pallas import NEG_INF
 
 
-def _causal_mask(scores: jax.Array, q_off, k_off) -> jax.Array:
+def _causal_mask(scores: jax.Array, q_off, k_off,
+                 window: Optional[int] = None) -> jax.Array:
     """Mask scores [..., Lq, Lk] so query i attends to keys j with
-    global_j <= global_i, where globals are local indices + offsets."""
+    global_j <= global_i, where globals are local indices + offsets;
+    under a window, to the `window` keys up to its own alone
+    (`attention_pallas.sees`)."""
     lq, lk = scores.shape[-2], scores.shape[-1]
     qi = q_off + jnp.arange(lq)[:, None]
     kj = k_off + jnp.arange(lk)[None, :]
-    return jnp.where(kj <= qi, scores, NEG_INF)
+    return jnp.where(attention_pallas.sees(qi, kj, True, window), scores,
+                     NEG_INF)
 
 
 def mha(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -94,23 +100,67 @@ def _flash_finish(o, l, dtype):
     return jnp.einsum("bhqd->bqhd", out).astype(dtype)
 
 
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """A rotary table stretched to `factor` times the `original_max_len`
+    positions it was trained on (YaRN, arXiv:2309.00071, as transformers'
+    `_compute_yarn_parameters`): a pair of dimensions that turns more
+    than `beta_fast` times over the original length keeps its frequency,
+    one that turns fewer than `beta_slow` times has it divided by
+    `factor`, a linear ramp over the pairs between; cosines and sines
+    times `attention_factor` (0.1 ln(factor) + 1 where none is given), so
+    a score's rotary part carries its square."""
+
+    factor: float
+    original_max_len: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+    def amplitude(self) -> float:
+        if self.attention_factor is not None:
+            return self.attention_factor
+        return 0.1 * math.log(self.factor) + 1.0
+
+    def frequencies(self, theta: float, width: int) -> jax.Array:
+        """[width / 2] float32: the inverse frequencies of a rotary
+        part `width` wide."""
+        def pair_of(turns):     # the pair that turns this often (real)
+            return width * math.log(self.original_max_len / (
+                2 * math.pi * turns)) / (2 * math.log(theta))
+
+        low = max(math.floor(pair_of(self.beta_fast)), 0)
+        high = min(math.ceil(pair_of(self.beta_slow)), width - 1)
+        pairs = jnp.arange(width // 2, dtype=jnp.float32)
+        kept = theta ** (-pairs / (width // 2))
+        ramp = jnp.clip((pairs - low) / max(high - low, 0.001), 0.0, 1.0)
+        return kept / self.factor * ramp + kept * (1.0 - ramp)
+
+
 def rope(x: jax.Array, positions: jax.Array, theta: float,
-         rotary_dim: Optional[int] = None) -> jax.Array:
+         rotary_dim: Optional[int] = None,
+         scaling: Optional[YarnScaling] = None) -> jax.Array:
     """Rotary positions on the last axis of x [B, L, H, D] (D even), in
     the "halves" pairing: dimension i rotates with dimension i + D/2 by
     the angle position * theta^(-2i/D). positions: [L] or [B, L]. With
     `rotary_dim` < D only the leading `rotary_dim` dimensions rotate
-    (halves pairing within them) and the others pass through."""
+    (halves pairing within them) and the others pass through. With a
+    `scaling` the frequencies and the amplitude are its own."""
     if rotary_dim is not None and rotary_dim < x.shape[-1]:
         return jnp.concatenate(
-            [rope(x[..., :rotary_dim], positions, theta),
+            [rope(x[..., :rotary_dim], positions, theta, scaling=scaling),
              x[..., rotary_dim:]], axis=-1)
     half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if scaling is None:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        freq = scaling.frequencies(theta, x.shape[-1])
     ang = positions.astype(jnp.float32)[..., None] * freq    # [(B,) L, half]
     if ang.ndim == 2:
         ang = ang[None]
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    if scaling is not None:
+        cos, sin = (t * scaling.amplitude() for t in (cos, sin))
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(
         jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
@@ -132,25 +182,28 @@ def split_heads(qkv: jax.Array, heads: int,
     return q, k, v
 
 
-def _block_scores(q_i, k_j, mask_j, i, j, block_q, block_k, causal, scale):
+def _block_scores(q_i, k_j, mask_j, i, j, block_q, block_k, causal, scale,
+                  window=None):
     """Masked scores [B, H, bq, bk] of query block i against key block j
-    (NEG_INF where the key is padding or lies in the causal future)."""
+    (NEG_INF where the key is padding, lies in the causal future or
+    behind the window)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q_i, k_j,
                    preferred_element_type=jnp.float32) * scale
     if causal:
-        s = _causal_mask(s, i * block_q, j * block_k)
+        s = _causal_mask(s, i * block_q, j * block_k, window)
     return jnp.where(mask_j[:, None, None, :], s, NEG_INF)
 
 
 def _block_pairs(n_q: int, n_k: int, block_q: int, block_k: int,
-                 causal: bool) -> jax.Array:
+                 causal: bool, window: Optional[int] = None) -> jax.Array:
     """The (query block, key block) pairs that hold an unmasked score,
     query-major: a causal pair whose keys all lie in the future is left
-    out, so causal attention does half the work."""
-    return jnp.asarray(
-        [(i, j) for i in range(n_q) for j in range(n_k)
-         if not causal or j * block_k <= i * block_q + block_q - 1],
-        jnp.int32)
+    out, so causal attention does half the work, and under a window a
+    pair whose keys all lie behind the band. The kernels' table
+    (`attention_pallas._block_pairs`): the two routes cannot disagree
+    about an edge."""
+    return jnp.asarray(attention_pallas._block_pairs(
+        n_q, n_k, block_q, block_k, causal, False, window))
 
 
 def _rows(x, i, n):
@@ -162,12 +215,13 @@ def _put_rows(x, i, n, rows):
     return jax.lax.dynamic_update_slice_in_dim(x, rows, i * n, axis=2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _blockwise(q, k, v, key_mask, block_q, block_k, causal):
-    return _blockwise_fwd(q, k, v, key_mask, block_q, block_k, causal)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _blockwise(q, k, v, key_mask, block_q, block_k, causal, window=None):
+    return _blockwise_fwd(q, k, v, key_mask, block_q, block_k, causal,
+                          window)[0]
 
 
-def _blockwise_fwd(q, k, v, key_mask, block_q, block_k, causal):
+def _blockwise_fwd(q, k, v, key_mask, block_q, block_k, causal, window=None):
     """q [B, H, Lq, Dk], k [B, H, Lk, Dk], v [B, H, Lk, Dv], lengths
     block multiples. One scan over the block pairs; the running
     (output, max, denominator) of every query block live in the carry."""
@@ -175,7 +229,7 @@ def _blockwise_fwd(q, k, v, key_mask, block_q, block_k, causal):
     dv = v.shape[-1]
     scale = dk ** -0.5
     pairs = _block_pairs(lq // block_q, k.shape[2] // block_k, block_q,
-                         block_k, causal)
+                         block_k, causal, window)
 
     def step(carry, ij):
         o, m, l = carry
@@ -183,7 +237,7 @@ def _blockwise_fwd(q, k, v, key_mask, block_q, block_k, causal):
         s = _block_scores(_rows(q, i, block_q), _rows(k, j, block_k),
                           jax.lax.dynamic_slice_in_dim(
                               key_mask, j * block_k, block_k, axis=1),
-                          i, j, block_q, block_k, causal, scale)
+                          i, j, block_q, block_k, causal, scale, window)
         m_i, l_i = _rows(m, i, block_q), _rows(l, i, block_q)
         m_new = jnp.maximum(m_i, s.max(axis=-1))
         alpha = jnp.exp(m_i - m_new)
@@ -205,7 +259,7 @@ def _blockwise_fwd(q, k, v, key_mask, block_q, block_k, causal):
     return out, (q, k, v, key_mask, out, m + jnp.log(l))
 
 
-def _blockwise_bwd(block_q, block_k, causal, res, d_out):
+def _blockwise_bwd(block_q, block_k, causal, window, res, d_out):
     """The backward pass recomputes each block's probabilities from the
     saved log-sum-exp instead of keeping them: O(L x block) memory where
     differentiating the forward scan keeps every block's."""
@@ -214,7 +268,7 @@ def _blockwise_bwd(block_q, block_k, causal, res, d_out):
     d_out = d_out.astype(jnp.float32)
     delta = (d_out * out.astype(jnp.float32)).sum(axis=-1)   # [B, H, Lq]
     pairs = _block_pairs(q.shape[2] // block_q, k.shape[2] // block_k,
-                         block_q, block_k, causal)
+                         block_q, block_k, causal, window)
 
     def step(carry, ij):
         dq, dk, dv = carry
@@ -223,7 +277,7 @@ def _blockwise_bwd(block_q, block_k, causal, res, d_out):
                          _rows(v, j, block_k))
         s = _block_scores(q_i, k_j, jax.lax.dynamic_slice_in_dim(
             key_mask, j * block_k, block_k, axis=1),
-            i, j, block_q, block_k, causal, scale)
+            i, j, block_q, block_k, causal, scale, window)
         p = jnp.where(s > NEG_INF / 2,
                       jnp.exp(s - _rows(lse, i, block_q)[..., None]), 0.0)
         do_i = _rows(d_out, i, block_q)
@@ -258,7 +312,7 @@ def _blocks_and_pads(lq: int, lk: int, block_k: int,
 
 def attention_route(device_kind: str, lq: int, lk: int, dk: int, dv: int,
                     block_k: int = 512, block_q: Optional[int] = None,
-                    devices: int = 1) -> str:
+                    devices: int = 1, window: Optional[int] = None) -> str:
     """Which implementation `blockwise_attention` runs for these sizes on
     a device of this kind (`jax.Device.device_kind`), in a program traced
     for `devices` devices: "pallas", the kernels of
@@ -267,12 +321,33 @@ def attention_route(device_kind: str, lq: int, lk: int, dk: int, dv: int,
     (the lengths as `blockwise_attention` pads them), in a program for
     one device (the compiler partitions no Mosaic kernel: a program
     sharded over a mesh keeps the scan); "xla", the scan over block
-    pairs, everywhere else."""
+    pairs, everywhere else. A `window` goes either way: the kernels
+    tile it in blocks of the band's own."""
     _, _, pad_q, pad_k = _blocks_and_pads(lq, lk, block_k, block_q)
     if (device_kind in attention_pallas.KINDS and devices == 1
-            and attention_pallas.tiles(lq + pad_q, lk + pad_k, dk, dv)):
+            and attention_pallas.tiles(lq + pad_q, lk + pad_k, dk, dv,
+                                       window)):
         return "pallas"
     return "xla"
+
+
+def band_pairs(device_kind: str, length: int, dk: int, dv: int, window: int,
+               block_k: int = 512, devices: int = 1) -> Tuple[int, int]:
+    """Of one session and head of `length` positions under a `window`:
+    (the (query, key) pairs inside the band, `sees`' own count; the
+    pairs of the blocks that `attention_route`'s implementation visits
+    for them, its table's pairs times a block pair's scores). Their
+    ratio is how much of the computed scores counts."""
+    near = min(window, length)
+    inside = near * (near + 1) // 2 + (length - near) * near
+    block, _, pad, _ = _blocks_and_pads(length, length, block_k, None)
+    if attention_route(device_kind, length, length, dk, dv, block_k,
+                       devices=devices, window=window) == "pallas":
+        block = attention_pallas._block(length + pad, window)
+    n = (length + pad) // block
+    visited = len(attention_pallas._block_pairs(n, n, block, block, True,
+                                                False, window))
+    return inside, visited * block * block
 
 
 def attention_layout(device_kind: str, lq: int, lk: int, dk: int, dv: int,
@@ -332,7 +407,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         block_k: int = 512, causal: bool = False,
                         key_mask: Optional[jax.Array] = None,
                         block_q: Optional[int] = None,
-                        devices: int = 1) -> jax.Array:
+                        devices: int = 1,
+                        window: Optional[int] = None) -> jax.Array:
     """Flash-style single-device attention: stream over blocks of queries
     and of keys with the running-max/denominator recurrence so the
     [Lq, Lk] score matrix never materializes, forward or backward
@@ -352,7 +428,13 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     mesh's size) whether the blocks are folded by Pallas kernels (with
     blocks of their own, on operands head-first behind a transpose:
     `rotary_attention` is the entry that keeps them token-first) or by
-    a scan of XLA operations."""
+    a scan of XLA operations. With a `window` (causal, >= 1) a query
+    sees its own key and the window - 1 before it
+    (`attention_pallas.sees`); block pairs wholly behind the band are
+    visited on neither route."""
+    if window is not None and not (causal and window >= 1):
+        raise ValueError(f"a window of {window} keys, causal {causal}: a "
+                         f"window is causal and holds the query's own key")
     b, lq, h, dk = q.shape
     lk, dv = k.shape[1], v.shape[-1]
     if h % k.shape[2] or k.shape[2] != v.shape[2]:
@@ -371,16 +453,18 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
     heads_first = lambda t: jnp.swapaxes(t, 1, 2)
     route = attention_route(_device_kind(), lq, lk, dk, dv, block_k, block_q,
-                            devices)
+                            devices, window)
     _hear("heads" if route == "pallas" else "xla")
     if route == "pallas":
-        out = attention_pallas.flash_attention_pallas(
-            heads_first(q), heads_first(k), heads_first(v), key_mask, causal)
+        ops = heads_first(q), heads_first(k), heads_first(v), key_mask
+        out = attention_pallas.flash_attention_pallas(*ops, causal) \
+            if window is None \
+            else attention_pallas.window_attention_pallas(*ops, window)
     else:
         if group > 1:
             k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
         out = _blockwise(heads_first(q), heads_first(k), heads_first(v),
-                         key_mask, block_q, block_k, causal)
+                         key_mask, block_q, block_k, causal, window)
     return heads_first(out)[:, :lq]
 
 

@@ -10,7 +10,11 @@ is every row of ``dq``, ``dk`` and ``dv``.
 Two kernels, each a grid over (batch, head, block pair). The pairs are a
 table the kernel is handed (scalar prefetch), so a causal pair whose
 keys all lie in the future is no grid step at all; only a pair on the
-diagonal, or one that holds a padding key, builds a mask.
+diagonal, or one that holds a padding key, builds a mask. Under a
+`window` (query t sees the keys s with t - window < s <= t) the band has
+a second edge: a pair whose keys all lie behind it is no grid step
+either, a pair that edge cuts builds a mask too, the block is the band's
+own (`WINDOW_BLOCK`) and the kernels are named `window_attention_pallas_*`.
 
 * ``flash_attention_pallas_fwd``: pairs query-major, scores [bq, bk];
   output, maximum and denominator of a query block accumulate over its
@@ -43,6 +47,7 @@ accumulates in float32; scores, maxima, exponentials, denominators,
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +68,16 @@ NEG_INF = -1e30    # large-negative instead of -inf: avoids NaN in exp(m - m)
 #: 122.4 ms at 512, 68.6 at 1024, 74.4 at 2048; forward + backward 271.9,
 #: 198.6, 217.5: the block does not follow the width.
 BLOCK = 1024
+#: the block under a window: the largest of 512, 256, 128 that divides the
+#: length. A constant, from chip runs of each kernel alone at 1 x 64 (8
+#: key/value) heads x 16,384 positions x 128 under a window of 512 (PERF.md
+#: section 6, PR 44), one block for both: a forward call took 29.8 ms at
+#: 128, 16.7 at 256, 11.2 at 512, 13.8 at 1024; forward + backward 64.6,
+#: 36.1, 25.5, 34.6 (the whole causal triangle there: 37.9 and 107.0). Of
+#: the scores the visited blocks compute, 80%, 67%, 50% and 25% lie inside
+#: the band: a small block wastes less and pays more grid steps, and the
+#: triangle's 1024 computes four scores for one that counts.
+WINDOW_BLOCK = 512
 
 #: the device kinds (`jax.Device.device_kind`) the block and the two limits
 #: below were measured on; `ops/attention.attention_route` sends no other
@@ -81,20 +96,26 @@ _DQ_VMEM = 48 * 1024 * 1024
 _NT = (((1,), (1,)), ((), ()))     # a @ b.T
 
 
-def _block(length: int) -> int:
+def _block(length: int, window: Optional[int] = None) -> int:
     """The largest of BLOCK, BLOCK / 2, / 4, / 8 that divides the length,
-    0 if none does."""
-    return next((b for b in (BLOCK, BLOCK // 2, BLOCK // 4, BLOCK // 8)
-                 if length % b == 0), 0)
+    0 if none does; under a window, of WINDOW_BLOCK down to one lane
+    tile."""
+    largest = BLOCK if window is None else WINDOW_BLOCK
+    return next((b for b in (largest, largest // 2, largest // 4,
+                             largest // 8)
+                 if b >= 128 and length % b == 0), 0)
 
 
-def tiles(lq: int, lk: int, dk: int, dv: int) -> bool:
+def tiles(lq: int, lk: int, dk: int, dv: int,
+          window: Optional[int] = None) -> bool:
     """Whether the kernels lay these shapes out: lengths in whole lane
     tiles, widths in half lane tiles (a block's trailing dimension is
     the array's whole width), and `dq` of a (batch row, head), float32
-    on whole lane tiles, within its VMEM."""
-    return (_block(lq) > 0 and _block(lk) > 0 and dk % 64 == 0
-            and dv % 64 == 0
+    on whole lane tiles, within its VMEM (whole whatever the window: a
+    key block's pairs add to the rows of its band alone, but the block
+    that holds them is the head's)."""
+    return (_block(lq, window) > 0 and _block(lk, window) > 0
+            and dk % 64 == 0 and dv % 64 == 0
             and 2 * lq * -(-dk // 128) * 128 * 4 <= _DQ_VMEM)
 
 
@@ -106,20 +127,43 @@ def layout(dk: int, dv: int) -> str:
     return "rows" if dk % 128 == 0 and dv % 128 == 0 else "heads"
 
 
+def sees(query, key, causal: bool, window: Optional[int] = None):
+    """Whether the query at position `query` sees the key at `key`: if
+    causal, no key in its future; under a window, none `window`
+    positions or more behind it either (t - window < s <= t with both).
+    The one statement of the band's edges, on numbers or on arrays: the
+    kernels' masks and the scan's (ops/attention.py) are this, and the
+    pair tables hold it at a block pair's corners."""
+    seen = key <= query if causal else True
+    if window is not None:
+        seen = seen & (key > query - window)
+    return seen
+
+
 def _block_pairs(n_q: int, n_k: int, bq: int, bk: int, causal: bool,
-                 key_major: bool) -> np.ndarray:
-    """[pairs, 2] (query block, key block) with an unmasked score."""
-    pairs = [(i, j) for i in range(n_q) for j in range(n_k)
-             if not causal or j * bk <= i * bq + bq - 1]
+                 key_major: bool,
+                 window: Optional[int] = None) -> np.ndarray:
+    """[pairs, 2] (query block, key block) with a score that counts, the
+    one table both routes walk (`ops/attention._block_pairs` is this
+    one, query-major). The distances query - key of a pair's scores are
+    every whole number between its corners', so it holds a score that
+    `sees` iff its last query is not before its first key (causal) and
+    its first query less than a window past its last key."""
+    def holds(i, j):
+        first_q, first_k = i * bq, j * bk
+        return sees(first_q + bq - 1, first_k, causal) and (
+            window is None or first_k + bk - 1 > first_q - window)
+
+    pairs = [(i, j) for i in range(n_q) for j in range(n_k) if holds(i, j)]
     if key_major:
         pairs.sort(key=lambda ij: (ij[1], ij[0]))
-    return np.asarray(pairs, np.int32)
+    return np.asarray(pairs, np.int32).reshape(-1, 2)
 
 
-def _keep(mask, i, j, bq, bk, causal, keys_on_rows):
-    """Which scores of pair (i, j) count: the key is no padding and, if
-    causal, not in the query's future. mask: the key block's, laid along
-    the scores' key axis."""
+def _keep(mask, i, j, bq, bk, causal, keys_on_rows, window=None):
+    """Which scores of pair (i, j) count: the key is no padding and the
+    query `sees` it. mask: the key block's, laid along the scores' key
+    axis."""
     keep = mask > 0
     if not causal:
         return keep
@@ -128,20 +172,26 @@ def _keep(mask, i, j, bq, bk, causal, keys_on_rows):
     keys = j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, key_axis)
     queries = i * bq + jax.lax.broadcasted_iota(jnp.int32, shape,
                                                 1 - key_axis)
-    return keep & (keys <= queries)
+    return keep & sees(queries, keys, causal, window)
 
 
-def _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal):
-    """Run fold(masked) with masked True only where the pair needs it."""
+def _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal,
+                   window=None):
+    """Run fold(masked) with masked True only where the pair needs it: a
+    padding key, or a corner an edge cuts off (the first query and the
+    last key at the causal edge, the last query and the first key at the
+    band's trailing one)."""
     needs = full_ref[b * n_k + j] == 0
     if causal:
         needs = needs | (j * bk + bk - 1 > i * bq)
+    if window is not None:
+        needs = needs | (j * bk <= i * bq + bq - 1 - window)
     pl.when(needs)(lambda: fold(True))
     pl.when(jnp.logical_not(needs))(lambda: fold(False))
 
 
 def _fwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
-                *refs, scale, causal, bq, bk, n_k, save_lse):
+                *refs, scale, causal, bq, bk, n_k, save_lse, window=None):
     if save_lse:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -150,8 +200,12 @@ def _fwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
     i, j = qi_ref[p], kj_ref[p]
     last_j = jnp.minimum(((i + 1) * bq - 1) // bk, n_k - 1) \
         if causal else n_k - 1
+    # the first key block of the query block's pairs: under a window, the
+    # one that holds the last key its first query sees
+    first_j = 0 if window is None \
+        else jnp.maximum(i * bq - window + 1, 0) // bk
 
-    @pl.when(j == 0)
+    @pl.when(j == first_j)
     def _():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -161,7 +215,7 @@ def _fwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
         s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0], _NT,
                                 preferred_element_type=jnp.float32) * scale
         if masked:
-            keep = _keep(mask_ref[0], i, j, bq, bk, causal, False)
+            keep = _keep(mask_ref[0], i, j, bq, bk, causal, False, window)
             s = jnp.where(keep, s, NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -178,7 +232,7 @@ def _fwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
-    _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal)
+    _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal, window)
 
     @pl.when(j == last_j)
     def _():
@@ -191,10 +245,15 @@ def _fwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
 
 def _bwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
                 do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, scale, causal, bq, bk, n_q, n_k):
+                dk_scr, dv_scr, *, scale, causal, bq, bk, n_q, n_k,
+                window=None):
     b, p = pl.program_id(0), pl.program_id(2)
     i, j = qi_ref[p], kj_ref[p]
     first_i = (j * bk) // bq if causal else 0
+    # the last query block of the key block's pairs: under a window, the
+    # one that holds the last query that sees its last key
+    last_i = n_q - 1 if window is None else jnp.minimum(
+        (j * bk + bk + window - 2) // bq, n_q - 1)
 
     @pl.when(p == 0)
     def _():
@@ -211,8 +270,8 @@ def _bwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
                                 preferred_element_type=jnp.float32) * scale
         prob = jnp.exp(s - lse_ref[0, 0])
         if masked:
-            prob = jnp.where(_keep(mask_ref[0], i, j, bq, bk, causal, True),
-                             prob, 0.0)
+            prob = jnp.where(_keep(mask_ref[0], i, j, bq, bk, causal, True,
+                                   window), prob, 0.0)
         dv_scr[...] += jnp.dot(prob.astype(do.dtype), do,
                                preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(v_ref[0, 0], do, _NT,
@@ -224,9 +283,9 @@ def _bwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
         dq_ref[0, 0, rows, :] += jnp.dot(ds.T.astype(k.dtype), k,
                                          preferred_element_type=jnp.float32)
 
-    _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal)
+    _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal, window)
 
-    @pl.when(i == n_q - 1)
+    @pl.when(i == last_i)
     def _():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
@@ -299,11 +358,22 @@ def _shape(b, h, length, width, rows):
     return (b, 1, length, h * width) if rows else (b, h, length, width)
 
 
+def _kernel(kernel, name: str, window, **sizes):
+    """The kernel at its sizes and its name in a device trace: a
+    windowed call's is its own (`window_attention_pallas_*`), so that the
+    band's time reads apart from the whole-causal calls'."""
+    if window is None:
+        return functools.partial(kernel, **sizes), \
+            f"flash_attention_pallas_{name}"
+    return functools.partial(kernel, window=window, **sizes), \
+        f"window_attention_pallas_{name}"
+
+
 def _forward(q, k, v, key_mask, causal, bq, bk, interpret, save_lse,
-             heads=None):
+             heads=None, window=None):
     b, h, kv, lq, lk, dk, dv = _sizes(q, k, v, heads)
     rows = heads is not None
-    pairs = _block_pairs(lq // bq, lk // bk, bq, bk, causal, key_major=False)
+    pairs = _block_pairs(lq // bq, lk // bk, bq, bk, causal, False, window)
     by_query, _, by_kv_head, _ = _specs(bq, bk, h // kv, rows)
     out_shape = [jax.ShapeDtypeStruct(_shape(b, h, lq, dv, rows),
                                       jnp.float32)]
@@ -312,9 +382,9 @@ def _forward(q, k, v, key_mask, causal, bq, bk, interpret, save_lse,
         out_shape.append(jax.ShapeDtypeStruct((b, h, lq, 1), jnp.float32))
         out_specs.append(_specs(bq, bk)[0](1))
     return _call(
-        functools.partial(_fwd_kernel, scale=dk ** -0.5, causal=causal,
-                          bq=bq, bk=bk, n_k=lk // bk, save_lse=save_lse),
-        "flash_attention_pallas_fwd", key_mask, pairs, h, bk,
+        *_kernel(_fwd_kernel, "fwd", window, scale=dk ** -0.5, causal=causal,
+                 bq=bq, bk=bk, n_k=lk // bk, save_lse=save_lse),
+        key_mask, pairs, h, bk,
         [by_query(dk), by_kv_head(dk), by_kv_head(dv),
          pl.BlockSpec((1, 1, bk),
                       lambda b, h, p, qi, kj, full: (b, 0, kj[p]))],
@@ -325,18 +395,18 @@ def _forward(q, k, v, key_mask, causal, bq, bk, interpret, save_lse,
 
 
 def _backward(q, k, v, key_mask, d_out, lse, delta, causal, bq, bk,
-              interpret, heads=None):
+              interpret, heads=None, window=None):
     b, h, kv, lq, lk, dk, dv = _sizes(q, k, v, heads)
     rows = heads is not None
-    pairs = _block_pairs(lq // bq, lk // bk, bq, bk, causal, key_major=True)
+    pairs = _block_pairs(lq // bq, lk // bk, bq, bk, causal, True, window)
     group = h // kv
     by_query, by_key, by_kv_head, whole = _specs(bq, bk, group, rows)
     row = pl.BlockSpec((1, 1, 1, bq),
                        lambda b, h, p, qi, kj, full: (b, h, 0, qi[p]))
     dq, d_k, d_v = _call(
-        functools.partial(_bwd_kernel, scale=dk ** -0.5, causal=causal,
-                          bq=bq, bk=bk, n_q=lq // bq, n_k=lk // bk),
-        "flash_attention_pallas_bwd", key_mask, pairs, h, bk,
+        *_kernel(_bwd_kernel, "bwd", window, scale=dk ** -0.5, causal=causal,
+                 bq=bq, bk=bk, n_q=lq // bq, n_k=lk // bk),
+        key_mask, pairs, h, bk,
         [by_query(dk), by_kv_head(dk), by_kv_head(dv),
          pl.BlockSpec((1, bk, 1),
                       lambda b, h, p, qi, kj, full: (b, kj[p], 0)),
@@ -369,12 +439,12 @@ def flash_attention_pallas(q, k, v, key_mask, causal: bool,
     The operands lie head-first; `rotary_attention_pallas` is the entry
     that reaches the same kernels token-first.
     `interpret` runs the kernels in the Pallas interpreter (the CPU
-    tests)."""
+    tests). `window_attention_pallas` is the same call over a band."""
     return _fwd(q, k, v, key_mask, causal, interpret, save_lse=False)[0]
 
 
 def _fwd(q, k, v, key_mask, causal, interpret, save_lse=True, heads=None,
-         dtype=None):
+         dtype=None, window=None):
     """-> (the output in `dtype`, the operands' by default; what the
     backward pass keeps). With `heads` = H the operands lie token-first,
     where a projection writes them (`layout` "rows"): q, k [B, L, H x
@@ -386,9 +456,9 @@ def _fwd(q, k, v, key_mask, causal, interpret, save_lse=True, heads=None,
     ops = tuple(t.astype(jnp.bfloat16) for t in (q, k, v))
     if heads is not None:       # [B, 1, L, .]: no array moves for it
         ops = tuple(t[:, None] for t in ops)
-    lengths = ops[0].shape[2], ops[1].shape[2]
-    out, *lse = _forward(*ops, key_mask, causal, *map(_block, lengths),
-                         interpret, save_lse, heads)
+    bq, bk = (_block(t.shape[2], window) for t in ops[:2])
+    out, *lse = _forward(*ops, key_mask, causal, bq, bk, interpret, save_lse,
+                         heads, window)
     out = out.astype(dtype or q.dtype)
     shown = out if heads is None else out[:, 0]
     if not save_lse:
@@ -396,15 +466,15 @@ def _fwd(q, k, v, key_mask, causal, interpret, save_lse=True, heads=None,
     return shown, (*ops, key_mask, out, lse[0][..., 0])
 
 
-def _bwd(causal, interpret, res, d_out, heads=None):
+def _bwd(causal, interpret, res, d_out, heads=None, window=None):
     q, k, v, key_mask, out, lse = res
     if heads is not None:
         d_out = d_out[:, None]
     delta = _head_sums(d_out.astype(jnp.float32) * out.astype(jnp.float32),
                        heads)                                   # [B, H, Lq]
     grads = _backward(q, k, v, key_mask, d_out.astype(jnp.bfloat16), lse,
-                      delta, causal, _block(q.shape[2]), _block(k.shape[2]),
-                      interpret, heads)
+                      delta, causal, _block(q.shape[2], window),
+                      _block(k.shape[2], window), interpret, heads, window)
     if heads is not None:
         grads = tuple(g[:, 0] for g in grads)
     return (*(g.astype(out.dtype) for g in grads), None)
@@ -424,6 +494,25 @@ def _head_sums(t, heads):
 
 
 flash_attention_pallas.defvjp(_fwd, _bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def window_attention_pallas(q, k, v, key_mask, window: int,
+                            interpret: bool = False):
+    """`flash_attention_pallas`, causal, over a band: a query sees its
+    own key and the `window` - 1 before it (`sees`; window >= 1). The
+    same kernel bodies on tables that leave out every pair wholly behind
+    the band, in blocks of the band's own (`WINDOW_BLOCK`), under names
+    of their own (`window_attention_pallas_fwd` / `_bwd`)."""
+    return _fwd(q, k, v, key_mask, True, interpret, save_lse=False,
+                window=window)[0]
+
+
+window_attention_pallas.defvjp(
+    lambda q, k, v, key_mask, window, interpret: _fwd(
+        q, k, v, key_mask, True, interpret, window=window),
+    lambda window, interpret, res, d_out: _bwd(True, interpret, res, d_out,
+                                               window=window))
 
 
 # -- rotary positions and the cast, on the projection's columns ----------

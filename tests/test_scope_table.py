@@ -177,15 +177,16 @@ def every_mixer_spec(**over):
     """d 64, `remat` on; a convolution layer with the dense feed-forward,
     then a linear-attention, a grouped-query attention and a state-space
     layer with 16 experts top-3 in a latent of 32 and a gated shared
-    expert each, and a multi-token-prediction module of one attention
-    layer."""
+    expert each, and a multi-token-prediction module of one sliding-window
+    attention layer."""
     from predictionio_tpu.models import seqrec
 
     return seqrec.SeqRecParams(**{**dict(
         d_model=64, n_heads=4, n_layers=4, max_len=24, seed=11,
         mixer=("conv", "gdn", "gqa", "ssm"), ffn="moe", first_dense_layers=1,
         ssm=dict(heads=4, head_dim=8, groups=2, state=8, conv_kernel=4,
-                 chunk=8), moe_latent_size=32, mtp_layers=("gqa",),
+                 chunk=8), moe_latent_size=32, mtp_layers=("swa",),
+        swa=dict(heads=4, window=9, rope_theta=1e4, rotary_dim=16),
         mtp_loss_weight=0.1,
         ffn_width=96, norm="rms", norm_eps=1e-5, positions="rope",
         rope_theta=1e6, tied_head=False, n_kv_heads=2, head_dim=16,

@@ -37,6 +37,11 @@ SPECS = {
                  post_norm=True, n_loops=2, exit_gate=True),
     "ssm": dict(BASE, norm="rms", positions="none", mixer="ssm", ssm=SSM,
                 ffn_width=24),
+    "swa": dict(BASE, **RMS, mixer=("gqa", "swa"), ffn="swiglu",
+                n_kv_heads=2, head_dim=4, rotary_dim=2, qk_norm=False,
+                attention_gate="head",
+                rope_scaling=dict(factor=4.0, original_max_len=4),
+                swa=dict(heads=6, window=3, rope_theta=100.0, rotary_dim=4)),
     "moe_mtp": dict(BASE, **RMS, **MOE, mixer="gqa", ffn="moe",
                     first_dense_layers=1, n_kv_heads=2, head_dim=4,
                     rotary_dim=4, shared_expert_gate=True,
@@ -48,8 +53,9 @@ SPECS = {
                          moe_latent_size=8, tensor_ways=2, ssm=SSM,
                          mtp_layers=("ssm", "moe")),
 }
-#: the spec each of the table's nine kinds is read from here
-SPEC_OF = {"mha": "mha", "mla": "mla", "gqa": "gqa", "gdn": "gdn",
+#: the spec each of the table's ten kinds is read from here
+SPEC_OF = {"mha": "mha", "mla": "mla", "gqa": "gqa", "swa": "swa",
+           "gdn": "gdn",
            "conv": "conv", "ssm": "ssm", "gelu": "mha", "swiglu": "mla",
            "moe": "moe_mtp"}
 
@@ -87,13 +93,14 @@ def draws(rng):
         "scale": jnp.ones((width,), jnp.float32)}
 
 
-def test_the_table_names_the_nine_kinds_in_the_order_the_messages_give():
+def test_the_table_names_the_ten_kinds_in_the_order_the_messages_give():
     assert tuple(seqrec.KINDS) == seqrec.MIXERS + seqrec.FFNS == (
-        "mha", "mla", "gqa", "gdn", "conv", "ssm", "gelu", "swiglu", "moe")
+        "mha", "mla", "gqa", "swa", "gdn", "conv", "ssm", "gelu", "swiglu",
+        "moe")
     assert [seqrec._sub_layer(kind) for kind in ("gqa", "moe")] == [
         ("gqa", None), (None, "moe")]
     scopes = {record.scope for record in seqrec.KINDS.values()}
-    assert scopes <= set(seqrec.STEP_SCOPES) and len(seqrec.STEP_SCOPES) == 15
+    assert scopes <= set(seqrec.STEP_SCOPES) and len(seqrec.STEP_SCOPES) == 16
 
 
 @pytest.mark.parametrize("kind", list(seqrec.KINDS))
@@ -178,12 +185,12 @@ def test_a_kind_told_its_share_halves_what_it_holds(kind):
     ({"positions": "none"}, "positions 'none' goes with the mixers gqa and "
                             "ssm, not ['gdn']"),
     ({"mixer": ("gdn", "flash")}, "unknown mixer ['flash']: expected among "
-     "('mha', 'mla', 'gqa', 'gdn', 'conv', 'ssm')"),
+     "('mha', 'mla', 'gqa', 'swa', 'gdn', 'conv', 'ssm')"),
     ({"ffn": "relu"}, "unknown ffn 'relu': expected one of ('gelu', "
                       "'swiglu', 'moe')"),
     ({"sublayers": ("gqa", "mlp")}, "unknown sublayers ['mlp']: expected "
-     "among ('mha', 'mla', 'gqa', 'gdn', 'conv', 'ssm', 'gelu', 'swiglu', "
-     "'moe')"),
+     "among ('mha', 'mla', 'gqa', 'swa', 'gdn', 'conv', 'ssm', 'gelu', "
+     "'swiglu', 'moe')"),
 ])
 def test_what_crosses_kinds_is_refused_in_the_words_it_had(over, message):
     with pytest.raises(ValueError) as refusal:
@@ -252,7 +259,8 @@ def test_a_run_keeps_the_identity_it_had(name):
 
 
 def test_the_spec_has_one_field_fewer_and_refuses_the_old_key():
-    assert len(dataclasses.fields(seqrec.SeqRecParams)) == 57
+    assert len(dataclasses.fields(seqrec.SeqRecParams)) == 57 + len(
+        seqrec.LATER_FIELDS)
     assert seqrec.MEMORY_FIELDS == ("remat",)
     with pytest.raises(ValueError, match="attentionImpl"):
         params_from_json({"attentionImpl": "ring"}, seqrec.SeqRecParams)
@@ -267,7 +275,8 @@ def test_tokens_are_counted_by_the_family_a_record_names():
     families = {name: kind.family for name, kind in seqrec.KINDS.items()
                 if kind.role == "mixer"}
     assert families == {"mha": "attention", "mla": "attention",
-                        "gqa": "attention", "gdn": "linear_attention",
+                        "gqa": "attention", "swa": "attention",
+                        "gdn": "linear_attention",
                         "conv": "short_conv", "ssm": None}
 
     def counter(name, **labels):

@@ -574,6 +574,93 @@ def test_the_state_space_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
     assert held <= 15.75 * 2 ** 30 - 1e9, held
 
 
+@pytest.mark.parametrize("block", [128, 256, 512, 1024])
+def test_the_banded_attention_kernels_compile_for_v5e(one_chip, monkeypatch,
+                                                      block):
+    """laguna-xs2-ep8.train's sliding layers: 1 session x 64 query heads
+    over 8 key/value heads x 16,384 x 128 under a window of 512, forward
+    and backward, at each block the sweep that chose `WINDOW_BLOCK` ran
+    (PERF.md section 6, PR 44), under names of their own."""
+    from predictionio_tpu.ops import attention_pallas
+
+    monkeypatch.setattr(attention_pallas, "WINDOW_BLOCK", block)
+    assert attention_pallas.tiles(16384, 16384, 128, 128, 512)
+    assert attention_pallas._block(16384, 512) == block
+
+    def loss(q, k, v, mask, w):
+        out = attention_pallas.window_attention_pallas(q, k, v, mask, 512)
+        return (out * w).sum()
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        shape(1, 64, 16384, 128), shape(1, 8, 16384, 128),
+        shape(1, 8, 16384, 128), shape(1, 16384, dtype=jnp.bool_),
+        shape(1, 64, 16384, 128)).compile().as_text()
+    for kernel in ("window_attention_pallas_fwd",
+                   "window_attention_pallas_bwd"):
+        assert kernel in text
+    assert "flash_attention_pallas" not in text
+
+
+def test_the_window_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
+    """laguna-xs2-ep8.train's step compiled for one described v5e: 16,384
+    positions through a full layer with a dense feed-forward, three
+    sliding-window layers and a full one with their experts; the two
+    full layers on the whole-causal kernels at 48 heads (two forward
+    calls under `remat`, one backward, each), the three sliding ones on
+    the banded kernels at 64 under names of their own, under the
+    layer's own scope; the four expert layers' products on the
+    grouped-product kernels at 2048 x 512; arguments + temporaries leave
+    the 16 GB chip 0.5 GB and more."""
+    import json
+    import os
+
+    from predictionio_tpu.models import seqrec
+    from predictionio_tpu.obs import profiler
+    from predictionio_tpu.ops import attention, moe
+
+    kind = chips[0].device_kind
+    for module in (attention, moe):
+        monkeypatch.setattr(module, "_device_kind", lambda: kind)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "seqrec-laguna-xs2-ep8.json")) as f:
+        cfg = json.load(f)
+    p = seqrec.SeqRecParams(**cfg["algorithm_params"])
+    assert p.remat and p.max_len == 16384
+    assert p.mixer_kinds() == ("gqa", "swa", "swa", "swa", "gqa")
+    optimizer = seqrec.make_optimizer(p)
+    params = jax.eval_shape(
+        lambda: seqrec.init_params(None, cfg["n_items"], p))
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params)) \
+        == 691_624_960
+    put = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    seqs = jax.ShapeDtypeStruct((p.batch_size, p.max_len), jnp.int32,
+                                sharding=one_chip)
+    compiled = seqrec.make_train_step(None, p, optimizer).lower(
+        put(params), put(jax.eval_shape(optimizer.init, params)), seqs,
+        seqs).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(text, "flash_attention_pallas_fwd") == 2 * 2
+    assert _kernel_calls(text, "flash_attention_pallas_bwd") == 2
+    assert _kernel_calls(text, "window_attention_pallas_fwd") == 3 * 2
+    assert _kernel_calls(text, "window_attention_pallas_bwd") == 3
+    assert _kernel_calls(text, "grouped_product_pallas_[a-z_]*") == 4 * 12
+    assert "ragged-dot" not in text
+    rows = profiler.parse_scope_table(text, seqrec.STEP_SCOPES)[1]
+    scopes = {row[0] for key, row in rows.items()
+              if "window_attention_pallas" in key}
+    assert scopes == {"seqrec_window_attention"}, scopes
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print("window cell step: arguments", memory.argument_size_in_bytes,
+          "temporaries", memory.temp_size_in_bytes)
+    assert held <= 15.75 * 2 ** 30 - 0.5e9, held
+
+
 @pytest.mark.parametrize("name,tokens,k,d,w,held,devices,kernels", [
     # a pass of each sequence cell's expert layers, forward and backward:
     # kimivl-a3b-ep8.train, lfm2-a2b-ep8.train, qwen3next-a3b-ep16.train

@@ -42,8 +42,9 @@ from predictionio_tpu.obs import jax_stats, train_stats
 from predictionio_tpu.obs.tracing import span
 from predictionio_tpu.ops import linear_attention, moe, state_space
 from predictionio_tpu.ops.attention import (
-    YarnScaling, band_pairs, blockwise_attention, ring_attention_traced,
-    rope, rotary_attention, routes_into, split_heads,
+    YarnScaling, attention_layout, band_pairs, blockwise_attention,
+    grouped_attention, ring_attention_traced, rope, rotary_attention,
+    routes_into, split_heads,
 )
 
 
@@ -493,7 +494,11 @@ class GroupedQueryAttention:
     the table stretched by `scaling` where there is one, the output
     gated by a sigmoid of a projection of the input (`attention_gate`:
     element by element, or one column a head), causal over the whole
-    session (the "swa" kind below is this one under a `window`)."""
+    session (the "swa" kind below is this one under a `window`). Without
+    `qk_norm`, at heads of whole lane tiles on the kernels' route
+    (`attention_layout`), nothing between the products holds a
+    head axis (`grouped_attention`); elsewhere heads are rows of [B, L,
+    H, D] (`_attend`)."""
 
     heads: int = 0
     kv_heads: int = 0
@@ -573,6 +578,28 @@ class GroupedQueryAttention:
             q = x @ w["wq"]
         if self.gate == "head":
             gate = (x @ w["w_head_gate"])[..., None]        # [B, L, H, 1]
+        devices = 1 if mesh is None else mesh.size
+        if not self.qk_norm and attention_layout(
+                None, l, l, self.head_dim, self.head_dim, ATTENTION_BLOCK,
+                devices=devices, window=self.window) == "rows":
+            # the three products' outputs where they lie, as the "mha"
+            # mixer's one: nothing between them and `@ wo` holds a head
+            # axis (a gate of a column a head goes in, an elementwise one
+            # is flat already), and what the products around it alone
+            # read, the three gradients and the gated output, may be
+            # written in the type they read it in (`_qkv_grad_dtype`). A
+            # head's own norm would have to run in the pass in front of
+            # the kernels: a layer with one keeps its heads apart, below
+            att = grouped_attention(
+                q, x @ w["wk"], x @ w["wv"], self.head_dim,
+                gate=gate[..., 0] if self.gate == "head" else None,
+                theta=None if self.rotary_dim is None else self.theta,
+                rotary_dim=self.rotary_dim, scaling=self.scaling,
+                window=self.window, block_k=ATTENTION_BLOCK,
+                key_mask=key_mask, operand_dtype=_qkv_grad_dtype())
+            if self.gate is True:
+                att = att * jax.nn.sigmoid(gate)
+            return att @ w["wo"]
 
         def head_rows(t, norm_name):
             t = t.reshape(b, l, -1, self.head_dim)
@@ -1174,7 +1201,9 @@ def _linear_attention(layer, x, key_mask, p: SeqRecParams, devices: int):
 
 def _qkv_grad_dtype():
     """What the "mha" mixer lets `rotary_attention` round the gradient of
-    `x @ wqkv` to where the kernels' route writes it token-first, and
+    `x @ wqkv` to where the kernels' route writes it token-first, the
+    "gqa" and "swa" mixers `grouped_attention` those of `x @ wq`, `x @
+    wk`, `x @ wv` (and the gated output, which `@ wo` rounds likewise), and
     `_short_conv` lets `gated_short_conv` round that of `x @ conv_in` to:
     bfloat16, the type that product's two backward products read it in
     anyway, BECAUSE the mixers multiply at the default precision and

@@ -137,6 +137,19 @@ class YarnScaling:
         return kept / self.factor * ramp + kept * (1.0 - ramp)
 
 
+def _rotary_angles(positions: jax.Array, theta: float, width: int,
+                   scaling: Optional[YarnScaling]) -> jax.Array:
+    """[(B,) L, width / 2] float32: what pair i of a rotary part `width`
+    wide turns by at each position, position * theta^(-2i/width) or a
+    `scaling`'s frequency."""
+    half = width // 2
+    if scaling is None:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        freq = scaling.frequencies(theta, width)
+    return positions.astype(jnp.float32)[..., None] * freq
+
+
 def rope(x: jax.Array, positions: jax.Array, theta: float,
          rotary_dim: Optional[int] = None,
          scaling: Optional[YarnScaling] = None) -> jax.Array:
@@ -151,11 +164,7 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
             [rope(x[..., :rotary_dim], positions, theta, scaling=scaling),
              x[..., rotary_dim:]], axis=-1)
     half = x.shape[-1] // 2
-    if scaling is None:
-        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    else:
-        freq = scaling.frequencies(theta, x.shape[-1])
-    ang = positions.astype(jnp.float32)[..., None] * freq    # [(B,) L, half]
+    ang = _rotary_angles(positions, theta, x.shape[-1], scaling)
     if ang.ndim == 2:
         ang = ang[None]
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
@@ -350,21 +359,24 @@ def band_pairs(device_kind: str, length: int, dk: int, dv: int, window: int,
     return inside, visited * block * block
 
 
-def attention_layout(device_kind: str, lq: int, lk: int, dk: int, dv: int,
-                     block_k: int = 512, block_q: Optional[int] = None,
-                     devices: int = 1) -> str:
-    """Where `rotary_attention`'s operands of these sizes lie on their
-    way through `attention_route`'s implementation: "rows", the kernels
-    reading a head as a block of columns of the projection's own [B, L,
-    heads x width] (`attention_pallas.layout`: both widths whole lane
-    tiles); "heads", the kernels on [B, heads, L, width]; "xla", the
-    scan. The entry comes first, the widths second: it is
-    `rotary_attention` that may be token-first, and `blockwise_attention`
-    is head-first on the kernels' route whatever the widths (its callers
-    hold [B, L, H, D]: grouped heads with per-head norms, partial rotary
-    or none, the latent form)."""
-    if attention_route(device_kind, lq, lk, dk, dv, block_k, block_q,
-                       devices) == "xla":
+def attention_layout(device_kind: Optional[str], lq: int, lk: int, dk: int,
+                     dv: int, block_k: int = 512,
+                     block_q: Optional[int] = None, devices: int = 1,
+                     window: Optional[int] = None) -> str:
+    """Where the operands of `rotary_attention` (fused [q | k | v], as
+    many key/value heads as query heads) and of `grouped_attention` (q,
+    k, v of three products, grouped heads, a window or none) lie at these
+    sizes on their way through `attention_route`'s implementation:
+    "rows", the kernels reading a head as a block of columns of the
+    projection's own [B, L, heads x width] (`attention_pallas.layout`:
+    both widths whole lane tiles); "heads", the kernels on [B, heads, L,
+    width]; "xla", the scan. The entry comes first, the widths second:
+    those two may be token-first, and `blockwise_attention` is head-first
+    on the kernels' route whatever the widths (its callers hold [B, L,
+    H, D]: heads with norms of their own, widths off the lane tiles, the
+    latent form). `device_kind` None: this process's device."""
+    if attention_route(device_kind or _device_kind(), lq, lk, dk, dv,
+                       block_k, block_q, devices, window) == "xla":
         return "xla"
     return attention_pallas.layout(dk, dv)
 
@@ -381,8 +393,9 @@ _ROUTES: contextvars.ContextVar[
 @contextlib.contextmanager
 def routes_into(routes: Set[str],
                 layouts: Optional[Set[str]] = None) -> Iterator[None]:
-    """While the block runs (a trace), every `blockwise_attention` or
-    `rotary_attention` call adds the route it took to `routes` and, on
+    """While the block runs (a trace), every `blockwise_attention`,
+    `rotary_attention` or `grouped_attention` call adds the route it took
+    to `routes` and, on
     the kernels' route, where they read a head ("rows" | "heads":
     `attention_layout`) to `layouts`."""
     token = _ROUTES.set((routes, layouts))
@@ -427,9 +440,9 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     `devices` (how many devices the calling program is traced for: a
     mesh's size) whether the blocks are folded by Pallas kernels (with
     blocks of their own, on operands head-first behind a transpose:
-    `rotary_attention` is the entry that keeps them token-first) or by
-    a scan of XLA operations. With a `window` (causal, >= 1) a query
-    sees its own key and the window - 1 before it
+    `rotary_attention` and `grouped_attention` are the entries that keep
+    them token-first) or by a scan of XLA operations. With a `window`
+    (causal, >= 1) a query sees its own key and the window - 1 before it
     (`attention_pallas.sees`); block pairs wholly behind the band are
     visited on neither route."""
     if window is not None and not (causal and window >= 1):
@@ -488,7 +501,7 @@ def rotary_attention(qkv: jax.Array, heads: int, theta: float,
     so, and so they do on the other routes, where this is not read."""
     b, l, width = qkv.shape
     d = width // (3 * heads)
-    if attention_layout(_device_kind(), l, l, d, d, block_k, block_q,
+    if attention_layout(None, l, l, d, d, block_k, block_q,
                         devices) != "rows":
         return blockwise_attention(
             *split_heads(qkv, heads, jnp.arange(l), theta), block_k=block_k,
@@ -503,6 +516,83 @@ def rotary_attention(qkv: jax.Array, heads: int, theta: float,
         key_mask = jnp.pad(key_mask, ((0, 0), (0, pad)))
     return attention_pallas.rotary_attention_pallas(
         qkv, key_mask, heads, theta, causal, grad_dtype=grad_dtype)[:, :l]
+
+
+def rotary_tables(length: int, width: int, theta: float,
+                  rotary_dim: Optional[int] = None,
+                  scaling: Optional[YarnScaling] = None):
+    """`rope` on positions 0 .. L - 1 as tables for a head's columns
+    where they lie -> ((cos, a signed sine a roll) [L, width] float32,
+    the rolls' distances): a head x [., width] turns into x cos + sum_s
+    roll(x, s) sin_s, roll(x, s)[i] = x[i - s]. Column i of the leading
+    `rotary_dim` turns with column i +- rotary_dim / 2: at the whole
+    width both partners are one roll by half of it and the one sine is
+    [-sin | sin]; at a part of it the lower half's partner lies a roll
+    by width - rotary_dim / 2 away and the upper half's a roll by
+    rotary_dim / 2, each sine 0 off its half, and the passing columns
+    carry cos 1. A `scaling`'s frequencies and amplitude are in the
+    numbers (`YarnScaling`), the angles, cosines and sines `rope`'s own
+    float32 expressions."""
+    rotary_dim = width if rotary_dim is None else min(rotary_dim, width)
+    half, rest = rotary_dim // 2, width - rotary_dim
+    ang = _rotary_angles(jnp.arange(length), theta, rotary_dim, scaling)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None:
+        cos, sin = (t * scaling.amplitude() for t in (cos, sin))
+    none = jnp.zeros_like(sin)
+    passing = lambda fill: jnp.full((length, rest), fill, jnp.float32)
+    cos = jnp.concatenate([cos, cos, passing(1.0)], axis=-1)
+    if not rest:
+        return (cos, jnp.concatenate([-sin, sin], axis=-1)), (half,)
+    return (cos, jnp.concatenate([-sin, none, passing(0.0)], axis=-1),
+            jnp.concatenate([none, sin, passing(0.0)], axis=-1)), \
+        (width - half, half)
+
+
+def grouped_attention(q: jax.Array, k: jax.Array, v: jax.Array, width: int,
+                      gate: Optional[jax.Array] = None,
+                      theta: Optional[float] = None,
+                      rotary_dim: Optional[int] = None,
+                      scaling: Optional[YarnScaling] = None,
+                      window: Optional[int] = None, block_k: int = 512,
+                      key_mask: Optional[jax.Array] = None,
+                      operand_dtype=None) -> jax.Array:
+    """Causal attention of grouped query heads on three projections'
+    outputs, token-first from them to its output: q [B, L, H x width],
+    k, v [B, L, Hkv x width] -> [B, L, H x width], key/value head j
+    serving the query heads [j H / Hkv, (j + 1) H / Hkv); rotary
+    positions 0 .. L - 1 at base `theta` as `rope` turns them
+    (`rotary_dim`, `scaling`; `theta` None: none), under a `window` the
+    band `blockwise_attention` takes, `gate` [B, L, H] a sigmoid gate of
+    one column a head on the output. Only for the sizes and the program
+    `attention_layout` answers "rows" for: the caller asks it, once, and
+    elsewhere holds heads apart and calls `blockwise_attention` (this
+    entry has no other route to fall back on). One pass rotates and
+    rounds, the kernels read a head as a block of columns, one pass
+    gates, and the gradients come back as three arrays
+    (`attention_pallas.grouped_attention_pallas`). `operand_dtype`:
+    `rotary_attention`'s `grad_dtype` and its rule, for everything the
+    caller's products alone read: the three projections' gradients and
+    the gated output, `@ wo`'s operand (a caller whose products run at
+    the TPU's default precision may say bfloat16: they round these so
+    themselves); the results are float32 either way."""
+    b, l, _ = q.shape
+    assert attention_pallas.layout(width, width) == "rows", width
+    _hear("rows")
+    _, _, _, pad = _blocks_and_pads(l, l, block_k, None)
+    if key_mask is None:
+        key_mask = jnp.ones((b, l), bool)
+    if pad:     # pad keys are masked out, pad queries cut off the result
+        q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in (q, k, v))
+        key_mask = jnp.pad(key_mask, ((0, 0), (0, pad)))
+        if gate is not None:
+            gate = jnp.pad(gate, ((0, 0), (0, pad), (0, 0)))
+    tables, shifts = ((), ()) if theta is None else rotary_tables(
+        l + pad, width, theta, rotary_dim, scaling)
+    return attention_pallas.grouped_attention_pallas(
+        q, k, v, gate, key_mask, tables,
+        (q.shape[-1] // width, k.shape[-1] // width), shifts, window,
+        operand_dtype=operand_dtype)[:, :l]
 
 
 def _ring_attention_local(q, k, v, key_mask, *, axis: str, causal: bool,

@@ -28,15 +28,19 @@ own (`WINDOW_BLOCK`) and the kernels are named `window_attention_pallas_*`.
 
 Two layouts of the operands in HBM, one kernel pair: a head's block is
 [block, width] of VMEM either way, and only the index maps know where it
-came from. The entry chooses. `flash_attention_pallas` takes them
-head-first, q [B, H, L, Dk]: a head is an index of axis 1 (any width the
-kernels tile, grouped query heads). `rotary_attention_pallas` takes a
-projection's output token-first, q [B, L, H x Dk]: a head is a block of
-columns, which Mosaic takes where a head's width is a whole number of
-lane tiles (`layout`: its caller asks first), with as many key/value
-heads as query heads. There nothing between a projection and a kernel
-changes an array's layout, and one pass lies between them: rotary
-positions and the rounding.
+came from. The entry chooses. `flash_attention_pallas` and
+`window_attention_pallas` take them head-first, q [B, H, L, Dk]: a head
+is an index of axis 1 (any width the kernels tile, grouped query heads).
+Two entries take a projection's output token-first, q [B, L, H x Dk]: a
+head is a block of columns, which Mosaic takes where a head's width is a
+whole number of lane tiles (`layout`: their callers ask first).
+`rotary_attention_pallas` takes one product's [q | k | v] with as many
+key/value heads as query heads; `grouped_attention_pallas` three
+products' q, k, v at any head counts (key/value head h // group a block
+of its own array's columns), whole-causal or under a window, with
+whichever rotary table and a gate of one column a head. There nothing
+between a projection and a kernel changes an array's layout, and one
+pass lies between them: rotary positions and the rounding.
 
 Precision: every product takes its operands in bfloat16 (what the TPU's
 default does to float32 operands), rounded once on their way in, and
@@ -345,13 +349,15 @@ def _specs(bq, bk, group=1, rows=False):
 
 def _sizes(q, k, v, heads):
     """(batch, query heads, key/value heads, lq, lk, dk, dv) of operands
-    head-first (`heads` None) or token-first [B, 1, L, heads x width]
-    (as many key/value heads as query heads: the one caller's)."""
+    head-first (`heads` None) or token-first, q [B, 1, L, H x Dk], k, v
+    [B, 1, L, Hkv x .] (`heads` = (H, Hkv): the shapes alone do not say
+    where one head ends)."""
     if heads is None:
         return (q.shape[0], q.shape[1], k.shape[1], q.shape[2], k.shape[2],
                 q.shape[3], v.shape[3])
-    return (q.shape[0], heads, heads, q.shape[2], k.shape[2],
-            q.shape[3] // heads, v.shape[3] // heads)
+    h, kv = heads
+    return (q.shape[0], h, kv, q.shape[2], k.shape[2], q.shape[3] // h,
+            v.shape[3] // kv)
 
 
 def _shape(b, h, length, width, rows):
@@ -421,7 +427,10 @@ def _backward(q, k, v, key_mask, d_out, lse, delta, causal, bq, bk,
     seen = -(-lq // bk) * bk      # causal keys past every query: no pair
     if causal and seen < lk:
         d_k, d_v = (t.at[:, :, seen:].set(0.0) for t in (d_k, d_v))
-    if group > 1:       # a key/value head's gradient: its query heads' sum
+    if group > 1 and not rows:
+        # a key/value head's gradient: its query heads' sum. (Token-first
+        # they stay a block of columns a query head, which the pass behind
+        # the kernels adds up as it reads them: `_grouped_back`.)
         d_k, d_v = (t.reshape(b, h // group, group, lk, -1).sum(axis=2)
                     for t in (d_k, d_v))
     return dq, d_k, d_v
@@ -436,8 +445,9 @@ def flash_attention_pallas(q, k, v, key_mask, causal: bool,
     it lies, and the backward kernel's per-query-head `dk`, `dv` are
     summed over the group after it), lengths multiples of 128; key_mask
     [B, Lk] bool, False = padding -> [B, H, Lq, Dv] in that dtype.
-    The operands lie head-first; `rotary_attention_pallas` is the entry
-    that reaches the same kernels token-first.
+    The operands lie head-first; `rotary_attention_pallas` and
+    `grouped_attention_pallas` (grouped heads too) are the entries that
+    reach the same kernels token-first.
     `interpret` runs the kernels in the Pallas interpreter (the CPU
     tests). `window_attention_pallas` is the same call over a band."""
     return _fwd(q, k, v, key_mask, causal, interpret, save_lse=False)[0]
@@ -446,10 +456,10 @@ def flash_attention_pallas(q, k, v, key_mask, causal: bool,
 def _fwd(q, k, v, key_mask, causal, interpret, save_lse=True, heads=None,
          dtype=None, window=None):
     """-> (the output in `dtype`, the operands' by default; what the
-    backward pass keeps). With `heads` = H the operands lie token-first,
-    where a projection writes them (`layout` "rows"): q, k [B, L, H x
-    Dk], v [B, L, H x Dv] -> [B, L, H x Dv], and so do `_bwd`'s
-    gradients."""
+    backward pass keeps). With `heads` = (H, Hkv) the operands lie
+    token-first, where a projection writes them (`layout` "rows"): q [B,
+    L, H x Dk], k [B, L, Hkv x Dk], v [B, L, Hkv x Dv] -> [B, L, H x Dv],
+    and so do `_bwd`'s gradients."""
     if not q.dtype == k.dtype == v.dtype:
         raise ValueError(f"q, k, v of one dtype expected, got {q.dtype}, "
                          f"{k.dtype}, {v.dtype}")
@@ -466,12 +476,19 @@ def _fwd(q, k, v, key_mask, causal, interpret, save_lse=True, heads=None,
     return shown, (*ops, key_mask, out, lse[0][..., 0])
 
 
-def _bwd(causal, interpret, res, d_out, heads=None, window=None):
+def _bwd(causal, interpret, res, d_out, heads=None, window=None, delta=None):
+    """-> (dq, dk, dv, None) in the output's type. Token-first under
+    grouped heads `dk` and `dv` are [B, L, H x .], a block of columns a
+    QUERY head (`_grouped_back` adds a group's up). `delta` [B, H, Lq]:
+    each head's sum of d_out x out over its width, from the caller that
+    has it already."""
     q, k, v, key_mask, out, lse = res
     if heads is not None:
         d_out = d_out[:, None]
-    delta = _head_sums(d_out.astype(jnp.float32) * out.astype(jnp.float32),
-                       heads)                                   # [B, H, Lq]
+    if delta is None:
+        delta = _head_sums(
+            d_out.astype(jnp.float32) * out.astype(jnp.float32),
+            None if heads is None else heads[0])                # [B, H, Lq]
     grads = _backward(q, k, v, key_mask, d_out.astype(jnp.bfloat16), lse,
                       delta, causal, _block(q.shape[2], window),
                       _block(k.shape[2], window), interpret, heads, window)
@@ -642,15 +659,266 @@ def _rotary_attention_fwd(qkv, key_mask, heads, theta, causal, interpret,
                           grad_dtype=None, save_lse=True):
     table = rotary_table(qkv.shape[1], qkv.shape[2] // (3 * heads), theta)
     return _fwd(*_rotary(qkv, *table, heads, interpret), key_mask, causal,
-                interpret, save_lse, heads, qkv.dtype)
+                interpret, save_lse, (heads, heads), qkv.dtype)
 
 
 def _rotary_attention_bwd(heads, theta, causal, interpret, grad_dtype, res,
                           d_out):
-    dq, dk, dv, _ = _bwd(causal, interpret, res, d_out, heads)
+    dq, dk, dv, _ = _bwd(causal, interpret, res, d_out, (heads, heads))
     table = rotary_table(dq.shape[1], dq.shape[2] // heads, theta)
     return _rotary_backward(dq, dk, dv, *table, heads, interpret,
                             grad_dtype or dq.dtype).astype(dq.dtype), None
 
 
 rotary_attention_pallas.defvjp(_rotary_attention_fwd, _rotary_attention_bwd)
+
+
+# -- grouped heads token-first: the passes around the kernels ------------
+#
+# q [B, L, H x D] and k, v [B, L, Hkv x D] are three products' outputs (a
+# gate of one column a head, [B, L, H], a fourth's). One pass in front of
+# the kernels turns q and k and rounds all three (`_grouped_front`), one
+# behind them adds a group's `dk`, `dv` up and turns the gradients back
+# (`_grouped_back`), and the gate is a pass over the output's columns
+# (`_gated`). Head counts are grids and rotary variants tables: a body
+# knows a head's width and the rolls' distances.
+
+def _turned(x, tables, shifts, back=False):
+    """x [rows, D] float32 against the tables' blocks (refs: cos, then a
+    signed sine a roll): x cos + sum_s roll(x, s) sin_s, the partner of
+    column i the column i - s; `back`, its transpose. No table: x."""
+    if not shifts:
+        return x
+    width = x.shape[-1]
+    out = x * tables[0][...]
+    for sin_ref, shift in zip(tables[1:], shifts):
+        out += pltpu.roll(x * sin_ref[...], width - shift, 1) if back \
+            else pltpu.roll(x, shift, 1) * sin_ref[...]
+    return out
+
+
+def _grouped_front_kernel(q_ref, k_ref, v_ref, *refs, shifts):
+    *tables, qo_ref, ko_ref, vo_ref = refs
+    qo_ref[0] = _turned(q_ref[0], tables, shifts).astype(qo_ref.dtype)
+
+    @pl.when(pl.program_id(3) == 0)     # the group's first query head
+    def _():
+        ko_ref[0] = _turned(k_ref[0], tables, shifts).astype(ko_ref.dtype)
+        vo_ref[0] = v_ref[0].astype(vo_ref.dtype)
+
+
+def _grouped_back_kernel(dq_ref, dk_ref, dv_ref, *refs, shifts):
+    *tables, qo_ref, ko_ref, vo_ref, k_scr, v_scr = refs
+    g = pl.program_id(3)
+    qo_ref[0] = _turned(dq_ref[0], tables, shifts, True).astype(qo_ref.dtype)
+
+    @pl.when(g == 0)
+    def _():
+        k_scr[...] = dk_ref[0]
+        v_scr[...] = dv_ref[0]
+
+    @pl.when(g > 0)
+    def _():
+        k_scr[...] += dk_ref[0]
+        v_scr[...] += dv_ref[0]
+
+    @pl.when(g == pl.num_programs(3) - 1)   # the group's sum is whole
+    def _():
+        ko_ref[0] = _turned(k_scr[...], tables, shifts, True).astype(
+            ko_ref.dtype)
+        vo_ref[0] = v_scr[...].astype(vo_ref.dtype)
+
+
+def _grouped_pass(kernel, name, arrays, tables, heads, shifts, per_query,
+                  dtype, interpret, scratch=False):
+    """A pass over three arrays' columns: a grid over (batch row, row
+    block, key/value head, query head of its group), a head's block
+    [rows, D] a step. The first array is q's (a block a step); the other
+    two k's and v's, [B, L, Hkv x D] (a block a group) or, `per_query`,
+    a block a query head like q's -> three arrays in `dtype`, H, Hkv and
+    Hkv heads wide."""
+    (h, kv), (b, l, width) = heads, arrays[0].shape
+    d, rows, group = width // h, _block(l), h // kv
+    by_query = pl.BlockSpec((1, rows, d),
+                            lambda b, i, j, g: (b, i, j * group + g))
+    by_group = pl.BlockSpec((1, rows, d), lambda b, i, j, g: (b, i, j))
+    table = pl.BlockSpec((rows, d), lambda b, i, j, g: (i, 0))
+    taken = by_query if per_query else by_group
+    return pl.pallas_call(
+        functools.partial(kernel, shifts=shifts), name=name,
+        grid=(b, l // rows, kv, group),
+        in_specs=[by_query, taken, taken] + [table] * len(tables),
+        out_specs=[by_query, by_group, by_group],
+        out_shape=[jax.ShapeDtypeStruct((b, l, n * d), dtype)
+                   for n in (h, kv, kv)],
+        scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32)] * 2
+        if scratch else [],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret)(*arrays, *tables)
+
+
+def _grouped_front(q, k, v, tables, heads, shifts, interpret):
+    """q [B, L, H x D], k, v [B, L, Hkv x D] float32, read where three
+    products wrote them -> the same in bfloat16, q and k turned in
+    float32 against `tables` (cos, a signed sine a roll of `shifts`:
+    `ops/attention.rotary_tables`; none: no rotary positions), each
+    rounded once."""
+    return _grouped_pass(_grouped_front_kernel, "grouped_attention_front",
+                         (q, k, v), tables, heads, shifts, False,
+                         jnp.bfloat16, interpret)
+
+
+def _grouped_back(dq, dk, dv, tables, heads, shifts, interpret,
+                  dtype=jnp.float32):
+    """The kernels' float32 dq, dk, dv [B, L, H x D], `dk` and `dv` a
+    block of columns a query head -> the gradients of q [B, L, H x D], k
+    and v [B, L, Hkv x D] in `dtype`: a group's blocks added up in VMEM
+    in float32 as they are read, the rotation transposed in float32, one
+    rounding where `dtype` is narrower."""
+    return _grouped_pass(_grouped_back_kernel, "grouped_attention_back",
+                         (dq, dk, dv), tables, heads, shifts, True, dtype,
+                         interpret, scratch=True)
+
+
+def _gate_kernel(x_ref, s_ref, *refs, width):
+    """A block of n heads of x times their columns of s; behind a second
+    operand a (the backward pass: x is the cotangent, a the kernels'
+    output), each head's sum of x a over its width too."""
+    a_ref, o_ref, u_ref = refs if len(refs) == 3 else (None, *refs, None)
+    for g in range(s_ref.shape[-1]):
+        cols = slice(g * width, (g + 1) * width)
+        x = x_ref[0, :, cols]
+        o_ref[0, :, cols] = (x * s_ref[0, 0, :, g:g + 1]).astype(o_ref.dtype)
+        if a_ref is not None:
+            u_ref[0, 0, :, g:g + 1] = (x * a_ref[0, :, cols]).sum(
+                axis=1, keepdims=True)
+
+
+def _gated(x, s, dtype, interpret, a=None):
+    """x [B, L, H x D] float32 times s [B, L, H], a head's column spread
+    over its width, in float32 -> `dtype`, with no head axis: a grid
+    over (batch row, row block, block of up to 8 heads), s laid out a
+    block of heads at a time ([B, H / n, L, n]: 1 / D of x's bytes).
+    With `a` [B, L, H x D] float32 -> (that, each head's sum of x a over
+    its width [B, L, H] float32), x read once for both."""
+    (b, l, width), h = x.shape, s.shape[-1]
+    n = next(n for n in (8, 4, 2, 1) if h % n == 0)
+    d, rows = width // h, _block(l)
+    block = pl.BlockSpec((1, rows, n * d), lambda b, i, c: (b, i, c))
+    heads = pl.BlockSpec((1, 1, rows, n), lambda b, i, c: (b, c, i, 0))
+    by_block = lambda t: jnp.swapaxes(t.reshape(b, l, h // n, n), 1, 2)
+    out = jax.ShapeDtypeStruct(x.shape, dtype)
+    sums = jax.ShapeDtypeStruct((b, h // n, l, n), jnp.float32)
+    got = pl.pallas_call(
+        functools.partial(_gate_kernel, width=d),
+        name="attention_head_gate" if a is None else "attention_head_gate_bwd",
+        grid=(b, l // rows, h // n),
+        in_specs=[block, heads] + ([] if a is None else [block]),
+        out_specs=block if a is None else [block, heads],
+        out_shape=out if a is None else [out, sums],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret)(x, by_block(s), *(() if a is None else (a,)))
+    if a is None:
+        return got
+    return got[0], jnp.swapaxes(got[1], 1, 2).reshape(b, l, h)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def grouped_attention_pallas(q, k, v, gate, key_mask, tables, heads,
+                             shifts=(), window: Optional[int] = None,
+                             interpret: bool = False, operand_dtype=None):
+    """Causal attention of grouped query heads from its projections to
+    its (gated) output, token-first all the way: q [B, L, H x D], k, v
+    [B, L, Hkv x D] float32 as `x @ wq`, `x @ wk`, `x @ wv` wrote them
+    (`heads` = (H, Hkv), Hkv a divisor of H; D a whole number of lane
+    tiles, L a multiple of 128), key_mask [B, L] bool -> [B, L, H x D]
+    float32; under a `window` over the band (`window_attention_pallas`'s
+    kernels). `tables` (cos, a signed sine a roll) [L, D] with the rolls'
+    distances `shifts` are the rotary positions, whatever their variant
+    (`ops/attention.rotary_tables`); () and (): none. `gate` [B, L, H]
+    (or None): the output of head h times sigmoid(gate_h).
+
+    One pass in front of the kernels turns q and k in float32 and rounds
+    q, k, v once to bfloat16 (`grouped_attention_front`); the kernels
+    read a head as a block of its array's columns, a key/value head at
+    column block h // (H / Hkv); the gate is one pass over the output's
+    columns (`attention_head_gate`). Backward the cotangent goes through
+    that pass again (times the sigmoid, rounded once to bfloat16 as the
+    kernels round their operand; `attention_head_gate_bwd`), which also
+    sums d_out x out over each head's width: s times that is the `delta`
+    the backward kernel needs anyway and `delta` (1 - s) the gate's own
+    gradient, with no pass of its own. The
+    kernels' float32 dq, dk, dv go through one pass that adds a
+    group's `dk`, `dv` up and transposes the rotation
+    (`grouped_attention_back`). No array between the products is split
+    into heads, transposed, joined or cast by XLA. `operand_dtype`: the
+    type the caller's products round their operands to, if it names one
+    (`rotary_attention_pallas`'s `grad_dtype`, and its rule): the passes
+    then write what only those products read rounded once to it, the
+    gradients of q, k and v and the gated output (the operand of `@ wo`:
+    the kernels' own `out` stays float32); the results are float32
+    either way."""
+    return _grouped_attention_fwd(q, k, v, gate, key_mask, tables, heads,
+                                  shifts, window, interpret, operand_dtype,
+                                  save_lse=False)[0]
+
+
+# (the two rules' bodies are jitted: layers of one kind, and a layer's
+# forward pass and its recomputation, are ONE traced function and one
+# lowering of its kernels, called from each place. The casts back to
+# float32 stay OUT of the jitted bodies, beside the caller's products that
+# read them: there XLA drops them and hands the products the narrow
+# arrays, as it does with no jit at all)
+def _grouped_attention_fwd(q, k, v, gate, key_mask, tables, heads, shifts,
+                           window, interpret, operand_dtype=None,
+                           save_lse=True):
+    out, saved = _grouped_attention_out(q, k, v, gate, key_mask, tables,
+                                        heads, shifts, window, interpret,
+                                        operand_dtype, save_lse)
+    return out.astype(q.dtype), saved
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+def _grouped_attention_out(q, k, v, gate, key_mask, tables, heads, shifts,
+                           window, interpret, operand_dtype, save_lse):
+    out, res = _fwd(*_grouped_front(q, k, v, tables, heads, shifts,
+                                    interpret), key_mask, True, interpret,
+                    save_lse, heads, q.dtype, window)
+    if gate is None:
+        return out, (res, None, tables)
+    s = jax.nn.sigmoid(gate)
+    return _gated(out, s, operand_dtype or out.dtype, interpret), \
+        (res, s, tables)
+
+
+def _grouped_attention_bwd(heads, shifts, window, interpret, operand_dtype,
+                           saved, d_out):
+    *grads, d_gate = _grouped_attention_grads(
+        heads, shifts, window, interpret, operand_dtype, saved, d_out)
+    return (*(g.astype(d_out.dtype) for g in grads), d_gate, None,
+            tuple(None for _ in saved[2]))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _grouped_attention_grads(heads, shifts, window, interpret, operand_dtype,
+                             saved, d_out):
+    res, s, tables = saved
+    delta = d_gate = None
+    if s is not None:
+        # y = s a: da = s dy, and with u = sum_D(dy a) the kernel's
+        # delta = sum_D(da a) = s u and d gate = u s (1 - s)
+        d_out, u = _gated(d_out, s, jnp.bfloat16, interpret, res[4][:, 0])
+        delta = jnp.swapaxes(s * u, 1, 2)                   # [B, H, L]
+        d_gate = s * u * (1.0 - s)
+    dq, dk, dv, _ = _bwd(True, interpret, res, d_out, heads, window, delta)
+    return (*_grouped_back(dq, dk, dv, tables, heads, shifts, interpret,
+                           operand_dtype or dq.dtype), d_gate)
+
+
+grouped_attention_pallas.defvjp(_grouped_attention_fwd,
+                                _grouped_attention_bwd)

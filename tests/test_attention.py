@@ -137,7 +137,14 @@ def _pallas_case(b, h, lq, lk, dk, dv, left_pad, seed, group=1):
 _FORWARD_TOL, _GRAD_TOL = 2e-3, 6e-3
 
 
-@pytest.mark.parametrize("name,lq,lk,dk,dv,causal,left_pad,group,layout", [
+def _with_window_and_gate(cases):
+    """(..., window, gated) behind the cases that name neither."""
+    return [c + (None, False)[len(c) - 9:] for c in cases]
+
+
+@pytest.mark.parametrize(
+    "name,lq,lk,dk,dv,causal,left_pad,group,layout,window,gated",
+    _with_window_and_gate([
     ("causal", 384, 384, 24, 16, True, 0, 1, "heads"),   # 3 x 3 blocks of 128
     ("full", 384, 384, 24, 16, False, 0, 1, "heads"),
     ("one-block", 256, 256, 24, 16, True, 0, 1, "heads"),
@@ -163,107 +170,206 @@ _FORWARD_TOL, _GRAD_TOL = 2e-3, 6e-3
     ("rows-left-padding-and-masked-rows", 384, 384, 128, 128, True, 140, 1,
      "rows"),
     ("rows-left-padding-full", 384, 384, 128, 128, False, 130, 1, "rows"),
-])
+    # token-first under grouped heads (`grouped_attention_pallas`, no
+    # rotary table): q [B, L, H x D] over k, v [B, L, H / group x D], the
+    # whole triangle and a band, against the head-first call
+    ("rows-group-4", 384, 384, 128, 128, True, 0, 4, "rows"),
+    ("rows-group-6-left-padding", 384, 384, 128, 128, True, 140, 6, "rows"),
+    ("rows-group-8", 256, 256, 128, 128, True, 3, 8, "rows"),
+    ("rows-group-4-of-256", 256, 256, 256, 256, True, 0, 4, "rows"),
+    ("rows-group-8-window-of-a-block", 384, 384, 128, 128, True, 0, 8,
+     "rows", 128),
+    ("rows-group-6-window-under-a-block", 384, 384, 128, 128, True, 140, 6,
+     "rows", 100),
+    ("rows-group-4-of-256-window", 256, 256, 256, 256, True, 5, 4, "rows",
+     37),
+    # the gate of a column a head: its gradient is `delta` (1 - s), no
+    # pass of its own, against autodiff of att * sigmoid(gate)[..., None]
+    ("rows-group-4-gated", 384, 384, 128, 128, True, 0, 4, "rows", None,
+     True),
+    ("rows-group-6-gated-left-padding", 256, 256, 128, 128, True, 140, 6,
+     "rows", None, True),
+    ("rows-group-8-gated-window", 384, 384, 128, 128, True, 9, 8, "rows",
+     100, True),
+    ("rows-ungrouped-gated-of-256", 256, 256, 256, 256, True, 0, 1, "rows",
+     None, True),
+]))
 def test_pallas_kernels_match_dense(name, lq, lk, dk, dv, causal, left_pad,
-                                    group, layout):
+                                    group, layout, window, gated):
     """Forward and jax.grad of the kernels against `mha`, on operands
     head-first (`flash_attention_pallas`) or token-first (the kernels as
     `rotary_attention_pallas` reaches them, float32 gradients: q and k
-    are turned by `rope` and rounded once on the dense side too)."""
+    are turned by `rope` and rounded once on the dense side too). Grouped,
+    banded or gated token-first operands (the kernels as
+    `grouped_attention_pallas` reaches them) against the head-first
+    kernels on the same operands, the gate applied on [B, L, H, D]."""
     from predictionio_tpu.ops import attention_pallas
     from predictionio_tpu.ops.attention import rope
-    from predictionio_tpu.ops.attention_pallas import flash_attention_pallas
+    from predictionio_tpu.ops.attention_pallas import (
+        flash_attention_pallas, window_attention_pallas,
+    )
 
     heads = 2 * group
+    grouped = layout == "rows" and (group > 1 or window is not None
+                                    or gated)
     if layout == "rows":
         assert attention_pallas.layout(dk, dv) == "rows"
-        assert lq == lk and dk == dv and group == 1
+        assert lq == lk and dk == dv and (group == 1 or grouped)
     q, k, v, w, mask = _pallas_case(2, heads, lq, lk, dk, dv, left_pad,
                                     seed=len(name), group=group)
+    gate = jnp.asarray(np.random.default_rng(lq + group).normal(
+        size=(2, lq, heads)), jnp.float32) if gated else None
     heads_first = lambda t: jnp.swapaxes(t, 1, 2)
     flat = lambda t: t.reshape(*t.shape[:2], -1)
 
-    def kernel(q, k, v):
+    def head_first(q, k, v):
+        ops = heads_first(q), heads_first(k), heads_first(v), mask
+        if window is not None:
+            return heads_first(window_attention_pallas(*ops, window, True))
+        return heads_first(flash_attention_pallas(*ops, causal, True))
+
+    def kernel(q, k, v, *gate):
+        if grouped:
+            return attention_pallas.grouped_attention_pallas(
+                flat(q), flat(k), flat(v), gate[0] if gate else None, mask,
+                (), (heads, heads // group), (), window, True).reshape(
+                    2, lq, heads, dv)
         if layout == "rows":
             return attention_pallas.rotary_attention_pallas(
                 jnp.concatenate([flat(q), flat(k), flat(v)], axis=-1), mask,
                 heads, 1e4, causal, True).reshape(2, lq, heads, dv)
-        return heads_first(flash_attention_pallas(
-            heads_first(q), heads_first(k), heads_first(v), mask, causal,
-            True))
+        return head_first(q, k, v)
 
-    def dense(q, k, v):
+    def dense(q, k, v, *gate):
+        if grouped:
+            out = head_first(q, k, v)
+            return out * jax.nn.sigmoid(gate[0])[..., None] if gate else out
         if layout == "rows":
             q, k = (rope(t, jnp.arange(lq), 1e4).astype(jnp.bfloat16).astype(
                 jnp.float32) for t in (q, k))
         k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
         return mha(q, k, v, causal=causal, key_mask=mask)
 
-    got, want = kernel(q, k, v), dense(q, k, v)
+    ops = (q, k, v) + ((gate,) if gated else ())
+    got, want = kernel(*ops), dense(*ops)
     np.testing.assert_allclose(
         got, want, atol=_FORWARD_TOL * float(jnp.abs(want).max()))
     if causal and left_pad:
         assert not np.asarray(got[0, :left_pad]).any()     # masked rows: 0
-    grads = jax.grad(lambda *a: (kernel(*a) * w).sum(), (0, 1, 2))(q, k, v)
-    wants = jax.grad(lambda *a: (dense(*a) * w).sum(), (0, 1, 2))(q, k, v)
+    wrt = tuple(range(len(ops)))
+    grads = jax.grad(lambda *a: (kernel(*a) * w).sum(), wrt)(*ops)
+    wants = jax.grad(lambda *a: (dense(*a) * w).sum(), wrt)(*ops)
+    assert len(grads) == (4 if gated else 3)
     for g, want_g in zip(grads, wants):
         assert np.isfinite(np.asarray(g)).all()
         np.testing.assert_allclose(
             g, want_g, atol=_GRAD_TOL * float(jnp.abs(want_g).max()))
 
 
-@pytest.mark.parametrize("heads,width,length,left_pad", [
-    (2, 128, 256, 0), (3, 128, 384, 5), (2, 256, 128, 0)])
+_YARN = dict(factor=64.0, original_max_len=64, beta_fast=64.0, beta_slow=1.0,
+             attention_factor=1.4158883083359672)
+
+
+@pytest.mark.parametrize("heads,width,length,kv_heads,rotary_dim,yarn", [
+    (2, 128, 256, 2, 128, False), (3, 128, 384, 3, 128, False),
+    (2, 256, 128, 2, 256, False),
+    # three products' outputs (`_grouped_front`, `_grouped_back`): fewer
+    # key/value heads than query heads; a rotary part of the width (64 of
+    # 128: a column's partner lies 32 lanes away; 64 of 256); a YaRN
+    # table with its amplitude; no rotary positions at all
+    (8, 128, 256, 2, 128, False), (4, 128, 256, 2, 64, False),
+    (6, 128, 256, 1, 64, True), (8, 128, 128, 8, 128, True),
+    (4, 256, 128, 2, 64, False), (4, 128, 128, 1, None, False)])
 def test_rotary_on_the_flat_columns_is_rope_on_heads(heads, width, length,
-                                                     left_pad):
-    """The pass between a projection and the token-first kernels
-    (`attention_pallas._rotary`, interpreted): q and k of [q | k | v]
-    rotated where they lie equal `rope` on [B, L, H, D], v passes, each
-    rounded once to bfloat16; its transpose (`_rotary_backward`) is
-    `rope`'s gradient to float32 round-off, in float32 unless it is
-    told a narrower type, and then that gradient rounded once."""
+                                                     kv_heads, rotary_dim,
+                                                     yarn):
+    """The pass between the projections and the token-first kernels
+    (interpreted): q and k rotated where they lie equal `rope` on [B, L,
+    H, D], v passes, each rounded once to bfloat16; its transpose is
+    `rope`'s gradient to float32 round-off, in float32 unless it is told
+    a narrower type, and then that gradient rounded once. Of one product's
+    [q | k | v] at the whole width (`attention_pallas._rotary`,
+    `_rotary_backward`) and of three products' q, k, v at any head counts
+    and any of `rope`'s variants, which are numbers of
+    `ops/attention.rotary_tables` (`_grouped_front`; `_grouped_back`,
+    which is handed `dk` and `dv` a block of columns a QUERY head and adds
+    a group's up)."""
     from predictionio_tpu.ops import attention_pallas
-    from predictionio_tpu.ops.attention import rope
+    from predictionio_tpu.ops.attention import (
+        YarnScaling, rope, rotary_tables,
+    )
 
     theta = 1e6
     rng = np.random.default_rng(heads + width)
-    qkv = jnp.asarray(rng.normal(size=(2, length, 3 * heads * width)),
-                      jnp.float32)
-    table = attention_pallas.rotary_table(length, width, theta)
-    split = lambda t: tuple(p.reshape(2, length, heads, width)
-                            for p in jnp.split(t, 3, axis=-1))
-    positions = jnp.arange(length)
+    scaling = YarnScaling(**_YARN) if yarn else None
+    positions, group = jnp.arange(length), heads // kv_heads
+    draw = lambda n: jnp.asarray(rng.normal(size=(2, length, n * width)),
+                                 jnp.float32)
+    by_head = lambda t: t.reshape(2, length, -1, width)
 
-    def turned(qkv):
-        q, k, v = split(qkv)
-        return rope(q, positions, theta), rope(k, positions, theta), v
+    def turn(t):
+        if rotary_dim is None:
+            return by_head(t)
+        return rope(by_head(t), positions, theta, rotary_dim, scaling)
 
-    got = attention_pallas._rotary(qkv, *table, heads, True)
-    for g, want in zip(got, turned(qkv)):
-        assert g.dtype == jnp.bfloat16 and g.shape == (2, length,
-                                                       heads * width)
-        want = want.reshape(g.shape)
-        # float32 round-off (a fused multiply-add or not) before one
-        # rounding to bfloat16: a value may land on the neighbour
-        np.testing.assert_allclose(g.astype(jnp.float32), want,
+    def close(got, want, dtype):
+        """float32 round-off (a fused multiply-add or not), then the one
+        rounding to `dtype`: a value may land on the neighbour."""
+        want = want.reshape(got.shape)
+        assert got.dtype == dtype
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(
+                got, want, atol=2e-6 * float(jnp.abs(want).max()))
+            return
+        np.testing.assert_allclose(got.astype(jnp.float32), want,
                                    atol=2 ** -8 * float(jnp.abs(want).max()))
-        assert float((g == want.astype(jnp.bfloat16)).mean()) > 0.999
-    grads = tuple(jnp.asarray(rng.normal(size=g.shape), jnp.float32)
-                  for g in got)
-    want = jax.vjp(turned, qkv)[1](
-        tuple(g.reshape(2, length, heads, width) for g in grads))[0]
-    back = attention_pallas._rotary_backward(*grads, *table, heads, True)
-    assert back.dtype == jnp.float32
-    np.testing.assert_allclose(back, want,
-                               atol=1e-6 * float(jnp.abs(want).max()))
-    # float32 round-off, then the one rounding the backward products of
-    # a projection at the default precision would give it
-    rounded = attention_pallas._rotary_backward(*grads, *table, heads, True,
-                                                jnp.bfloat16)
-    assert rounded.dtype == jnp.bfloat16
-    assert float((rounded == back.astype(jnp.bfloat16)).mean()) > 0.999
-    np.testing.assert_allclose(rounded.astype(jnp.float32), want,
-                               atol=2 ** -8 * float(jnp.abs(want).max()))
+        assert float((got == want.astype(dtype)).mean()) > 0.999
+
+    if heads == kv_heads and rotary_dim == width and not yarn:
+        qkv = draw(3 * heads)
+        table = attention_pallas.rotary_table(length, width, theta)
+
+        def turned(qkv):
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            return turn(q), turn(k), by_head(v)
+
+        got = attention_pallas._rotary(qkv, *table, heads, True)
+        for g, want in zip(got, turned(qkv)):
+            assert g.shape == (2, length, heads * width)
+            close(g, want, jnp.bfloat16)
+        grads = tuple(draw(heads) for _ in got)
+        want = jax.vjp(turned, qkv)[1](tuple(by_head(g) for g in grads))[0]
+        back = attention_pallas._rotary_backward(*grads, *table, heads, True)
+        close(back, want, jnp.float32)
+        # the one rounding the backward products of a projection at the
+        # default precision would give it
+        close(attention_pallas._rotary_backward(*grads, *table, heads, True,
+                                                jnp.bfloat16), want,
+              jnp.bfloat16)
+    tables, shifts = ((), ()) if rotary_dim is None else rotary_tables(
+        length, width, theta, rotary_dim, scaling)
+    assert len(shifts) == (0 if rotary_dim is None
+                           else 1 if rotary_dim == width else 2)
+    q, k, v = draw(heads), draw(kv_heads), draw(kv_heads)
+    sizes = (heads, kv_heads)
+
+    def turned(q, k, v):        # the kernels' operands, a head a query head
+        return turn(q), jnp.repeat(turn(k), group, axis=2), \
+            jnp.repeat(by_head(v), group, axis=2)
+
+    got = attention_pallas._grouped_front(q, k, v, tables, sizes, shifts,
+                                          True)
+    for g, t, want in zip(got, (q, k, v), (turn(q), turn(k), by_head(v))):
+        assert g.shape == t.shape
+        close(g, want, jnp.bfloat16)
+    grads = tuple(draw(heads) for _ in got)
+    wants = jax.vjp(turned, q, k, v)[1](tuple(by_head(g) for g in grads))
+    for dtype in (jnp.float32, jnp.bfloat16):
+        back = attention_pallas._grouped_back(*grads, tables, sizes, shifts,
+                                              True, dtype)
+        for g, t, want in zip(back, (q, k, v), wants):
+            assert g.shape == t.shape
+            close(g, want, dtype)
 
 
 def _interpreted_kernels(monkeypatch):
@@ -282,6 +388,14 @@ def _interpreted_kernels(monkeypatch):
         attention_pallas, "rotary_attention_pallas",
         lambda qkv, mask, heads, theta, causal, grad_dtype=None: rotary(
             qkv, mask, heads, theta, causal, True, grad_dtype))
+    banded = attention_pallas.window_attention_pallas
+    grouped = attention_pallas.grouped_attention_pallas
+    monkeypatch.setattr(
+        attention_pallas, "window_attention_pallas",
+        lambda q, k, v, mask, window: banded(q, k, v, mask, window, True))
+    monkeypatch.setattr(
+        attention_pallas, "grouped_attention_pallas",
+        lambda *a, operand_dtype=None: grouped(*a, True, operand_dtype))
 
 
 def test_rotary_attention_is_one_result_on_every_layout(monkeypatch):
@@ -333,6 +447,97 @@ def test_rotary_attention_is_one_result_on_every_layout(monkeypatch):
         for got, want, tol in zip(rows[2:], other[2:], tols):
             np.testing.assert_allclose(
                 got, want, atol=tol * float(jnp.abs(want).max()))
+
+
+@pytest.mark.parametrize("heads,kv_heads,width,rotary_dim,yarn,window,gated", [
+    (6, 1, 128, 64, True, None, True),      # a full layer of the Laguna cell
+    (8, 1, 128, 128, False, 100, True),     # a sliding one
+    (4, 2, 256, 64, False, None, False),    # 256 wide, a part rotary
+    (4, 1, 128, None, False, None, False),  # no rotary positions, no gate
+])
+def test_grouped_attention_is_one_result_on_both_layouts(
+        monkeypatch, heads, kv_heads, width, rotary_dim, yarn, window, gated):
+    """`grouped_attention` on three projections' outputs, token-first
+    through the kernels, against `rope` on [B, L, H, D],
+    `blockwise_attention` head-first through the same kernels and the
+    gate on [B, L, H, 1]: one result and one gradient of q, k, v and the
+    gate up to the rotation's float32 round-off, with lengths it has to
+    pad (200 -> 256) and a left-padded row; a listener hears "rows"; a
+    named `operand_dtype` rounds the three projections' gradients and
+    the gated output once (float32 arrays of narrow values: what the
+    caller's products alone read) and not the gate's gradient, nor an
+    output with no gate, which is the kernels' own; `attention_layout`, which its caller asks beforehand,
+    says where else there is no such route (a mesh, heads off the lane
+    tiles: those the entry itself refuses)."""
+    from predictionio_tpu.ops import attention
+    from predictionio_tpu.ops.attention import (
+        YarnScaling, attention_layout, grouped_attention, rope,
+    )
+
+    _interpreted_kernels(monkeypatch)
+    rng = np.random.default_rng(45 + heads)
+    l, theta = 200, None if rotary_dim is None else 5e5
+    scaling = YarnScaling(**_YARN) if yarn else None
+    draw = lambda n: jnp.asarray(rng.normal(size=(2, l, n)), jnp.float32)
+    q, k, v = (draw(n * width) for n in (heads, kv_heads, kv_heads))
+    gate, w = draw(heads) if gated else None, draw(heads * width)
+    mask = jnp.asarray(np.arange(l)[None, :] >= np.array([[0], [37]]))
+    layout = lambda width, devices: attention_layout(
+        None, l, l, width, width, 128, devices=devices, window=window)
+    assert layout(width, 1) == "rows" and layout(width, 4) == "xla"
+    assert layout(192, 1) == "heads"
+
+    def rows(q, k, v, gate, operand_dtype=None):
+        return grouped_attention(
+            q, k, v, width, gate=gate, theta=theta, rotary_dim=rotary_dim,
+            scaling=scaling, window=window, block_k=128, key_mask=mask,
+            operand_dtype=operand_dtype)
+
+    def heads_apart(q, k, v, gate):
+        def turn(t):
+            t = t.reshape(2, l, -1, width)
+            return t if theta is None else rope(t, jnp.arange(l), theta,
+                                                rotary_dim, scaling)
+
+        att = blockwise_attention(turn(q), turn(k), v.reshape(2, l, -1, width),
+                                  block_k=128, causal=True, key_mask=mask,
+                                  window=window)
+        if gate is not None:
+            att = att * jax.nn.sigmoid(gate)[..., None]
+        return att.reshape(2, l, -1)
+
+    def run(fn, **named):
+        routes, layouts = set(), set()
+        with attention.routes_into(routes, layouts):
+            out, pull = jax.vjp(lambda *a: fn(*a, **named), q, k, v, gate)
+        assert routes == {"pallas"}
+        return layouts, out, pull(w)[:4 if gated else 3]
+
+    layouts, got, grads = run(rows)
+    assert layouts == {"rows"}
+    layouts, want, wants = run(heads_apart)
+    assert layouts == {"heads"}
+    assert got.shape == (2, l, heads * width) and not np.asarray(
+        got[1, :37]).any()
+    np.testing.assert_allclose(got, want,
+                               atol=2e-3 * float(jnp.abs(want).max()))
+    for g, want_g in zip(grads, wants):
+        assert g.dtype == jnp.float32 and g.shape == want_g.shape
+        np.testing.assert_allclose(g, want_g,
+                                   atol=5e-3 * float(jnp.abs(want_g).max()))
+    as_bfloat16 = lambda t: t.astype(jnp.bfloat16).astype(jnp.float32)
+    _, narrow, rounded = run(rows, operand_dtype=jnp.bfloat16)
+    assert narrow.dtype == jnp.float32
+    assert (narrow == (as_bfloat16(got) if gated else got)).all()
+    assert not (got == as_bfloat16(got)).all()
+    for g, r in zip(grads[:3], rounded):
+        assert r.dtype == jnp.float32 and (r == as_bfloat16(g)).all()
+        assert not (g == as_bfloat16(g)).all()
+    if gated:
+        assert (rounded[3] == grads[3]).all()
+    with pytest.raises(AssertionError):
+        grouped_attention(*(t[..., :192] for t in (q, k, v)), 192,
+                          block_k=128)
 
 
 def test_blockwise_attention_pads_for_the_pallas_kernels(monkeypatch):
@@ -391,8 +596,16 @@ def test_attention_route(kind, lq, lk, dk, dv, block, devices, route):
                            devices=devices) == route
 
 
-@pytest.mark.parametrize("kind,length,dk,dv,devices,layout", [
+@pytest.mark.parametrize("kind,length,dk,dv,devices,layout,window", [
+    c + (None,)[len(c) - 6:] for c in [
     ("TPU v5 lite", 8192, 128, 128, 1, "rows"),     # ouro-2.6b-pp8.train
+    # laguna-xs2-ep8.train: the full layers, and the sliding ones' band
+    ("TPU v5 lite", 16384, 128, 128, 1, "rows"),
+    ("TPU v5 lite", 16384, 128, 128, 1, "rows", 512),
+    ("TPU v5 lite", 16384, 128, 128, 8, "xla", 512),
+    ("TPU v5 lite", 16384, 64, 64, 1, "heads", 512),
+    ("TPU v5 lite", 16384, 192, 192, 1, "heads", 512),
+    ("TPU v5 lite", 8192, 256, 256, 1, "rows"),     # qwen3next-a3b-ep16
     ("TPU v5 lite", 16384, 256, 256, 1, "rows"),
     ("TPU v5 lite", 8192, 128, 256, 1, "rows"),
     ("TPU v5 lite", 8192, 192, 128, 1, "heads"),    # kimivl-a3b-ep8.train
@@ -405,19 +618,21 @@ def test_attention_route(kind, lq, lk, dk, dv, block, devices, route):
     ("TPU v4", 8192, 128, 128, 1, "xla"),
     ("cpu", 8192, 128, 128, 1, "xla"),
     ("TPU v5 lite", 65536, 128, 128, 1, "xla"),
-])
-def test_attention_layout(kind, length, dk, dv, devices, layout):
-    """Where `rotary_attention`'s operands lie follows the widths alone,
-    on the route `attention_route` gives. (`blockwise_attention`, which
-    the grouped and the latent mixers call at any of these widths, is
-    head-first on the kernels' route: the next test.)"""
+]])
+def test_attention_layout(kind, length, dk, dv, devices, layout, window):
+    """Where `rotary_attention`'s and `grouped_attention`'s operands lie
+    follows the widths alone, on the route `attention_route` gives, under
+    a window or none. (`blockwise_attention`, which the latent mixer and
+    a grouped one with norms of its heads' own call at any of these
+    widths, is head-first on the kernels' route: the next test.)"""
     from predictionio_tpu.ops.attention import (
         attention_layout, attention_route,
     )
 
-    assert attention_layout(kind, length, length, dk, dv,
-                            devices=devices) == layout
-    assert (attention_route(kind, length, length, dk, dv, devices=devices)
+    assert attention_layout(kind, length, length, dk, dv, devices=devices,
+                            window=window) == layout
+    assert (attention_route(kind, length, length, dk, dv, devices=devices,
+                            window=window)
             == "xla") == (layout == "xla")
 
 

@@ -261,6 +261,74 @@ def test_grouped_query_attention_is_mha_over_repeated_heads(route,
                             v[:, :, :1].repeat(3, axis=2))
 
 
+@pytest.mark.parametrize("name,over,rows", [
+    # the hybrid's own form without its heads' norms: 4 heads of 256 on
+    # 2, rotary on 64 of 256, the gate element by element (flat already)
+    ("elementwise-gate", dict(head_dim=256, rotary_dim=64, qk_norm=False),
+     True),
+    # the state-space hybrid's: 4 heads of 128 on 1, no rotary, no gate
+    ("no-rotary-no-gate", dict(head_dim=128, n_kv_heads=1, positions="none",
+                               attention_gate=False, qk_norm=False,
+                               norm="rms"), True),
+    # a head's own norm runs on [B, L, H, D]: the layer stays head-first
+    ("qk-norm", dict(head_dim=256, rotary_dim=64), False),
+    # heads of 64 (the convolution hybrid's) are no whole lane tiles
+    ("heads-of-64", dict(head_dim=64, rotary_dim=64, qk_norm=False), False),
+])
+def test_a_gqa_layer_on_the_token_first_route_is_the_head_first_one(
+        monkeypatch, name, over, rows):
+    """A `gqa` layer function on the kernels' route (the device's kind
+    patched, the kernels interpreted) at 256 positions with a left-padded
+    row: token-first where `attention_layout` answers "rows" AND the
+    layer norms no head (`grouped_attention`), head-first everywhere
+    else; where it is token-first, the head-first layer (`layout`
+    patched) gives the same values and the same gradients of every leaf
+    and of the input, to the tolerance the `mha` layer's two layouts are
+    held to (tests/test_seqrec_looped.py)."""
+    from predictionio_tpu.ops import attention, attention_pallas
+
+    monkeypatch.setattr(seqrec, "ATTENTION_BLOCK", 128)
+    monkeypatch.setattr(attention, "_device_kind",
+                        lambda: attention_pallas.KINDS[0])
+    whole, grouped = (attention_pallas.flash_attention_pallas,
+                      attention_pallas.grouped_attention_pallas)
+    monkeypatch.setattr(
+        attention_pallas, "flash_attention_pallas",
+        lambda q, k, v, mask, causal: whole(q, k, v, mask, causal, True))
+    monkeypatch.setattr(
+        attention_pallas, "grouped_attention_pallas",
+        lambda *a, operand_dtype=None: grouped(*a, True, operand_dtype))
+    p = small_spec(d_model=128, max_len=256, n_layers=1, mixer="gqa",
+                   **over)
+    layer = weights(p)["layers"][0]
+    rng = np.random.default_rng(len(name))
+    x, cot = (jnp.asarray(rng.normal(size=(2, 256, 128)), jnp.float32)
+              for _ in range(2))
+    mask = jnp.asarray(np.arange(256)[None, :] >= np.array([[9], [0]]))
+
+    def run():
+        layouts = set()
+        with attention.routes_into(set(), layouts):
+            out, pull = jax.vjp(lambda w, x: seqrec._attention(
+                w, x, mask, p, "gqa", None, False), layer, x)
+        return layouts, out, pull(cot)
+
+    layouts, got, (d_layer, d_x) = run()
+    assert layouts == ({"rows"} if rows else {"heads"})
+    if not rows:
+        return
+    monkeypatch.setattr(attention_pallas, "layout", lambda dk, dv: "heads")
+    layouts, want, (want_layer, want_x) = run()
+    assert layouts == {"heads"}
+    assert rel(got, want) < 2e-3 and rel(d_x, want_x) < 5e-3
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(d_layer),
+                            jax.tree.leaves(want_layer)):
+        assert rel(g, w) < 5e-3, jax.tree_util.keystr(path)
+    wq = "wq_gate" if p.attention_gate is True else "wq"
+    for leaf in (wq, "wk", "wv", "wo"):
+        assert float(jnp.abs(d_layer[leaf]).max()) > 0, leaf
+
+
 def test_the_softmax_router_by_hand():
     x = jnp.asarray([[1.0, 0.0], [0.0, 2.0]], jnp.float32)
     w = jnp.asarray([[2.0, 1.0, 0.0, -1.0], [0.0, 0.5, 1.0, 1.5]],

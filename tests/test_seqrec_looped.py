@@ -638,9 +638,9 @@ def test_the_looped_step_under_a_mesh_is_the_step(mesh8):
 
 
 def _on_attention_kernels(monkeypatch):
-    """`blockwise_attention` and `rotary_attention` as a v5e would route
-    them, the kernels in the Pallas interpreter, with blocks a session
-    of 128 fills."""
+    """`blockwise_attention`, `rotary_attention` and `grouped_attention`
+    as a v5e would route them, the kernels in the Pallas interpreter,
+    with blocks a session of 128 fills."""
     from predictionio_tpu.ops import attention, attention_pallas
 
     monkeypatch.setattr(seqrec, "ATTENTION_BLOCK", 512)
@@ -655,6 +655,14 @@ def _on_attention_kernels(monkeypatch):
         attention_pallas, "rotary_attention_pallas",
         lambda qkv, mask, heads, theta, causal, grad_dtype=None: rotary(
             qkv, mask, heads, theta, causal, True, grad_dtype))
+    banded = attention_pallas.window_attention_pallas
+    grouped = attention_pallas.grouped_attention_pallas
+    monkeypatch.setattr(
+        attention_pallas, "window_attention_pallas",
+        lambda q, k, v, mask, window: banded(q, k, v, mask, window, True))
+    monkeypatch.setattr(
+        attention_pallas, "grouped_attention_pallas",
+        lambda *a, operand_dtype=None: grouped(*a, True, operand_dtype))
 
 
 def test_the_looped_step_is_one_step_on_both_layouts(monkeypatch):
@@ -775,13 +783,33 @@ def _tiny_spec(config, **over):
     ("seqrec-lfm2-24b-a2b-ep8",
      dict(n_heads=2, n_kv_heads=1, head_dim=64, rotary_dim=64, n_layers=2,
           mixer=["conv", "gqa"]), "heads"),
+    # a full and a sliding layer at heads of 128, no norm of their own:
+    # both kinds on three products' columns
+    ("seqrec-laguna-xs2-ep8",
+     dict(n_heads=2, n_kv_heads=1, head_dim=128, rotary_dim=64, n_layers=2,
+          mixer=["gqa", "swa"],
+          swa=dict(heads=3, window=100, rope_theta=10000.0,
+                   rotary_dim=128)), "rows"),
+    # ... and the sliding ones alone at another width would not do: a
+    # step says "rows" only where every layer heard it (the full layers
+    # normed a head: head-first)
+    ("seqrec-laguna-xs2-ep8",
+     dict(n_heads=2, n_kv_heads=1, head_dim=128, rotary_dim=64, n_layers=2,
+          mixer=["gqa", "swa"], qk_norm=True,
+          swa=dict(heads=3, window=100, rope_theta=10000.0,
+                   rotary_dim=128)), "heads"),
+    # 2 heads of 128 on 1 with a norm of their own (`qk_norm`): head-first
+    ("seqrec-qwen3-next-80b-a3b-ep16",
+     dict(n_heads=2, n_kv_heads=1, head_dim=128, rotary_dim=64, n_layers=2,
+          mixer=["gdn", "gqa"]), "heads"),
 ])
 def test_a_train_counts_where_its_attention_kernels_read_a_head(
         config, over, layout, monkeypatch):
     """`pio_train_seqrec_attention_layout_tokens_total{layout}`: every
     position of a train on the kernels' route, under where its compiled
     step says the kernels read a head; `impl="pallas"` either way. The
-    tiny Ouro, Kimi and LFM2 specs at widths the kernels tile."""
+    tiny Ouro, Kimi, LFM2, Laguna and Qwen specs at widths the kernels
+    tile."""
     from predictionio_tpu.obs.registry import default_registry
 
     _on_attention_kernels(monkeypatch)

@@ -467,11 +467,131 @@ def test_every_fault_control_of_the_reference_moves_its_part(fault):
                if g.endswith("." + part)) > 1e-3, part
 
 
+def _interpreted(monkeypatch, calls):
+    """The attention entries as a v5e would route them, the kernels in
+    the Pallas interpreter; `calls` takes what each entry was asked for."""
+    monkeypatch.setattr(attention, "_device_kind",
+                        lambda: attention_pallas.KINDS[0])
+    whole, banded, grouped = (attention_pallas.flash_attention_pallas,
+                              attention_pallas.window_attention_pallas,
+                              attention_pallas.grouped_attention_pallas)
+    monkeypatch.setattr(
+        attention_pallas, "flash_attention_pallas",
+        lambda q, k, v, mask, causal: calls.append(("whole", q.shape[1]))
+        or whole(q, k, v, mask, causal, True))
+    monkeypatch.setattr(
+        attention_pallas, "window_attention_pallas",
+        lambda q, k, v, mask, window: calls.append((window, q.shape[1]))
+        or banded(q, k, v, mask, window, True))
+    monkeypatch.setattr(
+        attention_pallas, "grouped_attention_pallas",
+        lambda q, k, v, gate, mask, tables, heads, shifts, window,
+        operand_dtype=None: calls.append(("rows", window, heads, shifts))
+        or grouped(q, k, v, gate, mask, tables, heads, shifts, window, True,
+                   operand_dtype))
+
+
+@pytest.mark.parametrize("kind", ["gqa", "swa"])
+def test_a_layer_on_the_token_first_route_is_the_head_first_one(monkeypatch,
+                                                                kind):
+    """A full layer (6 heads of 128 on 2, rotary on 64 of 128 under
+    YaRN) and a sliding one (8 on 2, a window of 100, rotary on all 128),
+    each with its gate of a column a head, on the kernels' route at 256
+    positions with a left-padded row: the layer function token-first
+    (`grouped_attention`: the widths' layout) equals the head-first one
+    (`layout` patched: `rope` and the gate on [B, L, H, D], the
+    transposes), values and the gradients of every leaf and of the
+    input, to the tolerance the `mha` layer's two layouts are held to
+    (tests/test_seqrec_looped.py): both round the same operands to
+    bfloat16 for the same kernels."""
+    monkeypatch.undo()
+    monkeypatch.setattr(seqrec, "ATTENTION_BLOCK", 128)
+    p = small_spec(d_model=128, n_heads=6, n_kv_heads=2, head_dim=128,
+                   rotary_dim=64, max_len=256, n_layers=2,
+                   mixer=("gqa", "swa"), first_dense_layers=1,
+                   swa=dict(heads=8, window=100, rope_theta=10000.0,
+                            rotary_dim=128))
+    layer = weights(p)["layers"][1 if kind == "swa" else 0]
+    rng = np.random.default_rng(7)
+    x, cot = (jnp.asarray(rng.normal(size=(2, 256, 128)), jnp.float32)
+              for _ in range(2))
+    mask = jnp.asarray(np.arange(256)[None, :] >= np.array([[9], [0]]))
+    calls = []
+    _interpreted(monkeypatch, calls)
+
+    def run():
+        del calls[:]
+        layouts = set()
+        with attention.routes_into(set(), layouts):
+            out, pull = jax.vjp(lambda w, x: seqrec._attention(
+                w, x, mask, p, kind, None, False), layer, x)
+        return layouts, out, pull(cot)
+
+    layouts, got, (d_layer, d_x) = run()
+    window = 100 if kind == "swa" else None
+    assert layouts == {"rows"} and calls == [
+        ("rows", window, (8, 2) if kind == "swa" else (6, 2),
+         (64,) if kind == "swa" else (96, 32))]
+    monkeypatch.setattr(attention_pallas, "layout", lambda dk, dv: "heads")
+    layouts, want, (want_layer, want_x) = run()
+    assert layouts == {"heads"} and calls == [
+        (100, 8) if kind == "swa" else ("whole", 6)]
+    assert rel(got, want) < 2e-3 and rel(d_x, want_x) < 5e-3
+    own = d_layer["swa"] if kind == "swa" else d_layer
+    for name in ("wq", "w_head_gate", "wk", "wv", "wo"):
+        assert float(jnp.abs(own[name]).max()) > 0, name
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(d_layer),
+                            jax.tree.leaves(want_layer)):
+        assert rel(g, w) < 5e-3, jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("ambient,grad_dtype", [
+    (None, jnp.bfloat16), ("bfloat16", jnp.bfloat16), ("highest", None),
+    ("float32", None)])
+def test_the_three_products_gradients_are_rounded_where_their_products_round(
+        ambient, grad_dtype, monkeypatch):
+    """The `swa` mixer (the `gqa` one's `apply`) asks the token-first
+    route for bfloat16 gradients of `x @ wq`, `x @ wk`, `x @ wv` only
+    while those products, and so their backward products, take their
+    operands in one bfloat16 pass (`seqrec._qkv_grad_dtype`, the rule the
+    `mha` mixer follows: tests/test_seqrec_looped.py); under a higher
+    default it asks for none."""
+    monkeypatch.undo()
+    monkeypatch.setattr(seqrec, "ATTENTION_BLOCK", 128)
+    monkeypatch.setattr(attention, "_device_kind",
+                        lambda: attention_pallas.KINDS[0])
+    asked = []
+    grouped = attention_pallas.grouped_attention_pallas
+    monkeypatch.setattr(
+        attention_pallas, "grouped_attention_pallas",
+        lambda *a, operand_dtype=None: asked.append(operand_dtype)
+        or grouped(*a, True, operand_dtype))
+    p = small_spec(d_model=128, n_heads=2, n_kv_heads=1, head_dim=128,
+                   rotary_dim=64, max_len=128, n_layers=2,
+                   mixer=("gqa", "swa"), first_dense_layers=1,
+                   swa=dict(heads=2, window=100, rope_theta=10000.0,
+                            rotary_dim=128))
+    layer = weights(p)["layers"][1]
+    x = jnp.ones((1, 128, 128), jnp.float32)
+    mask = jnp.ones((1, 128), bool)
+    traced = lambda: jax.make_jaxpr(lambda w, x: seqrec._attention(
+        w, x, mask, p, "swa", None, False))(layer, x)
+    if ambient is None:
+        traced()
+    else:
+        with jax.default_matmul_precision(ambient):
+            traced()
+    assert asked == [grad_dtype]
+
+
 def test_a_step_on_the_kernels_route_is_the_step_on_the_scans(monkeypatch):
     """Heads of 128 at 256 positions, on both routes (the device's kind
     patched, the kernels interpreted): the same loss and gradient norms
     by group to the kernels' bfloat16 operands, both kinds of layer on
-    the kernels, and the sliding ones through the banded entry alone."""
+    the kernels token-first (`attention_rows`: the widths' layout), and
+    head-first (`layout` patched) the sliding ones through the banded
+    entry alone. A step says `attention_rows` only where BOTH kinds
+    heard "rows": with the sliding layers alone head-first it does not."""
     monkeypatch.undo()
     monkeypatch.setattr(seqrec, "ATTENTION_BLOCK", 128)
     p = small_spec(d_model=128, n_heads=2, n_kv_heads=1, head_dim=128,
@@ -492,28 +612,34 @@ def test_a_step_on_the_kernels_route_is_the_step_on_the_scans(monkeypatch):
             jnp.asarray(seqs), jnp.asarray(targets))[2]
 
     scan = step()
-    assert not scan["attention_pallas"]
-    monkeypatch.setattr(attention, "_device_kind",
-                        lambda: attention_pallas.KINDS[0])
-    whole, banded, calls = (attention_pallas.flash_attention_pallas,
-                            attention_pallas.window_attention_pallas, [])
+    assert not scan["attention_pallas"] and "attention_rows" not in scan
+    calls = []
+    _interpreted(monkeypatch, calls)
+    rows = step()
+    assert rows["attention_pallas"] and rows["attention_rows"]
+    assert set(calls) == {("rows", 100, (3, 1), (64,)),
+                          ("rows", None, (2, 1), (96, 32))}, calls
+    del calls[:]
+    layout = seqrec.attention_layout
     monkeypatch.setattr(
-        attention_pallas, "flash_attention_pallas",
-        lambda q, k, v, mask, causal: calls.append(("whole", q.shape[1]))
-        or whole(q, k, v, mask, causal, True))
-    monkeypatch.setattr(
-        attention_pallas, "window_attention_pallas",
-        lambda q, k, v, mask, window: calls.append((window, q.shape[1]))
-        or banded(q, k, v, mask, window, True))
-    kernels = step()
-    assert kernels["attention_pallas"] and "attention_rows" not in kernels
+        seqrec, "attention_layout",
+        lambda *sizes, window=None, **named: "heads"
+        if window else layout(*sizes, window=window, **named))
+    mixed = step()
+    assert mixed["attention_pallas"] and "attention_rows" not in mixed
+    assert set(calls) == {(100, 3), ("rows", None, (2, 1), (96, 32))}, calls
+    del calls[:]
+    monkeypatch.setattr(attention_pallas, "layout", lambda dk, dv: "heads")
+    heads = step()
+    assert heads["attention_pallas"] and "attention_rows" not in heads
     assert set(calls) == {(100, 3), ("whole", 2)}, calls
-    assert abs(float(kernels["loss"]) - float(scan["loss"])) \
-        < 2e-3 * float(scan["loss"])
-    assert set(kernels["grad_norm"]) == set(scan["grad_norm"])
-    for group, norm in scan["grad_norm"].items():
-        assert abs(float(kernels["grad_norm"][group]) - float(norm)) \
-            < 3e-2 * float(norm), group
+    for kernels in (rows, mixed, heads):
+        assert abs(float(kernels["loss"]) - float(scan["loss"])) \
+            < 2e-3 * float(scan["loss"])
+        assert set(kernels["grad_norm"]) == set(scan["grad_norm"])
+        for group, norm in scan["grad_norm"].items():
+            assert abs(float(kernels["grad_norm"][group]) - float(norm)) \
+                < 3e-2 * float(norm), group
 
 
 # -- the share ------------------------------------------------------------------

@@ -648,6 +648,15 @@ def test_the_window_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
     assert _kernel_calls(text, "flash_attention_pallas_bwd") == 2
     assert _kernel_calls(text, "window_attention_pallas_fwd") == 3 * 2
     assert _kernel_calls(text, "window_attention_pallas_bwd") == 3
+    # both kinds token-first: a pass in front and the gate's pass with
+    # every forward call, the gate's backward pass and the pass behind
+    # with every backward one, and no activation relaid in either scope
+    assert _kernel_calls(text, "grouped_attention_front") == 5 * 2
+    assert _kernel_calls(text, "attention_head_gate") == 5 * 2
+    assert _kernel_calls(text, "attention_head_gate_bwd") == 5
+    assert _kernel_calls(text, "grouped_attention_back") == 5
+    for scope in ("seqrec_attention", "seqrec_window_attention"):
+        assert not _relayouts(text, p.max_len, scope), scope
     assert _kernel_calls(text, "grouped_product_pallas_[a-z_]*") == 4 * 12
     assert "ragged-dot" not in text
     rows = profiler.parse_scope_table(text, seqrec.STEP_SCOPES)[1]

@@ -62,6 +62,14 @@ def clear_cache() -> None:
     clear_scan_cache()
 
 
+def _of_store(method: str, app_name: str, channel_name: Optional[str]):
+    """`method(app_id, channel_id)` of the configured event store, or
+    None where the backend has no such method."""
+    app_id, channel_id = resolve_app(app_name, channel_name)
+    fn = getattr(Storage.get_events(), method, None)
+    return fn(app_id, channel_id) if fn is not None else None
+
+
 class EventStoreClient:
     """Unified facade over the configured event store, by app name."""
 
@@ -138,13 +146,19 @@ class EventStoreClient:
 
     @staticmethod
     def snapshot_digest(app_name: str, channel_name: Optional[str] = None):
-        """Cheap content fingerprint of the app's event namespace (None
-        when the backend cannot produce one) — the ingest scan-cache key
-        (data/ingest.py): equal digests promise an identical rescan."""
-        app_id, channel_id = resolve_app(app_name, channel_name)
-        store = Storage.get_events()
-        fn = getattr(store, "snapshot_digest", None)
-        return fn(app_id, channel_id) if fn is not None else None
+        """Durable content fingerprint of the app's event namespace (None
+        when the backend cannot produce one): equal digests promise an
+        identical rescan, across processes. The deploy orchestrator's
+        change detector, and the ingest scan cache's key on a backend
+        without a `change_token`."""
+        return _of_store("snapshot_digest", app_name, channel_name)
+
+    @staticmethod
+    def change_token(app_name: str, channel_name: Optional[str] = None):
+        """The backend's O(1), process-local change token of the app's
+        event namespace (`EventStore.change_token`), or None when it has
+        none — the ingest scan cache's key (data/ingest.py)."""
+        return _of_store("change_token", app_name, channel_name)
 
     @staticmethod
     def read_snapshot(app_name: str, channel_name: Optional[str] = None):
@@ -153,10 +167,7 @@ class EventStoreClient:
         backend cannot partition. Multi-host trainers capture this ONCE,
         broadcast it, and pass shard=(index, count, snapshot) to
         find_columnar so every process reads the same stable set."""
-        app_id, channel_id = resolve_app(app_name, channel_name)
-        store = Storage.get_events()
-        fn = getattr(store, "read_snapshot", None)
-        return fn(app_id, channel_id) if fn is not None else None
+        return _of_store("read_snapshot", app_name, channel_name)
 
 
 # short aliases mirroring the reference object names

@@ -16,12 +16,20 @@ Three concerns live here so the six engines share one implementation:
   (JDBCPEvents.scala:89-101); engines whose algorithms re-key rows to
   their owners (recommendation's distributed ALS) opt in with
   ``sharded=True``, everything else reads replicated.
-* **scan cache** — keyed by the backend's ``snapshot_digest()`` so the
-  repeated folds of `pio eval` (k-fold re-reads) and back-to-back
-  `pio train` runs skip the rescan when the store hasn't changed.
-  Disable with ``PIO_INGEST_CACHE=0``.
+* **scan cache** — process-local, so the repeated folds of `pio eval`
+  (k-fold re-reads) and a second algorithm of one engine skip the
+  rescan when the store hasn't changed. Keyed by what the backend
+  offers to identify its state (`_store_state`): its O(1)
+  ``change_token()`` where it has one (sqlite: counters of the
+  connection, no statement over the event table), else its durable
+  ``snapshot_digest()``. Either is taken BEFORE the scan: states only
+  move forward and every commit moves the key, so a later read that
+  finds the same key knows nothing was committed since it was taken,
+  the scan included; taken after the scan it could describe a newer
+  state than the table scanned. Disable with ``PIO_INGEST_CACHE=0``.
 * **`pio_ingest_*` metrics** — rows scanned and decoded, rows/s, cache
-  hit/miss counters on the process registry, plus ``ingest_digest`` /
+  hit/miss and key-kind counters on the process registry, plus
+  ``ingest_digest`` /
   ``ingest_scan`` / ``ingest_decode`` / ``ingest_intern`` /
   ``ingest_assemble`` spans,
   which reach the span histogram of the job they run under
@@ -79,7 +87,8 @@ def _count_cache(app_name: str, hit: bool) -> None:
             else "pio_ingest_cache_misses_total")
     verb = "hits" if hit else "misses"
     _registry().counter(
-        name, f"Ingest scan-cache {verb} (snapshot-digest keyed)",
+        name, f"Ingest scan-cache {verb} (keyed by the store's change "
+        "token or snapshot digest)",
         labelnames=("app",)).inc(app=app_name)
 
 
@@ -100,15 +109,30 @@ def _cache_put(key, value) -> None:
         _scan_cache[key] = value
 
 
-def _snapshot_digest(app_name: str, channel_name: Optional[str]):
-    """The scan-cache key's content fingerprint, under an
-    ``ingest_digest`` span: on sqlite it is a COUNT and a MAX over the
-    whole event table, which a read pays whether or not the cache then
-    hits."""
+def _store_state(app_name: str, channel_name: Optional[str]):
+    """What identifies the store's state in a scan-cache key, taken
+    before the scan under an ``ingest_digest`` span: ``("token", t)``
+    with the backend's O(1) change token where it has one (sqlite), else
+    ``("digest", d)`` with its snapshot digest (postgres, parquet: a
+    statement or a listing a read pays whether or not the cache then
+    hits), else None: the read is not cached. Which of the two is what
+    the backend offers; nobody sets it."""
     from predictionio_tpu.data.eventstore import EventStoreClient
 
     with span("ingest_digest"):
-        return EventStoreClient.snapshot_digest(app_name, channel_name)
+        kind, state = "token", EventStoreClient.change_token(
+            app_name, channel_name)
+        if state is None:
+            kind, state = "digest", EventStoreClient.snapshot_digest(
+                app_name, channel_name)
+    if state is None:
+        return None
+    _registry().counter(
+        "pio_ingest_cache_key_total",
+        "Training reads that keyed the ingest scan cache, by what "
+        "identified the store's state: its O(1) change token or its "
+        "snapshot digest", labelnames=("kind",)).inc(kind=kind)
+    return kind, state
 
 
 @dataclasses.dataclass
@@ -143,7 +167,7 @@ def training_scan(app_name: str, channel_name: Optional[str] = None, *,
                   sharded: bool = False, cache: bool = True,
                   **filters) -> TrainingScan:
     """The shared columnar training read: filtered, optionally sharded,
-    snapshot-digest cached, instrumented.
+    cached by the store's state (`_store_state`), instrumented.
 
     ``filters`` go straight to ``find_columnar`` (entity_type,
     event_names, target_entity_type, ...); ``ordered=False`` is applied
@@ -190,9 +214,9 @@ def training_scan(app_name: str, channel_name: Optional[str] = None, *,
 
     key = None
     if cache and _cache_enabled():
-        digest = _snapshot_digest(app_name, channel_name)
-        if digest is not None:
-            key = (app_name, channel_name, digest,
+        state = _store_state(app_name, channel_name)
+        if state is not None:
+            key = (app_name, channel_name, state,
                    shard[:2] if shard else None,
                    tuple(sorted(
                        (k, tuple(v) if isinstance(v, list) else v)
@@ -217,18 +241,18 @@ def aggregate_scan(app_name: str, entity_type: str,
                    channel_name: Optional[str] = None, *,
                    required=None, cache: bool = True):
     """Entity properties for training reads: the columnar
-    ``aggregate_properties`` fold behind the same snapshot-digest cache
-    and ``ingest_aggregate`` span as `training_scan`. Returns
+    ``aggregate_properties`` fold behind the same cache as
+    `training_scan`, under an ``ingest_aggregate`` span. Returns
     ``{entity_id: PropertyMap}`` (a fresh dict per call; the immutable
     PropertyMaps are shared with the cache)."""
     from predictionio_tpu.data.eventstore import EventStoreClient
 
     key = None
     if cache and _cache_enabled():
-        digest = _snapshot_digest(app_name, channel_name)
-        if digest is not None:
+        state = _store_state(app_name, channel_name)
+        if state is not None:
             key = ("aggregate", app_name, channel_name, entity_type,
-                   tuple(required) if required else None, digest)
+                   tuple(required) if required else None, state)
             hit = _cache_get(app_name, key)
             if hit is not None:
                 return dict(hit)

@@ -602,12 +602,27 @@ class EventStore(abc.ABC):
 
     def snapshot_digest(self, app_id: int,
                         channel_id: Optional[int] = None) -> Optional[str]:
-        """Cheap fingerprint of the namespace's current contents, or None
-        when the backend cannot produce one. Equal digests mean a
-        repeated training scan would return the same rows — the cache key
-        for the ingest-side scan cache (data/ingest.py). Backends include
+        """Durable fingerprint of the namespace's current contents, or
+        None when the backend cannot produce one. Equal digests mean a
+        repeated training scan would return the same rows, in this
+        process or another: the deploy orchestrator compares it from tick
+        to tick, and the ingest-side scan cache (data/ingest.py) keys
+        with it where the backend has no `change_token`. Backends include
         enough state (row window + count, fragment + tombstone lists)
         that both appends and deletes change the digest."""
+        return None
+
+    def change_token(self, app_id: int,
+                     channel_id: Optional[int] = None):
+        """O(1), process-local stand-in for `snapshot_digest`, or None
+        when the backend has none: a hashable value that differs from
+        every token this process took of the namespace before a commit
+        that came since, whoever committed. It need tell apart only the
+        states THIS process can have seen (it keys a cache that lives and
+        dies with the process), so it may read counters of the connection
+        where the digest reads the table. A token that cannot be compared
+        with an earlier one (another thread's connection, a store opened
+        again) must differ from it."""
         return None
 
 

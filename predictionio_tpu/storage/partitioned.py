@@ -495,6 +495,17 @@ class PartitionedEvents(base.EventStore):
             return None
         return f"pmap:{self._count}:" + "|".join(digests)
 
+    def change_token(self, app_id: int,
+                     channel_id: Optional[int] = None):
+        """The partitions' tokens under the map they were taken under
+        (a reshard opens new stores, whose tokens compare with none of
+        the old); None as soon as one partition has none."""
+        tokens = tuple(s.change_token(app_id, channel_id)
+                       for s in self._stores)
+        if any(t is None for t in tokens):
+            return None
+        return ("pmap", self._count, self._gen, tokens)
+
     # -- resharding ----------------------------------------------------------
     def reshard(self, new_count: int,
                 apps: Iterable[Tuple[int, Optional[int]]]) -> Dict[str, int]:

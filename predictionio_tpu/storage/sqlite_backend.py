@@ -13,6 +13,7 @@ thread pool to read during writes.
 from __future__ import annotations
 
 import datetime as _dt
+import itertools
 import json
 import sqlite3
 import threading
@@ -41,6 +42,20 @@ def _tz_offset_min(t: _dt.datetime) -> int:
     return 0 if off is None else int(off.total_seconds() // 60)
 
 
+_CONN_SERIALS = itertools.count(1)
+
+
+class _Connection(sqlite3.Connection):
+    """A connection that knows which one it is: `serial`, one number per
+    connection this process ever opened. It tells a re-opened store's
+    connection from the closed one in a `change_token` (`id()` of a
+    freed connection comes back)."""
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.serial = next(_CONN_SERIALS)
+
+
 class SqliteClient:
     """Shared connection manager for one sqlite database file."""
 
@@ -61,15 +76,33 @@ class SqliteClient:
             with self._lock:
                 if self._memory_conn is None:
                     self._memory_conn = sqlite3.connect(
-                        ":memory:", check_same_thread=False)
+                        ":memory:", check_same_thread=False,
+                        factory=_Connection)
                 return self._memory_conn
         c = getattr(self._local, "conn", None)
         if c is None:
-            c = sqlite3.connect(self.path)
+            c = sqlite3.connect(self.path, factory=_Connection)
             c.execute("PRAGMA journal_mode=WAL")
             c.execute("PRAGMA synchronous=NORMAL")
             self._local.conn = c
         return c
+
+    def change_token(self) -> Optional[tuple]:
+        """What the calling thread's connection knows, in O(1), of
+        whether the database changed: which connection it is, `PRAGMA
+        data_version` (moves when any OTHER connection, of this process
+        or another, commits), `total_changes` (this connection's own row
+        changes) and `PRAGMA schema_version` (a table dropped and made
+        again, which counts no row). Every commit moves one of them and
+        none moves back, so two equal tokens mean that nothing was
+        committed in between. Only tokens of one connection compare.
+        None inside a transaction of this connection's own: a rollback
+        takes rows back and no number with them."""
+        c = self.conn()
+        if c.in_transaction:
+            return None
+        return (c.serial, sqlite_scan.data_version(c), c.total_changes,
+                c.execute("PRAGMA schema_version").fetchone()[0])
 
     def close(self) -> None:
         if self._memory_conn is not None:
@@ -332,13 +365,18 @@ class SqliteEvents(base.EventStore):
         simply not part of this training read)."""
         name = event_table_name(app_id, channel_id)
         try:
-            row = self.client.conn().execute(
-                f"SELECT MIN(rowid), MAX(rowid) FROM {name}").fetchone()
+            return sqlite_scan.rowid_window(self.client.conn(), name)
         except sqlite3.OperationalError as ex:
             raise StorageError(
                 f"cannot read app {app_id} channel {channel_id}: {ex}"
             ) from ex
-        return (row[0] or 0), (row[1] or 0) + 1
+
+    def change_token(self, app_id: int,
+                     channel_id: Optional[int] = None) -> Optional[tuple]:
+        """`SqliteClient.change_token`: no statement reads the table. The
+        numbers are the database's, so a write to any table of the file
+        moves them: a miss too many, never a hit too many."""
+        return self.client.change_token()
 
     def snapshot_digest(self, app_id: int,
                         channel_id: Optional[int] = None) -> str:
@@ -347,8 +385,10 @@ class SqliteEvents(base.EventStore):
         component covers delete-then-insert pairs — a plain rowid table
         reuses MAX(rowid)+1 after the newest row is deleted, so window +
         count alone could alias two different states; the replacement
-        row's later creationTime still changes the digest (ingest-cache
-        key)."""
+        row's later creationTime still changes the digest. One statement
+        over the whole table (`creationTime` has no index): what the
+        deploy orchestrator compares from tick to tick and across
+        processes; a training read keys its cache with `change_token`."""
         name = event_table_name(app_id, channel_id)
         try:
             row = self.client.conn().execute(

@@ -85,7 +85,8 @@ def rowid_window(conn: sqlite3.Connection, table: str) -> Tuple[int, int]:
     return (lo or 0), (hi or 0) + 1
 
 
-def _data_version(conn: sqlite3.Connection) -> int:
+def data_version(conn: sqlite3.Connection) -> int:
+    """Moves whenever a connection other than `conn` commits."""
     return conn.execute("PRAGMA data_version").fetchone()[0]
 
 
@@ -296,11 +297,11 @@ def fan_out(conn: sqlite3.Connection, path: str,
             for attempt in range(PIN_ATTEMPTS):
                 if attempt:
                     _retries().inc()
-                before = _data_version(conn)
+                before = data_version(conn)
                 lo_hi = window()
                 readers.tell_all({"op": "pin"})
                 readers.expect_line(b"pinned", HANDSHAKE_TIMEOUT_S)
-                if _data_version(conn) == before:
+                if data_version(conn) == before:
                     break
             else:
                 log.warning("sqlite scan over %d readers falls back to the "

@@ -10,6 +10,8 @@ must be a pure representation change.
 
 import datetime as dt
 import random
+import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -277,6 +279,214 @@ def test_training_scan_cache_disabled_by_env(backend, monkeypatch):
                          event_names=["view"], target_entity_type="item")
     with ingest._scan_lock:
         assert not ingest._scan_cache
+
+
+def _view(k):
+    return Event(event="view", entity_type="user", entity_id=f"w{k}",
+                 target_entity_type="item", target_entity_id="i0",
+                 event_time=ms(20_000 + k))
+
+
+def _write_same_connection(backend, app_id, path):
+    backend.get_events().insert(_view(1), app_id)
+    return 1
+
+
+def _write_other_connection(backend, app_id, path):
+    """Another thread of this process: the client gives it a connection
+    of its own."""
+    th = threading.Thread(
+        target=lambda: backend.get_events().insert(_view(2), app_id))
+    th.start()
+    th.join(timeout=30)
+    assert not th.is_alive()
+    return 1
+
+
+def _write_other_process(backend, app_id, path):
+    code = (
+        "import sqlite3, sys\n"
+        "c = sqlite3.connect(sys.argv[1])\n"
+        "c.execute('INSERT INTO ' + sys.argv[2] + ' VALUES "
+        "(?,?,?,?,?,?,?,?,?,?,?,?,?)', ('raw3', 'view', 'user', 'w3', "
+        "'item', 'i0', None, 20003, 0, None, None, 20003, 0))\n"
+        "c.commit()\n"
+        "c.close()\n")
+    done = subprocess.run(
+        [sys.executable, "-c", code, path, f"pio_event_{app_id}"],
+        timeout=60)
+    assert done.returncode == 0
+    return 1
+
+
+def _delete_then_insert(backend, app_id, path):
+    """The newest row goes and a new one takes its rowid: COUNT(*) and
+    MAX(rowid) read what they read before."""
+    store = backend.get_events()
+    conn, table = store.client.conn(), f"pio_event_{app_id}"
+
+    def shape():
+        return conn.execute(
+            f"SELECT COUNT(*), MAX(rowid) FROM {table}").fetchone()
+
+    before = shape()
+    newest = conn.execute(
+        f"SELECT id FROM {table} ORDER BY rowid DESC LIMIT 1").fetchone()[0]
+    assert store.delete(newest, app_id)
+    store.insert(_view(4), app_id)
+    assert shape() == before
+    return 0
+
+
+def _table_remade(backend, app_id, path):
+    """DROP and CREATE count no row change: `total_changes` stands
+    still, the schema cookie moves."""
+    store = backend.get_events()
+    n = store.client.conn().execute(
+        f"SELECT COUNT(*) FROM pio_event_{app_id}").fetchone()[0]
+    changes = store.client.conn().total_changes
+    store.remove_channel(app_id)
+    store.init_channel(app_id)
+    assert store.client.conn().total_changes == changes
+    return -n
+
+
+def _store_reopened(backend, app_id, path):
+    """The thread's connection closed, a commit from outside, a new
+    connection: its three numbers read what the closed one's read (none
+    of them is the file's), and only which connection it is differs."""
+    client = backend.get_events().client
+    numbers = client.change_token()[1:]
+    client.close()
+    gained = _write_other_process(backend, app_id, path)
+    assert client.change_token()[1:] == numbers
+    return gained
+
+
+@pytest.mark.parametrize("write", [
+    None, _write_same_connection, _write_other_connection,
+    _write_other_process, _delete_then_insert, _table_remade,
+    _store_reopened,
+], ids=lambda w: "no_write" if w is None else w.__name__.lstrip("_"))
+def test_scan_cache_key_sees_every_commit(backend, tmp_path, write):
+    """A lookup after any commit — this connection's, another one's of
+    the process, another process's, one that leaves count and MAX(rowid)
+    alone, one that changes no row, one this connection was not there
+    for — misses and returns the table as it is now; with no commit
+    between, it hits."""
+    from predictionio_tpu.data import ingest
+
+    app_id = _seed_app(backend, "KeyApp")
+    ingest.clear_scan_cache()
+    backend.get_events().client.close()     # read on a connection
+    first = ingest.training_scan("KeyApp").table    # that never wrote
+    gained = 0 if write is None else write(
+        backend, app_id, str(tmp_path / "t.db"))
+    with ingest._scan_lock:
+        entries = len(ingest._scan_cache)
+    second = ingest.training_scan("KeyApp").table
+    if write is None:
+        assert second is first
+        return
+    assert second is not first
+    assert second.num_rows == first.num_rows + gained
+    ids = set(second.column("event_id").to_pylist())
+    want = set(e.event_id for e in backend.get_events().find(app_id))
+    assert ids == want
+    with ingest._scan_lock:
+        assert len(ingest._scan_cache) == entries + 1
+
+
+def test_rolled_back_rows_are_not_served_from_the_cache(backend):
+    """Inside a transaction of the connection's own there is no token (a
+    rollback moves no counter back): the read keys with the digest, and
+    the rows that were taken back are not in the next read."""
+    from predictionio_tpu.data import ingest
+
+    app_id = _seed_app(backend, "RollApp")
+    ingest.clear_scan_cache()
+    store = backend.get_events()
+    conn = store.client.conn()
+    n = ingest.training_scan("RollApp").table.num_rows
+    conn.execute(
+        f"INSERT INTO pio_event_{app_id} VALUES (?,?,?,?,?,?,?,?,?,?,?,?,?)",
+        ("open1", "view", "user", "w9", "item", "i0", None, 20009, 0, None,
+         None, 20009, 0))
+    assert conn.in_transaction
+    assert store.change_token(app_id) is None
+    assert ingest.training_scan("RollApp").table.num_rows == n + 1
+    conn.rollback()
+    assert ingest.training_scan("RollApp").table.num_rows == n
+
+
+def test_training_read_walks_no_table_for_its_cache_key(backend):
+    """In a process whose cache is empty a training read on sqlite issues
+    no statement that walks the event table before the scan, and the
+    ``ingest_digest`` span is still recorded around what it does
+    instead."""
+    from predictionio_tpu.data import ingest
+    from predictionio_tpu.obs import tracing
+    from predictionio_tpu.obs.registry import default_registry
+
+    app_id = _seed_app(backend, "TraceApp")
+    ingest.clear_scan_cache()
+    store = backend.get_events()
+    digest = store.snapshot_digest(app_id)
+    keys = default_registry().counter(
+        "pio_ingest_cache_key_total", "", labelnames=("kind",))
+    tokens0, digests0 = keys.value(kind="token"), keys.value(kind="digest")
+    statements = []
+    conn = store.client.conn()
+    conn.set_trace_callback(statements.append)
+    tokens, trace = tracing.start_trace("rid")
+    try:
+        scan = ingest.training_scan("TraceApp")
+        props = ingest.aggregate_scan("TraceApp", "item")
+    finally:
+        tracing.reset_trace(tokens)
+        conn.set_trace_callback(None)
+    assert scan.table.num_rows == 91 and len(props) == 5
+    assert any("PRAGMA data_version" in st for st in statements)
+    for st in statements:
+        assert "COUNT(*)" not in st and "MAX(creationTime)" not in st, st
+    assert [s.name for s in trace.spans].count("ingest_digest") == 2
+    assert keys.value(kind="token") == tokens0 + 2
+    assert keys.value(kind="digest") == digests0
+    # the durable fingerprint is what it was, for those who compare it
+    assert store.snapshot_digest(app_id) == digest
+    assert digest.startswith("rowid:1:91:91:")
+
+
+def test_partitioned_store_composes_its_partitions_tokens(
+        tmp_path, monkeypatch):
+    from predictionio_tpu.data import ingest
+    from predictionio_tpu.data.eventstore import clear_cache
+
+    monkeypatch.setenv("PIO_INGEST_PARTITIONS", "3")
+    Storage.configure({
+        "sources": {"DB": {"TYPE": "sqlite", "PATH": str(tmp_path / "p.db")}},
+        "repositories": {r: {"NAME": "pio", "SOURCE": "DB"}
+                         for r in ("METADATA", "EVENTDATA", "MODELDATA")},
+    })
+    clear_cache()
+    try:
+        app_id = _seed_app(Storage, "PartApp")
+        store = Storage.get_events()
+        token = store.change_token(app_id)
+        assert token[:3] == ("pmap", 3, store._gen) and len(token[3]) == 3
+        assert token[3] == tuple(
+            s.change_token(app_id) for s in store._stores)
+        assert store.change_token(app_id) == token
+        first = ingest.training_scan("PartApp").table
+        assert ingest.training_scan("PartApp").table is first
+        store.insert(_view(5), app_id)      # one partition's token moves
+        moved = store.change_token(app_id)
+        assert sum(a != b for a, b in zip(moved[3], token[3])) == 1
+        second = ingest.training_scan("PartApp").table
+        assert second.num_rows == first.num_rows + 1
+    finally:
+        Storage.reset()
+        clear_cache()
 
 
 def test_aggregate_scan_matches_direct(backend):

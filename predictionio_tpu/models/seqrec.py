@@ -10,15 +10,18 @@ the transition matrix, and the same DASE Engine surface serves it.
 The stack is a spec (`SeqRecParams`): each layer a mixer then a feed-forward,
 or one of the two alone, every kind of either one record of one table
 (`KINDS`: seven mixers, three feed-forwards). The default is the SASRec block
-this began with; the benchmark's six sequence configurations (latent
+this began with; the benchmark's seven sequence configurations (latent
 attention with routed experts, the gated delta rule, gated short
 convolutions, a looped stack with exit gates, state-space layers with latent
 experts and a multi-token-prediction module, sliding-window layers beside
-full ones with head counts and rotary tables of their own) are specs of the
-same table.
+full ones with head counts and rotary tables of their own, the same two
+kinds over rows packed from many sessions) are specs of the same table.
 
-TPU-native design: all shapes static (sessions padded/truncated to max_len;
-id 0 = padding); one jitted train step with donated state (adamw), its layers
+TPU-native design: all shapes static (a row of max_len positions is one
+session, left-padded or cut to its last max_len items, or under `packing`
+several whole sessions one after another, attention and positions kept
+inside each; id 0 = padding); one jitted train step with donated state
+(adamw), its layers
 on the routes `ops/` choose from the device and the shapes; batch over the
 mesh's "data" axis, table rows and projections over "model" (`shard_params`),
 and ring attention over a "seq" axis where the mesh has one.
@@ -44,7 +47,7 @@ from predictionio_tpu.ops import linear_attention, moe, state_space
 from predictionio_tpu.ops.attention import (
     YarnScaling, attention_layout, band_pairs, blockwise_attention,
     grouped_attention, ring_attention_traced, rope, rotary_attention,
-    routes_into, split_heads,
+    routes_into, session_pairs, split_heads,
 )
 
 
@@ -198,6 +201,15 @@ class SeqRecParams(Params):
     mtp_layers: Sequence[str] = ()
     mtp_loss_weight: float = 0.0
 
+    #: a training row holds several whole sessions one after another
+    #: (`pack_sessions`: first-fit over the sessions in decreasing length)
+    #: instead of one session left-padded to max_len: a query sees the
+    #: keys of its own session alone and rotary positions restart at each
+    #: session, so a packed session is trained as the
+    #: same session alone; `batch_size` then counts rows. For the mixers
+    #: whose record says it `packs` (rotary positions, or none)
+    packing: bool = False
+
     #: draw the initial weights on the device (jax.random) instead of on
     #: the host in numpy: the same seed gives the same weights either way,
     #: but not the same as the other way
@@ -344,6 +356,18 @@ class SeqRecParams(Params):
                     f"no share of their own, {uneven} do not divide")
         if self.mtp_layers and self.n_loops > 1:
             raise ValueError("mtp_layers does not go with n_loops > 1")
+        if self.packing:
+            # a session's boundary is taught to one kernel family: the
+            # other mixers carry a state or taps across it
+            packs = lambda kind: getattr(KINDS[kind], "packs", False)
+            whole = sorted(kind for kind in mixers if not packs(kind))
+            if whole:
+                raise ValueError(
+                    f"packing goes with the mixers "
+                    f"{[k for k in MIXERS if packs(k)]}, not {whole}")
+            if self.mtp_layers:
+                raise ValueError("packing does not go with mtp_layers (the "
+                                 "module's targets cross a session's end)")
         if self.moe_latent_size < 0 or self.mtp_loss_weight < 0:
             raise ValueError("moe_latent_size and mtp_loss_weight must be "
                              ">= 0")
@@ -382,8 +406,10 @@ class SeqRecParams(Params):
 # runs under, `grad_groups` where each leaf's gradient norm is recorded,
 # `columns` and `rows` the leaves `shard_params` splits over "model". A
 # mixer names the `norms` and `positions` it is defined with, whether it
-# runs over a mesh's "seq" axis (`ring`) and the `family` its tokens are
-# counted under; a feed-forward whether it is `routed`.
+# runs over a mesh's "seq" axis (`ring`), whether it takes a row of
+# several sessions (`packs`: its `apply` then takes the `positions` inside
+# a session, beside session ids in the key mask's place) and the `family`
+# its tokens are counted under; a feed-forward whether it is `routed`.
 
 
 @dataclasses.dataclass(frozen=True)
@@ -513,8 +539,9 @@ class GroupedQueryAttention:
     role, scope, family = "mixer", "seqrec_attention", "attention"
     grad_groups = dict.fromkeys(("wq_gate", "wq", "w_head_gate", "wk", "wv",
                                  "q_norm", "k_norm", "wo"), "attention")
-    # the ring takes one key/value head a query head
-    columns, rows, ring = ("wq_gate",), (), False
+    # the ring takes one key/value head a query head; a row of several
+    # sessions is this kind's (and "swa"'s) to take: `apply`'s `positions`
+    columns, rows, ring, packs = ("wq_gate",), (), False, True
     share = "n_heads or n_kv_heads"
     norms = ("rms", "rms_zero_centered")
     positions = ("rope", "none")
@@ -568,9 +595,14 @@ class GroupedQueryAttention:
                 **qk_norms,
                 "wo": dense(h * self.head_dim, d)}
 
-    def apply(self, w, x, key_mask, p: SeqRecParams, mesh):
+    def apply(self, w, x, key_mask, p: SeqRecParams, mesh, positions=None):
+        """`positions` [B, L] (a packed batch): each position's place in
+        its own session, beside which `key_mask` holds its session's id;
+        None: a row is one session and its positions are its indices."""
         b, l, _ = x.shape
-        positions = jnp.arange(l)
+        packed = positions is not None
+        if not packed:
+            positions = jnp.arange(l)
         gate = None
         if self.gate is True:
             q, gate = jnp.split(x @ w["wq_gate"], 2, axis=-1)
@@ -596,7 +628,8 @@ class GroupedQueryAttention:
                 theta=None if self.rotary_dim is None else self.theta,
                 rotary_dim=self.rotary_dim, scaling=self.scaling,
                 window=self.window, block_k=ATTENTION_BLOCK,
-                key_mask=key_mask, operand_dtype=_qkv_grad_dtype())
+                key_mask=key_mask, operand_dtype=_qkv_grad_dtype(),
+                positions=positions if packed else None)
             if self.gate is True:
                 att = att * jax.nn.sigmoid(gate)
             return att @ w["wo"]
@@ -613,7 +646,8 @@ class GroupedQueryAttention:
         q, k = (head_rows(t, name) for t, name in (
             (q, "q_norm"), (x @ w["wk"], "k_norm")))
         v = (x @ w["wv"]).reshape(b, l, -1, self.head_dim)
-        return _attend(q, k, v, gate, w["wo"], key_mask, mesh, self.window)
+        return _attend(q, k, v, gate, w["wo"], key_mask, mesh, self.window,
+                       packed)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -649,8 +683,8 @@ class WindowAttention(GroupedQueryAttention):
     def init(self, d: int, dense, uniform, norm) -> Dict:
         return {"swa": super().init(d, dense, uniform, norm)}
 
-    def apply(self, w, x, key_mask, p: SeqRecParams, mesh):
-        return super().apply(w["swa"], x, key_mask, p, mesh)
+    def apply(self, w, x, key_mask, p: SeqRecParams, mesh, positions=None):
+        return super().apply(w["swa"], x, key_mask, p, mesh, positions)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -984,7 +1018,7 @@ MEMORY_FIELDS = ("remat",)
 #: fields the spec gained after runs had taken checkpoints under it: part
 #: of a run's identity (`spec_key`) only where they are set, so that an
 #: older run keeps the identity it had
-LATER_FIELDS = ("rope_scaling", "swa", "expert_update_by_expert")
+LATER_FIELDS = ("rope_scaling", "swa", "expert_update_by_expert", "packing")
 
 #: query and key block of the attention where it runs as a scan of XLA
 #: operations (`attention_route`: off a v5e, in a step sharded over a
@@ -1223,12 +1257,14 @@ def _rings(mesh: Optional[Mesh]) -> bool:
     return mesh is not None and "seq" in mesh.axis_names
 
 
-def _attend(q, k, v, gate, wo, key_mask, mesh, window=None):
+def _attend(q, k, v, gate, wo, key_mask, mesh, window=None, packed=False):
     """What the softmax-attention mixers share: q, k, v [B, L, heads, .]
     -> [B, L, D]. The key mask keeps padding out of the softmax. `gate`:
     None, the output's own shape flat [B, L, heads x .], or one column a
     head [B, L, heads, 1]. Under a `window` a query sees that many keys
-    up to its own (no ring takes one: `WindowAttention.ring`)."""
+    up to its own (no ring takes one: `WindowAttention.ring`); `packed`,
+    the keys of its own session alone (key_mask the session ids; no ring
+    either)."""
     b, l = q.shape[:2]
     if _rings(mesh):
         att = ring_attention_traced(q, k, v, mesh, axis="seq", causal=True,
@@ -1237,7 +1273,7 @@ def _attend(q, k, v, gate, wo, key_mask, mesh, window=None):
         att = blockwise_attention(q, k, v, block_k=ATTENTION_BLOCK,
                                   causal=True, key_mask=key_mask,
                                   devices=1 if mesh is None else mesh.size,
-                                  window=window)
+                                  window=window, packed=packed)
     if gate is not None and gate.ndim == 4:
         att = att * jax.nn.sigmoid(gate)
     att = att.reshape(b, l, -1)
@@ -1305,7 +1341,8 @@ def _moe(layer, x, p: SeqRecParams, devices: int = 1):
 
 def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
              mesh: Optional[Mesh] = None,
-             next_items: Optional[jax.Array] = None
+             next_items: Optional[jax.Array] = None,
+             packed: Optional[Tuple[jax.Array, jax.Array]] = None
              ) -> Tuple[Sequence[jax.Array], List[Dict], Dict[str, int],
                         Optional[jax.Array]]:
     """[B, L] int32 item ids (0 = pad) -> (the [B, L, D] hidden states of
@@ -1316,14 +1353,21 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
     None). With `next_items` [B, L] (each position's next item: the
     targets) under `mtp_layers` the module runs too: its last norm's
     output [B, L, D] is the fourth value, its expert layers' numbers come
-    after the stack's."""
+    after the stack's. `packed` (`pack_sessions`' rows): (each position's
+    session id [B, L], 0 = padding and rising by one along a row; its
+    position inside its session [B, L]): a mixer's query then sees the
+    keys of its own session alone, at positions that restart with it."""
     b, l = seqs.shape
+    ids, positions = packed or (None, None)
     with jax.named_scope("seqrec_embed"):
         h = params["emb"][seqs]
         if "pos" in params:
             h = h + params["pos"][None, :l]
     pad = (seqs == 0)[..., None]
-    key_mask = seqs != 0       # left-padding sits in the causal PAST
+    # left-padding sits in the causal PAST; a packed row's (its tail) in
+    # no session
+    key_mask = seqs != 0 if packed is None else ids
+    in_session = {} if packed is None else {"positions": positions}
 
     def joined(h, y, layer, name):
         """The residual h + y, y through its own norm first under
@@ -1342,8 +1386,8 @@ def _forward(params: Dict, seqs: jax.Array, p: SeqRecParams,
             with jax.named_scope("seqrec_norm"):
                 x = _norm(h, layer["ln1"], p)
             with jax.named_scope(record.scope):
-                h = joined(h, record.apply(layer, x, key_mask, p, mesh),
-                           layer, "post1")
+                h = joined(h, record.apply(layer, x, key_mask, p, mesh,
+                                           **in_session), layer, "post1")
         if kind is not None:
             with jax.named_scope("seqrec_norm"):
                 x = _norm(h, layer["ln2"], p)
@@ -1438,7 +1482,7 @@ def exit_distribution(z: jax.Array) -> jax.Array:
     return jnp.concatenate([jax.nn.log_sigmoid(z[:-1]) + before, stay[-1:]])
 
 
-def _loss_fn(params, seqs, targets, p: SeqRecParams, mesh=None):
+def _loss_fn(params, seqs, targets, p: SeqRecParams, mesh=None, packed=None):
     """Next-item softmax cross-entropy, pad-masked, plus the expert
     layers' balance loss; under `exit_gate` the cross-entropy of every
     pass weighed by the probability of leaving there, less
@@ -1446,9 +1490,11 @@ def _loss_fn(params, seqs, targets, p: SeqRecParams, mesh=None):
     (the expert layers' balance numbers, the layer passes run by mixer,
     under `exit_gate` each pass's own loss `loop_loss` [R] and its mean
     exit probability `exit_share` [R], under `mtp_layers` the module's
-    own loss `mtp_loss`, which joins the loss `mtp_loss_weight` times)."""
+    own loss `mtp_loss`, which joins the loss `mtp_loss_weight` times).
+    `packed`: `_forward`'s (a packed row's targets are each session's own
+    shift, so the loss is the same sum over the same targets)."""
     passes, expert_layers, mixers, module_state = _forward(
-        params, seqs, p, mesh, targets)
+        params, seqs, p, mesh, targets, packed)
     head = head_matrix(params)
 
     def nll_of(hid, tgt):
@@ -1584,9 +1630,11 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
     by expert; the same under `expert_update_by_expert`). With a mesh,
     batch is
     sharded over "data" and embedding/ffn rows over "model"; XLA inserts
-    the psums."""
+    the psums. Under `packing` the step takes two more arrays [B, L]
+    behind the targets: each position's session id and its position
+    inside its session (`pack_sessions`)."""
 
-    def step(params, opt_state, seqs, targets):
+    def step(params, opt_state, seqs, targets, *packed):
         if mesh is not None and "data" in mesh.axis_names:
             # with ring attention the sequence dim lives on "seq"; laying
             # the tokens out that way up front saves XLA a full reshard
@@ -1594,6 +1642,8 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
             sh = NamedSharding(mesh, P("data", seq_dim))
             seqs = jax.lax.with_sharding_constraint(seqs, sh)
             targets = jax.lax.with_sharding_constraint(targets, sh)
+            packed = tuple(jax.lax.with_sharding_constraint(t, sh)
+                           for t in packed)
         routes, rule_routes, product_routes = set(), set(), set()
         layouts, conv_routes = set(), set()
         with routes_into(routes, layouts), \
@@ -1601,7 +1651,7 @@ def make_train_step(mesh: Optional[Mesh], p: SeqRecParams, optimizer):
                 moe.routes_into(product_routes):
             (loss, (expert_layers, mixers, exits)), grads = \
                 jax.value_and_grad(_loss_fn, has_aux=True)(
-                    params, seqs, targets, p, mesh)
+                    params, seqs, targets, p, mesh, packed or None)
         with jax.named_scope("seqrec_optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
         with jax.named_scope("seqrec_record"):
@@ -1708,6 +1758,67 @@ def pad_sessions(sessions: Sequence[Sequence[int]], max_len: int
     return inputs, targets
 
 
+@dataclasses.dataclass(frozen=True)
+class PackedRows:
+    """`pack_sessions`' rows, [rows, max_len] int32 each: `inputs` and
+    `targets` as `pad_sessions`' (each session's own shift, 0 = padding,
+    a row's unfilled tail), `ids` each position's session in its row (0 =
+    padding, from 1, rising by one along the row), `positions` its place
+    inside its session (from 0); `sessions` [row]: the sessions laid
+    into it, in order, as indices into the sessions given."""
+
+    inputs: np.ndarray
+    targets: np.ndarray
+    ids: np.ndarray
+    positions: np.ndarray
+    sessions: Tuple[Tuple[int, ...], ...]
+
+
+def pack_sessions(sessions: Sequence[Sequence[int]], max_len: int
+                  ) -> PackedRows:
+    """Sessions of 1-based item ids -> rows of `max_len` positions that
+    hold whole sessions one after another: first-fit over the sessions
+    in decreasing length (ties in the order given), each laid into the
+    first row with room for it, a new row where none has. A session is
+    its inputs and targets as `pad_sessions` makes them (n items give n -
+    1 positions; the LAST max_len + 1 items of a longer one), so no
+    position's target is another session's item; a session of fewer than
+    two items takes no room. Deterministic: the same sessions, the same
+    rows."""
+    spans = [min(len(s), max_len + 1) - 1 for s in sessions]
+    order = sorted((i for i, n in enumerate(spans) if n > 0),
+                   key=lambda i: -spans[i])
+    room: List[int] = []                  # what each row still takes
+    members: List[List[int]] = []
+    # rows that are full never take another session: the search starts
+    # behind the leading run of them
+    first_open = 0
+    for i in order:
+        row = next((r for r in range(first_open, len(room))
+                    if room[r] >= spans[i]), len(room))
+        if row == len(room):
+            room.append(max_len)
+            members.append([])
+        room[row] -= spans[i]
+        members[row].append(i)
+        while first_open < len(room) and room[first_open] == 0:
+            first_open += 1
+    shape = (len(room), max_len)
+    inputs, targets, ids, positions = (np.zeros(shape, np.int32)
+                                       for _ in range(4))
+    for row, inside in enumerate(members):
+        at = 0
+        for n, i in enumerate(inside):
+            s = np.asarray(sessions[i][-(max_len + 1):], np.int32)
+            span = slice(at, at + len(s) - 1)
+            inputs[row, span], targets[row, span] = s[:-1], s[1:]
+            ids[row, span] = n + 1
+            positions[row, span] = np.arange(len(s) - 1)
+            at = span.stop
+    return PackedRows(inputs, targets, ids, positions,
+                      tuple(map(tuple, members)))
+
+
 def _on_one_device(leaf) -> jax.Array:
     """A weight where the serving forward pass, a program for one device,
     wants it: a trained leaf stays where it is unless a mesh holds it."""
@@ -1798,7 +1909,9 @@ def seqrec_fingerprint(item_vocab: np.ndarray, p: SeqRecParams,
 
 def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
                  p: SeqRecParams, checkpointer=None) -> SeqRecModel:
-    """End-to-end: id-assign, pad, adamw train, return pickled-friendly
+    """End-to-end: id-assign, pad (or, under `packing`, pack: a row is
+    then several whole sessions, `pack_sessions`, and a batch is
+    `batch_size` rows), adamw train, return pickled-friendly
     model. `sessions` are per-user time-ordered item-id lists. With a
     `workflow.checkpoint.Checkpointer`, (params, opt_state) snapshot every
     `interval` epochs and a preempted run resumes from the latest one.
@@ -1809,7 +1922,10 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
     the update is what the step added to the group's parameters, the
     selection bias's own update with its router's), `rows` (the
     sessions of the batch, as indices into the sessions of two events or
-    more, in the order given) and, with expert layers, `load` [expert
+    more, in the order given; under `packing` the batch's rows, as
+    indices into `pack_sessions`' rows, and beside them `sessions`: for
+    each of those rows the sessions laid into it, in order, as such
+    indices) and, with expert layers, `load` [expert
     layer, routed expert], `held_tokens` [expert layer, held expert] and
     `dropped` [expert layer] (under `n_loops` an expert layer once a
     pass, the first pass's layers first; the multi-token-prediction
@@ -1825,6 +1941,9 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
     if _rings(mesh) and unringed:
         raise ValueError(f'a mesh with a "seq" axis (ring attention) does '
                          f'not go with the mixers {unringed}')
+    if _rings(mesh) and p.packing:
+        raise ValueError('a mesh with a "seq" axis (ring attention) does '
+                         'not go with packing')
     with span("seqrec_prepare"):
         all_items = np.asarray(sorted({it for s in sessions for it in s}),
                                dtype=object)
@@ -1832,7 +1951,13 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
         coded = [[code[it] for it in s] for s in sessions if len(s) >= 2]
         if not coded:
             raise ValueError("need at least one session with >= 2 events")
-        inputs, targets = pad_sessions(coded, p.max_len)
+        packed = None
+        if p.packing:
+            with span("seqrec_pack"):
+                packed = pack_sessions(coded, p.max_len)
+            inputs, targets = packed.inputs, packed.targets
+        else:
+            inputs, targets = pad_sessions(coded, p.max_len)
         fp = seqrec_fingerprint(all_items, p, sessions)
 
     with span("seqrec_init"):
@@ -1922,7 +2047,10 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
                 t0 = time.perf_counter()
                 params, opt_state, stats = step(
                     params, opt_state, jnp.asarray(inputs[idx]),
-                    jnp.asarray(targets[idx]))
+                    jnp.asarray(targets[idx]),
+                    *(() if packed is None else (
+                        jnp.asarray(packed.ids[idx]),
+                        jnp.asarray(packed.positions[idx]))))
                 # a step's numbers are ready when its program has ended
                 jax.block_until_ready(stats["loss"])
                 # a warm step only: compile seconds would drown the step's
@@ -1963,6 +2091,9 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
         # in the one order whatever built the tree
         params = jax.tree.unflatten(treedef, leaves)
         record = _training_record(steps, rows)
+        if packed is not None:
+            record["sessions"] = [[list(packed.sessions[r]) for r in idx]
+                                  for idx in rows]
     train_stats.seqrec_fetch_bytes().inc(sum(leaf.nbytes for leaf in leaves))
     # one compiled step made every step of the train: one route a kind
     # of layer, one pattern of mixers
@@ -1975,13 +2106,27 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
     # of the mixers under a window: a session and head's pairs inside
     # the band and in the blocks its route visits, a layer each
     window_pairs = None
+    # of a packed train's rows, by the scope of the layers' kind: the
+    # pairs of one session its queries see and the pairs of the block
+    # pairs its route multiplied for them, a row, layer and query head
+    packed_pairs: Optional[Dict[str, Tuple[int, int]]] = None
+    if packed is not None:
+        packed_pairs = {}
+        trained = packed.ids[np.concatenate(rows)] if rows \
+            else packed.ids[:0]
+    route = (jax.devices()[0].device_kind, ATTENTION_BLOCK,
+             1 if mesh is None else mesh.size)
     for kind, n in mixer_layers.items():
         band = p.held_kind(kind)
-        if getattr(band, "window", None):
-            pairs = band_pairs(
-                jax.devices()[0].device_kind, p.max_len, band.head_dim,
-                band.head_dim, band.window, ATTENTION_BLOCK,
-                1 if mesh is None else mesh.size)
+        if packed is not None:
+            pairs = session_pairs(route[0], trained, band.head_dim,
+                                  band.head_dim, band.window, *route[1:])
+            packed_pairs[band.scope] = tuple(
+                n * new + old for new, old in zip(
+                    pairs, packed_pairs.get(band.scope, (0, 0))))
+        elif getattr(band, "window", None):
+            pairs = band_pairs(route[0], p.max_len, band.head_dim,
+                               band.head_dim, band.window, *route[1:])
             window_pairs = tuple(n * new + old for new, old in zip(
                 pairs, window_pairs or (0, 0)))
     train_stats.observe_seqrec_record(
@@ -1994,7 +2139,10 @@ def train_seqrec(mesh: Optional[Mesh], sessions: Sequence[Sequence[str]],
         if steps and "layer_passes" in steps[0] else None,
         "rows" if steps and steps[0].get("attention_rows") else "heads",
         "pallas" if steps and steps[0].get("short_conv_pallas") else "xla",
-        window_pairs)
+        window_pairs,
+        None if packed is None else [[len(packed.sessions[r]) for r in idx]
+                                     for idx in rows],
+        packed_pairs)
     return SeqRecModel(item_vocab=all_items, params=params, hyper=p,
                        record=record)
 

@@ -291,6 +291,61 @@ def seqrec_window_block_pairs(registry: MetricsRegistry = None):
         "(ops/attention.band_pairs)")
 
 
+def seqrec_rows(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_rows_total",
+        "Rows of the trained batches of a packed train (models/seqrec."
+        "pack_sessions: several whole sessions a row)")
+
+
+def seqrec_packed_sessions(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_packed_sessions_total",
+        "Sessions laid into the rows of the trained batches of a packed "
+        "train")
+
+
+def seqrec_packed_attention_pairs(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_packed_attention_pairs_total",
+        "(query, key) pairs of one session (causal) that the full-"
+        "attention layers of a packed train's batches see, a row, layer "
+        "and query head")
+
+
+def seqrec_packed_attention_block_pairs(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_packed_attention_block_pairs_total",
+        "Pairs of the block pairs that the route of a packed train's "
+        "full-attention layers multiplied, a row, layer and query head "
+        "(ops/attention.session_pairs)")
+
+
+def seqrec_packed_window_pairs(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_packed_window_pairs_total",
+        "(query, key) pairs of one session (causal, inside the band) that "
+        "the sliding-window layers of a packed train's batches see, a "
+        "row, layer and query head")
+
+
+def seqrec_packed_window_block_pairs(registry: MetricsRegistry = None):
+    return (registry or default_registry()).counter(
+        "pio_train_seqrec_packed_window_block_pairs_total",
+        "Pairs of the block pairs that the route of a packed train's "
+        "sliding-window layers multiplied, a row, layer and query head "
+        "(ops/attention.session_pairs)")
+
+
+#: a packed train's pair counters by the scope of the layers' kind: (the
+#: pairs that count, the pairs of the block pairs multiplied for them)
+_PACKED_PAIRS = {
+    "seqrec_attention": (seqrec_packed_attention_pairs,
+                         seqrec_packed_attention_block_pairs),
+    "seqrec_window_attention": (seqrec_packed_window_pairs,
+                                seqrec_packed_window_block_pairs)}
+
+
 def seqrec_layer_pass_tokens(registry: MetricsRegistry = None):
     return (registry or default_registry()).counter(
         "pio_train_seqrec_layer_pass_tokens_total",
@@ -362,7 +417,9 @@ def observe_seqrec_record(record: dict, targets, rows,
                           layer_passes: dict = None,
                           attention_layout: str = "heads",
                           short_conv_impl: str = "xla",
-                          window_pairs: tuple = None) -> None:
+                          window_pairs: tuple = None,
+                          row_sessions: list = None,
+                          packed_pairs: dict = None) -> None:
     """The token and expert counters from one train's record
     (models/seqrec.train_seqrec): `targets` the padded target ids of all
     sessions, `rows` the sessions of each step's batch, `attention_impl`
@@ -377,7 +434,11 @@ def observe_seqrec_record(record: dict, targets, rows,
     short-convolution layers' chain was traced on, `window_pairs` (the
     pairs inside the band, the pairs of the blocks visited) of a session
     and head over its step's sliding-window layers (None: no such
-    layer)."""
+    layer, or a packed train); of a packed train (`rows` then its
+    batches' rows, padding their unfilled tails) `row_sessions` [step,
+    row], the sessions laid into each row, and `packed_pairs` {scope:
+    (pairs that count, pairs multiplied)} over all its rows and each
+    scope's layers (`_PACKED_PAIRS`)."""
     import numpy as np
 
     real = sum(int((targets[r] > 0).sum()) for r in rows)
@@ -406,6 +467,12 @@ def observe_seqrec_record(record: dict, targets, rows,
         sessions = sum(len(r) for r in rows)
         seqrec_window_band_pairs().inc(sessions * window_pairs[0])
         seqrec_window_block_pairs().inc(sessions * window_pairs[1])
+    if row_sessions is not None:
+        seqrec_rows().inc(sum(len(step) for step in row_sessions))
+        seqrec_packed_sessions().inc(sum(map(sum, row_sessions)))
+    for scope, pairs in (packed_pairs or {}).items():
+        for counter, n in zip(_PACKED_PAIRS[scope], pairs):
+            counter().inc(n)
     if "linear_attention" in family_layers:
         for counter in (seqrec_linear_attention_tokens,
                         seqrec_linear_attention_chain_tokens):

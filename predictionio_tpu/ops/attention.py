@@ -192,15 +192,20 @@ def split_heads(qkv: jax.Array, heads: int,
 
 
 def _block_scores(q_i, k_j, mask_j, i, j, block_q, block_k, causal, scale,
-                  window=None):
+                  window=None, ids_i=None):
     """Masked scores [B, H, bq, bk] of query block i against key block j
     (NEG_INF where the key is padding, lies in the causal future or
-    behind the window)."""
+    behind the window). `ids_i` (packed rows): the query block's session
+    ids [B, bq] beside the keys' in `mask_j`; a key of another session
+    is masked too."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q_i, k_j,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         s = _causal_mask(s, i * block_q, j * block_k, window)
-    return jnp.where(mask_j[:, None, None, :], s, NEG_INF)
+    if ids_i is None:
+        return jnp.where(mask_j[:, None, None, :], s, NEG_INF)
+    seen = (mask_j > 0)[:, None, :] & (mask_j[:, None, :] == ids_i[:, :, None])
+    return jnp.where(seen[:, None], s, NEG_INF)
 
 
 def _block_pairs(n_q: int, n_k: int, block_q: int, block_k: int,
@@ -224,16 +229,25 @@ def _put_rows(x, i, n, rows):
     return jax.lax.dynamic_update_slice_in_dim(x, rows, i * n, axis=2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _blockwise(q, k, v, key_mask, block_q, block_k, causal, window=None):
+def _cols(mask, j, n):
+    """Columns [j*n, (j+1)*n) of a key mask (or a row's ids) [B, L]."""
+    return jax.lax.dynamic_slice_in_dim(mask, j * n, n, axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _blockwise(q, k, v, key_mask, block_q, block_k, causal, window=None,
+               packed=False):
     return _blockwise_fwd(q, k, v, key_mask, block_q, block_k, causal,
-                          window)[0]
+                          window, packed)[0]
 
 
-def _blockwise_fwd(q, k, v, key_mask, block_q, block_k, causal, window=None):
+def _blockwise_fwd(q, k, v, key_mask, block_q, block_k, causal, window=None,
+                   packed=False):
     """q [B, H, Lq, Dk], k [B, H, Lk, Dk], v [B, H, Lk, Dv], lengths
     block multiples. One scan over the block pairs; the running
-    (output, max, denominator) of every query block live in the carry."""
+    (output, max, denominator) of every query block live in the carry.
+    `packed`: key_mask holds a row's session ids (0 = padding), the
+    queries' as the keys' (Lq = Lk)."""
     b, h, lq, dk = q.shape
     dv = v.shape[-1]
     scale = dk ** -0.5
@@ -244,9 +258,9 @@ def _blockwise_fwd(q, k, v, key_mask, block_q, block_k, causal, window=None):
         o, m, l = carry
         i, j = ij[0], ij[1]
         s = _block_scores(_rows(q, i, block_q), _rows(k, j, block_k),
-                          jax.lax.dynamic_slice_in_dim(
-                              key_mask, j * block_k, block_k, axis=1),
-                          i, j, block_q, block_k, causal, scale, window)
+                          _cols(key_mask, j, block_k),
+                          i, j, block_q, block_k, causal, scale, window,
+                          _cols(key_mask, i, block_q) if packed else None)
         m_i, l_i = _rows(m, i, block_q), _rows(l, i, block_q)
         m_new = jnp.maximum(m_i, s.max(axis=-1))
         alpha = jnp.exp(m_i - m_new)
@@ -268,7 +282,7 @@ def _blockwise_fwd(q, k, v, key_mask, block_q, block_k, causal, window=None):
     return out, (q, k, v, key_mask, out, m + jnp.log(l))
 
 
-def _blockwise_bwd(block_q, block_k, causal, window, res, d_out):
+def _blockwise_bwd(block_q, block_k, causal, window, packed, res, d_out):
     """The backward pass recomputes each block's probabilities from the
     saved log-sum-exp instead of keeping them: O(L x block) memory where
     differentiating the forward scan keeps every block's."""
@@ -284,9 +298,9 @@ def _blockwise_bwd(block_q, block_k, causal, window, res, d_out):
         i, j = ij[0], ij[1]
         q_i, k_j, v_j = (_rows(q, i, block_q), _rows(k, j, block_k),
                          _rows(v, j, block_k))
-        s = _block_scores(q_i, k_j, jax.lax.dynamic_slice_in_dim(
-            key_mask, j * block_k, block_k, axis=1),
-            i, j, block_q, block_k, causal, scale, window)
+        s = _block_scores(q_i, k_j, _cols(key_mask, j, block_k),
+                          i, j, block_q, block_k, causal, scale, window,
+                          _cols(key_mask, i, block_q) if packed else None)
         p = jnp.where(s > NEG_INF / 2,
                       jnp.exp(s - _rows(lse, i, block_q)[..., None]), 0.0)
         do_i = _rows(d_out, i, block_q)
@@ -359,6 +373,46 @@ def band_pairs(device_kind: str, length: int, dk: int, dv: int, window: int,
     return inside, visited * block * block
 
 
+def session_pairs(device_kind: str, ids, dk: int, dv: int,
+                  window: Optional[int] = None, block_k: int = 512,
+                  devices: int = 1) -> Tuple[int, int]:
+    """Of packed rows and one head, ids [B, L] (numpy: each position's
+    session id, 0 = padding, rising by one along a row), causal, under a
+    `window` or none: (the (query, key) pairs that count: of one
+    session, `sees`' own count; the pairs of the block pairs that
+    `attention_route`'s implementation multiplies for them: on the
+    kernels' route the pairs of its table that hold a query and a key
+    of one session (`attention_pallas.session_pair`), on the scan's its
+    whole table). `band_pairs` for rows of many sessions."""
+    import numpy as np
+
+    ids = np.asarray(ids)
+    length = ids.shape[1]
+    block, _, pad, _ = _blocks_and_pads(length, length, block_k, None)
+    ids = np.pad(ids, ((0, 0), (0, pad)))
+    kernels = attention_route(device_kind, length, length, dk, dv, block_k,
+                              devices=devices, window=window) == "pallas"
+    if kernels:
+        block = attention_pallas._block(length + pad, window)
+    n = (length + pad) // block
+    pairs = attention_pallas._block_pairs(n, n, block, block, True, False,
+                                          window)
+    inside = multiplied = 0
+    for row in ids:
+        sizes = np.bincount(row[row > 0]).astype(np.int64)
+        near = sizes if window is None else np.minimum(sizes, window)
+        inside += int((near * (near + 1) // 2 + (sizes - near) * near).sum())
+        if kernels:
+            first, last = attention_pallas.session_blocks(row[None], block)
+            does, _ = attention_pallas.session_pair(
+                first[0, pairs[:, 0]], last[0, pairs[:, 0]],
+                first[0, pairs[:, 1]], last[0, pairs[:, 1]])
+            multiplied += int(does.sum()) * block * block
+        else:
+            multiplied += len(pairs) * block * block
+    return inside, multiplied
+
+
 def attention_layout(device_kind: Optional[str], lq: int, lk: int, dk: int,
                      dv: int, block_k: int = 512,
                      block_q: Optional[int] = None, devices: int = 1,
@@ -421,7 +475,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         key_mask: Optional[jax.Array] = None,
                         block_q: Optional[int] = None,
                         devices: int = 1,
-                        window: Optional[int] = None) -> jax.Array:
+                        window: Optional[int] = None,
+                        packed: bool = False) -> jax.Array:
     """Flash-style single-device attention: stream over blocks of queries
     and of keys with the running-max/denominator recurrence so the
     [Lq, Lk] score matrix never materializes, forward or backward
@@ -444,7 +499,14 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     them token-first) or by a scan of XLA operations. With a `window`
     (causal, >= 1) a query sees its own key and the window - 1 before it
     (`attention_pallas.sees`); block pairs wholly behind the band are
-    visited on neither route."""
+    visited on neither route. `packed` (causal, Lq = Lk): a row holds
+    several sessions, key_mask [B, L] int32 each position's session id (0
+    = padding, ids rising by one along a row), and a query sees the keys
+    of its own session alone, on either route."""
+    if packed and not (causal and key_mask is not None
+                       and q.shape[1] == k.shape[1]):
+        raise ValueError("packed rows are causal, attend to themselves "
+                         "and come with their session ids")
     if window is not None and not (causal and window >= 1):
         raise ValueError(f"a window of {window} keys, causal {causal}: a "
                          f"window is causal and holds the query's own key")
@@ -470,14 +532,17 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     _hear("heads" if route == "pallas" else "xla")
     if route == "pallas":
         ops = heads_first(q), heads_first(k), heads_first(v), key_mask
-        out = attention_pallas.flash_attention_pallas(*ops, causal) \
-            if window is None \
-            else attention_pallas.window_attention_pallas(*ops, window)
+        if packed:
+            out = attention_pallas.packed_attention_pallas(*ops, window)
+        elif window is None:
+            out = attention_pallas.flash_attention_pallas(*ops, causal)
+        else:
+            out = attention_pallas.window_attention_pallas(*ops, window)
     else:
         if group > 1:
             k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
         out = _blockwise(heads_first(q), heads_first(k), heads_first(v),
-                         key_mask, block_q, block_k, causal, window)
+                         key_mask, block_q, block_k, causal, window, packed)
     return heads_first(out)[:, :lq]
 
 
@@ -520,8 +585,10 @@ def rotary_attention(qkv: jax.Array, heads: int, theta: float,
 
 def rotary_tables(length: int, width: int, theta: float,
                   rotary_dim: Optional[int] = None,
-                  scaling: Optional[YarnScaling] = None):
-    """`rope` on positions 0 .. L - 1 as tables for a head's columns
+                  scaling: Optional[YarnScaling] = None,
+                  positions: Optional[jax.Array] = None):
+    """`rope` on positions 0 .. L - 1 (or on `positions` [B, L], a batch
+    row's own: tables [B, L, width]) as tables for a head's columns
     where they lie -> ((cos, a signed sine a roll) [L, width] float32,
     the rolls' distances): a head x [., width] turns into x cos + sum_s
     roll(x, s) sin_s, roll(x, s)[i] = x[i - s]. Column i of the leading
@@ -535,12 +602,14 @@ def rotary_tables(length: int, width: int, theta: float,
     float32 expressions."""
     rotary_dim = width if rotary_dim is None else min(rotary_dim, width)
     half, rest = rotary_dim // 2, width - rotary_dim
-    ang = _rotary_angles(jnp.arange(length), theta, rotary_dim, scaling)
+    ang = _rotary_angles(jnp.arange(length) if positions is None
+                         else positions, theta, rotary_dim, scaling)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     if scaling is not None:
         cos, sin = (t * scaling.amplitude() for t in (cos, sin))
     none = jnp.zeros_like(sin)
-    passing = lambda fill: jnp.full((length, rest), fill, jnp.float32)
+    passing = lambda fill: jnp.full((*ang.shape[:-1], rest), fill,
+                                    jnp.float32)
     cos = jnp.concatenate([cos, cos, passing(1.0)], axis=-1)
     if not rest:
         return (cos, jnp.concatenate([-sin, sin], axis=-1)), (half,)
@@ -556,7 +625,8 @@ def grouped_attention(q: jax.Array, k: jax.Array, v: jax.Array, width: int,
                       scaling: Optional[YarnScaling] = None,
                       window: Optional[int] = None, block_k: int = 512,
                       key_mask: Optional[jax.Array] = None,
-                      operand_dtype=None) -> jax.Array:
+                      operand_dtype=None,
+                      positions: Optional[jax.Array] = None) -> jax.Array:
     """Causal attention of grouped query heads on three projections'
     outputs, token-first from them to its output: q [B, L, H x width],
     k, v [B, L, Hkv x width] -> [B, L, H x width], key/value head j
@@ -575,7 +645,11 @@ def grouped_attention(q: jax.Array, k: jax.Array, v: jax.Array, width: int,
     caller's products alone read: the three projections' gradients and
     the gated output, `@ wo`'s operand (a caller whose products run at
     the TPU's default precision may say bfloat16: they round these so
-    themselves); the results are float32 either way."""
+    themselves); the results are float32 either way. With `positions`
+    [B, L] the rows are packed (`blockwise_attention`'s `packed`): each
+    holds several sessions, key_mask [B, L] int32 is a position's session
+    id and `positions` its place inside its session, which the rotary
+    tables are read by."""
     b, l, _ = q.shape
     assert attention_pallas.layout(width, width) == "rows", width
     _hear("rows")
@@ -587,12 +661,14 @@ def grouped_attention(q: jax.Array, k: jax.Array, v: jax.Array, width: int,
         key_mask = jnp.pad(key_mask, ((0, 0), (0, pad)))
         if gate is not None:
             gate = jnp.pad(gate, ((0, 0), (0, pad), (0, 0)))
+        if positions is not None:
+            positions = jnp.pad(positions, ((0, 0), (0, pad)))
     tables, shifts = ((), ()) if theta is None else rotary_tables(
-        l + pad, width, theta, rotary_dim, scaling)
+        l + pad, width, theta, rotary_dim, scaling, positions)
     return attention_pallas.grouped_attention_pallas(
         q, k, v, gate, key_mask, tables,
         (q.shape[-1] // width, k.shape[-1] // width), shifts, window,
-        operand_dtype=operand_dtype)[:, :l]
+        operand_dtype=operand_dtype, packed=positions is not None)[:, :l]
 
 
 def _ring_attention_local(q, k, v, key_mask, *, axis: str, causal: bool,

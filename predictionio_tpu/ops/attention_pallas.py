@@ -15,6 +15,13 @@ diagonal, or one that holds a padding key, builds a mask. Under a
 a second edge: a pair whose keys all lie behind it is no grid step
 either, a pair that edge cuts builds a mask too, the block is the band's
 own (`WINDOW_BLOCK`) and the kernels are named `window_attention_pallas_*`.
+Under `packed` a row holds several sessions one after another and the
+key mask is each position's session id (0 = padding, ids rising by one
+along a row): a query sees a key of its own session alone (`_keep`
+compares the two ids), and the per-block flags become each block's first
+and last id (`session_pair`): a pair whose blocks are one session
+throughout builds no mask for it, a pair whose blocks share no session
+does no products.
 
 * ``flash_attention_pallas_fwd``: pairs query-major, scores [bq, bk];
   output, maximum and denominator of a query block accumulate over its
@@ -80,7 +87,12 @@ BLOCK = 1024
 #: 36.1, 25.5, 34.6 (the whole causal triangle there: 37.9 and 107.0). Of
 #: the scores the visited blocks compute, 80%, 67%, 50% and 25% lie inside
 #: the band: a small block wastes less and pays more grid steps, and the
-#: triangle's 1024 computes four scores for one that counts.
+#: triangle's 1024 computes four scores for one that counts. Under a window
+#: of 1,024 at 2 x 32 (4 key/value) heads x 8,192 positions x 128 (PR 47,
+#: `tools/seqrec_packed_probe.py --sweep`), a session a row: forward 7.14
+#: ms at 512, 6.74 at 1024; forward + backward 16.14, 16.75 (67% and 50%
+#: of the visited scores inside the band); on packed rows (`packed`) 6.89,
+#: 7.19 and 16.06, 18.32 (37% and 22% inside band and session): 512 stays.
 WINDOW_BLOCK = 512
 
 #: the device kinds (`jax.Device.device_kind`) the block and the two limits
@@ -144,6 +156,37 @@ def sees(query, key, causal: bool, window: Optional[int] = None):
     return seen
 
 
+#: the id a padding position takes in a packed row's block table, past
+#: every session's: ids then rise along the whole row (padding is a
+#: row's tail)
+PAD_ID = 2 ** 30
+
+
+def session_pair(first_q, last_q, first_k, last_k):
+    """Of a block pair of a packed row, from the first and the last
+    session id of its query block and of its key block (ids rise by one
+    along a row, padding reads `PAD_ID`; numbers or arrays): (whether
+    the pair holds a query and a key of one session: the two ranges of
+    ids meet and neither block is all padding; whether both blocks are
+    one and the same session throughout, so that no id need be
+    compared). The three states of a packed pair: no products, a mask,
+    no mask."""
+    does = (first_q < PAD_ID) & (first_k < PAD_ID) \
+        & (last_k >= first_q) & (first_k <= last_q)
+    one = (first_k == last_k) & (first_q == last_q) & (first_k == first_q)
+    return does, one
+
+
+def session_blocks(ids, block: int):
+    """ids [B, L] of a packed batch (0 = padding) -> (first, last) [B,
+    L / block]: each block's first and last session id, padding as
+    `PAD_ID` (numpy or jax arrays)."""
+    b, l = ids.shape
+    rising = (jnp if isinstance(ids, jax.Array) else np).where(
+        ids > 0, ids, PAD_ID).reshape(b, l // block, block)
+    return rising[:, :, 0], rising[:, :, -1]
+
+
 def _block_pairs(n_q: int, n_k: int, bq: int, bk: int, causal: bool,
                  key_major: bool,
                  window: Optional[int] = None) -> np.ndarray:
@@ -164,11 +207,16 @@ def _block_pairs(n_q: int, n_k: int, bq: int, bk: int, causal: bool,
     return np.asarray(pairs, np.int32).reshape(-1, 2)
 
 
-def _keep(mask, i, j, bq, bk, causal, keys_on_rows, window=None):
+def _keep(mask, i, j, bq, bk, causal, keys_on_rows, window=None,
+          queries=None):
     """Which scores of pair (i, j) count: the key is no padding and the
     query `sees` it. mask: the key block's, laid along the scores' key
-    axis."""
+    axis; `queries` (a packed row): the query block's session ids along
+    the scores' query axis, beside which `mask` holds the keys' ids: a
+    query sees the keys of its own session alone."""
     keep = mask > 0
+    if queries is not None:
+        keep = keep & (mask == queries)
     if not causal:
         return keep
     shape = (bk, bq) if keys_on_rows else (bq, bk)
@@ -180,11 +228,26 @@ def _keep(mask, i, j, bq, bk, causal, keys_on_rows, window=None):
 
 
 def _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal,
-                   window=None):
+                   window=None, packed=None):
     """Run fold(masked) with masked True only where the pair needs it: a
     padding key, or a corner an edge cuts off (the first query and the
     last key at the causal edge, the last query and the first key at the
-    band's trailing one)."""
+    band's trailing one). `packed` (a packed row; where the blocks' last
+    ids start in the table, behind their first): a pair of more than one
+    session needs it too, and one whose blocks share no session is not
+    run at all (`session_pair`)."""
+    if packed is not None:
+        at_q, at_k = b * n_k + i, b * n_k + j
+        does, one = session_pair(full_ref[at_q], full_ref[packed + at_q],
+                                 full_ref[at_k], full_ref[packed + at_k])
+        needs = jnp.logical_not(one)
+        if causal:
+            needs = needs | (j * bk + bk - 1 > i * bq)
+        if window is not None:
+            needs = needs | (j * bk <= i * bq + bq - 1 - window)
+        pl.when(does & needs)(lambda: fold(True))
+        pl.when(does & jnp.logical_not(needs))(lambda: fold(False))
+        return
     needs = full_ref[b * n_k + j] == 0
     if causal:
         needs = needs | (j * bk + bk - 1 > i * bq)
@@ -195,7 +258,11 @@ def _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal,
 
 
 def _fwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
-                *refs, scale, causal, bq, bk, n_k, save_lse, window=None):
+                *refs, scale, causal, bq, bk, n_k, save_lse, window=None,
+                packed=None):
+    qid_ref = None
+    if packed is not None:      # the query block's session ids [bq, 1]
+        qid_ref, *refs = refs
     if save_lse:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -219,7 +286,8 @@ def _fwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
         s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0], _NT,
                                 preferred_element_type=jnp.float32) * scale
         if masked:
-            keep = _keep(mask_ref[0], i, j, bq, bk, causal, False, window)
+            keep = _keep(mask_ref[0], i, j, bq, bk, causal, False, window,
+                         None if qid_ref is None else qid_ref[0])
             s = jnp.where(keep, s, NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -236,7 +304,8 @@ def _fwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
-    _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal, window)
+    _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal, window,
+                   packed)
 
     @pl.when(j == last_j)
     def _():
@@ -248,9 +317,12 @@ def _fwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
 
 
 def _bwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
-                do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, scale, causal, bq, bk, n_q, n_k,
-                window=None):
+                *refs, scale, causal, bq, bk, n_q, n_k, window=None,
+                packed=None):
+    qid_ref = None
+    if packed is not None:      # the query block's session ids [1, bq]
+        qid_ref, *refs = refs
+    do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
     b, p = pl.program_id(0), pl.program_id(2)
     i, j = qi_ref[p], kj_ref[p]
     first_i = (j * bk) // bq if causal else 0
@@ -274,8 +346,9 @@ def _bwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
                                 preferred_element_type=jnp.float32) * scale
         prob = jnp.exp(s - lse_ref[0, 0])
         if masked:
-            prob = jnp.where(_keep(mask_ref[0], i, j, bq, bk, causal, True,
-                                   window), prob, 0.0)
+            prob = jnp.where(_keep(
+                mask_ref[0], i, j, bq, bk, causal, True, window,
+                None if qid_ref is None else qid_ref[0]), prob, 0.0)
         dv_scr[...] += jnp.dot(prob.astype(do.dtype), do,
                                preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(v_ref[0, 0], do, _NT,
@@ -287,7 +360,8 @@ def _bwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
         dq_ref[0, 0, rows, :] += jnp.dot(ds.T.astype(k.dtype), k,
                                          preferred_element_type=jnp.float32)
 
-    _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal, window)
+    _masked_or_not(fold, full_ref, b, i, j, n_k, bq, bk, causal, window,
+                   packed)
 
     @pl.when(i == last_i)
     def _():
@@ -296,13 +370,17 @@ def _bwd_kernel(qi_ref, kj_ref, full_ref, q_ref, k_ref, v_ref, mask_ref,
 
 
 def _call(kernel, name, key_mask, pairs, heads, bk, in_specs, out_specs,
-          out_shape, scratch_shapes, interpret):
+          out_shape, scratch_shapes, interpret, packed=False):
     """The pallas_call over (batch row, head, pair), applied to the pair
     table: each pair's query and key block and, per (batch row, key
-    block), whether every key in it is real. Index maps see (b, h, pair,
-    qi, kj, full)."""
+    block), whether every key in it is real (`packed`: its first session
+    id and, behind those, its last: `session_blocks`). Index maps see
+    (b, h, pair, qi, kj, full)."""
     b, lk = key_mask.shape
-    full = key_mask.reshape(b, lk // bk, bk).all(axis=-1)
+    if packed:
+        full = jnp.stack(session_blocks(key_mask, bk))
+    else:
+        full = key_mask.reshape(b, lk // bk, bk).all(axis=-1)
     return functools.partial(
         pl.pallas_call(
             kernel, name=name, out_shape=out_shape,
@@ -364,10 +442,13 @@ def _shape(b, h, length, width, rows):
     return (b, 1, length, h * width) if rows else (b, h, length, width)
 
 
-def _kernel(kernel, name: str, window, **sizes):
+def _kernel(kernel, name: str, window, packed=None, **sizes):
     """The kernel at its sizes and its name in a device trace: a
     windowed call's is its own (`window_attention_pallas_*`), so that the
-    band's time reads apart from the whole-causal calls'."""
+    band's time reads apart from the whole-causal calls'. `packed`: of a
+    packed batch, where the blocks' last ids start in the table."""
+    if packed is not None:
+        sizes["packed"] = packed
     if window is None:
         return functools.partial(kernel, **sizes), \
             f"flash_attention_pallas_{name}"
@@ -375,12 +456,28 @@ def _kernel(kernel, name: str, window, **sizes):
         f"window_attention_pallas_{name}"
 
 
+def _packed_at(packed, b, lq, lk, bq, bk):
+    """Where a packed batch's table holds its blocks' last ids (None:
+    the batch is not packed). One row of ids serves queries and keys, so
+    both lengths and both blocks are the same."""
+    if not packed:
+        return None
+    if lq != lk or bq != bk:
+        raise ValueError(f"packed rows attend to themselves: {lq} queries "
+                         f"and {lk} keys in blocks of {bq} and {bk}")
+    return b * (lk // bk)
+
+
 def _forward(q, k, v, key_mask, causal, bq, bk, interpret, save_lse,
-             heads=None, window=None):
+             heads=None, window=None, packed=False):
     b, h, kv, lq, lk, dk, dv = _sizes(q, k, v, heads)
     rows = heads is not None
     pairs = _block_pairs(lq // bq, lk // bk, bq, bk, causal, False, window)
     by_query, _, by_kv_head, _ = _specs(bq, bk, h // kv, rows)
+    # a packed row's ids once more, along the scores' query axis
+    query_ids = [pl.BlockSpec((1, bq, 1),
+                              lambda b, h, p, qi, kj, full: (b, qi[p], 0))] \
+        if packed else []
     out_shape = [jax.ShapeDtypeStruct(_shape(b, h, lq, dv, rows),
                                       jnp.float32)]
     out_specs = [by_query(dv)]
@@ -388,20 +485,24 @@ def _forward(q, k, v, key_mask, causal, bq, bk, interpret, save_lse,
         out_shape.append(jax.ShapeDtypeStruct((b, h, lq, 1), jnp.float32))
         out_specs.append(_specs(bq, bk)[0](1))
     return _call(
-        *_kernel(_fwd_kernel, "fwd", window, scale=dk ** -0.5, causal=causal,
-                 bq=bq, bk=bk, n_k=lk // bk, save_lse=save_lse),
+        *_kernel(_fwd_kernel, "fwd", window,
+                 _packed_at(packed, b, lq, lk, bq, bk), scale=dk ** -0.5,
+                 causal=causal, bq=bq, bk=bk, n_k=lk // bk,
+                 save_lse=save_lse),
         key_mask, pairs, h, bk,
         [by_query(dk), by_kv_head(dk), by_kv_head(dv),
          pl.BlockSpec((1, 1, bk),
-                      lambda b, h, p, qi, kj, full: (b, 0, kj[p]))],
+                      lambda b, h, p, qi, kj, full: (b, 0, kj[p]))]
+        + query_ids,
         out_specs, out_shape,
         [pltpu.VMEM((bq, 1), jnp.float32), pltpu.VMEM((bq, 1), jnp.float32),
-         pltpu.VMEM((bq, dv), jnp.float32)], interpret)(
-        q, k, v, key_mask.astype(jnp.int32)[:, None, :])
+         pltpu.VMEM((bq, dv), jnp.float32)], interpret, packed)(
+        q, k, v, key_mask.astype(jnp.int32)[:, None, :],
+        *([key_mask.astype(jnp.int32)[:, :, None]] if packed else []))
 
 
 def _backward(q, k, v, key_mask, d_out, lse, delta, causal, bq, bk,
-              interpret, heads=None, window=None):
+              interpret, heads=None, window=None, packed=False):
     b, h, kv, lq, lk, dk, dv = _sizes(q, k, v, heads)
     rows = heads is not None
     pairs = _block_pairs(lq // bq, lk // bk, bq, bk, causal, True, window)
@@ -409,20 +510,25 @@ def _backward(q, k, v, key_mask, d_out, lse, delta, causal, bq, bk,
     by_query, by_key, by_kv_head, whole = _specs(bq, bk, group, rows)
     row = pl.BlockSpec((1, 1, 1, bq),
                        lambda b, h, p, qi, kj, full: (b, h, 0, qi[p]))
+    query_ids = [pl.BlockSpec((1, 1, bq),
+                              lambda b, h, p, qi, kj, full: (b, 0, qi[p]))] \
+        if packed else []
     dq, d_k, d_v = _call(
-        *_kernel(_bwd_kernel, "bwd", window, scale=dk ** -0.5, causal=causal,
-                 bq=bq, bk=bk, n_q=lq // bq, n_k=lk // bk),
+        *_kernel(_bwd_kernel, "bwd", window,
+                 _packed_at(packed, b, lq, lk, bq, bk), scale=dk ** -0.5,
+                 causal=causal, bq=bq, bk=bk, n_q=lq // bq, n_k=lk // bk),
         key_mask, pairs, h, bk,
         [by_query(dk), by_kv_head(dk), by_kv_head(dv),
          pl.BlockSpec((1, bk, 1),
-                      lambda b, h, p, qi, kj, full: (b, kj[p], 0)),
-         by_query(dv), row, row],
+                      lambda b, h, p, qi, kj, full: (b, kj[p], 0))]
+        + query_ids + [by_query(dv), row, row],
         [whole(lq, dk), by_key(dk), by_key(dv)],
         [jax.ShapeDtypeStruct(_shape(b, h, length, width, rows), jnp.float32)
          for length, width in ((lq, dk), (lk, dk), (lk, dv))],
         [pltpu.VMEM((bk, dk), jnp.float32),
-         pltpu.VMEM((bk, dv), jnp.float32)], interpret)(
-        q, k, v, key_mask.astype(jnp.int32)[:, :, None], d_out,
+         pltpu.VMEM((bk, dv), jnp.float32)], interpret, packed)(
+        q, k, v, key_mask.astype(jnp.int32)[:, :, None],
+        *([key_mask.astype(jnp.int32)[:, None, :]] if packed else []), d_out,
         lse[:, :, None, :], delta[:, :, None, :])
     seen = -(-lq // bk) * bk      # causal keys past every query: no pair
     if causal and seen < lk:
@@ -454,12 +560,14 @@ def flash_attention_pallas(q, k, v, key_mask, causal: bool,
 
 
 def _fwd(q, k, v, key_mask, causal, interpret, save_lse=True, heads=None,
-         dtype=None, window=None):
+         dtype=None, window=None, packed=False):
     """-> (the output in `dtype`, the operands' by default; what the
     backward pass keeps). With `heads` = (H, Hkv) the operands lie
     token-first, where a projection writes them (`layout` "rows"): q [B,
     L, H x Dk], k [B, L, Hkv x Dk], v [B, L, Hkv x Dv] -> [B, L, H x Dv],
-    and so do `_bwd`'s gradients."""
+    and so do `_bwd`'s gradients. `packed`: key_mask [B, L] holds each
+    position's session id (0 = padding) and a query sees its own
+    session's keys alone."""
     if not q.dtype == k.dtype == v.dtype:
         raise ValueError(f"q, k, v of one dtype expected, got {q.dtype}, "
                          f"{k.dtype}, {v.dtype}")
@@ -468,7 +576,7 @@ def _fwd(q, k, v, key_mask, causal, interpret, save_lse=True, heads=None,
         ops = tuple(t[:, None] for t in ops)
     bq, bk = (_block(t.shape[2], window) for t in ops[:2])
     out, *lse = _forward(*ops, key_mask, causal, bq, bk, interpret, save_lse,
-                         heads, window)
+                         heads, window, packed)
     out = out.astype(dtype or q.dtype)
     shown = out if heads is None else out[:, 0]
     if not save_lse:
@@ -476,7 +584,8 @@ def _fwd(q, k, v, key_mask, causal, interpret, save_lse=True, heads=None,
     return shown, (*ops, key_mask, out, lse[0][..., 0])
 
 
-def _bwd(causal, interpret, res, d_out, heads=None, window=None, delta=None):
+def _bwd(causal, interpret, res, d_out, heads=None, window=None, delta=None,
+         packed=False):
     """-> (dq, dk, dv, None) in the output's type. Token-first under
     grouped heads `dk` and `dv` are [B, L, H x .], a block of columns a
     QUERY head (`_grouped_back` adds a group's up). `delta` [B, H, Lq]:
@@ -491,7 +600,8 @@ def _bwd(causal, interpret, res, d_out, heads=None, window=None, delta=None):
             None if heads is None else heads[0])                # [B, H, Lq]
     grads = _backward(q, k, v, key_mask, d_out.astype(jnp.bfloat16), lse,
                       delta, causal, _block(q.shape[2], window),
-                      _block(k.shape[2], window), interpret, heads, window)
+                      _block(k.shape[2], window), interpret, heads, window,
+                      packed)
     if heads is not None:
         grads = tuple(g[:, 0] for g in grads)
     return (*(g.astype(out.dtype) for g in grads), None)
@@ -513,23 +623,41 @@ def _head_sums(t, heads):
 flash_attention_pallas.defvjp(_fwd, _bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def window_attention_pallas(q, k, v, key_mask, window: int,
-                            interpret: bool = False):
+def _causal_call(name: str, packed: bool, doc: str):
+    """A causal custom_vjp call (q, k, v, key_mask, window, interpret)
+    under `name`: the band's or the whole-causal kernels by `window`,
+    `packed` or not (`_fwd`)."""
+    def call(q, k, v, key_mask, window, interpret=False):
+        return _fwd(q, k, v, key_mask, True, interpret, save_lse=False,
+                    window=window, packed=packed)[0]
+
+    call.__name__ = call.__qualname__ = name
+    call.__doc__ = doc
+    call = jax.custom_vjp(call, nondiff_argnums=(4, 5))
+    call.defvjp(
+        lambda q, k, v, key_mask, window, interpret: _fwd(
+            q, k, v, key_mask, True, interpret, window=window,
+            packed=packed),
+        lambda window, interpret, res, d_out: _bwd(
+            True, interpret, res, d_out, window=window, packed=packed))
+    return call
+
+
+window_attention_pallas = _causal_call(
+    "window_attention_pallas", False,
     """`flash_attention_pallas`, causal, over a band: a query sees its
     own key and the `window` - 1 before it (`sees`; window >= 1). The
     same kernel bodies on tables that leave out every pair wholly behind
     the band, in blocks of the band's own (`WINDOW_BLOCK`), under names
-    of their own (`window_attention_pallas_fwd` / `_bwd`)."""
-    return _fwd(q, k, v, key_mask, True, interpret, save_lse=False,
-                window=window)[0]
+    of their own (`window_attention_pallas_fwd` / `_bwd`).""")
 
-
-window_attention_pallas.defvjp(
-    lambda q, k, v, key_mask, window, interpret: _fwd(
-        q, k, v, key_mask, True, interpret, window=window),
-    lambda window, interpret, res, d_out: _bwd(True, interpret, res, d_out,
-                                               window=window))
+packed_attention_pallas = _causal_call(
+    "packed_attention_pallas", True,
+    """`flash_attention_pallas`, causal (under a `window` the band's
+    kernels, else None), over packed rows: key_mask [B, L] int32, each
+    position's session id (0 = padding, ids rising by one along a row);
+    a query sees the keys of its own session alone. Lq = Lk: a row
+    attends to itself.""")
 
 
 # -- rotary positions and the cast, on the projection's columns ----------
@@ -686,14 +814,16 @@ rotary_attention_pallas.defvjp(_rotary_attention_fwd, _rotary_attention_bwd)
 def _turned(x, tables, shifts, back=False):
     """x [rows, D] float32 against the tables' blocks (refs: cos, then a
     signed sine a roll): x cos + sum_s roll(x, s) sin_s, the partner of
-    column i the column i - s; `back`, its transpose. No table: x."""
+    column i the column i - s; `back`, its transpose. No table: x. (A
+    table by batch row, [B, L, D], comes as a block [1, rows, D].)"""
     if not shifts:
         return x
+    at = lambda ref: ref[0] if len(ref.shape) == 3 else ref[...]
     width = x.shape[-1]
-    out = x * tables[0][...]
+    out = x * at(tables[0])
     for sin_ref, shift in zip(tables[1:], shifts):
-        out += pltpu.roll(x * sin_ref[...], width - shift, 1) if back \
-            else pltpu.roll(x, shift, 1) * sin_ref[...]
+        out += pltpu.roll(x * at(sin_ref), width - shift, 1) if back \
+            else pltpu.roll(x, shift, 1) * at(sin_ref)
     return out
 
 
@@ -736,13 +866,17 @@ def _grouped_pass(kernel, name, arrays, tables, heads, shifts, per_query,
     [rows, D] a step. The first array is q's (a block a step); the other
     two k's and v's, [B, L, Hkv x D] (a block a group) or, `per_query`,
     a block a query head like q's -> three arrays in `dtype`, H, Hkv and
-    Hkv heads wide."""
+    Hkv heads wide. `tables` [L, D], one for every batch row, or [B, L,
+    D], a batch row's own (positions that restart inside a packed
+    row)."""
     (h, kv), (b, l, width) = heads, arrays[0].shape
     d, rows, group = width // h, _block(l), h // kv
     by_query = pl.BlockSpec((1, rows, d),
                             lambda b, i, j, g: (b, i, j * group + g))
     by_group = pl.BlockSpec((1, rows, d), lambda b, i, j, g: (b, i, j))
     table = pl.BlockSpec((rows, d), lambda b, i, j, g: (i, 0))
+    if tables and tables[0].ndim == 3:
+        table = pl.BlockSpec((1, rows, d), lambda b, i, j, g: (b, i, 0))
     taken = by_query if per_query else by_group
     return pl.pallas_call(
         functools.partial(kernel, shifts=shifts), name=name,
@@ -828,10 +962,11 @@ def _gated(x, s, dtype, interpret, a=None):
     return got[0], jnp.swapaxes(got[1], 1, 2).reshape(b, l, h)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
 def grouped_attention_pallas(q, k, v, gate, key_mask, tables, heads,
                              shifts=(), window: Optional[int] = None,
-                             interpret: bool = False, operand_dtype=None):
+                             interpret: bool = False, operand_dtype=None,
+                             packed: bool = False):
     """Causal attention of grouped query heads from its projections to
     its (gated) output, token-first all the way: q [B, L, H x D], k, v
     [B, L, Hkv x D] float32 as `x @ wq`, `x @ wk`, `x @ wv` wrote them
@@ -862,10 +997,13 @@ def grouped_attention_pallas(q, k, v, gate, key_mask, tables, heads,
     then write what only those products read rounded once to it, the
     gradients of q, k and v and the gated output (the operand of `@ wo`:
     the kernels' own `out` stays float32); the results are float32
-    either way."""
+    either way. `packed`: a row holds several sessions, key_mask [B, L]
+    int32 each position's session id (0 = padding, ids rising by one
+    along a row) and `tables` [B, L, D] the rotary positions inside the
+    session: a query sees the keys of its own session alone."""
     return _grouped_attention_fwd(q, k, v, gate, key_mask, tables, heads,
                                   shifts, window, interpret, operand_dtype,
-                                  save_lse=False)[0]
+                                  packed, save_lse=False)[0]
 
 
 # (the two rules' bodies are jitted: layers of one kind, and a layer's
@@ -876,19 +1014,20 @@ def grouped_attention_pallas(q, k, v, gate, key_mask, tables, heads,
 # arrays, as it does with no jit at all)
 def _grouped_attention_fwd(q, k, v, gate, key_mask, tables, heads, shifts,
                            window, interpret, operand_dtype=None,
-                           save_lse=True):
+                           packed=False, save_lse=True):
     out, saved = _grouped_attention_out(q, k, v, gate, key_mask, tables,
                                         heads, shifts, window, interpret,
-                                        operand_dtype, save_lse)
+                                        operand_dtype, save_lse, packed)
     return out.astype(q.dtype), saved
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12))
 def _grouped_attention_out(q, k, v, gate, key_mask, tables, heads, shifts,
-                           window, interpret, operand_dtype, save_lse):
+                           window, interpret, operand_dtype, save_lse,
+                           packed=False):
     out, res = _fwd(*_grouped_front(q, k, v, tables, heads, shifts,
                                     interpret), key_mask, True, interpret,
-                    save_lse, heads, q.dtype, window)
+                    save_lse, heads, q.dtype, window, packed)
     if gate is None:
         return out, (res, None, tables)
     s = jax.nn.sigmoid(gate)
@@ -897,16 +1036,17 @@ def _grouped_attention_out(q, k, v, gate, key_mask, tables, heads, shifts,
 
 
 def _grouped_attention_bwd(heads, shifts, window, interpret, operand_dtype,
-                           saved, d_out):
+                           packed, saved, d_out):
     *grads, d_gate = _grouped_attention_grads(
-        heads, shifts, window, interpret, operand_dtype, saved, d_out)
+        heads, shifts, window, interpret, operand_dtype, saved, d_out,
+        packed)
     return (*(g.astype(d_out.dtype) for g in grads), d_gate, None,
             tuple(None for _ in saved[2]))
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 7))
 def _grouped_attention_grads(heads, shifts, window, interpret, operand_dtype,
-                             saved, d_out):
+                             saved, d_out, packed=False):
     res, s, tables = saved
     delta = d_gate = None
     if s is not None:
@@ -915,7 +1055,8 @@ def _grouped_attention_grads(heads, shifts, window, interpret, operand_dtype,
         d_out, u = _gated(d_out, s, jnp.bfloat16, interpret, res[4][:, 0])
         delta = jnp.swapaxes(s * u, 1, 2)                   # [B, H, L]
         d_gate = s * u * (1.0 - s)
-    dq, dk, dv, _ = _bwd(True, interpret, res, d_out, heads, window, delta)
+    dq, dk, dv, _ = _bwd(True, interpret, res, d_out, heads, window, delta,
+                         packed)
     return (*_grouped_back(dq, dk, dv, tables, heads, shifts, interpret,
                            operand_dtype or dq.dtype), d_gate)
 

@@ -395,7 +395,8 @@ def _interpreted_kernels(monkeypatch):
         lambda q, k, v, mask, window: banded(q, k, v, mask, window, True))
     monkeypatch.setattr(
         attention_pallas, "grouped_attention_pallas",
-        lambda *a, operand_dtype=None: grouped(*a, True, operand_dtype))
+        lambda *a, operand_dtype=None, packed=False: grouped(
+            *a, True, operand_dtype, packed))
 
 
 def test_rotary_attention_is_one_result_on_every_layout(monkeypatch):

@@ -297,7 +297,8 @@ def test_a_gqa_layer_on_the_token_first_route_is_the_head_first_one(
         lambda q, k, v, mask, causal: whole(q, k, v, mask, causal, True))
     monkeypatch.setattr(
         attention_pallas, "grouped_attention_pallas",
-        lambda *a, operand_dtype=None: grouped(*a, True, operand_dtype))
+        lambda *a, operand_dtype=None, packed=False: grouped(
+            *a, True, operand_dtype, packed))
     p = small_spec(d_model=128, max_len=256, n_layers=1, mixer="gqa",
                    **over)
     layer = weights(p)["layers"][0]

@@ -662,7 +662,8 @@ def _on_attention_kernels(monkeypatch):
         lambda q, k, v, mask, window: banded(q, k, v, mask, window, True))
     monkeypatch.setattr(
         attention_pallas, "grouped_attention_pallas",
-        lambda *a, operand_dtype=None: grouped(*a, True, operand_dtype))
+        lambda *a, operand_dtype=None, packed=False: grouped(
+            *a, True, operand_dtype, packed))
 
 
 def test_the_looped_step_is_one_step_on_both_layouts(monkeypatch):

@@ -486,9 +486,10 @@ def _interpreted(monkeypatch, calls):
     monkeypatch.setattr(
         attention_pallas, "grouped_attention_pallas",
         lambda q, k, v, gate, mask, tables, heads, shifts, window,
-        operand_dtype=None: calls.append(("rows", window, heads, shifts))
+        operand_dtype=None, packed=False: calls.append(
+            ("rows", window, heads, shifts))
         or grouped(q, k, v, gate, mask, tables, heads, shifts, window, True,
-                   operand_dtype))
+                   operand_dtype, packed))
 
 
 @pytest.mark.parametrize("kind", ["gqa", "swa"])
@@ -564,8 +565,8 @@ def test_the_three_products_gradients_are_rounded_where_their_products_round(
     grouped = attention_pallas.grouped_attention_pallas
     monkeypatch.setattr(
         attention_pallas, "grouped_attention_pallas",
-        lambda *a, operand_dtype=None: asked.append(operand_dtype)
-        or grouped(*a, True, operand_dtype))
+        lambda *a, operand_dtype=None, packed=False: asked.append(
+            operand_dtype) or grouped(*a, True, operand_dtype, packed))
     p = small_spec(d_model=128, n_heads=2, n_kv_heads=1, head_dim=128,
                    rotary_dim=64, max_len=128, n_layers=2,
                    mixer=("gqa", "swa"), first_dense_layers=1,

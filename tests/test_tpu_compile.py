@@ -670,6 +670,64 @@ def test_the_window_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
     assert held <= 15.75 * 2 ** 30 - 0.5e9, held
 
 
+def test_the_packed_cells_step_fits_a_v5e(chips, one_chip, monkeypatch):
+    """mellum2-a2.5b-ep4.train's step compiled for one described v5e: 2
+    packed rows of 8,192 positions (a position's session and its place in
+    it two more arguments) through three sliding-window layers and a full
+    one with their experts; the full layer on the whole-causal kernels at
+    32 heads over 4 (two forward calls under `remat`, one backward), the
+    sliding ones on the banded kernels, both token-first and both told
+    the sessions; the four expert layers' products on the grouped-product
+    kernels at 2304 x 896; arguments + temporaries leave the 16 GB chip
+    0.5 GB and more."""
+    import json
+    import os
+
+    from predictionio_tpu.models import seqrec
+    from predictionio_tpu.ops import attention, moe
+
+    kind = chips[0].device_kind
+    for module in (attention, moe):
+        monkeypatch.setattr(module, "_device_kind", lambda: kind)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs",
+                           "seqrec-mellum2-12b-a2.5b-ep4.json")) as f:
+        cfg = json.load(f)
+    p = seqrec.SeqRecParams(**cfg["algorithm_params"])
+    assert p.remat and p.packing and p.max_len == 8192
+    assert p.mixer_kinds() == ("swa", "swa", "swa", "gqa")
+    optimizer = seqrec.make_optimizer(p)
+    params = jax.eval_shape(
+        lambda: seqrec.init_params(None, cfg["n_items"], p))
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params)) \
+        == 595_153_152 + 4 * 64        # and each layer's selection bias
+    put = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    rows = jax.ShapeDtypeStruct((p.batch_size, p.max_len), jnp.int32,
+                                sharding=one_chip)
+    compiled = seqrec.make_train_step(None, p, optimizer).lower(
+        put(params), put(jax.eval_shape(optimizer.init, params)), rows,
+        rows, rows, rows).compile()
+    text = compiled.as_text()
+    assert _kernel_calls(text, "flash_attention_pallas_fwd") == 2
+    assert _kernel_calls(text, "flash_attention_pallas_bwd") == 1
+    assert _kernel_calls(text, "window_attention_pallas_fwd") == 3 * 2
+    assert _kernel_calls(text, "window_attention_pallas_bwd") == 3
+    assert _kernel_calls(text, "grouped_attention_front") == 4 * 2
+    assert _kernel_calls(text, "grouped_attention_back") == 4
+    assert _kernel_calls(text, "attention_head_gate") == 0
+    for scope in ("seqrec_attention", "seqrec_window_attention"):
+        assert not _relayouts(text, p.max_len, scope), scope
+    assert _kernel_calls(text, "grouped_product_pallas_[a-z_]*") == 4 * 12
+    assert "ragged-dot" not in text
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print("packed cell step: arguments", memory.argument_size_in_bytes,
+          "temporaries", memory.temp_size_in_bytes)
+    assert held <= 15.75 * 2 ** 30 - 0.5e9, held
+
+
 @pytest.mark.parametrize("name,tokens,k,d,w,held,devices,kernels", [
     # a pass of each sequence cell's expert layers, forward and backward:
     # kimivl-a3b-ep8.train, lfm2-a2b-ep8.train, qwen3next-a3b-ep16.train

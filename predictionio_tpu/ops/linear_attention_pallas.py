@@ -81,7 +81,7 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 def tiles(dk: int, dv: int) -> bool:
     """Whether the kernels lay these widths out: whole lane tiles, and a
-    head's state within what was compiled (tests/test_tpu_compile.py)."""
+    head's state within what was compiled (test_tpu_compile_kernels.py)."""
     return dk % 128 == 0 and dv % 128 == 0 and max(dk, dv) <= 256
 
 

@@ -6,129 +6,42 @@ at a small size; and the pieces the spec is made of against their
 hand-computed values."""
 
 import dataclasses
-import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import seqrec_cases as cases
+from seqrec_cases import (  # noqa: F401 (the fixtures: model, small_blocks)
+    batch, model, rel, small_blocks,
+)
 
-from benchmarks.checks import seqrec_hybrid_reference as ref
 from predictionio_tpu.models import seqrec
 from predictionio_tpu.ops import linear_attention, moe
 from predictionio_tpu.ops.attention import blockwise_attention, mha, rope
 
-VOCAB, L = 97, 24
-
-
-def small_spec(**over) -> seqrec.SeqRecParams:
-    """d 64; a period of three gdn layers (4 key heads of 8 serving 8
-    value heads of 8, a convolution of 4) and one gqa layer (4 query
-    heads of 16 over 2 key/value heads, 4 rotary dimensions); 16 experts
-    top-3 by softmax plus a gated shared one in every layer."""
-    base = dict(
-        d_model=64, n_heads=4, n_layers=4, max_len=L, seed=11,
-        mixer=("gdn", "gdn", "gdn", "gqa"), ffn="moe",
-        norm="rms_zero_centered", norm_eps=1e-6, positions="rope",
-        rope_theta=1e7, tied_head=False, n_kv_heads=2, head_dim=16,
-        rotary_dim=4, linear_key_heads=4, linear_value_heads=8,
-        linear_key_head_dim=8, linear_value_head_dim=8, linear_conv_kernel=4,
-        n_routed_experts=16, held_experts=(0, 16), experts_per_token=3,
-        moe_width=24, n_shared_experts=1, shared_expert_gate=True,
-        router_scoring="softmax", remat=True)
-    return seqrec.SeqRecParams(**{**base, **over})
-
-
-@pytest.fixture(autouse=True)
-def small_blocks(monkeypatch):
-    """Blocks small enough that a session of 24 takes three attention
-    blocks and three chunks of the delta rule, a step's 48 tokens four
-    token blocks, and a linear layer its heads in two groups."""
-    monkeypatch.setattr(seqrec, "ATTENTION_BLOCK", 8)
-    monkeypatch.setattr(seqrec, "TOKEN_BLOCK", 12)
-    monkeypatch.setattr(seqrec, "LINEAR_KEY_HEADS", 2)
-    monkeypatch.setattr(linear_attention, "CHUNK", 8)
-
-
-def batch(seed=0, rows=2, pad=0):
-    rng = np.random.default_rng(seed)
-    s = rng.integers(1, VOCAB, size=(rows, L + 1))
-    s[:, :pad] = 0
-    return s[:, :-1].astype(np.int32), s[:, 1:].astype(np.int32)
-
-
-def weights(p, seed=3, vocab_multiple=1):
-    """The spec's draws, with every norm's weight moved off its start so
-    that it matters."""
-    params = seqrec.init_params(np.random.default_rng(seed), VOCAB - 1, p,
-                                vocab_multiple)
-    rng = np.random.default_rng(seed + 1)
-    norms = ("ln1", "ln2", "ln_f", "q_norm", "k_norm", "o_norm")
-
-    def move(path, w):
-        if any(getattr(k, "key", None) in norms for k in path):
-            return w + jnp.asarray(rng.normal(size=w.shape) * 0.1,
-                                   jnp.float32)
-        return w
-
-    return jax.tree_util.tree_map_with_path(move, params)
-
-
-def ref_spec(p, **over):
-    return ref.Spec.of(dataclasses.asdict(p), **over)
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-30))
+#: the record (tests/seqrec_cases.py): blocks small enough that a session
+#: of 24 takes three attention blocks and three chunks of the delta rule,
+#: a step's 48 tokens four token blocks, and a linear layer its heads in
+#: two groups
+CASE = cases.CASES["hybrid"]
+ref, L = CASE.ref, CASE.length
+small_spec, weights, ref_spec = CASE.small_spec, CASE.weights, CASE.ref_spec
 
 
 @pytest.mark.parametrize("pad", [0, 5])
-def test_loss_and_every_gradient_match_the_reference(pad):
-    """float32 on both sides, on the CPU. The orders of summation differ
-    (the chunked rule against the recurrence, blocked attention, grouped
-    experts, token blocks), and a linear layer's output is normed a head
-    where it can be small, so a gradient's last digits are amplified on
-    their way down: the same program under another chunk reads up to
-    5e-4 of an array's largest entry from itself. A lower precision
-    anywhere reads 1e-2 and more (the int8 case below)."""
-    p = small_spec()
-    params = weights(p)
-    seqs, targets = batch(pad=pad)
-    with jax.default_matmul_precision("highest"):
-        (loss, _), grads = jax.value_and_grad(seqrec._loss_fn, has_aux=True)(
-            params, jnp.asarray(seqs), jnp.asarray(targets), p)
-    want_loss, want_grads, _ = ref.loss_and_grads(params, seqs, targets,
-                                                  ref_spec(p))
-    assert abs(float(loss) - want_loss) < 2e-6 * want_loss
-    got = dict(jax.tree_util.tree_leaves_with_path(grads))
-    for path, want in jax.tree_util.tree_leaves_with_path(want_grads):
-        assert rel(got[path], want) < 1e-3, jax.tree_util.keystr(path)
-    # every group of the record, the linear layers' own among them
-    groups = seqrec._group_norms(grads)
-    assert set(groups) == set(ref.group_norms(want_grads))
+def test_loss_and_every_gradient_match_the_reference(model, pad):
+    _, _, grads, _, _ = cases.loss_and_every_gradient_match_the_reference(
+        model, pad)
+    # the linear layers' own groups among the record's
     assert {"layer0.linear_attention", "layer3.attention",
-            "layer2.shared_expert"} <= set(groups)
+            "layer2.shared_expert"} <= set(seqrec._group_norms(grads))
     # the selection bias is no parameter of this spec
     assert not np.asarray(grads["layers"][1]["router_bias"]).any()
-    # and the control: the reference's own int8 products
-    _, low, _ = ref.loss_and_grads(params, seqs, targets,
-                                   ref_spec(p, precision="int8"))
-    for name in ("w_out", "w_qkvz"):
-        assert rel(low["layers"][2][name],
-                   want_grads["layers"][2][name]) > 1e-2
 
 
-def test_logits_match_the_reference():
-    p = small_spec()
-    params = weights(p)
-    seqs, _ = batch(seed=5, rows=1, pad=3)
-    with jax.default_matmul_precision("highest"):
-        hidden = seqrec.forward(params, jnp.asarray(seqs), p)
-        logits = hidden[0] @ seqrec.head_matrix(params)
-        want = ref.hidden_states(params, seqs[0], ref_spec(p))[0] \
-            @ params["head"]
-    assert rel(logits, want) < 1e-5      # float32 roundings
+def test_logits_match_the_reference(model):
+    cases.logits_match_the_reference(model)
 
 
 @pytest.mark.parametrize("length", [8, 24, 29, 3])
@@ -178,21 +91,8 @@ def test_the_causal_convolution_by_hand():
                                atol=1e-6)
 
 
-def test_a_left_padded_session_is_the_unpadded_one():
-    """Every mixer of the period: the linear layers write nothing at a
-    padding position, the full layer masks its keys and rotates by
-    distance."""
-    p = small_spec(max_len=L)
-    params = weights(p)
-    seqs, _ = batch(seed=4, rows=1)
-    short = seqs[:, 7:]
-    padded = np.concatenate([np.zeros((1, 7), np.int32), short], axis=1)
-    with jax.default_matmul_precision("highest"):
-        whole = seqrec.forward(params, jnp.asarray(padded), p)
-        alone = seqrec.forward(params, jnp.asarray(short),
-                               dataclasses.replace(p, max_len=L - 7))
-    assert not np.asarray(whole[0, :7]).any()
-    np.testing.assert_allclose(whole[0, 7:], alone[0], atol=2e-5)
+def test_a_left_padded_session_is_the_unpadded_one(model):
+    cases.a_left_padded_session_is_the_unpadded_one(model)
 
 
 def _gqa_case(lq, heads, kv_heads, width, seed):
@@ -385,72 +285,56 @@ def test_the_shares_add_up_to_the_uncut_layer():
     assert float(jnp.abs(shared).max()) > 1e-3      # the gate lets it by
 
 
-def test_a_step_adds_what_the_references_adamw_adds():
-    """By parameter group, the norm of step 1's update against the
-    reference's adamw step from its own gradients (float32 both sides;
-    adamw's first step is -lr g / (|g| + eps), and the few entries whose
-    gradient is near eps = 1e-8 feel the gradients' last digits), and
-    what a learning rate ten times off reads; the step reports the
-    layers it ran by mixer."""
-    p = small_spec(learning_rate=1e-3)
-    params = weights(p)
-    seqs, targets = batch(seed=2)
-    _, grads, _ = ref.loss_and_grads(params, seqs, targets, ref_spec(p))
-    want = ref.first_update_norms(params, grads, ref_spec(p))
-    off = ref.first_update_norms(params, grads,
-                                 ref_spec(p, learning_rate=1e-2))
-    optimizer = seqrec.make_optimizer(p)
-    with jax.default_matmul_precision("highest"):
-        _, _, stats = seqrec.make_train_step(None, p, optimizer)(
-            params, optimizer.init(params), jnp.asarray(seqs),
-            jnp.asarray(targets))
-    got = {k: float(v) for k, v in stats["update_norm"].items()}
-    assert set(got) == set(want)
-    for group, norm in want.items():
-        assert abs(got[group] - norm) < 2e-4 * norm, group
-    assert off["layer1.linear_attention"] > 9 * got["layer1.linear_attention"]
+def test_a_step_adds_what_the_references_adamw_adds(model):
+    """The shared test, and the layers the step reports by mixer."""
+    _, stats, *_ = cases.a_step_adds_what_the_references_adamw_adds(
+        model, "layer1.linear_attention")
     assert {k: int(v) for k, v in stats["mixer_layers"].items()} == \
         {"gdn": 3, "gqa": 1}
     assert np.asarray(stats["load"]).shape == (4, 16)
 
 
-@pytest.mark.parametrize("pad", [0, 5])
-def test_a_step_on_the_kernels_route_is_the_step_on_the_scans(monkeypatch,
-                                                              pad):
-    """A linear layer whose widths the kernels tile, on both routes (the
-    device's kind patched, the kernels interpreted, their products'
-    operands left float32): the same loss and gradient norms by group,
-    and the step reports the route, which `gated_delta_chain` takes for
-    the rule and the chain around it alike: the kernels' step runs the
-    fused chain once a linear layer and no rule outside it."""
+@pytest.fixture(scope="module")
+def both_routes():
+    """A linear layer whose widths the kernels tile, its step compiled
+    once on the scans and once on the kernels' route (the device's kind
+    patched while it is traced, the kernels interpreted, their products'
+    operands left float32) -> (the scans' model, the kernels', what the
+    fused chain was called with while the kernels' step was traced)."""
     from predictionio_tpu.ops import attention_pallas, linear_attention_pallas
 
-    monkeypatch.setattr(linear_attention, "CHUNK", 64)
-    p = small_spec(n_layers=2, mixer=("gdn", "gqa"), linear_key_heads=1,
-                   linear_value_heads=2, linear_key_head_dim=128,
-                   linear_value_head_dim=128)
-    params = weights(p)
-    optimizer = seqrec.make_optimizer(p)
-    seqs, targets = batch(seed=4, pad=pad)
-
-    def step():
-        with jax.default_matmul_precision("highest"):
-            return seqrec.make_train_step(None, p, optimizer)(
-                jax.tree.map(jnp.copy, params), optimizer.init(params),
-                jnp.asarray(seqs), jnp.asarray(targets))[2]
-
-    scan = step()
-    assert not scan["linear_attention_pallas"]
-    monkeypatch.setattr(linear_attention, "_device_kind",
-                        lambda: attention_pallas.KINDS[0])
-    monkeypatch.setattr(linear_attention_pallas, "_BF16", jnp.float32)
+    case = dataclasses.replace(CASE, blocks=CASE.blocks[:-1] + (
+        (linear_attention, "CHUNK", 64),))
+    scan, kernels = (cases.Model(
+        case, n_layers=2, mixer=("gdn", "gqa"), linear_key_heads=1,
+        linear_value_heads=2, linear_key_head_dim=128,
+        linear_value_head_dim=128) for _ in range(2))
     chain, calls = linear_attention_pallas.gated_delta_chain_pallas, []
-    monkeypatch.setattr(
-        linear_attention_pallas, "gated_delta_chain_pallas",
-        lambda *a: calls.append(a[5]) or chain(*a, True))
-    monkeypatch.setattr(linear_attention_pallas, "gated_delta_rule_pallas",
-                        lambda *a: pytest.fail("the rule outside the chain"))
-    kernels = step()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(linear_attention, "_device_kind",
+                      lambda: attention_pallas.KINDS[0])
+        patch.setattr(linear_attention_pallas, "_BF16", jnp.float32)
+        patch.setattr(
+            linear_attention_pallas, "gated_delta_chain_pallas",
+            lambda *a: calls.append(a[5]) or chain(*a, True))
+        patch.setattr(linear_attention_pallas, "gated_delta_rule_pallas",
+                      lambda *a: pytest.fail("the rule outside the chain"))
+        kernels.step(*batch(seed=4))
+    return scan, kernels, calls
+
+
+@pytest.mark.parametrize("pad", [0, 5])
+def test_a_step_on_the_kernels_route_is_the_step_on_the_scans(both_routes,
+                                                              pad):
+    """The same loss and gradient norms by group on both routes, and the
+    step reports the route, which `gated_delta_chain` takes for the rule
+    and the chain around it alike: the kernels' step runs the fused chain
+    once a linear layer and no rule outside it. The padding is an input
+    to the two programs."""
+    scan, kernels, calls = both_routes
+    scan, kernels = (m.step(*batch(seed=4, pad=pad))[1]
+                     for m in (scan, kernels))
+    assert not scan["linear_attention_pallas"]
     assert kernels["linear_attention_pallas"]
     assert calls == [(1, 2, 128, 128)]
     assert abs(float(kernels["loss"]) - float(scan["loss"])) \
@@ -468,14 +352,7 @@ def test_a_train_counts_its_positions_by_mixer():
     """`pio_train_seqrec_mixer_tokens_total{mixer}`: positions of the
     trained batches times the layers of each kind the step ran; the
     attention route's counter keeps its meaning for the full layer."""
-    from predictionio_tpu.obs.registry import default_registry
-
-    reg = default_registry()
-
-    def counted(name, **labels):
-        c = reg.get(name)
-        return c.value(**labels) if c is not None else 0
-
+    counted = cases.counted
     series = [("pio_train_seqrec_mixer_tokens_total", {"mixer": "gdn"}),
               ("pio_train_seqrec_mixer_tokens_total", {"mixer": "gqa"}),
               ("pio_train_seqrec_attention_tokens_total", {"impl": "xla"}),
@@ -488,8 +365,7 @@ def test_a_train_counts_its_positions_by_mixer():
                {"impl": "pallas"})]
     before = [counted(name, **labels) for name, labels in series]
     p = small_spec(epochs=1, batch_size=2, device_init=True)
-    sessions = [[f"i{(3 * s + j * (1 + s % 2)) % 50:02d}"
-                 for j in range(L + 1)] for s in range(4)]
+    sessions = cases.sessions(4)
     model = seqrec.train_seqrec(None, sessions, p)
     assert len(model.record["loss"]) == 2
     assert "layer0.linear_attention" in model.record["grad_norm"][0]
@@ -514,59 +390,10 @@ def test_recommend_next_through_the_hybrid():
 
 
 def test_the_hybrid_trains_and_serves_from_an_engine_json(tmp_path):
-    """`pio train` and `pio deploy`'s predict from a variant file alone:
-    the new keys of the layer spec reach the model like the old ones."""
-    import datetime as dt
-
-    from predictionio_tpu.core.params import engine_params_from_json
-    from predictionio_tpu.data import Event
-    from predictionio_tpu.data.eventstore import clear_cache
-    from predictionio_tpu.engines.sessionrec import (
-        AlgorithmParams, DataSourceParams, Query, engine,
-    )
-    from predictionio_tpu.storage import App, Storage
-    from predictionio_tpu.workflow import run_train
-    from predictionio_tpu.workflow.train import load_for_deploy
-
-    Storage.configure({
-        "sources": {"DB": {"TYPE": "sqlite", "PATH": str(tmp_path / "t.db")}},
-        "repositories": {name: {"NAME": "pio", "SOURCE": "DB"}
-                         for name in ("METADATA", "EVENTDATA", "MODELDATA")}})
-    clear_cache()
-    try:
-        app_id = Storage.get_meta_data_apps().insert(App(id=0, name="Hyb"))
-        store = Storage.get_events()
-        store.init_channel(app_id)
-        t0 = dt.datetime(2026, 1, 1, tzinfo=dt.timezone.utc)
-        store.insert_batch([
-            Event(event="view", entity_type="user", entity_id=f"u{u}",
-                  target_entity_type="item",
-                  target_entity_id=f"i{(u + j) % 15:02d}",
-                  event_time=t0 + dt.timedelta(minutes=u * 100 + j))
-            for u in range(40) for j in range(4 + u % 5)], app_id)
-        spec = dataclasses.asdict(small_spec(max_len=16, epochs=30,
-                                             batch_size=20,
-                                             learning_rate=3e-3))
-        variant = json.loads(json.dumps({
-            "datasource": {"params": {"appName": "Hyb"}},
-            "algorithms": [{"name": "seqrec", "params": spec}]}))
-        assert variant["algorithms"][0]["params"]["mixer"] == \
-            ["gdn", "gdn", "gdn", "gqa"]
-        params = engine_params_from_json(
-            variant, DataSourceParams, None, {"seqrec": AlgorithmParams})
-        eng = engine()
-        instance = run_train(eng, params)
-        assert instance.status == "COMPLETED"
-        result, _ = load_for_deploy(eng, instance)
-        algo, model = result.algorithms[0], result.models[0]
-        assert model.hyper.mixer_kinds() == ("gdn", "gdn", "gdn", "gqa")
-        assert model.record["loss"][-1] < model.record["loss"][0]
-        pred = algo.predict(model, Query(items=["i03", "i04", "i05"], num=3))
-        items = [s.item for s in pred.item_scores]
-        assert "i06" in items and "i05" not in items
-    finally:
-        Storage.reset()
-        clear_cache()
+    params, trained = cases.the_model_trains_and_serves_from_an_engine_json(
+        CASE, tmp_path, "Hyb")
+    assert params["mixer"] == ["gdn", "gdn", "gdn", "gqa"]
+    assert trained.hyper.mixer_kinds() == ("gdn", "gdn", "gdn", "gqa")
 
 
 @pytest.mark.parametrize("over,match", [
@@ -589,14 +416,7 @@ def test_check_refuses_the_combinations_that_do_not_exist(over, match):
 
 
 def test_a_seq_mesh_is_refused_where_a_mixer_does_not_ring(mesh8):
-    """The ring takes one key/value head a query head: a train over a
-    mesh with a "seq" axis is refused before anything is traced."""
-    from jax.sharding import Mesh
-
-    mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(2, 4),
-                axis_names=("data", "seq"))
-    with pytest.raises(ValueError, match="ring"):
-        seqrec.train_seqrec(mesh, [["a", "b", "c"]] * 4, small_spec())
+    cases.a_seq_mesh_is_refused_where_a_mixer_does_not_ring(CASE)
 
 
 def test_a_mixer_is_a_name_or_one_period():
@@ -626,27 +446,11 @@ def test_a_mixer_is_a_name_or_one_period():
     assert ((rate > 0) & (rate < 16)).all()
 
 
-def test_the_hybrid_step_under_a_mesh_is_the_step(mesh8):
-    """Batch over "data", the projections' columns over "model": the
-    sharded step's loss and gradient norms are the one-device step's."""
+def test_the_hybrid_step_under_a_mesh_is_the_step(model, mesh8):
     from jax.sharding import Mesh
 
-    p = small_spec(learning_rate=1e-3)
-    params = weights(p, vocab_multiple=2)
-    seqs, targets = batch(seed=6, rows=4)
-    optimizer = seqrec.make_optimizer(p)
-    _, _, want = seqrec.make_train_step(None, p, optimizer)(
-        jax.tree.map(jnp.copy, params), optimizer.init(params),
-        jnp.asarray(seqs), jnp.asarray(targets))
     mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(4, 2),
                 axis_names=("data", "model"))
-    sharded = seqrec.shard_params(jax.tree.map(jnp.copy, params), mesh)
+    sharded, _, _ = cases.the_step_under_a_mesh_is_the_step(model, mesh)
     assert sharded["layers"][0]["w_qkvz"].sharding.spec == \
         jax.sharding.PartitionSpec(None, "model")
-    _, _, got = seqrec.make_train_step(mesh, p, optimizer)(
-        sharded, optimizer.init(sharded), jnp.asarray(seqs),
-        jnp.asarray(targets))
-    assert abs(float(got["loss"]) - float(want["loss"])) < 1e-5
-    for group, norm in want["grad_norm"].items():
-        assert abs(float(got["grad_norm"][group]) - float(norm)) \
-            < 2e-3 * float(norm), group

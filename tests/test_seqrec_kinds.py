@@ -1,9 +1,13 @@
 """The sequence model's table of layer kinds (`models/seqrec.KINDS`): every
 record has the one shape, holds what its kind alone knows, and a refactor
-behind it draws the weights it drew. Toy sizes; nothing compiles for a chip."""
+behind it draws the weights it drew; and the one table of tiny-step pins:
+what each older benchmark configuration's first step gave, which a new
+field's default may not move. Toy sizes; nothing compiles for a chip."""
 
 import dataclasses
 import hashlib
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -264,6 +268,64 @@ def test_the_spec_has_one_field_fewer_and_refuses_the_old_key():
     assert seqrec.MEMORY_FIELDS == ("remat",)
     with pytest.raises(ValueError, match="attentionImpl"):
         params_from_json({"attentionImpl": "ring"}, seqrec.SeqRecParams)
+
+
+# -- what the program already ran ------------------------------------------
+
+CONFIGS = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                       "configs")
+#: the first step's loss and whole gradient norm of each sequence
+#: configuration's tiny section on one seeded batch, read at the commit
+#: before the configuration after it came (PRs 39, 44, 47): a new field's
+#: default changes nothing. The ONE table: the next `model_config` adds
+#: the row of the configuration that was the newest, and no copy.
+TINY_STEPS = {
+    "seqrec-kimi-vl-a3b-ep8": (5.165351390838623, 7.646289342187191),
+    "seqrec-qwen3-next-80b-a3b-ep16": (5.0000810623168945, 76.57708056258434),
+    "seqrec-lfm2-24b-a2b-ep8": (5.029688835144043, 4.87177540506),
+    "seqrec-ouro-2.6b-pp8": (5.02148962020874, 3.437611412089871),
+    "seqrec-nemotron3-super-120b-a12b-tp8ep64": (5.584339618682861,
+                                                 4.24192990355414),
+    "seqrec-laguna-xs2-ep8": (5.065154552459717, 6.6178168454284085),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TINY_STEPS))
+def test_the_older_configurations_tiny_steps_give_the_losses_they_gave(name):
+    with open(os.path.join(CONFIGS, name + ".json")) as f:
+        tiny = json.load(f)["tiny"]
+    p = seqrec.SeqRecParams(**tiny["algorithm_params"])
+    assert not p.packing
+    params = seqrec.init_params(None, tiny["n_items"], p)
+    optimizer = seqrec.make_optimizer(p)
+    seqs = np.random.default_rng(40).integers(
+        1, tiny["n_items"] + 1, size=(p.batch_size, p.max_len + 1))
+    seqs[0, :7] = 0
+    _, _, stats = seqrec.make_train_step(None, p, optimizer)(
+        params, optimizer.init(params), jnp.asarray(seqs[:, :-1], jnp.int32),
+        jnp.asarray(seqs[:, 1:], jnp.int32))
+    norms = jax.device_get(stats["grad_norm"])
+    loss, norm = TINY_STEPS[name]
+    assert float(stats["loss"]) == pytest.approx(loss, rel=1e-6)
+    assert float(np.sqrt(sum(float(v) ** 2 for v in norms.values()))) \
+        == pytest.approx(norm, rel=1e-5)
+    # and a step says of itself what its spec asks for, no more
+    assert ("mtp_loss" in stats) == bool(p.mtp_layers)
+    assert ("layer_passes" in stats) == (
+        p.n_loops > 1 or bool(p.sublayers or p.mtp_layers))
+    assert ("expert_update_norm" in stats) == (
+        p.expert_act == "relu2" or p.expert_update_by_expert)
+
+
+def test_every_configuration_but_the_newest_has_its_row_of_pins():
+    """A `model_config` PR adds a row for the configuration that was the
+    newest before it (whose own tests held its tiny step until then); the
+    newest's is held by the reference in its own file."""
+    configs = {name[:-len(".json")] for name in os.listdir(CONFIGS)
+               if name.startswith("seqrec-")}
+    assert set(TINY_STEPS) <= configs
+    assert len(configs - set(TINY_STEPS)) == 1, sorted(
+        configs - set(TINY_STEPS))
 
 
 # -- the counters --------------------------------------------------------------

@@ -14,144 +14,74 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import seqrec_cases as cases
+from seqrec_cases import (  # noqa: F401 (the fixtures: model, small_blocks)
+    VOCAB, batch, model, rel, small_blocks,
+)
 
-from benchmarks.checks import seqrec_looped_reference as ref
 from predictionio_tpu.models import seqrec
 from predictionio_tpu.obs import profiler
 
-VOCAB, L, R, N = 97, 24, 4, 2
-
-
-def small_spec(**over) -> seqrec.SeqRecParams:
-    """d 64; two layers of 4 heads of 16 with rotary positions and a
-    SwiGLU of 96, a norm before and after each sub-layer, run four times;
-    an untied head; an exit gate and an entropy term of 0.05."""
-    base = dict(
-        d_model=64, n_heads=4, n_layers=N, n_loops=R, max_len=L, seed=11,
-        mixer="mha", ffn="swiglu", ffn_width=96, norm="rms", norm_eps=1e-6,
-        post_norm=True, positions="rope", rope_theta=1e6, tied_head=False,
-        exit_gate=True, exit_entropy_beta=0.05, remat=True)
-    return seqrec.SeqRecParams(**{**base, **over})
-
-
-@pytest.fixture(autouse=True)
-def small_blocks(monkeypatch):
-    """Blocks small enough that a session of 24 takes three attention
-    blocks, a step's 48 tokens four token blocks and the loss's 4 x 48
-    rows sixteen."""
-    monkeypatch.setattr(seqrec, "ATTENTION_BLOCK", 8)
-    monkeypatch.setattr(seqrec, "TOKEN_BLOCK", 12)
-
-
-def batch(seed=0, rows=2, pad=0):
-    rng = np.random.default_rng(seed)
-    s = rng.integers(1, VOCAB, size=(rows, L + 1))
-    s[:, :pad] = 0
-    return s[:, :-1].astype(np.int32), s[:, 1:].astype(np.int32)
-
-
-def weights(p, seed=3):
-    """The spec's draws, with every norm's weight moved off 1 and the
-    gate off 0, so that they matter."""
-    params = seqrec.init_params(np.random.default_rng(seed), VOCAB - 1, p)
-    rng = np.random.default_rng(seed + 1)
-    moved = {"ln1": 0.1, "ln2": 0.1, "post1": 0.1, "post2": 0.1,
-             "ln_f": 0.1, "exit_gate": 0.3}
-
-    def move(path, w):
-        for k in path:
-            if getattr(k, "key", None) in moved:
-                return w + jnp.asarray(
-                    rng.normal(size=w.shape) * moved[k.key], jnp.float32)
-        return w
-
-    return jax.tree_util.tree_map_with_path(move, params)
-
-
-def ref_spec(p, **over):
-    return ref.Spec.of(dataclasses.asdict(p), **over)
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-30))
-
-
-def loss_and_grads(params, seqs, targets, p):
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(seqrec._loss_fn, has_aux=True)(
-            params, jnp.asarray(seqs), jnp.asarray(targets), p)
+#: the record (tests/seqrec_cases.py): a session of 24 takes three
+#: attention blocks, a step's 48 tokens four token blocks and the loss's
+#: 4 x 48 rows sixteen
+CASE = cases.CASES["looped"]
+ref, L = CASE.ref, CASE.length
+R, N = CASE.spec["n_loops"], CASE.spec["n_layers"]
+small_spec, weights, ref_spec = CASE.small_spec, CASE.weights, CASE.ref_spec
 
 
 @pytest.mark.parametrize("remat", [True, False])
 @pytest.mark.parametrize("pad", [0, 5])
-def test_loss_and_every_gradient_match_the_reference(pad, remat):
-    """float32 on both sides, on the CPU; the orders of summation differ
-    (blocked attention, token blocks, the scan's sum of a weight's
-    gradient over the passes), which costs a few float32 roundings a
-    value: 2e-5 of each array's largest entry. A lower precision
-    anywhere reads 1e-3 and more (the int8 case below)."""
-    p = small_spec(remat=remat)
-    params = weights(p)
-    seqs, targets = batch(pad=pad)
-    (loss, (_, mixers, exits)), grads = loss_and_grads(params, seqs, targets,
-                                                       p)
-    want_loss, want_grads, want = ref.loss_and_grads(params, seqs, targets,
-                                                     ref_spec(p))
-    assert abs(float(loss) - want_loss) < 2e-6 * want_loss
+def test_loss_and_every_gradient_match_the_reference(model, pad, remat):
+    """The shared test (among the orders of summation that differ here:
+    the scan's sum of a weight's gradient over the passes), each pass's
+    loss and share, and the groups of a looped stack."""
+    _, (_, mixers, exits), grads, _, want = \
+        cases.loss_and_every_gradient_match_the_reference(
+            model if remat else model.of(remat=False), pad)
     np.testing.assert_allclose(exits["loop_loss"], want["loop_loss"],
                                rtol=2e-6)
     np.testing.assert_allclose(exits["exit_share"], want["exit_share"],
                                rtol=2e-6)
     assert float(exits["exit_share"].sum()) == pytest.approx(1.0, abs=1e-6)
-    got = dict(jax.tree_util.tree_leaves_with_path(grads))
-    assert set(got) == set(dict(
-        jax.tree_util.tree_leaves_with_path(want_grads)))
-    for path, want_g in jax.tree_util.tree_leaves_with_path(want_grads):
-        assert rel(got[path], want_g) < 2e-5, jax.tree_util.keystr(path)
     # every group of the record: the post norms with the norms, the gate
-    groups = seqrec._group_norms(grads)
-    assert set(groups) == set(ref.group_norms(want_grads)) == {
+    assert set(seqrec._group_norms(grads)) == {
         "embedding", "head", "final_norm", "exit_gate",
         *(f"layer{i}.{part}" for i in range(N)
           for part in ("attention", "ffn", "norms"))}
     # a layer counts once a pass
     assert {k: int(v) for k, v in mixers.items()} == {"mha": R * N}
-    # and the control: the reference's own int8 products
-    _, low, _ = ref.loss_and_grads(params, seqs, targets,
-                                   ref_spec(p, precision="int8"))
-    for name in ("wqkv", "w_down"):
-        assert rel(low["layers"][1][name],
-                   want_grads["layers"][1][name]) > 1e-3
 
 
-def test_every_passes_logits_match_the_reference():
-    p = small_spec()
-    params = weights(p)
+def test_every_passes_logits_match_the_reference(model):
+    p, params = model.p, model.params
     seqs, _ = batch(seed=5, rows=1, pad=3)
+    passes = model.passes(seqs)
     with jax.default_matmul_precision("highest"):
-        passes, *_ = seqrec._forward(params, jnp.asarray(seqs), p)
         states = ref.pass_states(params, seqs[0], ref_spec(p))
         assert passes.shape == (R, 1, L, 64) and len(states) == R
         for r in range(R):
             logits = passes[r, 0] @ seqrec.head_matrix(params)
             assert logits.shape == (L, VOCAB)
             assert rel(logits, states[r] @ params["head"]) < 1e-5, r
-        # `forward`, what serving reads, is the last pass
-        np.testing.assert_array_equal(
-            seqrec.forward(params, jnp.asarray(seqs), p), passes[-1])
+    # `forward`, what serving reads, is the last pass
+    np.testing.assert_array_equal(model.forward(seqs), passes[-1])
     assert not np.asarray(passes[:, 0, :3]).any()      # padding reads 0
 
 
-def test_shared_passes_are_an_unshared_stack_of_copies():
+def test_a_left_padded_session_is_the_unpadded_one(model):
+    cases.a_left_padded_session_is_the_unpadded_one(model)
+
+
+def test_shared_passes_are_an_unshared_stack_of_copies(model):
     """R passes of N shared layers = an unshared model of R x N layers
     holding the same weights R times, and a shared weight's gradient =
     the sum of its R copies': the reference as the unshared twin (pass r
     takes layers [r N, (r + 1) N) of a list of R x N)."""
-    p = small_spec()
-    params = weights(p)
+    p, params = model.p, model.params
     seqs, targets = batch(seed=7)
-    (loss, _), grads = loss_and_grads(params, seqs, targets, p)
+    (loss, _), grads = model.loss_and_grads(seqs, targets)
     copies = {**params, "layers": [
         jax.tree.map(jnp.copy, layer) for _ in range(R)
         for layer in params["layers"]]}
@@ -173,15 +103,14 @@ def test_shared_passes_are_an_unshared_stack_of_copies():
         assert rel(grads[name], twin[name]) < 2e-5
 
 
-def test_the_scanned_loop_is_the_unrolled_one(monkeypatch):
+def test_the_scanned_loop_is_the_unrolled_one(model, monkeypatch):
     """One `lax.scan` over the passes against the stack written out four
     times: the same operations a pass, but the compiler fuses them
     otherwise and the backward pass adds a weight's gradient up in
     another order (the scan's carry against a tree of sums). The stated
     bound: the loss to 1e-6 of itself, every gradient to 5e-6 of its
     largest entry, float32 roundings both."""
-    p = small_spec()
-    params = weights(p)
+    p, params = model.p, model.params
     seqs, targets = batch(seed=8, pad=2)
 
     def loops():
@@ -190,10 +119,11 @@ def test_the_scanned_loop_is_the_unrolled_one(monkeypatch):
             w, jnp.asarray(seqs), dataclasses.replace(p, remat=False))[0]
         ).lower(params).compile().as_text().count(" while(")
 
-    (loss, _), grads = loss_and_grads(params, seqs, targets, p)
+    (loss, _), grads = model.loss_and_grads(seqs, targets)
     scanned = loops()
     monkeypatch.setattr(seqrec, "LOOP_UNROLL", True)
-    (unrolled, _), unrolled_grads = loss_and_grads(params, seqs, targets, p)
+    (unrolled, _), unrolled_grads = cases.Model(CASE).loss_and_grads(
+        seqs, targets)           # the same record, traced written out
     assert float(loss) == pytest.approx(float(unrolled), rel=1e-6)
     for (path, g), u in zip(jax.tree_util.tree_leaves_with_path(grads),
                             jax.tree.leaves(unrolled_grads)):
@@ -270,12 +200,11 @@ def test_the_exit_distribution_by_hand():
         [s[0], 1 - s[0]], rtol=1e-6)
 
 
-def test_the_last_passes_gate_gets_no_gradient_and_the_entropy_counts():
+def test_the_last_passes_gate_gets_no_gradient_and_the_entropy_counts(model):
     """The loss does not read lambda_R: with the last pass's state alone
     feeding a gate of its own, that gate's gradient is 0 while the other
     passes' is not. The entropy term is beta x sum p log p a target."""
-    p = small_spec()
-    params = weights(p)
+    p, params = model.p, model.params
     seqs, targets = batch(seed=4)
 
     def loss_of(gates):
@@ -285,43 +214,27 @@ def test_the_last_passes_gate_gets_no_gradient_and_the_entropy_counts():
         logp = seqrec.exit_distribution(z)
         return (jnp.exp(logp) * jnp.arange(1.0, R + 1)[:, None]).sum()
 
-    by_pass = jax.grad(loss_of)(jnp.tile(params["exit_gate"]["w"], (R, 1)))
+    by_pass = jax.jit(jax.grad(loss_of))(
+        jnp.tile(params["exit_gate"]["w"], (R, 1)))
     assert not np.asarray(by_pass[-1]).any()
     assert all(np.asarray(by_pass[r]).any() for r in range(R - 1))
-    (with_h, _), _ = loss_and_grads(params, seqs, targets, p)
-    (without, _), _ = loss_and_grads(
-        params, seqs, targets, dataclasses.replace(p, exit_entropy_beta=0.0))
-    ref_without, _, _ = ref.loss_and_grads(
-        params, seqs, targets, ref_spec(p, exit_entropy_beta=0.0))
+    (with_h, _), _ = model.loss_and_grads(seqs, targets)
+    (without, _), _ = model.of(exit_entropy_beta=0.0).loss_and_grads(
+        seqs, targets)
+    ref_without, _, _ = model.reference(4, exit_entropy_beta=0.0)
     assert float(without) == pytest.approx(ref_without, rel=2e-6)
     # 0.05 x H(p), H between 0 and log 4
     assert 0 < float(without - with_h) < 0.05 * np.log(R)
 
 
-def test_a_step_adds_what_the_references_adamw_adds():
-    """By parameter group, the norm of step 1's update against the
-    reference's adamw step from its own gradients, what a learning rate
-    ten times off reads, each pass's loss and share and the layer passes
-    the step reports."""
-    p = small_spec(learning_rate=1e-3)
-    params = weights(p)
-    seqs, targets = batch(seed=2)
-    _, grads, passes = ref.loss_and_grads(params, seqs, targets, ref_spec(p))
-    want = ref.first_update_norms(params, grads, ref_spec(p))
-    off = ref.first_update_norms(params, grads,
-                                 ref_spec(p, learning_rate=1e-2))
-    optimizer = seqrec.make_optimizer(p)
-    with jax.default_matmul_precision("highest"):
-        _, _, stats = seqrec.make_train_step(None, p, optimizer)(
-            jax.tree.map(jnp.copy, params), optimizer.init(params),
-            jnp.asarray(seqs), jnp.asarray(targets))
-    got = {k: float(v) for k, v in stats["update_norm"].items()}
-    assert set(got) == set(want) and "exit_gate" in got
-    for group, norm in want.items():
-        assert abs(got[group] - norm) < 2e-4 * norm, group
-    assert off["layer1.attention"] > 9 * got["layer1.attention"]
-    want_norms = ref.group_norms(grads)
-    for group, norm in want_norms.items():
+def test_a_step_adds_what_the_references_adamw_adds(model):
+    """The shared test, the gradient norms, each pass's loss and share
+    and the layer passes the step reports."""
+    _, stats, grads, passes, want = \
+        cases.a_step_adds_what_the_references_adamw_adds(
+            model, "layer1.attention")
+    assert "exit_gate" in want
+    for group, norm in ref.group_norms(grads).items():
         assert abs(float(stats["grad_norm"][group]) - norm) < 2e-4 * norm
     np.testing.assert_allclose(stats["loop_loss"], passes["loop_loss"],
                                rtol=1e-5)
@@ -339,17 +252,13 @@ def test_a_step_adds_what_the_references_adamw_adds():
     ({"post_norm": False}, "loss"),
     ({"exit_entropy_beta": 0.0}, "loss"),
 ])
-def test_the_references_fault_controls_are_faults(fault, apart):
+def test_the_references_fault_controls_are_faults(model, fault, apart):
     """Each control of the benchmark's check moves what it should: the
     loss (a pass or the post norms or the entropy left out) or the shared
     weights' gradient with the loss unmoved (the gradient through the
     last pass only)."""
-    p = small_spec()
-    params = weights(p)
-    seqs, targets = batch(seed=6)
-    loss, grads, _ = ref.loss_and_grads(params, seqs, targets, ref_spec(p))
-    bad_loss, bad, passes = ref.loss_and_grads(params, seqs, targets,
-                                               ref_spec(p, **fault))
+    loss, grads, _ = model.reference(6)
+    bad_loss, bad, passes = model.reference(6, **fault)
     if apart == "loss":
         assert abs(bad_loss - loss) > 1e-3 * loss
     else:
@@ -387,20 +296,12 @@ def test_a_train_counts_its_layer_passes_and_reports_each_pass():
     pass and in its repeats; a step of one pass counts 0 repeats. The
     gauges hold the last step's loss and share a pass, the record every
     step's."""
-    from predictionio_tpu.obs.registry import default_registry
-
-    reg = default_registry()
-
-    def counted(name, **labels):
-        c = reg.get(name)
-        return c.value(**labels) if c is not None else 0
-
+    counted = cases.counted
     series = [("pio_train_seqrec_layer_pass_tokens_total", {"pass": "first"}),
               ("pio_train_seqrec_layer_pass_tokens_total",
                {"pass": "repeat"}),
               ("pio_train_seqrec_mixer_tokens_total", {"mixer": "mha"})]
-    sessions = [[f"i{(3 * s + j * (1 + s % 2)) % 50:02d}"
-                 for j in range(L + 1)] for s in range(4)]
+    sessions = cases.sessions(4)
     positions = 2 * 2 * L
     before = [counted(name, **labels) for name, labels in series]
     model = seqrec.train_seqrec(None, sessions, small_spec(
@@ -471,19 +372,18 @@ def test_a_looped_stack_of_other_mixers_and_experts_runs():
         np.testing.assert_allclose(layer["router_bias"], want, atol=1e-7)
 
 
-def test_the_scope_table_of_a_scanned_step_names_the_bodys_instructions():
+def test_the_scope_table_of_a_scanned_step_names_the_bodys_instructions(
+        model):
     """The stack lies in the body of the scan's `while`, its feed-forward
     and loss blocks in loops inside that: the table reaches them all, an
     instruction of the body stands once however often it runs, and the
     join adds an instruction's seconds, which a capture gives summed
     over its events, to its scope and skips the loops themselves."""
-    p = small_spec()
-    params = weights(p)
+    params = model.params
     seqs, targets = batch(seed=1)
-    optimizer = seqrec.make_optimizer(p)
-    step = seqrec.make_train_step(None, p, optimizer)
-    text = step.lower(params, optimizer.init(params), jnp.asarray(seqs),
-                      jnp.asarray(targets)).compile().as_text()
+    text = model.train_step().lower(
+        params, model.optimizer.init(params), jnp.asarray(seqs),
+        jnp.asarray(targets)).compile().as_text()
     module, table = profiler.parse_scope_table(text, seqrec.STEP_SCOPES)
     assert module.startswith("jit_step")
     _, _, computations = profiler._computations(text)
@@ -512,62 +412,15 @@ def test_the_scope_table_of_a_scanned_step_names_the_bodys_instructions():
 
 
 def test_the_model_trains_and_serves_from_an_engine_json(tmp_path):
-    """`pio train` and `pio deploy`'s predict from a variant file alone:
-    the new keys of the layer spec (`n_loops`, `post_norm`, `exit_gate`,
-    `exit_entropy_beta`) reach the model like the old ones."""
-    import datetime as dt
-
-    from predictionio_tpu.core.params import engine_params_from_json
-    from predictionio_tpu.data import Event
-    from predictionio_tpu.data.eventstore import clear_cache
-    from predictionio_tpu.engines.sessionrec import (
-        AlgorithmParams, DataSourceParams, Query, engine,
-    )
-    from predictionio_tpu.storage import App, Storage
-    from predictionio_tpu.workflow import run_train
-    from predictionio_tpu.workflow.train import load_for_deploy
-
-    Storage.configure({
-        "sources": {"DB": {"TYPE": "sqlite", "PATH": str(tmp_path / "t.db")}},
-        "repositories": {name: {"NAME": "pio", "SOURCE": "DB"}
-                         for name in ("METADATA", "EVENTDATA", "MODELDATA")}})
-    clear_cache()
-    try:
-        app_id = Storage.get_meta_data_apps().insert(App(id=0, name="Loop"))
-        store = Storage.get_events()
-        store.init_channel(app_id)
-        t0 = dt.datetime(2026, 1, 1, tzinfo=dt.timezone.utc)
-        store.insert_batch([
-            Event(event="view", entity_type="user", entity_id=f"u{u}",
-                  target_entity_type="item",
-                  target_entity_id=f"i{(u + j) % 15:02d}",
-                  event_time=t0 + dt.timedelta(minutes=u * 100 + j))
-            for u in range(40) for j in range(4 + u % 5)], app_id)
-        spec = dataclasses.asdict(small_spec(max_len=16, epochs=30,
-                                             batch_size=20,
-                                             learning_rate=3e-3))
-        variant = json.loads(json.dumps({
-            "datasource": {"params": {"appName": "Loop"}},
-            "algorithms": [{"name": "seqrec", "params": spec}]}))
-        params = engine_params_from_json(
-            variant, DataSourceParams, None, {"seqrec": AlgorithmParams})
-        eng = engine()
-        instance = run_train(eng, params)
-        assert instance.status == "COMPLETED"
-        result, _ = load_for_deploy(eng, instance)
-        algo, model = result.algorithms[0], result.models[0]
-        assert (model.hyper.n_loops, model.hyper.post_norm,
-                model.hyper.exit_gate, model.hyper.exit_entropy_beta) == \
-            (R, True, True, 0.05)
-        assert model.record["loss"][-1] < model.record["loss"][0]
-        assert len(model.record["loop_loss"][-1]) == R
-        assert isinstance(model.params["exit_gate"]["w"], np.ndarray)
-        pred = algo.predict(model, Query(items=["i03", "i04", "i05"], num=3))
-        items = [s.item for s in pred.item_scores]
-        assert "i06" in items and "i05" not in items
-    finally:
-        Storage.reset()
-        clear_cache()
+    """The new keys of the layer spec: `n_loops`, `post_norm`,
+    `exit_gate`, `exit_entropy_beta`."""
+    _, trained = cases.the_model_trains_and_serves_from_an_engine_json(
+        CASE, tmp_path, "Loop")
+    assert (trained.hyper.n_loops, trained.hyper.post_norm,
+            trained.hyper.exit_gate, trained.hyper.exit_entropy_beta) == \
+        (R, True, True, 0.05)
+    assert len(trained.record["loop_loss"][-1]) == R
+    assert isinstance(trained.params["exit_gate"]["w"], np.ndarray)
 
 
 @pytest.mark.parametrize("over,match", [
@@ -586,18 +439,18 @@ def test_check_refuses_what_does_not_exist(over, match):
     small_spec(n_loops=1, exit_gate=False).check()
 
 
-def test_a_loop_without_a_gate_reads_the_last_pass_alone():
+def test_a_loop_without_a_gate_reads_the_last_pass_alone(model):
     """`n_loops` without `exit_gate`: one head over the last pass's
     state, no gate among the weights, no per-pass numbers."""
-    p = small_spec(exit_gate=False, post_norm=False)
-    params = weights(p)
+    plain = model.of(exit_gate=False, post_norm=False)
+    p, params = plain.p, plain.params
     assert "exit_gate" not in params
     seqs, targets = batch(seed=9)
-    (loss, (_, _, exits)), grads = loss_and_grads(params, seqs, targets, p)
+    (loss, (_, _, exits)), grads = plain.loss_and_grads(seqs, targets)
     assert exits == {}
     with jax.default_matmul_precision("highest"):
         last = ref.pass_states(params, seqs[0], ref_spec(p))[-1]
-        hidden = seqrec.forward(params, jnp.asarray(seqs), p)
+    hidden = plain.forward(seqs)
     assert rel(hidden[0], last) < 1e-5
     logits = hidden.reshape(-1, 64) @ params["head"]
     nll = -jnp.take_along_axis(jax.nn.log_softmax(logits),
@@ -606,35 +459,19 @@ def test_a_loop_without_a_gate_reads_the_last_pass_alone():
     assert all(np.asarray(g).any() for g in jax.tree.leaves(grads))
 
 
-def test_the_looped_step_under_a_mesh_is_the_step(mesh8):
-    """Batch over "data", the projections' columns over "model"; the
-    post norms and the gate replicate. The sharded step's loss, per-pass
-    numbers and gradient norms are the one-device step's."""
+def test_the_looped_step_under_a_mesh_is_the_step(model, mesh8):
+    """The shared test; the post norms and the gate replicate, and the
+    per-pass numbers too are the one-device step's."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    p = small_spec(learning_rate=1e-3)
-    params = seqrec.init_params(np.random.default_rng(3), VOCAB - 1, p,
-                                vocab_multiple=2)
-    seqs, targets = batch(seed=6, rows=4)
-    optimizer = seqrec.make_optimizer(p)
-    _, _, want = seqrec.make_train_step(None, p, optimizer)(
-        jax.tree.map(jnp.copy, params), optimizer.init(params),
-        jnp.asarray(seqs), jnp.asarray(targets))
     mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(4, 2),
                 axis_names=("data", "model"))
-    sharded = seqrec.shard_params(jax.tree.map(jnp.copy, params), mesh)
+    sharded, got, want = cases.the_step_under_a_mesh_is_the_step(model, mesh)
     assert sharded["layers"][0]["post1"]["scale"].sharding.spec == P()
     assert sharded["exit_gate"]["w"].sharding.spec == P()
     assert sharded["layers"][0]["wqkv"].sharding.spec == P(None, "model")
-    _, _, got = seqrec.make_train_step(mesh, p, optimizer)(
-        sharded, optimizer.init(sharded), jnp.asarray(seqs),
-        jnp.asarray(targets))
-    assert abs(float(got["loss"]) - float(want["loss"])) < 1e-5
     np.testing.assert_allclose(got["loop_loss"], want["loop_loss"],
                                rtol=1e-5)
-    for group, norm in want["grad_norm"].items():
-        assert abs(float(got["grad_norm"][group]) - float(norm)) \
-            < 2e-3 * float(norm), group
 
 
 def _on_attention_kernels(monkeypatch):
@@ -811,16 +648,8 @@ def test_a_train_counts_where_its_attention_kernels_read_a_head(
     step says the kernels read a head; `impl="pallas"` either way. The
     tiny Ouro, Kimi, LFM2, Laguna and Qwen specs at widths the kernels
     tile."""
-    from predictionio_tpu.obs.registry import default_registry
-
     _on_attention_kernels(monkeypatch)
-    p = _tiny_spec(config, **over)
-    reg = default_registry()
-
-    def counted(name, **labels):
-        c = reg.get(name)
-        return c.value(**labels) if c is not None else 0
-
+    p, counted = _tiny_spec(config, **over), cases.counted
     names = ("pio_train_seqrec_attention_layout_tokens_total",
              "pio_train_seqrec_attention_tokens_total")
     before = {(name, label): counted(name, **{key: label})

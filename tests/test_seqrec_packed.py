@@ -8,7 +8,8 @@ never packs), on seeded random weights at a small size; `pack_sessions`'
 properties; a session's boundary in the scan and in the interpreted
 kernels against a dense masked softmax, the three states of a block
 pair, an edge case that reads whole numbers off; the share tied to the
-model; the counters; and the specs the program already ran, unchanged."""
+model; the counters. (What the program already ran: the one table of pins
+in tests/test_seqrec_kinds.py.)"""
 
 import dataclasses
 import json
@@ -18,47 +19,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import seqrec_cases as cases
+from seqrec_cases import (  # noqa: F401 (the fixtures: model, small_blocks)
+    VOCAB, model, rel, small_blocks,
+)
 
-from benchmarks.checks import seqrec_packed_reference as ref
 from benchmarks.checks import seqrec_packed_step as check
 from benchmarks.events import sessions_packed
 from predictionio_tpu.models import seqrec
 from predictionio_tpu.ops import attention, attention_pallas
 
-VOCAB, L = 97, 48
-YARN = dict(factor=4.0, original_max_len=16, beta_fast=4.0, beta_slow=1.0,
-            attention_factor=1.1386294361119891)
-SWA = dict(heads=4, window=7, rope_theta=500000.0, rotary_dim=8)
+#: the record (tests/seqrec_cases.py): a row of 48 takes six attention
+#: blocks, a step's 96 tokens eight token blocks
+CASE = cases.CASES["packed"]
+ref, L = CASE.ref, CASE.length
+small_spec, weights, ref_spec = CASE.small_spec, CASE.weights, CASE.ref_spec
 CONFIGS = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
                        "configs")
-
-
-def small_spec(**over) -> seqrec.SeqRecParams:
-    """d 64; four layers, sliding, sliding, sliding, full: 4 query heads
-    of 8 over 2 key/value heads, rotary on all 8 columns at theta
-    500,000, the full one under YaRN, the sliding ones a window of 7; no
-    gate, no q/k norm; 16 softmax-routed experts of 24 top-4 in every
-    layer, no shared one, no dense layer; experts 0-3 held here; rows
-    packed."""
-    base = dict(
-        d_model=64, n_heads=4, n_kv_heads=2, head_dim=8, n_layers=4,
-        max_len=L, seed=11, mixer=("swa", "swa", "swa", "gqa"), swa=SWA,
-        ffn="moe", norm="rms", norm_eps=1e-6, positions="rope",
-        rope_theta=500000.0, rotary_dim=8, rope_scaling=YARN, qk_norm=False,
-        attention_gate=False, tied_head=False, n_routed_experts=16,
-        held_experts=(0, 4), experts_per_token=4, moe_width=24,
-        n_shared_experts=0, router_scoring="softmax",
-        expert_update_by_expert=True, packing=True, remat=True)
-    return seqrec.SeqRecParams(**{**base, **over})
-
-
-@pytest.fixture(autouse=True)
-def small_blocks(monkeypatch):
-    """A row of 48 takes six attention blocks, a step's 96 tokens eight
-    token blocks."""
-    monkeypatch.setattr(seqrec, "ATTENTION_BLOCK", 8)
-    monkeypatch.setattr(seqrec, "TOKEN_BLOCK", 12)
-
 
 LENGTHS = (2, 3, 5, 9, 17, 30, 12, 40, 7, 3, 25, 2, 11)
 
@@ -66,31 +43,6 @@ LENGTHS = (2, 3, 5, 9, 17, 30, 12, 40, 7, 3, 25, 2, 11)
 def sessions_of(lengths=LENGTHS, seed=0):
     rng = np.random.default_rng(seed)
     return [rng.integers(1, VOCAB, size=n).tolist() for n in lengths]
-
-
-def weights(p, seed=3):
-    """The spec's draws, with every norm's weight moved off 1 so that it
-    matters."""
-    params = seqrec.init_params(np.random.default_rng(seed), VOCAB - 1, p)
-    rng = np.random.default_rng(seed + 1)
-
-    def move(path, w):
-        if any(getattr(k, "key", None) in ("ln1", "ln2", "ln_f")
-               for k in path):
-            return w + jnp.asarray(rng.normal(size=w.shape) * 0.1,
-                                   jnp.float32)
-        return w
-
-    return jax.tree_util.tree_map_with_path(move, params)
-
-
-def ref_spec(p, **over):
-    return ref.Spec.of(dataclasses.asdict(p), **over)
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-30))
 
 
 def alone(sessions, max_len=L):
@@ -410,47 +362,34 @@ def reference_of(p, params, sessions, n_positions, **over):
 
 
 @pytest.mark.parametrize("lengths", [LENGTHS, (49, 30, 18), (60, 2, 2, 2)])
-def test_loss_loads_and_every_gradient_match_the_reference(lengths):
+def test_loss_loads_and_every_gradient_match_the_reference(model, lengths):
     """The packed rows' loss, every gradient leaf and the routed counts
     against every session run ALONE and added up: no key of the session
     before, no target across a boundary, positions from 0, a band inside
     a session; a row's padding tail routed on both sides."""
-    p = small_spec()
-    params = weights(p)
     sessions = sessions_of(lengths)
     packed = seqrec.pack_sessions(sessions, L)
-    seqs, targets, ids, positions = arrays(packed)
-    with jax.default_matmul_precision("highest"):
-        (loss, (expert_layers, _, _)), grads = jax.value_and_grad(
-            seqrec._loss_fn, has_aux=True)(params, seqs, targets, p, None,
-                                           (ids, positions))
-    want_loss, want, load, _ = reference_of(p, params, sessions,
+    (loss, (expert_layers, _, _)), grads = model.loss_and_grads(
+        *arrays(packed))
+    want_loss, want, load, _ = reference_of(model.p, model.params, sessions,
                                             packed.inputs.size)
-    assert abs(float(loss) - want_loss) < 1e-5 * want_loss
+    assert abs(float(loss) - want_loss) < CASE.loss_tol * want_loss
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
                             jax.tree.leaves(want)):
-        if getattr(path[-1], "key", None) != "router_bias":
-            assert rel(g, w) < 2e-4, jax.tree_util.keystr(path)
+        if getattr(path[-1], "key", None) not in CASE.no_gradient:
+            assert rel(g, w) < CASE.grad_tol, jax.tree_util.keystr(path)
     assert np.array_equal(np.stack([s["load"] for s in expert_layers]), load)
 
 
-def test_sessions_one_a_row_and_packed_give_the_same_loss_and_gradients():
+def test_sessions_one_a_row_and_packed_give_the_same_loss_and_gradients(
+        model):
     """The same sessions trained a row each (left-padded, as every other
     configuration trains them) and packed: one loss, one gradient."""
-    p = small_spec()
-    params = weights(p)
     sessions = sessions_of()
-    packed = seqrec.pack_sessions(sessions, L)
-    seqs, targets, ids, positions = arrays(packed)
-    inputs, shifted = seqrec.pad_sessions(sessions, L)
-    whole = dataclasses.replace(p, packing=False)
-    with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(
-            lambda w: seqrec._loss_fn(w, seqs, targets, p, None,
-                                      (ids, positions))[0])(params)
-        want_loss, want = jax.value_and_grad(
-            lambda w: seqrec._loss_fn(w, jnp.asarray(inputs),
-                                      jnp.asarray(shifted), whole)[0])(params)
+    (loss, _), grads = model.loss_and_grads(
+        *arrays(seqrec.pack_sessions(sessions, L)))
+    (want_loss, _), want = model.of(packing=False).loss_and_grads(
+        *seqrec.pad_sessions(sessions, L))
     assert abs(float(loss) - float(want_loss)) < 1e-6 * float(want_loss)
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
                             jax.tree.leaves(want)):
@@ -600,11 +539,7 @@ def test_a_packed_train_counts_its_rows_sessions_and_pairs():
     multiplied; `recommend_next` serves the model."""
     from predictionio_tpu.obs.registry import default_registry
 
-    reg = default_registry()
-
-    def counted(name, **labels):
-        c = reg.get(name)
-        return c.value(**labels) if c is not None else 0
+    reg, counted = default_registry(), cases.counted
 
     def packs():
         spans = reg.get("pio_span_duration_seconds")
@@ -653,42 +588,3 @@ def test_a_packed_train_counts_its_rows_sessions_and_pairs():
     assert packs() == packs_before + 1
     top = model.recommend_next(sessions[0][:10], 5)
     assert len(top) == 5 and all(np.isfinite(score) for _, score in top)
-
-
-# -- what the program already ran ----------------------------------------------
-
-#: the first step's loss and whole gradient norm of each older sequence
-#: configuration's tiny section on one seeded batch, at the commit before
-#: this PR (dd23023): the new field's default changes nothing
-TINY_STEPS = {
-    "seqrec-kimi-vl-a3b-ep8": (5.165351390838623, 7.646289342187191),
-    "seqrec-qwen3-next-80b-a3b-ep16": (5.0000810623168945, 76.57708056258434),
-    "seqrec-lfm2-24b-a2b-ep8": (5.029688835144043, 4.87177540506),
-    "seqrec-ouro-2.6b-pp8": (5.02148962020874, 3.437611412089871),
-    "seqrec-nemotron3-super-120b-a12b-tp8ep64": (5.584339618682861,
-                                                 4.24192990355414),
-    "seqrec-laguna-xs2-ep8": (5.065154552459717, 6.6178168454284085),
-}
-
-
-@pytest.mark.parametrize("name", sorted(TINY_STEPS))
-def test_the_older_configurations_tiny_steps_give_the_losses_they_gave(
-        name, monkeypatch):
-    monkeypatch.undo()          # the blocks those numbers were read under
-    with open(os.path.join(CONFIGS, name + ".json")) as f:
-        tiny = json.load(f)["tiny"]
-    p = seqrec.SeqRecParams(**tiny["algorithm_params"])
-    assert not p.packing
-    params = seqrec.init_params(None, tiny["n_items"], p)
-    optimizer = seqrec.make_optimizer(p)
-    seqs = np.random.default_rng(40).integers(
-        1, tiny["n_items"] + 1, size=(p.batch_size, p.max_len + 1))
-    seqs[0, :7] = 0
-    _, _, stats = seqrec.make_train_step(None, p, optimizer)(
-        params, optimizer.init(params), jnp.asarray(seqs[:, :-1], jnp.int32),
-        jnp.asarray(seqs[:, 1:], jnp.int32))
-    norms = jax.device_get(stats["grad_norm"])
-    loss, norm = TINY_STEPS[name]
-    assert float(stats["loss"]) == pytest.approx(loss, rel=1e-6)
-    assert float(np.sqrt(sum(float(v) ** 2 for v in norms.values()))) \
-        == pytest.approx(norm, rel=1e-5)

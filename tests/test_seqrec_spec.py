@@ -10,98 +10,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import seqrec_cases as cases
+from seqrec_cases import (  # noqa: F401 (the fixtures: model, small_blocks)
+    batch, model, rel, small_blocks,
+)
 
-from benchmarks.checks import seqrec_reference as ref
 from predictionio_tpu.models import seqrec
 from predictionio_tpu.ops import moe
 from predictionio_tpu.ops.attention import blockwise_attention, mha, rope
 
-VOCAB, L = 97, 24
-
-
-def small_spec(**over) -> seqrec.SeqRecParams:
-    """d 64, 4 heads of nope/rope/v 16/8/16, latent 32, 8 experts top-2
-    + 1 shared, 1 dense + 2 expert layers."""
-    base = dict(
-        d_model=64, n_heads=4, n_layers=3, max_len=L, seed=11,
-        mixer="mla", ffn="moe", norm="rms", norm_eps=1e-5,
-        positions="rope", rope_theta=800000.0, tied_head=False,
-        ffn_width=160, first_dense_layers=1, qk_nope_head_dim=16,
-        qk_rope_head_dim=8, v_head_dim=16, kv_lora_rank=32,
-        n_routed_experts=8, held_experts=(0, 8), experts_per_token=2,
-        moe_width=48, n_shared_experts=1, routed_scaling_factor=2.446,
-        bias_update_rate=0.001, balance_loss_alpha=0.001,
-        remat=True)
-    return seqrec.SeqRecParams(**{**base, **over})
-
-
-@pytest.fixture(autouse=True)
-def small_blocks(monkeypatch):
-    """Blocks small enough that a session of 24 takes three attention
-    blocks and a step's 48 tokens four token blocks."""
-    monkeypatch.setattr(seqrec, "ATTENTION_BLOCK", 8)
-    monkeypatch.setattr(seqrec, "TOKEN_BLOCK", 12)
-
-
-def batch(seed=0, rows=2, pad=0):
-    rng = np.random.default_rng(seed)
-    s = rng.integers(1, VOCAB, size=(rows, L + 1))
-    s[:, :pad] = 0
-    return s[:, :-1].astype(np.int32), s[:, 1:].astype(np.int32)
-
-
-def weights(p, seed=3):
-    params = seqrec.init_params(np.random.default_rng(seed), VOCAB - 1, p)
-    # a bias that matters: selection must follow score + bias
-    rng = np.random.default_rng(seed + 1)
-    for i, layer in enumerate(params["layers"]):
-        if "router_bias" in layer:
-            layer["router_bias"] = jnp.asarray(
-                rng.normal(size=p.n_routed_experts) * 0.05, jnp.float32)
-    return params
-
-
-def ref_spec(p, **over):
-    return ref.Spec.of(dataclasses.asdict(p), **over)
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-30))
+#: the record (tests/seqrec_cases.py): blocks small enough that a session
+#: of 24 takes three attention blocks and a step's 48 tokens four token
+#: blocks
+CASE = cases.CASES["spec"]
+ref, L = CASE.ref, CASE.length
+small_spec, weights, ref_spec = CASE.small_spec, CASE.weights, CASE.ref_spec
 
 
 @pytest.mark.parametrize("pad", [0, 5])
-def test_loss_and_every_gradient_match_the_reference(pad):
-    """float32 on both sides, on the CPU; the orders of summation differ
-    (blocked attention, grouped experts, token blocks), which costs a few
-    float32 roundings a value: 2e-5 of each array's largest entry. A
-    lower precision anywhere reads 1e-3 and more (the int8 case below)."""
-    p = small_spec()
-    params = weights(p)
-    seqs, targets = batch(pad=pad)
-    with jax.default_matmul_precision("highest"):
-        (loss, _), grads = jax.value_and_grad(seqrec._loss_fn, has_aux=True)(
-            params, jnp.asarray(seqs), jnp.asarray(targets), p)
-    want_loss, want_grads, _ = ref.loss_and_grads(params, seqs, targets,
-                                                  ref_spec(p))
-    assert abs(float(loss) - want_loss) < 2e-6 * want_loss
-    got = dict(jax.tree_util.tree_leaves_with_path(grads))
-    for path, want in jax.tree_util.tree_leaves_with_path(want_grads):
-        assert rel(got[path], want) < 2e-5, jax.tree_util.keystr(path)
+def test_loss_and_every_gradient_match_the_reference(model, pad):
+    _, _, grads, _, _ = cases.loss_and_every_gradient_match_the_reference(
+        model, pad)
     # the selection bias takes no gradient
     assert not np.asarray(grads["layers"][1]["router_bias"]).any()
-    # and the control: the reference's own int8 products are 50x off
-    _, low, _ = ref.loss_and_grads(params, seqs, targets,
-                                   ref_spec(p, precision="int8"))
-    assert rel(low["layers"][2]["wo"], want_grads["layers"][2]["wo"]) > 1e-3
 
 
-def test_logits_match_the_reference():
-    p = small_spec()
-    params = weights(p)
+def test_logits_match_the_reference(model):
+    p, params = model.p, model.params
     seqs, _ = batch(seed=5, rows=1)
+    hidden = model.forward(seqs)
     with jax.default_matmul_precision("highest"):
-        hidden = seqrec.forward(params, jnp.asarray(seqs), p)
         logits = hidden[0] @ seqrec.head_matrix(params)
         spec = ref_spec(p)
         h = params["emb"][seqs[0]]
@@ -113,6 +51,10 @@ def test_logits_match_the_reference():
                      else ref.expert_layer(layer, x, spec)[0])
         want = ref.rms_norm(h, params["ln_f"]["scale"], 1e-5) @ params["head"]
     assert rel(logits, want) < 1e-5      # float32 roundings, as above
+
+
+def test_a_left_padded_session_is_the_unpadded_one(model):
+    cases.a_left_padded_session_is_the_unpadded_one(model)
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -211,22 +153,17 @@ def test_bias_update_and_balance_loss_by_hand():
                                1.5 * p0 + 1.5 * p2, rtol=1e-6)
 
 
-def test_a_train_step_moves_the_bias_and_leaves_it_out_of_adamw():
-    p = small_spec(bias_update_rate=0.01, learning_rate=1e-2)
-    params = weights(p)
-    before = np.asarray(params["layers"][1]["router_bias"])
-    optimizer = seqrec.make_optimizer(p)
-    step = seqrec.make_train_step(None, p, optimizer)
-    seqs, targets = batch()
-    _, _, load = ref.loss_and_grads(params, seqs, targets, ref_spec(p))
-    groups = set(ref.group_norms(params))
-    new, _, stats = step(params, optimizer.init(params), jnp.asarray(seqs),
-                         jnp.asarray(targets))            # donates params
+def test_a_train_step_moves_the_bias_and_leaves_it_out_of_adamw(model):
+    faster = model.of(bias_update_rate=0.01, learning_rate=1e-2)
+    before = np.asarray(faster.params["layers"][1]["router_bias"])
+    _, _, load = faster.reference()
+    new, stats = faster.step(*batch())
     assert np.array_equal(stats["load"], load)
     np.testing.assert_allclose(
         new["layers"][1]["router_bias"],
         ref.bias_after_step(before, load[0], 0.01), atol=1e-7)
-    assert set(stats["grad_norm"]) == set(stats["update_norm"]) == groups
+    assert set(stats["grad_norm"]) == set(stats["update_norm"]) == set(
+        ref.group_norms(faster.params))
 
 
 def test_blockwise_attention_with_unequal_widths_and_rope_matches_mha():
@@ -311,8 +248,7 @@ def test_recommend_next_reads_the_head_the_spec_names():
 
 def test_a_train_records_its_steps():
     p = small_spec(epochs=2, batch_size=2, device_init=True)
-    sessions = [[f"i{(3 * s + j * (1 + s % 2)) % 50:02d}"
-                 for j in range(L + 1)] for s in range(4)]
+    sessions = cases.sessions(4)
     model = seqrec.train_seqrec(None, sessions, p)
     rec = model.record
     assert len(rec["loss"]) == 4 and rec["loss"][-1] < rec["loss"][0]
@@ -326,31 +262,18 @@ def test_a_train_records_its_steps():
     assert again.record["loss"] == rec["loss"]
 
 
-def test_a_step_adds_what_the_references_adamw_adds():
-    """By parameter group, the norm of step 1's update against the
-    reference's adamw step from its own gradients (float32 both sides;
-    1e-4: adamw's first step is -lr g / (|g| + eps), and the few entries
-    whose gradient is near eps = 1e-8 feel the gradients' last digits),
-    and what a learning rate ten times off reads."""
-    p = small_spec(learning_rate=1e-3)
-    params = weights(p)
-    seqs, targets = batch(seed=2)
-    _, grads, load = ref.loss_and_grads(params, seqs, targets, ref_spec(p))
-    want = ref.first_update_norms(params, grads, load, ref_spec(p))
-    off = ref.first_update_norms(params, grads, load,
-                                 ref_spec(p, learning_rate=1e-2))
-    optimizer = seqrec.make_optimizer(p)
-    with jax.default_matmul_precision("highest"):
-        _, _, stats = seqrec.make_train_step(None, p, optimizer)(
-            params, optimizer.init(params), jnp.asarray(seqs),
-            jnp.asarray(targets))
-    got = {k: float(v) for k, v in stats["update_norm"].items()}
-    assert set(got) == set(want)
-    for group, norm in want.items():
-        assert abs(got[group] - norm) < 1e-4 * norm, group
-    assert off["layer1.attention"] > 9 * got["layer1.attention"]
+def test_a_step_adds_what_the_references_adamw_adds(model):
+    """The shared test at this record's 1e-4, and the router's group."""
+    *_, want = cases.a_step_adds_what_the_references_adamw_adds(
+        model, "layer1.attention")
     # a router's group holds its bias's own update: gamma x sign, 8 experts
     assert want["layer1.router"] ** 2 > 8 * 0.001 ** 2 * 0.99
+
+
+def test_a_train_steps_record_against_the_reference_and_the_int8_control(
+        model):
+    cases.a_train_steps_record_against_the_reference_and_the_int8_control(
+        model, ("attention", "experts", "ffn"))
 
 
 def test_memory_settings_are_no_part_of_a_runs_identity():

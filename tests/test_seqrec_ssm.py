@@ -5,84 +5,30 @@ with a multi-token-prediction module and a tensor share, against the
 plain reference the benchmark brings
 (benchmarks/checks/seqrec_ssm_reference.py), on seeded random weights at
 a small size; the chunked scan against the position-by-position
-recurrence; the share tied to the model; and the specs the program
-already ran, unchanged."""
+recurrence; the share tied to the model. (What the program already ran:
+the one table of pins in tests/test_seqrec_kinds.py.)"""
 
 import dataclasses
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import seqrec_cases as cases
+from seqrec_cases import (  # noqa: F401 (the fixtures: model, small_blocks)
+    VOCAB, batch, model, rel, small_blocks,
+)
 
-from benchmarks.checks import seqrec_ssm_reference as ref
 from predictionio_tpu.models import seqrec
 from predictionio_tpu.ops import moe, state_space
 
-VOCAB, L = 97, 24
-PERIOD = ("gqa", "moe", "ssm", "moe", "ssm")
-SSM = dict(heads=8, head_dim=8, groups=4, state=16, conv_kernel=4, chunk=8)
-
-
-def small_spec(**over) -> seqrec.SeqRecParams:
-    """d 64; a period of one attention layer (8 query heads of 8 over 2
-    key/value heads, no positions, norms or gate), two state-space layers
-    (8 heads of 8 in 4 groups, a state of 16, chunks of 8) and two expert
-    layers (16 experts top-3 of two matrices and a squared ReLU in a
-    latent of 32, a shared expert of 48); a module of one attention and
-    one expert layer; everything held here."""
-    base = dict(
-        d_model=64, n_heads=8, n_kv_heads=2, head_dim=8, n_layers=5,
-        max_len=L, seed=11, sublayers=PERIOD, ssm=SSM, norm="rms",
-        norm_eps=1e-5, positions="none", qk_norm=False,
-        attention_gate=False, tied_head=False, n_routed_experts=16,
-        held_experts=(0, 16), experts_per_token=3, moe_width=24,
-        expert_act="relu2", moe_latent_size=32, n_shared_experts=2,
-        routed_scaling_factor=5.0, mtp_layers=("gqa", "moe"),
-        mtp_loss_weight=0.1, remat=True)
-    return seqrec.SeqRecParams(**{**base, **over})
-
-
-@pytest.fixture(autouse=True)
-def small_blocks(monkeypatch):
-    """A session of 24 takes three attention blocks and three chunks of
-    the scan, a step's 48 tokens four token blocks."""
-    monkeypatch.setattr(seqrec, "ATTENTION_BLOCK", 8)
-    monkeypatch.setattr(seqrec, "TOKEN_BLOCK", 12)
-
-
-def batch(seed=0, rows=2, pad=0):
-    rng = np.random.default_rng(seed)
-    s = rng.integers(1, VOCAB, size=(rows, L + 1))
-    s[:, :pad] = 0
-    return s[:, :-1].astype(np.int32), s[:, 1:].astype(np.int32)
-
-
-def weights(p, seed=3):
-    """The spec's draws, with every norm's weight and the skip D moved
-    off their start so that they matter."""
-    params = seqrec.init_params(np.random.default_rng(seed), VOCAB - 1, p)
-    rng = np.random.default_rng(seed + 1)
-    moved = ("ln1", "ln2", "ln_f", "norm", "norm_e", "norm_h", "D")
-
-    def move(path, w):
-        if any(getattr(k, "key", None) in moved for k in path):
-            return w + jnp.asarray(rng.normal(size=w.shape) * 0.1,
-                                   jnp.float32)
-        return w
-
-    return jax.tree_util.tree_map_with_path(move, params)
-
-
-def ref_spec(p, **over):
-    return ref.Spec.of(dataclasses.asdict(p), **over)
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-30))
+#: the record (tests/seqrec_cases.py): a session of 24 takes three
+#: attention blocks and three chunks of the scan, a step's 48 tokens four
+#: token blocks
+CASE = cases.CASES["ssm"]
+ref, L = CASE.ref, CASE.length
+PERIOD, SSM = CASE.spec["sublayers"], CASE.spec["ssm"]
+small_spec, weights, ref_spec = CASE.small_spec, CASE.weights, CASE.ref_spec
 
 
 # -- the scan --------------------------------------------------------------
@@ -173,9 +119,9 @@ def test_a_new_layer_alone_matches_the_reference_forward_and_backward(kind):
     loss = lambda fn: lambda w, x: (jnp.sin(jnp.where(real, fn(w, x), 0.0))
                                     ).sum()
     with jax.default_matmul_precision("highest"):
-        got, want = program(layer, x), reference(layer, x)
-        d_got = jax.grad(loss(program), (0, 1))(layer, x)
-        d_want = jax.grad(loss(reference), (0, 1))(layer, x)
+        (got, d_got), (want, d_want) = (
+            jax.jit(lambda w, x: (fn(w, x), jax.grad(loss(fn), (0, 1))(w, x))
+                    )(layer, x) for fn in (program, reference))
     assert rel(jnp.where(real, got, 0.0), jnp.where(real, want, 0.0)) < 1e-5
     want_leaves = dict(jax.tree_util.tree_leaves_with_path(d_want))
     for path, g in jax.tree_util.tree_leaves_with_path(d_got):
@@ -233,77 +179,45 @@ def test_two_matrix_experts_in_passes_and_their_dropped_count():
 # -- the whole step ----------------------------------------------------------
 
 @pytest.mark.parametrize("pad", [0, 5])
-def test_loss_both_heads_and_every_gradient_match_the_reference(pad):
-    p = small_spec()
-    params = weights(p)
-    seqs, targets = batch(pad=pad)
-    with jax.default_matmul_precision("highest"):
-        (loss, (expert_layers, mixers, rest)), grads = jax.value_and_grad(
-            seqrec._loss_fn, has_aux=True)(
-            params, jnp.asarray(seqs), jnp.asarray(targets), p)
-    want_loss, want_grads, want = ref.loss_and_grads(params, seqs, targets,
-                                                     ref_spec(p))
-    assert abs(float(loss) - want_loss) < 2e-6 * want_loss
+def test_loss_both_heads_and_every_gradient_match_the_reference(model, pad):
+    loss, (expert_layers, mixers, rest), grads, _, want = \
+        cases.loss_and_every_gradient_match_the_reference(model, pad)
     assert abs(float(rest["mtp_loss"]) - want["mtp_loss"]) \
         < 2e-6 * want["mtp_loss"]
     # the module's loss is in the loss a tenth, and is no copy of the main
     main = float(loss) - 0.1 * float(rest["mtp_loss"])
     assert abs(main - float(rest["mtp_loss"])) > 1e-3
-    got = dict(jax.tree_util.tree_leaves_with_path(grads))
-    for path, w in jax.tree_util.tree_leaves_with_path(want_grads):
-        assert rel(got[path], w) < 1e-3, jax.tree_util.keystr(path)
-    groups = seqrec._group_norms(grads)
-    assert set(groups) == set(ref.group_norms(want_grads))
     assert {"layer0.attention", "layer1.latent_projection",
             "layer2.state_space", "layer1.shared_expert", "mtp",
-            "mtp0.attention", "mtp1.experts", "mtp1.norms"} <= set(groups)
+            "mtp0.attention", "mtp1.experts", "mtp1.norms"} <= set(
+                seqrec._group_norms(grads))
     # the expert loads, the module's layer after the stack's two
     load = np.stack([np.asarray(s["load"]) for s in expert_layers])
     assert load.shape == (3, 16) and np.array_equal(load, want["load"])
     assert {k: int(v) for k, v in mixers.items()} == {"gqa": 2, "ssm": 2}
 
 
-def test_a_train_steps_record_against_the_reference_and_the_int8_control():
-    p = small_spec(learning_rate=1e-3)
-    params = weights(p)
-    theta0 = jax.tree.map(np.asarray, params)
-    seqs, targets = batch(seed=2)
-    optimizer = seqrec.make_optimizer(p)
-    with jax.default_matmul_precision("highest"):
-        after, _, stats = seqrec.make_train_step(None, p, optimizer)(
-            params, optimizer.init(params), jnp.asarray(seqs),
-            jnp.asarray(targets))
-    stats = jax.device_get(stats)
-    spec = ref_spec(p)
-    loss, grads, rest = ref.loss_and_grads(theta0, seqs, targets, spec)
-    update_norms, by_expert = ref.first_update_norms(theta0, grads, spec)
-    for key, want in (("grad_norm", ref.group_norms(grads)),
-                      ("update_norm", update_norms)):
-        assert set(stats[key]) == set(want)
-        for group, norm in want.items():
-            assert abs(float(stats[key][group]) - norm) < 2e-4 * norm, group
+def test_a_left_padded_session_is_the_unpadded_one(model):
+    cases.a_left_padded_session_is_the_unpadded_one(model)
+
+
+def test_a_train_steps_record_against_the_reference_and_the_int8_control(
+        model):
+    after, stats, rest, update_norms, by_expert = \
+        cases.a_train_steps_record_against_the_reference_and_the_int8_control(
+            model, ("state_space", "attention", "experts"))
     # the experts' update expert by expert, the module's layer last; over
     # a layer's held experts it is the group's
     assert by_expert.shape == (3, 16)
-    assert np.allclose(stats["expert_update_norm"], by_expert, rtol=2e-4)
     assert np.allclose(np.sqrt((by_expert ** 2).sum(-1)), [
         update_norms[g] for g in ("layer1.experts", "layer3.experts",
                                   "mtp1.experts")], rtol=1e-6)
     assert np.array_equal(stats["load"], rest["load"])
-    assert int(stats["dropped"].sum()) == 0
     assert {k: int(v) for k, v in stats["layer_passes"].items()} \
         == {"first": 7, "repeat": 0}
     # the selection bias is no parameter of adamw's and its rate is 0
     assert not np.asarray(after["layers"][1]["router_bias"]).any()
     assert not np.asarray(after["mtp"]["layers"][1]["router_bias"]).any()
-    # every matrix product's operands at 8 bits: the loss and every
-    # part's gradient leave by far more than the program does
-    low, low_grads, _ = ref.loss_and_grads(theta0, seqs, targets,
-                                           ref_spec(p, precision="int8"))
-    assert abs(low - loss) > 1e-4 * loss
-    sound, low_norms = ref.group_norms(grads), ref.group_norms(low_grads)
-    assert all(abs(low_norms[g] - n) > 1e-3 * n for g, n in sound.items()
-               if g.endswith(("state_space", "attention", "experts")))
 
 
 @pytest.mark.parametrize("control", [
@@ -311,13 +225,9 @@ def test_a_train_steps_record_against_the_reference_and_the_int8_control():
     {"norm_gate_left_out": True}, {"latent_as_slice": True},
     {"dropped_head": 0}, {"relu_plain": True}, {"mtp_loss_weight": 0.0},
     {"mtp_wrong_item": True}])
-def test_every_fault_control_of_the_reference_moves_the_loss(control):
-    p = small_spec()
-    params = weights(p)
-    seqs, targets = batch(seed=4, rows=1)
-    sound = ref.loss_and_grads(params, seqs, targets, ref_spec(p))[0]
-    broken = ref.loss_and_grads(params, seqs, targets,
-                                ref_spec(p, **control))[0]
+def test_every_fault_control_of_the_reference_moves_the_loss(model, control):
+    sound = model.reference(4, rows=1)[0]
+    broken = model.reference(4, rows=1, **control)[0]
     assert abs(broken - sound) > 1e-4 * sound
 
 
@@ -379,8 +289,8 @@ def test_the_tensor_shares_of_a_mixer_add_up_to_the_uncut_reference(kind):
     p, layer, x, key_mask = layer_case(kind)
     held = dataclasses.replace(p, tensor_ways=WAYS)
     with jax.default_matmul_precision("highest"):
-        whole = layer_fns(kind, p, key_mask)[1](layer, x)     # the reference
-        program = layer_fns(kind, held, key_mask)[0]
+        whole = jax.jit(layer_fns(kind, p, key_mask)[1])(layer, x)  # reference
+        program = jax.jit(layer_fns(kind, held, key_mask)[0])
         parts = [program(share_of(layer, kind, rank, p), x)
                  for rank in range(WAYS)]
     real = key_mask[..., None]
@@ -451,7 +361,7 @@ def test_check_refuses_what_the_new_fields_cannot_mean(over, message):
         small_spec(**over).check()
 
 
-def test_the_spec_by_layer_and_its_key():
+def test_the_spec_by_layer_and_its_key(model):
     p = small_spec(n_layers=7)
     p.check()
     assert [p.mixer_kind(i) for i in range(7)] == [
@@ -466,8 +376,9 @@ def test_the_spec_by_layer_and_its_key():
     assert p.spec_key() == small_spec(n_layers=7).spec_key()
     # a layer of one sub-layer has one norm; a feed-forward's kind may
     # differ from layer to layer
-    mixed = small_spec(sublayers=("gqa", "swiglu", "ssm", "gelu", "moe"),
-                       mtp_layers=(), ffn_width=48)
+    mixed_model = model.of(sublayers=("gqa", "swiglu", "ssm", "gelu", "moe"),
+                           mtp_layers=(), ffn_width=48)
+    mixed = mixed_model.p
     mixed.check()
     params = seqrec.init_params(None, VOCAB - 1, dataclasses.replace(
         mixed, device_init=True))
@@ -477,9 +388,7 @@ def test_the_spec_by_layer_and_its_key():
                                      ["ln2"]]
     assert "w_gate" in layers[1] and "w1" in layers[3] \
         and "router" in layers[4]
-    seqs, targets = batch()
-    loss, _ = seqrec._loss_fn(params, jnp.asarray(seqs),
-                              jnp.asarray(targets), mixed)
+    (loss, _), _ = mixed_model.loss_and_grads(*batch(), params=params)
     assert np.isfinite(float(loss))
 
 
@@ -537,41 +446,3 @@ def test_a_train_records_the_module_counts_its_layers_and_serves_from_the_main_h
     without = dataclasses.replace(model, params={
         k: v for k, v in model.params.items() if k != "mtp"})
     assert without.recommend_next(SESSIONS[0][:10], 5) == top
-
-
-# -- what the program already ran ----------------------------------------------
-
-#: the first step's loss and whole gradient norm of each sequence
-#: configuration's tiny section on one seeded batch, at the commit before
-#: this layer spec grew (PR 39): the new fields' defaults change nothing
-TINY_STEPS = {
-    "seqrec-kimi-vl-a3b-ep8": (5.165351390838623, 7.646289342187191),
-    "seqrec-qwen3-next-80b-a3b-ep16": (5.0000810623168945, 76.57708056258434),
-    "seqrec-lfm2-24b-a2b-ep8": (5.029688835144043, 4.87177540506),
-    "seqrec-ouro-2.6b-pp8": (5.02148962020874, 3.437611412089871),
-}
-
-
-@pytest.mark.parametrize("name", sorted(TINY_STEPS))
-def test_the_existing_configurations_tiny_steps_give_the_losses_they_gave(
-        name, monkeypatch):
-    monkeypatch.undo()          # the blocks those numbers were read under
-    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                           "configs", name + ".json")) as f:
-        tiny = json.load(f)["tiny"]
-    p = seqrec.SeqRecParams(**tiny["algorithm_params"])
-    params = seqrec.init_params(None, tiny["n_items"], p)
-    optimizer = seqrec.make_optimizer(p)
-    seqs = np.random.default_rng(40).integers(
-        1, tiny["n_items"] + 1, size=(p.batch_size, p.max_len + 1))
-    seqs[0, :7] = 0
-    _, _, stats = seqrec.make_train_step(None, p, optimizer)(
-        params, optimizer.init(params), jnp.asarray(seqs[:, :-1], jnp.int32),
-        jnp.asarray(seqs[:, 1:], jnp.int32))
-    norms = jax.device_get(stats["grad_norm"])
-    loss, norm = TINY_STEPS[name]
-    assert float(stats["loss"]) == pytest.approx(loss, rel=1e-6)
-    assert float(np.sqrt(sum(float(v) ** 2 for v in norms.values()))) \
-        == pytest.approx(norm, rel=1e-5)
-    assert "layer_passes" not in stats or p.n_loops > 1
-    assert "mtp_loss" not in stats

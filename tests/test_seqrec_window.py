@@ -6,86 +6,31 @@ reference the benchmark brings
 (benchmarks/checks/seqrec_window_reference.py), on seeded random weights
 at a small size; the band in the scan and in the interpreted kernels
 against a dense masked softmax; the pair tables; YaRN by hand; the share
-tied to the model; and the specs the program already ran, unchanged."""
+tied to the model. (What the program already ran: the one table of pins
+in tests/test_seqrec_kinds.py.)"""
 
 import dataclasses
-import json
 import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import seqrec_cases as cases
+from seqrec_cases import (  # noqa: F401 (the fixtures: model, small_blocks)
+    VOCAB, YARN, model, rel, small_blocks,
+)
 
-from benchmarks.checks import seqrec_window_reference as ref
 from predictionio_tpu.models import seqrec
 from predictionio_tpu.ops import attention, attention_pallas
 from predictionio_tpu.ops.attention import YarnScaling
 
-VOCAB, L = 97, 24
-YARN = dict(factor=4.0, original_max_len=16, beta_fast=4.0, beta_slow=1.0,
-            attention_factor=1.1386294361119891)
-SWA = dict(heads=8, window=7, rope_theta=10000.0, rotary_dim=8)
-
-
-def small_spec(**over) -> seqrec.SeqRecParams:
-    """d 64; five layers, full, sliding, sliding, sliding, full: the full
-    ones 4 query heads of 8 over 2 key/value heads, rotary on 4 of 8
-    columns at theta 500,000 under YaRN; the sliding ones 8 heads, a
-    window of 7, rotary on all 8 at theta 10,000; a gate of one column a
-    head, no q/k norm; a leading dense SwiGLU of 96, then 16 sigmoid-
-    routed experts of 24 top-3 scaled 2.5 beside a shared one;
-    everything held here."""
-    base = dict(
-        d_model=64, n_heads=4, n_kv_heads=2, head_dim=8, n_layers=5,
-        max_len=L, seed=11, mixer=("gqa", "swa", "swa", "swa"), swa=SWA,
-        ffn="moe", first_dense_layers=1, ffn_width=96, norm="rms",
-        norm_eps=1e-6, positions="rope", rope_theta=500000.0, rotary_dim=4,
-        rope_scaling=YARN, qk_norm=False, attention_gate="head",
-        tied_head=False, n_routed_experts=16, held_experts=(0, 16),
-        experts_per_token=3, moe_width=24, n_shared_experts=1,
-        routed_scaling_factor=2.5, expert_update_by_expert=True, remat=True)
-    return seqrec.SeqRecParams(**{**base, **over})
-
-
-@pytest.fixture(autouse=True)
-def small_blocks(monkeypatch):
-    """A session of 24 takes three attention blocks (a window of 7 leaves
-    the pair (2, 0) out), a step's 48 tokens four token blocks."""
-    monkeypatch.setattr(seqrec, "ATTENTION_BLOCK", 8)
-    monkeypatch.setattr(seqrec, "TOKEN_BLOCK", 12)
-
-
-def batch(seed=0, rows=2, pad=0):
-    rng = np.random.default_rng(seed)
-    s = rng.integers(1, VOCAB, size=(rows, L + 1))
-    s[:, :pad] = 0
-    return s[:, :-1].astype(np.int32), s[:, 1:].astype(np.int32)
-
-
-def weights(p, seed=3):
-    """The spec's draws, with every norm's weight moved off 1 so that it
-    matters."""
-    params = seqrec.init_params(np.random.default_rng(seed), VOCAB - 1, p)
-    rng = np.random.default_rng(seed + 1)
-
-    def move(path, w):
-        if any(getattr(k, "key", None) in ("ln1", "ln2", "ln_f")
-               for k in path):
-            return w + jnp.asarray(rng.normal(size=w.shape) * 0.1,
-                                   jnp.float32)
-        return w
-
-    return jax.tree_util.tree_map_with_path(move, params)
-
-
-def ref_spec(p, **over):
-    return ref.Spec.of(dataclasses.asdict(p), **over)
-
-
-def rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-30))
+#: the record (tests/seqrec_cases.py): a session of 24 takes three
+#: attention blocks (a window of 7 leaves the pair (2, 0) out), a step's
+#: 48 tokens four token blocks
+CASE = cases.CASES["window"]
+ref, L, SWA = CASE.ref, CASE.length, CASE.spec["swa"]
+small_spec, weights, ref_spec = CASE.small_spec, CASE.weights, CASE.ref_spec
 
 
 # -- the band ----------------------------------------------------------------
@@ -358,24 +303,10 @@ def test_a_left_padded_session_is_the_unpadded_one_in_a_sliding_layer():
 # -- the whole step -----------------------------------------------------------
 
 @pytest.mark.parametrize("pad", [0, 5])
-def test_loss_loads_and_every_gradient_match_the_reference(pad):
-    p = small_spec()
-    params = weights(p)
-    seqs, targets = batch(pad=pad)
-    with jax.default_matmul_precision("highest"):
-        (loss, (expert_layers, mixers, _)), grads = jax.value_and_grad(
-            seqrec._loss_fn, has_aux=True)(
-            params, jnp.asarray(seqs), jnp.asarray(targets), p)
-    want_loss, want_grads, want_load = ref.loss_and_grads(
-        params, seqs, targets, ref_spec(p))
-    assert abs(float(loss) - want_loss) < 2e-6 * want_loss
-    got = dict(jax.tree_util.tree_leaves_with_path(grads))
-    for path, w in jax.tree_util.tree_leaves_with_path(want_grads):
-        if getattr(path[-1], "key", None) == "router_bias":
-            continue        # no gradient reaches a selection bias
-        assert rel(got[path], w) < 1e-3, jax.tree_util.keystr(path)
+def test_loss_loads_and_every_gradient_match_the_reference(model, pad):
+    _, (expert_layers, mixers, _), grads, _, want_load = \
+        cases.loss_and_every_gradient_match_the_reference(model, pad)
     groups = seqrec._group_norms(grads)
-    assert set(groups) == set(ref.group_norms(want_grads))
     assert {"layer0.attention", "layer0.ffn", "layer1.window_attention",
             "layer3.window_attention", "layer4.attention", "layer1.router",
             "layer4.experts", "layer2.shared_expert",
@@ -386,50 +317,35 @@ def test_loss_loads_and_every_gradient_match_the_reference(pad):
     assert {k: int(v) for k, v in mixers.items()} == {"gqa": 2, "swa": 3}
 
 
-def test_a_train_steps_record_against_the_reference_and_the_int8_control():
-    p = small_spec(learning_rate=1e-3)
-    params = weights(p)
-    theta0 = jax.tree.map(np.asarray, params)
-    seqs, targets = batch(seed=2)
-    optimizer = seqrec.make_optimizer(p)
-    with jax.default_matmul_precision("highest"):
-        after, _, stats = seqrec.make_train_step(None, p, optimizer)(
-            params, optimizer.init(params), jnp.asarray(seqs),
-            jnp.asarray(targets))
-    stats = jax.device_get(stats)
-    spec = ref_spec(p)
-    loss, grads, load = ref.loss_and_grads(theta0, seqs, targets, spec)
-    update_norms, by_expert = ref.first_update_norms(theta0, grads, spec)
-    for key, want in (("grad_norm", ref.group_norms(grads)),
-                      ("update_norm", update_norms)):
-        assert set(stats[key]) == set(want)
-        for group, norm in want.items():
-            assert abs(float(stats[key][group]) - norm) < 2e-4 * norm, group
+def test_logits_match_the_reference(model):
+    cases.logits_match_the_reference(model)
+
+
+def test_a_left_padded_session_is_the_unpadded_one(model):
+    cases.a_left_padded_session_is_the_unpadded_one(model)
+
+
+def test_a_train_steps_record_against_the_reference_and_the_int8_control(
+        model):
+    after, stats, load, update_norms, by_expert = \
+        cases.a_train_steps_record_against_the_reference_and_the_int8_control(
+            model, ("attention", "experts", "ffn"))
     # the experts' update expert by expert; over a layer's held experts
     # it is the group's
     assert by_expert.shape == (4, 16)
-    assert np.allclose(stats["expert_update_norm"], by_expert, rtol=2e-4)
     assert np.allclose(np.sqrt((by_expert ** 2).sum(-1)), [
         update_norms[f"layer{i}.experts"] for i in (1, 2, 3, 4)], rtol=1e-6)
     assert np.array_equal(stats["load"], load)
-    assert int(stats["dropped"].sum()) == 0
     assert "layer_passes" not in stats
     # the selection bias is no parameter of adamw's and its rate is 0
     assert not np.asarray(after["layers"][1]["router_bias"]).any()
     # one held expert left where it is: its layer's group and its own row
-    frozen = dataclasses.replace(spec, expert_not_updated=(1, 3))
-    less, by_expert_less = ref.first_update_norms(theta0, grads, frozen)
+    grads = model.reference(2)[1]
+    less, by_expert_less = model.update_norms(grads, load,
+                                              expert_not_updated=(1, 3))
     assert by_expert_less[1, 3] == 0 and less["layer2.experts"] \
         < update_norms["layer2.experts"]
     assert less["layer1.experts"] == update_norms["layer1.experts"]
-    # every matrix product's operands at 8 bits: the loss and every
-    # part's gradient leave by far more than the program does
-    low, low_grads, _ = ref.loss_and_grads(theta0, seqs, targets,
-                                           ref_spec(p, precision="int8"))
-    assert abs(low - loss) > 1e-4 * loss
-    sound, low_norms = ref.group_norms(grads), ref.group_norms(low_grads)
-    assert all(abs(low_norms[g] - n) > 1e-3 * n for g, n in sound.items()
-               if g.endswith(("attention", "experts", "ffn")))
 
 
 #: each planted fault of the reference and a gradient part it has to move
@@ -452,14 +368,9 @@ def test_every_fault_has_a_part():
 
 
 @pytest.mark.parametrize("fault", sorted(FAULT_PARTS))
-def test_every_fault_control_of_the_reference_moves_its_part(fault):
-    p = small_spec()
-    params = weights(p)
-    seqs, targets = batch(seed=7)
-    sound_loss, sound, _ = ref.loss_and_grads(params, seqs, targets,
-                                              ref_spec(p))
-    loss, grads, _ = ref.loss_and_grads(params, seqs, targets,
-                                        ref_spec(p, fault=fault))
+def test_every_fault_control_of_the_reference_moves_its_part(model, fault):
+    sound_loss, sound, _ = model.reference(7)
+    loss, grads, _ = model.reference(7, fault=fault)
     assert abs(loss - sound_loss) > 1e-6 * sound_loss
     sound, broken = ref.group_norms(sound), ref.group_norms(grads)
     part = FAULT_PARTS[fault]
@@ -726,14 +637,7 @@ def test_a_train_counts_the_band_and_serves(tmp_path):
     """The mixer counter's new label, the band's pair counters, the
     attention route's counter over both kinds; `recommend_next` runs the
     windowed layers too."""
-    from predictionio_tpu.obs.registry import default_registry
-
-    reg = default_registry()
-
-    def counted(name, **labels):
-        c = reg.get(name)
-        return c.value(**labels) if c is not None else 0
-
+    counted = cases.counted
     series = [("pio_train_seqrec_mixer_tokens_total", {"mixer": "swa"}),
               ("pio_train_seqrec_mixer_tokens_total", {"mixer": "gqa"}),
               ("pio_train_seqrec_attention_tokens_total", {"impl": "xla"}),
@@ -741,8 +645,7 @@ def test_a_train_counts_the_band_and_serves(tmp_path):
               ("pio_train_seqrec_window_block_pairs_total", {})]
     before = [counted(name, **labels) for name, labels in series]
     p = small_spec(epochs=1, batch_size=2, device_init=True)
-    sessions = [[f"i{(3 * s + j * (1 + s % 2)) % 50:02d}"
-                 for j in range(L + 1)] for s in range(4)]
+    sessions = cases.sessions(4)
     model = seqrec.train_seqrec(None, sessions, p)
     record = model.record
     assert len(record["loss"]) == 2
@@ -757,42 +660,3 @@ def test_a_train_counts_the_band_and_serves(tmp_path):
                       4 * 3 * (28 + 17 * 7), 4 * 3 * 5 * 64]
     top = model.recommend_next(sessions[0][:10], 5)
     assert len(top) == 5 and all(np.isfinite(score) for _, score in top)
-
-
-# -- what the program already ran ----------------------------------------------
-
-#: the first step's loss and whole gradient norm of each older sequence
-#: configuration's tiny section on one seeded batch, at the commit before
-#: this PR (50dd5dc): the new fields' defaults change nothing
-TINY_STEPS = {
-    "seqrec-kimi-vl-a3b-ep8": (5.165351390838623, 7.646289342187191),
-    "seqrec-qwen3-next-80b-a3b-ep16": (5.0000810623168945, 76.57708056258434),
-    "seqrec-lfm2-24b-a2b-ep8": (5.029688835144043, 4.87177540506),
-    "seqrec-ouro-2.6b-pp8": (5.02148962020874, 3.437611412089871),
-    "seqrec-nemotron3-super-120b-a12b-tp8ep64": (5.584339618682861,
-                                                 4.24192990355414),
-}
-
-
-@pytest.mark.parametrize("name", sorted(TINY_STEPS))
-def test_the_older_configurations_tiny_steps_give_the_losses_they_gave(
-        name, monkeypatch):
-    monkeypatch.undo()          # the blocks those numbers were read under
-    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                           "configs", name + ".json")) as f:
-        tiny = json.load(f)["tiny"]
-    p = seqrec.SeqRecParams(**tiny["algorithm_params"])
-    params = seqrec.init_params(None, tiny["n_items"], p)
-    optimizer = seqrec.make_optimizer(p)
-    seqs = np.random.default_rng(40).integers(
-        1, tiny["n_items"] + 1, size=(p.batch_size, p.max_len + 1))
-    seqs[0, :7] = 0
-    _, _, stats = seqrec.make_train_step(None, p, optimizer)(
-        params, optimizer.init(params), jnp.asarray(seqs[:, :-1], jnp.int32),
-        jnp.asarray(seqs[:, 1:], jnp.int32))
-    norms = jax.device_get(stats["grad_norm"])
-    loss, norm = TINY_STEPS[name]
-    assert float(stats["loss"]) == pytest.approx(loss, rel=1e-6)
-    assert float(np.sqrt(sum(float(v) ** 2 for v in norms.values()))) \
-        == pytest.approx(norm, rel=1e-5)
-    assert ("expert_update_norm" in stats) == (p.expert_act == "relu2")

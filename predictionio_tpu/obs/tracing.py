@@ -337,22 +337,46 @@ class span:
 
     def __exit__(self, *exc) -> bool:
         end = time.perf_counter_ns()
-        trace, registry = self._trace, self.registry
-        hist = None
+        trace = self._trace
         if trace is not None:
             self._record.end_ns = end
             _open_var.set(self._record.parent)
-            if registry is None:
-                hist = trace.span_hist
-                if hist is None and trace.registry is not None:
-                    hist = span_histogram(trace.registry)
-        if hist is None and registry is not None:
-            hist = span_histogram(registry)
+        hist = _span_hist(trace, self.registry)
         if hist is not None:
             hist.observe((end - self._t0) * 1e-9, span=self.name)
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
         return False
+
+
+def _span_hist(trace: Optional[Trace], registry: Optional[MetricsRegistry]):
+    """The span histogram a stage's sample goes to: the given registry's,
+    else the one the active trace resolved, else none."""
+    if registry is not None:
+        return span_histogram(registry)
+    if trace is None:
+        return None
+    if trace.span_hist is None and trace.registry is not None:
+        return span_histogram(trace.registry)
+    return trace.span_hist
+
+
+def timed_stage(name: str, seconds: float, hist=None) -> None:
+    """A stage timed by the caller's own clock, on whatever thread spent
+    it: it lasted ``seconds``. One sample of
+    ``pio_span_duration_seconds{span=name}`` (``hist``: a handle a hot
+    path resolved once; else as :class:`span` finds it) and one row on
+    the active trace under the span open here (:meth:`Trace.add`). Such
+    a row has a length, not a position: its end is the moment of this
+    call. No profiler annotation: a capture's host lines hold only
+    blocks that were open on the clock."""
+    trace = _trace_var.get()
+    if hist is None:
+        hist = _span_hist(trace, None)
+    if hist is not None:
+        hist.observe(seconds, span=name)
+    if trace is not None:
+        trace.add(name, seconds)
 
 
 def log_slow_request(service: str, method: str, path: str, status: int,

@@ -81,6 +81,7 @@ from predictionio_tpu.obs.slo import SLOEngine, SLOSpec
 from predictionio_tpu.obs.trace_context import record_event
 from predictionio_tpu.obs.tracing import (
     capture_context, carried, current_trace, span, span_histogram,
+    timed_stage,
 )
 from predictionio_tpu.ops.bucketing import bucket_size, padding_waste
 from predictionio_tpu.server.plugins import PluginContext
@@ -118,10 +119,7 @@ def _stage(hist, name: str):
         yield
     finally:
         dt = time.perf_counter() - t0
-        hist.observe(dt, span=name)
-        trace = current_trace()
-        if trace is not None:
-            trace.add(name, dt)
+        timed_stage(name, dt, hist)
         # and into the active batch's anatomy breakdown (no-op outside
         # a micro-batch) so members get their per-request stage share
         note_stage(name, dt)

@@ -13,6 +13,7 @@ from __future__ import annotations
 import datetime as _dt
 import json
 import logging
+import time
 from typing import Optional, Tuple
 
 from predictionio_tpu.core.engine import Engine
@@ -22,10 +23,11 @@ from predictionio_tpu.storage.base import EngineInstance
 from predictionio_tpu.storage.registry import Storage
 from predictionio_tpu.workflow.context import WorkflowContext, WorkflowParams
 from predictionio_tpu.workflow.instrument import (
-    observe_persist, workflow_run_metrics,
+    PersistRecord, digest_parts, observe_persist, pickling_parts,
+    process_faults, unwritten_bytes, workflow_run_metrics,
 )
 from predictionio_tpu.workflow.serialization import (
-    DigestingWriter, dump_models,
+    CollectorPauses, DigestingWriter, dump_models,
 )
 
 logger = logging.getLogger("pio.workflow")
@@ -38,45 +40,54 @@ def run_train(engine: Engine,
               workflow_params: Optional[WorkflowParams] = None,
               ctx: Optional[WorkflowContext] = None) -> EngineInstance:
     """Returns the COMPLETED EngineInstance (raises on failure)."""
-    wp = workflow_params or WorkflowParams()
-    ctx = ctx or WorkflowContext.create(
-        mode="Training", batch=wp.batch, workflow_params=wp)
-
-    instances = Storage.get_meta_data_engine_instances()
-    instance = EngineInstance(
-        status="INIT",
-        start_time=_dt.datetime.now(tz=UTC),
-        engine_id=engine_factory or type(engine).__name__,
-        engine_version="1",
-        engine_variant=engine_variant,
-        engine_factory=engine_factory,
-        batch=wp.batch,
-        runtime_conf={k: str(v) for k, v in wp.runtime_conf.items()},
-        data_source_params=json.dumps(
-            params_to_json(engine_params.data_source_params), sort_keys=True),
-        preparator_params=json.dumps(
-            params_to_json(engine_params.preparator_params), sort_keys=True),
-        algorithms_params=json.dumps(
-            [{"name": n, "params": params_to_json(p)}
-             for n, p in engine_params.algorithm_params_list], sort_keys=True),
-        serving_params=json.dumps(
-            params_to_json(engine_params.serving_params), sort_keys=True),
-    )
-    instance_id = instances.insert(instance)
-    instance.id = instance_id  # insert returns the generated id; don't rely
-    # on the backend mutating the record in place
-    logger.info("EngineInstance %s created (INIT)", instance_id)
-
-    model_digest, model_size = "", 0
-    # the whole run is one trace: a parent pipeline (or a multi-process
-    # launcher) hands its context via PIO_TRACE_CONTEXT so this train's
-    # record joins the parent's trace id in the flight recorder
+    # the whole run is one trace, opened before anything is read or
+    # written: a parent pipeline (or a multi-process launcher) hands its
+    # context via PIO_TRACE_CONTEXT so this train's record joins the
+    # parent's trace id in the flight recorder
     from predictionio_tpu.deploy.releases import record_release
     from predictionio_tpu.obs.trace_context import record_event
     from predictionio_tpu.obs.tracing import adopt, span
 
-    with adopt("train", attrs={"instance": instance_id,
-                               "variant": engine_variant}):
+    wp = workflow_params or WorkflowParams()
+    model_digest, model_size = "", 0
+    attrs = {"variant": engine_variant}
+    with adopt("train", attrs=attrs):
+        # the workflow context, the instance's row (four parameter sets as
+        # JSON) and its insert, a metadata-store commit
+        with span("train_begin"):
+            ctx = ctx or WorkflowContext.create(
+                mode="Training", batch=wp.batch, workflow_params=wp)
+            instances = Storage.get_meta_data_engine_instances()
+            instance = EngineInstance(
+                status="INIT",
+                start_time=_dt.datetime.now(tz=UTC),
+                engine_id=engine_factory or type(engine).__name__,
+                engine_version="1",
+                engine_variant=engine_variant,
+                engine_factory=engine_factory,
+                batch=wp.batch,
+                runtime_conf={k: str(v) for k, v in wp.runtime_conf.items()},
+                data_source_params=json.dumps(
+                    params_to_json(engine_params.data_source_params),
+                    sort_keys=True),
+                preparator_params=json.dumps(
+                    params_to_json(engine_params.preparator_params),
+                    sort_keys=True),
+                algorithms_params=json.dumps(
+                    [{"name": n, "params": params_to_json(p)}
+                     for n, p in engine_params.algorithm_params_list],
+                    sort_keys=True),
+                serving_params=json.dumps(
+                    params_to_json(engine_params.serving_params),
+                    sort_keys=True),
+            )
+            instance_id = instances.insert(instance)
+            # insert returns the generated id; don't rely on the backend
+            # mutating the record in place
+            instance.id = instance_id
+            attrs["instance"] = instance_id   # the record is written on exit
+            logger.info("EngineInstance %s created (INIT)", instance_id)
+
         with workflow_run_metrics("train", "pio_train"):
             # CoreWorkflow.runTrain:45 — train, persist, mark COMPLETED
             result = engine.train(
@@ -132,22 +143,56 @@ def _persist(instance_id: str, models) -> Tuple[str, int]:
     (`persist_dump`: the pickler and the write; `persist_close`: the hash
     thread's join and, where the store handed out its own file, that
     file's close, the flush to the mount; `persist_commit`, in the
-    store: the rename or the row's insert)."""
-    from predictionio_tpu.obs.tracing import span
+    store: the rename or the row's insert).
+
+    `persist_dump` is accounted from inside, by the thread that spends
+    the seconds (`instrument.pickling_parts`, `digest_parts`): eight
+    sums a persist, each one sample of the span histogram and one row of
+    the train's record (`tracing.timed_stage`), the pickling thread's
+    under `persist_dump`, the digest thread's under `train_persist` once
+    the join has made them final. None is a `span()`: the device is idle
+    from one end of `persist_dump` to the other, and an annotation a
+    buffer, or any on the digest thread, would cut that one gap of a
+    capture into hundreds."""
+    from predictionio_tpu.obs.tracing import span, timed_stage
 
     store = Storage.get_model_data_models()
+    pauses = CollectorPauses()
     with store.open_write(instance_id) as f:
-        with DigestingWriter(f) as out:
+        with DigestingWriter(f, pauses) as out:
+            # the machine, read at each end of the span and outside it
+            faults, dirty = process_faults(), unwritten_bytes()
             with span("persist_dump"):
-                fetched = dump_models(models, out)
+                t0 = time.perf_counter()
+                with pauses:
+                    fetched = dump_models(models, out, pauses)
+                dump_seconds = time.perf_counter() - t0
+                parts = pickling_parts(dump_seconds, fetched.wait_seconds,
+                                       out, pauses.seconds)
+                for name, seconds in parts.items():
+                    timed_stage(name, seconds)
+            faulted = major = None
+            if faults is not None:
+                faulted, major = (b - a for a, b in
+                                  zip(faults, process_faults()))
             with span("persist_close"):
                 out.close()
                 if store.streams_writes:
                     # the store closes it again on its way out: a no-op
                     f.close()
-    observe_persist(out.size, store.streams_writes, out.write_seconds,
-                    out.hash_seconds, fetched.device_bytes,
-                    fetched.wait_seconds)
+    # the digest thread's sums are final since the join
+    for name, seconds in digest_parts(out).items():
+        timed_stage(name, seconds)
+        parts[name] = seconds
+    record = PersistRecord(
+        size=out.size, streamed=store.streams_writes,
+        device_bytes=fetched.device_bytes, dump_seconds=dump_seconds,
+        parts=parts, slowest_write_bytes=out.slowest_write_bytes,
+        slowest_write_offset=out.slowest_write_offset,
+        digest_life_seconds=out.life_seconds,
+        faulted_bytes=faulted, major_faults=major, dirty_bytes=dirty)
+    observe_persist(record)
+    logger.info("%s", record.line())
     return out.hexdigest(), out.size
 
 

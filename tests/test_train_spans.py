@@ -13,6 +13,7 @@ from predictionio_tpu.obs.registry import MetricsRegistry, default_registry
 #: table B of ISSUE 24: every span of a recommendation train, with the
 #: span that encloses it (None = the job itself)
 TRAIN_SPANS = {
+    "train_begin": None,
     "train_read": None,
     "ingest_digest": "train_read",
     "ingest_scan": "train_read",
@@ -27,6 +28,17 @@ TRAIN_SPANS = {
     "train_persist": None,
     "persist_models": "train_persist",
     "persist_dump": "train_persist",
+    # ISSUE 49: `persist_dump` from inside, a sum a persist each, timed by
+    # the thread that spends it (`tracing.timed_stage`, no annotation);
+    # the digest thread's two are final after the join
+    "persist_leaf_wait": "persist_dump",
+    "persist_put_wait": "persist_dump",
+    "persist_store_write": "persist_dump",
+    "persist_slowest_write": "persist_dump",
+    "persist_gc": "persist_dump",
+    "persist_walk": "persist_dump",
+    "persist_hash": "train_persist",
+    "persist_hash_starved": "train_persist",
     "persist_close": "train_persist",
     "persist_commit": "train_persist",
     "persist_instance_update": "train_persist",
@@ -219,7 +231,8 @@ def test_failed_train_publishes_closed_spans(rated_app):
                 pass
             raise RuntimeError("boom")
 
-    closed = ("train_read", "train_prepare", "boom_step", "train_algorithm")
+    closed = ("train_begin", "train_read", "train_prepare", "boom_step",
+              "train_algorithm")
     never = ("train_persist", "train_release")
     counts0 = {name: span_count(name) for name in closed + never}
     runs0 = run_counts("pio_train")
@@ -381,6 +394,56 @@ def test_spans_are_annotations_in_a_profiler_capture(tmp_path):
     assert "pio:als_fetch" in host_events
     # the Python tracer is off: no event per Python call of the host loop
     assert not any(name.startswith("$") for name in host_events)
+
+
+def test_a_capture_names_the_preamble_and_keeps_persist_dump_whole(
+        rated_app, tmp_path):
+    """A train inside a capture: `pio:train_begin` names `run_train`'s
+    stretch before the read, `pio:persist_dump` is there, and none of
+    its eight parts is an annotation: they are sums a persist, timed by
+    the thread that spent them, and an annotation a buffer (or any on
+    the digest thread) would cut the one gap of a capture into pieces."""
+    import glob
+    import threading
+
+    from jax.profiler import ProfileData
+
+    from predictionio_tpu.engines.recommendation import (
+        default_engine_params, engine,
+    )
+    from predictionio_tpu.obs import profiler
+    from predictionio_tpu.workflow import run_train
+
+    def train():
+        return run_train(
+            engine(),
+            default_engine_params(rated_app, rank=4, num_iterations=2),
+            engine_factory="predictionio_tpu.engines.recommendation:engine")
+
+    train()                                        # compile outside
+    before = span_count("train_begin")
+    out: dict = {}
+    capture = threading.Thread(target=lambda: out.update(
+        profiler.capture(0.5, str(tmp_path / "prof"))))
+    capture.start()
+    deadline = time.monotonic() + 30
+    while capture.is_alive() and time.monotonic() < deadline:
+        train()
+    capture.join(timeout=30)
+    assert not capture.is_alive() and out["traceDir"]
+    assert span_count("train_begin") > before
+    files = glob.glob(f"{out['traceDir']}/plugins/profile/*/*.xplane.pb")
+    host_events = {
+        e.name for plane in ProfileData.from_file(files[-1]).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events}
+    assert {"pio:train_begin", "pio:persist_dump",
+            "pio:train_persist"} <= host_events
+    parts = {name for name, parent in TRAIN_SPANS.items()
+             if name.startswith("persist_")
+             and (parent == "persist_dump" or "hash" in name)}
+    assert len(parts) == 8
+    assert not {f"pio:{name}" for name in parts} & host_events
 
 
 def test_a_silent_retrace_is_counted_and_kept_out_of_the_half_sweeps():

@@ -226,14 +226,9 @@ def _by_serialize_models(models, tmp_path):
     return serialize_models(models), None
 
 
-def _by_persist(models, tmp_path):
-    """`_persist` into a file store: (the stored bytes, the bytes the
-    device counter gained), the returned digest held to the bytes."""
-    import hashlib
-
-    from predictionio_tpu.obs.registry import default_registry
-    from predictionio_tpu.workflow.train import _persist
-
+def _models_in_files(tmp_path):
+    """Metadata in sqlite, models in a local file store (the caller
+    resets `Storage`)."""
     Storage.configure({
         "sources": {"DB": {"TYPE": "sqlite", "PATH": str(tmp_path / "p.db")},
                     "M": MODEL_STORES["localfs"](tmp_path)},
@@ -243,6 +238,17 @@ def _by_persist(models, tmp_path):
             "MODELDATA": {"NAME": "pio", "SOURCE": "M"},
         },
     })
+
+
+def _by_persist(models, tmp_path):
+    """`_persist` into a file store: (the stored bytes, the bytes the
+    device counter gained), the returned digest held to the bytes."""
+    import hashlib
+
+    from predictionio_tpu.obs.registry import default_registry
+    from predictionio_tpu.workflow.train import _persist
+
+    _models_in_files(tmp_path)
     try:
         before = _persist_series()["device"]
         digest, size = _persist("m", models)
@@ -373,6 +379,348 @@ def test_digesting_writer_keeps_order_and_stops_its_thread():
         with DigestingWriter(Full()) as out:
             out.write(b"x" * 100_000)
     assert digest_threads() == []
+
+
+# -- the write accounted from inside, by the thread that spends it ----------
+
+class _SlowSha:
+    """A sha256 whose every update takes `seconds` longer."""
+
+    def __init__(self, seconds):
+        import hashlib
+
+        self._sha, self._seconds = hashlib.sha256(), seconds
+
+    def update(self, view):
+        import time
+
+        time.sleep(self._seconds)
+        self._sha.update(view)
+
+    def hexdigest(self):
+        return self._sha.hexdigest()
+
+
+class _SlowFile:
+    """A store whose write takes `seconds`, and `stall` more at the call
+    of index `at`."""
+
+    def __init__(self, seconds, at=None, stall=0.0):
+        self.calls = 0
+        self._seconds, self._at, self._stall = seconds, at, stall
+
+    def write(self, view):
+        import time
+
+        time.sleep(self._seconds + (self._stall if self.calls == self._at
+                                    else 0.0))
+        self.calls += 1
+
+
+@pytest.mark.parametrize("slow", ["store", "hash"])
+def test_digesting_writer_says_which_thread_paces_the_other(slow):
+    """Each thread's clocks: a slow store is `write_seconds` and, at the
+    one call that stalled, `slowest_write_seconds` with that call's bytes
+    and offset, and the digest thread starves while the writer never
+    waits for room in the queue; a slow hash is the reverse, the writer
+    blocked in `put` once it is `_QUEUE_DEPTH` buffers ahead. The digest
+    thread's two sums are its whole life."""
+    import hashlib
+    import io
+
+    from predictionio_tpu.workflow.serialization import DigestingWriter
+
+    chunks = [bytes([i]) * (1000 + i) for i in range(12)]
+    sink = _SlowFile(0.004, at=7, stall=0.03) if slow == "store" \
+        else io.BytesIO()
+    with DigestingWriter(sink) as out:
+        if slow == "hash":
+            out._sha = _SlowSha(0.004)
+        for c in chunks:
+            out.write(c)
+    assert out.hexdigest() == hashlib.sha256(b"".join(chunks)).hexdigest()
+    if slow == "store":
+        assert 12 * 0.004 + 0.03 <= out.write_seconds < 0.2
+        assert 0.034 <= out.slowest_write_seconds < 0.1
+        assert out.slowest_write_bytes == len(chunks[7])
+        assert out.slowest_write_offset == sum(map(len, chunks[:7]))
+        assert out.put_wait_seconds < 0.03
+        assert out.starved_seconds > 12 * 0.004
+        assert out.hash_seconds < 0.03
+    else:
+        assert out.hash_seconds >= 12 * 0.004
+        # eight of the twelve puts found the queue full
+        assert out.put_wait_seconds > 4 * 0.004
+        assert out.starved_seconds < 0.03
+        assert out.write_seconds < 0.03
+        assert out.slowest_write_seconds <= out.write_seconds
+    assert out.hash_seconds + out.starved_seconds <= out.life_seconds
+    assert out.life_seconds - out.hash_seconds - out.starved_seconds < 0.02
+
+
+def test_a_collection_on_the_writing_thread_counts_once():
+    """`CollectorPauses` hears the collector on the thread that entered
+    it and on no other, takes `gc.callbacks` back as it was, and a clock
+    with a collection inside it (a leaf's wait, the store's write) leaves
+    the pause out."""
+    import gc
+    import threading
+    import time
+
+    from predictionio_tpu.workflow.serialization import (
+        CollectorPauses, DigestingWriter, dump_models,
+    )
+
+    hooks = list(gc.callbacks)
+    pauses = CollectorPauses()
+
+    class Collecting:
+        def write(self, view):
+            time.sleep(0.002)
+            gc.collect()
+
+    class Leaf:
+        def __reduce__(self):
+            gc.collect()
+            return (dict, ())
+
+    with DigestingWriter(Collecting(), pauses) as out:
+        with pauses:
+            assert len(gc.callbacks) == len(hooks) + 1
+            other = threading.Thread(target=gc.collect)
+            other.start()
+            other.join()
+            assert pauses.seconds == 0.0
+            t0 = time.perf_counter()
+            dump_models([{"leaves": [Leaf() for _ in range(3)],
+                          "w": np.arange(100_000.)}], out, pauses)
+            wall = time.perf_counter() - t0
+    assert gc.callbacks == hooks
+    assert pauses.seconds > 0
+    # every write collected: without the subtraction the write's clock
+    # would hold nearly all of the collector's seconds
+    assert out.write_seconds + out.put_wait_seconds + pauses.seconds \
+        <= wall + 1e-4
+    gc.collect()
+    assert pauses.seconds + out.write_seconds < wall     # nothing hooked now
+
+
+def _persist_under_a_job(tmp_path, monkeypatch, models):
+    """`_persist` into a file store under a job's trace: (the record
+    `observe_persist` was handed, seconds and count by span of the job's
+    own registry, the job's span rows)."""
+    from predictionio_tpu.obs import tracing
+    from predictionio_tpu.obs.registry import MetricsRegistry
+    from predictionio_tpu.workflow import train
+
+    records = []
+    observe = train.observe_persist
+    monkeypatch.setattr(train, "observe_persist",
+                        lambda r: (records.append(r), observe(r)))
+    _models_in_files(tmp_path)
+    own = MetricsRegistry()
+    try:
+        with tracing.adopt("job", registry=own) as trace:
+            with tracing.span("train_persist"):
+                train._persist("m", models)
+    finally:
+        Storage.reset()
+    hist = own.get("pio_span_duration_seconds")
+    spans = {labels["span"]: (hist.sum_(**labels), hist.count(**labels))
+             for labels, _ in hist.samples()}
+    return (records[0] if records else None), spans, trace.spans
+
+
+PICKLING_SUM = ("persist_leaf_wait", "persist_put_wait",
+                "persist_store_write", "persist_gc", "persist_walk")
+
+
+def test_a_persists_parts_add_up_to_its_dump(tmp_path, monkeypatch):
+    """The pickling thread's five parts are `persist_dump`'s seconds to a
+    millisecond (`persist_walk` is what the four clocks leave), the
+    slowest write is one of the store's calls, the digest thread's two
+    parts are its life; each of the eight is one sample a persist of the
+    job's span histogram, a part of 0 too, and one row of the job's
+    record: the pickling thread's six under `persist_dump`, the digest
+    thread's two under `train_persist`."""
+    record, spans, rows = _persist_under_a_job(
+        tmp_path, monkeypatch, [_seqrec_model(_on_device)])
+    parts = PICKLING_SUM + ("persist_slowest_write", "persist_hash",
+                            "persist_hash_starved")
+    assert set(record.parts) == set(parts)
+    for name in parts + ("persist_dump", "persist_close"):
+        assert spans[name][1] == 1, name
+        assert spans[name][0] >= 0
+    assert spans["persist_gc"][0] == record.parts["persist_gc"] >= 0
+    dump = spans["persist_dump"][0]
+    assert sum(record.parts[n] for n in PICKLING_SUM) \
+        == pytest.approx(record.dump_seconds, abs=1e-9)
+    assert abs(sum(spans[n][0] for n in PICKLING_SUM) - dump) < 1e-3
+    assert 0 < spans["persist_slowest_write"][0] \
+        <= spans["persist_store_write"][0]
+    assert record.slowest_write_bytes > 0
+    assert 0 <= record.slowest_write_offset < record.size
+    assert spans["persist_leaf_wait"][0] > 0 and record.device_bytes > 0
+    life = record.digest_life_seconds
+    assert abs(spans["persist_hash"][0] + spans["persist_hash_starved"][0]
+               - life) < max(0.005, 0.01 * life)
+    parents = {r.name: r.parent.name for r in rows if r.parent is not None}
+    for name in PICKLING_SUM + ("persist_slowest_write",):
+        assert parents[name] == "persist_dump"
+    for name in ("persist_hash", "persist_hash_starved"):
+        assert parents[name] == "train_persist"
+    line = record.line()
+    for word in ("leaf_wait", "put_wait", "store_write", "gc", "walk",
+                 "slowest write", "hash", "starved",
+                 f"{record.slowest_write_bytes} bytes at offset "
+                 f"{record.slowest_write_offset}"):
+        assert word in line
+
+
+@pytest.mark.parametrize("missing", ["meminfo", "getrusage"])
+def test_a_persist_without_the_machines_state_publishes_no_series(
+        tmp_path, monkeypatch, missing):
+    """On a platform without `/proc/meminfo` the dirty series gains
+    nothing, without `getrusage` the two fault counters gain nothing,
+    and the persist raises nothing and publishes the rest."""
+    import builtins
+
+    from predictionio_tpu.obs.registry import default_registry
+    from predictionio_tpu.workflow import instrument
+
+    def reading():
+        reg = default_registry()
+        dirty = reg.get("pio_train_persist_dirty_bytes")
+        faulted = reg.get("pio_train_persist_faulted_bytes_total")
+        major = reg.get("pio_train_persist_major_faults_total")
+        return {"dirty": dirty.count() if dirty else 0,
+                "faulted": faulted.value() if faulted else 0,
+                "major": major.value() if major else 0,
+                "bytes": instrument.persist_bytes().value()}
+
+    # both there (Linux): each series gains its sample
+    before = reading()
+    record, _, _ = _persist_under_a_job(tmp_path, monkeypatch, _als_model())
+    seen = reading()
+    if instrument.unwritten_bytes() is not None:
+        assert seen["dirty"] == before["dirty"] + 1
+        assert record.dirty_bytes >= 0
+    if instrument.process_faults() is not None:
+        assert seen["faulted"] >= before["faulted"]
+        assert record.faulted_bytes >= 0 and record.major_faults >= 0
+
+    if missing == "meminfo":
+        real_open = builtins.open
+
+        def no_proc(path, *a, **kw):
+            if path == instrument.MEMINFO:
+                raise FileNotFoundError(path)
+            return real_open(path, *a, **kw)
+        monkeypatch.setattr(builtins, "open", no_proc)
+        assert instrument.unwritten_bytes() is None
+    else:
+        import resource
+
+        def no_rusage(who):
+            raise OSError("no getrusage here")
+        monkeypatch.setattr(resource, "getrusage", no_rusage)
+        assert instrument.process_faults() is None
+    record, spans, _ = _persist_under_a_job(tmp_path, monkeypatch,
+                                            _als_model())
+    after = reading()
+    assert after["bytes"] == seen["bytes"] + record.size
+    assert spans["persist_walk"][1] == 1
+    if missing == "meminfo":
+        assert record.dirty_bytes is None
+        assert after["dirty"] == seen["dirty"]
+    else:
+        assert record.faulted_bytes is None and record.major_faults is None
+        assert (after["faulted"], after["major"]) \
+            == (seen["faulted"], seen["major"])
+        assert "faulted None bytes" in record.line()
+
+
+@pytest.mark.parametrize("breaks", [False, True])
+def test_the_collectors_hook_is_gone_after_a_persist(tmp_path, monkeypatch,
+                                                     breaks):
+    """`gc.callbacks` is what it was before a persist, one whose pickle
+    raised included; a failed persist publishes no part."""
+    import gc
+
+    hooks = list(gc.callbacks)
+    models = [{"first": np.ones(50_000, np.float32),
+               "second": _SecondLeafBreaks() if breaks else 2}]
+    if breaks:
+        with pytest.raises(RuntimeError, match="second leaf"):
+            _persist_under_a_job(tmp_path, monkeypatch, models)
+    else:
+        record, spans, _ = _persist_under_a_job(tmp_path, monkeypatch,
+                                                models)
+        assert spans["persist_gc"][1] == 1 and record.size > 200_000
+    assert gc.callbacks == hooks
+
+
+def test_a_releases_bytes_are_pinned():
+    """One release's size and sha256, as the tree before ISSUE 49 wrote
+    it (pickle protocol 5 of numpy arrays, in and out of band: the
+    literal is this numpy's and this Python's): the accounting changed
+    no byte and no order of the writes."""
+    import hashlib
+    import io
+
+    from predictionio_tpu.workflow.serialization import (
+        DigestingWriter, dump_models,
+    )
+
+    rng = np.random.default_rng(49)
+    models = [{"w": rng.normal(size=(300, 64)).astype(np.float32),
+               "table": [np.arange(70_000, dtype=np.int32),
+                         _on_device(np.linspace(0, 1, 40_000,
+                                                dtype=np.float32))],
+               "names": np.asarray(["a", "b"], dtype=object), "n": 49},
+              None]
+    sink = io.BytesIO()
+    with DigestingWriter(sink) as out:
+        dump_models(models, out)
+    assert out.hexdigest() == hashlib.sha256(sink.getvalue()).hexdigest()
+    assert (out.size, out.hexdigest()) == PINNED_RELEASE
+
+
+PINNED_RELEASE = (
+    517274,
+    "486973847dbfde6795f754f1987db48c6fe7f94550f43565eada32a808855a6a")
+
+
+def test_the_persist_probe_runs_unedited_on_this_writer(tmp_path):
+    """`benchmarks/tools/persist_probe.py` (a builder's tool, not this
+    PR's to edit) reads the writer's `write_seconds`, `hash_seconds` and
+    `size` and subclasses `_ReleasePickler` for its `wait_seconds` and
+    `device_bytes`: its `--tiny` rehearsal runs to its last line and its
+    device and host routes agree on the digest."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "TMPDIR": str(tmp_path)}
+    env.pop("XLA_FLAGS", None)
+    done = subprocess.run(
+        [sys.executable, os.path.join(root, "benchmarks", "tools",
+                                      "persist_probe.py"),
+         "--tiny", "--rounds", "1", "--ahead", "1"],
+        cwd=str(tmp_path), env=env, capture_output=True, text=True,
+        timeout=280)
+    assert done.returncode == 0, done.stderr[-2000:]
+    readings = json.loads(done.stdout.strip().splitlines()[-1])["readings"]
+    persists = [r for r in readings
+                if str(r.get("reading", "")).startswith("persist_")]
+    assert len(persists) == 4
+    assert len({r["digest"] for r in persists}) == 1
+    for r in persists:
+        assert r["bytes"] > 0 and r["write_s"] > 0 and r["hash_s"] > 0
+    assert all(r["device_bytes"] > 0 for r in persists
+               if r["reading"] != "persist_host")
 
 
 MODEL_STORES = {
